@@ -322,7 +322,7 @@ class Config:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"   # MXU-friendly activations/matmuls
     remat: bool = False               # jax.checkpoint the DNN tower
-    use_pallas: bool = True           # fused Pallas FM kernel when on TPU
+    use_pallas: bool = True           # fused Pallas FM kernel in the train/eval steps on TPU
     # Row-sharded lookup collective: masked_psum (traffic ∝ batch; the CTR
     # default) or allgather_table (traffic ∝ table; huge-batch/small-table
     # regimes). See TUNING.md "Sharded embedding lookup".
@@ -356,13 +356,12 @@ class Config:
     # bits of relative precision per element vs int8's fixed grid.
     embedding_cold_dtype: str = "float32"  # float32 | int8 | fp8_e4m3
     # Sparse embedding-plane kernel selection (ops/pallas_embedding.py):
-    # "auto" = Pallas kernels on TPU where the probe passes, the optimized
-    # XLA legs (counting plan build, fused one-leaf backward, select
-    # writeback, fused cache install) elsewhere; "pallas" forces Pallas
-    # where possible; "xla" forces the optimized XLA legs even on TPU;
-    # "off" is the kill switch — the seed formulation everywhere,
-    # bit-for-bit. TUNING §2.11 has the selection table.
-    embedding_kernels: str = "auto"   # auto | pallas | xla | off
+    # "auto" = the optimized legs (counting plan build, fused one-leaf
+    # backward, select writeback, fused cache install), with the Pallas
+    # take kernel on TPU where its working set fits VMEM; "xla" forces the
+    # XLA legs even on TPU; "off" is the kill switch — the seed formulation
+    # everywhere, bit-for-bit. TUNING §2.11 has the selection table.
+    embedding_kernels: str = "auto"   # auto | xla | off
     # Model-parallel row sharding of the embedding tables under the SPARSE
     # update path: "rows" partitions every logical table (monolithic or
     # hash-bucketed) contiguously over the model mesh axis with the
@@ -684,9 +683,9 @@ class Config:
             raise ValueError(
                 f"embedding_cold_dtype must be float32|int8|fp8_e4m3, got "
                 f"{self.embedding_cold_dtype!r}")
-        if self.embedding_kernels not in ("auto", "pallas", "xla", "off"):
+        if self.embedding_kernels not in ("auto", "xla", "off"):
             raise ValueError(
-                f"embedding_kernels must be auto|pallas|xla|off, got "
+                f"embedding_kernels must be auto|xla|off, got "
                 f"{self.embedding_kernels!r}")
         if self.embedding_shard not in ("off", "rows"):
             raise ValueError(

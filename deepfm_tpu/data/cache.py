@@ -72,14 +72,9 @@ class CacheColumns(NamedTuple):
 
 def decoder_version() -> str:
     """Identity of the decode implementation baked into cached rows."""
-    try:
-        from ..native import loader  # noqa: PLC0415
+    from ..native import loader  # noqa: PLC0415
 
-        if loader.available():
-            return "native-1"
-    except Exception:
-        pass
-    return "python-1"
+    return "native-1" if loader.available() else "python-1"
 
 
 def compute_fingerprint(files: List[str], *, field_size: int,

@@ -38,7 +38,8 @@ Batch = Dict[str, np.ndarray]
 
 
 def decode_batch_python(records: Sequence[bytes], field_size: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Vectorized-decode fallback: parse each Example with the Python codec."""
+    """Batched decode with the Python codec (``use_native_decoder=False``,
+    and the reference the native decoder is tested against)."""
     n = len(records)
     labels = np.empty((n,), np.float32)
     ids = np.empty((n, field_size), np.int32)
@@ -54,7 +55,7 @@ def decode_batch_python(records: Sequence[bytes], field_size: int) -> Tuple[np.n
 def decode_batch2_python(records: Sequence[bytes], field_size: int
                          ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
                                     np.ndarray]:
-    """Two-label decode fallback (multi-task input): ``label2`` defaults to
+    """Two-label Python decode (multi-task input): ``label2`` defaults to
     0.0 for single-label records. Mirrors ``native.loader.decode_batch2``."""
     n = len(records)
     labels = np.empty((n,), np.float32)
@@ -72,25 +73,18 @@ def decode_batch2_python(records: Sequence[bytes], field_size: int
 
 
 def _get_decoder(use_native: bool):
+    """The batched decoder the caller asked for: the native one builds on
+    first use and a failed build raises (``native.loader.NativeBuildError``)
+    — it never degrades to the per-record Python codec on its own."""
     if use_native:
-        try:
-            from ..native import loader  # noqa: PLC0415 (lazy: builds .so on first use)
-            if loader.available():
-                return loader.decode_batch
-        except Exception:
-            pass
+        return _native_loader().decode_batch
     return decode_batch_python
 
 
 def _get_decoder2(use_native: bool):
-    """Two-label sibling of ``_get_decoder`` (same fallback discipline)."""
+    """Two-label sibling of ``_get_decoder``."""
     if use_native:
-        try:
-            from ..native import loader  # noqa: PLC0415
-            if loader.available():
-                return loader.decode_batch2
-        except Exception:
-            pass
+        return _native_loader().decode_batch2
     return decode_batch2_python
 
 
@@ -98,7 +92,7 @@ def decode_batch_hist_python(records: Sequence[bytes], field_size: int,
                              max_len: int
                              ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
                                         np.ndarray, np.ndarray, np.ndarray]:
-    """History decode fallback (sequence-model input): the ragged
+    """History Python decode (sequence-model input): the ragged
     ``hist_ids``/``hist_vals`` pair zero-padded/truncated to ``max_len`` per
     record. Mirrors ``native.loader.decode_batch_hist``."""
     n = len(records)
@@ -121,16 +115,9 @@ def decode_batch_hist_python(records: Sequence[bytes], field_size: int,
 
 
 def _get_decoder_hist(use_native: bool):
-    """History sibling of ``_get_decoder``. The native entry internally
-    falls back per-record to the Python codec mirror on a stale .so, so
-    either return emits identical values."""
+    """History sibling of ``_get_decoder``."""
     if use_native:
-        try:
-            from ..native import loader  # noqa: PLC0415
-            if loader.available():
-                return loader.decode_batch_hist
-        except Exception:
-            pass
+        return _native_loader().decode_batch_hist
     return decode_batch_hist_python
 
 
@@ -165,14 +152,11 @@ def _timed(stats, name: str):
 
 
 def _native_loader():
-    """The native decoder module, or None when toolchain/build unavailable."""
-    try:
-        from ..native import loader  # noqa: PLC0415
-        if loader.available():
-            return loader
-    except ImportError:
-        pass
-    return None
+    """The native decoder module, built and loaded (first use compiles it;
+    ``NativeBuildError`` propagates when that fails)."""
+    from ..native import loader  # noqa: PLC0415 (lazy: builds .so on first use)
+    loader.load()
+    return loader
 
 
 def _iter_framed_stream(stream: BinaryIO, loader, verify_crc: bool = True,
@@ -714,30 +698,24 @@ class CtrPipeline:
         return arrival[np.argsort(perm)].astype(np.int32)
 
     def _make_input_service(self, epoch: int):
-        """Spawn the decode-worker fleet for one epoch, or None to fall
-        back in-process (service start can fail where spawn or POSIX shm
-        is restricted — the pipeline must degrade, not die)."""
+        """Spawn the decode-worker fleet for one epoch. A start failure
+        (spawn or POSIX shm restricted) propagates: the caller asked for
+        ``input_workers`` decode processes, and the in-process decode is a
+        different, slower host path."""
         from . import workers  # noqa: PLC0415 (keeps module import light)
-        try:
-            return workers.ShmInputService(
-                self._epoch_files(epoch),
-                field_size=self.field_size,
-                num_workers=self.input_workers,
-                slab_records=self.input_worker_slab_records,
-                verify_crc=self.verify_crc,
-                on_bad_record=self._bad_policy.on_bad,
-                max_bad_records=self._bad_policy.max_bad,
-                retry_policy=self._retry_policy,
-                health=self.health,
-                on_worker_death=self.input_worker_death,
-                stall_timeout_s=self.stall_timeout_s,
-            ).start()
-        except Exception as exc:
-            import warnings  # noqa: PLC0415
-            warnings.warn(
-                f"input service unavailable ({exc!r}); falling back to "
-                f"in-process decode", RuntimeWarning, stacklevel=2)
-            return None
+        return workers.ShmInputService(
+            self._epoch_files(epoch),
+            field_size=self.field_size,
+            num_workers=self.input_workers,
+            slab_records=self.input_worker_slab_records,
+            verify_crc=self.verify_crc,
+            on_bad_record=self._bad_policy.on_bad,
+            max_bad_records=self._bad_policy.max_bad,
+            retry_policy=self._retry_policy,
+            health=self.health,
+            on_worker_death=self.input_worker_death,
+            stall_timeout_s=self.stall_timeout_s,
+        ).start()
 
     def _iter_framed_span_chunks(self, epoch: int, loader
                                  ) -> Iterator[Tuple[bytes, np.ndarray,

@@ -126,8 +126,6 @@ def worker_main(worker_id: int, handle: shm_ring.RingHandle,
             retry_policy = RetryPolicy(**opts["retry"])
         from . import pipeline as pipe_mod  # noqa: PLC0415
         loader = pipe_mod._native_loader()
-        if loader is None:
-            raise RuntimeError("native decoder unavailable in input worker")
         S = handle.slab_records
         F = handle.field_size
         for fidx, path in files:

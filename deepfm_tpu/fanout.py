@@ -1,12 +1,21 @@
 """Local worker fan-out: spawn ``worker_per_host`` training processes on this
-host, each with its own JAX process id — the TPU-native analog of the
-reference's MPI launch (``mpirun -np 4`` via ``processes_per_host=4``,
+host, each with its own JAX process id — the analog of the reference's MPI
+launch (``mpirun -np 4`` via ``processes_per_host=4``,
 ``2-hvd-gpu/deepfm-sagemaker-hvd-gpu.ipynb:87-92``).
 
-Usage (one command per host; see scripts/launch_slice.sh for the multi-host
-wrapper):
+More than one worker per host is for CPU clusters (``JAX_PLATFORMS=cpu``: the
+local test cluster, where the workers share the virtual devices). On an
+accelerator host it is refused: the chips of one host belong to ONE process
+(``--mesh_data/--mesh_model`` spread the work over them). Pinning one chip per
+worker with ``TPU_VISIBLE_DEVICES`` — what this module used to do — was tried
+on a four-chip v5e host (PERF.md, PR 21): the workers collide on libtpu's
+multi-process lock, three abort at backend start-up, and none of them exits
+until the distributed shutdown times out minutes later. With
+``--worker_per_host 1`` this is the per-host launcher of a multi-host job.
 
-    python -m deepfm_tpu.fanout --worker_per_host 4 \
+Usage (one command per host):
+
+    JAX_PLATFORMS=cpu python -m deepfm_tpu.fanout --worker_per_host 4 \
         --num_hosts 2 --host_index 0 --coordinator_address host0:12355 \
         --task_type train --data_dir ... <any launch.py flags>
 
@@ -15,10 +24,6 @@ Spawns ``worker_per_host`` copies of ``python -m deepfm_tpu.launch`` with:
   * ``num_processes`` = num_hosts * worker_per_host
   * ``dist_mode=1`` rendezvous on the coordinator (defaults to a local port
     for single-host runs)
-  * ``TPU_VISIBLE_DEVICES=<local_worker>`` so each worker binds one local
-    chip (the GPU-pinning analog of ``visible_device_list = local_rank``,
-    reference ``2-hvd-gpu/...py:355-357``); skipped when JAX_PLATFORMS=cpu
-    (CPU test clusters share the virtual devices).
 
 The parent streams children's output and exits nonzero if any child fails.
 """
@@ -63,6 +68,14 @@ def main(argv: Optional[List[str]] = None) -> int:
     n = args.worker_per_host
     if n < 1:
         raise SystemExit("--worker_per_host must be >= 1")
+    if n > 1 and os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        raise SystemExit(
+            f"fanout: --worker_per_host {n} needs JAX_PLATFORMS=cpu. On an "
+            "accelerator host several workers cannot form one device "
+            "topology (one chip per worker was tried on four v5e chips: the "
+            "workers collide on libtpu's multi-process lock and hang); run "
+            "ONE process over all the host's chips instead: python -m "
+            "deepfm_tpu.launch --mesh_data N [--mesh_model M].")
     if args.num_hosts > 1 and not args.coordinator_address:
         raise SystemExit(
             "--coordinator_address is required for num_hosts > 1 "
@@ -83,13 +96,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             "--coordinator_address", coord,
             "--worker_per_host", str(n),
         ]
-        env = dict(os.environ)
-        if env.get("JAX_PLATFORMS", "").lower() != "cpu":
-            # One chip per local worker (GPU-pinning analog, ref :355-357).
-            env["TPU_VISIBLE_DEVICES"] = str(local)
         p = subprocess.Popen(
-            cmd, env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-            text=True)
+            cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
         procs.append(p)
         t = threading.Thread(
             target=_pump, args=(p.stdout, sys.stdout, f"worker {pid}"),

@@ -12,15 +12,30 @@ from __future__ import annotations
 import json
 import sys
 
+import jax
+
 from .config import parse_args
 from .parallel import bootstrap
 from .train import tasks
+from .utils import compile_cache
 from .utils import logging as ulog
 from .utils import preempt as preempt_lib
 
 
+def device_report() -> dict:
+    """The devices this process ran on, as JAX reports them. Stamped into
+    every result line so a number can never be read under another device's
+    name (a parent collecting children's results reads it from theirs)."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
+
+
 def main(argv=None) -> int:
     cfg = parse_args(argv)
+    # Before the first compile: JAX decides once whether a persistent
+    # compilation cache is in use.
+    compile_cache.configure()
     # Bootstrap before the first log line: rank-aware logging calls
     # jax.process_index(), which would initialize the XLA backend and break
     # a later jax.distributed.initialize() (it must run first).
@@ -34,11 +49,12 @@ def main(argv=None) -> int:
         # exit code tells an orchestrator (scripts/supervise.py) "restart
         # me" as opposed to an ordinary crash.
         ulog.warning(f"exiting after preemption: {p}")
-        print(json.dumps({"task": cfg.task_type, "preempted": True,
-                          "step": p.step}))
+        print(json.dumps({"task": cfg.task_type, "device": device_report(),
+                          "preempted": True, "step": p.step}))
         return preempt_lib.EXIT_PREEMPTED
     ulog.info(f"task {cfg.task_type} finished: {result}")
-    print(json.dumps({"task": cfg.task_type, **result}))
+    print(json.dumps({"task": cfg.task_type, "device": device_report(),
+                      **result}))
     return 0
 
 

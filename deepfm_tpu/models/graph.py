@@ -29,6 +29,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import export as jax_export
 
 from ..config import Config
 from ..ops import fm as fm_ops
@@ -52,9 +53,14 @@ def fm_block(cfg: Config, w: jnp.ndarray, feat_vals: jnp.ndarray,
     Matches DeepFM's reference graph: ``sum_f(W*vals) + FM(xv)``. Takes the
     Pallas fused kernel when supported — both reductions in one VMEM pass —
     else the factored identity from ``ops.fm``.
+
+    The kernel's grid is sized from the batch, so it needs a static one: the
+    exported serving function (``utils.export``) traces with a symbolic
+    batch and is lowered for CPU and TPU at once, and therefore takes the
+    portable formulation on every platform.
     """
-    if cfg.use_pallas and pallas_fm.supported(cfg.field_size,
-                                              cfg.embedding_size):
+    if (cfg.use_pallas and not jax_export.is_symbolic_dim(xv.shape[0])
+            and pallas_fm.supported(cfg.field_size, cfg.embedding_size)):
         # Fused Pallas path: both FM reductions in one VMEM pass over the
         # same xv the tower consumes; d(xv)->d(v),d(vals) via JAX's
         # product rule outside the kernel.

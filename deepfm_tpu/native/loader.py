@@ -1,20 +1,25 @@
 """ctypes loader for the native TFRecord decoder (builds on first use).
 
-Compiles ``tfrecord_native.cc`` with g++ into a cached shared library and
-exposes:
+Compiles ``tfrecord_native.cc`` with g++ into a shared library under
+``_build/`` (never committed) and exposes:
   * ``split_frames(buf, verify_crc)`` -> (offsets, lengths) int64 arrays
   * ``decode_batch(records, field_size)`` -> (labels, ids, vals) — drop-in
     replacement for ``pipeline.decode_batch_python``
   * ``decode_file_bytes(buf, field_size, verify_crc)`` — whole-buffer
     one-pass framing + CRC + proto decode (the true hot path)
 
-Falls back gracefully: ``available()`` returns False if the toolchain or
-build fails, and the pipeline uses the pure-Python codec.
+The built file is named after a hash of the source, so a checkout always
+loads the library its own ``.cc`` describes: a fresh copy (where mtimes mean
+nothing) builds once, an edited source builds again, and a library left by
+another source is never picked up. A build or load failure raises
+:class:`NativeBuildError` — the per-record Python codec is a far slower
+host path, so a run asked to use the native decoder does not quietly take it.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,41 +30,63 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "tfrecord_native.cc")
 _BUILD_DIR = os.path.join(_HERE, "_build")
-_SO = os.path.join(_BUILD_DIR, "libtfrecord.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_build_failed = False
 
 
-def _build() -> bool:
+class NativeBuildError(RuntimeError):
+    """The native decoder could not be built or loaded on this machine."""
+
+
+def _so_path() -> str:
+    """``_build/libtfrecord-<source hash>.so`` for the source as it is now."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libtfrecord-{digest}.so")
+
+
+def _build(so: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO]
+    # Build beside the target and rename: input workers and parallel test
+    # processes may all find the library missing at once, and none of them
+    # may dlopen a half-written file.
+    tmp = f"{so}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except (subprocess.CalledProcessError, subprocess.TimeoutExpired,
-            FileNotFoundError):
-        return False
+        os.replace(tmp, so)
+    except FileNotFoundError as e:
+        raise NativeBuildError(
+            "native TFRecord decoder needs g++ on PATH to build "
+            f"{os.path.basename(_SRC)} (or run with "
+            "--use_native_decoder false)") from e
+    except subprocess.CalledProcessError as e:
+        raise NativeBuildError(
+            f"g++ failed building the native TFRecord decoder:\n"
+            f"{e.stderr.decode(errors='replace')[-2000:]}") from e
+    except subprocess.TimeoutExpired as e:
+        raise NativeBuildError(
+            "g++ timed out building the native TFRecord decoder") from e
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
-def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+def load() -> ctypes.CDLL:
+    """The decoder library, built first if this source has no build yet."""
+    global _lib
     with _lock:
         if _lib is not None:
             return _lib
-        if _build_failed:
-            return None
-        needs_build = (not os.path.exists(_SO)
-                       or os.path.getmtime(_SO) < os.path.getmtime(_SRC))
-        if needs_build and not _build():
-            _build_failed = True
-            return None
+        so = _so_path()
+        if not os.path.exists(so):
+            _build(so)
         try:
-            lib = ctypes.CDLL(_SO)
-        except OSError:
-            _build_failed = True
-            return None
+            lib = ctypes.CDLL(so)
+        except OSError as e:
+            raise NativeBuildError(
+                f"built native decoder {so} failed to load: {e}") from e
         # Buffer params are raw pointers (not c_char_p) so zero-copy views of
         # bytes AND mmap objects both work via np.frombuffer.
         lib.dfm_split_frames.restype = ctypes.c_long
@@ -144,12 +171,18 @@ def _load() -> Optional[ctypes.CDLL]:
 
 
 def available() -> bool:
-    return _load() is not None
+    """Whether the decoder builds and loads here — for test skips and
+    tooling. The input pipeline does not ask: with ``use_native_decoder``
+    it calls into the library and lets :class:`NativeBuildError` propagate."""
+    try:
+        load()
+    except NativeBuildError:
+        return False
+    return True
 
 
 def crc32c(data: bytes) -> int:
-    lib = _load()
-    assert lib is not None
+    lib = load()
     return int(lib.dfm_crc32c(data, len(data)))
 
 
@@ -162,8 +195,7 @@ def split_frames_partial(buf, *, verify_crc: bool = True
     chunk. This is the chunked-streaming primitive — constant memory on
     multi-GB shards, ordinary read() I/O (no mmap SIGBUS hazard on network
     filesystems)."""
-    lib = _load()
-    assert lib is not None
+    lib = load()
     cap = max(len(buf) // 16, 1)
     offsets = np.empty(cap, dtype=np.int64)
     lengths = np.empty(cap, dtype=np.int64)
@@ -188,8 +220,7 @@ def _as_ubyte_ptr(buf) -> "ctypes.POINTER(ctypes.c_ubyte)":
 
 def split_frames(buf, *, verify_crc: bool = True) -> Tuple[np.ndarray, np.ndarray]:
     """Frame offsets/lengths of every record in a TFRecord byte buffer."""
-    lib = _load()
-    assert lib is not None
+    lib = load()
     # Upper bound: every record is >= 16 bytes on disk.
     cap = max(len(buf) // 16, 1)
     offsets = np.empty(cap, dtype=np.int64)
@@ -209,8 +240,7 @@ def split_frames(buf, *, verify_crc: bool = True) -> Tuple[np.ndarray, np.ndarra
 
 def decode_spans(buf, offsets: np.ndarray, lengths: np.ndarray,
                  field_size: int) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    lib = _load()
-    assert lib is not None
+    lib = load()
     n = len(offsets)
     labels = np.empty(n, dtype=np.float32)
     ids = np.empty((n, field_size), dtype=np.int32)
@@ -254,7 +284,7 @@ def has_labels2() -> bool:
     """True when the built library exports the two-label decode entry
     (``dfm_decode_ctr2_ex``). False on a stale cached .so — callers fall
     back to the Python codec mirror, which emits identical values."""
-    lib = _load()
+    lib = load()
     return lib is not None and hasattr(lib, "dfm_decode_ctr2_ex")
 
 
@@ -266,7 +296,7 @@ def decode_spans2(buf, offsets: np.ndarray, lengths: np.ndarray,
     optional ``label2`` key (0.0 when absent). Falls back to the
     bit-identical Python codec mirror when the cached library predates the
     entry (same discipline as ``assemble_spans``)."""
-    lib = _load()
+    lib = load()
     n = len(offsets)
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
     lengths = np.ascontiguousarray(lengths, dtype=np.int64)
@@ -322,7 +352,7 @@ def has_hist() -> bool:
     """True when the built library exports the history decode entry
     (``dfm_decode_ctr_hist``). False on a stale cached .so — callers fall
     back to the Python codec mirror, which emits identical values."""
-    lib = _load()
+    lib = load()
     return lib is not None and hasattr(lib, "dfm_decode_ctr_hist")
 
 
@@ -338,7 +368,7 @@ def decode_spans_hist(
     per record (absent pair -> empty history). Falls back to the
     bit-identical Python codec mirror when the cached library predates the
     entry (same discipline as ``decode_spans2``)."""
-    lib = _load()
+    lib = load()
     n = len(offsets)
     offsets = np.ascontiguousarray(offsets, dtype=np.int64)
     lengths = np.ascontiguousarray(lengths, dtype=np.int64)
@@ -405,8 +435,7 @@ def decode_spans_scatter(buf, offsets: np.ndarray, lengths: np.ndarray,
     ``dest[i]`` is in bounds and disjoint across concurrent calls (the GIL
     is released inside the C call, so threads may fill disjoint rows of the
     same pool in parallel)."""
-    lib = _load()
-    assert lib is not None
+    lib = load()
     n = len(offsets)
     assert labels.flags.c_contiguous and ids.flags.c_contiguous \
         and vals.flags.c_contiguous
@@ -450,7 +479,7 @@ def has_assemble() -> bool:
     decode->assemble entry (``dfm_decode_ctr_assemble``). False on a stale
     cached .so from an older source tree — callers fall back to the
     per-chunk ``decode_spans_scatter`` path, which emits identical bytes."""
-    lib = _load()
+    lib = load()
     return lib is not None and hasattr(lib, "dfm_decode_ctr_assemble")
 
 
@@ -486,8 +515,7 @@ def assemble_spans(jobs, field_size: int, labels: np.ndarray,
     bounds and disjointness, exactly like ``decode_spans_scatter``; unlike
     it, the whole drain crosses ctypes once, so a contended host pays one
     GIL reacquisition per drain instead of one per chunk."""
-    lib = _load()
-    assert lib is not None
+    lib = load()
     if not jobs:
         return
     if not hasattr(lib, "dfm_decode_ctr_assemble"):

@@ -18,6 +18,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def lookup(table: jax.Array, ids: jax.Array, *,
@@ -144,9 +145,12 @@ _pad_warned = False
 # the mapping is pure arithmetic — no dictionaries, no RNG, no host state.
 # All math stays in uint32 (JAX_ENABLE_X64 off in tests and on TPU).
 
-_KNUTH = jnp.uint32(2654435761)       # 2^32 / golden ratio
-_MIX1 = jnp.uint32(0x85EBCA6B)        # murmur3 fmix32 constants
-_MIX2 = jnp.uint32(0xC2B2AE35)
+# NumPy scalars, not jnp: a jnp scalar is a device array, and creating one
+# at import starts the XLA backend before the launcher can call
+# jax.distributed.initialize() — which then refuses to run.
+_KNUTH = np.uint32(2654435761)        # 2^32 / golden ratio
+_MIX1 = np.uint32(0x85EBCA6B)         # murmur3 fmix32 constants
+_MIX2 = np.uint32(0xC2B2AE35)
 TABLE_ASSIGN_SALT = 0x9E3779B9        # distinct stream for table selection
 
 

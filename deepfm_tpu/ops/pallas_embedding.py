@@ -1,36 +1,37 @@
-"""Pallas TPU kernels for the sparse embedding plane, with XLA fallbacks.
+"""Kernel-leg selection for the sparse embedding plane.
 
-EMBED_r01 measured the sparse update path losing to dense (40.8 vs 14.7
-ms/step at V=100k) because its three hot seams ran on XLA defaults:
+EMBED_r01 measured the sparse update path losing to dense because its three
+hot seams ran on XLA defaults:
 
   1. **plan build** — ``make_plan``'s ``jnp.unique(size=N)`` lowers to a
-     sort-based program (~26 ms at N=40k on XLA:CPU);
+     sort-based program;
   2. **gather + segment-sum cotangent** — one forward gather per embedding
      name, and one batch-sized scatter-add per name in the backward;
   3. **cache install** — ``TieredEmbeddingRuntime`` launched one pow2-padded
      jit scatter per array (w/m/v/tau = 4 launches) per transaction.
 
-Each seam here has up to three legs, selected by :func:`resolve`:
+Each seam has the legs :func:`resolve` can return for it:
 
-  * ``pallas`` — a fused kernel (this module), compiled only on TPU behind
-    :func:`supported`; every kernel also runs through the Pallas
-    interpreter on CPU (``interpret=True``) so the tier-1 suite checks the
-    kernel bodies against NumPy oracles without TPU hardware.
+  * ``pallas`` — seam 2 only: :func:`take_rows_pallas`, compiled on TPU where
+    :func:`supported` says its working set fits VMEM. The Pallas plan-build
+    and install kernels this module used to carry were deleted: the TPU
+    lowering refuses their scalar stores into VMEM before Mosaic sees them,
+    so ``auto`` could select a program that did not exist (PERF.md, PR 21).
+    The kernel bodies also run through the Pallas interpreter on CPU
+    (``interpret=True``) so the tier-1 suite checks them against NumPy
+    oracles without hardware.
   * ``opt`` — a restructured XLA program with bit-identical outputs: the
     counting plan build (``ops.embedding.make_plan_counting``), the
     select-writeback (``scatter_rows`` on counting plans), and the fused
-    multi-array install. These are what ``auto`` picks on non-TPU backends.
+    multi-array install.
   * ``ref`` — the seed formulation, byte-for-byte (``--embedding_kernels
     off`` restores it everywhere: the kill switch).
 
-Selection is static per (backend, shape) from the committed A/B table in
-EMBED_r02.json — a leg only becomes the default where it measured a
-clean-band win; ties and losses keep the reference leg (TUNING §2.11 has
-the table). The one shape-dependent rule: the counting plan build does a
-vocab-shaped prefix sum, so it wins only while the physical table is small
-relative to the sort cost — above ``PLAN_COUNT_MAX_ROWS`` rows ``auto``
-keeps the sort-based ``make_plan`` (and with it the scatter writeback,
-whose cost does not scale with the vocab).
+The one shape-dependent rule: the counting plan build does a vocab-shaped
+prefix sum, so it wins only while the physical table is small relative to the
+sort cost — above ``PLAN_COUNT_MAX_ROWS`` rows ``auto`` keeps the sort-based
+``make_plan`` (and with it the scatter writeback, whose cost does not scale
+with the vocab).
 """
 
 from __future__ import annotations
@@ -41,18 +42,12 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import embedding as emb_ops
 
-try:  # pltpu import fails on some non-TPU builds; interpret mode never needs it
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
 #: embedding_kernels values (config-validated).
-MODES = ("auto", "pallas", "xla", "off")
+MODES = ("auto", "xla", "off")
 
 # The counting plan build costs one [rows+1] prefix sum + one presence
 # scatter; the sort-based unique costs O(N log N) independent of rows.
@@ -61,27 +56,28 @@ MODES = ("auto", "pallas", "xla", "off")
 # a safety margin before the vocab-shaped pass could dominate.
 PLAN_COUNT_MAX_ROWS = 2_000_000
 
-# VMEM budget for the compiled kernels (per pallas_fm: ~16MB/core, leave
-# headroom). The gather/segsum kernels keep the [U, D] row block plus the
-# [N, D] batch block live; the plan kernel keeps the [rows+1] count vector.
-_VMEM_BUDGET = 14 * 1024 * 1024
+# Scoped-VMEM limit the take kernels are compiled with. They keep the
+# [U, D] row block and the [N, D] batch block whole in VMEM, each row
+# padded to the 128-lane tile and each block double-buffered by the
+# pipeline; at the reference shape (U = N = 39,936, D = 33) that is ~82 MB,
+# which a v5e's 128 MiB VMEM takes and the 16 MiB default limit does not
+# (compiled on the chip at this setting: PERF.md, PR 21).
+_VMEM_LIMIT = 100 * 1024 * 1024
+_LANES = 128
 
 
 def supported(kernel: str, *, num_rows: int = 0, n_ids: int = 0,
               width: int = 1) -> bool:
-    """True when ``kernel`` ("plan" | "take" | "install") can run COMPILED
-    at this shape — requires a TPU backend and the kernel's working set to
-    fit VMEM. CPU/GPU backends always gate the compiled path off (the
-    interpreter is a numerics tool, not a fast path)."""
-    if pltpu is None or jax.default_backend() != "tpu":
+    """True when seam ``kernel`` ("plan" | "take" | "install") has a Pallas
+    kernel that can run COMPILED at this shape: only ``take``, on a TPU
+    backend, with ``num_rows`` gathered rows + ``n_ids`` positions of
+    ``width`` columns fitting the VMEM limit it is compiled with."""
+    if kernel not in ("plan", "take", "install"):
+        raise ValueError(f"unknown kernel {kernel!r}")
+    if kernel != "take" or jax.default_backend() != "tpu":
         return False
-    if kernel == "plan":
-        return 4 * (num_rows + 1) + 3 * 4 * n_ids <= _VMEM_BUDGET
-    if kernel == "take":
-        return 4 * width * (2 * n_ids) <= _VMEM_BUDGET
-    if kernel == "install":
-        return 4 * width * (2 * n_ids) <= _VMEM_BUDGET
-    raise ValueError(f"unknown kernel {kernel!r}")
+    lanes = -(-width // _LANES) * _LANES
+    return 2 * 4 * lanes * (num_rows + n_ids) <= _VMEM_LIMIT
 
 
 def resolve(mode: str, kernel: str, *, num_rows: int = 0, n_ids: int = 0,
@@ -89,12 +85,11 @@ def resolve(mode: str, kernel: str, *, num_rows: int = 0, n_ids: int = 0,
     """Pick the leg ("pallas" | "opt" | "ref") for one seam.
 
     ``off`` is the kill switch: the seed path everywhere, bit-for-bit.
-    ``xla`` forces the optimized XLA legs even on TPU. ``pallas`` and
-    ``auto`` take the compiled kernel where :func:`supported` allows and
-    degrade to the optimized XLA leg elsewhere — except the plan seam,
-    where tables above ``PLAN_COUNT_MAX_ROWS`` keep the sort-based
-    reference build (the vocab-shaped counting pass would scale with rows;
-    the sort does not)."""
+    ``xla`` forces the optimized XLA legs even on TPU. ``auto`` takes the
+    compiled kernel where :func:`supported` allows and the optimized XLA
+    leg elsewhere — except the plan seam, where tables above
+    ``PLAN_COUNT_MAX_ROWS`` keep the sort-based reference build (the
+    vocab-shaped counting pass would scale with rows; the sort does not)."""
     if mode not in MODES:
         raise ValueError(f"embedding_kernels must be one of {MODES}, "
                          f"got {mode!r}")
@@ -102,88 +97,15 @@ def resolve(mode: str, kernel: str, *, num_rows: int = 0, n_ids: int = 0,
         return "ref"
     if kernel == "plan" and num_rows > PLAN_COUNT_MAX_ROWS:
         return "ref"
-    if mode in ("auto", "pallas") and supported(
+    if mode == "auto" and supported(
             kernel, num_rows=num_rows, n_ids=n_ids, width=width):
         return "pallas"
     return "opt"
 
 
 # ---------------------------------------------------------------------------
-# Kernel 1: device-side plan build (unique + remap, static shapes)
+# Seam 1: device-side plan build (unique + remap, static shapes)
 # ---------------------------------------------------------------------------
-# Same counting formulation as make_plan_counting, as one kernel: presence
-# marks and the prefix sum stay in VMEM instead of round-tripping three
-# HBM-shaped intermediates through XLA op boundaries. Outputs are
-# PlanEntry-compatible: uids/inv bit-identical to jnp.unique(size=N,
-# fill_value=num_rows), plus the touched/rank writeback companions.
-
-
-def _plan_kernel(ids_ref, uids_ref, inv_ref, touched_ref, rank_ref,
-                 counts_ref):
-    # counts_ref is a [1, rows+1] work buffer (an extra kernel output — the
-    # wrapper discards it; using an output instead of pltpu scratch keeps
-    # the body identical between interpret and compiled modes).
-    n = ids_ref.shape[1]
-    rows = touched_ref.shape[1]
-    counts_ref[...] = jnp.zeros_like(counts_ref)
-
-    def mark(i, _):
-        counts_ref[0, ids_ref[0, i]] = 1
-        return 0
-
-    jax.lax.fori_loop(0, n, mark, 0)
-    csum = jnp.cumsum(counts_ref[...], axis=1)          # [1, rows+1]
-    rank = csum - counts_ref[...]                        # exclusive rank
-    touched_ref[...] = counts_ref[0, :rows].reshape(1, rows) > 0
-    # rank spans the FULL [rows+1] id space: the OOB fill id (= rows) must
-    # be remappable too (masked hashed positions carry it).
-    rank_ref[...] = rank.astype(jnp.int32)
-    # uids: compact the present row ids into their rank slot; unfilled
-    # slots keep the OOB fill id (= rows), matching unique's fill_value.
-    uids_ref[...] = jnp.full_like(uids_ref, rows)
-
-    def emit(r, _):
-        @pl.when(counts_ref[0, r] > 0)
-        def _():
-            uids_ref[0, rank_ref[0, r]] = r
-        return 0
-
-    jax.lax.fori_loop(0, rows, emit, 0)
-
-    def remap(i, _):
-        inv_ref[0, i] = rank_ref[0, ids_ref[0, i]]
-        return 0
-
-    jax.lax.fori_loop(0, n, remap, 0)
-
-
-def plan_build_pallas(ids: jax.Array, num_rows: int,
-                      mask: Optional[jax.Array] = None,
-                      interpret: bool = False) -> emb_ops.PlanEntry:
-    """Device-side plan build as ONE kernel launch. ``interpret=True`` runs
-    the identical body on CPU (tests); the compiled path is TPU-only
-    behind ``supported("plan", ...)``.
-
-    NOTE: rank[r] for rows past the last touched id equals U (one past the
-    uid slots) inside the kernel's scratch; the emitted ``rank`` output is
-    only read under ``touched`` downstream, same contract as the XLA leg.
-    """
-    flat = ids.reshape(1, -1).astype(jnp.int32)
-    n = flat.shape[1]
-    uids, inv, touched, rank, _counts = pl.pallas_call(
-        _plan_kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((1, n), jnp.int32),
-            jax.ShapeDtypeStruct((1, n), jnp.int32),
-            jax.ShapeDtypeStruct((1, num_rows), jnp.bool_),
-            jax.ShapeDtypeStruct((1, num_rows + 1), jnp.int32),
-            jax.ShapeDtypeStruct((1, num_rows + 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(flat)
-    return emb_ops.PlanEntry(
-        uids=uids[0], inv=inv[0].reshape(ids.shape), mask=mask,
-        num_rows=num_rows, touched=touched[0], rank=rank[0, :num_rows])
 
 
 def plan_build(ids: jax.Array, num_rows: int,
@@ -193,15 +115,13 @@ def plan_build(ids: jax.Array, num_rows: int,
     bit-identical uids/inv; the counting legs additionally carry the
     touched/rank select-writeback companions."""
     leg = resolve(mode, "plan", num_rows=num_rows, n_ids=ids.size)
-    if leg == "pallas":
-        return plan_build_pallas(ids, num_rows, mask)
     if leg == "opt":
         return emb_ops.make_plan_counting(ids, num_rows, mask)
     return emb_ops.make_plan(ids, num_rows, mask)
 
 
 # ---------------------------------------------------------------------------
-# Kernel 2: fused gather forward + segment-sum backward (custom VJP)
+# Seam 2: fused gather forward + segment-sum backward (custom VJP)
 # ---------------------------------------------------------------------------
 # Forward: out[p] = rows[inv[p]] for every batch position p. Backward: the
 # batch-sized segment-sum d_rows[u] = sum_{p: inv[p]=u} g[p] — the exact
@@ -213,44 +133,49 @@ def plan_build(ids: jax.Array, num_rows: int,
 # names (train.loop).
 
 
-def _take_fwd_kernel(rows_ref, inv_ref, out_ref):
-    n = inv_ref.shape[1]
+# The index vector is scalar-prefetched into SMEM: Mosaic refuses a scalar
+# read at a dynamic lane offset of a VMEM vector ("cannot statically prove
+# that index in dimension 1 is a multiple of 128"), while an SMEM scalar
+# indexing a one-row sublane slab of the VMEM block is the supported form.
 
+
+def _take_fwd_kernel(inv_ref, rows_ref, out_ref):
     def body(i, _):
-        out_ref[i, :] = rows_ref[inv_ref[0, i], :]
+        out_ref[pl.ds(i, 1), :] = rows_ref[pl.ds(inv_ref[i], 1), :]
         return 0
 
-    jax.lax.fori_loop(0, n, body, 0)
+    jax.lax.fori_loop(0, out_ref.shape[0], body, 0)
 
 
-def _take_bwd_kernel(g_ref, inv_ref, out_ref):
-    n = inv_ref.shape[1]
+def _take_bwd_kernel(inv_ref, g_ref, out_ref):
     out_ref[...] = jnp.zeros_like(out_ref)
 
     def body(i, _):
-        out_ref[inv_ref[0, i], :] += g_ref[i, :]
+        out_ref[pl.ds(inv_ref[i], 1), :] += g_ref[pl.ds(i, 1), :]
         return 0
 
-    jax.lax.fori_loop(0, n, body, 0)
+    jax.lax.fori_loop(0, g_ref.shape[0], body, 0)
 
 
-def _take_pallas_fwd(rows: jax.Array, inv2: jax.Array,
-                     interpret: bool) -> jax.Array:
-    n = inv2.shape[1]
+def _take_call(kernel, inv: jax.Array, x: jax.Array, out_rows: int,
+               interpret: bool) -> jax.Array:
+    """One whole-block launch of a take kernel: ``inv`` int32 [N] rides in
+    SMEM, ``x`` [*, D] and the [out_rows, D] result whole in VMEM."""
+    d = x.shape[1]
     return pl.pallas_call(
-        _take_fwd_kernel,
-        out_shape=jax.ShapeDtypeStruct((n, rows.shape[1]), rows.dtype),
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[pl.BlockSpec(x.shape, lambda i, inv: (0, 0))],
+            out_specs=pl.BlockSpec((out_rows, d), lambda i, inv: (0, 0))),
+        # Inside shard_map the result varies over whatever mesh axes the
+        # operands do; pallas_call cannot infer that and check_vma asks.
+        out_shape=jax.ShapeDtypeStruct(
+            (out_rows, d), x.dtype,
+            vma=jax.typeof(inv).vma | jax.typeof(x).vma),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(rows, inv2)
-
-
-def _take_pallas_bwd(g: jax.Array, inv2: jax.Array, u: int,
-                     interpret: bool) -> jax.Array:
-    return pl.pallas_call(
-        _take_bwd_kernel,
-        out_shape=jax.ShapeDtypeStruct((u, g.shape[1]), g.dtype),
-        interpret=interpret,
-    )(g, inv2)
+    )(inv, x)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -258,8 +183,8 @@ def take_rows_pallas(rows: jax.Array, inv: jax.Array,
                      interpret: bool = False) -> jax.Array:
     """rows[inv] with a hand-written segment-sum VJP, both as Pallas
     kernels. rows: [U, D]; inv: int32 [...] -> out [..., D]."""
-    inv2 = inv.reshape(1, -1).astype(jnp.int32)
-    out = _take_pallas_fwd(rows, inv2, interpret)
+    flat = inv.reshape(-1).astype(jnp.int32)
+    out = _take_call(_take_fwd_kernel, flat, rows, flat.shape[0], interpret)
     return out.reshape(inv.shape + rows.shape[1:])
 
 
@@ -270,8 +195,8 @@ def _take_rows_fwd(rows, inv, interpret):
 def _take_rows_bwd(interpret, res, g):
     inv, u = res
     g2 = g.reshape(-1, g.shape[-1])
-    inv2 = inv.reshape(1, -1).astype(jnp.int32)
-    return _take_pallas_bwd(g2, inv2, u, interpret), None
+    flat = inv.reshape(-1).astype(jnp.int32)
+    return _take_call(_take_bwd_kernel, flat, g2, u, interpret), None
 
 
 take_rows_pallas.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -282,65 +207,22 @@ def take_rows(rows: jax.Array, inv: jax.Array, *,
     """Positionwise view of gathered rows, leg-selected. The XLA legs are
     ``jnp.take`` — its AD transpose IS the batch-sized segment-sum — so
     every leg produces bit-identical values and cotangents."""
-    leg = resolve(mode, "take", n_ids=inv.size,
-                  width=int(rows.shape[-1]) if rows.ndim > 1 else 1)
+    leg = "opt" if rows.ndim != 2 else resolve(
+        mode, "take", num_rows=int(rows.shape[0]), n_ids=inv.size,
+        width=int(rows.shape[1]))
     if leg == "pallas":
         return take_rows_pallas(rows, inv)
     return jnp.take(rows, inv, axis=0)
 
 
 # ---------------------------------------------------------------------------
-# Kernel 3: fused install/evict scatter (tiered cache transaction)
+# Seam 3: fused install/evict scatter (tiered cache transaction)
 # ---------------------------------------------------------------------------
-# One launch installs a transaction's weight rows AND the three lazy-Adam
+# One dispatch installs a transaction's weight rows AND the three lazy-Adam
 # companions (m, v, tau) at their hot-cache slots; OOB slot ids (the pow2
-# padding) are dropped. The XLA "opt" leg fuses the same four scatters into
-# one jit program (one dispatch instead of four); "ref" is the seed
-# per-array ``_jit_install``.
-
-
-def _install_kernel(w_ref, m_ref, v_ref, tau_ref, slots_ref,
-                    wv_ref, mv_ref, vv_ref, tv_ref,
-                    ow_ref, om_ref, ov_ref, otau_ref):
-    rows = w_ref.shape[0]
-    s = slots_ref.shape[1]
-    ow_ref[...] = w_ref[...]
-    om_ref[...] = m_ref[...]
-    ov_ref[...] = v_ref[...]
-    otau_ref[...] = tau_ref[...]
-
-    def body(i, _):
-        slot = slots_ref[0, i]
-
-        @pl.when(slot < rows)
-        def _():
-            ow_ref[slot, :] = wv_ref[i, :]
-            om_ref[slot, :] = mv_ref[i, :]
-            ov_ref[slot, :] = vv_ref[i, :]
-            otau_ref[0, slot] = tv_ref[0, i]
-        return 0
-
-    jax.lax.fori_loop(0, s, body, 0)
-
-
-def install_pallas(w, m, v, tau, slots, wv, mv, vv, tv,
-                   interpret: bool = False):
-    """One cache transaction as ONE kernel: returns (w, m, v, tau) with
-    ``slots`` rows replaced by the fetched values; OOB slots dropped."""
-    slots2 = slots.reshape(1, -1).astype(jnp.int32)
-    tau2 = tau.reshape(1, -1)
-    tv2 = tv.reshape(1, -1)
-    ow, om, ov, otau = pl.pallas_call(
-        _install_kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct(w.shape, w.dtype),
-            jax.ShapeDtypeStruct(m.shape, m.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-            jax.ShapeDtypeStruct(tau2.shape, tau.dtype),
-        ],
-        interpret=interpret,
-    )(w, m, v, tau2, slots2, wv, mv, vv, tv2)
-    return ow, om, ov, otau.reshape(tau.shape)
+# padding) are dropped. The "opt" leg fuses the four scatters into one jit
+# program (one dispatch instead of four); "ref" is the seed per-array
+# ``_jit_install``.
 
 
 @functools.partial(jax.jit, donate_argnums=())
@@ -358,8 +240,6 @@ def install_rows(w, m, v, tau, slots, wv, mv, vv, tv, *, mode: str = "auto"):
     rows get the same values, OOB (padding) slots are dropped."""
     leg = resolve(mode, "install", n_ids=int(slots.shape[0]),
                   width=int(w.shape[-1]) if w.ndim > 1 else 1)
-    if leg == "pallas":
-        return install_pallas(w, m, v, tau, slots, wv, mv, vv, tv)
     if leg == "opt":
         return _install_fused_xla(w, m, v, tau, slots, wv, mv, vv, tv)
     return None  # ref: caller keeps its per-array scatter path

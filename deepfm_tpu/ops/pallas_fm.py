@@ -28,12 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu import fails on some non-TPU builds; interpret mode never needs it
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 # VMEM budget for picking the batch-tile height. The backward kernel keeps
 # ~4 [Bt, F, K] f32 buffers effectively live (inputs/outputs stream per grid
@@ -60,10 +55,11 @@ _BLOCK_FALLBACK = 128
 
 
 def supported(field_size: int = 39, embedding_size: int = 32) -> bool:
-    """True when the compiled kernels can run at this (F, K) shape —
-    requires a TPU backend and a batch tile that fits VMEM (larger shapes
-    fall back to the XLA formulation rather than failing to compile)."""
-    return (pltpu is not None and jax.default_backend() == "tpu"
+    """True when the compiled kernels can run at this (F, K) shape: a TPU
+    backend and a batch tile that fits VMEM. Where it is False the caller
+    takes the XLA formulation (``ops.fm``) — a choice made from the
+    platform and the shape, both of which the step's program records."""
+    return (jax.default_backend() == "tpu"
             and _pick_block_b(field_size, embedding_size) > 0)
 
 
@@ -99,6 +95,13 @@ def _bwd_kernel(g_ref, w_ref, vals_ref, xv_ref, dw_ref, dvals_ref, dxv_ref):
     dxv_ref[:] = ((s[:, None, :] - xv) * g[:, :, None]).astype(dxv_ref.dtype)
 
 
+def _vma(*arrays) -> frozenset:
+    """Mesh axes the kernel's outputs vary over inside ``shard_map``: the
+    union of its inputs' (empty outside one). ``pallas_call`` cannot infer
+    it, and ``check_vma`` refuses an output that does not say."""
+    return frozenset().union(*(jax.typeof(a).vma for a in arrays))
+
+
 def _pad_b(x: jnp.ndarray, b_pad: int) -> jnp.ndarray:
     if b_pad == 0:
         return x
@@ -113,14 +116,15 @@ def _run_fwd(w, vals, xv, interpret: bool) -> jnp.ndarray:
     b_pad = (-b) % bt
     w, vals, xv = _pad_b(w, b_pad), _pad_b(vals, b_pad), _pad_b(xv, b_pad)
     bp = b + b_pad
-    ms = None if interpret else _VMEM
+    ms = None if interpret else pltpu.VMEM
     kw = {} if ms is None else {"memory_space": ms}
     out = pl.pallas_call(
         _fwd_kernel,
         grid=(bp // bt,),
         in_specs=_block_specs(bt, f, k, ms),
         out_specs=pl.BlockSpec((bt, 1), lambda i: (i, 0), **kw),
-        out_shape=jax.ShapeDtypeStruct((bp, 1), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bp, 1), jnp.float32,
+                                       vma=_vma(w, vals, xv)),
         interpret=interpret,
     )(w, vals, xv)
     return out[:b, 0]
@@ -134,9 +138,10 @@ def _run_bwd(g, w, vals, xv, interpret: bool):
     g2 = _pad_b(g.reshape(b, 1), b_pad)
     w, vals, xv = _pad_b(w, b_pad), _pad_b(vals, b_pad), _pad_b(xv, b_pad)
     bp = b + b_pad
-    ms = None if interpret else _VMEM
+    ms = None if interpret else pltpu.VMEM
     kw = {} if ms is None else {"memory_space": ms}
     g_spec = pl.BlockSpec((bt, 1), lambda i: (i, 0), **kw)
+    vma = _vma(g2, w, vals, xv)
     dw, dvals, dxv = pl.pallas_call(
         _bwd_kernel,
         grid=(bp // bt,),
@@ -149,9 +154,9 @@ def _run_bwd(g, w, vals, xv, interpret: bool):
         out_shape=[
             # Cotangent dtypes mirror the primals (bf16 in -> bf16 grads),
             # written directly by the kernel — no f32 round trip in HBM.
-            jax.ShapeDtypeStruct((bp, f), w.dtype),
-            jax.ShapeDtypeStruct((bp, f), vals.dtype),
-            jax.ShapeDtypeStruct((bp, f, k), xv.dtype),
+            jax.ShapeDtypeStruct((bp, f), w.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bp, f), vals.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bp, f, k), xv.dtype, vma=vma),
         ],
         interpret=interpret,
     )(g2, w, vals, xv)

@@ -44,6 +44,18 @@ def initialize(cfg: Config) -> None:
     else:
         raise ValueError(f"unknown dist_mode {cfg.dist_mode}")
     _INITIALIZED = True
+    if cfg.dist_mode == 1 and jax.process_count() != cfg.num_processes:
+        # The coordinator only rendezvouses processes; which devices form
+        # one topology is the accelerator runtime's say. Workers that each
+        # came up as their own 1-process world would otherwise all train as
+        # "chief" into the same model_dir.
+        raise RuntimeError(
+            f"{cfg.num_processes} processes met at {cfg.coordinator_address} "
+            f"but the backend came up as {jax.process_count()} process(es) "
+            f"with {jax.device_count()} device(s): the workers did not form "
+            "one device topology. On one multi-chip host run ONE process "
+            "over all its chips (--mesh_data/--mesh_model); pinning one chip "
+            "per worker (deepfm_tpu.fanout) does not join them into a mesh.")
 
 
 def process_index() -> int:

@@ -106,6 +106,13 @@ def cascade_extra_export(cfg: Config, tower_params, index: CandidateIndex, *,
     return hook
 
 
+class CannotFuse(TypeError):
+    """This artifact's cascade has no single fused program (structural: a
+    multitask ranker answers with a dict of heads). The one condition under
+    which the engine serves staged instead; a compile or device error in
+    the fused program is not this, and propagates."""
+
+
 class CascadeModel:
     """ONE loaded artifact version: ranker + towers + index, swap-atomic.
 
@@ -213,7 +220,7 @@ class CascadeModel:
             probs = raw(ids.reshape(b * n, -1).astype(jnp.int32),
                         vals.reshape(b * n, -1).astype(jnp.float32))
             if isinstance(probs, dict):
-                raise TypeError(
+                raise CannotFuse(
                     "fused cascade needs a single-output ranker; "
                     "multitask artifacts use the staged path")
             probs = jnp.reshape(probs, (b, n))
@@ -439,7 +446,7 @@ class CascadeEngine:
                     model, hist_ids, hist_mask, feat_ids[None],
                     feat_vals[None], k)
                 return ids_k[0], probs_k[0]
-            except Exception:  # noqa: BLE001 — structural; staged fallback
+            except CannotFuse:
                 model.fused_failed = True
                 trace_lib.instant("serve.cascade_fused", event="fallback")
         retrieve_k = self.retrieve_k if rung == 0 \
@@ -539,7 +546,7 @@ class CascadeEngine:
             try:
                 return self._recommend_fused(model, hist_ids, hist_mask,
                                              feat_ids, feat_vals, k)
-            except Exception:  # noqa: BLE001 — structural; staged fallback
+            except CannotFuse:
                 model.fused_failed = True
                 trace_lib.instant("serve.cascade_fused", event="fallback")
         out_ids, out_ps = [], []

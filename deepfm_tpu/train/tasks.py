@@ -1220,6 +1220,7 @@ def _task_train(trainer: Trainer, cfg: Config) -> Dict[str, float]:
         out = fileio.join(cfg.servable_model_dir, str(int(state.step)))
         export_lib.export_serving(
             trainer.model, _servable_state(trainer, state), cfg, out)
+        result["saved_model"] = export_lib.saved_model_status(out)
     result["steps"] = float(int(state.step))
     result["read_retries"] = float(health_totals.get("read_retries", 0))
     result["bad_records"] = float(health_totals.get("bad_records", 0))
@@ -1361,8 +1362,10 @@ def _task_export(trainer: Trainer, cfg: Config) -> Dict[str, float]:
     if not cfg.servable_model_dir:
         raise ValueError("export task requires servable_model_dir")
     state = _restore_or_init(trainer, cfg, require=True)
+    result: Dict[str, float] = {"step": float(int(state.step))}
     if bootstrap.is_chief():
         out = fileio.join(cfg.servable_model_dir, str(int(state.step)))
         export_lib.export_serving(
             trainer.model, _servable_state(trainer, state), cfg, out)
-    return {"step": float(int(state.step))}
+        result["saved_model"] = export_lib.saved_model_status(out)
+    return result

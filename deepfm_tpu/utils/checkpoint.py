@@ -24,6 +24,17 @@ from . import logging as ulog
 from . import retry as retry_lib
 
 
+# Orbax (0.11) numbers each save from a process-wide counter and reads the
+# "current" number back several times while the save is being set up. Two
+# saves set up from different threads at once — the trainer's checkpoint and
+# the publisher thread's artifact, whose cadences coincide — can therefore
+# read each other's number, and each then waits out a five-minute timeout for
+# a directory-creation signal that was sent under the other's. Everything the
+# background commit threads need is captured during set-up, so starting saves
+# one at a time is enough; the asynchronous writes still overlap.
+ORBAX_SAVE_SETUP = threading.Lock()
+
+
 class AsyncSaveExecutor:
     """One background thread for artifact writes off the training hot path.
 
@@ -115,8 +126,9 @@ class CheckpointManager:
     def _do_save(self, step: int, state: Any, force: bool) -> bool:
         """The actual Orbax write. Seam for fault injection (FlakyFS
         patches this) — keep all failure handling in save() above it."""
-        return self._mgr.save(step, args=ocp.args.StandardSave(state),
-                              force=force)
+        with ORBAX_SAVE_SETUP:
+            return self._mgr.save(step, args=ocp.args.StandardSave(state),
+                                  force=force)
 
     def save(self, step: int, state: Any, *, force: bool = False) -> bool:
         # Dedup against steps saved THIS session too: async saves may not yet

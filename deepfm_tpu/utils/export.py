@@ -6,7 +6,8 @@ The reference exports a SavedModel with a raw serving signature
 
 Here the servable artifact is a directory containing:
   * ``serving_fn.stablehlo`` — the predict function serialized with
-    ``jax.export`` (StableHLO, batch-dim symbolic, lowered for CPU+TPU)
+    ``jax.export`` (StableHLO, batch-dim symbolic, lowered for CPU+TPU in
+    one module, so an artifact written on either platform serves on both)
   * ``params.ckpt/`` — the inference parameters (Orbax standard format)
   * ``model_config.json`` — the model hyperparameters + signature schema
 
@@ -30,12 +31,16 @@ from jax import export as jax_export
 
 from ..config import Config
 from ..data import fileio
+from . import checkpoint as ckpt_lib
 from . import logging as ulog
 
 _SERVING_FILE = "serving_fn.stablehlo"
 _PARAMS_DIR = "params.ckpt"
 _CONFIG_FILE = "model_config.json"
 _SAVEDMODEL_DIR = "saved_model"
+
+# Platforms every artifact is lowered for, whichever backend writes it.
+SERVING_PLATFORMS = ("cpu", "tpu")
 
 # Written LAST by export_serving: its presence certifies every other file in
 # the artifact dir is complete. load_serving refuses dirs without it — a
@@ -51,7 +56,8 @@ LATEST_FILE = "LATEST"
 
 class ArtifactIncomplete(RuntimeError):
     """A servable artifact dir is missing its completion marker (export
-    crashed mid-write, or the caller raced an in-flight publish)."""
+    crashed mid-write, or the caller raced an in-flight publish) or the
+    serialized serving function the marker certifies."""
 
 
 def _task_names(model) -> Tuple[str, ...]:
@@ -119,8 +125,9 @@ def export_serving(model, state, cfg: Config, out_dir: str) -> str:
         lambda x: np.asarray(jax.device_get(x)), state.model_state)
     ckptr = ocp.StandardCheckpointer()
     params_path = fileio.join(fileio.normalize_dir(out_dir), _PARAMS_DIR)
-    ckptr.save(params_path, {"params": params, "model_state": model_state},
-               force=True)
+    with ckpt_lib.ORBAX_SAVE_SETUP:  # see its comment: publisher thread here
+        ckptr.save(params_path,
+                   {"params": params, "model_state": model_state}, force=True)
     ckptr.wait_until_finished()
 
     # 2. Serialized serving function with symbolic batch dim. History-aware
@@ -134,30 +141,24 @@ def export_serving(model, state, cfg: Config, out_dir: str) -> str:
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
     mstate_spec = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), model_state)
-    serialized = None
-    try:
-        exported = jax_export.export(
-            jax.jit(serve), platforms=("cpu", "tpu"))(
-                params_spec, mstate_spec, ids_spec, vals_spec)
-        serialized = exported.serialize()
-    except Exception as e:  # pragma: no cover - platform-specific lowering
-        ulog.warning(f"stablehlo export skipped ({e}); params-only artifact")
-    if serialized is not None:
-        # Outside the guard: an I/O failure here is a real error (retryable
-        # store hiccup, bad permissions), not a lowering limitation, and must
-        # surface instead of silently degrading to a params-only artifact.
-        with fileio.open_stream(fileio.join(out_dir, _SERVING_FILE), "wb") as f:
-            f.write(serialized)
+    # Lowered for both platforms whichever one exports: trainers publish on
+    # the accelerator, and tests, drills and CPU frontends load the same
+    # artifact. A lowering failure propagates — a params-only artifact would
+    # serve through a different program than the one that was exported.
+    exported = jax_export.export(
+        jax.jit(serve), platforms=SERVING_PLATFORMS)(
+            params_spec, mstate_spec, ids_spec, vals_spec)
+    with fileio.open_stream(fileio.join(out_dir, _SERVING_FILE), "wb") as f:
+        f.write(exported.serialize())
 
     # 3. TF SavedModel (optional): the reference's actual serving artifact
     # (``export_savedmodel`` with the raw feat_ids/feat_vals signature,
     # ``1-ps-cpu/...py:458-467``) — a user's existing TF-Serving deployment
-    # can load this directly. Emitted via jax2tf when TF is importable;
-    # lowering failures degrade to the StableHLO+params artifact with a
-    # warning, but write failures surface (same policy as the StableHLO
-    # file above).
-    _export_tf_savedmodel(serve, params, model_state, cfg, out_dir,
-                          in_cols=in_cols)
+    # can load this directly. Emitted via jax2tf when TF is importable, for
+    # the same platform set as the StableHLO file; what became of it is
+    # recorded in the metadata below (and from there in the task result).
+    saved_model = _export_tf_savedmodel(serve, params, model_state, cfg,
+                                        out_dir, in_cols=in_cols)
 
     # 4. Signature/config metadata. Single-task keeps the historical "prob"
     # output name; multitask artifacts advertise one output per task name.
@@ -176,6 +177,7 @@ def export_serving(model, state, cfg: Config, out_dir: str) -> str:
         "history_len": _serving_hist_len(model, cfg),
         "config": cfg.to_dict(),
         "step": int(jax.device_get(state.step)),
+        "saved_model": saved_model,
     }
     with fileio.open_stream(fileio.join(out_dir, _CONFIG_FILE), "w") as f:
         json.dump(meta, f, indent=2)
@@ -190,8 +192,9 @@ def export_serving(model, state, cfg: Config, out_dir: str) -> str:
 
 def _export_tf_savedmodel(serve: Callable, params, model_state, cfg: Config,
                           out_dir: str,
-                          in_cols: Optional[int] = None) -> None:
-    """Write ``<out_dir>/saved_model`` loadable by TF Serving / tf.saved_model.
+                          in_cols: Optional[int] = None) -> str:
+    """Write ``<out_dir>/saved_model`` loadable by TF Serving / tf.saved_model;
+    returns what happened (``"written"`` or ``"skipped: <why>"``).
 
     The serving signature mirrors the reference exactly: inputs
     ``feat_ids`` int64[None, F] / ``feat_vals`` float32[None, F] (int64 per
@@ -201,61 +204,65 @@ def _export_tf_savedmodel(serve: Callable, params, model_state, cfg: Config,
     Weights are held as ``tf.Variable``s on the module (the jax2tf
     deployment pattern), NOT closed over as Python values — closure would
     freeze the embedding table into GraphDef constants and hit the 2GB
-    proto limit at CTR scale. Lowering/trace failures degrade with a
-    warning; ``tf.saved_model.save`` I/O failures propagate.
+    proto limit at CTR scale. The function is lowered for
+    ``SERVING_PLATFORMS``, not for whatever backend exports it: a sidecar
+    written on a TPU host must load in a CPU TF-Serving. A lowering or
+    write failure propagates; the sidecar is skipped only where it cannot
+    exist — the seam is set, TensorFlow is not installed, or TF's
+    filesystem layer does not know the destination's scheme.
     """
     if os.environ.get("DEEPFM_TPU_SKIP_TF_EXPORT", ""):
         # Drill/test seam (docs/TUNING.md seam table): the TF SavedModel
         # sidecar costs ~10s per publish and the jax-native serving runtime
         # never reads it — subprocess drills set this to keep the publish
         # cadence realistic. Production publishes leave it unset.
-        return
+        return "skipped: DEEPFM_TPU_SKIP_TF_EXPORT is set"
     try:
         import tensorflow as tf  # noqa: PLC0415 (lazy, heavy)
         from jax.experimental import jax2tf  # noqa: PLC0415
     except ImportError as e:  # pragma: no cover - env without TF
-        ulog.warning(f"TF SavedModel export skipped (no tensorflow: {e})")
-        return
-    try:
-        variables = tf.nest.map_structure(
-            tf.Variable, (params, model_state))
-        tf_fn = jax2tf.convert(
-            lambda pv, ids, vals: serve(pv[0], pv[1], ids, vals),
-            polymorphic_shapes=[None, "(b, _)", "(b, _)"],
-            with_gradient=False)
-        module = tf.Module()
-        module.model_variables = variables  # tracked -> variables shard
-        def _sig_out(feat_ids, feat_vals):
-            out = tf_fn(variables, tf.cast(feat_ids, tf.int32), feat_vals)
-            # Multitask serve fns already return a {task: probs} dict;
-            # single-task keeps the reference's "prob" key.
-            return out if isinstance(out, dict) else {"prob": out}
+        return f"skipped: tensorflow not importable ({e})"
+    variables = tf.nest.map_structure(tf.Variable, (params, model_state))
+    tf_fn = jax2tf.convert(
+        lambda pv, ids, vals: serve(pv[0], pv[1], ids, vals),
+        polymorphic_shapes=[None, "(b, _)", "(b, _)"],
+        with_gradient=False,
+        native_serialization_platforms=SERVING_PLATFORMS)
+    module = tf.Module()
+    module.model_variables = variables  # tracked -> variables shard
 
-        cols = in_cols if in_cols is not None else cfg.field_size
-        module.f = tf.function(
-            _sig_out,
-            input_signature=[
-                tf.TensorSpec([None, cols], tf.int64,
-                              name="feat_ids"),
-                tf.TensorSpec([None, cols], tf.float32,
-                              name="feat_vals"),
-            ])
-        # Trace now: lowering errors belong to this guard, not to save().
-        concrete = module.f.get_concrete_function()
-    except Exception as e:  # pragma: no cover - TF-version specific
-        ulog.warning(f"TF SavedModel export skipped ({e})")
-        return
+    def _sig_out(feat_ids, feat_vals):
+        out = tf_fn(variables, tf.cast(feat_ids, tf.int32), feat_vals)
+        # Multitask serve fns already return a {task: probs} dict;
+        # single-task keeps the reference's "prob" key.
+        return out if isinstance(out, dict) else {"prob": out}
+
+    cols = in_cols if in_cols is not None else cfg.field_size
+    module.f = tf.function(
+        _sig_out,
+        input_signature=[
+            tf.TensorSpec([None, cols], tf.int64, name="feat_ids"),
+            tf.TensorSpec([None, cols], tf.float32, name="feat_vals"),
+        ])
+    concrete = module.f.get_concrete_function()
     sm_dir = fileio.join(out_dir, _SAVEDMODEL_DIR)
     try:
         tf.saved_model.save(module, sm_dir,
                             signatures={"serving_default": concrete})
     except tf.errors.UnimplementedError as e:
         # Storage scheme TF's filesystem layer doesn't support: a capability
-        # gap, not a transient failure — degrade like a lowering failure.
-        # (Real I/O errors — permissions, 5xx — are other types and raise.)
-        ulog.warning(f"TF SavedModel export skipped (unsupported scheme: {e})")
-        return
+        # gap of the destination, not of the program. (Real I/O errors —
+        # permissions, 5xx — are other types and raise.)
+        return f"skipped: TF cannot write to this storage scheme ({e})"
     ulog.info(f"wrote TF SavedModel to {sm_dir}")
+    return "written"
+
+
+def saved_model_status(artifact_dir: str) -> str:
+    """What ``export_serving`` recorded about the TF SavedModel sidecar of
+    ``artifact_dir`` (``"written"`` or ``"skipped: <why>"``)."""
+    with fileio.open_stream(fileio.join(artifact_dir, _CONFIG_FILE), "r") as f:
+        return json.load(f)["saved_model"]
 
 
 # --------------------------------------------------------------------------
@@ -363,9 +370,10 @@ def load_serving(artifact_dir: str, *,
     programs ever compile (the serving engine's shape policy).
 
     Raises :class:`ArtifactIncomplete` when the dir lacks its completion
-    marker — the dir is mid-write, or an export crashed into it. Callers
-    that poll (``watch_latest``) treat this as "try again later"; everything
-    else should treat it as a corrupt deployment.
+    marker — the dir is mid-write, or an export crashed into it — or its
+    serialized serving function. Callers that poll (``watch_latest``) treat
+    this as "try again later"; everything else should treat it as a corrupt
+    deployment.
     """
     if not fileio.exists(fileio.join(artifact_dir, COMPLETE_MARKER)):
         raise ArtifactIncomplete(
@@ -373,46 +381,37 @@ def load_serving(artifact_dir: str, *,
             "is incomplete (crashed or in-flight export); refusing to load")
     with fileio.open_stream(fileio.join(artifact_dir, _CONFIG_FILE), "r") as f:
         meta = json.load(f)
-    cfg = Config.from_dict(meta["config"])
     ckptr = ocp.StandardCheckpointer()
     restored = ckptr.restore(
         fileio.join(fileio.normalize_dir(artifact_dir), _PARAMS_DIR))
     params, model_state = restored["params"], restored["model_state"]
 
     hlo_path = fileio.join(artifact_dir, _SERVING_FILE)
-    if fileio.exists(hlo_path):
-        with fileio.open_stream(hlo_path, "rb") as f:
-            exported = jax_export.deserialize(f.read())
+    if not fileio.exists(hlo_path):
+        # export_serving writes the program or raises, so a marker without
+        # it is a damaged artifact. Rebuilding a predict function from the
+        # config would serve through a program nobody exported.
+        raise ArtifactIncomplete(
+            f"{artifact_dir} has no {_SERVING_FILE}; refusing to serve a "
+            "program rebuilt from its config")
+    with fileio.open_stream(hlo_path, "rb") as f:
+        exported = jax_export.deserialize(f.read())
 
-        def serve(feat_ids: np.ndarray, feat_vals: np.ndarray) -> np.ndarray:
-            out = exported.call(
-                params, model_state, feat_ids.astype(np.int32),
-                feat_vals.astype(np.float32))
-            if isinstance(out, dict):  # multitask: {task: probs}
-                return {k: np.asarray(v) for k, v in out.items()}
-            return np.asarray(out)
+    def serve(feat_ids: np.ndarray, feat_vals: np.ndarray) -> np.ndarray:
+        out = exported.call(
+            params, model_state, feat_ids.astype(np.int32),
+            feat_vals.astype(np.float32))
+        if isinstance(out, dict):  # multitask: {task: probs}
+            return {k: np.asarray(v) for k, v in out.items()}
+        return np.asarray(out)
 
-        # Traceable predict for callers that fuse the ranker into a larger
-        # jitted program (the cascade fast path): ``exported.call`` is
-        # jax-traceable, so this composes under an outer ``jax.jit``.
-        # Inputs must already be int32/float32 tracers of a bucket shape.
-        def raw_call(feat_ids, feat_vals):
-            return exported.call(params, model_state, feat_ids, feat_vals)
-    else:
-        # Fallback: rebuild from config (params-only artifact).
-        from ..models import get_model
-        model = get_model(cfg)
-        fn_raw = _serving_fn(model, cfg)
-        fn = jax.jit(fn_raw)
+    # Traceable predict for callers that fuse the ranker into a larger
+    # jitted program (the cascade fast path): ``exported.call`` is
+    # jax-traceable, so this composes under an outer ``jax.jit``.
+    # Inputs must already be int32/float32 tracers of a bucket shape.
+    def raw_call(feat_ids, feat_vals):
+        return exported.call(params, model_state, feat_ids, feat_vals)
 
-        def serve(feat_ids: np.ndarray, feat_vals: np.ndarray) -> np.ndarray:
-            out = fn(params, model_state, feat_ids, feat_vals)
-            if isinstance(out, dict):
-                return {k: np.asarray(v) for k, v in out.items()}
-            return np.asarray(out)
-
-        def raw_call(feat_ids, feat_vals):
-            return fn_raw(params, model_state, feat_ids, feat_vals)
     # Input width from the signature metadata: what a pre-warm caller (the
     # hot-swap watcher) needs to drive every bucket shape before the swap.
     in_cols = int(meta["signature"]["inputs"]["feat_ids"][1])

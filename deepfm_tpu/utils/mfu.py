@@ -1,34 +1,16 @@
-"""MFU (model FLOPs utilization) with an explicit basis label.
+"""MFU (model FLOPs utilization) against a published device peak.
 
-An MFU number is only meaningful relative to the peak it is divided by,
-and that peak comes from different places depending on where the bench
-runs:
-
-- On a recognized TPU, the divisor is the chip's dense bf16 peak from the
-  public spec sheet: basis ``measured-device-peak``.
-- On the CPU backend there is no spec-sheet peak. Rather than silently
-  emitting ``null`` (which readers mistook for "not applicable" instead
-  of "unknown"), we divide by a labeled nominal host estimate: basis
-  ``nominal-estimate``. The number is order-of-magnitude only — its job
-  is to show the workload is nowhere near a FLOP wall, not to rank
-  hosts.
-- On an unrecognized accelerator the honest answer is no number at all:
-  basis ``unavailable`` with a null MFU.
-
-Every MFU a bench emits must carry its basis in-band (see BASELINE.md):
-a consumer that averages a measured-device-peak MFU with a
-nominal-estimate MFU gets garbage, and the label is what lets it refuse.
+An MFU is only meaningful relative to the peak it is divided by, and a peak
+exists only for a device whose spec sheet gives one. For a recognized TPU
+that is the chip's dense bf16 peak. For anything else — the CPU backend, an
+accelerator not in the table — there is no number: a host has no spec-sheet
+peak, and an MFU against a made-up one reads as a device metric that nobody
+measured.
 """
-from typing import Optional, Tuple
-
-# Basis labels, stamped next to every emitted MFU value.
-BASIS_MEASURED = "measured-device-peak"
-BASIS_NOMINAL = "nominal-estimate"
-BASIS_UNAVAILABLE = "unavailable"
+from typing import Optional
 
 # Dense bf16 peak FLOP/s per chip by device_kind (public spec sheets).
-# Matched by substring against jax's device_kind; unknown kinds yield
-# basis "unavailable" rather than a wrong number.
+# Matched by substring against jax's device_kind.
 PEAK_FLOPS_BF16 = {
     "v6e": 918e12, "v6 lite": 918e12,
     "v5p": 459e12,
@@ -38,42 +20,27 @@ PEAK_FLOPS_BF16 = {
     "v2": 45e12,
 }
 
-# Nominal single-host CPU peak for the labeled CPU estimate: a few AVX2
-# cores' worth of fp32 FMA (~1e11 FLOP/s). Deliberately coarse — the
-# basis label marks every number derived from it as an estimate.
-NOMINAL_CPU_PEAK_FLOPS = 1e11
+
+def peak_flops(device_kind: str) -> Optional[float]:
+    """Dense bf16 peak FLOP/s of one chip of ``device_kind`` (as
+    ``jax.Device.device_kind`` reports it), or None when it is not a TPU
+    with a spec-sheet entry."""
+    low = device_kind.lower()
+    if "tpu" not in low:
+        return None
+    for key, peak in PEAK_FLOPS_BF16.items():
+        if key in low:
+            return peak
+    return None
 
 
-def device_peak_flops() -> Tuple[Optional[float], str, str]:
-    """(peak_flops_or_None, device_kind, basis) for the first device.
-
-    TPUs with a spec-sheet entry get (peak, kind, BASIS_MEASURED); the
-    CPU backend gets the labeled nominal estimate; anything else gets
-    (None, kind, BASIS_UNAVAILABLE).
-    """
-    import jax
-    dev = jax.devices()[0]
-    kind = dev.device_kind
-    low = kind.lower()
-    if "tpu" in low:
-        for key, peak in PEAK_FLOPS_BF16.items():
-            if key in low:
-                return peak, kind, BASIS_MEASURED
-        return None, kind, BASIS_UNAVAILABLE
-    if dev.platform == "cpu":
-        return NOMINAL_CPU_PEAK_FLOPS, kind, BASIS_NOMINAL
-    return None, kind, BASIS_UNAVAILABLE
-
-
-def mfu_pct(flops_per_example: float,
-            examples_per_sec_per_chip: float) -> Tuple[Optional[float], str, str]:
-    """(mfu_pct_or_None, basis, device_kind) for an achieved throughput.
-
-    Returns a percentage against the first device's peak; None (with
-    basis ``unavailable``) when no peak — measured or nominal — exists.
-    """
-    peak, kind, basis = device_peak_flops()
+def mfu_pct(flops_per_example: float, examples_per_sec_per_chip: float,
+            device_kind: str) -> Optional[float]:
+    """Achieved model FLOP/s over the chip's peak, in percent — or None
+    when ``device_kind`` has no published peak. The caller passes the kind
+    of the device that produced the throughput (a parent process reporting
+    a child's number must not look at its own devices)."""
+    peak = peak_flops(device_kind)
     if peak is None:
-        return None, basis, kind
-    pct = 100.0 * flops_per_example * examples_per_sec_per_chip / peak
-    return round(pct, 4), basis, kind
+        return None
+    return round(100.0 * flops_per_example * examples_per_sec_per_chip / peak, 4)

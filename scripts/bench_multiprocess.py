@@ -5,19 +5,25 @@ For each device count N this script runs the reference-shaped trainer
 twice per trial — ``--staging_buffers 1`` (each dispatch waits for its
 own host->device transfer) vs ``--staging_buffers 2`` (dispatch k+1's
 transfer overlaps dispatch k's compute) — interleaved A/B so host
-weather hits both variants equally, best-of-N wins (same methodology as
-bench.py / BASELINE.md). Each row of the emitted curve carries:
+noise hits both variants equally, best-of-N wins (same methodology as
+bench.py). The parent process never touches JAX — a parent that has
+initialised a backend holds the chip its children need — so every device
+fact in the output is what the CHILD that produced the number reported in
+its result line (``deepfm_tpu.launch.device_report``). Each row of the
+emitted curve carries:
 
 - ``examples_per_sec`` (double-buffered) and ``serialized_eps``
   (single-buffered), plus their ratio ``overlap_speedup`` and the
   trainer's measured ``overlap_fraction`` (transfer time hidden behind
   device compute / total transfer time);
-- ``mfu_pct`` with an in-band ``mfu_basis`` label
-  (measured-device-peak | nominal-estimate | unavailable — see
-  deepfm_tpu/utils/mfu.py and BASELINE.md);
-- ``topology_kind``: ``real-devices`` when N real accelerator chips ran
-  the mesh, ``virtual-mesh-timeslice`` when N virtual XLA CPU devices
-  time-sliced this host's core(s);
+- ``mfu_pct`` against the published peak of the child's ``device_kind``
+  (deepfm_tpu/utils/mfu.py) — null where there is none, the CPU backend
+  included;
+- ``device`` and ``topology_kind`` from the child's own report:
+  ``real-devices`` when N accelerator chips ran the mesh,
+  ``virtual-mesh-timeslice`` when N virtual XLA CPU devices time-sliced
+  this host's core(s) — which is what this script's children are, being
+  started with ``JAX_PLATFORMS=cpu``;
 - ``scaling_efficiency`` = eps(N) / (N * eps(1)) — REFUSED (null, with
   the reason in-band) for time-sliced topologies, where the ratio would
   measure time-slicing overhead and not hardware scaling.
@@ -59,14 +65,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-_RUNNER = """
-import jax
-jax.config.update('jax_platforms', 'cpu')
-import sys
-from deepfm_tpu.launch import main
-sys.exit(main(sys.argv[1:]))
-"""
-
 TIMESLICE = "virtual-mesh-timeslice"
 REAL = "real-devices"
 
@@ -77,18 +75,10 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _topology() -> tuple:
-    """(topology_kind, device_kind) for the devices the children will use.
-
-    The child runs force JAX_PLATFORMS=cpu and split the host into N
-    virtual XLA devices whenever the parent itself has no accelerator —
-    that is a time-sliced topology, never a scaling claim.
-    """
-    import jax
-    dev = jax.devices()[0]
-    if dev.platform == "cpu":
-        return TIMESLICE, dev.device_kind
-    return REAL, dev.device_kind
+def topology_kind(device: dict) -> str:
+    """Topology label for a child's ``device`` report: virtual CPU devices
+    time-slice the host's cores and never carry a scaling claim."""
+    return TIMESLICE if device["platform"] == "cpu" else REAL
 
 
 def _flops_per_example() -> float:
@@ -147,7 +137,8 @@ def run_once(data_dir: str, model_dir: str, staging_buffers: int,
         ]
     procs = [
         subprocess.Popen(
-            [sys.executable, "-c", _RUNNER] + args + ["--process_id", str(r)],
+            [sys.executable, "-m", "deepfm_tpu.launch"] + args
+            + ["--process_id", str(r)],
             env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE,
             text=True, cwd=_REPO)
         for r in range(world)
@@ -197,7 +188,6 @@ def main() -> None:
     from deepfm_tpu.data import libsvm
     from deepfm_tpu.utils import mfu as mfu_lib
 
-    topology_kind, device_kind = _topology()
     flops = _flops_per_example()
     counts = sorted({int(x) for x in args.device_counts.split(",") if x})
 
@@ -214,6 +204,7 @@ def main() -> None:
             feature_size=117581, field_size=39, prefix="tr", seed=1)
 
         eps1 = None
+        device = None
         for n in counts:
             best = {1: (0.0, 0.0), 2: (0.0, 0.0)}  # buffers -> (eps, ovl)
             for t in range(args.trials):
@@ -222,6 +213,7 @@ def main() -> None:
                         data, os.path.join(root, f"m{n}_{t}_{buffers}"),
                         buffers, epochs, n,
                         inflate_host_ns=args.inflate_host_ns)
+                    device = r["device"]
                     eps = float(r["examples_per_sec"])
                     ovl = float(r.get("staging_overlap_fraction", 0.0))
                     if eps > best[buffers][0]:
@@ -232,19 +224,19 @@ def main() -> None:
             eps_n = best[2][0]
             if n == 1 or eps1 is None:
                 eps1 = eps_n if n == 1 else eps1
-            mfu, basis, _ = mfu_lib.mfu_pct(flops, eps_n / max(n, 1))
             row = {
                 "n_devices": n,
-                "topology_kind": topology_kind,
+                "device": device,
+                "topology_kind": topology_kind(device),
                 "examples_per_sec": round(eps_n, 1),
                 "serialized_eps": round(best[1][0], 1),
                 "overlap_speedup": round(eps_n / max(best[1][0], 1e-9), 3),
                 "overlap_fraction": round(best[2][1], 4),
-                "mfu_pct": mfu,
-                "mfu_basis": basis,
+                "mfu_pct": mfu_lib.mfu_pct(flops, eps_n / max(n, 1),
+                                           device["kind"]),
             }
             row.update(scaling_efficiency_row(
-                topology_kind, n, eps_n, eps1 or 0.0))
+                row["topology_kind"], n, eps_n, eps1 or 0.0))
             curve.append(row)
 
         mp = None
@@ -260,8 +252,9 @@ def main() -> None:
                     mp_best[buffers] = max(mp_best[buffers],
                                            float(r["examples_per_sec"]))
             mp = {
-                "topology": "2-process jax.distributed, CPU backend",
-                "topology_kind": topology_kind,
+                "topology": "2-process jax.distributed",
+                "device": r["device"],
+                "topology_kind": topology_kind(r["device"]),
                 "serialized_eps": round(mp_best[1], 1),
                 "overlapped_eps": round(mp_best[2], 1),
                 "overlap_speedup": round(
@@ -270,8 +263,6 @@ def main() -> None:
 
     out = {
         "bench": "scaling_overlap",
-        "device_kind": device_kind,
-        "topology_kind": topology_kind,
         "model_flops_per_example": flops,
         "staging_ab": "staging_buffers 1 (serialized) vs 2 (double-buffered)"
                       ", interleaved trials, best-of-N",

@@ -22,7 +22,7 @@ carries BOTH):
   4. **Scaling honesty.** On a host with fewer cores than replicas, the
      replica axis time-slices the same core(s), so the report REFUSES a
      scaling-efficiency claim (``scaling_efficiency: null`` + reason, the
-     SCALING_r01.json rule per BASELINE.md) while still publishing the
+     SCALING_r01.json rule) while still publishing the
      measured per-point QPS/p99 curve.
 
 Run on CPU:  JAX_PLATFORMS=cpu python scripts/bench_serving.py
@@ -113,7 +113,7 @@ def run_sweep(report_path=None, run_secs=3.0, verbose=True):
             f"{host_cpus} host core(s), so aggregate QPS measures "
             "scheduler interleaving, not replica scaling; the per-point "
             "curve is published for latency/correctness reading only "
-            "(BASELINE.md scaling rules)")
+            "(scaling-efficiency refusal rule)")
     else:
         base_qps = next(p["serving_qps"] for p in series
                         if p["replicas"] == 1 and p["serve_inflight"] == 2)

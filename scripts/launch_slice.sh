@@ -7,7 +7,7 @@
 # Usage:
 #   scripts/launch_slice.sh \
 #     --tpu-name deepfm-v5e --zone us-west4-a --accel-type v5litepod-8 \
-#     [--create] [--spot] [--worker-per-host N] [--repo-tar] \
+#     [--create] [--spot] [--repo-tar] \
 #     -- --task_type train --data_dir gs://bucket/criteo --model_dir gs://bucket/ckpt \
 #        --feature_size 117581 --field_size 39 --batch_size 1024 --num_epochs 10
 #
@@ -19,13 +19,18 @@
 #      checkpoint resume, same as the reference's SageMaker spot story).
 #   2. Ships the repo to every host (--repo-tar) or assumes a shared image.
 #   3. Runs the task on ALL hosts simultaneously via
-#      `gcloud ... tpu-vm ssh --worker=all`:
-#        worker_per_host == 1 -> `python -m deepfm_tpu.launch --dist_mode 2`
-#          (jax.distributed discovers the slice topology itself)
-#        worker_per_host  > 1 -> `python -m deepfm_tpu.fanout` spawns N local
-#          processes per host with explicit rank math (MPI
-#          processes_per_host analog, ref hvd-gpu.ipynb:87-92), rendezvousing
-#          on host 0's port 12355.
+#      `gcloud ... tpu-vm ssh --worker=all`: one process per host,
+#      `python -m deepfm_tpu.launch --dist_mode 2` (jax.distributed discovers
+#      the slice topology itself; each process drives all of its host's
+#      chips).
+#
+# What has actually been run: NOT this script. PR 21 ran the per-host
+# command it issues — one launcher process over all the chips of one host —
+# on a single four-chip v5e host (4x1, 2x2 and rows-sharded 1x4 meshes,
+# PERF.md). The gcloud steps and every multi-host rendezvous are untested on
+# the current stack. Several workers per host (`--worker-per-host N`, via
+# deepfm_tpu.fanout) was tried on that host and cannot work: the workers
+# collide on libtpu's multi-process lock; the option is refused.
 set -euo pipefail
 
 TPU_NAME=""
@@ -36,7 +41,6 @@ CREATE=0
 SPOT=0
 WORKER_PER_HOST=1
 SHIP_REPO=0
-COORD_PORT=12355
 
 while [[ $# -gt 0 ]]; do
   case "$1" in
@@ -53,6 +57,13 @@ while [[ $# -gt 0 ]]; do
   esac
 done
 TASK_ARGS=("$@")
+
+if [[ "$WORKER_PER_HOST" != 1 ]]; then
+  echo "--worker-per-host $WORKER_PER_HOST: several workers per TPU host" \
+       "cannot form one device topology (tried on four v5e chips, PERF.md);" \
+       "one process per host drives all of its chips" >&2
+  exit 2
+fi
 
 [[ -n "$TPU_NAME" && -n "$ZONE" ]] || {
   echo "required: --tpu-name and --zone" >&2; exit 2; }
@@ -72,8 +83,7 @@ NUM_HOSTS=$("${GC[@]}" describe "$TPU_NAME" --zone "$ZONE" \
               --format='value(networkEndpoints.length())')
 HOST0_IP=$("${GC[@]}" describe "$TPU_NAME" --zone "$ZONE" \
              --format='value(networkEndpoints[0].ipAddress)')
-echo ">> slice $TPU_NAME: $NUM_HOSTS host(s), host0=$HOST0_IP, " \
-     "worker_per_host=$WORKER_PER_HOST"
+echo ">> slice $TPU_NAME: $NUM_HOSTS host(s), host0=$HOST0_IP"
 
 if [[ "$SHIP_REPO" == 1 ]]; then
   echo ">> shipping repo to all hosts"
@@ -87,34 +97,9 @@ fi
 
 QUOTED_ARGS=$(printf ' %q' "${TASK_ARGS[@]}")
 
-if [[ "$WORKER_PER_HOST" == 1 ]]; then
-  # One process per host: jax.distributed discovers the slice topology.
-  REMOTE_CMD="cd ~/deepfm_tpu_run 2>/dev/null || true; \
+# One process per host: jax.distributed discovers the slice topology.
+REMOTE_CMD="cd ~/deepfm_tpu_run 2>/dev/null || true; \
 python -m deepfm_tpu.launch --dist_mode 2 --worker_per_host 1$QUOTED_ARGS"
-  echo ">> running on all hosts: $REMOTE_CMD"
-  "${GC[@]}" ssh "$TPU_NAME" --zone "$ZONE" --worker=all \
-    --command="$REMOTE_CMD"
-else
-  # N processes per host: fanout computes per-process ranks; every host
-  # rendezvouses on host 0.
-  echo ">> fanning out $WORKER_PER_HOST workers/host across $NUM_HOSTS hosts"
-  PIDS=()
-  for (( h=0; h<NUM_HOSTS; h++ )); do
-    REMOTE_CMD="cd ~/deepfm_tpu_run 2>/dev/null || true; \
-python -m deepfm_tpu.fanout --worker_per_host $WORKER_PER_HOST \
---num_hosts $NUM_HOSTS --host_index $h \
---coordinator_address $HOST0_IP:$COORD_PORT$QUOTED_ARGS"
-    "${GC[@]}" ssh "$TPU_NAME" --zone "$ZONE" --worker="$h" \
-      --command="$REMOTE_CMD" &
-    PIDS+=($!)
-  done
-  RC=0
-  for (( h=0; h<NUM_HOSTS; h++ )); do
-    if ! wait "${PIDS[$h]}"; then
-      echo ">> host $h FAILED" >&2
-      RC=1
-    fi
-  done
-  [[ "$RC" == 0 ]] || { echo ">> launch failed" >&2; exit "$RC"; }
-fi
-echo ">> done"
+echo ">> running on all hosts: $REMOTE_CMD"
+"${GC[@]}" ssh "$TPU_NAME" --zone "$ZONE" --worker=all \
+  --command="$REMOTE_CMD"

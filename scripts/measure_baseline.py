@@ -1,8 +1,8 @@
 #!/usr/bin/env python
-"""Measure the BASELINE.md table cells on the current accelerator.
+"""Measure end-to-end train-task cells on the current accelerator.
 
 Generates a reference-shaped synthetic Criteo-like dataset (the reference
-trained on real Criteo; shape anchors from BASELINE.md — feature_size=117581,
+trained on real Criteo; shape anchors from docs/PARITY.md — feature_size=117581,
 field_size=39, embedding_size=32, deep 128/64/32, batch 1024, Adam 5e-4) and
 runs the measurable configs end-to-end through the task driver, printing one
 JSON line per config:
@@ -95,7 +95,7 @@ def main() -> None:
     for model in args.configs.split(","):
         if model == "deepfm_bs16k":
             # Large-batch convergence evidence: step time is flat 256->16384
-            # on-device (BASELINE.md), so bs=16k multiplies e2e throughput —
+            # on-device (July 2026 sweep, record deleted), so bs=16k multiplies e2e throughput —
             # IF it still reaches comparable AUC. Measured (2026-07-30):
             # UNSCALED lr 5e-4 converges (AUC 0.6456 vs 0.650 at bs=1024);
             # sqrt-scaled lr 2e-3 overshoots on this objective (AUC 0.59,

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
-"""Prove row-sharding rescues the vocab cliff (BASELINE.md sweep row).
+"""Prove row-sharding rescues the vocab cliff.
 
-The single-chip vocab sweep (BASELINE.md, measured r3) shows embedding
+A single-chip vocab sweep (July 2026, record deleted) showed embedding
 tables are free to ~10M rows x K=32 and then fall off a cliff: V=25M costs
 ~9.6 GB of params+Adam moments — HBM pressure pushes the step to 56 ms —
 and V=50M fails to compile at all. The claimed rescue is the X1 capability

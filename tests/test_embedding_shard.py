@@ -28,17 +28,8 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5 (see train/loop.py)
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        del check_vma
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
 
 from deepfm_tpu.config import Config
 from deepfm_tpu.ops import embedding as emb_ops

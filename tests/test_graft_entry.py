@@ -1,8 +1,7 @@
 """Driver-contract test: __graft_entry__.dryrun_multichip must succeed in a
 FRESH process on a host with fewer real devices than requested — i.e. it must
-self-provision the virtual 8-device CPU mesh (the round-1 failure mode:
-MULTICHIP_r01.json ok=false because the entry asserted on device count
-instead of provisioning).
+self-provision the virtual 8-device CPU mesh (the round-1 failure mode: the
+entry asserted on device count instead of provisioning).
 """
 
 import os
@@ -17,8 +16,8 @@ def test_dryrun_multichip_self_provisions():
     # process starts with none of them.
     env = {k: v for k, v in os.environ.items()
            if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    # Prepend (not replace): the driver's process may rely on sitecustomize
-    # entries already on PYTHONPATH — the exact hazard being tested.
+    # Prepend (not replace): the driver's process may rely on entries
+    # already on PYTHONPATH.
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     code = "import __graft_entry__; __graft_entry__.dryrun_multichip(8)"
     p = subprocess.run(

@@ -254,11 +254,11 @@ class TestEligibilityAndFallback:
             shm = _emissions(files, input_workers=2, **kw)
         _assert_same_emissions(_emissions(files, **kw), shm)
 
-    def test_service_failure_warns_and_falls_back(self, data_dir,
-                                                  monkeypatch):
+    def test_service_failure_raises(self, data_dir, monkeypatch):
         """If the fleet cannot start (sandboxed /dev/shm, fork server
-        restrictions...), the pipeline degrades to in-process with a
-        RuntimeWarning — identical output, never a crash."""
+        restrictions...), the run fails with the cause: input_workers asked
+        for decode processes, and the in-process decode is a different,
+        slower host path that must not be taken on the quiet."""
         files = _files(data_dir)
 
         class Unstartable:
@@ -266,9 +266,8 @@ class TestEligibilityAndFallback:
                 raise OSError("shm forbidden")
 
         monkeypatch.setattr(workers_mod, "ShmInputService", Unstartable)
-        with pytest.warns(RuntimeWarning, match="input service unavailable"):
-            shm = _emissions(files, input_workers=2)
-        _assert_same_emissions(_emissions(files), shm)
+        with pytest.raises(OSError, match="shm forbidden"):
+            _emissions(files, input_workers=2)
 
     def test_config_rejects_negative(self):
         from deepfm_tpu.config import Config

@@ -155,7 +155,7 @@ def test_fanout_spawns_local_cluster(mp_workdir):
         PYTHONPATH=_REPO,
     )
     # Workers must pin jax to CPU before backend init; fanout children run
-    # deepfm_tpu.launch directly, so route through sitecustomize-safe env.
+    # deepfm_tpu.launch directly, so the pin travels in their environment.
     cmd = [
         sys.executable, "-m", "deepfm_tpu.fanout",
         "--worker_per_host", "2",

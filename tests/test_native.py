@@ -323,7 +323,7 @@ class TestAssembleSpans:
         """A cached .so predating the fused entry must degrade to the
         per-chunk scatter path with identical bytes (the has_assemble()
         probe contract)."""
-        real = loader._load()
+        real = loader.load()
 
         class _StaleLib:
             def __getattr__(self, name):
@@ -335,7 +335,7 @@ class TestAssembleSpans:
         l_f, i_f, v_f = self._pools(total, label_2d=True)
         loader.assemble_spans(jobs, 7, l_f, i_f, v_f)  # fused
         stale = _StaleLib()
-        monkeypatch.setattr(loader, "_load", lambda: stale)
+        monkeypatch.setattr(loader, "load", lambda: stale)
         assert not loader.has_assemble()
         l_s, i_s, v_s = self._pools(total, label_2d=True)
         loader.assemble_spans(jobs, 7, l_s, i_s, v_s)  # per-chunk fallback
@@ -520,7 +520,7 @@ class TestHistoryDecode:
         contract, same discipline as the fused-assemble fallback)."""
         records = tfrecord.read_all_records(hist_file)[:50]
         native = loader.decode_batch_hist(records, 7, self.MAX_LEN)
-        real = loader._load()
+        real = loader.load()
 
         class _StaleLib:
             def __getattr__(self, name):
@@ -529,7 +529,7 @@ class TestHistoryDecode:
                 return getattr(real, name)
 
         stale = _StaleLib()
-        monkeypatch.setattr(loader, "_load", lambda: stale)
+        monkeypatch.setattr(loader, "load", lambda: stale)
         assert not loader.has_hist()
         fallback = loader.decode_batch_hist(records, 7, self.MAX_LEN)
         for a, b in zip(native, fallback):
@@ -554,3 +554,40 @@ class TestHistoryDecode:
             assert bn["hist_ids"].shape[1] == self.MAX_LEN
             n += 1
         assert n == 5
+
+
+class TestBuildFromCleanCheckout:
+    """The library is built on first use into a file named after the source's
+    hash — never committed, never chosen by mtime — and a build that cannot
+    happen is an error, not a quiet switch to the Python codec."""
+
+    def test_builds_hash_keyed_file_into_clean_build_dir(self, tmp_path,
+                                                         monkeypatch):
+        import hashlib
+
+        monkeypatch.setattr(loader, "_BUILD_DIR", str(tmp_path / "_build"))
+        monkeypatch.setattr(loader, "_lib", None)
+        loader.load()
+        with open(loader._SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        built = tmp_path / "_build" / f"libtfrecord-{digest}.so"
+        assert os.listdir(tmp_path / "_build") == [built.name]
+        assert loader.crc32c(b"123456789") == 0xE3069283
+        # Same source, next process: the existing file is loaded, not rebuilt.
+        stamp = built.stat().st_mtime_ns
+        monkeypatch.setattr(loader, "_lib", None)
+        loader.load()
+        assert built.stat().st_mtime_ns == stamp
+
+    def test_no_compiler_fails_loudly(self, tmp_path, monkeypatch):
+        monkeypatch.setattr(loader, "_BUILD_DIR", str(tmp_path / "_build"))
+        monkeypatch.setattr(loader, "_lib", None)
+        monkeypatch.setenv("PATH", str(tmp_path))
+        with pytest.raises(loader.NativeBuildError, match=r"g\+\+"):
+            loader.load()
+        assert not loader.available()
+        # use_native_decoder=True (the default) propagates it...
+        with pytest.raises(loader.NativeBuildError):
+            pipeline._get_decoder(True)
+        # ...and only an explicit False takes the Python codec.
+        assert pipeline._get_decoder(False) is pipeline.decode_batch_python

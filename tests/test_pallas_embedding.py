@@ -1,10 +1,12 @@
-"""Embedding-plane Pallas kernels vs NumPy oracles (interpret mode), plus
-the kernel-selection gates and the trainer's kill-switch parity contract.
+"""Embedding-plane kernel legs vs NumPy oracles, plus the kernel-selection
+gates and the trainer's kill-switch parity contract.
 
-The compiled kernels run only on TPU; the ``pallas``-marked tests exercise
-the identical kernel bodies through the Pallas interpreter on CPU against
-``ops.pallas_embedding.reference_plan_numpy`` / hand-rolled NumPy scatter
-oracles. The parity tests pin the ``--embedding_kernels`` contract:
+The compiled Pallas take kernels run only on TPU; the ``pallas``-marked
+tests exercise the identical kernel bodies through the Pallas interpreter on
+CPU, and ``test_pallas_kernels_lower_for_tpu`` cross-lowers every kernel in
+``deepfm_tpu/ops`` for TPU at the reference shape — which is where the
+deleted plan/install kernels were refused (``Cannot store scalars to
+VMEM``). The parity tests pin the ``--embedding_kernels`` contract:
 
 * ``auto`` vs ``xla``: bit-identical (same fused formulation, A/B legs
   are element-identical).
@@ -43,17 +45,16 @@ def _ids(shape, rows, seed=0, oob=False):
 
 
 # ---------------------------------------------------------------------------
-# Kernel 1: device-side plan build
+# Seam 1: device-side plan build
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.pallas
 @pytest.mark.parametrize("shape,rows,seed", [
     ((8, 3), 32, 0), ((16, 5), 64, 1), ((4, 4), 16, 2),
 ])
-def test_plan_kernel_matches_numpy_oracle(shape, rows, seed):
+def test_counting_plan_matches_numpy_oracle(shape, rows, seed):
     ids = _ids(shape, rows, seed)
-    got = pemb.plan_build_pallas(jnp.asarray(ids), rows, interpret=True)
+    got = emb_ops.make_plan_counting(jnp.asarray(ids), rows)
     uids, inv, touched, rank = pemb.reference_plan_numpy(ids, rows)
     np.testing.assert_array_equal(np.asarray(got.uids), uids)
     np.testing.assert_array_equal(np.asarray(got.inv), inv)
@@ -63,17 +64,14 @@ def test_plan_kernel_matches_numpy_oracle(shape, rows, seed):
         np.asarray(got.rank)[touched], rank[touched])
 
 
-@pytest.mark.pallas
-def test_plan_kernel_matches_xla_legs():
-    """All three plan legs must emit bit-identical uids/inv (the plan is
-    part of the numerics contract: rows order decides scatter order)."""
+def test_plan_legs_match():
+    """Both plan legs must emit bit-identical uids/inv (the plan is part of
+    the numerics contract: rows order decides scatter order)."""
     ids = jnp.asarray(_ids((12, 4), 40, seed=3))
-    k = pemb.plan_build_pallas(ids, 40, interpret=True)
     c = emb_ops.make_plan_counting(ids, 40)
     s = emb_ops.make_plan(ids, 40)
-    for a, b in ((k, c), (k, s)):
-        np.testing.assert_array_equal(np.asarray(a.uids), np.asarray(b.uids))
-        np.testing.assert_array_equal(np.asarray(a.inv), np.asarray(b.inv))
+    np.testing.assert_array_equal(np.asarray(c.uids), np.asarray(s.uids))
+    np.testing.assert_array_equal(np.asarray(c.inv), np.asarray(s.inv))
 
 
 def test_plan_build_gates():
@@ -92,8 +90,68 @@ def test_plan_build_gates():
     assert not pemb.supported("plan", num_rows=8, n_ids=8)  # CPU backend
 
 
+def test_resolve_only_returns_legs_that_exist(monkeypatch):
+    """The Pallas plan-build and install kernels were deleted (the TPU
+    lowering refuses them), so no mode on any backend may select a
+    ``pallas`` leg for those seams; ``take`` keeps its kernel, on TPU, while
+    the working set fits the VMEM limit it is compiled with. The ``pallas``
+    mode went with them: asking for it is a config error, not a quiet XLA
+    leg."""
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(pemb.jax, "default_backend", lambda b=backend: b)
+        for mode in pemb.MODES:
+            for seam in ("plan", "install"):
+                assert pemb.resolve(mode, seam, num_rows=117_581,
+                                    n_ids=39_936, width=33) in ("opt", "ref")
+        want = "pallas" if backend == "tpu" else "opt"
+        assert pemb.resolve("auto", "take", num_rows=39_936, n_ids=39_936,
+                            width=33) == want
+        assert pemb.resolve("xla", "take", num_rows=39_936, n_ids=39_936,
+                            width=33) == "opt"
+        assert pemb.resolve("auto", "take", num_rows=400_000, n_ids=39_936,
+                            width=33) == "opt"   # past the VMEM limit
+    assert "pallas" not in pemb.MODES
+    with pytest.raises(ValueError, match="embedding_kernels"):
+        pemb.resolve("pallas", "take", num_rows=8, n_ids=8)
+    with pytest.raises(ValueError, match="embedding_kernels"):
+        Config(embedding_update="sparse", embedding_kernels="pallas")
+
+
+def test_pallas_kernels_lower_for_tpu():
+    """Every pallas_call in deepfm_tpu/ops cross-lowers for TPU at the
+    reference shape (B=1024, F=39, K=32; U = N = 39,936 rows of width 33).
+    Needs no chip, and catches the class of kernel the TPU lowering refuses
+    before Mosaic ever sees it; that Mosaic then compiles them is what
+    chip_smoke.py proves on the device."""
+    from jax import export as jax_export
+
+    from deepfm_tpu.ops import pallas_fm
+
+    b, f, k = 1024, 39, 32
+    n = b * f
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def lowers(fn, *specs):
+        exp = jax_export.export(jax.jit(fn), platforms=("tpu",))(*specs)
+        assert "tpu_custom_call" in exp.mlir_module()
+
+    for dt in (jnp.float32, jnp.bfloat16):
+        fm_specs = (sds((b, f), dt), sds((b, f), dt), sds((b, f, k), dt))
+        lowers(lambda w, v, xv: pallas_fm.fused_fm(w, v, xv, False),
+               *fm_specs)
+        lowers(jax.grad(lambda w, v, xv: jnp.sum(
+            pallas_fm.fused_fm(w, v, xv, False)), argnums=(0, 1, 2)),
+            *fm_specs)
+    take_specs = (sds((n, 33), jnp.float32), sds((b, f), jnp.int32))
+    lowers(lambda r, i: pemb.take_rows_pallas(r, i, False), *take_specs)
+    lowers(jax.grad(lambda r, i: jnp.sum(
+        pemb.take_rows_pallas(r, i, False))), *take_specs)
+
+
 # ---------------------------------------------------------------------------
-# Kernel 2: fused gather forward + segment-sum backward
+# Seam 2: fused gather forward + segment-sum backward
 # ---------------------------------------------------------------------------
 
 
@@ -127,12 +185,11 @@ def test_take_rows_xla_leg_is_jnp_take():
 
 
 # ---------------------------------------------------------------------------
-# Kernel 3: fused install/evict scatter
+# Seam 3: fused install/evict scatter
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.pallas
-def test_install_kernel_matches_numpy_oracle():
+def test_fused_install_matches_numpy_oracle():
     rng = np.random.default_rng(4)
     H, D, n, p = 12, 4, 5, 8
     w = rng.standard_normal((H, D)).astype(np.float32)
@@ -149,10 +206,10 @@ def test_install_kernel_matches_numpy_oracle():
     vv[:n] = rng.standard_normal((n, D))
     tv = np.zeros((p,), np.int32)
     tv[:n] = 11
-    got = pemb.install_pallas(
+    got = pemb._install_fused_xla(
         jnp.asarray(w), jnp.asarray(m), jnp.asarray(v), jnp.asarray(tau),
         jnp.asarray(slots), jnp.asarray(wv), jnp.asarray(mv),
-        jnp.asarray(vv), jnp.asarray(tv), interpret=True)
+        jnp.asarray(vv), jnp.asarray(tv))
     ew, em, ev, et = w.copy(), m.copy(), v.copy(), tau.copy()
     ew[slots[:n]] = wv[:n]
     em[slots[:n]] = mv[:n]
@@ -160,28 +217,6 @@ def test_install_kernel_matches_numpy_oracle():
     et[slots[:n]] = tv[:n]
     for a, b in zip(got, (ew, em, ev, et)):
         np.testing.assert_array_equal(np.asarray(a), b)
-
-
-@pytest.mark.pallas
-def test_install_xla_leg_matches_pallas_leg():
-    rng = np.random.default_rng(5)
-    H, D, p = 8, 3, 4
-    args = (rng.standard_normal((H, D)).astype(np.float32),
-            rng.standard_normal((H, D)).astype(np.float32),
-            rng.standard_normal((H, D)).astype(np.float32),
-            rng.integers(0, 5, (H,)).astype(np.int32))
-    slots = np.array([1, 5, H, H], np.int32)
-    vals = (rng.standard_normal((p, D)).astype(np.float32),
-            rng.standard_normal((p, D)).astype(np.float32),
-            rng.standard_normal((p, D)).astype(np.float32),
-            rng.integers(0, 5, (p,)).astype(np.int32))
-    jargs = tuple(jnp.asarray(a) for a in args)
-    jvals = tuple(jnp.asarray(a) for a in vals)
-    a = pemb.install_pallas(*jargs, jnp.asarray(slots), *jvals,
-                            interpret=True)
-    b = pemb._install_fused_xla(*jargs, jnp.asarray(slots), *jvals)
-    for x, y in zip(a, b):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_install_rows_ref_leg_returns_none():

@@ -46,24 +46,50 @@ def test_savedmodel_exists_and_matches_jax(artifact):
     np.testing.assert_allclose(tf_probs, jax_probs, rtol=1e-5, atol=1e-6)
 
 
-def test_params_only_fallback_matches(artifact, tmp_path):
-    """Deleting serving_fn.stablehlo degrades load_serving to the
-    rebuild-from-config path with identical outputs (the artifact the
-    export writes when platform lowering fails)."""
+def test_artifact_without_program_refuses_to_load(artifact, tmp_path):
+    """export_serving writes serving_fn.stablehlo or raises, so an artifact
+    without it is damaged: load_serving must not rebuild a predict function
+    from the config and serve through a program nobody exported."""
     import os
     import shutil
-    if not os.path.exists(os.path.join(artifact, "serving_fn.stablehlo")):
-        pytest.skip("artifact is already params-only on this platform")
     degraded = str(tmp_path / "degraded")
     shutil.copytree(artifact, degraded)
     os.remove(os.path.join(degraded, "serving_fn.stablehlo"))
+    with pytest.raises(export_lib.ArtifactIncomplete,
+                       match="serving_fn.stablehlo"):
+        export_lib.load_serving(degraded)
 
-    rng = np.random.default_rng(1)
+
+def test_export_on_tpu_backend_carries_program_and_loads_on_cpu(
+        tmp_path, monkeypatch):
+    """What a TPU host writes: with use_pallas=True and the backend reporting
+    ``tpu`` the FM block is eligible for the compiled Pallas kernel, whose
+    CPU lowering does not exist. The export must still produce the
+    serialized program (symbolic batch -> portable formulation) and that
+    artifact must serve under the CPU backend, matching the trainer."""
+    import os
+    cfg = Config(
+        feature_size=120, field_size=5, embedding_size=4, deep_layers="8",
+        dropout="1.0", batch_size=32, compute_dtype="float32",
+        mesh_data=1, log_steps=0, seed=7, use_pallas=True)
+    trainer = Trainer(cfg)
+    state = trainer.init_state()
+    out = str(tmp_path / "1")
+    monkeypatch.setenv("DEEPFM_TPU_SKIP_TF_EXPORT", "1")
+    with monkeypatch.context() as m:
+        m.setattr(export_lib.jax, "default_backend", lambda: "tpu")
+        export_lib.export_serving(trainer.model, state, cfg, out)
+    assert os.path.exists(os.path.join(out, "serving_fn.stablehlo"))
+    assert export_lib.saved_model_status(out).startswith("skipped:")
+
+    rng = np.random.default_rng(2)
     ids = rng.integers(0, 120, (8, 5)).astype(np.int32)
     vals = rng.normal(size=(8, 5)).astype(np.float32)
-    full = export_lib.load_serving(artifact)(ids, vals)
-    fb = export_lib.load_serving(degraded)(ids, vals)
-    np.testing.assert_allclose(full, fb, rtol=1e-5, atol=1e-6)
+    batch = {"feat_ids": ids, "feat_vals": vals,
+             "label": np.zeros((8, 1), np.float32)}
+    want = np.concatenate(list(trainer.predict(state, [batch])))
+    np.testing.assert_allclose(export_lib.load_serving(out)(ids, vals), want,
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_savedmodel_batch_polymorphic(artifact):
