@@ -160,7 +160,7 @@ def host_stage_series() -> dict:
             pipeline actually returned — drop_remainder eats the tail, so
             dividing by the on-disk count understated the per-record cost
             (advisor r5, both). With ``with_stages`` the BEST trial's
-            per-stage breakdown rides along (read/frame/decode_assemble/
+            per-stage breakdown rides along (read/frame/pool_drain/
             emit + unattributed 'other'), so a total-ns regression is
             attributable to a stage, not just asserted."""
             best, n = float("inf"), 0

@@ -31,6 +31,7 @@ from typing import BinaryIO, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..obs import trace as trace_lib
 from . import example_codec, fileio, sharding, tfrecord
 from .health import BadRecordPolicy, DataHealth
 
@@ -143,11 +144,12 @@ _EOF_PROBE_BYTES = 64 << 10
 _SYNTH_STALL_ENV = "DEEPFM_TPU_SYNTH_HOST_NS_PER_RECORD"
 
 
-def _timed(stats, name: str):
-    """Stage-timing context: records wall ns into ``stats`` (a
-    ``profiling.HostStageStats``), or free when no collector is attached."""
+def _timed(stats, name: str, **attrs):
+    """Stage-timing context: an ``input.<name>`` span of the process tracer
+    (the shared no-op when tracing is off), or, when a collector is attached
+    (``profiling.HostStageStats``), wall ns recorded into it."""
     if stats is None:
-        return contextlib.nullcontext()
+        return trace_lib.span("input." + name, **attrs)
     return stats.stage(name)
 
 
@@ -862,7 +864,8 @@ class CtrPipeline:
         # filter those buffers hold ~world x the rows that count toward
         # pool_target — a world-fold RSS regression; the eager path decodes
         # (only) the kept rows and frees each buffer immediately.
-        fused = (not use_shm and self.shuffle and loader is not None
+        fused = (cached_cols is None and not use_shm and self.shuffle
+                 and loader is not None
                  and self._record_shard is None and self.num_labels == 1
                  and not self.history
                  and hasattr(loader, "decode_spans_scatter"))
@@ -882,6 +885,8 @@ class CtrPipeline:
                 raw: List[Tuple[bytes, np.ndarray, np.ndarray]] = []
                 n_pend = 0
                 service = self._make_input_service(epoch) if use_shm else None
+                trace_lib.instant("input.epoch", epoch=epoch,
+                                  files=len(self._files))
 
                 def drain(final: bool, service=service
                           ) -> Iterator[Tuple[Batch, int, int]]:
@@ -895,7 +900,8 @@ class CtrPipeline:
                         # decoded) scatters first, then raw chunks decode
                         # directly to their rows — matching the arrival order
                         # the permutation indexes.
-                        with _timed(stats, "decode_assemble"):
+                        with _timed(stats, "pool_drain", epoch=epoch,
+                                    records=n_pend):
                             perm = rng.permutation(n_pend)
                             # Transfer-layout pool: the label column is
                             # [n, 1] so a batch slice IS the emitted
@@ -932,7 +938,7 @@ class CtrPipeline:
                             service.release_consumed()
                     hl = self.history_max_len if self.history else 0
                     while n_pend >= sb:
-                        with _timed(stats, "emit"):
+                        with _timed(stats, "emit", records=sb):
                             rows = self._assemble_batch(pend, sb, hl)
                         if stall_ns:
                             time.sleep(stall_ns * sb * 1e-9)
@@ -940,52 +946,52 @@ class CtrPipeline:
                         n_pend -= sb
                     if final:
                         while n_pend >= bs:
-                            with _timed(stats, "emit"):
+                            with _timed(stats, "emit", records=bs):
                                 rows = self._assemble_batch(pend, bs, hl)
                             if stall_ns:
                                 time.sleep(stall_ns * bs * 1e-9)
                             yield rows, 1, bs
                             n_pend -= bs
                         if n_pend and not self.drop_remainder:
-                            with _timed(stats, "emit"):
+                            with _timed(stats, "emit", records=n_pend):
                                 rows = self._assemble_batch(pend, n_pend, hl)
                             if stall_ns:
                                 time.sleep(stall_ns * n_pend * 1e-9)
                             yield rows, 1, n_pend
                             n_pend = 0
 
-                if cached_cols is not None:
-                    for chunk in cache_lib.epoch_chunks(
-                            cached_cols, self._epoch_file_order(epoch)):
-                        pend.append(chunk)
-                        n_pend += len(chunk[0])
-                        if n_pend >= pool_target:
-                            yield from drain(final=False)
-                    yield from drain(final=True)
-                elif service is not None:
-                    with service:
+                def fill(chunks) -> bool:
+                    """Pull chunks into the pool until it holds
+                    ``pool_target`` records; False once the source ended."""
+                    nonlocal n_pend
+                    with trace_lib.span("input.pool_fill", epoch=epoch) as sp:
+                        n0 = n_pend
+                        for chunk in chunks:
+                            # A framed span is (buf, offsets, lengths); a
+                            # decoded chunk (labels, ids, vals).
+                            (raw if fused else pend).append(chunk)
+                            n_pend += len(chunk[1 if fused else 0])
+                            if n_pend >= pool_target:
+                                break
+                        sp.add(records=n_pend - n0)
+                    return n_pend >= pool_target
+
+                with contextlib.ExitStack() as held:
+                    if cached_cols is not None:
+                        chunks = iter(cache_lib.epoch_chunks(
+                            cached_cols, self._epoch_file_order(epoch)))
+                    elif service is not None:
+                        held.enter_context(service)
                         # shuffle=False never scatters, so views would stay
                         # referenced by batch slices indefinitely: copy out
                         # of the slabs instead of holding them.
-                        for chunk in service.chunks(copy=not self.shuffle):
-                            pend.append(chunk)
-                            n_pend += len(chunk[0])
-                            if n_pend >= pool_target:
-                                yield from drain(final=False)
-                        yield from drain(final=True)
-                elif fused:
-                    for span in self._iter_framed_span_chunks(epoch, loader):
-                        raw.append(span)
-                        n_pend += len(span[1])
-                        if n_pend >= pool_target:
-                            yield from drain(final=False)
-                    yield from drain(final=True)
-                else:
-                    for chunk in self._iter_decoded_chunks(epoch, loader):
-                        pend.append(chunk)
-                        n_pend += len(chunk[0])
-                        if n_pend >= pool_target:
-                            yield from drain(final=False)
+                        chunks = iter(service.chunks(copy=not self.shuffle))
+                    elif fused:
+                        chunks = self._iter_framed_span_chunks(epoch, loader)
+                    else:
+                        chunks = self._iter_decoded_chunks(epoch, loader)
+                    while fill(chunks):
+                        yield from drain(final=False)
                     yield from drain(final=True)
         finally:
             # Release the drain-decode executor when the generator ends OR

@@ -120,6 +120,7 @@ def init_hidden_stack(rng: jax.Array, in_dim: int, layer_sizes: Sequence[int],
     return params, state
 
 
+@jax.named_scope("tower")
 def apply_hidden_stack(
     params: Params,
     state: State,
@@ -170,6 +171,7 @@ def init_tower(rng: jax.Array, in_dim: int, layer_sizes: Sequence[int],
     return params, state
 
 
+@jax.named_scope("tower")
 def apply_tower(
     params: Params,
     state: State,

@@ -41,11 +41,13 @@ from . import common
 # Interaction blocks: pure functions over embedded features.
 # ----------------------------------------------------------------------
 
+@jax.named_scope("fm")
 def first_order(w: jnp.ndarray, feat_vals: jnp.ndarray) -> jnp.ndarray:
     """Linear term sum_f W[ids]*vals — the "wide" part. [B,F] -> [B]."""
     return jnp.sum(w * feat_vals, axis=1)
 
 
+@jax.named_scope("fm")
 def fm_block(cfg: Config, w: jnp.ndarray, feat_vals: jnp.ndarray,
              xv: jnp.ndarray) -> jnp.ndarray:
     """First-order + FM second-order in one block (fused on TPU).
@@ -84,6 +86,7 @@ def init_cross_layer(key: jax.Array, d: int, cross_rank: int
     }
 
 
+@jax.named_scope("fm")
 def cross_network(cross_params, x0c: jnp.ndarray,
                   compute_dtype: jnp.dtype) -> jnp.ndarray:
     """DCN-v2 cross tower: x_{l+1} = x0 * (W_l x_l + b_l) + x_l.
@@ -102,6 +105,7 @@ def cross_network(cross_params, x0c: jnp.ndarray,
     return x
 
 
+@jax.named_scope("fm")
 def dot_interaction(xv: jnp.ndarray) -> jnp.ndarray:
     """DLRM-style pairwise dot-interaction (Naumov et al., 2019).
 
@@ -140,6 +144,7 @@ class GraphModel:
     def num_tasks(self) -> int:
         return len(self.task_names)
 
+    @jax.named_scope("embed")
     def _emb_lookup(self, params: common.Params, name: str,
                     feat_ids: jnp.ndarray, shard_axis: Optional[str],
                     emb_rows: Optional[Dict[str, Any]],
@@ -152,6 +157,7 @@ class GraphModel:
             return self.emb.lookup_rows(emb_rows[name], emb_plan)
         return self.emb.lookup(params[name], feat_ids, axis_name=shard_axis)
 
+    @jax.named_scope("l2")
     def l2_loss(self, params: common.Params, *,
                 shard_axis: Optional[str] = None,
                 emb_rows: Optional[Dict[str, Any]] = None,

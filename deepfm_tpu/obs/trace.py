@@ -17,6 +17,13 @@ Three event shapes, all Perfetto/chrome://tracing loadable:
   finish on another (ring waits, executor handoffs).
 - ``instant("name", **attrs)`` — a point ("i") event (spills, swaps).
 
+While tracing is on, a ``gc.callbacks`` hook records each collection of the
+interpreter's cyclic collector as a ``host.gc`` span on the thread it
+stopped (``generation``, ``collected``): a stop-the-world pause blocks the
+dispatch thread whichever thread triggered it. A collection can start on a
+thread that is inside the tracer, holding its lock, so the hook takes no
+lock: its events go to a queue of their own (``Tracer._emit_gc``).
+
 The clock is ``time.time_ns()`` (wall), NOT ``perf_counter_ns``: traces
 from several processes (trainer, input workers, drill) merge into ONE
 timeline, so timestamps must share an epoch.
@@ -33,12 +40,14 @@ concatenates them into one file.
 
 from __future__ import annotations
 
+import collections
+import gc
 import itertools
 import json
 import os
 import threading
 import time
-from typing import Dict, Iterable, List, Optional, Tuple, Union
+from typing import Deque, Dict, Iterable, List, Optional, Tuple, Union
 
 MODES = ("off", "ring", "full")
 DEFAULT_CAPACITY = 65536
@@ -107,21 +116,39 @@ class Tracer:
         self._lock = threading.Lock()
         self._buf: List[Dict] = []
         self._head = 0          # ring overwrite cursor (oldest event)
-        self.dropped = 0        # ring wraparound overwrites, counted
+        self._dropped = 0       # ring wraparound overwrites, counted
+        # host.gc events, written without the lock (see _emit_gc)
+        self._gc_buf: Deque[Dict] = collections.deque(
+            maxlen=self.capacity if mode == "ring" else None)
+        self._gc_dropped = 0
         self._ids = itertools.count(1)
 
     @property
     def enabled(self) -> bool:
         return self.mode != "off"
 
+    @property
+    def dropped(self) -> int:
+        return self._dropped + self._gc_dropped
+
     def _emit(self, ev: Dict) -> None:
         with self._lock:
             if self.mode == "ring" and len(self._buf) >= self.capacity:
                 self._buf[self._head] = ev
                 self._head = (self._head + 1) % self.capacity
-                self.dropped += 1
+                self._dropped += 1
             else:
                 self._buf.append(ev)
+
+    def _emit_gc(self, ev: Dict) -> None:
+        """Record an event from inside the collector. The collection may
+        have started on a thread that holds ``_lock`` (``events()`` and
+        ``_emit`` allocate under it), so taking the lock here would hang
+        that thread for good. ``deque.append`` is one atomic call, and
+        collections never nest or overlap, so nothing else writes here."""
+        if len(self._gc_buf) == self._gc_buf.maxlen:
+            self._gc_dropped += 1
+        self._gc_buf.append(ev)
 
     def span(self, name: str, **attrs) -> Union[_Span, _NullSpan]:
         if self.mode == "off":
@@ -164,11 +191,14 @@ class Tracer:
         self._emit(ev)
 
     def events(self) -> List[Dict]:
-        """Chronological snapshot (ring order unrolled oldest-first)."""
+        """Chronological snapshot: what the buffer holds (after a ring's
+        wraparound, the newest ``capacity`` events) and the collector's
+        ``host.gc`` events, by start time."""
         with self._lock:
-            if self.mode == "ring" and len(self._buf) >= self.capacity:
-                return self._buf[self._head:] + self._buf[:self._head]
-            return list(self._buf)
+            out = list(self._buf)
+        out.extend(self._gc_buf)
+        out.sort(key=lambda e: e["ts"])
+        return out
 
 
 # --------------------------------------------------------------------------
@@ -180,6 +210,34 @@ _trace_dir = ""
 _id_counter = itertools.count(1)
 
 
+_gc_t0 = 0
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    """``gc.callbacks`` hook: one ``host.gc`` span per collection. The
+    collector never nests, so one module-level slot holds the start time;
+    the event goes in without the tracer's lock (``Tracer._emit_gc``)."""
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.time_ns()
+    elif _gc_t0:
+        t1 = time.time_ns()
+        _tracer._emit_gc({
+            "name": "host.gc", "ph": "X", "ts": _gc_t0 / 1e3,
+            "dur": (t1 - _gc_t0) / 1e3, "pid": os.getpid(),
+            "tid": threading.get_ident(),
+            "args": {"generation": info["generation"],
+                     "collected": info["collected"]}})
+        _gc_t0 = 0
+
+
+def _hook_gc(on: bool) -> None:
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+    if on:
+        gc.callbacks.append(_on_gc)
+
+
 def configure(mode: str, *, capacity: int = DEFAULT_CAPACITY,
               trace_dir: str = "", export_env: bool = True) -> None:
     """Install the process-global tracer. With ``export_env`` (default) the
@@ -189,6 +247,7 @@ def configure(mode: str, *, capacity: int = DEFAULT_CAPACITY,
     global _tracer, _trace_dir
     _tracer = Tracer(mode, capacity)
     _trace_dir = trace_dir or ""
+    _hook_gc(_tracer.enabled)
     if export_env:
         os.environ[ENV_MODE] = mode
         os.environ[ENV_BUFFER] = str(int(capacity))
@@ -217,6 +276,7 @@ def reset() -> None:
     global _tracer, _trace_dir
     _tracer = Tracer()
     _trace_dir = ""
+    _hook_gc(False)
     for k in (ENV_MODE, ENV_DIR, ENV_BUFFER):
         os.environ.pop(k, None)
 

@@ -213,6 +213,7 @@ class PlanEntry(NamedTuple):
     rank: Optional[jax.Array] = None
 
 
+@jax.named_scope("embed")
 def make_plan(ids: jax.Array, num_rows: int,
               mask: Optional[jax.Array] = None) -> PlanEntry:
     """Build a PlanEntry. ``ids`` must already be per-table row ids; masked
@@ -225,6 +226,7 @@ def make_plan(ids: jax.Array, num_rows: int,
                      mask=mask, num_rows=num_rows)
 
 
+@jax.named_scope("embed")
 def make_plan_counting(ids: jax.Array, num_rows: int,
                        mask: Optional[jax.Array] = None) -> PlanEntry:
     """``make_plan`` with bit-identical uids/inv, built by counting instead
@@ -268,6 +270,7 @@ def valid_rows(entry: PlanEntry) -> jax.Array:
     return entry.uids < entry.num_rows
 
 
+@jax.named_scope("embed")
 def gather_rows(table: jax.Array, entry: PlanEntry) -> jax.Array:
     """[U, ...] rows at ``entry.uids``. OOB fill slots read as ZERO
     (``mode="fill"`` — jnp.take's default fill is NaN, which would poison
@@ -277,6 +280,7 @@ def gather_rows(table: jax.Array, entry: PlanEntry) -> jax.Array:
     return jnp.take(table, entry.uids, axis=0, mode="fill", fill_value=0)
 
 
+@jax.named_scope("embed")
 def lookup_rows(rows: jax.Array, entry: PlanEntry) -> jax.Array:
     """Positionwise view of gathered rows: rows[inv] (masked in hashed
     mode). Differentiating this gather w.r.t. ``rows`` IS the segment-sum:
@@ -290,6 +294,7 @@ def lookup_rows(rows: jax.Array, entry: PlanEntry) -> jax.Array:
     return out
 
 
+@jax.named_scope("embed")
 def scatter_rows(table: jax.Array, entry: PlanEntry,
                  new_rows: jax.Array) -> jax.Array:
     """Write back updated touched rows; the OOB fill slots are DROPPED by
@@ -313,6 +318,7 @@ def scatter_rows(table: jax.Array, entry: PlanEntry,
     return jnp.where(keep, sel.astype(table.dtype), table)
 
 
+@jax.named_scope("embed")
 def set_rows_scalar(table: jax.Array, entry: PlanEntry,
                     value: jax.Array) -> jax.Array:
     """Set every touched row of a rank-1 per-row array (the lazy-Adam
@@ -364,6 +370,7 @@ class ExchangePlan(NamedTuple):
     n_ids: int
 
 
+@jax.named_scope("embed")
 def build_exchange(entry: PlanEntry, num_shards: int,
                    axis_name: str) -> ExchangePlan:
     """Bucket this peer's uid slice by owner shard. Must run inside
@@ -400,6 +407,7 @@ def build_exchange(entry: PlanEntry, num_shards: int,
                         num_shards=d, n_ids=n)
 
 
+@jax.named_scope("embed")
 def exchange_rows(local_table: jax.Array, ex: ExchangePlan,
                   axis_name: str) -> jax.Array:
     """Gather ``ex``'s uid rows from a row-sharded table: all_to_all the
@@ -426,6 +434,7 @@ def exchange_rows(local_table: jax.Array, ex: ExchangePlan,
     return full[:ex.n_ids]
 
 
+@jax.named_scope("embed")
 def owner_scatter_add(g_rows: jax.Array, entry: PlanEntry, num_shards: int,
                       axis_name: Optional[str]) -> tuple[jax.Array, jax.Array]:
     """Scatter per-uid cotangents into this shard's table space.

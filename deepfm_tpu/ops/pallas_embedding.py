@@ -175,6 +175,7 @@ def _take_call(kernel, inv: jax.Array, x: jax.Array, out_rows: int,
             vma=jax.typeof(inv).vma | jax.typeof(x).vma),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        name="embed_take",
     )(inv, x)
 
 

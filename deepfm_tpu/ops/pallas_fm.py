@@ -126,6 +126,7 @@ def _run_fwd(w, vals, xv, interpret: bool) -> jnp.ndarray:
         out_shape=jax.ShapeDtypeStruct((bp, 1), jnp.float32,
                                        vma=_vma(w, vals, xv)),
         interpret=interpret,
+        name="fm_fwd",
     )(w, vals, xv)
     return out[:b, 0]
 
@@ -159,6 +160,7 @@ def _run_bwd(g, w, vals, xv, interpret: bool):
             jax.ShapeDtypeStruct((bp, f, k), xv.dtype, vma=vma),
         ],
         interpret=interpret,
+        name="fm_bwd",
     )(g2, w, vals, xv)
     return dw[:b], dvals[:b], dxv[:b]
 

@@ -96,17 +96,20 @@ def _with_weight(batch: Dict[str, np.ndarray], bs: int) -> Dict[str, np.ndarray]
     return {**batch, "weight": w}
 
 
-def _staged_records(args) -> int:
-    """Record count of a staged transfer's host-side payload (batch dict or
-    list of batch dicts); 0 for layouts without a 'label' column (columnar
-    input-service rows) — the synthetic stall then leaves them alone."""
+def _staged_size(args) -> Tuple[int, int]:
+    """(records, bytes) of a staged transfer's host-side payload (batch dict
+    or list of batch dicts). Records count 0 for layouts without a 'label'
+    column (columnar input-service rows) — the synthetic stall then leaves
+    them alone."""
+    records = nbytes = 0
     for a in args:
-        if isinstance(a, dict) and "label" in a:
-            return int(a["label"].shape[0])
-        if isinstance(a, (list, tuple)) and a and isinstance(a[0], dict):
-            return sum(int(b["label"].shape[0]) for b in a
-                       if isinstance(b, dict) and "label" in b)
-    return 0
+        for b in ([a] if isinstance(a, dict) else
+                  a if isinstance(a, (list, tuple)) else ()):
+            if isinstance(b, dict):
+                nbytes += sum(getattr(v, "nbytes", 0) for v in b.values())
+                if "label" in b:
+                    records += int(b["label"].shape[0])
+    return records, nbytes
 
 
 class _StagingRing:
@@ -140,16 +143,20 @@ class _StagingRing:
         self.n_slots = max(int(n_slots), 1)
         self._fences: "queue.Queue[Any]" = queue.Queue()
         self._closed = threading.Event()
-        self._staged = 0
+        # Transfers begun / dispatches retired: the same superbatch gets the
+        # same number from both, the ``seq`` its spans share.
+        self.staged = 0
+        self.dispatched = 0
         self.transfer_s = 0.0
         self.wait_s = 0.0
         self._synth_ns = int(os.environ.get(self.SYNTH_TRANSFER_ENV, "0"))
 
-    def put(self, transfer: Callable[[], Any], n_records: int = 0) -> Any:
+    def put(self, transfer: Callable[[], Any], n_records: int = 0,
+            n_bytes: int = 0) -> Any:
         """Run one transfer under the slot discipline (staging thread)."""
-        self._staged += 1
-        if self._staged > self.n_slots:
-            with trace_lib.span("stage.wait", slot=self._staged):
+        self.staged += 1
+        if self.staged > self.n_slots:
+            with trace_lib.span("stage.wait", seq=self.staged):
                 t0 = time.time()
                 fence = None
                 # Poll against close so an abandoned fit (exception, early
@@ -163,7 +170,8 @@ class _StagingRing:
                 if fence is not None:
                     jax.block_until_ready(fence)
                 self.wait_s += time.time() - t0
-        with trace_lib.span("stage.transfer", records=n_records):
+        with trace_lib.span("stage.transfer", seq=self.staged,
+                            records=n_records, bytes=n_bytes):
             t0 = time.time()
             out = transfer()
             if self._synth_ns and n_records:
@@ -175,6 +183,7 @@ class _StagingRing:
         """Mark one dispatch's slot reusable once ``fence`` is ready
         (fit thread; the fence is any device value the dispatch produced)."""
         self._fences.put(fence)
+        self.dispatched += 1
 
     def close(self) -> None:
         self._closed.set()
@@ -339,14 +348,19 @@ class Trainer:
             step=P(), params=param_specs, opt_state=opt_specs,
             model_state=mstate_specs, rng=P())
 
+    def _state_shardings(self, state: TrainState) -> TrainState:
+        """The NamedSharding of every leaf of ``state`` (row-sharded
+        embeddings, replicated rest), real or abstract; needs a mesh."""
+        mi = self.mesh_info
+        return jax.tree.map(lambda _, s: mi.sharding(s), state,
+                            self._state_specs(state))
+
     def _place(self, state: TrainState) -> TrainState:
         """Apply NamedShardings (row-sharded embeddings, replicated rest)."""
-        mi = self.mesh_info
-        if mi.mesh is None:
+        if self.mesh_info.mesh is None:
             return jax.device_put(state)
-        specs = self._state_specs(state)
-        return jax.tree.map(
-            lambda x, s: jax.device_put(x, mi.sharding(s)), state, specs)
+        return jax.tree.map(jax.device_put, state,
+                            self._state_shardings(state))
 
     def put_batch(self, batch: Dict[str, np.ndarray]) -> Dict[str, jax.Array]:
         """Host numpy batch -> device array sharded over the data axis.
@@ -384,6 +398,13 @@ class Trainer:
         return jnp.stack(cols[:len(self._task_names)],
                          axis=1).astype(jnp.float32)
 
+    @jax.named_scope("loss")
+    def _mean_loss(self, logits, batch):
+        """Mean per-example loss of one (micro)batch, under the ``loss``
+        scope (TUNING §17)."""
+        return jnp.mean(self._per_example_loss(
+            logits, self._batch_labels(batch)))
+
     def _hist_kwargs(self, batch):
         """hist_ids/hist_mask forwarding for sequence models: only when the
         model opts in (``uses_history``) AND the batch carries the columns
@@ -400,8 +421,7 @@ class Trainer:
             params, model_state, batch["feat_ids"], batch["feat_vals"],
             train=train, rng=rng, shard_axis=shard_axis, data_axis=data_axis,
             **self._hist_kwargs(batch))
-        labels = self._batch_labels(batch)
-        xent = jnp.mean(self._per_example_loss(logits, labels))
+        xent = self._mean_loss(logits, batch)
         return logits, xent, new_mstate
 
     def _step_impl(self, state: TrainState, batch, *, data_axis, shard_axis
@@ -453,15 +473,23 @@ class Trainer:
         # Structural guarantee: padded_vocab pad rows never receive a
         # gradient (they are zero already — unreachable ids, masked l2 —
         # so this is bit-neutral; the regression test pins it).
-        grads = {**grads, **{
-            n: self.model.emb.mask_pad_grads(grads[n], axis_name=shard_axis)
-            for n in self._embed_names}}
-        updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("opt"):
+            grads = {**grads, **{
+                n: self.model.emb.mask_pad_grads(grads[n],
+                                                 axis_name=shard_axis)
+                for n in self._embed_names}}
+        new_params, new_opt = self._optax_apply(
+            grads, state.opt_state, state.params)
         new_state = state.replace(
             step=state.step + 1, params=new_params, opt_state=new_opt,
             model_state=new_mstate)
         return new_state, {"loss": xent + l2, "xent": xent}
+
+    @jax.named_scope("opt")
+    def _optax_apply(self, grads, opt_state, params):
+        """The optax update and its application: (new_params, new_opt)."""
+        updates, new_opt = self.tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), new_opt
 
     # -- sparse-plane helpers (fused vocab-space backward) --------------
     def _use_fused_backward(self) -> bool:
@@ -482,6 +510,7 @@ class Trainer:
                 and all(t.ndim in (1, 2) for t in tabs.values())
                 and heights.pop() <= pemb.PLAN_COUNT_MAX_ROWS)
 
+    @jax.named_scope("embed")
     def _fused_grad_ext(self, tabs, ids, g_views):
         """ONE table-shaped scatter-add for the whole embedding plane:
         column 0 accumulates an occupancy count (touch marks — exact
@@ -502,6 +531,28 @@ class Trainer:
         gext = jnp.zeros((rows, gcat.shape[1]), jnp.float32)
         return gext.at[flat].add(gcat)
 
+    @staticmethod
+    @jax.named_scope("l2")
+    def _touched_l2(tab, touched):
+        """0.5 * sum(x^2) over the touched rows of one table."""
+        sq = jnp.square(tab.astype(jnp.float32))
+        keep = touched.reshape(touched.shape + (1,) * (sq.ndim - 1))
+        return 0.5 * jnp.sum(jnp.where(keep, sq, jnp.zeros((), sq.dtype)))
+
+    @staticmethod
+    @jax.named_scope("opt")
+    def _lazy_decay(count, tau):
+        """(0.9, 0.999) ** (steps since each row's last touch), behind a
+        barrier. exp2 formulation: benches ~11x faster than jnp.power on
+        XLA:CPU (pow lowers to a libm call) at ~1 ULP from pow — inside the
+        tolerance already pinned for the masked sweep (sparse_adam_masked
+        doc)."""
+        idle = (count - tau).astype(jnp.float32)
+        return jax.lax.optimization_barrier(
+            (jnp.exp2(idle * np.float32(np.log2(0.9))),
+             jnp.exp2(idle * np.float32(np.log2(0.999)))))
+
+    @jax.named_scope("opt")
     def _fused_apply(self, state: TrainState, tabs, gext, count):
         """Masked lazy-Adam sweep per name over the gradient columns of
         ``gext`` (+ the touched-rows-only L2 term, added here exactly as
@@ -517,14 +568,8 @@ class Trainer:
         # tau is identical across tables (same touched set every step), so
         # the lazy-decay pows — the sweep's hot spot — are computed once
         # and shared by every table (see sparse_adam_masked's decay note).
-        # exp2 formulation: benches ~11x faster than jnp.power on XLA:CPU
-        # (pow lowers to a libm call) at ~1 ULP from pow — inside the
-        # tolerance already pinned for this leg (sparse_adam_masked doc).
-        tau = opt_embed[self._embed_names[0]][emb.MONO].tau
-        idle = (count - tau).astype(jnp.float32)
-        decay = jax.lax.optimization_barrier(
-            (jnp.exp2(idle * np.float32(np.log2(0.9))),
-             jnp.exp2(idle * np.float32(np.log2(0.999)))))
+        decay = self._lazy_decay(
+            count, opt_embed[self._embed_names[0]][emb.MONO].tau)
         o = 1
         for name in self._embed_names:
             tab = tabs[name]
@@ -539,12 +584,10 @@ class Trainer:
             new_params_embed[name] = new_tab
             new_embed[name] = {emb.MONO: new_oe}
             if l2_reg:
-                sq = jnp.square(tab.astype(jnp.float32))
-                keep = touched.reshape(touched.shape + (1,) * (sq.ndim - 1))
-                l2 = l2 + 0.5 * jnp.sum(
-                    jnp.where(keep, sq, jnp.zeros((), sq.dtype)))
+                l2 = l2 + self._touched_l2(tab, touched)
         return new_params_embed, new_embed, l2_reg * l2
 
+    @jax.named_scope("opt")
     def _sparse_apply(self, state: TrainState, plan, rows0, g_rows, count):
         """Lazy-Adam apply + writeback for every (name, table): returns
         ({name: new_entry_params}, {name: new_opt_tables}).
@@ -606,8 +649,9 @@ class Trainer:
 
         if fused:
             ids = batch["feat_ids"]
-            views0 = {n: jnp.take(tabs[n], ids, axis=0)
-                      for n in self._embed_names}
+            with jax.named_scope("embed"):
+                views0 = {n: jnp.take(tabs[n], ids, axis=0)
+                          for n in self._embed_names}
 
             def loss_fn(diff):
                 views, rest = diff
@@ -618,8 +662,7 @@ class Trainer:
                     shard_axis=None, data_axis=None,
                     emb_rows={n: {emb.MONO: views[n]}
                               for n in self._embed_names}, emb_plan=None)
-                labels = self._batch_labels(batch)
-                xent = jnp.mean(self._per_example_loss(logits, labels))
+                xent = self._mean_loss(logits, batch)
                 return xent, (xent, new_mstate)
 
             (_, (xent, new_mstate)), (g_views, g_rest) = (
@@ -638,8 +681,7 @@ class Trainer:
                     batch["feat_vals"], train=True, rng=rng,
                     shard_axis=None, data_axis=None,
                     emb_rows=rows, emb_plan=plan)
-                labels = self._batch_labels(batch)
-                xent = jnp.mean(self._per_example_loss(logits, labels))
+                xent = self._mean_loss(logits, batch)
                 # Touched-rows-only L2 (deliberate deviation from dense L2
                 # — idle rows do not decay between touches; TUNING §2.11).
                 l2 = self.model.l2_loss(params, emb_rows=rows, emb_plan=plan)
@@ -649,8 +691,7 @@ class Trainer:
                 jax.value_and_grad(loss_fn, has_aux=True)((rows0, rest0)))
 
         opt = state.opt_state
-        upd_rest, new_base = self.tx.update(g_rest, opt["base"], rest0)
-        new_rest = optax.apply_updates(rest0, upd_rest)
+        new_rest, new_base = self._optax_apply(g_rest, opt["base"], rest0)
         count = opt["count"] + 1
         new_params = dict(new_rest)
         if fused:
@@ -736,8 +777,7 @@ class Trainer:
                 batch["feat_vals"], train=True, rng=rng,
                 shard_axis=None, data_axis=data_axis,
                 emb_rows=rows, emb_plan=plan, **self._hist_kwargs(batch))
-            labels = self._batch_labels(batch)
-            xent = jnp.mean(self._per_example_loss(logits, labels))
+            xent = self._mean_loss(logits, batch)
             return xent, (xent, new_mstate)
 
         (_, (xent, new_mstate)), (g_rows, g_rest) = (
@@ -751,8 +791,7 @@ class Trainer:
             xent = jax.lax.pmean(xent, data_axis)
 
         opt = state.opt_state
-        upd_rest, new_base = self.tx.update(g_rest, opt["base"], rest0)
-        new_rest = optax.apply_updates(rest0, upd_rest)
+        new_rest, new_base = self._optax_apply(g_rest, opt["base"], rest0)
         count = opt["count"] + 1
         opt_embed = opt["embed"]
         l2_reg = self.cfg.l2_reg
@@ -775,14 +814,10 @@ class Trainer:
                     lambda g: jax.lax.pmean(g, data_axis), grads)
                 touched = jax.lax.psum(
                     touched.astype(jnp.int32), data_axis) > 0
-            # Shared lazy-decay pair per physical table (tau is identical
-            # across names — same touched set every step); exp2 form and
-            # barrier exactly as in _fused_apply.
-            tau = opt_embed[self._embed_names[0]][key].tau
-            idle = (count - tau).astype(jnp.float32)
-            decay = jax.lax.optimization_barrier(
-                (jnp.exp2(idle * np.float32(np.log2(0.9))),
-                 jnp.exp2(idle * np.float32(np.log2(0.999)))))
+            # Shared per physical table: tau is identical across names
+            # (same touched set every step).
+            decay = self._lazy_decay(
+                count, opt_embed[self._embed_names[0]][key].tau)
             for name in self._embed_names:
                 tab = emb.tables(tabs[name])[key]
                 g_eff = grads[name]
@@ -794,11 +829,7 @@ class Trainer:
                 new_tabs[name][key] = new_tab
                 new_embed[name][key] = new_oe
                 if l2_reg:
-                    sq = jnp.square(tab.astype(jnp.float32))
-                    keep = touched.reshape(
-                        touched.shape + (1,) * (sq.ndim - 1))
-                    l2 = l2 + 0.5 * jnp.sum(
-                        jnp.where(keep, sq, jnp.zeros((), sq.dtype)))
+                    l2 = l2 + self._touched_l2(tab, touched)
         l2 = l2_reg * l2
         if l2_reg and shard_axis is not None:
             # Per-shard partials -> the full-table touched-L2 scalar.
@@ -845,8 +876,7 @@ class Trainer:
                     params, mstate, batch["feat_ids"], batch["feat_vals"],
                     train=True, rng=rng, shard_axis=shard_axis,
                     data_axis=data_axis, **self._hist_kwargs(batch))
-                labels = self._batch_labels(batch)
-                xent = jnp.mean(self._per_example_loss(logits, labels))
+                xent = self._mean_loss(logits, batch)
                 return (new_mstate, xent_sum + xent), None
 
             (new_mstate, xent_sum), _ = jax.lax.scan(
@@ -869,11 +899,13 @@ class Trainer:
                 grads, data_axis, self._hier_groups,
                 self.mesh_info.data_size)
             xent = jax.lax.pmean(xent, data_axis)  # metrics only
-        grads = {**grads, **{
-            n: self.model.emb.mask_pad_grads(grads[n], axis_name=shard_axis)
-            for n in self._embed_names}}
-        updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("opt"):
+            grads = {**grads, **{
+                n: self.model.emb.mask_pad_grads(grads[n],
+                                                 axis_name=shard_axis)
+                for n in self._embed_names}}
+        new_params, new_opt = self._optax_apply(
+            grads, state.opt_state, state.params)
         new_state = state.replace(
             step=state.step + a, params=new_params, opt_state=new_opt,
             model_state=new_mstate)
@@ -908,8 +940,9 @@ class Trainer:
             # into ONE table-shaped scatter-add below (group-position
             # order == the merged plan's segment-sum order, bit-for-bit).
             ids = batches["feat_ids"]
-            views0 = {n: jnp.take(tabs[n], ids, axis=0)
-                      for n in self._embed_names}
+            with jax.named_scope("embed"):
+                views0 = {n: jnp.take(tabs[n], ids, axis=0)
+                          for n in self._embed_names}
 
             def loss_fn(diff):
                 views, rest = diff
@@ -926,8 +959,7 @@ class Trainer:
                         emb_rows={n: {emb.MONO: views_i[n]}
                                   for n in self._embed_names},
                         emb_plan=None)
-                    labels = self._batch_labels(batch)
-                    xent = jnp.mean(self._per_example_loss(logits, labels))
+                    xent = self._mean_loss(logits, batch)
                     return (new_mstate, xent_sum + xent), None
 
                 (new_mstate, xent_sum), _ = jax.lax.scan(
@@ -968,8 +1000,7 @@ class Trainer:
                         batch["feat_vals"], train=True, rng=rng,
                         shard_axis=None, data_axis=None,
                         emb_rows=rows, emb_plan=plan_i)
-                    labels = self._batch_labels(batch)
-                    xent = jnp.mean(self._per_example_loss(logits, labels))
+                    xent = self._mean_loss(logits, batch)
                     return (new_mstate, xent_sum + xent), None
 
                 (new_mstate, xent_sum), _ = jax.lax.scan(
@@ -983,8 +1014,7 @@ class Trainer:
                 jax.value_and_grad(loss_fn, has_aux=True)((rows0, rest0)))
 
         opt = state.opt_state
-        upd_rest, new_base = self.tx.update(g_rest, opt["base"], rest0)
-        new_rest = optax.apply_updates(rest0, upd_rest)
+        new_rest, new_base = self._optax_apply(g_rest, opt["base"], rest0)
         count = opt["count"] + 1
         new_params = dict(new_rest)
         if fused:
@@ -1095,6 +1125,42 @@ class Trainer:
             self._multi_step = self._make_train_multi_step()
         return self._multi_step
 
+    def step_hlo_text(self) -> str:
+        """The compiled K-step dispatch (``steps_per_loop`` steps of
+        ``batch_size``) as optimized HLO text, from abstract arguments laid
+        out as ``_place`` and ``_put_stacked`` lay out the real ones. This
+        compiles the program a fit runs once more; the compiler is
+        deterministic, so the instructions are the fit's. Where the
+        persistent cache answers instead (its key leaves debug info out),
+        an executable cached before a scope was renamed comes back with its
+        old ``op_name``s (TUNING §17)."""
+        cfg = self.cfg
+        state = jax.eval_shape(self._abstract_state_for_specs)
+        hl = (cfg.history_max_len
+              if getattr(self.model, "uses_history", False) else 0)
+        batch = zero_batch(cfg.field_size, cfg.batch_size,
+                           len(self._task_names), hl)
+        k = max(cfg.steps_per_loop, 1)
+        batches = {key: jax.ShapeDtypeStruct((k,) + v.shape, v.dtype)
+                   for key, v in batch.items()}
+        if self.mesh_info.mesh is not None:
+            state = jax.tree.map(
+                lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                  sharding=s),
+                state, self._state_shardings(state))
+            batches = {key: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=self._stacked_sharding(x.ndim))
+                for key, x in batches.items()}
+        return self.multi_step.lower(state, batches).compile().as_text()
+
+    def step_op_scopes(self) -> Dict[str, str]:
+        """{HLO instruction name: named scope, "" for none} of the compiled
+        K-step dispatch, for charging a device trace's ops to the step's
+        phases: a profiler event carries its instruction's text and nothing
+        of the ``op_name`` the scopes are in (``profiling.hlo_op_scopes``
+        over ``step_hlo_text``, which costs a compilation)."""
+        return prof_lib.hlo_op_scopes(self.step_hlo_text())
+
     def put_superbatch(self, batches) -> Dict[str, jax.Array]:
         """Stack K host batches into [K, B, ...] arrays and transfer in one
         host->device move (batch dim sharded over 'data', K replicated)."""
@@ -1112,15 +1178,18 @@ class Trainer:
                    for key, v in rows.items()}
         return self._put_stacked(stacked)
 
+    def _stacked_sharding(self, ndim: int):
+        """[K, B, ...]: K replicated, the batch dim over 'data'."""
+        return self.mesh_info.sharding(
+            P(None, mesh_lib.DATA_AXIS, *([None] * (ndim - 2))))
+
     def _put_stacked(self, stacked: Dict[str, np.ndarray]
                      ) -> Dict[str, jax.Array]:
-        mi = self.mesh_info
-        if mi.mesh is None:
+        if self.mesh_info.mesh is None:
             return jax.device_put(stacked)
         return jax.tree.map(
             lambda x: jax.make_array_from_process_local_data(
-                mi.sharding(
-                    P(None, mesh_lib.DATA_AXIS, *([None] * (x.ndim - 2)))), x),
+                self._stacked_sharding(x.ndim), x),
             stacked)
 
     def _eval_update(self, state: TrainState, batch, acc, *, data_axis,
@@ -1367,7 +1436,28 @@ class Trainer:
         ring = self._ring
         if ring is None:
             return put(*args)
-        return ring.put(lambda: put(*args), _staged_records(args))
+        return ring.put(lambda: put(*args), *_staged_size(args))
+
+    def _pull(self, source: Iterable) -> Iterator:
+        """``source``'s items, each ``next()`` under a ``stage.input_wait``
+        span on the staging thread: what the trainer waits for its input.
+        ``seq`` is the superbatch the item goes into."""
+        it = iter(source)
+        try:
+            while True:
+                ring = self._ring
+                with trace_lib.span(
+                        "stage.input_wait",
+                        seq=ring.staged + 1 if ring is not None else 0):
+                    try:
+                        item = next(it)
+                    except StopIteration:
+                        return
+                yield item
+        finally:
+            close = getattr(it, "close", None)
+            if close is not None:
+                close()
 
     def _grad_payload_bytes(self) -> int:
         """Analytic per-device payload of ONE gradient reduce over 'data'
@@ -1398,7 +1488,7 @@ class Trainer:
 
         def gen():
             if sb_iter is not None and k > 1:
-                for rows, m, n_ex in sb_iter(k):
+                for rows, m, n_ex in self._pull(sb_iter(k)):
                     if m == 1:
                         yield self._staged_put(self.put_batch, rows), 1, n_ex
                     else:
@@ -1406,7 +1496,7 @@ class Trainer:
                             self.put_superbatch_rows, rows, m), m, n_ex
                 return
             group = []
-            for b in batches:
+            for b in self._pull(batches):
                 group.append(b)
                 if len(group) == k:
                     n_ex = sum(g["label"].shape[0] for g in group)
@@ -1447,7 +1537,7 @@ class Trainer:
 
         def gen():
             group = []
-            for b in batches:
+            for b in self._pull(batches):
                 group.append(b)
                 if len(group) == k:
                     yield stage_group(group)
@@ -1486,7 +1576,7 @@ class Trainer:
         import itertools  # noqa: PLC0415
 
         def gen():
-            it = iter(batches)
+            it = self._pull(batches)
             try:
                 while True:
                     group = list(itertools.islice(it, k))
@@ -1674,8 +1764,9 @@ class Trainer:
                     # Donation is off under skip (see __init__), so the
                     # pre-dispatch state stays valid for a dropped update.
                     prev_state, prev_m = state, m
-                with trace_lib.span("train.dispatch", steps=steps_done,
-                                    examples=local_ex):
+                with trace_lib.span("train.dispatch",
+                                    seq=ring.dispatched + 1,
+                                    steps=steps_done, examples=local_ex):
                     if steps_done == 1:
                         state, m = self.train_step(state, dev_batch)
                     else:
@@ -1706,8 +1797,10 @@ class Trainer:
                     watchdog.beat(n_steps)
                 if cfg.log_steps and (n_steps // cfg.log_steps
                                       > prev_steps // cfg.log_steps):
-                    loss = float(m["loss"])  # device sync, bounded by log cadence
-                    gstep = int(state.step)
+                    with trace_lib.span("train.log_sync", step=n_steps):
+                        # device sync, bounded by the log cadence
+                        loss = float(m["loss"])
+                        gstep = int(state.step)
                     last_loss = loss
                     if guard is not None and not guard_active:
                         # abort policy: reuse the loss scalar this log line

@@ -129,6 +129,7 @@ def embed_adam_init(table: jax.Array) -> EmbedAdamEntry:
     )
 
 
+@jax.named_scope("opt")
 def sparse_adam_rows(
     rows0: jax.Array,      # f32 [U, ...] touched rows (pre-update values)
     g_rows: jax.Array,     # f32 [U, ...] summed per-row gradient
@@ -157,6 +158,7 @@ def sparse_adam_rows(
     return new_rows.astype(rows0.dtype), m, v
 
 
+@jax.named_scope("opt")
 def sparse_adam_masked(
     table: jax.Array,      # f32 [R, ...] full table (pre-update values)
     g_rows: jax.Array,     # f32 [R, ...] summed per-row gradient (junk on
@@ -216,6 +218,7 @@ def sparse_adam_masked(
     return new_table, new_oe
 
 
+@jax.named_scope("opt")
 def sparse_apply_rows(
     rows0: jax.Array,            # f32 [U, ...] touched rows (pre-update)
     g_rows: jax.Array,           # f32 [U, ...] summed per-row gradient

@@ -18,6 +18,7 @@ Usage:
 from __future__ import annotations
 
 import contextlib
+import re
 import time
 from typing import Dict, Iterator, List, Optional
 
@@ -78,12 +79,42 @@ class StepWindowTracer:
             self._done = True
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named region in the profiler timeline (TraceAnnotation)."""
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield
+#: The ``jax.named_scope`` names of the train step's phases (TUNING §17):
+#: every HLO instruction's ``op_name`` carries the scopes it was traced under,
+#: forward and backward (``transpose(jvp(embed))``).
+STEP_SCOPES = ("embed", "fm", "tower", "loss", "l2", "opt")
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
+_SCOPE_COMPONENT = re.compile(r"^(?:\w+\()*(\w+)\)*$")
+
+
+def innermost_scope(op_name: str, scopes=STEP_SCOPES) -> str:
+    """The last of ``scopes`` on an ``op_name`` path such as
+    ``jit(multi)/while/body/transpose(jvp(opt))/l2/reduce_sum`` (here
+    ``l2``); the path's last component is the primitive and is not looked
+    at. Empty when the path crosses none of them."""
+    found = ""
+    for part in op_name.split("/")[:-1]:
+        m = _SCOPE_COMPONENT.match(part)
+        if m and m.group(1) in scopes:
+            found = m.group(1)
+    return found
+
+
+def hlo_op_scopes(hlo_text: str, scopes=STEP_SCOPES) -> Dict[str, str]:
+    """{instruction name: innermost scope, or "" for none} over every
+    instruction of a compiled program's text (``Compiled.as_text()``). A
+    fusion carries its root's ``op_name``, so it belongs to its root's
+    scope."""
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            op_name = _HLO_OP_NAME.search(line)
+            out[m.group(1)] = (innermost_scope(op_name.group(1), scopes)
+                               if op_name else "")
+    return out
 
 
 class HostStageStats:
@@ -91,10 +122,11 @@ class HostStageStats:
 
     The pipeline brackets each stage of its hot loop with ``stage(name)``
     (``read`` — stream bytes in; ``frame`` — split TFRecord frames;
-    ``decode_assemble`` — proto decode scattered into the transfer-layout
-    pool; ``emit`` — slice/stack batches off the pool) when a collector is
+    ``pool_drain`` — proto decode scattered into the transfer-layout pool;
+    ``emit`` — slice/stack batches off the pool) when a collector is
     attached via ``CtrPipeline.stage_stats``; detached (the default) every
-    site is a no-op. All stages run on the pipeline generator's thread —
+    site is an ``input.<stage>`` span of ``obs.trace`` instead. All stages
+    run on the pipeline generator's thread —
     even when the decode fans out to a reader pool, the bracket measures
     the generator's wall wait — so the numbers add up to (most of) the
     observed ns/record and the remainder is attributable Python glue.

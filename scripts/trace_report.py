@@ -10,8 +10,25 @@ pair by id and aggregate the same way (self == total: they have no
 nesting). Ring-buffer drops recorded at export time are surfaced, never
 hidden: a wrapped ring means the totals undercount.
 
+``--stalls MS`` answers "what was the host doing when the trainer fell
+behind". Its clock is the END of each ``stage.wait`` span: there the staging
+thread sees a dispatch complete on the device (it fences on dispatch j-2
+before transfer j), so the ends are one dispatch apart while the device sets
+the pace, and further apart when it was starved. (``train.dispatch`` starts
+will not do: the fit thread reads the loss back every ``log_steps`` steps
+and enqueues in bunches.) Every interval longer than MS is printed with the
+time each of the spans that can hold a dispatch back covered of it
+(``STALL_SPANS``: ``stage.input_wait`` — the staging thread waiting for the
+input thread; ``input.pool_fill`` / ``input.pool_drain`` / ``input.emit`` —
+the input thread reading+framing, permuting+decoding, slicing; ``host.gc`` —
+a collection, which stops every thread; ``train.log_sync`` — the loss read
+back at the log cadence; ``stage.transfer`` — the host->device copy; and
+``stage.wait`` itself, the part the device was simply busy). TUNING §17
+lists every span.
+
 Usage:
     python scripts/trace_report.py TRACE.json [--top 20] [--json]
+                                              [--stalls MS]
 """
 
 import argparse
@@ -116,6 +133,42 @@ def summarize(events):
     return rows, dict(instants), unmatched
 
 
+#: Spans that can hold a dispatch back, in the order they are reported.
+STALL_SPANS = ("stage.input_wait", "input.pool_fill", "input.pool_drain",
+               "input.emit", "host.gc", "train.log_sync", "stage.transfer",
+               "stage.wait")
+
+
+def stalls(events, threshold_ms):
+    """Intervals between the ends of consecutive ``stage.wait`` spans (one
+    process) over ``threshold_ms``: one dict each with the ``seq`` of the
+    transfer that waited last, the interval and, per ``STALL_SPANS`` name,
+    the milliseconds of the interval that spans of that name cover (summed
+    over threads, so two busy threads can cover more than the interval)."""
+    spans = [e for e in events if e.get("ph") == "X"]
+    by_pid = collections.defaultdict(list)    # pid -> [(end, stage.wait)]
+    for e in spans:
+        if e["name"] == "stage.wait":
+            by_pid[e.get("pid")].append((float(e["ts"]) + float(e["dur"]), e))
+    out = []
+    for pid, waits in by_pid.items():
+        waits.sort(key=lambda w: w[0])
+        for (a, _), (b, wait) in zip(waits, waits[1:]):
+            if b - a <= threshold_ms * 1e3:
+                continue
+            cover = dict.fromkeys(STALL_SPANS, 0.0)
+            for e in spans:
+                if e["name"] in cover and e.get("pid") == pid:
+                    t0 = float(e["ts"])
+                    ov = min(t0 + float(e.get("dur", 0.0)), b) - max(t0, a)
+                    if ov > 0:
+                        cover[e["name"]] += ov / 1e3
+            out.append({"seq": wait.get("args", {}).get("seq"),
+                        "at_ms": (a - waits[0][0]) / 1e3,
+                        "interval_ms": (b - a) / 1e3, "cover_ms": cover})
+    return out
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("trace", help="trace-<pid>.json or a merged trace file")
@@ -123,17 +176,24 @@ def main(argv=None):
                     help="rows to print, by self time (default 20)")
     ap.add_argument("--json", action="store_true",
                     help="machine-readable output instead of the table")
+    ap.add_argument("--stalls", type=float, default=None, metavar="MS",
+                    help="also list dispatch intervals longer than MS with "
+                         "the spans that cover them")
     args = ap.parse_args(argv)
 
     events, other = _load(args.trace)
     rows, instants, unmatched = summarize(events)
     dropped = int(other.get("dropped_spans", 0))
+    slow = stalls(events, args.stalls) if args.stalls is not None else None
 
     if args.json:
-        print(json.dumps({
+        doc = {
             "spans": rows[:args.top], "instants": instants,
             "unmatched_async": unmatched, "dropped_spans": dropped,
-            "events": len(events), "other": other}, indent=2))
+            "events": len(events), "other": other}
+        if slow is not None:
+            doc["stalls"] = slow
+        print(json.dumps(doc, indent=2))
         return 0
 
     print(f"{len(events)} events"
@@ -153,6 +213,14 @@ def main(argv=None):
               f"{r['p50_ms']:>9.3f}{r['p99_ms']:>9.3f}")
     for name, n in sorted(instants.items()):
         print(f"instant {name}: {n}")
+    for st in slow or ():
+        cover = ", ".join(f"{k} {v:.1f}" for k, v in st["cover_ms"].items()
+                          if v > 0)
+        print(f"stall before transfer seq={st['seq']} at "
+              f"{st['at_ms'] / 1e3:.2f} s: {st['interval_ms']:.1f} ms; "
+              f"covered (ms): {cover or 'by no known span'}")
+    if slow is not None:
+        print(f"{len(slow)} dispatch intervals over {args.stalls:g} ms")
     return 0
 
 

@@ -243,6 +243,34 @@ class TestTraceReport:
         assert trace_report._pct(durs, 99) == 99.0
         assert trace_report._pct([], 50) is None
 
+    def test_stalls_names_what_covers_a_long_dispatch_interval(self):
+        def x(name, ts_ms, dur_ms, tid=1, **args):
+            return {"name": name, "ph": "X", "ts": ts_ms * 1e3,
+                    "dur": dur_ms * 1e3, "pid": 1, "tid": tid, "args": args}
+        events = [
+            x("stage.wait", 10, 490, seq=3),              # ends at 500
+            x("stage.wait", 510, 480, seq=4),             # ends at 990
+            x("stage.input_wait", 1000, 2000, seq=5),     # the input is late
+            x("input.pool_drain", 1100, 1500, tid=3),
+            x("host.gc", 1200, 900, tid=3),
+            x("stage.wait", 3000, 1, seq=5),              # ends at 3001
+            x("input.pool_drain", 3050, 100, tid=3),      # after the stall
+            x("stage.wait", 3010, 481, seq=6),            # ends at 3491
+            # the fit thread enqueues in bunches: not the clock
+            x("train.dispatch", 0, 1, seq=1),
+            x("train.dispatch", 2000, 1, seq=2),
+        ]
+        (st,) = trace_report.stalls(events, 550)
+        assert st["seq"] == 5 and st["interval_ms"] == 2011.0
+        assert st["at_ms"] == 490.0
+        cover = st["cover_ms"]
+        assert cover["stage.input_wait"] == 2000.0
+        assert cover["input.pool_drain"] == 1500.0
+        assert cover["host.gc"] == 900.0
+        assert cover["stage.wait"] == 1.0
+        assert cover["train.log_sync"] == 0.0
+        assert trace_report.stalls(events, 3000) == []
+
     def test_cli_json_roundtrip(self, tmp_path, capsys):
         trace_lib.configure("full", trace_dir=str(tmp_path),
                             export_env=False)
@@ -254,7 +282,8 @@ class TestTraceReport:
         assert [r["name"] for r in doc["spans"]] == ["cli.span"]
         assert doc["dropped_spans"] == 0
         # Table mode on the same file also runs clean.
-        assert trace_report.main([path]) == 0
+        assert trace_report.main([path, "--stalls", "550"]) == 0
+        assert "0 dispatch intervals over 550 ms" in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------------

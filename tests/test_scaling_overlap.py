@@ -29,7 +29,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deepfm_tpu.config import Config
 from deepfm_tpu.parallel import mesh as mesh_lib
 from deepfm_tpu.train import Trainer
-from deepfm_tpu.train.loop import _StagingRing, _staged_records
+from deepfm_tpu.train.loop import _StagingRing, _staged_size
 
 # 2x2 virtual topology over the first 4 of conftest's 8 devices: rows
 # {0,1} and {2,3} play "hosts", stage 2 reduces one representative per
@@ -203,11 +203,12 @@ class TestDoubleBufferedStaging:
         # An untouched ring reports full overlap (nothing ever fenced).
         assert _StagingRing(1).overlap_fraction() == 1.0
 
-    def test_staged_records(self):
+    def test_staged_size(self):
         b = _batches(1, 16)[0]
-        assert _staged_records((b,)) == 16
-        assert _staged_records(([b, b],)) == 32
-        assert _staged_records((np.zeros(3), 2)) == 0
+        nbytes = sum(v.nbytes for v in b.values())
+        assert _staged_size((b,)) == (16, nbytes)
+        assert _staged_size(([b, b],)) == (32, 2 * nbytes)
+        assert _staged_size((np.zeros(3), 2)) == (0, 0)
 
 
 class TestHierarchicalReduction:

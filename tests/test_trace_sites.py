@@ -1,0 +1,215 @@
+"""The instrumentation sites of the train path: the named scopes that name
+the step's phases in the compiled program, and the spans of the input
+thread, the staging ring, the dispatch loop and the collector (TUNING §17).
+"""
+
+import faulthandler
+import gc
+import re
+import threading
+
+import numpy as np
+import pytest
+
+from deepfm_tpu.config import Config
+from deepfm_tpu.data import libsvm, pipeline
+from deepfm_tpu.obs import trace as trace_lib
+from deepfm_tpu.train import Trainer
+from deepfm_tpu.utils import profiling
+
+SCOPES = ("embed", "fm", "tower", "loss", "l2", "opt")
+K = 2
+
+
+@pytest.fixture(autouse=True)
+def _clean_tracer():
+    trace_lib.reset()
+    yield
+    trace_lib.reset()
+
+
+def _cfg(**over):
+    base = dict(
+        feature_size=200, field_size=4, embedding_size=4, deep_layers="8",
+        dropout="0.5", batch_size=32, compute_dtype="float32", l2_reg=1e-4,
+        learning_rate=0.01, log_steps=2, seed=7, scale_lr_by_world=False,
+        mesh_data=1, mesh_model=1, steps_per_loop=K)
+    base.update(over)
+    return Config(**base)
+
+
+def _batches(n, bs=32, fields=4, vocab=200):
+    rng = np.random.default_rng(3)
+    return [{
+        "label": rng.integers(0, 2, (bs, 1)).astype(np.float32),
+        "feat_ids": rng.integers(0, vocab, (bs, fields)).astype(np.int32),
+        "feat_vals": rng.standard_normal((bs, fields)).astype(np.float32),
+    } for _ in range(n)]
+
+
+# ---------------------------------------------------------------------------
+# (a) named scopes reach the lowered step
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("update,kernels", [
+    ("dense", "auto"), ("sparse", "auto"), ("sparse", "off")],
+    ids=["dense", "sparse-fused", "sparse-plan"])
+def test_compiled_multi_step_names_every_phase(update, kernels):
+    tr = Trainer(_cfg(embedding_update=update, embedding_kernels=kernels))
+    lowered = tr.multi_step.lower(tr.init_state(),
+                                  tr.put_superbatch(_batches(K)))
+    # The scan's body is lowered as a function of its own, so the whole
+    # path of an op is only put together in the compiled program's text.
+    ops = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    assert {profiling.innermost_scope(o) for o in ops} >= set(SCOPES)
+    # The backward pass keeps the name: the gather's transpose is the
+    # scatter-add into the table-shaped (dense) or row-shaped gradient. The
+    # fused sparse step differentiates the gathered views, and scatters
+    # their cotangents itself.
+    backward = {"transpose(jvp(tower))", "transpose(jvp(fm))"}
+    if kernels != "auto" or update == "dense":
+        backward.add("transpose(jvp(embed))")
+    for name in backward:
+        assert any(f"/{name}/" in o for o in ops), name
+    # What the benchmark's reader is handed: instruction -> scope.
+    by_op = tr.step_op_scopes()
+    assert set(by_op.values()) == set(SCOPES) | {""}
+    assert all(re.fullmatch(r"[\w.\-]+", name) for name in by_op)
+
+
+def test_innermost_scope():
+    f = profiling.innermost_scope
+    assert f("jit(multi)/jit(main)/while/body/transpose(jvp(embed))/"
+             "scatter-add") == "embed"
+    assert f("jit(multi)/while/body/opt/l2/reduce_sum") == "l2"
+    assert f("jit(multi)/while/body/jvp(tower)/tower/dot_general") == "tower"
+    assert f("jit(multi)/while/body/add") == ""
+    assert f("jit(multi)/while/body/jvp(opt)") == ""      # the primitive
+    assert f("jit(embedding)/fm_extra/mul") == ""
+
+
+# ---------------------------------------------------------------------------
+# (b) the in-process input thread
+# ---------------------------------------------------------------------------
+
+def _pipeline(tmp_path, epochs=2):
+    files = libsvm.generate_synthetic_ctr(
+        str(tmp_path), num_files=2, examples_per_file=96, feature_size=200,
+        field_size=4, prefix="tr", seed=1)
+    return pipeline.CtrPipeline(
+        files, field_size=4, batch_size=16, num_epochs=epochs,
+        shuffle_buffer=64, seed=5, prefetch_batches=0)
+
+
+def test_pooled_pipeline_emits_input_spans(tmp_path):
+    trace_lib.configure("full", export_env=False)
+    n = sum(n_ex for _, _, n_ex in _pipeline(tmp_path).iter_superbatches(K))
+    assert n == 2 * 192
+    events = trace_lib._tracer.events()
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    epochs = by["input.epoch"]
+    assert [e["args"]["epoch"] for e in epochs] == [0, 1]
+    assert all(e["ph"] == "i" and e["args"]["files"] == 2 for e in epochs)
+    for name in ("input.pool_fill", "input.pool_drain", "input.emit",
+                 "input.read", "input.frame"):
+        assert by[name] and all(e["ph"] == "X" for e in by[name]), name
+    # Every record is filled once, drained at least once, emitted once.
+    for epoch in (0, 1):
+        filled = sum(e["args"]["records"] for e in by["input.pool_fill"]
+                     if e["args"]["epoch"] == epoch)
+        assert filled == 192
+    assert sum(e["args"]["records"] for e in by["input.emit"]) == n
+    assert all(e["args"]["records"] > 0 for e in by["input.pool_drain"])
+
+
+def test_pooled_pipeline_is_silent_when_off(tmp_path, monkeypatch):
+    built = []
+    monkeypatch.setattr(trace_lib, "_Span",
+                        lambda *a, **k: built.append(a) or pytest.fail())
+    list(_pipeline(tmp_path).iter_superbatches(K))
+    assert trace_lib._tracer.events() == [] and not built
+
+
+# ---------------------------------------------------------------------------
+# (c) the collector
+# ---------------------------------------------------------------------------
+
+def test_host_gc_span_and_hook_lifetime():
+    before = len(gc.callbacks)
+    trace_lib.configure("full", export_env=False)
+    assert len(gc.callbacks) == before + 1
+    trace_lib.configure("ring", export_env=False)     # not installed twice
+    assert len(gc.callbacks) == before + 1
+    gc.collect()
+    ev = [e for e in trace_lib._tracer.events() if e["name"] == "host.gc"]
+    assert ev and ev[-1]["ph"] == "X"
+    assert ev[-1]["args"]["generation"] == 2
+    assert ev[-1]["args"]["collected"] >= 0
+    trace_lib.reset()
+    assert len(gc.callbacks) == before
+    trace_lib.configure("off", export_env=False)
+    assert len(gc.callbacks) == before
+
+
+@pytest.mark.parametrize("mode", ["full", "ring"])
+def test_a_collection_inside_the_tracer_does_not_hang_it(mode, tmp_path):
+    """With a threshold of 1 the collector fires wherever the tracer
+    allocates, under its own lock too (``events()`` copies the buffer
+    there): the ``host.gc`` hook must never wait for that lock."""
+    trace_lib.configure(mode, capacity=64, export_env=False)
+    done = []
+
+    def work():
+        for i in range(300):
+            with trace_lib.span("w", i=i):
+                trace_lib.instant("p", held=[[i]])
+            trace_lib._tracer.events()
+        trace_lib.export(str(tmp_path / "t.json"))
+        done.append(len(trace_lib._tracer.events()))
+
+    old = gc.get_threshold()
+    gc.set_threshold(1)
+    try:
+        t = threading.Thread(target=work, daemon=True)
+        t.start()
+        t.join(30)
+    finally:
+        gc.set_threshold(*old)
+    if t.is_alive():
+        faulthandler.dump_traceback(all_threads=True)
+        pytest.fail("the tracer hangs when a collection starts inside it")
+    names = {e["name"] for e in trace_lib._tracer.events()}
+    assert done and {"w", "p", "host.gc"} <= names
+    if mode == "ring":
+        assert done[0] <= 2 * 64 and trace_lib.dropped() > 0
+
+
+# ---------------------------------------------------------------------------
+# (d) one superbatch, one seq
+# ---------------------------------------------------------------------------
+
+def test_fit_spans_share_seq():
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(_cfg())
+    n_dispatch = 4
+    tr.fit(tr.init_state(), _batches(K * n_dispatch))
+    events = [e for e in trace_lib._tracer.events() if e["ph"] == "X"]
+    seqs = {name: [e["args"]["seq"] for e in events if e["name"] == name]
+            for name in ("stage.input_wait", "stage.wait", "stage.transfer",
+                         "train.dispatch")}
+    want = list(range(1, n_dispatch + 1))
+    assert seqs["stage.transfer"] == want
+    assert seqs["train.dispatch"] == want
+    # Batches arrive one by one here: K waits per superbatch, then the one
+    # that finds the source at its end.
+    assert seqs["stage.input_wait"] == sorted(want * K) + [n_dispatch + 1]
+    # Transfer j fences on dispatch j - staging_buffers.
+    assert seqs["stage.wait"] == want[tr.cfg.staging_buffers:]
+    transfers = [e for e in events if e["name"] == "stage.transfer"]
+    per_dispatch = K * 32 * (4 * 4 + 4 * 4 + 4)
+    assert all(e["args"]["bytes"] == per_dispatch
+               and e["args"]["records"] == K * 32 for e in transfers)
+    syncs = [e["args"]["step"] for e in events if e["name"] == "train.log_sync"]
+    assert syncs == [K * i for i in want]
