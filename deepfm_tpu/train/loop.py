@@ -439,11 +439,34 @@ class Trainer:
             # shards (keeps activations replicated over 'model').
             rng = jax.random.fold_in(rng, jax.lax.axis_index(data_axis))
 
-        def loss_fn(params):
+        def data_loss(params):
             _, xent, new_mstate = self._loss_terms(
                 params, state.model_state, batch, train=True, rng=rng,
                 shard_axis=shard_axis, data_axis=data_axis)
-            if data_axis is not None and self._hier_groups is None:
+            return xent, new_mstate
+
+        xent, l2, new_mstate, grads = self._dense_value_and_grad(
+            data_loss, state.params, data_axis=data_axis,
+            shard_axis=shard_axis)
+        new_params, new_opt = self._optax_apply(
+            grads, state.opt_state, state.params)
+        new_state = state.replace(
+            step=state.step + 1, params=new_params, opt_state=new_opt,
+            model_state=new_mstate)
+        return new_state, {"loss": xent + l2, "xent": xent}
+
+    def _dense_value_and_grad(self, data_loss, params, *, data_axis,
+                              shard_axis):
+        """(xent, l2, new_model_state, grads) of a dense-update step, for
+        ``data_loss(params) -> (mean data loss of this shard, new model
+        state)``. The plain and the accumulating step share it: the
+        gradient sync over the data axis, the L2 term and the pad-row mask
+        are defined here once."""
+        flat_sync = data_axis is not None and self._hier_groups is None
+
+        def loss_fn(params):
+            xent, new_mstate = data_loss(params)
+            if flat_sync:
                 # THE gradient sync point: the loss is made a *global*
                 # scalar (mean over the data axis); differentiating it
                 # under shard_map's replication-aware AD yields gradients
@@ -451,25 +474,20 @@ class Trainer:
                 # this replaces hvd.DistributedOptimizer's NCCL allreduce
                 # (2-hvd-gpu/...py:262) and the PS push/pull (X1).
                 xent = jax.lax.pmean(xent, data_axis)
-            l2 = self.model.l2_loss(params)
-            if shard_axis is not None:
-                # l2 over the full row-sharded table (invariant scalar).
-                l2 = jax.lax.psum(l2, shard_axis)
-            return xent + l2, (xent, l2, new_mstate)
+            return xent, (xent, new_mstate)
 
-        (_, (xent, l2, new_mstate)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params)
-        if data_axis is not None and self._hier_groups is not None:
+        (_, (xent, new_mstate)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params)
+        if data_axis is not None and not flat_sync:
             # Hierarchical sync point (TUNING §2.13): the loss stayed
             # per-shard above, so the raw grads carry no psum; average
             # them intra-host then inter-host — the DCN stage moves 1/L
-            # of the flat-ring traffic (L = data rows per host). The l2
-            # component is shard-invariant over 'data', so averaging it
-            # too is a no-op up to reassociation.
+            # of the flat-ring traffic (L = data rows per host).
             grads = mesh_lib.hierarchical_pmean(
                 grads, data_axis, self._hier_groups,
                 self.mesh_info.data_size)
             xent = jax.lax.pmean(xent, data_axis)  # metrics only
+        l2, grads = self._add_dense_l2(params, grads, shard_axis=shard_axis)
         # Structural guarantee: padded_vocab pad rows never receive a
         # gradient (they are zero already — unreachable ids, masked l2 —
         # so this is bit-neutral; the regression test pins it).
@@ -478,12 +496,34 @@ class Trainer:
                 n: self.model.emb.mask_pad_grads(grads[n],
                                                  axis_name=shard_axis)
                 for n in self._embed_names}}
-        new_params, new_opt = self._optax_apply(
-            grads, state.opt_state, state.params)
-        new_state = state.replace(
-            step=state.step + 1, params=new_params, opt_state=new_opt,
-            model_state=new_mstate)
-        return new_state, {"loss": xent + l2, "xent": xent}
+        return xent, l2, new_mstate, grads
+
+    def _add_dense_l2(self, params, grads, *, shard_axis):
+        """(l2 value, grads + the L2 term's gradient on the embedding
+        tables): dense L2, once per optimizer apply, on every real row.
+
+        The term stays out of the differentiated data loss and its gradient
+        ``l2_reg * mask * w`` (AD of ``Model.l2_loss`` over the tables
+        alone: elementwise, no scatter) is added to the *fenced* data
+        gradient. Without the fence XLA rewrites ``scatter-add(zeros, rows)
+        + X`` into ``scatter-add(X, rows)``, and X, a product that would
+        have been arithmetic inside Adam's sweep, becomes a table in HBM
+        that costs a write and a read pass of its own (PERF.md §6, PR 26).
+        Across data replicas the gradient's all-reduce already stands
+        between the two, so that program is left as it compiles."""
+        if not self.cfg.l2_reg:
+            return jnp.zeros((), jnp.float32), grads
+        tabs = {n: params[n] for n in self._embed_names}
+        l2, l2_grads = jax.value_and_grad(
+            lambda t: self.model.l2_loss({**params, **t},
+                                         shard_axis=shard_axis))(tabs)
+        if shard_axis is not None:
+            # l2 over the full row-sharded table (invariant scalar).
+            l2 = jax.lax.psum(l2, shard_axis)
+        data_grads = {n: grads[n] for n in self._embed_names}
+        if self.mesh_info.data_size == 1:
+            data_grads = jax.lax.optimization_barrier(data_grads)
+        return l2, {**grads, **jax.tree.map(jnp.add, data_grads, l2_grads)}
 
     @jax.named_scope("opt")
     def _optax_apply(self, grads, opt_state, params):
@@ -864,7 +904,7 @@ class Trainer:
         a = batches["label"].shape[0]
         base_rng = jax.random.fold_in(state.rng, state.step)
 
-        def loss_fn(params):
+        def data_loss(params):
             def micro(carry, inp):
                 mstate, xent_sum = carry
                 i, batch = inp
@@ -879,31 +919,20 @@ class Trainer:
                 xent = self._mean_loss(logits, batch)
                 return (new_mstate, xent_sum + xent), None
 
+            xent0 = jnp.zeros((), jnp.float32)
+            if data_axis is not None:
+                # Each data shard sums its own microbatches: the carry
+                # varies over the axis from the start (shard_map's typing).
+                xent0 = jax.lax.pcast(xent0, (data_axis,), to="varying")
             (new_mstate, xent_sum), _ = jax.lax.scan(
-                micro, (state.model_state, jnp.zeros((), jnp.float32)),
-                (jnp.arange(a), batches))
-            xent = xent_sum / a
-            if data_axis is not None and self._hier_groups is None:
-                xent = jax.lax.pmean(xent, data_axis)
-            # L2 charged once per APPLY, not per microbatch — matching the
-            # equivalent big-batch step, where it also appears once.
-            l2 = self.model.l2_loss(params)
-            if shard_axis is not None:
-                l2 = jax.lax.psum(l2, shard_axis)
-            return xent + l2, (xent, l2, new_mstate)
+                micro, (state.model_state, xent0), (jnp.arange(a), batches))
+            return xent_sum / a, new_mstate
 
-        (_, (xent, l2, new_mstate)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params)
-        if data_axis is not None and self._hier_groups is not None:
-            grads = mesh_lib.hierarchical_pmean(
-                grads, data_axis, self._hier_groups,
-                self.mesh_info.data_size)
-            xent = jax.lax.pmean(xent, data_axis)  # metrics only
-        with jax.named_scope("opt"):
-            grads = {**grads, **{
-                n: self.model.emb.mask_pad_grads(grads[n],
-                                                 axis_name=shard_axis)
-                for n in self._embed_names}}
+        # L2 charged once per APPLY, not per microbatch — matching the
+        # equivalent big-batch step, where it also appears once.
+        xent, l2, new_mstate, grads = self._dense_value_and_grad(
+            data_loss, state.params, data_axis=data_axis,
+            shard_axis=shard_axis)
         new_params, new_opt = self._optax_apply(
             grads, state.opt_state, state.params)
         new_state = state.replace(
@@ -1125,10 +1154,12 @@ class Trainer:
             self._multi_step = self._make_train_multi_step()
         return self._multi_step
 
-    def step_hlo_text(self) -> str:
+    def step_hlo_text(self, device=None) -> str:
         """The compiled K-step dispatch (``steps_per_loop`` steps of
         ``batch_size``) as optimized HLO text, from abstract arguments laid
-        out as ``_place`` and ``_put_stacked`` lay out the real ones. This
+        out as ``_place`` and ``_put_stacked`` lay out the real ones; a
+        trainer without a mesh compiles for ``device`` where one is given
+        (a described chip: ``scripts/step_table_ops.py``). This
         compiles the program a fit runs once more; the compiler is
         deterministic, so the instructions are the fit's. Where the
         persistent cache answers instead (its key leaves debug info out),
@@ -1151,6 +1182,11 @@ class Trainer:
             batches = {key: jax.ShapeDtypeStruct(
                 x.shape, x.dtype, sharding=self._stacked_sharding(x.ndim))
                 for key, x in batches.items()}
+        elif device is not None:
+            one = jax.sharding.SingleDeviceSharding(device)
+            state, batches = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+                (state, batches))
         return self.multi_step.lower(state, batches).compile().as_text()
 
     def step_op_scopes(self) -> Dict[str, str]:

@@ -117,6 +117,79 @@ def hlo_op_scopes(hlo_text: str, scopes=STEP_SCOPES) -> Dict[str, str]:
     return out
 
 
+_HLO_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]")
+_HLO_CALLS = re.compile(r"\b(calls|body)=%?([\w.\-]+)")
+_HLO_ALIAS_PAIR = re.compile(r'\{"indices":\["(\d+)","(\d+)"\]\}')
+#: Opcodes that move no bytes: a table-shaped result of one is a name for a
+#: buffer another instruction made.
+_HLO_PLUMBING = frozenset(("parameter", "get-tuple-element", "tuple", "while",
+                           "bitcast", "call", "conditional"))
+
+
+def _balanced(text: str, start: int) -> int:
+    """Index just past the bracket that closes the one at ``start``."""
+    depth = 0
+    for i in range(start, len(text)):
+        if text[i] == "(":
+            depth += 1
+        elif text[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i + 1
+    return len(text)
+
+
+def hlo_table_ops(hlo_text: str, rows: int) -> List[Dict[str, object]]:
+    """Every instruction of a compiled program's text (``Compiled.
+    as_text()``) that *makes* an array ``rows`` tall, outside fused
+    computations: one pass or more over a table in HBM each. Per
+    instruction: ``name``, ``opcode``, whether it sits in a ``loop_body``,
+    ``results`` (``f32[rows,32]`` per table-shaped result), ``operands``
+    (names), ``tables`` (those operands that name a table-shaped instruction
+    or parameter), ``in_place`` (operand positions the backend aliases to an
+    output: that operand's buffer is updated, not copied) and the innermost
+    ``scope`` of ``STEP_SCOPES``. TUNING §5 says how to read the listing."""
+    fused, bodies = set(), set()
+    for kind, name in _HLO_CALLS.findall(hlo_text):
+        (fused if kind == "calls" else bodies).add(name)
+    tall = re.compile(r"\[%d[,\]]" % rows)
+    out: List[Dict[str, object]] = []
+    computation, tables = "", set()
+    for line in hlo_text.splitlines():
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            computation, tables = m.group(2), set()
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m or computation in fused:
+            continue
+        at = m.end()
+        end = _balanced(line, at) if line[at] == "(" else line.index(" ", at)
+        results = ["%s[%s]" % a for a in _HLO_ARRAY.findall(line[at:end])
+                   if tall.search("[%s]" % a[1])]
+        if not results:
+            continue
+        tables.add(m.group(1))
+        paren = line.index("(", end)
+        opcode = line[end:paren].strip()
+        if opcode in _HLO_PLUMBING:
+            continue
+        operands = re.findall(r"%([\w.\-]+)",
+                              line[paren:_balanced(line, paren)])
+        _, _, aliasing = line.partition('"aliasing_operands"')
+        op_name = _HLO_OP_NAME.search(line)
+        out.append({
+            "name": m.group(1), "opcode": opcode,
+            "loop_body": computation in bodies, "results": results,
+            "operands": operands,
+            "tables": [o for o in operands if o in tables],
+            "in_place": [int(i) for i, _ in
+                         _HLO_ALIAS_PAIR.findall(aliasing)],
+            "scope": innermost_scope(op_name.group(1)) if op_name else ""})
+    return out
+
+
 class HostStageStats:
     """Per-stage wall-time accumulator for the host input path.
 

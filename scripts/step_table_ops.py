@@ -1,0 +1,84 @@
+#!/usr/bin/env python
+"""List the table passes of a benchmark cell's compiled train step.
+
+Compiles the cell's K-step dispatch (``Trainer.multi_step``, the program its
+``fit`` runs) for a *described* TPU — ``jax.experimental.topologies``: the
+chip's compiler is installed wherever libtpu is, no chip is attached and
+nothing runs — and prints every instruction outside fused computations whose
+result is as tall as the embedding table: ``*`` where it sits in a ``while``
+body (that is where the scanned steps live), its name, opcode, results,
+operands (``*`` after a table-shaped one), the operands the backend updates
+in place, and the ``jax.named_scope`` the trainer gave it. Each line is at
+least one pass over a table in HBM; ``docs/TUNING.md`` §5 says how to count
+them. It takes ~20 s and says nothing about time: times are the chip's
+(``benchmark/run.py --trace 1``; its ``breakdown`` names the same ops).
+
+Usage (from the repo root; keep ``JAX_PLATFORMS=cpu``):
+    python scripts/step_table_ops.py [--workload deepfm-criteo.train-files]
+    python scripts/step_table_ops.py --workload deepfm-criteo-host4.train-files
+        [--topology v5e:2x2] [--chips 1|4] [--hlo_out step.hlo.txt]
+"""
+
+import argparse
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                                ".."))
+
+
+def compile_step_text(workload: str, topology: str, chips: int) -> tuple:
+    """(optimized HLO text of the cell's dispatch, table height)."""
+    import jax
+    from jax.experimental import topologies
+
+    from benchmark import harness
+    from benchmark.drivers import _program
+
+    # A described device cannot read an executable back from the cache.
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name=topology)
+    # The trainer picks its kernels by backend: trace what a TPU host would.
+    jax.default_backend = lambda: "tpu"
+    cell = harness.load_cell(workload)
+    devices = list(topo.devices)[:chips or cell.chips]
+    trainer = _program.build_trainer(
+        _program.make_config(dict(cell.config["flags"])), devices)
+    return (trainer.step_hlo_text(device=devices[0]),
+            int(trainer.model.padded_vocab))
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", default="deepfm-criteo.train-files",
+                    help="a train cell of BENCHMARK.json")
+    ap.add_argument("--topology", default="v5e:2x2")
+    ap.add_argument("--chips", type=int, choices=(1, 4),
+                    help="default: the cell's own")
+    ap.add_argument("--hlo_out", help="also write the whole text here")
+    args = ap.parse_args(argv)
+
+    from deepfm_tpu.utils import profiling
+
+    text, rows = compile_step_text(args.workload, args.topology, args.chips)
+    if args.hlo_out:
+        with open(args.hlo_out, "w") as f:
+            f.write(text)
+    ops = profiling.hlo_table_ops(text, rows)
+    print(f"{args.workload} compiled for {args.topology}: "
+          f"{len(ops)} instructions make an array {rows} rows tall "
+          f"({sum(o['loop_body'] for o in ops)} in a loop body)")
+    for o in ops:
+        operands = ", ".join(n + "*" * (n in o["tables"])
+                             for n in o["operands"])
+        in_place = ",".join(str(i) for i in o["in_place"]) or "-"
+        print("%s %-30s %-10s %s <- (%s) in_place=%s scope=%s" % (
+            "*" if o["loop_body"] else " ", o["name"], o["opcode"],
+            " ".join(o["results"]), operands, in_place, o["scope"] or "-"))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
