@@ -49,7 +49,7 @@ class Config:
     coordinator_address: str = ""     # jax.distributed coordinator (host:port)
 
     # ---- model hyperparameters (reference: model flags) ----
-    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | din | bst
+    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst
     feature_size: int = 117581        # vocabulary size (reference ipynb:85)
     field_size: int = 39              # number of fields (reference ipynb:90)
     embedding_size: int = 32          # latent dim (reference flag default, ...py:44)
@@ -59,6 +59,11 @@ class Config:
     batch_norm_decay: float = 0.9
     cross_layers: int = 3             # DCN-v2 only: number of cross layers
     cross_rank: int = 0               # DCN-v2: low-rank dim for cross W (0 = full rank)
+    # dlrm_dcnv2 only (MLPerf DLRM-DCNv2): the first numeric_fields fields
+    # carry a numeric value each (their ids are never looked up) and feed a
+    # bottom MLP of widths bottom_layers, whose last width is embedding_size.
+    numeric_fields: int = 0
+    bottom_layers: str = ""
     l2_reg: float = 1e-4
     loss_type: str = "log_loss"       # log_loss | square_loss
 
@@ -419,9 +424,15 @@ class Config:
             raise ValueError("trace_buffer must be >= 1")
         if self.metrics_snapshot_secs < 0:
             raise ValueError("metrics_snapshot_secs must be >= 0")
-        if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm", "din",
-                              "bst"):
+        if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm",
+                              "dlrm_dcnv2", "din", "bst"):
             raise ValueError(f"unknown model: {self.model!r}")
+        if self.model == "dlrm_dcnv2":
+            self._validate_dlrm_dcnv2()
+        elif self.numeric_fields or self.bottom_layers:
+            raise ValueError(
+                "numeric_fields/bottom_layers belong to --model dlrm_dcnv2; "
+                f"{self.model!r} embeds every field")
         if self.history_max_len < 0:
             raise ValueError("history_max_len must be >= 0")
         if self.index_kind not in ("brute", "ann"):
@@ -755,10 +766,44 @@ class Config:
                 "device_dataset requires decoded_cache=ram|disk (the device "
                 "upload reads the cached columns)")
 
+    def _validate_dlrm_dcnv2(self) -> None:
+        """What MLPerf's DLRM-DCNv2 graph takes, and plainly what it does
+        not (models.graph.GraphDLRMDCNv2)."""
+        if not 0 < self.numeric_fields < self.field_size:
+            raise ValueError(
+                "model dlrm_dcnv2 needs 0 < numeric_fields < field_size "
+                f"(got {self.numeric_fields} of {self.field_size}): the "
+                "first numeric_fields fields feed the bottom MLP, the rest "
+                "are looked up")
+        bottom = self.bottom_layer_sizes
+        if not bottom or bottom[-1] != self.embedding_size:
+            raise ValueError(
+                "model dlrm_dcnv2 needs bottom_layers ending in "
+                f"embedding_size={self.embedding_size} (the bottom MLP's "
+                f"output is one more embedding), got {self.bottom_layers!r}")
+        if self.cross_layers < 1:
+            raise ValueError("model dlrm_dcnv2 needs cross_layers >= 1")
+        refused = {
+            "tasks (one ctr head; the multi-task bottom embeds every field)":
+                self.num_tasks > 1,
+            "history_max_len (no sequence encoder)": self.history_max_len > 0,
+            "batch_norm (the published MLPs have none)": self.batch_norm,
+            "embedding_update=sparse (the row plan covers every field of "
+            "feat_ids, this model looks up the categorical ones only)":
+                self.embedding_update == "sparse",
+        }
+        for what, set_ in refused.items():
+            if set_:
+                raise ValueError(f"model dlrm_dcnv2 does not take {what}")
+
     # ---- derived views ------------------------------------------------
     @property
     def deep_layer_sizes(self) -> List[int]:
         return [int(x) for x in self.deep_layers.split(",") if x.strip()]
+
+    @property
+    def bottom_layer_sizes(self) -> List[int]:
+        return [int(x) for x in self.bottom_layers.split(",") if x.strip()]
 
     @property
     def dropout_rates(self) -> List[float]:

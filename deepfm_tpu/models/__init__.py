@@ -11,7 +11,7 @@ from typing import Union
 from ..config import Config
 from .dcnv2 import DCNv2  # noqa: F401
 from .deepfm import DeepFM  # noqa: F401
-from .graph import DLRM  # noqa: F401
+from .graph import DLRM, GraphDLRMDCNv2  # noqa: F401
 from .multitask import MultiTaskModel  # noqa: F401
 from .sequence import GraphBST, GraphDIN  # noqa: F401
 from .widedeep import WideDeep  # noqa: F401
@@ -21,12 +21,13 @@ _REGISTRY = {
     "widedeep": WideDeep,
     "dcnv2": DCNv2,
     "dlrm": DLRM,
+    "dlrm_dcnv2": GraphDLRMDCNv2,
     "din": GraphDIN,
     "bst": GraphBST,
 }
 
-CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDIN, GraphBST,
-                 MultiTaskModel]
+CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDLRMDCNv2, GraphDIN,
+                 GraphBST, MultiTaskModel]
 
 
 def registered_models():

@@ -120,8 +120,7 @@ def init_hidden_stack(rng: jax.Array, in_dim: int, layer_sizes: Sequence[int],
     return params, state
 
 
-@jax.named_scope("tower")
-def apply_hidden_stack(
+def _hidden_stack(
     params: Params,
     state: State,
     x: jnp.ndarray,
@@ -156,6 +155,12 @@ def apply_hidden_stack(
             mask = jax.random.bernoulli(drop_keys[i], keep, h.shape)
             h = jnp.where(mask, h / keep, jnp.zeros((), h.dtype))
     return h, new_state
+
+
+#: One dense->relu stack, named for where it stands: the top tower of every
+#: model, and the bottom MLP under dlrm_dcnv2's numeric features.
+apply_hidden_stack = jax.named_scope("tower")(_hidden_stack)
+apply_bottom_stack = jax.named_scope("bottom")(_hidden_stack)
 
 
 def init_tower(rng: jax.Array, in_dim: int, layer_sizes: Sequence[int],

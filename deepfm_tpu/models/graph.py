@@ -86,7 +86,7 @@ def init_cross_layer(key: jax.Array, d: int, cross_rank: int
     }
 
 
-@jax.named_scope("fm")
+@jax.named_scope("cross")
 def cross_network(cross_params, x0c: jnp.ndarray,
                   compute_dtype: jnp.dtype) -> jnp.ndarray:
     """DCN-v2 cross tower: x_{l+1} = x0 * (W_l x_l + b_l) + x_l.
@@ -105,7 +105,7 @@ def cross_network(cross_params, x0c: jnp.ndarray,
     return x
 
 
-@jax.named_scope("fm")
+@jax.named_scope("cross")
 def dot_interaction(xv: jnp.ndarray) -> jnp.ndarray:
     """DLRM-style pairwise dot-interaction (Naumov et al., 2019).
 
@@ -347,10 +347,13 @@ class GraphDCNv2(GraphDeepFM):
 class DLRM(GraphDeepFM):
     """DLRM-style model: first-order + tower over [xv, pairwise dots].
 
-    Naumov et al. (2019): the dense tower consumes the flattened embeddings
-    concatenated with all pairwise dot products of the per-field embedding
-    vectors — explicit second-order crosses without the FM rank-1 collapse.
-    Same input contract and embedding tables as DeepFM.
+    DLRM-style, not Naumov et al.'s (2019) architecture: it borrows that
+    paper's pairwise dot-interaction, but has no bottom MLP (numeric fields
+    are embedded like every other field), adds a first-order term, and
+    feeds the flattened embeddings to the tower beside the dots. The
+    published recommender of this family is ``dlrm_dcnv2``
+    (:class:`GraphDLRMDCNv2`). Same input contract and embedding tables as
+    DeepFM.
     """
 
     name = "dlrm"
@@ -407,4 +410,77 @@ class DLRM(GraphDeepFM):
             y_d, new_state = tower_fn(params["tower"], top_in)
 
         logits = params["fm_b"][0] + y_first + y_d
+        return logits, new_state
+
+
+class GraphDLRMDCNv2(GraphModel):
+    """MLPerf DLRM-DCNv2: bottom MLP + embeddings -> stacked low-rank cross
+    network -> top MLP (mlcommons/training ``recommendation_v2/
+    torchrec_dlrm``; cross layers are DCN-v2's, Wang et al. 2021, in
+    torchrec's ``LowRankCrossNet`` form).
+
+        b   = bottom MLP over the numeric values (every layer ReLU)
+        x0  = concat(b, e_1, ..., e_C)        e_f = fm_v[id_f], one id a field
+        x3  = cross_network(x0)               stacked: the MLP reads x3
+        out = top MLP over x3 (hidden layers ReLU, then one linear -> logit)
+
+    The first ``numeric_fields`` fields of the ``[B, F]`` contract carry the
+    numeric values in ``feat_vals`` (already transformed; their ids are
+    never looked up), the other ``C = F - numeric_fields`` an id each
+    (``feat_vals`` is not read for them). One table leaf, ``fm_v``: there
+    is no first-order term and no global bias.
+    """
+
+    name = "dlrm_dcnv2"
+
+    def embedding_param_names(self) -> Tuple[str, ...]:
+        return ("fm_v",)
+
+    def init(self, rng: jax.Array) -> Tuple[common.Params, common.State]:
+        cfg = self.cfg
+        k_v, k_bottom, k_cross, k_top = jax.random.split(rng, 4)
+        bottom, _ = common.init_hidden_stack(
+            k_bottom, cfg.numeric_fields, cfg.bottom_layer_sizes, False)
+        # x0: the bottom MLP's output beside one embedding a looked-up field
+        d = (1 + cfg.field_size - cfg.numeric_fields) * cfg.embedding_size
+        cross = [init_cross_layer(k, d, cfg.cross_rank)
+                 for k in jax.random.split(k_cross, cfg.cross_layers)]
+        tower, bn_state = common.init_tower(
+            k_top, d, cfg.deep_layer_sizes, False)
+        params = {"fm_v": self.emb.init_entry(k_v, (cfg.embedding_size,)),
+                  "bottom": bottom, "cross": cross, "tower": tower}
+        return params, bn_state
+
+    def apply(
+        self,
+        params: common.Params,
+        state: common.State,
+        feat_ids: jnp.ndarray,   # int32 [B, F]
+        feat_vals: jnp.ndarray,  # f32 [B, F]
+        *,
+        train: bool,
+        rng: Optional[jax.Array] = None,
+        shard_axis: Optional[str] = None,
+        data_axis: Optional[str] = None,
+        emb_rows: Optional[Dict[str, Any]] = None,
+        emb_plan: Optional[Dict[str, Any]] = None,
+    ) -> Tuple[jnp.ndarray, common.State]:
+        cfg = self.cfg
+        cdt = jnp.dtype(cfg.compute_dtype)
+        n_num = cfg.numeric_fields
+        stack = dict(train=train, use_bn=False, bn_decay=cfg.batch_norm_decay,
+                     compute_dtype=cdt, data_axis=data_axis)
+
+        dense = feat_vals[:, :n_num].astype(jnp.float32)
+        b, _ = common.apply_bottom_stack(
+            params["bottom"], state, dense, dropout_keep=(), rng=None,
+            **stack)
+        e = self._emb_lookup(params, "fm_v", feat_ids[:, n_num:], shard_axis,
+                             emb_rows, emb_plan)  # [B,C,K]
+        x0c = jnp.concatenate(
+            [b, e.astype(cdt).reshape(e.shape[0], -1)], axis=1)
+        x = cross_network(params["cross"], x0c, cdt)
+        logits, new_state = common.apply_tower(
+            params["tower"], state, x, dropout_keep=cfg.dropout_rates,
+            rng=rng, **stack)
         return logits, new_state

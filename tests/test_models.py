@@ -22,6 +22,8 @@ def _cfg(**kw):
         compute_dtype="float32", l2_reg=1e-3, batch_norm=False,
     )
     base.update(kw)
+    if base.get("model") == "dlrm_dcnv2":    # its two own flags
+        base = {"numeric_fields": 2, "bottom_layers": "6,4", **base}
     return Config(**base)
 
 

@@ -44,6 +44,8 @@ def _cfg(**kw):
         mesh_data=1, mesh_model=1,
     )
     base.update(kw)
+    if base.get("model") == "dlrm_dcnv2":    # its two own flags
+        base = {"numeric_fields": 2, "bottom_layers": "6,8", **base}
     return Config(**base)
 
 

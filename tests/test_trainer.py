@@ -53,6 +53,8 @@ def _zoo_cfg(model, **kw):
     if model == "mmoe":
         return _cfg(model="deepfm", tasks="ctr,cvr", multitask="mmoe",
                     mmoe_experts=2, **kw)
+    if model == "dlrm_dcnv2":    # its two own flags
+        kw = {"numeric_fields": 2, "bottom_layers": "6,8", **kw}
     return _cfg(model=model, **kw)
 
 
