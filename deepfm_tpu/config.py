@@ -333,12 +333,17 @@ class Config:
     # regimes). See TUNING.md "Sharded embedding lookup".
     embedding_lookup: str = "masked_psum"
     # ---- embedding scale (README "Embedding scale", TUNING §2.11) ----
-    # Gradient application to the embedding tables: "dense" (the bit-exact
-    # reference — full-table optimizer sweep every step) or "sparse" (dedup
-    # the batch's ids, segment-sum cotangents, lazy timestamped Adam on the
-    # touched rows only — step cost ∝ unique ids, not vocab). sparse
-    # requires Adam and a single-device (1x1) mesh; L2 decays touched rows
-    # only (documented deviation, tolerance-pinned against dense).
+    # Gradient application to the embedding tables: "dense" (the reference's
+    # *result*: every row gets the optimizer's dense update every step) or
+    # "sparse" (dedup the batch's ids, segment-sum cotangents, lazy
+    # timestamped Adam on the touched rows only — step cost ∝ unique ids,
+    # not vocab; another mathematics). sparse requires Adam and a
+    # single-device (1x1) mesh; L2 decays touched rows only (documented
+    # deviation, tolerance-pinned against dense). dense promises the result,
+    # not the sweep: where a row without a gradient provably keeps value and
+    # state bit for bit (Adagrad, l2_reg 0, one device, no accumulation:
+    # Trainer._row_local_eligible) the program computes it on the batch's
+    # distinct rows; scripts/step_table_ops.py shows which form compiles.
     embedding_update: str = "dense"   # dense | sparse
     # Hash-bucketed multi-table embeddings: comma list of per-table bucket
     # counts ("" = one monolithic feature_size table). N tables replace the

@@ -157,6 +157,12 @@ class GraphModel:
             return self.emb.lookup_rows(emb_rows[name], emb_plan)
         return self.emb.lookup(params[name], feat_ids, axis_name=shard_axis)
 
+    def lookup_ids(self, feat_ids: jnp.ndarray) -> jnp.ndarray:
+        """The ids ``apply`` looks up in every embedding table (what it
+        hands ``_emb_lookup``): all fields' here. A trainer that passes
+        pre-gathered views as ``emb_rows`` gathers them at these."""
+        return feat_ids
+
     @jax.named_scope("l2")
     def l2_loss(self, params: common.Params, *,
                 shard_axis: Optional[str] = None,
@@ -436,6 +442,10 @@ class GraphDLRMDCNv2(GraphModel):
     def embedding_param_names(self) -> Tuple[str, ...]:
         return ("fm_v",)
 
+    def lookup_ids(self, feat_ids: jnp.ndarray) -> jnp.ndarray:
+        """The categorical fields' ids: the numeric fields' are not read."""
+        return feat_ids[:, self.cfg.numeric_fields:]
+
     def init(self, rng: jax.Array) -> Tuple[common.Params, common.State]:
         cfg = self.cfg
         k_v, k_bottom, k_cross, k_top = jax.random.split(rng, 4)
@@ -475,8 +485,8 @@ class GraphDLRMDCNv2(GraphModel):
         b, _ = common.apply_bottom_stack(
             params["bottom"], state, dense, dropout_keep=(), rng=None,
             **stack)
-        e = self._emb_lookup(params, "fm_v", feat_ids[:, n_num:], shard_axis,
-                             emb_rows, emb_plan)  # [B,C,K]
+        e = self._emb_lookup(params, "fm_v", self.lookup_ids(feat_ids),
+                             shard_axis, emb_rows, emb_plan)  # [B,C,K]
         x0c = jnp.concatenate(
             [b, e.astype(cdt).reshape(e.shape[0], -1)], axis=1)
         x = cross_network(params["cross"], x0c, cdt)

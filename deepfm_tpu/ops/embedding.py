@@ -331,6 +331,69 @@ def set_rows_scalar(table: jax.Array, entry: PlanEntry,
 
 
 # ---------------------------------------------------------------------------
+# Row sums of a batch's view cotangents (the dense step's row-local update)
+# ---------------------------------------------------------------------------
+# ``Trainer._update_rows`` differentiates the ``[B, F, ...]`` views of the
+# tables and needs, per distinct looked-up row, the float32 sum of its
+# positions' cotangents: the rows of the table-shaped gradient that are not
+# zero, without the table. One two-operand sort of (id, position) does the
+# dedup (``jnp.unique`` is three times a sort and 6.2 ms where this is 0.44:
+# PERF.md §6, PR 28); the cotangents, permuted into id order, are
+# scatter-added into an array as short as the batch, where a row costs 9 ns
+# against 74 in the table (the cost is the operand's height, not the
+# scatter's). Plain sums, no prefix-sum-and-subtract, so nothing cancels.
+
+
+class RowSums(NamedTuple):
+    """The distinct rows one batch looked up, and what each receives.
+
+    uids:  int32 [M]      the distinct in-bounds row ids, ascending, in the
+                          first ``count`` slots; every later slot holds an
+                          id past the table (``num_rows + slot``: distinct,
+                          ascending, out of bounds), so a gather reads fill
+                          there and a scatter drops it. M is static: the
+                          positions rounded up to ``multiple``.
+    sums:  f32 [M, D]     per slot, the sum of its positions' cotangents
+                          (all tables' columns side by side); past count,
+                          nothing a scatter keeps.
+    count: int32 []       distinct in-bounds rows.
+    """
+    uids: jax.Array
+    sums: jax.Array
+    count: jax.Array
+
+
+@jax.named_scope("embed")
+def sum_rows(ids: jax.Array, cots: jax.Array, num_rows: int,
+             valid_rows: int, multiple: int = 1) -> RowSums:
+    """Per distinct id of ``ids`` (any shape, N positions; negative ids
+    count from the end, as ``jnp.take`` reads them) the float32 sum of the
+    rows of ``cots`` ``[N, D]`` at its positions. Ids outside
+    ``[0, valid_rows)`` receive nothing (pad rows, ids past the table)."""
+    flat = ids.reshape(-1).astype(jnp.int32)
+    n = flat.shape[0]
+    m = -(-n // multiple) * multiple
+    flat = jnp.where(flat < 0, flat + num_rows, flat)
+    last = jnp.iinfo(jnp.int32).max
+    key = jnp.where((flat >= 0) & (flat < valid_rows), flat, last)
+    key, perm = jax.lax.sort((key, jnp.arange(n, dtype=jnp.int32)),
+                             num_keys=1)
+    first = jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), key[1:] != key[:-1]])
+    slot = jnp.cumsum(first.astype(jnp.int32)) - 1      # row slot a position
+    count = jnp.sum(first & (key != last), dtype=jnp.int32)
+    sums = jnp.zeros((m, cots.shape[1]), jnp.float32).at[slot].add(
+        jnp.take(cots.astype(jnp.float32), perm, axis=0),
+        indices_are_sorted=True)
+    # each distinct id once, ascending: a sort is the cheapest compaction
+    uids = jnp.sort(jnp.where(first, key, last))
+    uids = jnp.concatenate([uids, jnp.full((m - n,), last, jnp.int32)])
+    at = jnp.arange(m, dtype=jnp.int32)
+    return RowSums(uids=jnp.where(at < count, uids, num_rows + at),
+                   sums=sums, count=count)
+
+
+# ---------------------------------------------------------------------------
 # Row-sharded all-to-all exchange (--embedding_shard rows)
 # ---------------------------------------------------------------------------
 # The sparse path's plan (PlanEntry.uids, sorted ascending with OOB fill)

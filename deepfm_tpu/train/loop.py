@@ -51,6 +51,16 @@ from .state import TrainState
 # fixture for the device-dataset budget check, never a claim about a device.
 _CPU_TEST_MEMORY_BYTES = 16 << 30
 
+# Distinct rows one trip of the row-local table update gathers, updates and
+# writes back (``Trainer._update_rows``): a batch with more distinct rows
+# takes another trip. Chosen on the chip (PERF.md §6, PR 28): a trip costs
+# its slots, filled or spare (a dropped row costs 0.87 of a written one) and
+# nothing beside, so a small capacity only wastes less of the last trip
+# (15 trips of 2,048 read 15.86 ms a step where 1 of 32,768 reads 16.16).
+ROW_UPDATE_CAPACITY = 2048
+#: What that update reports beside the step's loss: distinct rows, trips.
+ROW_COUNTS = ("embed_distinct_rows", "embed_row_trips")
+
 
 def pad_batch(batch: Dict[str, np.ndarray], bs: int) -> Dict[str, np.ndarray]:
     """Pad a short tail batch up to the compiled shape by repeating the last
@@ -416,11 +426,11 @@ class Trainer:
         return {}
 
     def _loss_terms(self, params, model_state, batch, *, train, rng,
-                    shard_axis, data_axis):
+                    shard_axis, data_axis, **emb):
         logits, new_mstate = self.model.apply(
             params, model_state, batch["feat_ids"], batch["feat_vals"],
             train=train, rng=rng, shard_axis=shard_axis, data_axis=data_axis,
-            **self._hist_kwargs(batch))
+            **self._hist_kwargs(batch), **emb)
         xent = self._mean_loss(logits, batch)
         return logits, xent, new_mstate
 
@@ -439,21 +449,130 @@ class Trainer:
             # shards (keeps activations replicated over 'model').
             rng = jax.random.fold_in(rng, jax.lax.axis_index(data_axis))
 
-        def data_loss(params):
+        def data_loss(params, **emb):
             _, xent, new_mstate = self._loss_terms(
                 params, state.model_state, batch, train=True, rng=rng,
-                shard_axis=shard_axis, data_axis=data_axis)
+                shard_axis=shard_axis, data_axis=data_axis, **emb)
             return xent, new_mstate
 
-        xent, l2, new_mstate, grads = self._dense_value_and_grad(
-            data_loss, state.params, data_axis=data_axis,
-            shard_axis=shard_axis)
-        new_params, new_opt = self._optax_apply(
-            grads, state.opt_state, state.params)
+        counts: Dict[str, jnp.ndarray] = {}
+        if self._row_local_eligible():
+            l2 = jnp.zeros((), jnp.float32)
+            xent, new_mstate, new_params, new_opt, counts = (
+                self._row_local_apply(data_loss, state, batch))
+        else:
+            xent, l2, new_mstate, grads = self._dense_value_and_grad(
+                data_loss, state.params, data_axis=data_axis,
+                shard_axis=shard_axis)
+            new_params, new_opt = self._optax_apply(
+                grads, state.opt_state, state.params)
         new_state = state.replace(
             step=state.step + 1, params=new_params, opt_state=new_opt,
             model_state=new_mstate)
-        return new_state, {"loss": xent + l2, "xent": xent}
+        return new_state, {"loss": xent + l2, "xent": xent, **counts}
+
+    def _row_local_eligible(self) -> bool:
+        """Whether the dense-semantics step may update the embedding tables
+        on the batch's distinct rows alone (``_row_local_apply``) and still
+        compute the dense update exactly: an optimizer that leaves a row
+        with a zero gradient bit for bit, parameter and state
+        (``opt_lib.zero_grad_keeps_row``), and nothing else that moves
+        every row (dense L2) or needs the gradient as a table (a sync over
+        data replicas, row shards, accumulation over microbatches); tables
+        the model reads through ``_emb_lookup`` alone (history models also
+        read them by ``hist_ids``), one array each. Read from what the
+        trainer was built with; anything else compiles the step with the
+        table-shaped gradient."""
+        return (self.cfg.embedding_update == "dense"
+                and opt_lib.zero_grad_keeps_row(self.cfg)
+                and not self.cfg.l2_reg
+                and self.mesh_info.mesh is None
+                and self._accum == 1
+                and not self.model.emb.hashed
+                and not getattr(self.model, "uses_history", False))
+
+    def _row_local_apply(self, data_loss, state: TrainState, batch):
+        """(xent, new model state, new params, new optimizer state, row
+        counts) of a dense-semantics step whose table gradient is never a
+        table. The ``[B, F, ...]`` views of the tables are what is
+        differentiated (with the dense leaves); their cotangents are summed
+        per distinct row in float32 (``emb_ops.sum_rows``); then, a
+        ``ROW_UPDATE_CAPACITY`` of distinct rows a trip, those rows of each
+        table and of its share of the optimizer state are gathered, given
+        the trainer's own ``tx.update`` — elementwise, so on rows it is the
+        dense formula by construction — and written back in place. The
+        state's tree and shapes are the dense step's."""
+        emb, names = self.model.emb, self._embed_names
+        tabs = {n: state.params[n] for n in names}
+        rest = {k: v for k, v in state.params.items() if k not in names}
+        ids = self.model.lookup_ids(batch["feat_ids"])
+        with jax.named_scope("embed"):
+            views = {n: jnp.take(tabs[n], ids, axis=0) for n in names}
+
+        def loss_fn(diff):
+            views, rest = diff
+            xent, new_mstate = data_loss(
+                {**rest, **tabs}, emb_plan=None,
+                emb_rows={n: {emb.MONO: views[n]} for n in names})
+            return xent, (xent, new_mstate)
+
+        (_, (xent, new_mstate)), (g_views, g_rest) = jax.value_and_grad(
+            loss_fn, has_aux=True)((views, rest))
+        opt_rest = opt_lib.select_params(state.opt_state, state.params, rest)
+        opt_tabs = opt_lib.select_params(state.opt_state, state.params, tabs)
+        new_rest, opt_rest = self._optax_apply(g_rest, opt_rest, rest)
+
+        tabs, opt_tabs, counts = self._update_rows(tabs, opt_tabs, ids,
+                                                   g_views)
+        return (xent, new_mstate, {**new_rest, **tabs},
+                opt_lib.join_params(opt_rest, opt_tabs, rest), counts)
+
+    @jax.named_scope("embed")
+    def _update_rows(self, tabs, opt_tabs, ids, g_views):
+        """(tables, their optimizer state, row counts) after the optimizer's
+        update of the distinct rows of ``ids``, for the cotangents
+        ``g_views`` of the tables' ``[..., *row]`` views at ``ids``; rows no
+        id names are not read. A trip handles ``ROW_UPDATE_CAPACITY``
+        distinct rows (sorted, so the last trip's spare slots lie past the
+        table: read as fill, dropped on the way back), and the trips are as
+        many as the batch needs: exact for any batch at static shapes.
+        All of it is the ``embed`` scope's but the rows' arithmetic
+        (``_optax_apply``: ``opt``, the innermost scope wins)."""
+        names, cap = self._embed_names, ROW_UPDATE_CAPACITY
+        widths = [math.prod(tabs[n].shape[1:]) for n in names]
+        cuts = np.cumsum([0] + widths)
+        rows = emb_ops.sum_rows(
+            ids, jnp.concatenate([g_views[n].reshape(ids.size, w)
+                                  for n, w in zip(names, widths)], axis=1),
+            self.model.padded_vocab, self.cfg.feature_size, multiple=cap)
+        trips = (rows.count + cap - 1) // cap
+
+        def take(table, uids):
+            return jnp.take(table, uids, axis=0, mode="fill", fill_value=0)
+
+        def put(table, uids, new):
+            # Distinct and ascending, and XLA is told neither: with
+            # indices_are_sorted the TPU compiler sweeps the whole table
+            # (9.7 ms whatever the rows; PERF.md §6, PR 28).
+            return table.at[uids].set(new, mode="drop")
+
+        def trip(carry):
+            i, tabs, opt_tabs = carry
+            uids = jax.lax.dynamic_slice_in_dim(rows.uids, i * cap, cap)
+            g = jax.lax.dynamic_slice_in_dim(rows.sums, i * cap, cap)
+            g = {n: g[:, cuts[j]:cuts[j + 1]].reshape(
+                (cap,) + tabs[n].shape[1:]) for j, n in enumerate(names)}
+            new, new_opt = self._optax_apply(
+                g, jax.tree.map(lambda t: take(t, uids), opt_tabs),
+                {n: take(tabs[n], uids) for n in names})
+            return (i + 1, {n: put(tabs[n], uids, new[n]) for n in names},
+                    jax.tree.map(lambda t, r: put(t, uids, r),
+                                 opt_tabs, new_opt))
+
+        _, tabs, opt_tabs = jax.lax.while_loop(
+            lambda carry: carry[0] < trips, trip,
+            (jnp.zeros((), jnp.int32), tabs, opt_tabs))
+        return tabs, opt_tabs, dict(zip(ROW_COUNTS, (rows.count, trips)))
 
     def _dense_value_and_grad(self, data_loss, params, *, data_axis,
                               shard_axis):
@@ -1105,7 +1224,8 @@ class Trainer:
             def body(st, batch):
                 new_st, m = self._step_impl(
                     st, batch, data_axis=data_axis, shard_axis=shard_axis)
-                return new_st, jnp.stack((m["loss"], m["xent"]))
+                # the row-local update's counts ride beside (none otherwise)
+                return new_st, (jnp.stack((m.pop("loss"), m.pop("xent"))), m)
 
             if a > 1:
                 k_steps = batches["label"].shape[0]
@@ -1125,13 +1245,14 @@ class Trainer:
                     state, ms = jax.lax.scan(macro_body, state, groups)
                 if left:
                     tail = jax.tree.map(lambda x: x[k_steps - left:], batches)
-                    state, ms_tail = jax.lax.scan(body, state, tail)
+                    state, (ms_tail, _) = jax.lax.scan(body, state, tail)
                     ms = ms_tail if ms is None else jnp.concatenate(
                         [ms, ms_tail])
                 return state, {"loss": ms[-1, 0], "xent": ms[-1, 1]}
-            state2, ms = jax.lax.scan(body, state, batches)
+            state2, (ms, counts) = jax.lax.scan(body, state, batches)
             # Last-step metrics: matches what a sequential loop would report.
-            return state2, {"loss": ms[-1, 0], "xent": ms[-1, 1]}
+            return state2, {"loss": ms[-1, 0], "xent": ms[-1, 1],
+                            **{key: v[-1] for key, v in counts.items()}}
 
         # Donate only the state: scanned batch buffers are not reusable as
         # outputs (XLA reports them unusable and warns).
@@ -1155,8 +1276,13 @@ class Trainer:
         return self._multi_step
 
     def step_hlo_text(self, device=None) -> str:
+        """``step_compiled`` as optimized HLO text."""
+        return self.step_compiled(device).as_text()
+
+    def step_compiled(self, device=None):
         """The compiled K-step dispatch (``steps_per_loop`` steps of
-        ``batch_size``) as optimized HLO text, from abstract arguments laid
+        ``batch_size``; ``as_text()`` is its optimized HLO,
+        ``memory_analysis()`` its footprint), from abstract arguments laid
         out as ``_place`` and ``_put_stacked`` lay out the real ones; a
         trainer without a mesh compiles for ``device`` where one is given
         (a described chip: ``scripts/step_table_ops.py``). This
@@ -1187,7 +1313,7 @@ class Trainer:
             state, batches = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
                 (state, batches))
-        return self.multi_step.lower(state, batches).compile().as_text()
+        return self.multi_step.lower(state, batches).compile()
 
     def step_op_scopes(self) -> Dict[str, str]:
         """{HLO instruction name: named scope, "" for none} of the compiled
@@ -1833,10 +1959,16 @@ class Trainer:
                     watchdog.beat(n_steps)
                 if cfg.log_steps and (n_steps // cfg.log_steps
                                       > prev_steps // cfg.log_steps):
-                    with trace_lib.span("train.log_sync", step=n_steps):
+                    with trace_lib.span("train.log_sync",
+                                        step=n_steps) as sync:
                         # device sync, bounded by the log cadence
                         loss = float(m["loss"])
                         gstep = int(state.step)
+                        if trace_lib.enabled():
+                            # the row-local update's counts (the last
+                            # scanned step's, like the loss): ready with it
+                            sync.add(**{key: int(m[key])
+                                        for key in ROW_COUNTS if key in m})
                     last_loss = loss
                     if guard is not None and not guard_active:
                         # abort policy: reuse the loss scalar this log line

@@ -251,6 +251,38 @@ def sparse_apply_rows(
     return new_table, new_oe
 
 
+def zero_grad_keeps_row(cfg: Config) -> bool:
+    """Whether ``build_optimizer``'s update leaves a parameter *and* its
+    state bit for bit where the gradient is zero, whatever the step: what
+    lets the dense step update only the rows a batch touched and still be
+    the dense update (``Trainer._row_local_eligible``). Adagrad does
+    (``s + 0``, ``w - lr * 0 * rsqrt(s)``); Adam and momentum move every row
+    by their decaying moments, FTRL recomputes ``w`` from ``z, n``. Plain
+    ``sgd`` would qualify and is left out until something needs it. The
+    tests hold this to ``optax`` on a zero gradient."""
+    return cfg.optimizer.lower() == "adagrad"
+
+
+def select_params(opt_state, names, keep):
+    """``opt_state`` with every parameter-shaped dict in it (one entry a
+    parameter in ``names``: Adagrad's ``sum_of_squares``, Adam's ``mu`` and
+    ``nu``) cut down to ``keep``: the state an elementwise ``optax``
+    transformation holds for those parameters alone."""
+    names = set(names)
+    return jax.tree.map(
+        lambda d: {k: d[k] for k in keep}, opt_state,
+        is_leaf=lambda x: isinstance(x, dict) and set(x) == names)
+
+
+def join_params(state_a, state_b, names_a):
+    """Inverse of two ``select_params`` cuts: ``state_a`` (over
+    ``names_a``) with ``state_b``'s parameters beside its own."""
+    names_a = set(names_a)
+    return jax.tree.map(
+        lambda a, b: {**a, **b}, state_a, state_b,
+        is_leaf=lambda x: isinstance(x, dict) and set(x) == names_a)
+
+
 def build_optimizer(cfg: Config, *, world_size: int = 1) -> optax.GradientTransformation:
     lr = cfg.learning_rate
     if cfg.scale_lr_by_world and world_size > 1:

@@ -149,8 +149,11 @@ def hlo_table_ops(hlo_text: str, rows: int) -> List[Dict[str, object]]:
     ``results`` (``f32[rows,32]`` per table-shaped result), ``operands``
     (names), ``tables`` (those operands that name a table-shaped instruction
     or parameter), ``in_place`` (operand positions the backend aliases to an
-    output: that operand's buffer is updated, not copied) and the innermost
-    ``scope`` of ``STEP_SCOPES``. TUNING §5 says how to read the listing."""
+    output: that operand's buffer is updated, not copied), the innermost
+    ``scope`` of ``STEP_SCOPES`` and the ``primitive`` its ``op_name`` ends
+    in (a fusion's is its root's: ``scatter`` and ``scatter-add`` cost their
+    rows, not the table, when they update it in place). TUNING §5 says how
+    to read the listing."""
     fused, bodies = set(), set()
     for kind, name in _HLO_CALLS.findall(hlo_text):
         (fused if kind == "calls" else bodies).add(name)
@@ -187,7 +190,9 @@ def hlo_table_ops(hlo_text: str, rows: int) -> List[Dict[str, object]]:
             "tables": [o for o in operands if o in tables],
             "in_place": [int(i) for i, _ in
                          _HLO_ALIAS_PAIR.findall(aliasing)],
-            "scope": innermost_scope(op_name.group(1)) if op_name else ""})
+            "scope": innermost_scope(op_name.group(1)) if op_name else "",
+            "primitive": (op_name.group(1).rsplit("/", 1)[-1]
+                          if op_name else "")})
     return out
 
 

@@ -8,9 +8,13 @@ nothing runs — and prints every instruction outside fused computations whose
 result is as tall as the embedding table: ``*`` where it sits in a ``while``
 body (that is where the scanned steps live), its name, opcode, results,
 operands (``*`` after a table-shaped one), the operands the backend updates
-in place, and the ``jax.named_scope`` the trainer gave it. Each line is at
-least one pass over a table in HBM; ``docs/TUNING.md`` §5 says how to count
-them. It takes ~20 s and says nothing about time: times are the chip's
+in place, the ``jax.named_scope`` the trainer gave it and the primitive it
+came from. Each line is at least one pass over a table in HBM, except an
+``R`` line: a scatter that writes rows into a table in place costs its
+rows. A step whose only lines are ``R`` updates the tables on the rows the
+batch touched (``Trainer._row_local_eligible``); ``docs/TUNING.md`` §5 says
+how to count the others. Last, the step's ``memory_analysis()``. It takes
+~20 s and says nothing about time: times are the chip's
 (``benchmark/run.py --trace 1``; its ``breakdown`` names the same ops).
 
 Usage (from the repo root; keep ``JAX_PLATFORMS=cpu``):
@@ -28,8 +32,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
 
-def compile_step_text(workload: str, topology: str, chips: int) -> tuple:
-    """(optimized HLO text of the cell's dispatch, table height)."""
+def compile_step(workload: str, topology: str, chips: int) -> tuple:
+    """(the cell's compiled dispatch, table height)."""
     import jax
     from jax.experimental import topologies
 
@@ -46,7 +50,7 @@ def compile_step_text(workload: str, topology: str, chips: int) -> tuple:
     devices = list(topo.devices)[:chips or cell.chips]
     trainer = _program.build_trainer(
         _program.make_config(dict(cell.config["flags"])), devices)
-    return (trainer.step_hlo_text(device=devices[0]),
+    return (trainer.step_compiled(device=devices[0]),
             int(trainer.model.padded_vocab))
 
 
@@ -62,21 +66,31 @@ def main(argv=None) -> int:
 
     from deepfm_tpu.utils import profiling
 
-    text, rows = compile_step_text(args.workload, args.topology, args.chips)
+    compiled, rows = compile_step(args.workload, args.topology, args.chips)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
     if args.hlo_out:
         with open(args.hlo_out, "w") as f:
             f.write(text)
     ops = profiling.hlo_table_ops(text, rows)
+    row_writes = [o for o in ops if o["in_place"]
+                  and o["primitive"].startswith("scatter")]
     print(f"{args.workload} compiled for {args.topology}: "
           f"{len(ops)} instructions make an array {rows} rows tall "
-          f"({sum(o['loop_body'] for o in ops)} in a loop body)")
+          f"({sum(o['loop_body'] for o in ops)} in a loop body); "
+          f"{len(row_writes)} of them write rows into a table in place "
+          f"(R: the rows' cost), {len(ops) - len(row_writes)} pass over one")
     for o in ops:
         operands = ", ".join(n + "*" * (n in o["tables"])
                              for n in o["operands"])
         in_place = ",".join(str(i) for i in o["in_place"]) or "-"
-        print("%s %-30s %-10s %s <- (%s) in_place=%s scope=%s" % (
-            "*" if o["loop_body"] else " ", o["name"], o["opcode"],
-            " ".join(o["results"]), operands, in_place, o["scope"] or "-"))
+        print("%s%s %-30s %-10s %s <- (%s) in_place=%s scope=%s %s" % (
+            "*" if o["loop_body"] else " ", "R" if o in row_writes else " ",
+            o["name"], o["opcode"], " ".join(o["results"]), operands,
+            in_place, o["scope"] or "-", o["primitive"]))
+    print("memory_analysis: arguments %.3f GB, outputs %.3f GB (aliased "
+          "%.3f), temporaries %.3f GB" % tuple(
+              getattr(memory, f"{k}_size_in_bytes") / 1e9
+              for k in ("argument", "output", "alias", "temp")))
     return 0
 
 

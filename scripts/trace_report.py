@@ -26,6 +26,13 @@ back at the log cadence; ``stage.transfer`` — the host->device copy; and
 ``stage.wait`` itself, the part the device was simply busy). TUNING §17
 lists every span.
 
+Where the train step updates its tables on the rows the batch touched
+(Adagrad without L2: ``Trainer._row_local_eligible``), each
+``train.log_sync`` carries the last scanned step's ``embed_distinct_rows``
+and ``embed_row_trips``; the report prints their mean and maximum and the
+share of those steps that took one trip (a capacity that most steps
+overflow by a little pays a second trip for it).
+
 Usage:
     python scripts/trace_report.py TRACE.json [--top 20] [--json]
                                               [--stalls MS]
@@ -169,6 +176,27 @@ def stalls(events, threshold_ms):
     return out
 
 
+def row_updates(events):
+    """The row-local table update's counters off the ``train.log_sync``
+    spans that carry them: ``steps`` read, mean and max of
+    ``embed_distinct_rows`` and ``embed_row_trips``, and ``one_trip_share``
+    of those steps; None when no span has them (the step sweeps the table,
+    or the trace predates the counters)."""
+    seen = [e["args"] for e in events
+            if e.get("name") == "train.log_sync" and e.get("ph") == "X"
+            and "embed_distinct_rows" in e.get("args", {})]
+    if not seen:
+        return None
+    rows = [a["embed_distinct_rows"] for a in seen]
+    trips = [a["embed_row_trips"] for a in seen]
+    return {"steps": len(seen),
+            "distinct_rows_mean": sum(rows) / len(rows),
+            "distinct_rows_max": max(rows),
+            "row_trips_mean": sum(trips) / len(trips),
+            "row_trips_max": max(trips),
+            "one_trip_share": sum(t == 1 for t in trips) / len(trips)}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("trace", help="trace-<pid>.json or a merged trace file")
@@ -185,6 +213,7 @@ def main(argv=None):
     rows, instants, unmatched = summarize(events)
     dropped = int(other.get("dropped_spans", 0))
     slow = stalls(events, args.stalls) if args.stalls is not None else None
+    touched = row_updates(events)
 
     if args.json:
         doc = {
@@ -193,6 +222,8 @@ def main(argv=None):
             "events": len(events), "other": other}
         if slow is not None:
             doc["stalls"] = slow
+        if touched is not None:
+            doc["row_updates"] = touched
         print(json.dumps(doc, indent=2))
         return 0
 
@@ -213,6 +244,14 @@ def main(argv=None):
               f"{r['p50_ms']:>9.3f}{r['p99_ms']:>9.3f}")
     for name, n in sorted(instants.items()):
         print(f"instant {name}: {n}")
+    if touched is not None:
+        print("row-local table update over %d logged steps: "
+              "embed_distinct_rows mean %.0f max %d, embed_row_trips mean "
+              "%.2f max %d, one trip in %.0f%% of them" % (
+                  touched["steps"], touched["distinct_rows_mean"],
+                  touched["distinct_rows_max"], touched["row_trips_mean"],
+                  touched["row_trips_max"],
+                  100 * touched["one_trip_share"]))
     for st in slow or ():
         cover = ", ".join(f"{k} {v:.1f}" for k, v in st["cover_ms"].items()
                           if v > 0)

@@ -122,4 +122,7 @@ def test_hlo_table_ops_lists_what_makes_a_table():
         "get-tuple-element.1", "get-tuple-element.2", "fusion.7"]
     assert sweep["in_place"] == [0, 1] and sweep["scope"] == "opt"
     assert copy["tables"] == ["Arg_0.1"] and copy["scope"] == ""
+    # The primitive a line came from: a scatter in place costs its rows.
+    assert [o["primitive"] for o in ops] == [
+        "broadcast_in_dim", "scatter-add", "add", ""]
     assert profiling.hlo_table_ops(_STEP_HLO, 641) == []

@@ -213,3 +213,62 @@ def test_fit_spans_share_seq():
                and e["args"]["records"] == K * 32 for e in transfers)
     syncs = [e["args"]["step"] for e in events if e["name"] == "train.log_sync"]
     assert syncs == [K * i for i in want]
+
+
+# ---------------------------------------------------------------------------
+# (e) the row-local table update's counters ride on train.log_sync
+# ---------------------------------------------------------------------------
+
+def _report():
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "scripts"))
+    import trace_report
+    return trace_report
+
+
+@pytest.mark.parametrize("eligible", [True, False],
+                         ids=["adagrad-no-l2", "adam-l2"])
+def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
+    """Adagrad without L2 updates the tables on the batch's distinct rows
+    and says how many and in how many trips, on the span that reads the loss
+    back (no sync of its own); any other step has nothing to say."""
+    trace_lib.configure("full", export_env=False)
+    over = dict(optimizer="Adagrad", l2_reg=0.0) if eligible else {}
+    tr = Trainer(_cfg(**over))
+    assert tr._row_local_eligible() == eligible
+    n_dispatch = 3
+    batches = _batches(K * n_dispatch)
+    tr.fit(tr.init_state(), batches)
+    syncs = [e["args"] for e in trace_lib._tracer.events()
+             if e["name"] == "train.log_sync"]
+    assert [a["step"] for a in syncs] == [K * i for i in (1, 2, 3)]
+    path = str(tmp_path / "trace.json")
+    trace_lib.export(path)
+    report = _report()
+    events, _ = report._load(path)
+    if not eligible:
+        assert all(set(a) == {"step"} for a in syncs)
+        assert report.row_updates(events) is None
+        return
+    # the last scanned step's, like the loss
+    want = [len(np.unique(batches[K * i - 1]["feat_ids"])) for i in (1, 2, 3)]
+    assert [a["embed_distinct_rows"] for a in syncs] == want
+    assert [a["embed_row_trips"] for a in syncs] == [1, 1, 1]
+    assert report.row_updates(events) == {
+        "steps": 3, "distinct_rows_mean": sum(want) / 3,
+        "distinct_rows_max": max(want), "row_trips_mean": 1.0,
+        "row_trips_max": 1, "one_trip_share": 1.0}
+    assert report.main([path]) == 0
+    assert ("row-local table update over 3 logged steps: embed_distinct_rows "
+            "mean %.0f max %d, embed_row_trips mean 1.00 max 1, one trip in "
+            "100%% of them" % (sum(want) / 3, max(want))
+            ) in capsys.readouterr().out
+
+
+def test_log_sync_reads_no_counter_when_tracing_is_off():
+    """The counters cost two scalar reads a log line: not paid untraced."""
+    tr = Trainer(_cfg(optimizer="Adagrad", l2_reg=0.0))
+    _, out = tr.fit(tr.init_state(), _batches(K * 2))
+    assert out["steps"] == K * 2 and not trace_lib._tracer.events()
