@@ -104,8 +104,8 @@ def generate_synthetic_ctr(
     """Write synthetic Criteo-shaped TFRecords with a learnable signal.
 
     Labels follow a logistic model over a hidden random weight vector so AUC
-    above 0.5 is achievable — used by integration tests and the benchmark
-    harness (reference trained on real Criteo; shape/hparams from
+    above 0.5 is achievable — used by integration tests and the drills
+    (reference trained on real Criteo; shape/hparams from
     ``deepfm-sagemaker-ps-cpu.ipynb:82-90``). ``hidden_seed`` fixes the
     label-generating model independently of ``seed`` (the example sampler),
     so train/eval/test splits generated with different seeds share the same

@@ -26,7 +26,6 @@ import contextlib
 import os
 import queue
 import threading
-import time
 from typing import BinaryIO, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -137,21 +136,6 @@ _SCATTER_SPLIT_MIN = 4096
 # so a 64MB request that returns 0 bytes at EOF still costs a 64MB alloc).
 _EOF_PROBE_BYTES = 64 << 10
 
-# Env knob for scripts/bench_multiprocess.py: inflate the host emission cost
-# by N synthetic ns/record (a GIL-releasing sleep in the drain), making the
-# host path the bottleneck even on a 1-core box so the transfer-ahead
-# overlap A/B has something to overlap. Never set in production.
-_SYNTH_STALL_ENV = "DEEPFM_TPU_SYNTH_HOST_NS_PER_RECORD"
-
-
-def _timed(stats, name: str, **attrs):
-    """Stage-timing context: an ``input.<name>`` span of the process tracer
-    (the shared no-op when tracing is off), or, when a collector is attached
-    (``profiling.HostStageStats``), wall ns recorded into it."""
-    if stats is None:
-        return trace_lib.span("input." + name, **attrs)
-    return stats.stage(name)
-
 
 def _native_loader():
     """The native decoder module, built and loaded (first use compiles it;
@@ -163,7 +147,7 @@ def _native_loader():
 
 def _iter_framed_stream(stream: BinaryIO, loader, verify_crc: bool = True,
                         *, path: str = "", policy: Optional[BadRecordPolicy] = None,
-                        size_hint: Optional[int] = None, stats=None
+                        size_hint: Optional[int] = None
                         ) -> Iterator[Tuple[bytes, np.ndarray, np.ndarray]]:
     """Chunked read() + C-speed framing with a carried partial tail: yields
     (buf, offsets, lengths) per chunk from any sequential byte source.
@@ -199,13 +183,13 @@ def _iter_framed_stream(stream: BinaryIO, loader, verify_crc: bool = True,
             want = _EOF_PROBE_BYTES
         else:
             want = read_size
-        with _timed(stats, "read"):
+        with trace_lib.span("input.read"):
             chunk = stream.read(want)
         if not chunk:
             if carry:
                 # Strict parse of the leftover: surfaces truncated-input
                 # as an error (or a counted skip under the policy).
-                with _timed(stats, "frame"):
+                with trace_lib.span("input.frame"):
                     try:
                         offsets, lengths = loader.split_frames(
                             carry, verify_crc=verify_crc)
@@ -219,7 +203,7 @@ def _iter_framed_stream(stream: BinaryIO, loader, verify_crc: bool = True,
         buf = carry + chunk if carry else chunk
         buf_base = carry_base
         abort = False
-        with _timed(stats, "frame"):
+        with trace_lib.span("input.frame"):
             try:
                 offsets, lengths, consumed = loader.split_frames_partial(
                     buf, verify_crc=verify_crc)
@@ -250,7 +234,7 @@ def _health_retry_cb(policy: Optional[BadRecordPolicy], path: str):
 
 def _iter_framed_chunks(path: str, loader, verify_crc: bool = True, *,
                         policy: Optional[BadRecordPolicy] = None,
-                        retry_policy=None, stats=None
+                        retry_policy=None
                         ) -> Iterator[Tuple[bytes, np.ndarray, np.ndarray]]:
     """File-path front-end of ``_iter_framed_stream`` (local or gs://),
     reading through a ResilientStream so transient mid-file errors heal.
@@ -264,7 +248,7 @@ def _iter_framed_chunks(path: str, loader, verify_crc: bool = True, *,
                                on_retry=_health_retry_cb(policy, path)) as f:
         yield from _iter_framed_stream(f, loader, verify_crc,
                                        path=path, policy=policy,
-                                       size_hint=size_hint, stats=stats)
+                                       size_hint=size_hint)
 
 
 def _iter_file_records(path: str, use_native: bool, verify_crc: bool = True,
@@ -416,14 +400,10 @@ class CtrPipeline:
         # Fused decode->assemble (one C call per drain writing straight into
         # the transfer-layout pool). Off = per-chunk scatter-decode, which
         # emits bit-identical bytes — the flag exists as a kill switch and
-        # for the bench/tests to measure and pin that parity. Ignored when
+        # for the tests to pin that parity. Ignored when
         # the built .so predates the entry point (loader.has_assemble()).
         self.native_assembly = bool(native_assembly)
         self.verify_crc = verify_crc
-        # Optional per-stage wall-time collector (profiling.HostStageStats).
-        # None outside the bench: every timing site no-ops through _timed.
-        self.stage_stats = None
-        self._synth_stall_ns = float(os.environ.get(_SYNTH_STALL_ENV) or 0.0)
         # Shifts the internal epoch index used for shuffle seeding. The task
         # driver recreates the pipeline per epoch with num_epochs=1 (the
         # reference's file-mode shape, 2-hvd-gpu/...py:390-394); without the
@@ -735,8 +715,7 @@ class CtrPipeline:
             for buf, offsets, lengths in _iter_framed_chunks(
                     path, loader, self.verify_crc,
                     policy=self._bad_policy,
-                    retry_policy=self._retry_policy,
-                    stats=self.stage_stats):
+                    retry_policy=self._retry_policy):
                 if len(offsets) == 0:
                     continue
                 got_any = True
@@ -840,7 +819,7 @@ class CtrPipeline:
         # stream the service yields is exactly the in-process
         # ``_iter_decoded_chunks`` stream (same files, order, chunk
         # boundaries), so pooling it through the eager branch below emits
-        # bit-identical batches — the parity the bench asserts. Disabled
+        # bit-identical batches (tests/test_input_workers.py). Disabled
         # under record-sharding (workers see per-file streams, not the
         # global record index the 1/world filter needs).
         # Cached columns trump every decode path: no framing, no decode,
@@ -873,8 +852,6 @@ class CtrPipeline:
         # iterators of one pipeline must not share (advisor r5: the first
         # one's epoch-end close() killed the second's in-flight drain).
         drain_pool = _DrainPool(self.reader_threads)
-        stats = self.stage_stats
-        stall_ns = self._synth_stall_ns
         try:
             for e in range(self.num_epochs):
                 epoch = e + self.epoch_offset
@@ -900,8 +877,8 @@ class CtrPipeline:
                         # decoded) scatters first, then raw chunks decode
                         # directly to their rows — matching the arrival order
                         # the permutation indexes.
-                        with _timed(stats, "pool_drain", epoch=epoch,
-                                    records=n_pend):
+                        with trace_lib.span("input.pool_drain", epoch=epoch,
+                                            records=n_pend):
                             perm = rng.permutation(n_pend)
                             # Transfer-layout pool: the label column is
                             # [n, 1] so a batch slice IS the emitted
@@ -938,25 +915,20 @@ class CtrPipeline:
                             service.release_consumed()
                     hl = self.history_max_len if self.history else 0
                     while n_pend >= sb:
-                        with _timed(stats, "emit", records=sb):
+                        with trace_lib.span("input.emit", records=sb):
                             rows = self._assemble_batch(pend, sb, hl)
-                        if stall_ns:
-                            time.sleep(stall_ns * sb * 1e-9)
                         yield rows, k, sb
                         n_pend -= sb
                     if final:
                         while n_pend >= bs:
-                            with _timed(stats, "emit", records=bs):
+                            with trace_lib.span("input.emit", records=bs):
                                 rows = self._assemble_batch(pend, bs, hl)
-                            if stall_ns:
-                                time.sleep(stall_ns * bs * 1e-9)
                             yield rows, 1, bs
                             n_pend -= bs
                         if n_pend and not self.drop_remainder:
-                            with _timed(stats, "emit", records=n_pend):
+                            with trace_lib.span("input.emit",
+                                                records=n_pend):
                                 rows = self._assemble_batch(pend, n_pend, hl)
-                            if stall_ns:
-                                time.sleep(stall_ns * n_pend * 1e-9)
                             yield rows, 1, n_pend
                             n_pend = 0
 

@@ -11,7 +11,8 @@ policy, retry healing, and bad-record accounting as in-process) and decodes
 straight into :mod:`shm_ring` slabs; the trainer process consumes zero-copy
 ``np.frombuffer`` views and feeds them to the unchanged shuffle-pool drain.
 
-Determinism contract (the bit-identical parity the bench asserts):
+Determinism contract (the bit-identical parity
+``tests/test_input_workers.py`` asserts):
 
   * File ``i`` of the epoch-shuffled list goes to worker ``i % W`` (static
     round-robin — no dynamic work stealing, so the assignment is a pure

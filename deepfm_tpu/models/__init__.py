@@ -9,12 +9,12 @@ single-task graph from the registry.
 from typing import Union
 
 from ..config import Config
-from .dcnv2 import DCNv2  # noqa: F401
-from .deepfm import DeepFM  # noqa: F401
-from .graph import DLRM, GraphDLRMDCNv2  # noqa: F401
+from .graph import DLRM, GraphDLRMDCNv2
+from .graph import GraphDCNv2 as DCNv2
+from .graph import GraphDeepFM as DeepFM
+from .graph import GraphWideDeep as WideDeep
 from .multitask import MultiTaskModel  # noqa: F401
 from .sequence import GraphBST, GraphDIN  # noqa: F401
-from .widedeep import WideDeep  # noqa: F401
 
 _REGISTRY = {
     "deepfm": DeepFM,

@@ -14,11 +14,9 @@ Every model in the zoo factors into the same three stages:
      (``models.multitask``) emit ``[B, T]`` with per-task losses combined
      by configurable weights.
 
-The legacy classes (``DeepFM``, ``WideDeep``, ``DCNv2``) are thin wrappers
-over the graph classes here: identical RNG key derivation and identical op
-order, so forward, loss, and training trajectories are bit-identical to the
-pre-graph implementations (pinned by tests/test_multitask.py and the NumPy
-oracles in tests/test_models.py).
+``deepfm_tpu.models`` exports ``GraphDeepFM``, ``GraphWideDeep`` and
+``GraphDCNv2`` under their public names ``DeepFM``, ``WideDeep``, ``DCNv2``;
+the NumPy oracles in tests/test_models.py hold their forward and loss.
 """
 
 from __future__ import annotations
@@ -188,7 +186,17 @@ class GraphModel:
 
 
 class GraphDeepFM(GraphModel):
-    """DeepFM as a graph: (fm_w, fm_v) → [fm_block, tower] → ctr head."""
+    """DeepFM as a graph: (fm_w, fm_v) → [fm_block, tower] → ctr head.
+
+    The reference ``model_fn`` graph
+    (``1-ps-cpu/DeepFM-dist-ps-for-multipleCPU-multiInstance.py:149-292``):
+
+        y = FM_B + sum_f(W[ids]*vals) + FM(xv) + DNN(flatten(xv))
+        pred = sigmoid(y)
+
+    with FM_W: [V], FM_V: [V, K] glorot-normal (reference ``:166-168``), the
+    FM identity from ``ops.fm`` and the tower from ``models.common``.
+    """
 
     name = "deepfm"
 
@@ -246,7 +254,11 @@ class GraphDeepFM(GraphModel):
 
 
 class GraphWideDeep(GraphDeepFM):
-    """Wide&Deep as a graph: first_order block + tower, no FM term."""
+    """Wide&Deep as a graph: first_order block + tower, no FM term.
+
+    Same input contract and embedding tables as DeepFM; the model drops the
+    second-order FM term, keeping y = b + wide(ids, vals) + DNN(xv).
+    """
 
     name = "widedeep"
 
@@ -288,7 +300,16 @@ class GraphWideDeep(GraphDeepFM):
 
 
 class GraphDCNv2(GraphDeepFM):
-    """DCN-v2 as a graph: cross_network + hidden stack → combination head."""
+    """DCN-v2 as a graph: cross_network + hidden stack → combination head.
+
+    Same sparse-CTR input contract as DeepFM. Cross layers follow DCN-v2
+    (Wang et al., 2021):
+
+        x_{l+1} = x_0 * (W_l x_l + b_l) + x_l          (full-rank)
+        x_{l+1} = x_0 * (U_l (V_l x_l) + b_l) + x_l    (low-rank, cross_rank > 0)
+
+    The [D, D] cross matmuls (D = F*K) are dense MXU work.
+    """
 
     name = "dcnv2"
 

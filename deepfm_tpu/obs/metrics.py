@@ -4,9 +4,9 @@ Two layers:
 
 - Typed primitives — :class:`Counter` / :class:`Gauge` / :class:`Histogram`
   — for NEW metrics, created via ``REGISTRY.counter("name")`` etc.
-- Collector adapters — the five stat classes the repo already has
-  (``DataHealth``, ``TrainHealth``, ``ServingStats``, ``HostStageStats``,
-  ``Publisher``) self-register in ``__init__`` via :func:`auto_register`,
+- Collector adapters — the stat classes the repo already has
+  (``DataHealth``, ``TrainHealth``, ``ServingStats``, ``Publisher``, ...;
+  ``_KIND_METHOD`` below) self-register in ``__init__`` via :func:`auto_register`,
   and :func:`Registry.snapshot` calls their EXISTING snapshot/summary
   methods. Their result-dict and summary keys are untouched (pinned by
   tests); the registry is a read-side union, not a rewrite.
@@ -33,7 +33,6 @@ _KIND_METHOD = {
     "data_health": "snapshot",      # data.health.DataHealth
     "train_health": "snapshot",     # train.guard.TrainHealth
     "serving": "summary",           # serve.stats.ServingStats
-    "host_stage": "ns_per_record",  # utils.profiling.HostStageStats
     "publisher": "stats",           # train.publish.Publisher
     "loop_health": "snapshot",      # loop.health.LoopHealth
     "experiment": "summary",        # serve.experiment.ExperimentRouter
@@ -236,8 +235,7 @@ class SnapshotWriter:
 
     A daemon thread appends ``{"t": <wall>, "metrics": {...}}`` every
     ``period_secs`` and once more on :meth:`close` (so a short run still
-    leaves one line). ``writes``/``write_s`` expose its own cost for the
-    bench series."""
+    leaves one line). ``writes``/``write_s`` expose its own cost."""
 
     def __init__(self, path: str, period_secs: float,
                  registry: Optional[Registry] = None):

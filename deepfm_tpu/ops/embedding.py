@@ -83,8 +83,9 @@ def sharded_lookup_allgather(local_table: jax.Array, ids: jax.Array,
     axis-varying). Communication is O(V·K) per step independent of batch
     (vs masked+psum's O(B·F·K)); the table cotangent reduces back with the
     transposed collective. Only competitive when ids volume exceeds table
-    volume — exposed for A/B (scripts/bench_embedding.py, TUNING.md) and
-    for large-batch/small-table regimes via cfg.embedding_lookup."""
+    volume — selected via cfg.embedding_lookup for large-batch/small-table
+    regimes (``test_trainer.py::test_allgather_lookup_matches_masked_psum``
+    holds both strategies to the same weights)."""
     m = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     rows_local = local_table.shape[0]
@@ -233,7 +234,7 @@ def make_plan_counting(ids: jax.Array, num_rows: int,
     of sorting.
 
     ``jnp.unique(size=N)`` lowers to a sort-based program (~5x the cost of
-    this formulation on XLA:CPU at the bench shape). A presence-mark pass
+    this formulation on XLA:CPU). A presence-mark pass
     over the [num_rows+1] id space recovers the same sorted dedup:
 
         mark[r]   = 1 iff r occurs in ids            (one scatter)
@@ -305,7 +306,7 @@ def scatter_rows(table: jax.Array, entry: PlanEntry,
     Counting plans (touched/rank present) write back as a SELECT over the
     id space instead — ``where(touched, new_rows[rank], table)`` — which
     XLA:CPU executes as one fused vocab-shaped pass (~7x cheaper than its
-    row scatter at the bench shape) and is element-for-element identical:
+    row scatter) and is element-for-element identical:
     rank[r] is exactly the uid slot of each touched row r, untouched rows
     keep their bits. A table shorter than the id space (the tiered hot
     cache gathers with slot ids < hot_rows < padded_vocab) truncates the

@@ -1,7 +1,6 @@
 """Kernel-leg selection for the sparse embedding plane.
 
-EMBED_r01 measured the sparse update path losing to dense because its three
-hot seams ran on XLA defaults:
+The sparse update path has three hot seams:
 
   1. **plan build** — ``make_plan``'s ``jnp.unique(size=N)`` lowers to a
      sort-based program;
@@ -51,9 +50,9 @@ MODES = ("auto", "xla", "off")
 
 # The counting plan build costs one [rows+1] prefix sum + one presence
 # scatter; the sort-based unique costs O(N log N) independent of rows.
-# Measured crossover on XLA:CPU is far above the largest physical table in
-# the bench sweep (4x262144 hashed buckets, 100k monolithic); 2M rows keeps
-# a safety margin before the vocab-shaped pass could dominate.
+# Measured crossover on XLA:CPU is far above 4x262144 hashed buckets and a
+# 100k-row monolithic table; 2M rows keeps a safety margin before the
+# vocab-shaped pass could dominate. Not measured on the chip (ROADMAP C4+).
 PLAN_COUNT_MAX_ROWS = 2_000_000
 
 # Scoped-VMEM limit the take kernels are compiled with. They keep the

@@ -30,7 +30,7 @@ interface the frontend already speaks (``submit`` / ``pending_rows`` /
 
 Scaling honesty: on a time-sliced host (the 1-core CI box) replicas share
 the same core, so aggregate QPS does NOT scale and this module makes no
-claim that it does — the bench series labels those points.
+claim that it does.
 """
 
 from __future__ import annotations

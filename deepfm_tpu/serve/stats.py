@@ -2,10 +2,10 @@
 
 The serving mirror of ``data.health.DataHealth`` / ``train.guard.TrainHealth``
 — one thread-safe object every layer of the serving runtime stamps into, and
-one ``summary()`` dict the drill and ``bench.py``'s ``serving`` series read.
+one ``summary()`` dict the drills and the unified metrics registry read.
 All timestamps come from an injectable ``clock`` so tests are sleep-free.
 
-What the fields mean (the contract ``SERVING_r0*.json`` reports):
+What the fields mean (the contract the serving drill's report carries):
 
   * ``serving_p50_ms`` / ``serving_p99_ms`` — per-request latency from
     ``submit()`` admission to future resolution (queue wait + batch wait +

@@ -20,7 +20,6 @@ The fit loop feeds host batches via ``jax.make_array_from_process_local_data``
 from __future__ import annotations
 
 import math
-import os
 import queue
 import threading
 import time
@@ -109,8 +108,7 @@ def _with_weight(batch: Dict[str, np.ndarray], bs: int) -> Dict[str, np.ndarray]
 def _staged_size(args) -> Tuple[int, int]:
     """(records, bytes) of a staged transfer's host-side payload (batch dict
     or list of batch dicts). Records count 0 for layouts without a 'label'
-    column (columnar input-service rows) — the synthetic stall then leaves
-    them alone."""
+    column (columnar input-service rows)."""
     records = nbytes = 0
     for a in args:
         for b in ([a] if isinstance(a, dict) else
@@ -134,20 +132,9 @@ class _StagingRing:
     transfer waits out the previous dispatch — H2D serializes with compute
     (the A/B baseline, and the memory floor when two staged superbatches
     don't fit). Purely a scheduling constraint: the trajectory is
-    bit-identical across slot counts.
-
-    Also the overlap instrument: ``transfer_s`` is time inside transfers,
-    ``wait_s`` time blocked on fences — ``overlap_fraction`` is the share
-    of staging time doing useful transfer work (1.0 = never fenced).
+    bit-identical across slot counts. The ``stage.wait`` and
+    ``stage.transfer`` spans time the fence and the transfer.
     """
-
-    # Test/bench-only: inflate each transfer by N ns per staged record. On
-    # the CPU backend the host->device "transfer" is a core-local copy too
-    # cheap to measure, so the 1-vs-2-slot A/B has nothing to overlap; the
-    # synthetic stall stands in for a real PCIe/DMA leg (same spirit as the
-    # pipeline's DEEPFM_TPU_SYNTH_HOST_NS_PER_RECORD). Never set in
-    # production.
-    SYNTH_TRANSFER_ENV = "DEEPFM_TPU_SYNTH_TRANSFER_NS_PER_RECORD"
 
     def __init__(self, n_slots: int):
         self.n_slots = max(int(n_slots), 1)
@@ -157,9 +144,6 @@ class _StagingRing:
         # same number from both, the ``seq`` its spans share.
         self.staged = 0
         self.dispatched = 0
-        self.transfer_s = 0.0
-        self.wait_s = 0.0
-        self._synth_ns = int(os.environ.get(self.SYNTH_TRANSFER_ENV, "0"))
 
     def put(self, transfer: Callable[[], Any], n_records: int = 0,
             n_bytes: int = 0) -> Any:
@@ -167,7 +151,6 @@ class _StagingRing:
         self.staged += 1
         if self.staged > self.n_slots:
             with trace_lib.span("stage.wait", seq=self.staged):
-                t0 = time.time()
                 fence = None
                 # Poll against close so an abandoned fit (exception, early
                 # return) can never strand the staging thread on this queue.
@@ -179,15 +162,9 @@ class _StagingRing:
                         continue
                 if fence is not None:
                     jax.block_until_ready(fence)
-                self.wait_s += time.time() - t0
         with trace_lib.span("stage.transfer", seq=self.staged,
                             records=n_records, bytes=n_bytes):
-            t0 = time.time()
-            out = transfer()
-            if self._synth_ns and n_records:
-                time.sleep(self._synth_ns * n_records * 1e-9)
-            self.transfer_s += time.time() - t0
-        return out
+            return transfer()
 
     def retire(self, fence: Any) -> None:
         """Mark one dispatch's slot reusable once ``fence`` is ready
@@ -197,10 +174,6 @@ class _StagingRing:
 
     def close(self) -> None:
         self._closed.set()
-
-    def overlap_fraction(self) -> float:
-        total = self.transfer_s + self.wait_s
-        return 1.0 if total <= 0 else self.transfer_s / total
 
 
 class Trainer:
@@ -281,7 +254,7 @@ class Trainer:
         # equal per-host blocks. Tests override this seam to exercise the
         # hierarchical program on a single-host virtual mesh.
         self._hier_groups = mesh_lib.data_axis_host_groups(self.mesh_info)
-        # Active fit's device staging ring (slot fence + overlap timing);
+        # Active fit's device staging ring (slot fences);
         # None outside fit so eval/predict transfers pass through untouched.
         self._ring: Optional[_StagingRing] = None
         self._grad_bytes_cache: Optional[int] = None
@@ -756,8 +729,9 @@ class Trainer:
         fusion of the model backward (~1 ULP cotangent drift), breaking
         the kill-switch bit-parity pin. The scatter writeback is
         bit-exact, so the trainer always takes it; the select leg stays
-        available to the A/B bench through ``ops.embedding.scatter_rows``
-        directly (recorded as a parity loss in EMBED_r02.json)."""
+        available through ``ops.embedding.scatter_rows`` directly, held
+        element-identical to the scatter by ``test_pallas_embedding.py``
+        (``test_select_writeback_matches_scatter_writeback``)."""
         plan = {key: e._replace(touched=None, rank=None)
                 for key, e in plan.items()}
         emb = self.model.emb
@@ -787,7 +761,7 @@ class Trainer:
         segment-sum scatter-add instead of a [vocab, ...] cotangent, and
         lazy timestamped Adam (optimizers.sparse_adam_rows) touches only
         those rows. Per-step cost scales with unique-ids-per-batch, never
-        with vocab size (EMBED_r02.json pins the scaling curve).
+        with vocab size.
 
         With the embedding kernels enabled (default) the monolithic layout
         takes the FUSED vocab-space formulation: the [B, F, D] batch views
@@ -2020,12 +1994,9 @@ class Trainer:
             last_loss = float(m["loss"])
         out = {"loss": last_loss, "steps": float(n_steps)}
         out.update({k_: v for k_, v in meter.summary().items() if k_ != "steps"})
-        out["staging_overlap_fraction"] = ring.overlap_fraction()
-        out["staging_transfer_s"] = ring.transfer_s
-        out["staging_wait_s"] = ring.wait_s
         if self.mesh_info.data_size > 1 and comm_applies:
-            # Analytic comms volume of the gradient sync (the bench's
-            # comms-per-example column): applies x per-apply payload.
+            # Analytic comms volume of the gradient sync: applies x
+            # per-apply payload.
             out["collective_applies"] = float(comm_applies)
             out["collective_bytes"] = float(
                 comm_applies * self._grad_payload_bytes())

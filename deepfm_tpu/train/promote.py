@@ -17,8 +17,7 @@ replayable from ``pointer_history.jsonl`` alone, and a crash between the
 history append and the pointer move heals idempotently on retry.
 
 Gate evaluation is a pure function (:func:`evaluate_gates`) over two plain
-metric dicts, so tests and the bench series drive it without any serving
-stack behind it.
+metric dicts, so tests drive it without any serving stack behind it.
 """
 
 from __future__ import annotations

@@ -94,7 +94,7 @@ class Publisher:
         self._last_crossed_step = 0    # step-cadence boundary bookkeeping
         self._last_pub_time = clock()  # time cadence anchors at start
         self._head_step = 0            # newest step seen (staleness metric)
-        # Stats (host-side, cheap): consumed by bench + the task result.
+        # Stats (host-side, cheap): consumed by the task result and the drills.
         self.published: List[int] = []      # versions successfully published
         self.publish_failures = 0
         self.skipped_inflight = 0           # due cadences hit while busy

@@ -1080,7 +1080,7 @@ def _task_train(trainer: Trainer, cfg: Config) -> Dict[str, float]:
                         "examples_per_sec", 0.0)
                     result.update(
                         {k: v for k, v in fit_m.items()
-                         if k.startswith(("staging_", "collective_"))})
+                         if k.startswith("collective_")})
                 if publisher is not None:
                     # Stream ended (idle timeout / stop): force one final
                     # publish at the terminal step. Deterministic — both an
@@ -1140,7 +1140,7 @@ def _task_train(trainer: Trainer, cfg: Config) -> Dict[str, float]:
                             "examples_per_sec", 0.0)
                         result.update(
                             {k: v for k, v in fit_m.items()
-                             if k.startswith(("staging_", "collective_"))})
+                             if k.startswith("collective_")})
                     if (mgr is not None and last_saved[0] == step_counter[0]
                             and epoch + 1 < cfg.num_epochs):
                         # A checkpoint landed exactly on this epoch's last

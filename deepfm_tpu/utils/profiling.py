@@ -22,8 +22,6 @@ import re
 import time
 from typing import Dict, Iterator, List, Optional
 
-from ..obs import metrics as metrics_lib
-
 
 @contextlib.contextmanager
 def maybe_trace(profile_dir: Optional[str]) -> Iterator[None]:
@@ -194,46 +192,6 @@ def hlo_table_ops(hlo_text: str, rows: int) -> List[Dict[str, object]]:
             "primitive": (op_name.group(1).rsplit("/", 1)[-1]
                           if op_name else "")})
     return out
-
-
-class HostStageStats:
-    """Per-stage wall-time accumulator for the host input path.
-
-    The pipeline brackets each stage of its hot loop with ``stage(name)``
-    (``read`` — stream bytes in; ``frame`` — split TFRecord frames;
-    ``pool_drain`` — proto decode scattered into the transfer-layout pool;
-    ``emit`` — slice/stack batches off the pool) when a collector is
-    attached via ``CtrPipeline.stage_stats``; detached (the default) every
-    site is an ``input.<stage>`` span of ``obs.trace`` instead. All stages
-    run on the pipeline generator's thread —
-    even when the decode fans out to a reader pool, the bracket measures
-    the generator's wall wait — so the numbers add up to (most of) the
-    observed ns/record and the remainder is attributable Python glue.
-    """
-
-    def __init__(self) -> None:
-        self.ns: Dict[str, int] = {}
-        self.records = 0  # caller sets/accumulates the denominator
-        # Unified registry (obs.metrics): per-stage ns/record is the
-        # metric surface.
-        metrics_lib.auto_register("host_stage", self)
-
-    @contextlib.contextmanager
-    def stage(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            self.ns[name] = self.ns.get(name, 0) + (
-                time.perf_counter_ns() - t0)
-
-    def ns_per_record(self, records: Optional[int] = None
-                      ) -> Dict[str, float]:
-        """Per-stage ns/record; pass ``records`` or preset ``.records``."""
-        n = records if records is not None else self.records
-        n = max(int(n), 1)
-        return {name: round(total / n, 1)
-                for name, total in sorted(self.ns.items())}
 
 
 class ThroughputMeter:

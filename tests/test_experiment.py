@@ -5,9 +5,9 @@ the canary kill-switch, guardrail gate evaluation and the promotion
 controller's promote/rollback/quarantine state machine, the append-only
 ``pointer_history.jsonl`` audit sidecar and its crash-heal idempotence, the
 per-arm health window, the impression log's experiment fields, the
-challenger-poisoning chaos kinds, the experimentation drill's bit-replayable
-audit fingerprint, and the ``bench.experiment_series`` schema smoke. The
-full-parameter drill rides behind ``slow``."""
+challenger-poisoning chaos kinds, and the experimentation drill's
+bit-replayable audit fingerprint. The full-parameter drill rides behind
+``slow``."""
 
 import os
 import sys
@@ -388,6 +388,31 @@ class TestPromotionController:
         assert not ctl.offer("1")
         assert ctl.observe(HEALTHY, CONTROL).action == "hold"
 
+    @pytest.mark.parametrize("kind,health,reason,age_s", [
+        ("nan", dict(HEALTHY, nonfinite=7), promote_lib.REASON_NONFINITE,
+         None),
+        ("latency", dict(HEALTHY, p99_latency_ms=5000.0),
+         promote_lib.REASON_LATENCY, None),
+        ("calibration", dict(HEALTHY, mean_pred=0.9, calibration_err=0.4),
+         promote_lib.REASON_CALIBRATION, None),
+        ("stale", HEALTHY, promote_lib.REASON_STALE, 7200.0),
+    ])
+    def test_each_poison_rolls_back_in_one_window(
+            self, publish_dir, kind, health, reason, age_s):
+        """Gate evaluation is a pure function of the window, so every
+        poison kind must flip the decision to ``rollback`` in the FIRST
+        health window the controller observes, with its typed reason, and
+        leave ``LATEST`` where it was: more than one window means a
+        guardrail went soft."""
+        ctl = promote_lib.PromotionController(publish_dir, gates=_gates())
+        assert ctl.offer("2", now_s=0.0 if age_s is not None else None)
+        kw = {"now_s": age_s} if age_s is not None else {}
+        d = ctl.observe(health, CONTROL, **kw)
+        assert d.action == "rollback", (kind, d)
+        assert reason in d.reasons, (kind, d.reasons)
+        assert ctl.rollbacks == 1 and ctl.passing_windows == 0
+        assert os.path.basename(export_lib.read_latest(publish_dir)) == "1"
+
 
 # --------------------------------------------------------------------------
 # Pointer-history sidecar: append-then-move protocol, crash-heal
@@ -618,27 +643,3 @@ class TestExperimentDrill:
                                                   seed=7)
         assert r["ok"] and r["arm_health_offline_match"]
         assert r["primary"]["failed"] == 0
-
-
-# --------------------------------------------------------------------------
-# bench.experiment_series schema smoke.
-# --------------------------------------------------------------------------
-
-class TestExperimentBench:
-    def test_series_schema_and_detection_contract(self):
-        import bench
-        out = bench.experiment_series(n_requests=30, qps=200.0, rounds=1)
-        for key in ("baseline_p99_ms", "shadow_p99_ms",
-                    "shadow_p99_overhead_pct", "shadow_duplicated",
-                    "promotion_pointer_move_p50_ms", "rollback_detection",
-                    "load_kind", "device_kind", "host_cpu_count"):
-            assert key in out, key
-        assert out["shadow_errors"] == 0 and out["shadow_nonfinite"] == 0
-        assert out["shadow_duplicated"] > 0
-        assert out["promotion_pointer_move_p50_ms"] > 0
-        # Every poison kind detects in exactly ONE health window — the
-        # guardrails-went-soft trip-wire.
-        det = out["rollback_detection"]
-        assert set(det) == {"nan", "latency", "calibration", "stale"}
-        for kind, row in det.items():
-            assert row["windows"] == 1 and row["reason_typed"], (kind, row)

@@ -2,9 +2,8 @@
 
 Covers the PR-9 acceptance criteria end to end:
 
-* graph-path DeepFM / Wide&Deep / DCN-v2 are BIT-identical to the legacy
-  classes (which are now thin renames of the graph classes) — forward and
-  a pinned 5-step training trajectory at identical seeds;
+* DeepFM / Wide&Deep / DCN-v2 are the graph classes under their public
+  names, and a 5-step training trajectory repeats bit for bit at one seed;
 * an MMoE CTR+CVR run trains end-to-end, publishes a servable and serves
   named per-task probabilities through ServingEngine;
 * the two-label input contract (codec byte-identity, native/Python decode
@@ -70,59 +69,38 @@ def _batches(nb, seed=3, two_label=False, v=V, b=B):
 _GRAPH = {"deepfm": graph.GraphDeepFM,
           "widedeep": graph.GraphWideDeep,
           "dcnv2": graph.GraphDCNv2}
+_PUBLIC = {"deepfm": "DeepFM", "widedeep": "WideDeep", "dcnv2": "DCNv2"}
 
 
-class TestGraphLegacyParity:
-    """The legacy model classes are literal renames of the graph classes:
-    same init key derivation, same op order — everything below must be
-    bit-identical, not approximately equal."""
-
-    @pytest.mark.parametrize("name", sorted(_GRAPH))
-    def test_wrapper_is_pure_rename(self, name):
-        legacy = models_pkg._REGISTRY[name]
-        base = _GRAPH[name]
-        assert issubclass(legacy, base)
-        # no overridden math: the wrapper may only restate the public name
-        assert legacy.init is base.init
-        assert legacy.apply is base.apply
-        assert legacy.l2_loss is base.l2_loss
+class TestGraphPublicNames:
+    """``DeepFM`` / ``WideDeep`` / ``DCNv2`` are the graph classes under
+    their public names: one class per model, registered under its own
+    ``name``, and a fit from one seed is one trajectory."""
 
     @pytest.mark.parametrize("name", sorted(_GRAPH))
-    def test_forward_bit_identical(self, name):
+    def test_public_name_is_the_graph_class(self, name):
+        assert getattr(models_pkg, _PUBLIC[name]) is _GRAPH[name]
+        assert models_pkg._REGISTRY[name] is _GRAPH[name]
+        assert _GRAPH[name].name == name
+
+    @pytest.mark.parametrize("name", sorted(_GRAPH))
+    def test_five_step_trajectory_repeats_bit_identical(self, name):
         cfg = _cfg(model=name)
-        legacy = models_pkg._REGISTRY[name](cfg)
-        base = _GRAPH[name](cfg)
-        p_l, s_l = legacy.init(jax.random.PRNGKey(0))
-        p_g, s_g = base.init(jax.random.PRNGKey(0))
-        for a, b in zip(jax.tree.leaves(p_l), jax.tree.leaves(p_g)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        [batch] = _batches(1)
-        l_l, _ = legacy.apply(p_l, s_l, batch["feat_ids"],
-                              batch["feat_vals"], train=False)
-        l_g, _ = base.apply(p_g, s_g, batch["feat_ids"],
-                            batch["feat_vals"], train=False)
-        np.testing.assert_array_equal(np.asarray(l_l), np.asarray(l_g))
 
-    @pytest.mark.parametrize("name", sorted(_GRAPH))
-    def test_five_step_trajectory_bit_identical(self, name, monkeypatch):
-        cfg = _cfg(model=name)
-        losses_legacy, losses_graph = [], []
-
-        def _run(losses):
+        def _run():
+            losses = []
             tr = Trainer(cfg)
             state, _ = tr.fit(
                 tr.init_state(), _batches(5),
                 hooks=[lambda s, m: losses.append(float(m["loss"]))])
-            return tr, state
+            assert type(tr.model) is _GRAPH[name]
+            return losses, state
 
-        tr_l, s_l = _run(losses_legacy)
-        assert type(tr_l.model) is models_pkg._REGISTRY[name]
-        monkeypatch.setitem(models_pkg._REGISTRY, name, _GRAPH[name])
-        tr_g, s_g = _run(losses_graph)
-        assert type(tr_g.model) is _GRAPH[name]
-        assert losses_legacy == losses_graph  # floats, exact
-        for a, b in zip(jax.tree.leaves(s_l.params),
-                        jax.tree.leaves(s_g.params)):
+        losses_a, s_a = _run()
+        losses_b, s_b = _run()
+        assert len(losses_a) == 5 and losses_a == losses_b  # floats, exact
+        for a, b in zip(jax.tree.leaves(s_a.params),
+                        jax.tree.leaves(s_b.params)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

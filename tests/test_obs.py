@@ -351,30 +351,25 @@ class TestMetricsRegistry:
         assert "bad.error" in snap
 
     def test_existing_stat_classes_auto_register(self):
-        """The five stat surfaces self-register at construction and surface
+        """The stat surfaces self-register at construction and surface
         their EXISTING keys namespaced — no key renames."""
         from deepfm_tpu.data.health import DataHealth
         from deepfm_tpu.loop.health import LoopHealth
         from deepfm_tpu.serve.stats import ServingStats
         from deepfm_tpu.train.guard import TrainHealth
-        from deepfm_tpu.utils.profiling import HostStageStats
 
         obs_metrics.REGISTRY.reset()
         try:
             dh, lh = DataHealth(), LoopHealth()
-            th, ss, hs = TrainHealth(), ServingStats(), HostStageStats()
+            th, ss = TrainHealth(), ServingStats()
             dh.record_retry("f")
             lh.record("labels_joined", 2)
-            with hs.stage("read"):
-                pass
-            hs.records = 1
             snap = obs_metrics.REGISTRY.snapshot()
             assert snap["data_health.read_retries"] == 1
             assert snap["loop_health.labels_joined"] == 2
             assert snap["train_health.nonfinite_skips"] == 0
             assert snap["serving.serving_requests"] == 0
-            assert "host_stage.read" in snap
-            del dh, lh, th, ss, hs
+            del dh, lh, th, ss
         finally:
             gc.collect()
             obs_metrics.REGISTRY.reset()

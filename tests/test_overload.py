@@ -1,9 +1,8 @@
 """Flood-harness + degradation-drill tests: the million-user Zipf traffic
 plan (determinism, skew, per-user history continuity), the count-based
 ``executor_slow`` chaos seam, the overload drill's bit-replayable audit
-fingerprint, and the ``bench.overload_series`` schema/accounting smoke. The
-full flood sweep (``scripts/bench_serving.py --flood``) rides behind
-``slow``."""
+fingerprint, and the serving accounting identity on a flooded fleet's own
+counters."""
 
 import os
 import sys
@@ -13,6 +12,8 @@ import numpy as np
 import pytest
 
 from deepfm_tpu.loop.traffic import FloodTrafficPlan, ZipfUserPopulation
+from deepfm_tpu.serve import (AdmissionShed, ReplicatedEngine, ServeTimeout,
+                              ServerOverloaded, ServingEngine)
 from deepfm_tpu.serve.admission import DEGRADE_RUNGS, VALUE_CLASSES
 from deepfm_tpu.utils import faults
 
@@ -195,42 +196,105 @@ class TestOverloadDrill:
 
 
 # --------------------------------------------------------------------------
-# bench.overload_series schema smoke + slow full sweep.
+# The serving accounting identity, on the engines' own counters.
 # --------------------------------------------------------------------------
 
-class TestFloodBench:
-    def test_overload_series_schema_and_accounting(self, tmp_path):
-        import bench
-        workdir = str(tmp_path / "artifacts")
-        os.makedirs(workdir)
-        bench.export_serving_artifacts(workdir)
-        out = bench.overload_series(
-            run_secs=0.5, mults=(4.0,), replicas=2, users=20_000,
-            artifact_dir=workdir, saturation_qps=200.0, seed=3)
-        assert out["saturation_qps"] == 200.0
-        assert out["users"] == 20_000
-        assert out["load_kind"] == "synthetic-open-loop-zipf-flood"
-        assert out["touched_users"] > 0
-        (point,) = out["points"]
-        assert point["offered_mult"] == 4.0
-        assert point["offered_qps_target"] == 800.0
-        assert point["accounting_ok"], point
-        assert point["offered_requests"] == (
-            point["completed"] + point["sheds"] + point["overloads"]
-            + point["timeouts"] + point["failed"])
-        for key in ("goodput_qps", "p99_ms", "hedges_fired", "hedges_won",
-                    "hedges_cancelled", "sheds_by_class",
-                    "admission_transitions", "offered_qps_achieved"):
-            assert key in point, key
+def _replay_open_loop(fleet, plan, resolve_timeout_s=30.0):
+    """Submit every planned request at its planned time whatever has
+    completed (past saturation the driver does not throttle itself), then
+    resolve every admitted future: each request ends as exactly one of the
+    tallied outcomes."""
+    tally = dict(ok=0, coalesced=0, cache_hits=0, sheds=0, overloads=0,
+                 timeouts=0, failed=0)
+    futs = []
+    t0 = time.monotonic()
+    for r in plan.requests:
+        wait = t0 + r.t_s - time.monotonic()
+        if wait > 0:
+            time.sleep(wait)
+        try:
+            futs.append(fleet.submit(r.ids, r.vals, affinity=r.user_id,
+                                     value=r.value))
+        except AdmissionShed:
+            tally["sheds"] += 1
+        except ServerOverloaded:
+            tally["overloads"] += 1
+    deadline = time.monotonic() + resolve_timeout_s
+    for fut in futs:
+        try:
+            fut.result(timeout=max(0.05, deadline - time.monotonic()))
+        except ServeTimeout:
+            tally["timeouts"] += 1
+            fut.cancel()
+            continue
+        except Exception:  # noqa: BLE001 — typed into the identity
+            tally["failed"] += 1
+            continue
+        tally["ok"] += 1
+        tally["coalesced"] += bool(getattr(fut, "coalesced", False))
+        tally["cache_hits"] += bool(getattr(fut, "cache_hit", False))
+    return tally
 
-    @pytest.mark.slow
-    def test_full_flood_sweep(self, tmp_path):
-        import bench_serving
-        report = bench_serving.run_flood(
-            report_path=str(tmp_path / "FLOOD_test.json"),
-            run_secs=1.5, verbose=False)
-        assert report["ok"]
-        assert report["overload_drill"]["ladder_engaged"]
-        top = max(report["flood"]["points"],
-                  key=lambda p: p["offered_mult"])
-        assert top["sheds"] + top["overloads"] > 0
+
+def _slow_first_col_predict(feat_ids, feat_vals):
+    time.sleep(0.02)   # 8 rows a flush -> ~400 rows/s a replica
+    return feat_ids[:, 0].astype(np.float32) * 0.001 + feat_vals[:, 0] * 0.1
+
+
+class TestServingAccountingIdentity:
+    """offered == completed + coalesced + sheds + overloads + timeouts +
+    failed, read from the fleet's own ``ServingStats`` — a request the
+    engines lost (or counted twice) breaks the sum. The flood is offered
+    past the fleet's service rate so that the refusal terms are not zero."""
+
+    @pytest.mark.parametrize("gate,fast_path", [
+        (False, False), (True, False), (True, True)],
+        ids=["queue_full", "admission_gate", "gate_cache_coalesce"])
+    def test_flood_reconciles_with_engine_counters(self, gate, fast_path):
+        replicas = 2
+        plan = FloodTrafficPlan(
+            9, offered_qps=1500.0, duration_s=0.6,
+            population=ZipfUserPopulation(3, users=2_000, hist_len=4),
+            field_size=3, feature_size=64,
+            repeat_p=0.6 if fast_path else 0.0)
+        engine_kw = dict(max_batch=8, max_delay_ms=1, queue_rows=32)
+        if gate:
+            engine_kw.update(admission_kw=dict(shed_watermark=16))
+        if fast_path:
+            engine_kw.update(cache_rows=256, coalesce=True)
+        fleet = ReplicatedEngine(
+            [ServingEngine(_slow_first_col_predict, **engine_kw)
+             for _ in range(replicas)])
+        try:
+            got = _replay_open_loop(fleet, plan)
+        finally:
+            fleet.close(timeout=30)
+        s = fleet.summary()
+        assert got["timeouts"] == 0 and got["failed"] == 0, got
+        # Past saturation: the gate sheds by value class before the queue
+        # fills; without it the full queue refuses.
+        assert got["sheds" if gate else "overloads"] > 0, got
+        assert gate or got["sheds"] == 0, got
+        # The engines' view. A cache hit and a coalesced join are answered
+        # requests (serving_requests counts them); a fleet-level refusal is
+        # one refusal on EVERY replica, and a refusal followed by a spill
+        # that was admitted is one refusal and one spill.
+        assert s["serving_requests"] == got["ok"], (s, got)
+        assert s["serving_failed"] == 0
+        assert s["serving_coalesced"] == got["coalesced"]
+        assert s["serving_cache_hits"] == got["cache_hits"]
+        assert (s["serving_sheds"] + s["serving_overloads"]
+                == replicas * (got["sheds"] + got["overloads"])
+                + fleet.spills), (s, got, fleet.spills)
+        completed = s["serving_requests"] - s["serving_coalesced"]
+        refused = (s["serving_sheds"] + s["serving_overloads"]
+                   - fleet.spills) // replicas
+        assert len(plan.requests) == (
+            completed + s["serving_coalesced"] + refused
+            + s["serving_failed"] + got["timeouts"])
+        assert sum(s["serving_sheds_by_class"].values()) == s["serving_sheds"]
+        if fast_path:
+            assert got["cache_hits"] > 0, got
+        else:
+            assert got["cache_hits"] == 0 and got["coalesced"] == 0, got
+            assert s["serving_rows"] == got["ok"]   # one row a request

@@ -240,8 +240,7 @@ def test_select_writeback_matches_scatter_writeback():
     """The counting plan's touched/rank companions enable a select-based
     writeback; it must place exactly the same rows as the ids scatter.
     (The trainer still strips it — the vocab-shaped where perturbs the
-    backward's fusion at ~1 ULP — but the leg itself is element-exact,
-    recorded as a parity loss in EMBED_r02.json.)"""
+    backward's fusion at ~1 ULP — but the leg itself is element-exact.)"""
     rng = np.random.default_rng(6)
     rows_n, d = 20, 3
     ids = jnp.asarray(_ids((6, 3), rows_n, seed=6))

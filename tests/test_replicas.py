@@ -1,8 +1,7 @@
 """Replica scale-out tests: sticky/spill routing, dead-replica re-route,
 fleet-wide drain-on-close, staggered swap coordination, aggregate stats,
 frontend client-affinity passthrough, and the tier-1 serving smoke (lane
-p99 <= global p99 under a bypass-favoring load; bench serving series emits
-every honesty-label field — a schema check, not a perf gate)."""
+p99 <= global p99 under a bypass-favoring load)."""
 
 import threading
 import time
@@ -583,7 +582,7 @@ class TestFrontendAffinity:
 
 
 # ---------------------------------------------------------------------------
-# Tier-1 serving smoke (satellite: lane p99 + bench schema)
+# Tier-1 serving smoke (lane p99)
 # ---------------------------------------------------------------------------
 
 class TestServingSmoke:
@@ -610,26 +609,3 @@ class TestServingSmoke:
             assert s["serving_small_p99_ms"] <= s["serving_p99_ms"], s
         finally:
             eng.close()
-
-    def test_bench_serving_series_emits_honesty_schema(self):
-        """Schema check, not a perf gate: the bench serving series must
-        carry every honesty-label and lane/policy field the SERVING_r0N
-        reports are read by."""
-        import bench
-        out = bench.serving_series(run_secs=0.5, n_clients=2)
-        required = {
-            "replicas", "serve_inflight", "serve_small_rows",
-            "serving_p50_ms", "serving_p99_ms",
-            "serving_small_p50_ms", "serving_small_p99_ms",
-            "serving_large_p50_ms", "serving_large_p99_ms",
-            "serving_qps", "batch_occupancy_pct",
-            "swap_blackout_ms", "swap_blackout_ms_per_replica",
-            "serving_requests", "serving_failed", "serving_overloads",
-            "hot_swaps", "swap_failures", "clients",
-            "load_kind", "device_kind", "host_cpu_count",
-        }
-        missing = required - set(out)
-        assert not missing, f"bench serving series lost fields: {missing}"
-        assert out["load_kind"] == "synthetic-closed-loop"
-        assert out["device_kind"]
-        assert out["serving_failed"] == 0

@@ -16,9 +16,6 @@ Trajectory contracts pinned here:
   param copy bit-identical while tracking the single-device trajectory
   (the ground truth for synchronized data parallelism).
 """
-import os
-import sys
-
 import numpy as np
 import pytest
 
@@ -187,21 +184,23 @@ class TestDoubleBufferedStaging:
         s2, o2 = outs[2][1], outs[2][2]
         for la, lb in zip(_leaves(s1), _leaves(s2)):
             np.testing.assert_array_equal(la, lb)
-        for o in (o1, o2):
-            assert 0.0 <= o["staging_overlap_fraction"] <= 1.0
-            assert o["staging_transfer_s"] >= 0.0
-            assert o["staging_wait_s"] >= 0.0
+        assert o1["loss"] == o2["loss"] and o1["steps"] == o2["steps"] == 6
 
-    def test_ring_fences_and_instrumentation(self):
+    def test_ring_fences(self):
         ring = _StagingRing(2)
         for i in range(4):
             assert ring.put(lambda i=i: i) == i
             ring.retire(jnp.zeros(()))
+        assert ring.staged == ring.dispatched == 4
+        # Transfers 3 and 4 each consumed one fence (dispatches 1 and 2);
+        # the first two slots were free.
+        assert ring._fences.qsize() == 2
         ring.close()
-        assert 0.0 <= ring.overlap_fraction() <= 1.0
-        assert ring.transfer_s >= 0.0 and ring.wait_s >= 0.0
-        # An untouched ring reports full overlap (nothing ever fenced).
-        assert _StagingRing(1).overlap_fraction() == 1.0
+        # A closed ring never strands the staging thread on a fence.
+        drained = _StagingRing(1)
+        drained.put(lambda: 0)
+        drained.close()
+        assert drained.put(lambda: 1) == 1
 
     def test_staged_size(self):
         b = _batches(1, 16)[0]
@@ -313,50 +312,3 @@ class TestHierarchicalReduction:
         half = mesh_lib.grad_payload_bytes(params, ("emb_w",), model_size=2)
         assert full == 100 * 8 * 4 + 48 * 16 * 4
         assert half == 100 * 8 * 4 // 2 + 48 * 16 * 4
-
-
-@pytest.mark.multichip
-@pytest.mark.slow
-class TestRealMultiprocess:
-    def test_two_process_overlap_run(self, tmp_path):
-        # Real 2-process jax.distributed rendezvous through the rewritten
-        # bench harness (gated on the cross-process-collectives probe).
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "scripts"))
-        import bench_multiprocess as bmp
-
-        from deepfm_tpu.data import libsvm
-        data = str(tmp_path / "data")
-        libsvm.generate_synthetic_ctr(
-            data, num_files=2, examples_per_file=2048,
-            feature_size=500, field_size=6, prefix="tr", seed=1)
-        r = bmp.run_once(data, str(tmp_path / "model"), staging_buffers=2,
-                         epochs=1, n_devices=1, multiprocess=True)
-        assert float(r["examples_per_sec"]) > 0
-
-
-class TestScalingEfficiencyRefusal:
-    def test_refused_off_real_devices(self):
-        sys.path.insert(0, os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "scripts"))
-        import bench_multiprocess as bmp
-
-        row = bmp.scaling_efficiency_row(bmp.TIMESLICE, 2, 100.0, 60.0)
-        assert row["scaling_efficiency"] is None
-        assert "refused" in row["scaling_efficiency_reason"]
-        row = bmp.scaling_efficiency_row(bmp.REAL, 2, 100.0, 60.0)
-        assert row["scaling_efficiency"] == round(100.0 / 120.0, 4)
-
-    def test_mfu_only_against_a_published_peak(self):
-        from deepfm_tpu.utils import mfu as mfu_lib
-        # conftest pins the CPU backend: a host has no spec-sheet peak, so
-        # there is no MFU — not one against a nominal constant.
-        kind = jax.devices()[0].device_kind
-        assert mfu_lib.peak_flops(kind) is None
-        assert mfu_lib.mfu_pct(1e6, 1e4, kind) is None
-        assert mfu_lib.mfu_pct(1e6, 1e4, "TPU v7x-unknown") is None
-        assert mfu_lib.peak_flops("TPU v5 lite") == 197e12
-        assert mfu_lib.mfu_pct(1e9, 1e4, "TPU v5 lite") == pytest.approx(
-            100.0 * 1e9 * 1e4 / 197e12, rel=1e-4)

@@ -2,9 +2,9 @@
 result cache (copy semantics, TTL, eviction), cache/hot-swap interaction
 through the engine (hit before swap, stale-version miss after, TTL expiry,
 LRU under concurrent submit, shadow bypass never warms), in-flight
-coalescing (join/fan-out, leader cancel refusal, error propagation), the
-repeat-flood knob, and the tier-1 flood smoke over ``bench.overload_point``
-with the extended accounting identity."""
+coalescing (join/fan-out, leader cancel refusal, error propagation) and the
+repeat-flood knob. The accounting identity with the fast path armed is
+``test_overload.py::TestServingAccountingIdentity``."""
 
 import os
 import sys
@@ -488,32 +488,6 @@ class TestRepeatFlood:
             FloodTrafficPlan(9, offered_qps=10.0, duration_s=0.5,
                              population=_population(), field_size=FIELD_SIZE,
                              feature_size=64, repeat_p=1.0)
-
-    def test_flood_smoke_fast_path_accounting(self):
-        """bench.overload_point over a repeat-heavy flood with the fast
-        path armed: the extended identity closes (offered == completed +
-        coalesced + sheds + overloads + timeouts + failed) and the cache
-        saw real traffic."""
-        import bench
-        plan = FloodTrafficPlan(9, offered_qps=300.0, duration_s=1.0,
-                                population=_population(),
-                                field_size=FIELD_SIZE, feature_size=64,
-                                repeat_p=0.6)
-        fleet = ReplicatedEngine(
-            [ServingEngine(first_col_predict, max_batch=8, max_delay_ms=1,
-                           cache_rows=256, coalesce=True)
-             for _ in range(2)])
-        try:
-            point = bench.overload_point(fleet, plan, slo_ms=1000.0,
-                                         resolve_timeout_s=30.0)
-        finally:
-            fleet.close(timeout=30)
-        assert point["accounting_ok"], point
-        assert point["offered_requests"] == (
-            point["completed"] + point["coalesced"] + point["sheds"]
-            + point["overloads"] + point["timeouts"] + point["failed"])
-        assert point["cache_hits"] > 0, point
-        assert point["failed"] == 0 and point["timeouts"] == 0, point
 
 
 # ---------------------------------------------------------------------------

@@ -147,6 +147,40 @@ class TestLaunchCli:
         assert payload["task"] == "eval"
         assert payload["auc"] > 0.5
 
+    def test_cli_train_logs_and_returns_the_rate(self, workdir, capsys,
+                                                 caplog):
+        """The operator's instrument: a launcher train run logs
+        ``examples/sec=`` every ``log_steps`` and returns
+        ``examples_per_sec`` in its result line, beside no timing of the
+        staging ring (that is the ``stage.*`` spans' job)."""
+        import logging
+
+        from deepfm_tpu import launch
+        model_flags = [
+            "--data_dir", str(workdir / "data"),
+            "--val_data_dir", str(workdir / "data"),
+            "--model_dir", str(workdir / "ckpt_cli_train"),
+            "--feature_size", "300", "--field_size", "5",
+            "--embedding_size", "8", "--deep_layers", "16,8",
+            "--dropout", "1.0,1.0", "--batch_size", "64",
+            "--compute_dtype", "float32", "--mesh_data", "1",
+            "--mesh_model", "1"]
+        with caplog.at_level(logging.INFO, logger="deepfm_tpu"):
+            rc = launch.main(["--task_type", "train", "--num_epochs", "1",
+                              "--log_steps", "4", *model_flags])
+        assert rc == 0
+        assert any("examples/sec=" in r.getMessage()
+                   for r in caplog.records)
+        payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert payload["task"] == "train" and payload["steps"] == 12
+        assert payload["examples_per_sec"] > 0
+        assert not [k for k in payload if k.startswith("staging_")]
+        rc = launch.main(["--task_type", "eval", "--log_steps", "0",
+                          *model_flags])
+        assert rc == 0
+        payload = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+        assert payload["task"] == "eval" and 0.0 <= payload["auc"] <= 1.0
+
 
 class TestStreamingMode:
     """Pipe-mode analog (--pipe_mode 1): one sequential stream, epochs

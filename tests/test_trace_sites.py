@@ -5,8 +5,10 @@ thread, the staging ring, the dispatch loop and the collector (TUNING §17).
 
 import faulthandler
 import gc
+import os
 import re
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -132,6 +134,32 @@ def test_pooled_pipeline_is_silent_when_off(tmp_path, monkeypatch):
     assert trace_lib._tracer.events() == [] and not built
 
 
+def test_pipeline_reads_no_environment_knob(tmp_path, monkeypatch):
+    """The input thread's pace is the data's: the two ``DEEPFM_TPU_*``
+    variables that once slept a given number of ns per record in the drain
+    and in the staging transfer are read by nothing. At 1 s a record,
+    honouring either would take this test minutes."""
+    def emission():
+        t0 = time.monotonic()
+        out = [(k, n, {name: a.tobytes() for name, a in rows.items()})
+               for rows, k, n in _pipeline(tmp_path).iter_superbatches(K)]
+        return out, time.monotonic() - t0
+
+    for name in [n for n in os.environ if n.startswith("DEEPFM_TPU_")]:
+        monkeypatch.delenv(name)
+    plain, _ = emission()
+    for leg in ("HOST", "TRANSFER"):
+        monkeypatch.setenv(f"DEEPFM_TPU_SYNTH_{leg}_NS_PER_RECORD",
+                           "1000000000")
+    knobbed, seconds = emission()
+    assert knobbed == plain and sum(n for _, n, _ in plain) == 2 * 192
+    assert seconds < 60.0
+    tr = Trainer(_cfg())
+    t0 = time.monotonic()
+    _, out = tr.fit(tr.init_state(), _batches(K * 2))
+    assert out["steps"] == K * 2 and time.monotonic() - t0 < 60.0
+
+
 # ---------------------------------------------------------------------------
 # (c) the collector
 # ---------------------------------------------------------------------------
@@ -213,6 +241,26 @@ def test_fit_spans_share_seq():
                and e["args"]["records"] == K * 32 for e in transfers)
     syncs = [e["args"]["step"] for e in events if e["name"] == "train.log_sync"]
     assert syncs == [K * i for i in want]
+
+
+def test_staging_is_timed_by_its_spans_alone():
+    """``stage.wait`` and ``stage.transfer`` are the staging ring's only
+    clock: each carries exactly the attributes the benchmark's readers and
+    ``trace_report.py --stalls`` key on, and ``fit``'s result repeats
+    neither interval (``examples_per_sec``, the operator's line, stays)."""
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(_cfg())
+    _, out = tr.fit(tr.init_state(), _batches(K * 4))
+    events = [e for e in trace_lib._tracer.events() if e["ph"] == "X"]
+    transfers = [e for e in events if e["name"] == "stage.transfer"]
+    waits = [e for e in events if e["name"] == "stage.wait"]
+    assert len(transfers) == 4 and len(waits) == 4 - tr.cfg.staging_buffers
+    assert {frozenset(e["args"]) for e in transfers} == {
+        frozenset(("seq", "records", "bytes"))}
+    assert {frozenset(e["args"]) for e in waits} == {frozenset(("seq",))}
+    assert all(e["dur"] >= 0 for e in transfers + waits)
+    assert not [k for k in out if k.startswith("staging_")]
+    assert out["examples_per_sec"] > 0 and out["steps"] == K * 4
 
 
 # ---------------------------------------------------------------------------

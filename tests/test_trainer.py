@@ -164,7 +164,7 @@ class TestDistributedParity:
     def test_allgather_lookup_matches_masked_psum(self, data_files):
         """Both sharded-lookup strategies train to the same weights (the
         collective pattern is an implementation detail of the same gather);
-        see scripts/bench_embedding.py + TUNING.md for when each wins."""
+        see TUNING.md for when each wins."""
         _, s_psum, ev_psum = self._run(
             _cfg(mesh_data=4, mesh_model=2), data_files, steps=6)
         _, s_ag, ev_ag = self._run(
