@@ -94,9 +94,9 @@ def make_shards(out_dir: str, shape: Dict[str, object], prefix: str,
 
 def check_kernels(shape: Dict[str, object], *, interpret: bool = False
                   ) -> dict:
-    """fused_fm and take_rows_pallas, forward and backward, against their
-    XLA legs at (B, F, K) of ``shape``. ``interpret=False`` is the compiled
-    path (TPU only); the CPU rehearsal passes True."""
+    """fused_fm and take_rows_pallas, forward and backward, and put_rows
+    against their XLA legs at (B, F, K) of ``shape``. ``interpret=False``
+    is the compiled path (TPU only); the CPU rehearsal passes True."""
     import jax
     import jax.numpy as jnp
 
@@ -149,6 +149,26 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
     err = max_rel(g, g_ref)
     assert err <= 1e-5, f"take_rows_pallas backward vs XLA: {err}"
     out["take_rows_bwd_max_rel_err"] = err
+
+    # put_rows: a table and its twin, distinct rows with a spare tail (ids
+    # past the table: skipped, never copied) in one launch, bit for bit
+    # XLA's scatter. A row is one 128-lane line whatever the shape's K.
+    from deepfm_tpu.ops import pallas_put_rows
+
+    real, spare = n - n // 8, n // 8
+    tabs = [jnp.asarray(rng.normal(size=(4 * n, 128)), jnp.float32)
+            for _ in range(2)]
+    news = [jnp.asarray(rng.normal(size=(n, 128)), jnp.float32)
+            for _ in range(2)]
+    uids = jnp.asarray(np.concatenate([
+        np.sort(rng.choice(4 * n, real, replace=False)),
+        4 * n + np.arange(spare)]), jnp.int32)
+    got = jax.jit(lambda t, u, r: pallas_put_rows.put_rows_many(
+        t, u, r, interpret=interpret))(tabs, uids, news)
+    for t, r, g in zip(tabs, news, got):
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(t.at[uids].set(r, mode="drop")))
+    out["put_rows_slots_written"] = real
     return out
 
 

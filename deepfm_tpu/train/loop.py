@@ -23,7 +23,8 @@ import math
 import queue
 import threading
 import time
-from typing import Any, Callable, Dict, Iterable, Iterator, Optional, Tuple
+from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +38,7 @@ from ..models import get_model
 from ..obs import trace as trace_lib
 from ..ops import embedding as emb_ops
 from ..ops import pallas_embedding as pemb
+from ..ops import pallas_put_rows
 from ..parallel import mesh as mesh_lib
 from ..utils import logging as ulog
 from ..utils import profiling as prof_lib
@@ -52,13 +54,23 @@ _CPU_TEST_MEMORY_BYTES = 16 << 30
 
 # Distinct rows one trip of the row-local table update gathers, updates and
 # writes back (``Trainer._update_rows``): a batch with more distinct rows
-# takes another trip. Chosen on the chip (PERF.md §6, PR 28): a trip costs
-# its slots, filled or spare (a dropped row costs 0.87 of a written one) and
-# nothing beside, so a small capacity only wastes less of the last trip
-# (15 trips of 2,048 read 15.86 ms a step where 1 of 32,768 reads 16.16).
+# takes another trip. Chosen on the chip, once for each way the rows go
+# back. By XLA's scatter (PERF.md §6, PR 28): a trip costs its slots, filled
+# or spare (a dropped row costs 0.87 of a written one) and nothing beside,
+# so a small capacity only wastes less of the last trip (15 trips of 2,048
+# read 15.86 ms a step where 1 of 32,768 reads 16.16). By the
+# ``embed_put_rows`` kernel (PERF.md §6, PR 30): the same holds — a spare
+# slot costs the kernel's loop what a written one does, a launch nothing
+# that shows (15 trips of 2,048 read 12.33 ms a step; 8 / 4 / 2 trips of
+# 4,096 / 8,192 / 16,384, 32,768 slots each, 12.50 / 12.49 / 12.49; read
+# before the new rows were held in HBM, which took 0.54 ms off every one)
+# — so both share the one constant.
 ROW_UPDATE_CAPACITY = 2048
 #: What that update reports beside the step's loss: distinct rows, trips.
 ROW_COUNTS = ("embed_distinct_rows", "embed_row_trips")
+#: And how its rows went back: "dma", "scatter", or "dma+scatter" where a
+#: model's tables differ in row shape (``Trainer.row_writeback``).
+ROW_WRITEBACK = "embed_row_writeback"
 
 
 def pad_batch(batch: Dict[str, np.ndarray], bs: int) -> Dict[str, np.ndarray]:
@@ -258,6 +270,9 @@ class Trainer:
         # None outside fit so eval/predict transfers pass through untouched.
         self._ring: Optional[_StagingRing] = None
         self._grad_bytes_cache: Optional[int] = None
+        # How the row-local update writes its rows back (ROW_WRITEBACK):
+        # static per compiled step, known once ``_update_rows`` is traced.
+        self.row_writeback: Optional[str] = None
 
     # ------------------------------------------------------------------
     # State creation / placement
@@ -507,11 +522,19 @@ class Trainer:
         ``g_views`` of the tables' ``[..., *row]`` views at ``ids``; rows no
         id names are not read. A trip handles ``ROW_UPDATE_CAPACITY``
         distinct rows (sorted, so the last trip's spare slots lie past the
-        table: read as fill, dropped on the way back), and the trips are as
-        many as the batch needs: exact for any batch at static shapes.
+        table: read as fill, dropped or skipped on the way back), and the
+        trips are as many as the batch needs: exact for any batch at static
+        shapes.
         All of it is the ``embed`` scope's but the rows' arithmetic
         (``_optax_apply``: ``opt``, the innermost scope wins)."""
         names, cap = self._embed_names, ROW_UPDATE_CAPACITY
+        how = "+".join(sorted({
+            "dma" if pallas_put_rows.supported(t) else "scatter"
+            for t in jax.tree.leaves((tabs, opt_tabs))}))
+        if how != self.row_writeback:   # said once a trainer, at trace time
+            self.row_writeback = how
+            ulog.info(f"row-local table update: {cap} rows a trip, written "
+                      f"back by {how}")
         widths = [math.prod(tabs[n].shape[1:]) for n in names]
         cuts = np.cumsum([0] + widths)
         rows = emb_ops.sum_rows(
@@ -523,11 +546,28 @@ class Trainer:
         def take(table, uids):
             return jnp.take(table, uids, axis=0, mode="fill", fill_value=0)
 
-        def put(table, uids, new):
-            # Distinct and ascending, and XLA is told neither: with
-            # indices_are_sorted the TPU compiler sweeps the whole table
-            # (9.7 ms whatever the rows; PERF.md §6, PR 28).
-            return table.at[uids].set(new, mode="drop")
+        def put(old, new, uids):
+            """The tree ``old`` of table-tall arrays with rows ``uids`` of
+            each set to ``new``'s. Distinct, ascending, in bounds or past
+            the table. Where a row is whole 128-lane lines on a TPU, one
+            DMA a slot (spare slots skipped), one launch for the arrays of
+            one shape; else XLA's scatter, which is told neither property:
+            with indices_are_sorted the TPU compiler sweeps the whole table
+            (9.7 ms whatever the rows; PERF.md §6, PR 28)."""
+            olds, tree = jax.tree.flatten(old)
+            news = tree.flatten_up_to(new)
+            by_dma: Dict[Tuple[int, ...], List[int]] = {}
+            for k, table in enumerate(olds):
+                if pallas_put_rows.supported(table):
+                    by_dma.setdefault(table.shape, []).append(k)
+                else:
+                    olds[k] = table.at[uids].set(news[k], mode="drop")
+            for at in by_dma.values():
+                done = pallas_put_rows.put_rows_many(
+                    [olds[k] for k in at], uids, [news[k] for k in at])
+                for k, table in zip(at, done):
+                    olds[k] = table
+            return jax.tree.unflatten(tree, olds)
 
         def trip(carry):
             i, tabs, opt_tabs = carry
@@ -538,9 +578,7 @@ class Trainer:
             new, new_opt = self._optax_apply(
                 g, jax.tree.map(lambda t: take(t, uids), opt_tabs),
                 {n: take(tabs[n], uids) for n in names})
-            return (i + 1, {n: put(tabs[n], uids, new[n]) for n in names},
-                    jax.tree.map(lambda t, r: put(t, uids, r),
-                                 opt_tabs, new_opt))
+            return (i + 1, *put((tabs, opt_tabs), (new, new_opt), uids))
 
         _, tabs, opt_tabs = jax.lax.while_loop(
             lambda carry: carry[0] < trips, trip,
@@ -1941,8 +1979,11 @@ class Trainer:
                         if trace_lib.enabled():
                             # the row-local update's counts (the last
                             # scanned step's, like the loss): ready with it
-                            sync.add(**{key: int(m[key])
-                                        for key in ROW_COUNTS if key in m})
+                            counts = {key: int(m[key])
+                                      for key in ROW_COUNTS if key in m}
+                            if counts:
+                                counts[ROW_WRITEBACK] = self.row_writeback
+                            sync.add(**counts)
                     last_loss = loss
                     if guard is not None and not guard_active:
                         # abort policy: reuse the loss scalar this log line

@@ -10,8 +10,9 @@ body (that is where the scanned steps live), its name, opcode, results,
 operands (``*`` after a table-shaped one), the operands the backend updates
 in place, the ``jax.named_scope`` the trainer gave it and the primitive it
 came from. Each line is at least one pass over a table in HBM, except an
-``R`` line: a scatter that writes rows into a table in place costs its
-rows. A step whose only lines are ``R`` updates the tables on the rows the
+``R`` line: a scatter, or the ``embed_put_rows`` kernel (one DMA a row,
+``ops/pallas_put_rows.py``), that writes rows into a table in place costs
+its rows. A step whose only lines are ``R`` updates the tables on the rows the
 batch touched (``Trainer._row_local_eligible``); ``docs/TUNING.md`` §5 says
 how to count the others. Last, the step's ``memory_analysis()``. It takes
 ~20 s and says nothing about time: times are the chip's
@@ -72,8 +73,9 @@ def main(argv=None) -> int:
         with open(args.hlo_out, "w") as f:
             f.write(text)
     ops = profiling.hlo_table_ops(text, rows)
-    row_writes = [o for o in ops if o["in_place"]
-                  and o["primitive"].startswith("scatter")]
+    row_writes = [o for o in ops if o["in_place"] and (
+        o["primitive"].startswith("scatter")
+        or o["name"].startswith("embed_put_rows"))]
     print(f"{args.workload} compiled for {args.topology}: "
           f"{len(ops)} instructions make an array {rows} rows tall "
           f"({sum(o['loop_body'] for o in ops)} in a loop body); "

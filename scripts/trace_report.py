@@ -31,7 +31,9 @@ Where the train step updates its tables on the rows the batch touched
 ``train.log_sync`` carries the last scanned step's ``embed_distinct_rows``
 and ``embed_row_trips``; the report prints their mean and maximum and the
 share of those steps that took one trip (a capacity that most steps
-overflow by a little pays a second trip for it).
+overflow by a little pays a second trip for it), and how the compiled step
+writes its rows back (``embed_row_writeback``: ``dma``, one asynchronous
+copy a row, or ``scatter``, XLA's; TUNING §5).
 
 Usage:
     python scripts/trace_report.py TRACE.json [--top 20] [--json]
@@ -179,9 +181,10 @@ def stalls(events, threshold_ms):
 def row_updates(events):
     """The row-local table update's counters off the ``train.log_sync``
     spans that carry them: ``steps`` read, mean and max of
-    ``embed_distinct_rows`` and ``embed_row_trips``, and ``one_trip_share``
-    of those steps; None when no span has them (the step sweeps the table,
-    or the trace predates the counters)."""
+    ``embed_distinct_rows`` and ``embed_row_trips``, ``one_trip_share`` of
+    those steps and the step's ``writeback`` (``embed_row_writeback``; "?"
+    in a trace that predates it); None when no span has them (the step
+    sweeps the table, or the trace predates the counters)."""
     seen = [e["args"] for e in events
             if e.get("name") == "train.log_sync" and e.get("ph") == "X"
             and "embed_distinct_rows" in e.get("args", {})]
@@ -194,7 +197,8 @@ def row_updates(events):
             "distinct_rows_max": max(rows),
             "row_trips_mean": sum(trips) / len(trips),
             "row_trips_max": max(trips),
-            "one_trip_share": sum(t == 1 for t in trips) / len(trips)}
+            "one_trip_share": sum(t == 1 for t in trips) / len(trips),
+            "writeback": seen[-1].get("embed_row_writeback", "?")}
 
 
 def main(argv=None):
@@ -247,11 +251,12 @@ def main(argv=None):
     if touched is not None:
         print("row-local table update over %d logged steps: "
               "embed_distinct_rows mean %.0f max %d, embed_row_trips mean "
-              "%.2f max %d, one trip in %.0f%% of them" % (
+              "%.2f max %d, one trip in %.0f%% of them, rows written back "
+              "by %s" % (
                   touched["steps"], touched["distinct_rows_mean"],
                   touched["distinct_rows_max"], touched["row_trips_mean"],
                   touched["row_trips_max"],
-                  100 * touched["one_trip_share"]))
+                  100 * touched["one_trip_share"], touched["writeback"]))
     for st in slow or ():
         cover = ", ".join(f"{k} {v:.1f}" for k, v in st["cover_ms"].items()
                           if v > 0)

@@ -63,7 +63,8 @@ def test_kernel_phase_rehearsal():
     out = chip_smoke.check_kernels(TINY, interpret=True)
     assert set(out) == {"fused_fm_float32_max_rel_err",
                         "fused_fm_bfloat16_max_rel_err",
-                        "take_rows_bwd_max_rel_err"}
+                        "take_rows_bwd_max_rel_err",
+                        "put_rows_slots_written"}
 
 
 @pytest.mark.slow

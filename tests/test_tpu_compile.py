@@ -3,7 +3,9 @@
 runs; the ``on-chip-measurement`` guide, section 2): the row-local table
 update (``Trainer._update_rows``; PERF.md §6, PR 28) writes its rows into
 the tables **in place inside the step's loop** — no copy of a table, no
-sweep — which XLA:CPU's text cannot show (it reports no aliasing).
+sweep — which XLA:CPU's text cannot show (it reports no aliasing); and
+where a row is whole 128-lane lines it writes them by the ``embed_put_rows``
+kernel (one DMA a row; PERF.md §6, PR 30), which only Mosaic can compile.
 
 One file, and the topology is described inside a fixture: only one process
 may hold libtpu, and every xdist worker imports every test file.
@@ -19,9 +21,13 @@ from deepfm_tpu.train import Trainer
 from deepfm_tpu.utils import profiling
 
 # MLPerf DLRM-DCNv2's row (K=128, one 128-lane line) and field split, with
-# the heights, batch and towers cut so that the compile takes seconds.
+# the heights, batch and towers cut so that the compile takes seconds. The
+# table stays taller than VMEM (205 MB against 128 MiB): one that fits is
+# prefetched there whole by XLA around the ``embed_put_rows`` kernel
+# (``slice-start`` / ``copy-start`` of the table), which no benchmark cell's
+# table can be.
 FLAGS = dict(
-    model="dlrm_dcnv2", feature_size=200_000, field_size=39,
+    model="dlrm_dcnv2", feature_size=400_000, field_size=39,
     numeric_fields=13, embedding_size=128, bottom_layers="64,128",
     cross_layers=1, cross_rank=64, deep_layers="128,64",
     dropout="1,1", optimizer="Adagrad", learning_rate=0.004, l2_reg=0.0,
@@ -59,20 +65,65 @@ def _compiled(device, monkeypatch, **over):
     return tr, tr.step_compiled(device=device)
 
 
-def test_row_local_step_writes_rows_in_place_inside_the_loop(
-        v5e, no_compile_cache, monkeypatch):
-    tr, compiled = _compiled(v5e, monkeypatch)
-    assert tr._row_local_eligible()
+def _row_writes(tr, compiled):
+    """The step's table-tall instructions: for a row-local step, the table's
+    and its accumulator's row writes, inside the loop, in place, and nothing
+    else as tall as a table — no fill, no sweep, no copy."""
     ops = profiling.hlo_table_ops(compiled.as_text(), tr.model.padded_vocab)
-    # the table and its accumulator, one row write each, and nothing else
-    # as tall as the table: no fill, no sweep, no copy
-    assert len(ops) == 2, ops
     for op in ops:
         assert op["loop_body"] and op["scope"] == "embed", op
-        assert op["primitive"] == "scatter" and op["in_place"] == [0], op
     # the step's temporaries are the batch's, not a table's
-    table_bytes = tr.model.padded_vocab * 128 * 4
+    table_bytes = tr.model.padded_vocab * tr.cfg.embedding_size * 4
     assert compiled.memory_analysis().temp_size_in_bytes < table_bytes / 2
+    return ops
+
+
+def test_row_local_step_writes_rows_by_dma_in_place_inside_the_loop(
+        v5e, no_compile_cache, monkeypatch):
+    """K=128, a row one 128-lane line: one ``embed_put_rows`` kernel
+    (``ops/pallas_put_rows.py``) writes the table's and the accumulator's
+    rows, each array aliased to its result (operands: ids, two of new rows,
+    the two tables). An alias XLA did not honour would show as a ``copy``
+    of the table here."""
+    tr, compiled = _compiled(v5e, monkeypatch)
+    assert tr._row_local_eligible() and tr.row_writeback == "dma"
+    (op,) = _row_writes(tr, compiled)
+    assert op["opcode"] == "custom-call", op
+    assert op["name"].startswith("embed_put_rows"), op
+    assert op["results"] == ["f32[400000,128]"] * 2, op
+    assert op["primitive"] == "pallas_call" and op["in_place"] == [3, 4], op
+
+
+def test_row_local_step_at_k32_keeps_the_scatter(v5e, no_compile_cache,
+                                                 monkeypatch):
+    """K=32: ids run along the lanes, a row is not one line, and the
+    write-back stays XLA's scatter, in place inside the loop as before."""
+    tr, compiled = _compiled(v5e, monkeypatch, embedding_size=32,
+                             bottom_layers="64,32", feature_size=1_600_000)
+    assert tr._row_local_eligible() and tr.row_writeback == "scatter"
+    ops = _row_writes(tr, compiled)
+    assert len(ops) == 2, ops
+    for op in ops:
+        assert op["primitive"] == "scatter" and op["in_place"] == [0], op
+    assert "embed_put_rows" not in compiled.as_text()
+
+
+def test_row_local_deepfm_at_k128_writes_each_table_its_own_way(
+        v5e, no_compile_cache, monkeypatch):
+    """DeepFM under Adagrad at K=128 has two tables: ``fm_v`` ``[V,128]``
+    goes by DMA, the first-order ``fm_w`` ``[V]`` by the scatter; the choice
+    is each array's shape's. (``fm_w`` is 1.6 MB here and XLA prefetches it
+    into VMEM, so only the ``[V,128]`` arrays are held to "nothing else".)"""
+    tr, compiled = _compiled(
+        v5e, monkeypatch, model="deepfm", numeric_fields=0, bottom_layers="",
+        cross_layers=0, cross_rank=0)
+    assert tr._row_local_eligible() and tr.row_writeback == "dma+scatter"
+    ops = profiling.hlo_table_ops(compiled.as_text(), tr.model.padded_vocab)
+    wide = [o for o in ops if "f32[400000,128]" in o["results"]]
+    assert [(o["primitive"], o["in_place"]) for o in wide] == [
+        ("pallas_call", [3, 4])], wide
+    narrow = [o["primitive"] for o in ops if o["in_place"] and o not in wide]
+    assert narrow == ["scatter", "scatter"], ops
 
 
 def test_the_table_shaped_step_still_sweeps(v5e, no_compile_cache,

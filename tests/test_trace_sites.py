@@ -304,15 +304,45 @@ def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
     want = [len(np.unique(batches[K * i - 1]["feat_ids"])) for i in (1, 2, 3)]
     assert [a["embed_distinct_rows"] for a in syncs] == want
     assert [a["embed_row_trips"] for a in syncs] == [1, 1, 1]
+    # XLA:CPU, and a [V,4] row anyway: the write-back is the scatter
+    assert [a["embed_row_writeback"] for a in syncs] == ["scatter"] * 3
     assert report.row_updates(events) == {
         "steps": 3, "distinct_rows_mean": sum(want) / 3,
         "distinct_rows_max": max(want), "row_trips_mean": 1.0,
-        "row_trips_max": 1, "one_trip_share": 1.0}
+        "row_trips_max": 1, "one_trip_share": 1.0, "writeback": "scatter"}
     assert report.main([path]) == 0
     assert ("row-local table update over 3 logged steps: embed_distinct_rows "
             "mean %.0f max %d, embed_row_trips mean 1.00 max 1, one trip in "
-            "100%% of them" % (sum(want) / 3, max(want))
-            ) in capsys.readouterr().out
+            "100%% of them, rows written back by scatter"
+            % (sum(want) / 3, max(want))) in capsys.readouterr().out
+
+
+def test_log_sync_says_dma_where_the_kernel_writes_the_rows(monkeypatch):
+    """``embed_row_writeback`` is the compiled step's choice: with the
+    ``embed_put_rows`` kernel taken (forced on here through the interpreter;
+    on a TPU it is at K=128) every ``train.log_sync`` says ``dma``, and the
+    report's line with it."""
+    import functools
+    from deepfm_tpu.ops import pallas_put_rows as ppr
+    monkeypatch.setattr(ppr, "supported", lambda t: t.ndim == 2)
+    monkeypatch.setattr(ppr, "put_rows_many", functools.partial(
+        ppr.put_rows_many, interpret=True))
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(_cfg(optimizer="Adagrad", l2_reg=0.0, embedding_size=128))
+    tr.fit(tr.init_state(), _batches(K * 2))
+    # deepfm: fm_v [V,128] by the kernel, the first-order fm_w [V] not
+    assert tr.row_writeback == "dma+scatter"
+    events = trace_lib._tracer.events()
+    syncs = [e["args"] for e in events if e["name"] == "train.log_sync"]
+    assert [a["embed_row_writeback"] for a in syncs] == ["dma+scatter"] * 2
+    assert _report().row_updates(
+        [dict(e, ph="X") for e in events])["writeback"] == "dma+scatter"
+
+
+def test_report_reads_a_trace_that_predates_the_writeback_attribute():
+    events = [{"name": "train.log_sync", "ph": "X", "args": {
+        "step": 2, "embed_distinct_rows": 90, "embed_row_trips": 1}}]
+    assert _report().row_updates(events)["writeback"] == "?"
 
 
 def test_log_sync_reads_no_counter_when_tracing_is_off():
