@@ -49,7 +49,7 @@ class Config:
     coordinator_address: str = ""     # jax.distributed coordinator (host:port)
 
     # ---- model hyperparameters (reference: model flags) ----
-    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst
+    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe
     feature_size: int = 117581        # vocabulary size (reference ipynb:85)
     field_size: int = 39              # number of fields (reference ipynb:90)
     embedding_size: int = 32          # latent dim (reference flag default, ...py:44)
@@ -64,6 +64,28 @@ class Config:
     # bottom MLP of widths bottom_layers, whose last width is embedding_size.
     numeric_fields: int = 0
     bottom_layers: str = ""
+    # sdar_moe only (block-diffusion MoE decoder, models/sdar_moe.py): the
+    # model's width is embedding_size, its sequence length history_max_len,
+    # its vocabulary feature_size rows of which the last is [MASK]. A layer is
+    # told what it holds: attn_q_heads/attn_kv_heads are the heads here,
+    # moe_experts_held experts from moe_first_expert on of the moe_experts
+    # the router scores (moe_top_k a token). The sorted (position, expert)
+    # pairs on held experts fill a buffer of moe_pair_capacity rows a layer;
+    # pairs beyond it are counted, never dropped in silence.
+    decoder_layers: int = 0
+    attn_q_heads: int = 0
+    attn_kv_heads: int = 0
+    attn_head_dim: int = 128
+    rope_theta: float = 1e6
+    rms_norm_eps: float = 1e-6
+    moe_experts: int = 0
+    moe_top_k: int = 0
+    moe_expert_width: int = 0
+    moe_experts_held: int = 0
+    moe_first_expert: int = 0
+    moe_pair_capacity: int = 0
+    diffusion_block: int = 4
+    diffusion_t_min: float = 1e-3
     l2_reg: float = 1e-4
     loss_type: str = "log_loss"       # log_loss | square_loss
 
@@ -430,8 +452,14 @@ class Config:
         if self.metrics_snapshot_secs < 0:
             raise ValueError("metrics_snapshot_secs must be >= 0")
         if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm",
-                              "dlrm_dcnv2", "din", "bst"):
+                              "dlrm_dcnv2", "din", "bst", "sdar_moe"):
             raise ValueError(f"unknown model: {self.model!r}")
+        if self.model == "sdar_moe":
+            self._validate_sdar_moe()
+        elif self.decoder_layers or self.moe_experts or self.attn_q_heads:
+            raise ValueError(
+                "decoder_layers/attn_*/moe_* belong to --model sdar_moe; "
+                f"{self.model!r} has no decoder block")
         if self.model == "dlrm_dcnv2":
             self._validate_dlrm_dcnv2()
         elif self.numeric_fields or self.bottom_layers:
@@ -800,6 +828,64 @@ class Config:
         for what, set_ in refused.items():
             if set_:
                 raise ValueError(f"model dlrm_dcnv2 does not take {what}")
+
+    def _validate_sdar_moe(self) -> None:
+        """What the block-diffusion MoE decoder takes, and plainly what it
+        does not (models.sdar_moe.SdarMoE)."""
+        need = {
+            "decoder_layers >= 1": self.decoder_layers >= 1,
+            "attn_q_heads a positive multiple of attn_kv_heads >= 1":
+                self.attn_kv_heads >= 1 and self.attn_q_heads >= 1
+                and self.attn_q_heads % self.attn_kv_heads == 0,
+            "an even attn_head_dim (rotate-half rotary)":
+                self.attn_head_dim >= 2 and self.attn_head_dim % 2 == 0,
+            "1 <= moe_top_k <= moe_experts": 1 <= self.moe_top_k
+                <= self.moe_experts,
+            "moe_expert_width >= 1": self.moe_expert_width >= 1,
+            "moe_experts_held >= 1 experts from moe_first_expert on, all "
+            "among the moe_experts": self.moe_experts_held >= 1
+                and self.moe_first_expert >= 0
+                and self.moe_first_expert + self.moe_experts_held
+                <= self.moe_experts,
+            "moe_pair_capacity >= 1 (rows of a layer's pair buffer; every "
+            "pair of a step is batch_size * 2 * history_max_len * moe_top_k)":
+                self.moe_pair_capacity >= 1,
+            "history_max_len (the sequence length) a positive multiple of "
+            "diffusion_block": self.diffusion_block >= 1
+                and self.history_max_len >= 1
+                and self.history_max_len % self.diffusion_block == 0,
+            "0 < diffusion_t_min < 1": 0.0 < self.diffusion_t_min < 1.0,
+            "feature_size >= 2 (the last row is [MASK])":
+                self.feature_size >= 2,
+        }
+        for what, ok in need.items():
+            if not ok:
+                raise ValueError(f"model sdar_moe needs {what}")
+        refused = {
+            "tasks (the loss is over the positions of a sequence, one task)":
+                self.num_tasks > 1,
+            "loss_type other than log_loss (the loss is the model's own "
+            "cross-entropy)": self.loss_type != "log_loss",
+            "batch_norm (the block's norm is RMSNorm)": self.batch_norm,
+            "embedding_update=sparse (the row plan covers feat_ids; the "
+            "tokens ride hist_ids)": self.embedding_update == "sparse",
+            "embedding_shard=rows (the vocabulary slice is the chip's "
+            "share already; the head is not row-sharded)":
+                self.embedding_shard == "rows",
+            "embedding_buckets (token ids are not hashed)":
+                bool(self.embedding_bucket_sizes),
+            "mesh_model > 1 (experts and heads over a mesh need their "
+            "exchange, which this model does not have)": self.mesh_model > 1,
+            "task_type infer/export (a block-diffusion sampler is a serving "
+            "feature; train and eval report the loss)":
+                self.task_type in ("infer", "export"),
+            "servable_model_dir (no serving export: the exported function "
+            "would be the sampler)": bool(self.servable_model_dir),
+            "online_mode (publishing exports a servable)": self.online_mode,
+        }
+        for what, set_ in refused.items():
+            if set_:
+                raise ValueError(f"model sdar_moe does not take {what}")
 
     # ---- derived views ------------------------------------------------
     @property

@@ -3,7 +3,8 @@
 ``get_model`` is the one dispatch point: a config with more than one task
 (``--tasks ctr,cvr``) builds the multi-task model (``--multitask``
 architecture over the shared graph bottom); otherwise ``cfg.model`` picks a
-single-task graph from the registry.
+single-task graph from the registry, or the block-diffusion decoder
+(``sdar_moe``), which is no ranker.
 """
 
 from typing import Union
@@ -14,6 +15,7 @@ from .graph import GraphDCNv2 as DCNv2
 from .graph import GraphDeepFM as DeepFM
 from .graph import GraphWideDeep as WideDeep
 from .multitask import MultiTaskModel  # noqa: F401
+from .sdar_moe import SdarMoE
 from .sequence import GraphBST, GraphDIN  # noqa: F401
 
 _REGISTRY = {
@@ -24,15 +26,20 @@ _REGISTRY = {
     "dlrm_dcnv2": GraphDLRMDCNv2,
     "din": GraphDIN,
     "bst": GraphBST,
+    "sdar_moe": SdarMoE,
 }
 
 CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDLRMDCNv2, GraphDIN,
-                 GraphBST, MultiTaskModel]
+                 GraphBST, SdarMoE, MultiTaskModel]
 
 
 def registered_models():
-    """Registered single-task model names (the ``--model`` whitelist)."""
-    return sorted(_REGISTRY)
+    """Registered single-task ranker names: the zoo every ranker test and
+    tool walks. A model that owns its loss (``owns_loss``: no one logit an
+    example, so no AUC and no servable) is built by ``get_model`` and is not
+    of that zoo."""
+    return sorted(name for name, cls in _REGISTRY.items()
+                  if not getattr(cls, "owns_loss", False))
 
 
 def get_model(cfg: Config) -> CtrModel:

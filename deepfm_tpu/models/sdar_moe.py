@@ -1,0 +1,452 @@
+"""Block-diffusion mixture-of-experts decoder (``--model sdar_moe``).
+
+The first model of the zoo that is no pooled ranker: a pre-norm residual
+decoder (RMSNorm; grouped-query attention with per-head QK-norm and rotary
+positions; a top-k softmax router over an expert layer) trained as a
+*block-diffusion* language model (SDAR, arXiv:2510.06303; mask and objective
+of BD3-LM, arXiv:2503.09573). ``benchmark/reference_sdar_moe.py`` holds the
+equations; this is the program's form of them.
+
+What it reads of a batch: ``hist_ids`` [B, L], the sequence's tokens, which
+ride the record's history list (``--history_max_len L``); ``feat_ids``,
+``feat_vals`` and ``label`` are in the record because the codec writes them
+and are not read. The token table is the ``EmbeddingSchema`` entry
+``tok_emb`` (``--feature_size`` rows of ``--embedding_size``, the model's
+width); the last row is ``[MASK]``.
+
+One step: each block of ``--diffusion_block`` tokens draws t ~ U[t_min, 1]
+and masks its tokens with probability t (``draw_noise``, from the step's
+key); the model reads ``[noisy ; clean]``, 2L positions at position indices
+``(0..L-1, 0..L-1)``, under the block-diffusion mask (``allowed``); the loss
+is the 1/t-weighted cross-entropy over the masked positions of the noisy
+half. The model owns that loss (``owns_loss``): the trainer takes its
+per-sequence values where a ranker hands it one logit an example.
+
+**A share of a layer.** The layer is told what it holds: ``--attn_q_heads``
+and ``--attn_kv_heads`` are the heads whose projections are here,
+``--moe_experts_held`` experts from ``--moe_first_expert`` on of the
+``--moe_experts`` the router scores. The router keeps its width and its
+``--moe_top_k``; a (position, expert) pair that names an absent expert adds
+nothing, and the partial sums of ``wo`` and of the experts go on to the next
+layer unreduced: on one chip the layer runs without its exchange, and no
+code stands in for the absent chips.
+
+**The expert layer.** The pairs that land on held experts are sorted by
+expert into a buffer of ``--moe_pair_capacity`` rows (static shapes), which
+is computed in equal passes of at most ``PASS_ROWS`` rows (one pass's
+memory): the three products run as grouped products over the held experts'
+(``jax.lax.ragged_dot``), and the rows are weighted and added back to their
+positions. The buffer's spare rows are computed as zeros, so a step costs
+the same whatever the routing. No pair is dropped silently: pairs beyond the
+buffer's rows are counted
+(``moe_pairs_over_buffer``, cumulative), with the pairs held, the fullest
+expert's count, the fullest layer's pairs (what the buffer has to hold) and
+the masked positions of the last step; the counts ride
+the model state and the step's metrics.
+
+Memory: each layer is recomputed in the backward pass (``jax.checkpoint``
+around the scanned layer), and attention runs a chunk of queries at a time,
+each chunk recomputed too, so no [S, S] score matrix outlives its chunk.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from . import common
+from .graph import GraphModel
+
+#: The step's counts, in the model state and (by ``step_counts``) the metrics.
+COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
+               "moe_expert_load_max", "moe_layer_pairs_max",
+               "masked_positions")
+#: Queries a chunk of attention scores holds, and positions a chunk of the
+#: head's logits (all of them when the sequence does not divide).
+QUERY_CHUNK = 1024
+HEAD_CHUNK = 1024
+#: Most rows of the expert layer's pair buffer computed at once: a pass holds
+#: its rows' inputs, both hidden products and the output (15 KB a row at the
+#: published widths), and is made again in the backward pass.
+PASS_ROWS = 20480
+
+
+def rms_norm(x: jnp.ndarray, gain: jnp.ndarray, eps: float) -> jnp.ndarray:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps) * gain
+
+
+def rotary(x: jnp.ndarray, positions: jnp.ndarray, theta: float
+           ) -> jnp.ndarray:
+    """Rotate-half rotary embedding of ``x`` [B, S, H, D] (float32) at
+    ``positions`` [S]."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[:, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[:, None, :]
+    rot = jnp.concatenate([-x[..., d // 2:], x[..., : d // 2]], axis=-1)
+    return x * cos + rot * sin
+
+
+def allowed(q_index: jnp.ndarray, k_index: jnp.ndarray, length: int,
+            block: int) -> jnp.ndarray:
+    """bool [Q, K]: may the query at ``q_index`` read the key at ``k_index``
+    (indices into ``[noisy ; clean]``, 2 * ``length`` positions)? A noisy
+    query reads its own block's noisy keys and earlier blocks' clean keys; a
+    clean query reads clean keys of its own and earlier blocks."""
+    q, k = q_index[:, None], k_index[None, :]
+    q_noisy, k_noisy = q < length, k < length
+    qb, kb = (q % length) // block, (k % length) // block
+    return jnp.where(q_noisy,
+                     (k_noisy & (kb == qb)) | (~k_noisy & (kb < qb)),
+                     ~k_noisy & (kb <= qb))
+
+
+def draw_noise(key: jax.Array, tokens: jnp.ndarray, *, block: int,
+               t_min: float, mask_id: int
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(noisy tokens [B, L], t [B, L / block]) of one step: a t ~ U[t_min, 1]
+    a block, each of its tokens ``mask_id`` with probability t. The one place
+    the step's noise is drawn: a check that holds the step's key draws the
+    same."""
+    b, length = tokens.shape
+    k_t, k_mask = jax.random.split(key)
+    t = jax.random.uniform(k_t, (b, length // block), jnp.float32,
+                           minval=t_min, maxval=1.0)
+    u = jax.random.uniform(k_mask, (b, length), jnp.float32)
+    masked = u < jnp.repeat(t, block, axis=1)
+    return jnp.where(masked, jnp.int32(mask_id), tokens), t
+
+
+def _operand(x: jnp.ndarray, compute_dtype: jnp.dtype) -> jnp.ndarray:
+    """A matrix product's operand: rounded to the compute precision, and
+    handed to the MXU in at least bfloat16 (a grouped product takes nothing
+    narrower; a precision below that is a rounding of the operands)."""
+    x = x.astype(compute_dtype)
+    return x if compute_dtype.itemsize >= 2 else x.astype(jnp.bfloat16)
+
+
+def _dot(x: jnp.ndarray, w: jnp.ndarray, cdt: jnp.dtype) -> jnp.ndarray:
+    """``x @ w`` with float32 accumulation and result."""
+    return jnp.matmul(_operand(x, cdt), _operand(w, cdt),
+                      preferred_element_type=jnp.float32)
+
+
+@jax.named_scope("attn")
+def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
+              positions: jnp.ndarray, *, length: int, block: int,
+              head_dim: int, eps: float, theta: float,
+              cdt: jnp.dtype) -> jnp.ndarray:
+    """The held heads' part of ``Attn(RMSNorm(x))``: x [B, S, d] -> [B, S, d]
+    (``wo``'s sum over the held heads, unreduced)."""
+    b, s, _ = x.shape
+    xn = rms_norm(x, lp["norm1"], eps)
+    q = _dot(xn, lp["wq"], cdt).reshape(b, s, -1, head_dim)
+    k = _dot(xn, lp["wk"], cdt).reshape(b, s, -1, head_dim)
+    v = _dot(xn, lp["wv"], cdt).reshape(b, s, -1, head_dim)
+    n_kv = k.shape[2]
+    group = q.shape[2] // n_kv
+    q = rotary(rms_norm(q, lp["q_norm"], eps), positions, theta)
+    k = rotary(rms_norm(k, lp["k_norm"], eps), positions, theta)
+    # query head j reads key/value head j // group
+    q = _operand(q, cdt).reshape(b, s, n_kv, group, head_dim)
+    k, v = _operand(k, cdt), _operand(v, cdt)
+    chunk = QUERY_CHUNK if s % QUERY_CHUNK == 0 else s
+    scale = 1.0 / math.sqrt(head_dim)
+    k_index = jnp.arange(s)
+
+    @jax.checkpoint
+    def one_chunk(args):
+        q_c, start = args                       # [B, Qc, n_kv, G, D]
+        scores = jnp.einsum("bqngd,bknd->bngqk", q_c, k,
+                            preferred_element_type=jnp.float32) * scale
+        ok = allowed(start + jnp.arange(chunk), k_index, length, block)
+        scores = jnp.where(ok[None, None, None], scores, -jnp.inf)
+        p = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bngqk,bknd->bqngd", _operand(p, cdt), v,
+                          preferred_element_type=jnp.float32)
+
+    # Positions ahead of heads, as the projections leave them: with heads
+    # ahead ([B, n_kv, G, S, D]) the score fusions ran 45 times slower on
+    # the chip (PERF.md, PR 31).
+    n_chunks = s // chunk
+    q_chunks = jnp.moveaxis(
+        q.reshape(b, n_chunks, chunk, n_kv, group, head_dim), 1, 0)
+    out = jax.lax.map(one_chunk, (q_chunks, jnp.arange(n_chunks) * chunk))
+    out = jnp.moveaxis(out, 0, 1).reshape(b, s, -1)
+    return _dot(out, lp["wo"], cdt)
+
+
+def route(xn: jnp.ndarray, router: jnp.ndarray, top_k: int
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """xn [T, d] -> (experts [T, k], weights [T, k]): the k largest of a
+    float32 softmax over every expert, their probabilities renormalised. The
+    product is float32 at full precision: it is 1/40 of a layer's work, and
+    a rounded logit moves which expert is the k-th."""
+    logits = jnp.matmul(xn.astype(jnp.float32), router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+
+@jax.named_scope("moe")
+def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
+                 top_k: int, first_expert: int, capacity: int,
+                 eps: float, cdt: jnp.dtype
+                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """The held experts' part of ``MoE(RMSNorm(x))``: x [B, S, d] ->
+    ([B, S, d], counts). ``lp['w_gate']`` [held, d, f] says how many experts
+    are held; they are experts ``first_expert`` onwards. The first
+    ``capacity`` of the sorted pairs are computed, in equal passes of at most
+    ``PASS_ROWS`` rows, each made again in the backward pass, so that the
+    layer's memory is one pass's; pairs beyond them are counted and add
+    nothing."""
+    shape = x.shape
+    xn = rms_norm(x, lp["norm2"], eps).reshape(-1, shape[-1])
+    n_tok = xn.shape[0]
+    n_held = lp["w_gate"].shape[0]
+    top_e, top_w = route(xn, lp["router"], top_k)
+    # Sort the (position, expert) pairs by held expert; absent ones last.
+    local = top_e.reshape(-1) - first_expert
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    order = jnp.argsort(key, stable=True)
+    sorted_key = key[order]
+    # each held expert's pairs: where its key starts in the sorted list
+    load = jnp.diff(jnp.searchsorted(
+        sorted_key, jnp.arange(n_held + 1, dtype=key.dtype))).astype(
+            jnp.int32)
+    held = jnp.sum(load)
+    passes = -(-capacity // PASS_ROWS)
+    buffer_rows = -(-capacity // passes)
+    capacity = passes * buffer_rows
+    spare = max(capacity - order.shape[0], 0)      # rows past every pair
+    order = jnp.concatenate([order, jnp.zeros((spare,), order.dtype)])
+    sorted_key = jnp.concatenate(
+        [sorted_key, jnp.full((spare,), n_held, key.dtype)])
+    pair_weight = top_w.reshape(-1)
+    weights = {n: _operand(lp[n], cdt) for n in ("w_gate", "w_up", "w_down")}
+
+    @jax.checkpoint
+    def one_pass(out, start):
+        rows = jax.lax.dynamic_slice(order, (start,), (buffer_rows,))
+        valid = jax.lax.dynamic_slice(sorted_key, (start,),
+                                      (buffer_rows,)) < n_held
+        # Group sizes of this pass's rows: the held experts' loads, cut to
+        # the pass; the rows left over (zeros) ride the last group, so a
+        # pass costs the same whatever the routing.
+        ends = jnp.clip(jnp.cumsum(load) - start, 0, buffer_rows)
+        sizes = jnp.diff(ends, prepend=0)
+        sizes = sizes.at[-1].add(buffer_rows - ends[-1])
+        tok = rows // top_k
+        xs = jnp.where(valid[:, None], jnp.take(xn, tok, axis=0), 0.0)
+        xs = _operand(xs, cdt)
+
+        def grouped(a, w):
+            return jax.lax.ragged_dot(a, w, sizes,
+                                      preferred_element_type=jnp.float32)
+
+        mid = jax.nn.silu(grouped(xs, weights["w_gate"])) \
+            * grouped(xs, weights["w_up"])
+        y = grouped(_operand(mid, cdt), weights["w_down"])      # [C, d]
+        weight = jnp.where(valid, pair_weight[rows], 0.0)
+        return out.at[tok].add(jnp.where(valid[:, None], y, 0.0)
+                               * weight[:, None]), None
+
+    # (zeros made of xn: across data replicas the carry varies as xn does)
+    out, _ = jax.lax.scan(
+        one_pass, xn * 0.0,
+        jnp.arange(passes, dtype=jnp.int32) * buffer_rows)
+    counts = {"moe_pairs_held": held,
+              "moe_pairs_over_buffer": jnp.maximum(held - capacity, 0),
+              "moe_expert_load_max": jnp.max(load),
+              "moe_layer_pairs_max": held}
+    return out.reshape(shape), counts
+
+
+class SdarMoE(GraphModel):
+    """Block-diffusion MoE decoder over ``hist_ids``; see the module's
+    docstring."""
+
+    name = "sdar_moe"
+    #: the trainer forwards hist_ids (the tokens) when the batch has them
+    uses_history = True
+    #: the model computes its own per-example loss (``per_example_loss``)
+    owns_loss = True
+    #: XLA's TPU backend compiles the expert layer's ``ragged_dot`` to
+    #: kernels it names ``ragged-dot-*`` and strips of where they were
+    #: traced: the step's text puts them under ``moe``
+    #: (``Trainer.step_hlo_text``).
+    kernel_scopes = (("ragged-dot", "moe"),)
+
+    def __init__(self, cfg: Any):
+        super().__init__(cfg)
+        self.cdt = jnp.dtype(cfg.compute_dtype)
+        self.mask_id = int(cfg.feature_size) - 1
+
+    def embedding_param_names(self) -> Tuple[str, ...]:
+        return ("tok_emb",)
+
+    def init_counts(self) -> common.State:
+        return {n: jnp.zeros((), jnp.int32) for n in COUNT_NAMES}
+
+    def step_counts(self, model_state: common.State
+                    ) -> Dict[str, jnp.ndarray]:
+        """The counts a step's metrics carry beside its loss."""
+        return dict(model_state)
+
+    def init(self, rng: jax.Array) -> Tuple[common.Params, common.State]:
+        cfg = self.cfg
+        d, hd, f = cfg.embedding_size, cfg.attn_head_dim, cfg.moe_expert_width
+        n, held = cfg.decoder_layers, cfg.moe_experts_held
+        keys = iter(jax.random.split(rng, 12))
+
+        def glorot(*shape):
+            return common.glorot_uniform(next(keys), (n, *shape))
+
+        layers = {
+            "norm1": jnp.ones((n, d), jnp.float32),
+            "wq": glorot(d, cfg.attn_q_heads * hd),
+            "wk": glorot(d, cfg.attn_kv_heads * hd),
+            "wv": glorot(d, cfg.attn_kv_heads * hd),
+            "q_norm": jnp.ones((n, hd), jnp.float32),
+            "k_norm": jnp.ones((n, hd), jnp.float32),
+            "wo": glorot(cfg.attn_q_heads * hd, d),
+            "norm2": jnp.ones((n, d), jnp.float32),
+            "router": glorot(d, cfg.moe_experts),
+            "w_gate": glorot(held, d, f),
+            "w_up": glorot(held, d, f),
+            "w_down": glorot(held, f, d),
+        }
+        params = {
+            "tok_emb": self.emb.init_entry(next(keys), (d,)),
+            "layers": layers,
+            "final_norm": jnp.ones((d,), jnp.float32),
+            "head": common.glorot_uniform(next(keys), (d, cfg.feature_size)),
+        }
+        return params, self.init_counts()
+
+    def hidden(self, params: common.Params, ids: jnp.ndarray, *,
+               shard_axis: Optional[str] = None,
+               emb_rows: Optional[Dict[str, Any]] = None,
+               emb_plan: Optional[Dict[str, Any]] = None
+               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+        """ids [B, 2L] (``[noisy ; clean]``) -> (the last residual stream
+        [B, 2L, d], the layers' counts summed; the fullest expert's the
+        largest)."""
+        cfg = self.cfg
+        length = ids.shape[1] // 2
+        positions = jnp.arange(ids.shape[1]) % length
+        x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
+                             emb_plan).astype(jnp.float32)
+
+        @jax.checkpoint
+        def layer(x, lp):
+            # The barrier keeps the layer's casts to the compute precision
+            # inside the loop: without it XLA casts the whole stack of every
+            # layer's weights ahead of the loop (1.1 GB here that a step
+            # does not have to spare).
+            lp = jax.lax.optimization_barrier(lp)
+            h = x + attention(
+                lp, x, positions, length=length, block=cfg.diffusion_block,
+                head_dim=cfg.attn_head_dim, eps=cfg.rms_norm_eps,
+                theta=cfg.rope_theta, cdt=self.cdt)
+            y, counts = expert_layer(
+                lp, h, top_k=cfg.moe_top_k,
+                first_expert=cfg.moe_first_expert,
+                capacity=cfg.moe_pair_capacity,
+                eps=cfg.rms_norm_eps, cdt=self.cdt)
+            return h + y, counts
+
+        x, counts = jax.lax.scan(layer, x, params["layers"])
+        return x, {k: (jnp.max(v) if k.endswith("_max") else jnp.sum(v))
+                   for k, v in counts.items()}
+
+    @jax.named_scope("head")
+    def logits(self, params: common.Params, h: jnp.ndarray) -> jnp.ndarray:
+        """[..., d] of the last residual stream -> [..., V]: final norm and
+        head product."""
+        hn = rms_norm(h, params["final_norm"], self.cfg.rms_norm_eps)
+        return _dot(hn, params["head"], self.cdt)
+
+    @jax.named_scope("head")
+    def _head_loss(self, params: common.Params, h: jnp.ndarray,
+                   tokens: jnp.ndarray, masked: jnp.ndarray,
+                   t: jnp.ndarray) -> jnp.ndarray:
+        """Loss a sequence [B] from the noisy half's residual stream h
+        [B, L, d]: the 1/t-weighted cross-entropy at the masked positions
+        over L, a chunk of positions at a time (each chunk's logits are made
+        again in the backward pass and never all held)."""
+        b, length, d = h.shape
+        chunk = HEAD_CHUNK if length % HEAD_CHUNK == 0 else length
+        weight = jnp.where(masked, 1.0 / jnp.repeat(
+            t, self.cfg.diffusion_block, axis=1), 0.0)
+
+        @jax.checkpoint
+        def one_chunk(args):
+            h_c, tok_c, w_c = args
+            logp = jax.nn.log_softmax(self.logits(params, h_c), axis=-1)
+            nll = -jnp.take_along_axis(logp, tok_c[..., None], axis=-1)
+            return jnp.sum(nll[..., 0] * w_c, axis=1)
+
+        def chunks(x):
+            return jnp.moveaxis(
+                x.reshape(b, length // chunk, chunk, *x.shape[2:]), 1, 0)
+
+        sums = jax.lax.map(one_chunk, (chunks(h), chunks(tokens),
+                                       chunks(weight)))
+        return jnp.sum(sums, axis=0) / length
+
+    def _run(self, params, state, tokens, rng, shard_axis, data_axis, emb):
+        cfg = self.cfg
+        tokens = tokens.astype(jnp.int32)
+        if rng is None:         # eval: one fixed draw, the same every call
+            rng = jax.random.PRNGKey(0)
+        noisy, t = draw_noise(rng, tokens, block=cfg.diffusion_block,
+                              t_min=cfg.diffusion_t_min,
+                              mask_id=self.mask_id)
+        h, counts = self.hidden(params, jnp.concatenate([noisy, tokens], 1),
+                                shard_axis=shard_axis, **emb)
+        masked = noisy != tokens
+        h = h[:, : tokens.shape[1]]
+        per_seq = self._head_loss(params, h, tokens, masked, t)
+        counts["masked_positions"] = jnp.sum(masked, dtype=jnp.int32)
+        if data_axis is not None:       # the replicas' counts, as one
+            counts = {k: (jax.lax.pmax(v, data_axis) if k.endswith("_max")
+                          else jax.lax.psum(v, data_axis))
+                      for k, v in counts.items()}
+        counts["moe_pairs_over_buffer"] = (
+            state["moe_pairs_over_buffer"] + counts["moe_pairs_over_buffer"])
+        return h, per_seq, counts
+
+    def apply(self, params: common.Params, state: common.State,
+              feat_ids: jnp.ndarray, feat_vals: jnp.ndarray, *,
+              train: bool, rng: Optional[jax.Array] = None,
+              shard_axis: Optional[str] = None,
+              data_axis: Optional[str] = None,
+              emb_rows: Optional[Dict[str, Any]] = None,
+              emb_plan: Optional[Dict[str, Any]] = None,
+              hist_ids: Optional[jnp.ndarray] = None,
+              hist_mask: Optional[jnp.ndarray] = None,
+              ) -> Tuple[jnp.ndarray, common.State]:
+        """Logits [B, L, V] of the noisy half under this call's noise."""
+        h, _, counts = self._run(
+            params, state, hist_ids, rng, shard_axis, data_axis,
+            {"emb_rows": emb_rows, "emb_plan": emb_plan})
+        return self.logits(params, h), counts
+
+    def per_example_loss(self, params: common.Params, state: common.State,
+                         batch: Dict[str, jnp.ndarray], *, train: bool,
+                         rng: Optional[jax.Array],
+                         shard_axis: Optional[str] = None,
+                         data_axis: Optional[str] = None, **emb
+                         ) -> Tuple[jnp.ndarray, common.State]:
+        """(loss a sequence [B], new state): what the trainer means over."""
+        _, per_seq, counts = self._run(
+            params, state, batch["hist_ids"], rng, shard_axis, data_axis,
+            emb)
+        return per_seq, counts

@@ -199,6 +199,10 @@ class Trainer:
         # path byte-for-byte (bit-exactness tests pin it).
         self._task_names = tuple(getattr(self.model, "task_names", ("ctr",)))
         self._multitask = len(self._task_names) > 1
+        # A model that owns its loss (a loss over the positions of a
+        # sequence: models.sdar_moe) hands the trainer per-example values
+        # where a ranker hands it one logit an example.
+        self._model_loss = getattr(self.model, "owns_loss", False)
         self.mesh_info = mesh_info if mesh_info is not None else mesh_lib.build_mesh(cfg)
         self.tx = opt_lib.build_optimizer(cfg, world_size=self.mesh_info.data_size)
         self._specs: Optional[Dict[str, Any]] = None
@@ -366,13 +370,17 @@ class Trainer:
         Under multi-process each process passes its local shard of the global
         batch; ``make_array_from_process_local_data`` assembles the global
         array (the pod-sharded tf.data->device-iterator analog, X3)."""
-        mi = self.mesh_info
-        if mi.mesh is None:
+        if self.mesh_info.mesh is None:
             return jax.device_put(batch)
         return jax.tree.map(
             lambda x: jax.make_array_from_process_local_data(
-                mi.sharding(P(mesh_lib.DATA_AXIS, *([None] * (x.ndim - 1)))), x),
+                self._batch_sharding(x.ndim), x),
             dict(batch))
+
+    def _batch_sharding(self, ndim: int):
+        """One batch's arrays: the leading (batch) dim over 'data'."""
+        return self.mesh_info.sharding(
+            P(mesh_lib.DATA_AXIS, *([None] * (ndim - 1))))
 
     # ------------------------------------------------------------------
     # Step functions
@@ -415,6 +423,11 @@ class Trainer:
 
     def _loss_terms(self, params, model_state, batch, *, train, rng,
                     shard_axis, data_axis, **emb):
+        if self._model_loss:
+            per_example, new_mstate = self.model.per_example_loss(
+                params, model_state, batch, train=train, rng=rng,
+                shard_axis=shard_axis, data_axis=data_axis, **emb)
+            return None, jnp.mean(per_example), new_mstate
         logits, new_mstate = self.model.apply(
             params, model_state, batch["feat_ids"], batch["feat_vals"],
             train=train, rng=rng, shard_axis=shard_axis, data_axis=data_axis,
@@ -454,6 +467,8 @@ class Trainer:
                 shard_axis=shard_axis)
             new_params, new_opt = self._optax_apply(
                 grads, state.opt_state, state.params)
+        if self._model_loss:    # the model's counts ride beside the loss
+            counts = self.model.step_counts(new_mstate)
         new_state = state.replace(
             step=state.step + 1, params=new_params, opt_state=new_opt,
             model_state=new_mstate)
@@ -1288,12 +1303,17 @@ class Trainer:
         return self._multi_step
 
     def step_hlo_text(self, device=None) -> str:
-        """``step_compiled`` as optimized HLO text."""
-        return self.step_compiled(device).as_text()
+        """``step_compiled`` as optimized HLO text, the kernels the compiler
+        names itself under the scopes the model declares for them
+        (``kernel_scopes``; ``profiling.scope_kernels``)."""
+        return prof_lib.scope_kernels(
+            self.step_compiled(device).as_text(),
+            getattr(self.model, "kernel_scopes", ()))
 
     def step_compiled(self, device=None):
         """The compiled K-step dispatch (``steps_per_loop`` steps of
-        ``batch_size``; ``as_text()`` is its optimized HLO,
+        ``batch_size``, as ``fit`` dispatches them: ``multi_step``, or
+        ``train_step`` where K is 1; ``as_text()`` is its optimized HLO,
         ``memory_analysis()`` its footprint), from abstract arguments laid
         out as ``_place`` and ``_put_stacked`` lay out the real ones; a
         trainer without a mesh compiles for ``device`` where one is given
@@ -1310,22 +1330,26 @@ class Trainer:
         batch = zero_batch(cfg.field_size, cfg.batch_size,
                            len(self._task_names), hl)
         k = max(cfg.steps_per_loop, 1)
-        batches = {key: jax.ShapeDtypeStruct((k,) + v.shape, v.dtype)
+        lead = (k,) if k > 1 else ()    # one step a dispatch: train_step
+        batches = {key: jax.ShapeDtypeStruct(lead + v.shape, v.dtype)
                    for key, v in batch.items()}
         if self.mesh_info.mesh is not None:
             state = jax.tree.map(
                 lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                                   sharding=s),
                 state, self._state_shardings(state))
+            sharding = self._stacked_sharding if k > 1 \
+                else self._batch_sharding
             batches = {key: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=self._stacked_sharding(x.ndim))
+                x.shape, x.dtype, sharding=sharding(x.ndim))
                 for key, x in batches.items()}
         elif device is not None:
             one = jax.sharding.SingleDeviceSharding(device)
             state, batches = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
                 (state, batches))
-        return self.multi_step.lower(state, batches).compile()
+        step = self.multi_step if k > 1 else self.train_step
+        return step.lower(state, batches).compile()
 
     def step_op_scopes(self) -> Dict[str, str]:
         """{HLO instruction name: named scope, "" for none} of the compiled
@@ -1374,6 +1398,20 @@ class Trainer:
         counts exactly once regardless of how the tail was padded — and all
         ranks can run the same compiled shape on ragged shards."""
         auc_state, loss_state = acc
+        if self._model_loss:
+            # The model's own loss, weighted like any other; there is no
+            # one probability an example for an AUC, whose state stays empty.
+            per_ex, _ = self.model.per_example_loss(
+                state.params, state.model_state, batch, train=False,
+                rng=None, shard_axis=shard_axis, data_axis=data_axis)
+            w = batch["weight"].reshape(-1).astype(jnp.float32)
+            loss_total, n = jnp.sum(per_ex * w), jnp.sum(w)
+            if data_axis is not None:
+                loss_total = jax.lax.psum(loss_total, data_axis)
+                n = jax.lax.psum(n, data_axis)
+            return (auc_state, metrics_lib.MeanState(
+                total=loss_state.total + loss_total,
+                count=loss_state.count + n))
         logits, _ = self.model.apply(
             state.params, state.model_state, batch["feat_ids"],
             batch["feat_vals"], train=False, rng=None,
@@ -1977,11 +2015,12 @@ class Trainer:
                         loss = float(m["loss"])
                         gstep = int(state.step)
                         if trace_lib.enabled():
-                            # the row-local update's counts (the last
-                            # scanned step's, like the loss): ready with it
-                            counts = {key: int(m[key])
-                                      for key in ROW_COUNTS if key in m}
-                            if counts:
+                            # the step's counts (the last scanned step's,
+                            # like the loss): ready with it
+                            counts = {key: int(v) for key, v in m.items()
+                                      if key not in ("loss", "xent",
+                                                     "steps_done")}
+                            if ROW_COUNTS[0] in counts:
                                 counts[ROW_WRITEBACK] = self.row_writeback
                             sync.add(**counts)
                     last_loss = loss

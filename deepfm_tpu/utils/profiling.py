@@ -80,8 +80,8 @@ class StepWindowTracer:
 #: The ``jax.named_scope`` names of the train step's phases (TUNING §17):
 #: every HLO instruction's ``op_name`` carries the scopes it was traced under,
 #: forward and backward (``transpose(jvp(embed))``).
-STEP_SCOPES = ("embed", "fm", "cross", "bottom", "tower", "loss", "l2",
-               "opt")
+STEP_SCOPES = ("embed", "fm", "cross", "bottom", "tower", "attn", "moe",
+               "head", "loss", "l2", "opt")
 
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
@@ -114,6 +114,29 @@ def hlo_op_scopes(hlo_text: str, scopes=STEP_SCOPES) -> Dict[str, str]:
             out[m.group(1)] = (innermost_scope(op_name.group(1), scopes)
                                if op_name else "")
     return out
+
+
+def scope_kernels(hlo_text: str, kernel_scopes) -> str:
+    """``hlo_text`` with the kernels the compiler names itself put under the
+    scope their model declares for them: ``kernel_scopes`` is ((instruction
+    name prefix, scope), ...). XLA's TPU backend compiles some primitives
+    (``jax.lax.ragged_dot``) to a kernel whose ``op_name`` is the kernel's
+    own name (``ragged-dot-none``) and says nothing of where it was traced;
+    such an ``op_name``, one without a path, gets the scope ahead of it
+    (``moe/ragged-dot-none``), and ``hlo_op_scopes`` reads it like any
+    other."""
+    if not kernel_scopes:
+        return hlo_text
+    lines = hlo_text.splitlines()
+    for i, line in enumerate(lines):
+        m = _HLO_INSTRUCTION.match(line)
+        scope = m and next((s for prefix, s in kernel_scopes
+                            if m.group(1).startswith(prefix)), None)
+        op_name = scope and _HLO_OP_NAME.search(line)
+        if op_name and "/" not in op_name.group(1):
+            at = op_name.start(1)
+            lines[i] = f"{line[:at]}{scope}/{line[at:]}"
+    return "\n".join(lines)
 
 
 _HLO_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$")

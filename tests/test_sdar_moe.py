@@ -1,0 +1,436 @@
+"""``--model sdar_moe`` (block-diffusion MoE decoder) at small widths on the
+CPU, from seeded weights, against the plain reference
+(``benchmark/reference_sdar_moe.py``): logits, loss, every leaf's gradient
+and three Adam steps in float32, on one device and on two data replicas, with
+bfloat16 compute required to miss the same tolerance; the mask against a
+hand-written example; the router against a hand-computed top-k; the share
+test (the 8 shares' partial results of an attention block and of an expert
+layer add up to the uncut reference's); pairs over a small buffer are
+counted, not lost; what ``Config`` refuses; the scopes in the compiled step;
+and the launcher's train -> eval on a tiny file."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import reference_sdar_moe as ref  # noqa: E402
+from deepfm_tpu.config import Config  # noqa: E402
+from deepfm_tpu.data import example_codec, tfrecord  # noqa: E402
+from deepfm_tpu.models import get_model, registered_models, sdar_moe  # noqa: E402
+from deepfm_tpu.parallel import mesh as mesh_lib  # noqa: E402
+from deepfm_tpu.train import Trainer  # noqa: E402
+
+V, L, B = 50, 8, 4
+SMALL = dict(model="sdar_moe", feature_size=V, field_size=1,
+             embedding_size=32, history_max_len=L, decoder_layers=2,
+             attn_q_heads=4, attn_kv_heads=2, attn_head_dim=8,
+             moe_experts=8, moe_top_k=2, moe_expert_width=16,
+             moe_experts_held=4, moe_first_expert=2,
+             moe_pair_capacity=B * 2 * L * 2, diffusion_block=4,
+             batch_size=B, l2_reg=0.0, learning_rate=1e-3, steps_per_loop=1)
+SIZES = dict(head_dim=8, top_k=2, first_expert=2, eps=1e-6, theta=1e6,
+             block=4)
+#: float32 program against float32 reference; bfloat16 compute has to miss it.
+TOL = 2e-4
+
+
+def config(**kw):
+    return Config(**{**SMALL, "compute_dtype": "float32", **kw})
+
+
+def flat(params):
+    """The program's parameter tree under the reference's names, the token
+    table cut to the vocabulary's rows."""
+    out = {}
+    for key, value in params.items():
+        if key == "layers":
+            out.update({"layers." + n: np.asarray(a)
+                        for n, a in value.items()})
+        else:
+            out[key] = np.asarray(value)
+    out["tok_emb"] = out["tok_emb"][:V]
+    return out
+
+
+def sequences(n, seed):
+    return np.random.default_rng(seed).integers(0, V - 1, (n, L)).astype(
+        np.int32)
+
+
+def trainer_on(n_dev, cfg):
+    return Trainer(cfg, mesh_info=mesh_lib.build_mesh(
+        cfg, devices=jax.devices()[:n_dev]))
+
+
+def batch_of(tokens):
+    n = tokens.shape[0]
+    return {"feat_ids": np.zeros((n, 1), np.int32),
+            "feat_vals": np.ones((n, 1), np.float32),
+            "label": np.zeros((n, 1), np.float32), "hist_ids": tokens,
+            "hist_mask": np.ones(tokens.shape, np.float32)}
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    """(model, params with gains moved off one, state)."""
+    model = get_model(config())
+    params, state = model.init(jax.random.PRNGKey(0))
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 8))
+    for name in ("norm1", "norm2", "q_norm", "k_norm"):
+        g = params["layers"][name]
+        params["layers"][name] = g + 0.1 * jax.random.normal(next(keys),
+                                                             g.shape)
+    params["final_norm"] = params["final_norm"] + 0.1 * jax.random.normal(
+        next(keys), params["final_norm"].shape)
+    return model, params, state
+
+
+def test_logits_and_loss_match_the_reference(seeded):
+    model, params, state = seeded
+    tokens = jnp.asarray(sequences(B, 0))
+    key = jax.random.PRNGKey(7)
+    logits, counts = model.apply(params, state, None, None, train=True,
+                                 rng=key, hist_ids=tokens)
+    per_seq, _ = model.per_example_loss(params, state, {"hist_ids": tokens},
+                                        train=True, rng=key)
+    noisy, t = sdar_moe.draw_noise(key, tokens, block=4, t_min=1e-3,
+                                   mask_id=V - 1)
+    with jax.default_matmul_precision("highest"):
+        want_loss, want_logits = ref.forward_loss(
+            {k: jnp.asarray(v) for k, v in flat(params).items()}, noisy,
+            tokens, t, SIZES)
+    assert logits.shape == (B, L, V)
+    np.testing.assert_allclose(logits, want_logits, atol=1e-5)
+    np.testing.assert_allclose(jnp.mean(per_seq), want_loss, rtol=1e-6)
+    assert int(counts["masked_positions"]) == int(jnp.sum(noisy != tokens))
+    assert int(counts["moe_pairs_over_buffer"]) == 0
+
+
+def _drawn(seed, kind):
+    """(noisy, tokens, t) at the cell's shape a step (2 x 4,096, block 4):
+    the program's draw, or a draw with one fault in it."""
+    rng = np.random.default_rng(seed)
+    tokens = rng.integers(0, 18991, (2, 4096))
+    if kind == "sound":
+        noisy, t = sdar_moe.draw_noise(
+            jax.random.PRNGKey(seed), jnp.asarray(tokens), block=4,
+            t_min=1e-3, mask_id=18991)
+        return np.asarray(noisy), tokens, np.asarray(t)
+    t = rng.uniform(0.5 if kind == "t from 0.5" else 1e-3, 1.0, (2, 1024))
+    p = {"t squared": t * t, "one less t": 1.0 - t,
+         "another block's t": np.roll(t, 1, axis=1)}.get(kind, t)
+    masked = rng.uniform(size=(2, 4096)) < np.repeat(p, 4, axis=1)
+    mask_id = 0 if kind == "another token" else 18991
+    return np.where(masked, mask_id, tokens), tokens, t
+
+
+@pytest.mark.parametrize("kind", [
+    "sound", "t squared", "one less t", "another block's t", "t from 0.5",
+    "another token"])
+def test_the_reference_tells_a_sound_draw_of_the_noise_from_a_wrong_one(
+        kind):
+    """``noise_z`` holds the step's noise to the objective's statement of it
+    and takes nothing of the program's: the program's own draw passes the
+    cell's limit (5) on every seed, a draw with one fault fails it."""
+    z = [ref.noise_z(*_drawn(seed, kind), block=4, t_min=1e-3,
+                     mask_id=18991) for seed in range(8)]
+    assert max(z) < 4.0 if kind == "sound" else min(z) > 5.0, z
+
+
+def test_gradients_of_every_leaf_match_the_reference(seeded):
+    model, params, state = seeded
+    tokens = jnp.asarray(sequences(B, 1))
+    key = jax.random.PRNGKey(8)
+    noisy, t = sdar_moe.draw_noise(key, tokens, block=4, t_min=1e-3,
+                                   mask_id=V - 1)
+
+    def loss(p):
+        per_seq, _ = model.per_example_loss(p, state, {"hist_ids": tokens},
+                                            train=True, rng=key)
+        return jnp.mean(per_seq)
+
+    got = flat(jax.grad(loss)(params))
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(lambda p: ref.forward_loss(p, noisy, tokens, t,
+                                                   SIZES)[0])(
+            {k: jnp.asarray(v) for k, v in flat(params).items()})
+    assert set(got) == set(want)
+    for name in want:
+        assert ref.leaf_gap(got[name], want[name]) < 1e-5, name
+        assert np.linalg.norm(want[name]) > 0, name
+
+
+def follow(n_dev, compute_dtype, steps=3):
+    """(worst first-moment gap, worst parameter-change gap, losses) of
+    ``steps`` trainer steps against the reference's follower."""
+    cfg = config(compute_dtype=compute_dtype, mesh_data=n_dev)
+    trainer = trainer_on(n_dev, cfg)
+    state = trainer.init_state(seed=3)
+    start = flat(jax.tree.map(np.asarray, state.params))
+    base = jnp.asarray(np.asarray(state.rng))
+    follower = ref.Follower(start, SIZES, cfg.learning_rate * n_dev)
+    losses = []
+    for step in range(steps):
+        tokens = sequences(B, 10 + step)
+        key = jax.random.fold_in(base, step)
+        per = B // n_dev
+        noise = [sdar_moe.draw_noise(
+            jax.random.fold_in(key, s) if n_dev > 1 else key,
+            jnp.asarray(tokens[s * per:(s + 1) * per]), block=4, t_min=1e-3,
+            mask_id=V - 1) for s in range(n_dev)]
+        state, m = trainer.train_step(state,
+                                      trainer.put_batch(batch_of(tokens)))
+        want = follower.step(np.concatenate([n for n, _ in noise]), tokens,
+                             np.concatenate([t for _, t in noise]))
+        losses.append((float(m["xent"]), want))
+    got = flat(jax.tree.map(np.asarray, state.params))
+    mu = flat(jax.tree.map(np.asarray, optax.tree_utils.tree_get(
+        state.opt_state, "mu")))
+    return (ref.worst_leaf_gap(mu, follower.mu)[0],
+            ref.worst_leaf_gap({k: got[k] - start[k] for k in got},
+                               {k: follower.params[k] - start[k]
+                                for k in got})[0], losses)
+
+
+@pytest.mark.parametrize("n_dev", [1, 2])
+def test_three_adam_steps_match_the_reference(n_dev):
+    mu_gap, change_gap, losses = follow(n_dev, "float32")
+    for got, want in losses:
+        assert abs(got - want) < 1e-5 * max(1.0, abs(want))
+    assert mu_gap < TOL
+    # Adam divides by the gradient's own size: where a gradient is tiny its
+    # rounding decides the step's sign, and the change reads it.
+    assert change_gap < 0.02
+
+
+def test_bfloat16_compute_misses_the_tolerance():
+    mu_gap, change_gap, _ = follow(1, "bfloat16")
+    assert mu_gap > 10 * TOL and change_gap > 0.02
+
+
+def test_mask_matches_a_hand_written_example():
+    n = np.array([[1, 1, 1, 1, 0, 0, 0, 0],      # noisy queries, block 0
+                  [0, 0, 0, 0, 1, 1, 1, 1]], bool)   # and block 1
+    none, first = np.zeros(8, bool), np.array([1] * 4 + [0] * 4, bool)
+    every = np.ones(8, bool)
+    rows = ([np.concatenate([n[0], none])] * 4     # noisy 0: own noisy block
+            + [np.concatenate([n[1], first])] * 4  # noisy 1: + clean block 0
+            + [np.concatenate([none, first])] * 4  # clean 0: clean block 0
+            + [np.concatenate([none, every])] * 4)  # clean 1: clean 0 and 1
+    want = np.stack(rows)
+    assert want.sum() == 4 * 4 * 2 * 3            # b*b*nb*(nb+1)
+    np.testing.assert_array_equal(ref.block_diffusion_mask(8, 4), want)
+    index = jnp.arange(16)
+    np.testing.assert_array_equal(sdar_moe.allowed(index, index, 8, 4), want)
+
+
+def test_router_matches_a_hand_computed_top_k():
+    x = jnp.asarray([[1.0, 0.0], [0.0, 2.0]])
+    router = jnp.asarray([[0.0, 1.0, 2.0, -1.0], [1.0, 0.0, -1.0, 0.5]])
+    # token 0: logits (0, 1, 2, -1): experts 2 and 1, e^2 and e^1 renormalised
+    # token 1: logits (2, 0, -2, 1): experts 0 and 3
+    experts, weights = sdar_moe.route(x, router, 2)
+    np.testing.assert_array_equal(experts, [[2, 1], [0, 3]])
+    e = np.exp
+    np.testing.assert_allclose(
+        weights, [[e(2) / (e(2) + e(1)), e(1) / (e(2) + e(1))]] * 2,
+        rtol=1e-6)
+    dense = ref.router_weights(x, router, {"top_k": 2})
+    np.testing.assert_allclose(dense[0], [0, weights[0, 1], weights[0, 0], 0],
+                               rtol=1e-6)
+    np.testing.assert_allclose(dense[1], [weights[1, 0], 0, 0, weights[1, 1]],
+                               rtol=1e-6)
+
+
+def uncut_layer(seed=0, d=32, hd=8, n_q=16, n_kv=4, experts=16, f=16):
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
+
+    def w(*shape):
+        return 0.3 * jax.random.normal(next(keys), shape)
+    return {"norm1": 1 + w(d), "norm2": 1 + w(d), "q_norm": 1 + w(hd),
+            "k_norm": 1 + w(hd), "wq": w(d, n_q * hd), "wk": w(d, n_kv * hd),
+            "wv": w(d, n_kv * hd), "wo": w(n_q * hd, d),
+            "router": w(d, experts), "w_gate": w(experts, d, f),
+            "w_up": w(experts, d, f), "w_down": w(experts, f, d)}
+
+
+def test_eight_shares_add_up_to_the_uncut_layer():
+    """8 chips share the layer as the benchmark's deployment does: each holds
+    2 of 16 query heads, the key/value head they read (a key/value head lives
+    on 2 chips) and 2 of 16 experts. The shares' partial results of the
+    attention block and of the expert layer, on the same input, add up to the
+    uncut reference's."""
+    hd, length, block = 8, 8, 4
+    lp = uncut_layer()
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 2 * length, 32))
+    pos = jnp.arange(2 * length) % length
+    sizes = dict(head_dim=hd, top_k=2, first_expert=0, eps=1e-6, theta=1e6)
+    mask = jnp.asarray(ref.block_diffusion_mask(length, block))
+    with jax.default_matmul_precision("highest"):
+        want_attn = ref.attention(ref.rms_norm(x, lp["norm1"], 1e-6), lp,
+                                  sizes, mask, pos)
+        want_moe = ref.moe(ref.rms_norm(x, lp["norm2"], 1e-6), lp, sizes)
+    got_attn, got_moe, held = 0.0, 0.0, 0
+    for r in range(8):
+        q = slice(2 * r * hd, (2 * r + 2) * hd)
+        kv = slice((r // 2) * hd, (r // 2 + 1) * hd)
+        share = {**lp, "wq": lp["wq"][:, q], "wk": lp["wk"][:, kv],
+                 "wv": lp["wv"][:, kv], "wo": lp["wo"][q],
+                 **{n: lp[n][2 * r:2 * r + 2]
+                    for n in ("w_gate", "w_up", "w_down")}}
+        got_attn += sdar_moe.attention(
+            share, x, pos, length=length, block=block, head_dim=hd, eps=1e-6,
+            theta=1e6, cdt=jnp.dtype("float32"))
+        part, counts = sdar_moe.expert_layer(
+            share, x, top_k=2, first_expert=2 * r, capacity=64, eps=1e-6,
+            cdt=jnp.dtype("float32"))
+        got_moe += part
+        held += int(counts["moe_pairs_held"])
+        assert int(counts["moe_pairs_over_buffer"]) == 0
+    assert held == x.shape[0] * x.shape[1] * 2     # every pair, once
+    np.testing.assert_allclose(got_attn, want_attn, atol=2e-5)
+    np.testing.assert_allclose(got_moe, want_moe, atol=2e-5)
+
+
+def test_pairs_over_a_small_buffer_are_counted_not_lost(monkeypatch):
+    lp = uncut_layer(experts=4)
+    x = jax.random.normal(jax.random.PRNGKey(2), (1, 16, 32))
+    kw = dict(top_k=2, first_expert=0, eps=1e-6, cdt=jnp.dtype("float32"))
+    whole, all_counts = sdar_moe.expert_layer(lp, x, capacity=32, **kw)
+    assert int(all_counts["moe_pairs_held"]) == 32       # all 4 are held
+    assert int(all_counts["moe_pairs_over_buffer"]) == 0
+    cut, counts = sdar_moe.expert_layer(lp, x, capacity=20, **kw)
+    monkeypatch.setattr(sdar_moe, "PASS_ROWS", 16)
+    for capacity in (32, 20):   # two passes of 16 and of 10: the same
+        twice, two = sdar_moe.expert_layer(lp, x, capacity=capacity, **kw)
+        assert int(two["moe_pairs_over_buffer"]) == 32 - capacity
+        np.testing.assert_allclose(twice, whole if capacity == 32 else cut,
+                                   atol=1e-5)
+    assert int(counts["moe_pairs_held"]) == 32
+    assert int(counts["moe_layer_pairs_max"]) == 32      # one layer's
+    assert int(counts["moe_pairs_over_buffer"]) == 12
+    assert int(counts["moe_expert_load_max"]) == int(
+        all_counts["moe_expert_load_max"]) >= 8
+    assert np.isfinite(np.asarray(cut)).all()
+    assert not np.allclose(cut, whole)
+    # and a trainer's state keeps the run's total
+    trainer = trainer_on(1, config(moe_pair_capacity=8))
+    state = trainer.init_state(seed=1)
+    seen = []
+    for step in range(2):
+        state, m = trainer.train_step(
+            state, trainer.put_batch(batch_of(sequences(B, step))))
+        seen.append(int(m["moe_pairs_over_buffer"]))
+    assert 0 < seen[0] < seen[1]
+    assert int(state.model_state["moe_pairs_over_buffer"]) == seen[1]
+
+
+@pytest.mark.parametrize("change, says", [
+    ({"tasks": "ctr,cvr"}, "tasks"),
+    ({"embedding_update": "sparse"}, "embedding_update=sparse"),
+    ({"task_type": "infer"}, "infer/export"),
+    ({"task_type": "export"}, "infer/export"),
+    ({"servable_model_dir": "/tmp/x"}, "servable_model_dir"),
+    ({"batch_norm": True}, "batch_norm"),
+    ({"loss_type": "square_loss"}, "loss_type"),
+    ({"embedding_buckets": "64,64"}, "embedding_buckets"),
+    ({"history_max_len": 6}, "multiple of diffusion_block"),
+    ({"history_max_len": 0}, "multiple of diffusion_block"),
+    ({"decoder_layers": 0}, "decoder_layers"),
+    ({"attn_q_heads": 3}, "attn_q_heads"),
+    ({"moe_top_k": 9}, "moe_top_k"),
+    ({"moe_first_expert": 6}, "moe_experts_held"),
+    ({"moe_pair_capacity": 0}, "moe_pair_capacity"),
+    ({"diffusion_t_min": 0.0}, "diffusion_t_min"),
+    ({"model": "deepfm", "history_max_len": 0}, "belong to --model sdar_moe"),
+])
+def test_config_says_plainly_what_the_model_does_not_take(change, says):
+    with pytest.raises(ValueError, match=says):
+        config(**change)
+
+
+def test_the_model_is_built_with_one_table_leaf():
+    assert "sdar_moe" not in registered_models()    # the rankers' zoo
+    model = get_model(config())
+    assert model.embedding_param_names() == ("tok_emb",)
+    assert model.uses_history and model.owns_loss
+    params, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert params["tok_emb"].shape == (model.padded_vocab, 32)
+    assert params["head"].shape == (32, V)
+    assert params["layers"]["w_gate"].shape == (2, 4, 32, 16)
+    assert set(state) == set(sdar_moe.COUNT_NAMES)
+
+
+def test_compiled_step_carries_each_blocks_scope():
+    scopes = set(trainer_on(1, config(steps_per_loop=2))
+                 .step_op_scopes().values())
+    assert {"embed", "attn", "moe", "head", "opt"} <= scopes
+    assert not {"fm", "tower", "cross", "bottom"} & scopes
+
+
+def test_grouped_product_kernels_are_charged_to_the_expert_layer():
+    """XLA's TPU backend compiles ``ragged_dot`` to kernels it names itself
+    and strips of where they were traced; the model declares their scope and
+    the step's text carries it, for this model only."""
+    from deepfm_tpu.utils import profiling
+
+    text = "\n".join([
+        '  %ragged-dot-none.3 = bf16[20480,768]{1,0} custom-call(%a, %b), '
+        'custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-none"}',
+        '  %ragged-dot-metadata = (s32[17]{0}) custom-call(%gs), '
+        'metadata={op_name="ragged-dot-metadata"}',
+        '  %fusion.9 = f32[16384,2048]{1,0} fusion(%p), kind=kLoop, '
+        'metadata={op_name="jit(step)/jvp()/while/body/moe/scatter-add"}',
+        '  %fusion.7 = f32[2,4,1024]{2,1,0} fusion(%q), kind=kLoop, '
+        'metadata={op_name="jit(step)/transpose(jvp(attn))/reduce_max"}',
+        '  %copy.4 = f32[2]{0} copy(%x)'])
+    bare = {"ragged-dot-none.3": "", "ragged-dot-metadata": "",
+            "fusion.9": "moe", "fusion.7": "attn", "copy.4": ""}
+    assert profiling.hlo_op_scopes(text) == bare
+    assert profiling.scope_kernels(text, ()) == text
+    named = profiling.scope_kernels(text, sdar_moe.SdarMoE.kernel_scopes)
+    assert 'op_name="moe/ragged-dot-none"' in named
+    assert profiling.hlo_op_scopes(named) == {
+        **bare, "ragged-dot-none.3": "moe", "ragged-dot-metadata": "moe"}
+    # every line but the kernels' is as it was
+    assert [a == b for a, b in zip(text.splitlines(), named.splitlines())
+            ] == [False, False, True, True, True]
+
+
+def test_launcher_trains_and_evaluates(tmp_path, capsys):
+    from deepfm_tpu import launch
+
+    data = tmp_path / "data"
+    os.makedirs(data)
+    for prefix, n, seed in (("tr", 64, 1), ("va", 24, 2)):
+        with tfrecord.TFRecordWriter(
+                str(data / f"{prefix}-00000.tfrecords")) as w:
+            for row in sequences(n, seed):
+                w.write(example_codec.encode_ctr_example(
+                    0.0, np.zeros(1), np.ones(1), hist_ids=row))
+    argv = ["--data_dir", str(data), "--val_data_dir", str(data),
+            "--model_dir", str(tmp_path / "ckpt"), "--num_epochs", "2",
+            "--compute_dtype", "float32", "--steps_per_loop", "4",
+            "--mesh_data", "2"]
+    for key, value in SMALL.items():
+        if "--" + key not in argv:
+            argv += ["--" + key, str(value)]
+    assert launch.main(argv + ["--task_type", "train"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["task"] == "train" and line["steps"] == 2 * (64 // B)
+    assert np.isfinite(line["loss"]) and "saved_model" not in line
+    assert launch.main(argv + ["--task_type", "eval"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["task"] == "eval" and 0 < line["loss"] < 20
+    with pytest.raises(ValueError, match="infer/export"):
+        launch.main(argv + ["--task_type", "infer"])
