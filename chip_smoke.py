@@ -95,8 +95,10 @@ def make_shards(out_dir: str, shape: Dict[str, object], prefix: str,
 def check_kernels(shape: Dict[str, object], *, interpret: bool = False
                   ) -> dict:
     """fused_fm and take_rows_pallas, forward and backward, and put_rows
-    against their XLA legs at (B, F, K) of ``shape``. ``interpret=False``
-    is the compiled path (TPU only); the CPU rehearsal passes True."""
+    against their XLA legs at (B, F, K) of ``shape``; the block-masked
+    attention kernel against the chunked XLA path at a shape of its own.
+    ``interpret=False`` is the compiled path (TPU only); the CPU rehearsal
+    passes True."""
     import jax
     import jax.numpy as jnp
 
@@ -169,6 +171,42 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
         np.testing.assert_array_equal(
             np.asarray(g), np.asarray(t.at[uids].set(r, mode="drop")))
     out["put_rows_slots_written"] = real
+
+    # block-masked attention (the SDAR decoder's masked scores): the
+    # kernel, forward and both backward kernels, against the chunked XLA
+    # path on one key/value head's four query heads of 128, 1,024 positions
+    # under the block-diffusion mask, bfloat16 operands.
+    from deepfm_tpu.models import sdar_moe
+
+    length, cdt = 512, jnp.dtype(jnp.bfloat16)
+    q, key, val = (jnp.asarray(rng.normal(size=shape_), jnp.float32)
+                   for shape_ in ((1, 2 * length, 1, 4, 128),
+                                  (1, 2 * length, 1, 128),
+                                  (1, 2 * length, 1, 128)))
+    key, val = key.astype(cdt), val.astype(cdt)
+    weight = jnp.asarray(rng.normal(size=(1, 2 * length, 512)), jnp.float32)
+
+    def by_kernel(q, k, v):
+        return sdar_moe._scores_kernel(
+            (q / np.sqrt(128.0)).astype(cdt), k, v, length=length, block=4,
+            interpret=interpret)
+
+    def by_xla(q, k, v):
+        return sdar_moe._scores_xla(q.astype(cdt), k, v, length=length,
+                                    block=4, cdt=cdt)
+
+    def out_and_grads(f):
+        def loss(q, k, v):
+            o = f(q, k, v).astype(jnp.float32)
+            return jnp.sum(o * weight), o
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, key, val)
+        return (o, *g)
+
+    errs = [max_rel(g, w) for g, w in zip(out_and_grads(by_kernel),
+                                          out_and_grads(by_xla))]
+    assert max(errs) <= 2.0 ** -5, f"block attention vs XLA: {errs}"
+    out["block_attention_max_rel_err"] = max(errs)
     return out
 
 

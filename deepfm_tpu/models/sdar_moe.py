@@ -44,19 +44,39 @@ expert's count, the fullest layer's pairs (what the buffer has to hold) and
 the masked positions of the last step; the counts ride
 the model state and the step's metrics.
 
+**The masked scores.** Between the rotated, rounded q/k/v and ``wo`` the
+block computes softmax(mask(q k^T / sqrt(D))) v in one of two ways, picked
+from what the code can see (``attn_scores_by``: backend, head_dim, the
+sequence against the kernel's block, one device's program; no flag). On a
+TPU at a head_dim of whole 128-lane lines it is one Pallas flash-attention
+call (``ops/block_attention``, the kernel JAX ships) whose grid visits only
+the blocks of the score matrix in which ``allowed_pairs`` is true anywhere
+(80 of 256 blocks of 512 at L = 4,096): a block's scores live and die in
+VMEM, forward and backward, float32 scores, max, sum and accumulators on
+operands of the compute precision. Everywhere else (a CPU, the tests' small
+shapes, a step across data replicas) it is XLA ops, a chunk of
+``QUERY_CHUNK`` queries against every key at a time, the mask applied to
+scores computed whole. ``allowed_pairs`` is the one statement of the mask
+both read.
+
 Memory: each layer is recomputed in the backward pass (``jax.checkpoint``
-around the scanned layer), and attention runs a chunk of queries at a time,
-each chunk recomputed too, so no [S, S] score matrix outlives its chunk.
+around the scanned layer). No [S, S] score matrix is ever held: the kernel
+keeps a block of scores in VMEM and saves a log-sum-exp a query for its
+backward kernels, which make the scores again; the XLA path runs a chunk of
+queries at a time, each chunk recomputed too, so its [chunk, S] scores do
+not outlive the chunk.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..ops import block_attention
 from . import common
 from .graph import GraphModel
 
@@ -68,6 +88,14 @@ COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
 #: head's logits (all of them when the sequence does not divide).
 QUERY_CHUNK = 1024
 HEAD_CHUNK = 1024
+#: Queries by keys a block of the attention kernel holds, forward and
+#: backward. On a v5e at the cell's shapes (q [2, 4, 8192, 128] on one
+#: key/value head, L = 4,096; PERF.md section 6, PR 32), forward / forward +
+#: backward a layer: 512 (80 of 256 blocks visited) 0.93 / 3.33 ms; 1,024
+#: with a compute block of 512 (24 of 64) 0.92 / 3.22; 256 2.1 / 7.5 with the
+#: mask computed in the kernel, where 512 read 1.47 / 5.12; the chunked XLA
+#: path 9.8 / 23.1.
+ATTN_BLOCK = 512
 #: Most rows of the expert layer's pair buffer computed at once: a pass holds
 #: its rows' inputs, both hidden products and the output (15 KB a row at the
 #: published widths), and is made again in the backward pass.
@@ -93,18 +121,25 @@ def rotary(x: jnp.ndarray, positions: jnp.ndarray, theta: float
     return x * cos + rot * sin
 
 
-def allowed(q_index: jnp.ndarray, k_index: jnp.ndarray, length: int,
-            block: int) -> jnp.ndarray:
-    """bool [Q, K]: may the query at ``q_index`` read the key at ``k_index``
-    (indices into ``[noisy ; clean]``, 2 * ``length`` positions)? A noisy
-    query reads its own block's noisy keys and earlier blocks' clean keys; a
-    clean query reads clean keys of its own and earlier blocks."""
-    q, k = q_index[:, None], k_index[None, :]
+def allowed_pairs(q, k, length: int, block: int):
+    """May the query at index ``q`` read the key at index ``k`` (indices into
+    ``[noisy ; clean]``, 2 * ``length`` positions; integer arrays that
+    broadcast against each other, NumPy's or ``jnp``'s)? A noisy query reads
+    its own block's noisy keys and earlier blocks' clean keys; a clean query
+    reads clean keys of its own and earlier blocks. The one statement of the
+    block-diffusion mask: the XLA path and the kernel both evaluate it."""
     q_noisy, k_noisy = q < length, k < length
     qb, kb = (q % length) // block, (k % length) // block
-    return jnp.where(q_noisy,
-                     (k_noisy & (kb == qb)) | (~k_noisy & (kb < qb)),
-                     ~k_noisy & (kb <= qb))
+    same = kb == qb
+    return (k_noisy & q_noisy & same) | (
+        ~k_noisy & ((kb < qb) | (~q_noisy & same)))
+
+
+def allowed(q_index: jnp.ndarray, k_index: jnp.ndarray, length: int,
+            block: int) -> jnp.ndarray:
+    """bool [Q, K] of ``allowed_pairs``: every query of ``q_index`` against
+    every key of ``k_index``."""
+    return allowed_pairs(q_index[:, None], k_index[None, :], length, block)
 
 
 def draw_noise(key: jax.Array, tokens: jnp.ndarray, *, block: int,
@@ -137,25 +172,12 @@ def _dot(x: jnp.ndarray, w: jnp.ndarray, cdt: jnp.dtype) -> jnp.ndarray:
                       preferred_element_type=jnp.float32)
 
 
-@jax.named_scope("attn")
-def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
-              positions: jnp.ndarray, *, length: int, block: int,
-              head_dim: int, eps: float, theta: float,
-              cdt: jnp.dtype) -> jnp.ndarray:
-    """The held heads' part of ``Attn(RMSNorm(x))``: x [B, S, d] -> [B, S, d]
-    (``wo``'s sum over the held heads, unreduced)."""
-    b, s, _ = x.shape
-    xn = rms_norm(x, lp["norm1"], eps)
-    q = _dot(xn, lp["wq"], cdt).reshape(b, s, -1, head_dim)
-    k = _dot(xn, lp["wk"], cdt).reshape(b, s, -1, head_dim)
-    v = _dot(xn, lp["wv"], cdt).reshape(b, s, -1, head_dim)
-    n_kv = k.shape[2]
-    group = q.shape[2] // n_kv
-    q = rotary(rms_norm(q, lp["q_norm"], eps), positions, theta)
-    k = rotary(rms_norm(k, lp["k_norm"], eps), positions, theta)
-    # query head j reads key/value head j // group
-    q = _operand(q, cdt).reshape(b, s, n_kv, group, head_dim)
-    k, v = _operand(k, cdt), _operand(v, cdt)
+def _scores_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                length: int, block: int, cdt: jnp.dtype) -> jnp.ndarray:
+    """softmax(mask(q k^T / sqrt(D))) v as XLA ops, a chunk of queries at a
+    time, each chunk made again in the backward pass: q [B, S, n_kv, G, D],
+    k and v [B, S, n_kv, D] (operands) -> [B, S, n_kv * G * D] float32."""
+    b, s, n_kv, group, head_dim = q.shape
     chunk = QUERY_CHUNK if s % QUERY_CHUNK == 0 else s
     scale = 1.0 / math.sqrt(head_dim)
     k_index = jnp.arange(s)
@@ -178,7 +200,81 @@ def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
     q_chunks = jnp.moveaxis(
         q.reshape(b, n_chunks, chunk, n_kv, group, head_dim), 1, 0)
     out = jax.lax.map(one_chunk, (q_chunks, jnp.arange(n_chunks) * chunk))
-    out = jnp.moveaxis(out, 0, 1).reshape(b, s, -1)
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, -1)
+
+
+@functools.lru_cache(maxsize=None)
+def attn_kernel(seq: int, length: int, block: int, heads: int,
+                interpret: bool = False, kernel_block: int = ATTN_BLOCK):
+    """The block-masked attention kernel of one key/value head's ``heads``
+    query heads under ``allowed_pairs`` (``ops/block_attention``), built
+    once a shape: finding the non-empty blocks takes half a second at
+    S = 8,192 and the step is traced more than once a run."""
+    with jax.ensure_compile_time_eval():
+        return block_attention.make_kernel(
+            functools.partial(allowed_pairs, length=length, block=block),
+            ("block_diffusion", length, block), seq=seq, heads=heads,
+            block=kernel_block, interpret=interpret)
+
+
+def _scores_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                   length: int, block: int, interpret: bool = False,
+                   kernel_block: int = ATTN_BLOCK) -> jnp.ndarray:
+    """``_scores_xla``'s result from the kernel, which visits only the blocks
+    of the score matrix the mask leaves something in and keeps a block's
+    scores in VMEM, forward and backward. q arrives scaled by
+    ``1/sqrt(D)``; the result is in the operands' type."""
+    b, s, n_kv, group, head_dim = q.shape
+    kernel = attn_kernel(s, length, block, group, interpret, kernel_block)
+    heads_first = jax.vmap(jax.vmap(kernel))(      # over B and n_kv
+        jnp.transpose(q, (0, 2, 3, 1, 4)),          # [B, n_kv, G, S, D]
+        jnp.transpose(k, (0, 2, 1, 3)), jnp.transpose(v, (0, 2, 1, 3)))
+    return jnp.transpose(heads_first, (0, 3, 1, 2, 4)).reshape(b, s, -1)
+
+
+def attn_scores_by(seq: int, head_dim: int, *, one_device: bool = True,
+                   backend: Optional[str] = None) -> str:
+    """``kernel`` where the block-masked attention kernel applies (a TPU
+    backend, ``head_dim`` whole 128-lane lines, a sequence its block
+    divides: ``ops/block_attention.supported``; and a step that is one
+    device's program: the shipped kernel does not say how its results vary
+    over a mesh's axes, which a step across data replicas is checked for),
+    else ``xla``: what the compiled step's attention is made of, read from
+    the backend, the shapes and the mesh."""
+    backend = jax.default_backend() if backend is None else backend
+    return ("kernel" if one_device and block_attention.supported(
+        backend, seq, head_dim, ATTN_BLOCK) else "xla")
+
+
+@jax.named_scope("attn")
+def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
+              positions: jnp.ndarray, *, length: int, block: int,
+              head_dim: int, eps: float, theta: float,
+              cdt: jnp.dtype, scores_by: str = "xla") -> jnp.ndarray:
+    """The held heads' part of ``Attn(RMSNorm(x))``: x [B, S, d] -> [B, S, d]
+    (``wo``'s sum over the held heads, unreduced). ``scores_by`` is
+    ``attn_scores_by``'s word for what makes the masked scores."""
+    b, s, _ = x.shape
+    xn = rms_norm(x, lp["norm1"], eps)
+    q = _dot(xn, lp["wq"], cdt).reshape(b, s, -1, head_dim)
+    k = _dot(xn, lp["wk"], cdt).reshape(b, s, -1, head_dim)
+    v = _dot(xn, lp["wv"], cdt).reshape(b, s, -1, head_dim)
+    n_kv = k.shape[2]
+    group = q.shape[2] // n_kv
+    q = rotary(rms_norm(q, lp["q_norm"], eps), positions, theta)
+    k = rotary(rms_norm(k, lp["k_norm"], eps), positions, theta)
+    k, v = _operand(k, cdt), _operand(v, cdt)
+    # query head j reads key/value head j // group
+    if scores_by == "kernel":
+        # the kernel applies no scale: it goes into q ahead of q's one
+        # rounding to the compute precision
+        q = _operand(q * (1.0 / math.sqrt(head_dim)), cdt)
+        out = _scores_kernel(q.reshape(b, s, n_kv, group, head_dim), k, v,
+                             length=length, block=block)
+    else:
+        out = _scores_xla(
+            _operand(q, cdt).reshape(b, s, n_kv, group, head_dim), k, v,
+            length=length, block=block, cdt=cdt)
     return _dot(out, lp["wo"], cdt)
 
 
@@ -287,6 +383,22 @@ class SdarMoE(GraphModel):
         super().__init__(cfg)
         self.cdt = jnp.dtype(cfg.compute_dtype)
         self.mask_id = int(cfg.feature_size) - 1
+        #: What the traced step is made of, said beside its counts on
+        #: ``train.log_sync`` while tracing is on: ``attn_scores`` (``kernel``
+        #: / ``xla``) and, of the kernel, ``attn_score_blocks`` (blocks of
+        #: the score matrix the forward pass computes / all of them, a head).
+        self.step_notes: Dict[str, str] = {}
+
+    def _attn_notes(self, scores_by: str, seq: int, length: int
+                    ) -> Dict[str, str]:
+        if scores_by != "kernel":       # every score of every chunk
+            return {"attn_scores": scores_by}
+        group = self.cfg.attn_q_heads // self.cfg.attn_kv_heads
+        visited, total = block_attention.visited_blocks(
+            attn_kernel(seq, length, self.cfg.diffusion_block, group),
+            seq, ATTN_BLOCK)
+        return {"attn_scores": scores_by,
+                "attn_score_blocks": f"{visited}/{total}"}
 
     def embedding_param_names(self) -> Tuple[str, ...]:
         return ("tok_emb",)
@@ -333,14 +445,20 @@ class SdarMoE(GraphModel):
     def hidden(self, params: common.Params, ids: jnp.ndarray, *,
                shard_axis: Optional[str] = None,
                emb_rows: Optional[Dict[str, Any]] = None,
-               emb_plan: Optional[Dict[str, Any]] = None
+               emb_plan: Optional[Dict[str, Any]] = None,
+               data_axis: Optional[str] = None,
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """ids [B, 2L] (``[noisy ; clean]``) -> (the last residual stream
         [B, 2L, d], the layers' counts summed; the fullest expert's the
-        largest)."""
+        largest). ``data_axis`` names the mesh axis of a step across data
+        replicas (the counts are this replica's all the same)."""
         cfg = self.cfg
-        length = ids.shape[1] // 2
-        positions = jnp.arange(ids.shape[1]) % length
+        seq = ids.shape[1]
+        length = seq // 2
+        positions = jnp.arange(seq) % length
+        scores_by = attn_scores_by(seq, cfg.attn_head_dim,
+                                   one_device=data_axis is None)
+        self.step_notes = self._attn_notes(scores_by, seq, length)
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
 
@@ -354,7 +472,7 @@ class SdarMoE(GraphModel):
             h = x + attention(
                 lp, x, positions, length=length, block=cfg.diffusion_block,
                 head_dim=cfg.attn_head_dim, eps=cfg.rms_norm_eps,
-                theta=cfg.rope_theta, cdt=self.cdt)
+                theta=cfg.rope_theta, cdt=self.cdt, scores_by=scores_by)
             y, counts = expert_layer(
                 lp, h, top_k=cfg.moe_top_k,
                 first_expert=cfg.moe_first_expert,
@@ -410,7 +528,8 @@ class SdarMoE(GraphModel):
                               t_min=cfg.diffusion_t_min,
                               mask_id=self.mask_id)
         h, counts = self.hidden(params, jnp.concatenate([noisy, tokens], 1),
-                                shard_axis=shard_axis, **emb)
+                                shard_axis=shard_axis, data_axis=data_axis,
+                                **emb)
         masked = noisy != tokens
         h = h[:, : tokens.shape[1]]
         per_seq = self._head_loss(params, h, tokens, masked, t)

@@ -1303,11 +1303,12 @@ class Trainer:
         return self._multi_step
 
     def step_hlo_text(self, device=None) -> str:
-        """``step_compiled`` as optimized HLO text, the kernels the compiler
-        names itself under the scopes the model declares for them
+        """``step_compiled`` as optimized HLO text, an instruction a line
+        (``profiling.whole_instructions``), the kernels the compiler names
+        itself under the scopes the model declares for them
         (``kernel_scopes``; ``profiling.scope_kernels``)."""
         return prof_lib.scope_kernels(
-            self.step_compiled(device).as_text(),
+            prof_lib.whole_instructions(self.step_compiled(device).as_text()),
             getattr(self.model, "kernel_scopes", ()))
 
     def step_compiled(self, device=None):
@@ -2022,6 +2023,9 @@ class Trainer:
                                                      "steps_done")}
                             if ROW_COUNTS[0] in counts:
                                 counts[ROW_WRITEBACK] = self.row_writeback
+                            # what the model says its traced step is made of
+                            counts.update(
+                                getattr(self.model, "step_notes", {}))
                             sync.add(**counts)
                     last_loss = loss
                     if guard is not None and not guard_active:

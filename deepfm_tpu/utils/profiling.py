@@ -116,6 +116,29 @@ def hlo_op_scopes(hlo_text: str, scopes=STEP_SCOPES) -> Dict[str, str]:
     return out
 
 
+def whole_instructions(hlo_text: str) -> str:
+    """``hlo_text`` with every instruction on one line. A Pallas call that
+    hands the profiler a note (``pallas_call(metadata=...)``, as JAX's
+    attention kernels do) prints it as a ``frontend_attributes`` group with
+    line breaks inside, so the instruction's own ``metadata={op_name=...}``
+    lands on a line that names no instruction and the line-by-line readers
+    below lose its scope; a line whose braces are still open takes the next
+    ones until they close. Text without such a group comes back as it
+    was."""
+    out, held, depth = [], [], 0
+    for line in hlo_text.splitlines():
+        if not held and not _HLO_INSTRUCTION.match(line):
+            out.append(line)
+            continue
+        held.append(line)
+        depth += line.count("{") - line.count("}")
+        if depth <= 0:
+            out.append(" ".join(held))
+            held, depth = [], 0
+    out.extend(held)
+    return "\n".join(out)
+
+
 def scope_kernels(hlo_text: str, kernel_scopes) -> str:
     """``hlo_text`` with the kernels the compiler names itself put under the
     scope their model declares for them: ``kernel_scopes`` is ((instruction

@@ -35,6 +35,14 @@ overflow by a little pays a second trip for it), and how the compiled step
 writes its rows back (``embed_row_writeback``: ``dma``, one asynchronous
 copy a row, or ``scatter``, XLA's; TUNING §5).
 
+Where the model says what makes its attention's masked scores
+(``--model sdar_moe``), each ``train.log_sync`` carries ``attn_scores``
+(``kernel``: one Pallas call that visits only the blocks of the score
+matrix the block-diffusion mask leaves something in; ``xla``: every score of
+every query chunk) and, of the kernel, ``attn_score_blocks`` (visited /
+all, a head); the report prints them on its "block-masked attention" line
+(TUNING §5).
+
 Usage:
     python scripts/trace_report.py TRACE.json [--top 20] [--json]
                                               [--stalls MS]
@@ -178,6 +186,14 @@ def stalls(events, threshold_ms):
     return out
 
 
+def _log_syncs(events, attribute):
+    """The attributes of the ``train.log_sync`` spans that carry
+    ``attribute``."""
+    return [e["args"] for e in events
+            if e.get("name") == "train.log_sync" and e.get("ph") == "X"
+            and attribute in e.get("args", {})]
+
+
 def row_updates(events):
     """The row-local table update's counters off the ``train.log_sync``
     spans that carry them: ``steps`` read, mean and max of
@@ -185,9 +201,7 @@ def row_updates(events):
     those steps and the step's ``writeback`` (``embed_row_writeback``; "?"
     in a trace that predates it); None when no span has them (the step
     sweeps the table, or the trace predates the counters)."""
-    seen = [e["args"] for e in events
-            if e.get("name") == "train.log_sync" and e.get("ph") == "X"
-            and "embed_distinct_rows" in e.get("args", {})]
+    seen = _log_syncs(events, "embed_distinct_rows")
     if not seen:
         return None
     rows = [a["embed_distinct_rows"] for a in seen]
@@ -199,6 +213,23 @@ def row_updates(events):
             "row_trips_max": max(trips),
             "one_trip_share": sum(t == 1 for t in trips) / len(trips),
             "writeback": seen[-1].get("embed_row_writeback", "?")}
+
+
+def attention_scores(events):
+    """What makes the attention's masked scores, off the ``train.log_sync``
+    spans that say so: ``steps`` read, ``scores`` (``attn_scores``:
+    ``kernel`` / ``xla``, the compiled step's choice) and, of the kernel,
+    ``visited`` and ``total`` blocks of the score matrix a head
+    (``attn_score_blocks``); None when no span has them (another model, or
+    a trace that predates them)."""
+    seen = _log_syncs(events, "attn_scores")
+    if not seen:
+        return None
+    out = {"steps": len(seen), "scores": seen[-1]["attn_scores"]}
+    if "attn_score_blocks" in seen[-1]:
+        visited, total = seen[-1]["attn_score_blocks"].split("/")
+        out.update(visited=int(visited), total=int(total))
+    return out
 
 
 def main(argv=None):
@@ -218,6 +249,7 @@ def main(argv=None):
     dropped = int(other.get("dropped_spans", 0))
     slow = stalls(events, args.stalls) if args.stalls is not None else None
     touched = row_updates(events)
+    attn = attention_scores(events)
 
     if args.json:
         doc = {
@@ -228,6 +260,8 @@ def main(argv=None):
             doc["stalls"] = slow
         if touched is not None:
             doc["row_updates"] = touched
+        if attn is not None:
+            doc["attention_scores"] = attn
         print(json.dumps(doc, indent=2))
         return 0
 
@@ -257,6 +291,13 @@ def main(argv=None):
                   touched["distinct_rows_max"], touched["row_trips_mean"],
                   touched["row_trips_max"],
                   100 * touched["one_trip_share"], touched["writeback"]))
+    if attn is not None:
+        print("block-masked attention over %d logged steps: scores by %s"
+              % (attn["steps"], attn["scores"])
+              + (", %d of %d blocks of the score matrix visited a head "
+                 "(%.1f%%)" % (attn["visited"], attn["total"],
+                               100 * attn["visited"] / attn["total"])
+                 if "visited" in attn else ", every score computed"))
     for st in slow or ():
         cover = ", ".join(f"{k} {v:.1f}" for k, v in st["cover_ms"].items()
                           if v > 0)

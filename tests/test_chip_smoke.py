@@ -64,7 +64,8 @@ def test_kernel_phase_rehearsal():
     assert set(out) == {"fused_fm_float32_max_rel_err",
                         "fused_fm_bfloat16_max_rel_err",
                         "take_rows_bwd_max_rel_err",
-                        "put_rows_slots_written"}
+                        "put_rows_slots_written",
+                        "block_attention_max_rel_err"}
 
 
 @pytest.mark.slow

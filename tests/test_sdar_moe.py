@@ -407,6 +407,157 @@ def test_grouped_product_kernels_are_charged_to_the_expert_layer():
             ] == [False, False, True, True, True]
 
 
+# --- the block-masked attention kernel (ops/block_attention.py) -------------
+
+def _qkv(dtype, length=256, group=4, head_dim=128, batch=1):
+    """Rotated, normalised q/k/v as ``attention`` hands them on: unit-RMS
+    rows, rounded to the compute precision."""
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    s = 2 * length
+    q = jax.random.normal(keys[0], (batch, s, 1, group, head_dim))
+    k = jax.random.normal(keys[1], (batch, s, 1, head_dim))
+    v = jax.random.normal(keys[2], (batch, s, 1, head_dim))
+    w = jax.random.normal(keys[3], (batch, s, group * head_dim))
+    return q, k.astype(dtype), v.astype(dtype), w
+
+
+@pytest.mark.parametrize("kernel_block", [128, 256])
+@pytest.mark.parametrize("dtype, tol", [("float32", 1e-4),
+                                        ("bfloat16", 2e-2)])
+def test_kernel_scores_match_the_chunked_xla_path(dtype, tol, kernel_block):
+    """The kernels (forward, dq, dk/dv) through the Pallas interpreter against
+    ``_scores_xla`` on the same q/k/v: L = 256, block 4, 4 query heads on 1
+    key/value head, head_dim 128; output and the gradients of q, k, v, within
+    1e-4 in float32 and within bfloat16's rounding of an operand (2^-8,
+    through three products) under bfloat16."""
+    length, block = 256, 4
+    cdt = jnp.dtype(dtype)
+    q, k, v, w = _qkv(cdt, length)
+    scale = 1.0 / np.sqrt(q.shape[-1])
+
+    def by_xla(q, k, v):
+        return sdar_moe._scores_xla(sdar_moe._operand(q, cdt), k, v,
+                                    length=length, block=block, cdt=cdt)
+
+    def by_kernel(q, k, v):
+        return sdar_moe._scores_kernel(
+            sdar_moe._operand(q * scale, cdt), k, v, length=length,
+            block=block, interpret=True, kernel_block=kernel_block)
+
+    def value_and_grads(f):
+        def loss(q, k, v):
+            out = f(q, k, v).astype(jnp.float32)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out, *grads)
+
+    names = ("out", "dq", "dk", "dv")
+    for name, got, want in zip(names, value_and_grads(by_kernel),
+                               value_and_grads(by_xla)):
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        assert np.isfinite(got).all(), name
+        gap = np.max(np.abs(got - want)) / np.max(np.abs(want))
+        assert gap < tol, (name, gap)
+
+
+@pytest.mark.parametrize("length", [8, 1024])
+def test_kernel_mask_is_allowed_entry_for_entry(length):
+    from deepfm_tpu.ops import block_attention
+    import functools
+    s, block = 2 * length, 4
+    mask = block_attention.PairMask(
+        s, functools.partial(sdar_moe.allowed_pairs, length=length,
+                             block=block), ("block_diffusion", length, block))
+    index = jnp.arange(s)
+    want = np.asarray(sdar_moe.allowed(index, index, length, block))
+    np.testing.assert_array_equal(mask[:, :], want)
+    np.testing.assert_array_equal(mask[4:s // 2, s // 4:], want[4:s // 2,
+                                                                s // 4:])
+    np.testing.assert_array_equal(want, ref.block_diffusion_mask(length,
+                                                                 block))
+
+
+@pytest.mark.parametrize("length, kernel_block, visited, total", [
+    (4096, 512, 80, 256), (4096, 1024, 24, 64), (256, 128, None, 16)])
+def test_forward_grid_visits_the_blocks_allowed_leaves_something_in(
+        length, kernel_block, visited, total):
+    """The kernel's own block table against a count made from ``allowed``
+    with NumPy, a stripe of query blocks at a time (computed, not traced)."""
+    from deepfm_tpu.ops import block_attention
+    s, block = 2 * length, 4
+    kernel = sdar_moe.attn_kernel(s, length, block, 4, True, kernel_block)
+    index = np.arange(s)
+    count = 0
+    for start in range(0, s, kernel_block):
+        stripe = sdar_moe.allowed_pairs(
+            index[start:start + kernel_block, None], index[None, :], length,
+            block)
+        count += int(stripe.reshape(kernel_block, s // kernel_block,
+                                    kernel_block).any(axis=(0, 2)).sum())
+    assert block_attention.visited_blocks(kernel, s, kernel_block) == (
+        count, total)
+    if visited is not None:
+        assert count == visited
+    # one kernel a shape: the step is traced more than once a run
+    assert sdar_moe.attn_kernel(s, length, block, 4, True,
+                                kernel_block) is kernel
+
+
+@pytest.mark.parametrize("backend, seq, head_dim, one_device, says", [
+    ("tpu", 8192, 128, True, "kernel"), ("tpu", 1024, 256, True, "kernel"),
+    ("cpu", 8192, 128, True, "xla"), ("gpu", 8192, 128, True, "xla"),
+    ("tpu", 8192, 64, True, "xla"), ("tpu", 8192 + 256, 128, True, "xla"),
+    ("tpu", 16, 8, True, "xla"), ("tpu", 8192, 128, False, "xla")])
+def test_the_kernel_is_taken_where_backend_shape_and_mesh_allow(
+        backend, seq, head_dim, one_device, says):
+    assert sdar_moe.attn_scores_by(seq, head_dim, one_device=one_device,
+                                   backend=backend) == says
+    assert sdar_moe.ATTN_BLOCK == 512
+
+
+def test_a_cpu_step_makes_its_scores_with_xla(seeded):
+    """On this backend the model's step takes the chunked XLA path and says
+    so (what ``train.log_sync`` carries while tracing is on)."""
+    model, params, _ = seeded
+    jax.eval_shape(lambda p: model.hidden(
+        p, jnp.zeros((B, 2 * L), jnp.int32)), params)
+    assert model.step_notes == {"attn_scores": "xla"}
+
+
+def test_attention_by_the_kernel_matches_attention_by_xla(monkeypatch):
+    """The whole block (projections, QK-norm, rotary, scale folded into q,
+    ``wo``) with the kernel forced on through the interpreter against the
+    XLA path, output and every weight's gradient, float32."""
+    import functools
+    monkeypatch.setattr(sdar_moe, "_scores_kernel", functools.partial(
+        sdar_moe._scores_kernel, interpret=True, kernel_block=128))
+    d, hd, length, block = 64, 128, 128, 4
+    keys = iter(jax.random.split(jax.random.PRNGKey(5), 8))
+    lp = {"norm1": jnp.ones((d,)), "q_norm": jnp.ones((hd,)),
+          "k_norm": jnp.ones((hd,)),
+          "wq": jax.random.normal(next(keys), (d, 2 * hd)) * 0.2,
+          "wk": jax.random.normal(next(keys), (d, hd)) * 0.2,
+          "wv": jax.random.normal(next(keys), (d, hd)) * 0.2,
+          "wo": jax.random.normal(next(keys), (2 * hd, d)) * 0.1}
+    x = jax.random.normal(next(keys), (2, 2 * length, d))
+    w = jax.random.normal(next(keys), x.shape)
+    pos = jnp.arange(2 * length) % length
+
+    def loss(lp, scores_by):
+        out = sdar_moe.attention(
+            lp, x, pos, length=length, block=block, head_dim=hd, eps=1e-6,
+            theta=1e6, cdt=jnp.dtype("float32"), scores_by=scores_by)
+        return jnp.sum(out * w), out
+
+    (_, want), want_g = jax.value_and_grad(loss, has_aux=True)(lp, "xla")
+    (_, got), got_g = jax.value_and_grad(loss, has_aux=True)(lp, "kernel")
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    for name in lp:
+        np.testing.assert_allclose(got_g[name], want_g[name], atol=2e-4,
+                                   err_msg=name)
+
+
 def test_launcher_trains_and_evaluates(tmp_path, capsys):
     from deepfm_tpu import launch
 

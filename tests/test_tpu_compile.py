@@ -136,3 +136,87 @@ def test_the_table_shaped_step_still_sweeps(v5e, no_compile_cache,
     primitives = [o["primitive"] for o in ops if o["loop_body"]]
     assert "scatter-add" in primitives and "broadcast_in_dim" in primitives
     assert any(o["scope"] == "opt" and len(o["in_place"]) >= 3 for o in ops)
+
+
+# --- the block-masked attention kernel (ops/block_attention.py; PR 32) ------
+
+# The SDAR cell's attention widths (4 query heads of 128 on one key/value
+# head) with the depth, the width, the experts, the vocabulary and the
+# length cut so that the step compiles in seconds; 2L = 1,024 positions are
+# two of the kernel's blocks.
+SDAR_FLAGS = dict(
+    model="sdar_moe", feature_size=512, field_size=1, embedding_size=256,
+    history_max_len=512, decoder_layers=2, attn_q_heads=4, attn_kv_heads=1,
+    attn_head_dim=128, moe_experts=8, moe_top_k=2, moe_expert_width=128,
+    moe_experts_held=4, moe_first_expert=0, moe_pair_capacity=4096,
+    diffusion_block=4, batch_size=1, l2_reg=0.0, learning_rate=1e-5,
+    compute_dtype="bfloat16", steps_per_loop=1)
+
+
+def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
+        v5e, no_compile_cache, monkeypatch):
+    """On a TPU at head_dim 128 the step's masked scores are the three
+    kernels JAX's flash attention is made of (forward; dq; dk and dv), each
+    charged to ``attn`` by the step's own text, though each prints over three
+    lines (``profiling.whole_instructions``), and the model says so."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = Config(**SDAR_FLAGS)
+    tr = Trainer(cfg, mesh_info=mesh_lib.build_mesh(cfg, devices=[v5e]))
+    scopes = profiling.hlo_op_scopes(tr.step_hlo_text(device=v5e))
+    assert tr.model.step_notes == {"attn_scores": "kernel",
+                                   "attn_score_blocks": "3/4"}
+    kernels = {name: scope for name, scope in scopes.items()
+               if name.startswith("splash_mqa_")}
+    assert {name.split(".")[0] for name in kernels} == {
+        "splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+        "splash_mqa_dkv_no_residuals"}, kernels
+    assert set(kernels.values()) == {"attn"}, kernels
+    # the raw text loses them: their op_name is on a continuation line
+    raw = profiling.hlo_op_scopes(tr.step_compiled(device=v5e).as_text())
+    assert {raw[name] for name in kernels} == {""}
+    # and every other instruction is charged as it was (the grouped
+    # products' kernels get their scope from the model, as before)
+    def others(by_op):
+        return {n: s for n, s in by_op.items()
+                if n not in kernels and not n.startswith(
+                    ("ragged-dot", "pallas_call"))}    # (the kernels' parts)
+    assert others(scopes) == others(raw)
+
+
+def test_decoder_step_at_head_dim_64_keeps_the_xla_scores(
+        v5e, no_compile_cache, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = Config(**{**SDAR_FLAGS, "attn_head_dim": 64})
+    tr = Trainer(cfg, mesh_info=mesh_lib.build_mesh(cfg, devices=[v5e]))
+    text = tr.step_hlo_text(device=v5e)
+    assert tr.model.step_notes == {"attn_scores": "xla"}
+    assert "splash_mqa" not in text
+
+
+def test_attention_kernels_compile_at_the_cells_shapes(v5e,
+                                                       no_compile_cache):
+    """Forward and backward at q [2, 8192, 1, 4, 128] bfloat16 under the
+    block-diffusion mask of 4,096 tokens: Mosaic takes the three kernels at
+    blocks of 512 (VMEM, tiling), and nothing [S, S] is made outside them."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from deepfm_tpu.models import sdar_moe
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(v5e))
+
+    def loss(q, k, v):
+        return jnp.sum(sdar_moe._scores_kernel(
+            q, k, v, length=4096, block=4).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec(2, 8192, 1, 4, 128), spec(2, 8192, 1, 128),
+        spec(2, 8192, 1, 128)).compile()
+    text = compiled.as_text()
+    for name in ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                 "splash_mqa_dkv_no_residuals"):
+        assert f"%{name}" in text, name
+    # one float32 [2, 4, 8192, 8192] score matrix would be 2.1 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20

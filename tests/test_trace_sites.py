@@ -345,6 +345,121 @@ def test_report_reads_a_trace_that_predates_the_writeback_attribute():
     assert _report().row_updates(events)["writeback"] == "?"
 
 
+def _sdar_fit(n_steps=4):
+    """A tiny ``--model sdar_moe`` fit of ``n_steps`` one-step dispatches,
+    traced; the model and the events."""
+    length, vocab, batch = 8, 50, 2
+    cfg = Config(model="sdar_moe", feature_size=vocab, field_size=1,
+                 embedding_size=16, history_max_len=length, decoder_layers=1,
+                 attn_q_heads=2, attn_kv_heads=1, attn_head_dim=8,
+                 moe_experts=4, moe_top_k=2, moe_expert_width=8,
+                 moe_experts_held=2, moe_first_expert=0,
+                 moe_pair_capacity=batch * 2 * length * 2, diffusion_block=4,
+                 batch_size=batch, l2_reg=0.0, learning_rate=1e-3,
+                 steps_per_loop=1, log_steps=2, compute_dtype="float32",
+                 mesh_data=1, mesh_model=1)
+    rng = np.random.default_rng(5)
+    batches = [{"feat_ids": np.zeros((batch, 1), np.int32),
+                "feat_vals": np.ones((batch, 1), np.float32),
+                "label": np.zeros((batch, 1), np.float32),
+                "hist_ids": rng.integers(0, vocab - 1, (batch, length)
+                                         ).astype(np.int32),
+                "hist_mask": np.ones((batch, length), np.float32)}
+               for _ in range(n_steps)]
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(cfg)
+    tr.fit(tr.init_state(), batches)
+    return tr, trace_lib._tracer.events()
+
+
+def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
+    """The block-diffusion decoder says what its compiled step's masked
+    scores are made of on the span that reads the loss back: on this backend
+    the chunked XLA path, and the report's line with it."""
+    tr, events = _sdar_fit()
+    assert tr.model.step_notes == {"attn_scores": "xla"}
+    syncs = [e["args"] for e in events if e["name"] == "train.log_sync"]
+    assert [a["attn_scores"] for a in syncs] == ["xla"] * 2
+    assert all("attn_score_blocks" not in a for a in syncs)
+    path = str(tmp_path / "trace.json")
+    trace_lib.export(path)
+    report = _report()
+    loaded, _ = report._load(path)
+    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla"}
+    assert report.row_updates(loaded) is None
+    assert report.main([path]) == 0
+    assert ("block-masked attention over 2 logged steps: scores by xla, "
+            "every score computed") in capsys.readouterr().out
+
+
+def test_report_prints_the_kernels_block_count(tmp_path, capsys):
+    """What a TPU's trace carries: ``attn_scores`` = ``kernel`` and the
+    forward grid's visited / total blocks a head."""
+    events = [{"name": "train.log_sync", "ph": "X", "ts": 10.0 * i,
+               "dur": 1.0, "pid": 1, "tid": 1,
+               "args": {"step": i, "attn_scores": "kernel",
+                        "attn_score_blocks": "80/256"}} for i in (1, 2, 3)]
+    report = _report()
+    assert report.attention_scores(events) == {
+        "steps": 3, "scores": "kernel", "visited": 80, "total": 256}
+    # a ranker's trace, or one that predates the attribute, has no line
+    assert report.attention_scores(
+        [{"name": "train.log_sync", "ph": "X", "args": {"step": 2}}]) is None
+    path = tmp_path / "trace.json"
+    path.write_text(__import__("json").dumps({"traceEvents": events}))
+    assert report.main([str(path)]) == 0
+    assert ("block-masked attention over 3 logged steps: scores by kernel, "
+            "80 of 256 blocks of the score matrix visited a head (31.2%)"
+            ) in capsys.readouterr().out
+    assert report.main([str(path), "--json"]) == 0
+    assert '"attention_scores"' in capsys.readouterr().out
+
+
+def test_a_model_note_on_the_kernel_names_the_forward_grids_blocks():
+    """With the kernel taken (a TPU backend; nothing is compiled here) the
+    model's note carries the block count of the kernel's own table."""
+    import jax
+    from deepfm_tpu.models import get_model
+    cfg = Config(model="sdar_moe", feature_size=50, field_size=1,
+                 embedding_size=16, history_max_len=512, decoder_layers=1,
+                 attn_q_heads=2, attn_kv_heads=1, attn_head_dim=128,
+                 moe_experts=4, moe_top_k=2, moe_expert_width=8,
+                 moe_experts_held=2, moe_first_expert=0,
+                 moe_pair_capacity=64, diffusion_block=4, batch_size=1,
+                 l2_reg=0.0, steps_per_loop=1, compute_dtype="float32")
+    model = get_model(cfg)
+    notes = model._attn_notes("kernel", 1024, 512)
+    # S = 1,024 in blocks of 512: [noisy ; clean] x [noisy ; clean], every
+    # quarter holds something but clean queries on noisy keys
+    assert notes == {"attn_scores": "kernel", "attn_score_blocks": "3/4"}
+    assert jax.default_backend() == "cpu"
+
+
+def test_whole_instructions_puts_a_kernels_note_back_on_its_line():
+    """A Pallas call with a profiler note prints over three lines, its
+    ``op_name`` on the last; the step's text joins them, so the kernel keeps
+    its scope, and text without such a note is unchanged."""
+    text = "\n".join([
+        "%body (p: f32[2]) -> f32[2] {",
+        '  %splash_mqa_fwd.2 = (f32[2,512,128]{2,1,0}, bf16[2,4,8192,128]'
+        '{3,2,1,0}) custom-call(%a, %b), custom_call_target="tpu_custom_'
+        'call", frontend_attributes={kernel_metadata={',
+        '"xprof_metadata":"{\\"block_q\\": 512}"',
+        '}}, metadata={op_name="jit(step)/while/body/checkpoint/attn/vmap('
+        'jit(_splash_attention))/pallas_call"}, backend_config={"x":[]}',
+        '  %fusion.7 = f32[2,4,1024]{2,1,0} fusion(%q), kind=kLoop, '
+        'metadata={op_name="jit(step)/transpose(jvp(attn))/reduce_max"}',
+        "}"])
+    assert profiling.hlo_op_scopes(text)["splash_mqa_fwd.2"] == ""
+    whole = profiling.whole_instructions(text)
+    assert len(whole.splitlines()) == 4
+    assert profiling.hlo_op_scopes(whole) == {"splash_mqa_fwd.2": "attn",
+                                              "fusion.7": "attn"}
+    assert profiling.whole_instructions(whole) == whole
+    plain = "\n".join(text.splitlines()[:1] + text.splitlines()[4:])
+    assert profiling.whole_instructions(plain) == plain
+
+
 def test_log_sync_reads_no_counter_when_tracing_is_off():
     """The counters cost two scalar reads a log line: not paid untraced."""
     tr = Trainer(_cfg(optimizer="Adagrad", l2_reg=0.0))
