@@ -49,7 +49,7 @@ class Config:
     coordinator_address: str = ""     # jax.distributed coordinator (host:port)
 
     # ---- model hyperparameters (reference: model flags) ----
-    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe
+    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear
     feature_size: int = 117581        # vocabulary size (reference ipynb:85)
     field_size: int = 39              # number of fields (reference ipynb:90)
     embedding_size: int = 32          # latent dim (reference flag default, ...py:44)
@@ -86,6 +86,28 @@ class Config:
     moe_pair_capacity: int = 0
     diffusion_block: int = 4
     diffusion_t_min: float = 1e-3
+    # kimi_linear only (hybrid linear-attention MoE decoder,
+    # models/kimi_linear.py), beside the decoder_layers / attn_* / moe_* /
+    # rms_norm_eps flags above, which mean here what they mean there
+    # (attn_q_heads = attn_kv_heads: the latent-attention heads held, each
+    # with its own key and value of attn_head_dim): layer i (from 1) mixes
+    # by latent attention where attn_every divides i and by KDA (kda_heads
+    # heads held, of kda_head_dim, short convolution kda_conv) elsewhere;
+    # the latent is mla_latent_dim wide and every head shares a key part of
+    # mla_rope_dim (not rotated); the first dense_layers layers feed forward
+    # through a dense MLP of dense_mlp_width, the others through the expert
+    # layer beside a shared expert of moe_shared_width; the router's
+    # renormalised sigmoid scores are scaled by moe_route_scale.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    attn_every: int = 0
+    mla_latent_dim: int = 0
+    mla_rope_dim: int = 0
+    dense_layers: int = 0
+    dense_mlp_width: int = 0
+    moe_shared_width: int = 0
+    moe_route_scale: float = 1.0
     l2_reg: float = 1e-4
     loss_type: str = "log_loss"       # log_loss | square_loss
 
@@ -452,14 +474,26 @@ class Config:
         if self.metrics_snapshot_secs < 0:
             raise ValueError("metrics_snapshot_secs must be >= 0")
         if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm",
-                              "dlrm_dcnv2", "din", "bst", "sdar_moe"):
+                              "dlrm_dcnv2", "din", "bst", "sdar_moe",
+                              "kimi_linear"):
             raise ValueError(f"unknown model: {self.model!r}")
         if self.model == "sdar_moe":
             self._validate_sdar_moe()
+        elif self.model == "kimi_linear":
+            self._validate_kimi_linear()
         elif self.decoder_layers or self.moe_experts or self.attn_q_heads:
             raise ValueError(
-                "decoder_layers/attn_*/moe_* belong to --model sdar_moe; "
-                f"{self.model!r} has no decoder block")
+                "decoder_layers/attn_*/moe_* belong to --model sdar_moe and "
+                f"kimi_linear; {self.model!r} has no decoder block")
+        if self.model != "kimi_linear" and (
+                self.kda_heads or self.attn_every or self.mla_latent_dim
+                or self.mla_rope_dim or self.dense_layers
+                or self.dense_mlp_width or self.moe_shared_width
+                or self.moe_route_scale != 1.0):
+            raise ValueError(
+                "kda_heads/attn_every/mla_*/dense_layers/dense_mlp_width/"
+                "moe_shared_width/moe_route_scale belong to --model "
+                f"kimi_linear; {self.model!r} has none of these layers")
         if self.model == "dlrm_dcnv2":
             self._validate_dlrm_dcnv2()
         elif self.numeric_fields or self.bottom_layers:
@@ -886,6 +920,75 @@ class Config:
         for what, set_ in refused.items():
             if set_:
                 raise ValueError(f"model sdar_moe does not take {what}")
+
+    def _validate_kimi_linear(self) -> None:
+        """What the hybrid linear-attention MoE decoder takes, and plainly
+        what it does not (models.kimi_linear.KimiLinear)."""
+        moe_layers = self.decoder_layers - self.dense_layers
+        need = {
+            "decoder_layers >= 1": self.decoder_layers >= 1,
+            "attn_every >= 1 (layer i mixes by latent attention where it "
+            "divides i, by KDA elsewhere)": self.attn_every >= 1,
+            "kda_heads >= 1 of kda_head_dim >= 1 and kda_conv >= 1 where a "
+            "layer is KDA": self.attn_every == 1 or (
+                self.kda_heads >= 1 and self.kda_head_dim >= 1
+                and self.kda_conv >= 1),
+            "attn_q_heads = attn_kv_heads >= 1 of attn_head_dim >= 1, "
+            "mla_latent_dim >= 1 and mla_rope_dim >= 1 where a layer is "
+            "latent attention": self.decoder_layers < self.attn_every or (
+                self.attn_q_heads == self.attn_kv_heads >= 1
+                and self.attn_head_dim >= 1 and self.mla_latent_dim >= 1
+                and self.mla_rope_dim >= 1),
+            "0 <= dense_layers <= decoder_layers": 0 <= self.dense_layers
+                <= self.decoder_layers,
+            "dense_mlp_width >= 1 where a layer is dense":
+                self.dense_layers == 0 or self.dense_mlp_width >= 1,
+            "1 <= moe_top_k <= moe_experts, moe_expert_width >= 1, "
+            "moe_shared_width >= 1 and moe_route_scale > 0 where a layer "
+            "has experts": moe_layers == 0 or (
+                1 <= self.moe_top_k <= self.moe_experts
+                and self.moe_expert_width >= 1 and self.moe_shared_width >= 1
+                and self.moe_route_scale > 0),
+            "moe_experts_held >= 1 experts from moe_first_expert on, all "
+            "among the moe_experts": moe_layers == 0 or (
+                self.moe_experts_held >= 1 and self.moe_first_expert >= 0
+                and self.moe_first_expert + self.moe_experts_held
+                <= self.moe_experts),
+            "moe_pair_capacity >= 1 (rows of a layer's pair buffer; every "
+            "pair of a step is batch_size * history_max_len * moe_top_k)":
+                moe_layers == 0 or self.moe_pair_capacity >= 1,
+            "history_max_len >= 2 (the sequence length; the loss is of the "
+            "next token)": self.history_max_len >= 2,
+            "feature_size >= 2": self.feature_size >= 2,
+        }
+        for what, ok in need.items():
+            if not ok:
+                raise ValueError(f"model kimi_linear needs {what}")
+        refused = {
+            "tasks (the loss is over the positions of a sequence, one task)":
+                self.num_tasks > 1,
+            "loss_type other than log_loss (the loss is the model's own "
+            "cross-entropy)": self.loss_type != "log_loss",
+            "batch_norm (the block's norm is RMSNorm)": self.batch_norm,
+            "embedding_update=sparse (the row plan covers feat_ids; the "
+            "tokens ride hist_ids)": self.embedding_update == "sparse",
+            "embedding_shard=rows (the vocabulary slice is the chip's "
+            "share already; the head is not row-sharded)":
+                self.embedding_shard == "rows",
+            "embedding_buckets (token ids are not hashed)":
+                bool(self.embedding_bucket_sizes),
+            "mesh_model > 1 (experts and heads over a mesh need their "
+            "exchange, which this model does not have)": self.mesh_model > 1,
+            "task_type infer/export (decoding from a recurrent state is a "
+            "serving feature; train and eval report the loss)":
+                self.task_type in ("infer", "export"),
+            "servable_model_dir (no serving export: the exported function "
+            "would be the decoder)": bool(self.servable_model_dir),
+            "online_mode (publishing exports a servable)": self.online_mode,
+        }
+        for what, set_ in refused.items():
+            if set_:
+                raise ValueError(f"model kimi_linear does not take {what}")
 
     # ---- derived views ------------------------------------------------
     @property

@@ -3,8 +3,8 @@
 ``get_model`` is the one dispatch point: a config with more than one task
 (``--tasks ctr,cvr``) builds the multi-task model (``--multitask``
 architecture over the shared graph bottom); otherwise ``cfg.model`` picks a
-single-task graph from the registry, or the block-diffusion decoder
-(``sdar_moe``), which is no ranker.
+single-task graph from the registry, or one of the decoders (``sdar_moe``,
+``kimi_linear``), which are no rankers.
 """
 
 from typing import Union
@@ -14,6 +14,7 @@ from .graph import DLRM, GraphDLRMDCNv2
 from .graph import GraphDCNv2 as DCNv2
 from .graph import GraphDeepFM as DeepFM
 from .graph import GraphWideDeep as WideDeep
+from .kimi_linear import KimiLinear
 from .multitask import MultiTaskModel  # noqa: F401
 from .sdar_moe import SdarMoE
 from .sequence import GraphBST, GraphDIN  # noqa: F401
@@ -27,10 +28,11 @@ _REGISTRY = {
     "din": GraphDIN,
     "bst": GraphBST,
     "sdar_moe": SdarMoE,
+    "kimi_linear": KimiLinear,
 }
 
 CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDLRMDCNv2, GraphDIN,
-                 GraphBST, SdarMoE, MultiTaskModel]
+                 GraphBST, SdarMoE, KimiLinear, MultiTaskModel]
 
 
 def registered_models():

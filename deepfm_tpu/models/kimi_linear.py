@@ -1,0 +1,470 @@
+"""Hybrid linear-attention mixture-of-experts decoder (``--model
+kimi_linear``).
+
+A pre-norm residual decoder (RMSNorm) trained on next-token cross-entropy,
+whose mixers alternate: ``--attn_every - 1`` layers of *Kimi Delta
+Attention* (KDA: a per-channel-gated delta-rule recurrence with a matrix
+state a head; Kimi Linear, arXiv:2510.26692) to one of multi-head latent
+attention without positional encoding (MLA, NoPE); the first
+``--dense_layers`` layers' feed-forward is a dense SwiGLU MLP, every later
+layer's an expert layer (sigmoid-scored router, ``--moe_top_k`` a token,
+renormalised and scaled) beside a shared expert every token passes.
+``benchmark/reference_kimi_linear.py`` holds the equations; this is the
+program's form of them. The expert layer, the router, the chunked attention,
+the head's loss and the rounding of operands are ``models.sdar_moe``'s, by
+import.
+
+What it reads of a batch: ``hist_ids`` [B, L], the sequence's tokens, which
+ride the record's history list (``--history_max_len L``); the token table is
+the ``EmbeddingSchema`` entry ``tok_emb`` (``--feature_size`` rows of
+``--embedding_size``, the model's width). The loss of a sequence is the mean
+over positions 0 .. L-2 of the cross-entropy of the next token; the model
+owns it (``owns_loss``).
+
+**A share of a layer.** The layer is told what it holds: ``--kda_heads`` KDA
+heads and ``--attn_q_heads`` MLA heads (their projections are here),
+``--moe_experts_held`` experts from ``--moe_first_expert`` on. What every
+chip of a layer computes alike is whole: the norms, the KDA gates'
+bottlenecks (``kda_w_fa``, ``kda_w_ga``), MLA's latent projection and its
+norm, the router, the shared expert, the dense MLP. The partial sums of the
+mixers' ``wo`` and of the routed experts go on unreduced; no code stands in
+for the absent chips.
+
+**The delta-rule scan** (``kda_scan``). Per head, with g_t <= 0 the
+per-channel log-decay and beta_t the write strength:
+``S_t = (I - beta_t k_t k_t^T) Diag(exp g_t) S_{t-1} + beta_t k_t v_t^T``,
+``o_t = S_t^T q_t``. Computed a chunk of ``KDA_CHUNK`` positions at a time:
+with G the cumulative log-decay inside a chunk, ``A[t,s] = sum_c k_tc k_sc
+exp(G_tc - G_sc)`` (s < t) and ``B[t,s]`` likewise with q_t (s <= t), the
+chunk's pseudo-values solve the unit lower-triangular system
+``(I + Diag(beta) A) U = Diag(beta) (V - (exp(G) * K) S_0)`` and
+``O = (exp(G) * Q) S_0 + B U``,
+``S_end = Diag(exp G_end) S_0 + (K * exp(G_end - G))^T U``; only the passing
+of S from chunk to chunk is sequential (a ``lax.scan``). No decay is ever
+divided by: every exponent is a difference ``G_t - G_s`` with s <= t, taken
+pairwise inside sub-chunks of ``KDA_SUB`` positions and through the
+sub-chunk's own first cumulative sum between them, so each factor is at most
+one whatever the decay (a chunk-wide ``exp(-G_s)`` overflows float32 from a
+log-decay of -1.4 a position on). The backward pass is the forward's,
+differentiated. ``kda_chunk_log_decay_min`` in the model state and the
+step's metrics is the most negative ``G_end`` of the step.
+
+Memory: every layer is recomputed in the backward pass (``jax.checkpoint``).
+The stack is a Python loop over layers of three shapes, not a scan.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from . import common
+from .graph import GraphModel
+from .sdar_moe import (_dot, _operand, _scores_xla, expert_layer, rms_norm,
+                       route, weighted_nll)
+
+#: The step's counts, in the model state and (by ``step_counts``) the metrics.
+COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
+               "moe_expert_load_max", "moe_layer_pairs_max")
+DECAY_MIN = "kda_chunk_log_decay_min"
+#: Positions a chunk of the delta-rule scan holds, and a sub-chunk inside
+#: which decays are taken pair by pair.
+KDA_CHUNK = 64
+KDA_SUB = 16
+#: epsilon of the L2 normalisation of q and k
+L2_EPS = 1e-6
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _merged(name: str, least, largest, total):
+    """How a count is merged over layers or replicas: the decay's least,
+    a ``_max`` count's largest, any other's sum."""
+    return least if name == DECAY_MIN else (
+        largest if name.endswith("_max") else total)
+
+
+def layer_kinds(cfg: Any) -> Tuple[Tuple[str, str], ...]:
+    """((mixer, feed-forward) of each layer): layer i (from 1) mixes by MLA
+    where ``attn_every`` divides i and by KDA elsewhere; the first
+    ``dense_layers`` feed forward through a dense MLP, the rest through the
+    expert layer."""
+    return tuple(("mla" if (i + 1) % cfg.attn_every == 0 else "kda",
+                  "mlp" if i < cfg.dense_layers else "moe")
+                 for i in range(cfg.decoder_layers))
+
+
+def causal_conv(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """Depthwise causal convolution over the last ``w.shape[0]`` positions:
+    x [B, S, C], w [K, C] -> ``y_t = sum_j w_j x_{t-K+1+j}``, positions before
+    the first reading zero."""
+    taps = w.shape[0]
+    s = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + s] * w[j] for j in range(taps))
+
+
+def _decayed_scores(x: jnp.ndarray, k: jnp.ndarray, gc: jnp.ndarray,
+                    sub: int) -> jnp.ndarray:
+    """``M[t, s] = sum_c x_tc k_sc exp(gc_tc - gc_sc)`` for s <= t inside a
+    chunk (x, k, gc [..., C, D], gc the chunk's cumulative log-decay;
+    entries with s > t are not meant to be read) -> [..., C, C], float32.
+    Inside a sub-chunk the exponent is taken pair by pair; between
+    sub-chunks it goes through the later one's reference (the cumulative
+    sum just ahead of it), both factors at most one."""
+    *lead, chunk, d = x.shape
+    m = chunk // sub
+    xs, ks, gs = (a.reshape(*lead, m, sub, d) for a in (x, k, gc))
+    ref = jnp.concatenate([jnp.zeros_like(gs[..., :1, -1, :]),
+                           gs[..., :-1, -1, :]], axis=-2)     # [..., m, D]
+    x_dec = xs * jnp.exp(gs - ref[..., None, :])
+    k_dec = k[..., None, :, :] * jnp.exp(jnp.minimum(
+        ref[..., :, None, :] - gc[..., None, :, :], 0.0))     # [..., m, C, D]
+    off = jnp.einsum("...itc,...isc->...its", x_dec, k_dec,
+                     precision=_HIGHEST).reshape(*lead, chunk, chunk)
+    pair = jnp.exp(jnp.minimum(
+        gs[..., :, None, :] - gs[..., None, :, :], 0.0))  # [..., m, t, s, D]
+    diag = jnp.sum(xs[..., :, None, :] * ks[..., None, :, :] * pair, axis=-1)
+    diag = jnp.einsum("...its,ij->...itjs", diag,
+                      jnp.eye(m, dtype=diag.dtype)).reshape(
+                          *lead, chunk, chunk)
+    sub_of = jnp.arange(chunk) // sub
+    return jnp.where(sub_of[:, None] == sub_of[None, :], diag, off)
+
+
+@jax.named_scope("kda_scan")
+def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
+             beta: jnp.ndarray, *, cdt: jnp.dtype, chunk: int = KDA_CHUNK,
+             sub: int = KDA_SUB) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The gated delta-rule recurrence from a zero state, chunk by chunk (the
+    module's docstring): q, k, g [B, L, H, Dk], v [B, L, H, Dv], beta
+    [B, L, H], float32 -> (o [B, L, H, Dv] float32, the most negative
+    cumulative log-decay a chunk held). Decays, the pairwise scores and the
+    triangular solve are float32; the products with the state take operands
+    of the compute precision."""
+    b, length, h, _ = q.shape
+    pad = -length % chunk
+    n = (length + pad) // chunk
+
+    def chunks(x):      # [B, L, H, D] -> [B, H, N, C, D], zeros past L
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        return jnp.moveaxis(x.reshape(b, n, chunk, h, x.shape[-1]), 3, 1)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta[..., None]))
+    gc = jnp.cumsum(g, axis=-2)
+    g_end = gc[..., -1:, :]
+    t_index = jnp.arange(chunk)
+    before = t_index[:, None] > t_index[None, :]
+    a = jnp.where(before, _decayed_scores(k, k, gc, sub), 0.0) * beta
+    reads = jnp.where(t_index[:, None] >= t_index[None, :],
+                      _decayed_scores(q, k, gc, sub), 0.0)
+    decay = jnp.exp(gc)
+    # (I + Diag(beta) A)^-1 applied to Diag(beta) [exp(G) * K, V]
+    solved = jax.scipy.linalg.solve_triangular(
+        a + jnp.eye(chunk, dtype=a.dtype),
+        beta * jnp.concatenate([k * decay, v], axis=-1),
+        lower=True, unit_diagonal=True)
+    w, u0 = solved[..., : k.shape[-1]], solved[..., k.shape[-1]:]
+    k_end = k * jnp.exp(g_end - gc)
+
+    def mm(spec, x, y):
+        return jnp.einsum(spec, _operand(x, cdt), _operand(y, cdt),
+                          preferred_element_type=jnp.float32)
+
+    def one_chunk(state, xs):       # state [B, H, Dk, Dv]
+        w_n, u0_n, q_n, reads_n, k_end_n, end_n = xs
+        u = u0_n - mm("bhck,bhkv->bhcv", w_n, state)
+        o = mm("bhck,bhkv->bhcv", q_n, state) + mm("bhcs,bhsv->bhcv",
+                                                   reads_n, u)
+        return end_n[..., None] * state + mm("bhck,bhcv->bhkv", k_end_n,
+                                             u), o
+
+    over_chunks = [jnp.moveaxis(x, 2, 0) for x in (
+        w, u0, q * decay, reads, k_end, jnp.exp(g_end[..., 0, :]))]
+    # (zeros made of the inputs: across data replicas the carry varies as
+    # they do)
+    state0 = jnp.einsum("bhk,bhv->bhkv", k[:, :, 0, 0] * 0.0,
+                        v[:, :, 0, 0] * 0.0)
+    _, o = jax.lax.scan(one_chunk, state0, over_chunks)
+    o = jnp.transpose(o, (1, 0, 3, 2, 4))               # [B, N, C, H, Dv]
+    o = o.reshape(b, n * chunk, h, -1)[:, :length]
+    return o, jnp.min(jax.lax.stop_gradient(g_end))
+
+
+@jax.named_scope("kda")
+def kda_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
+              eps: float, cdt: jnp.dtype
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The held heads' part of ``KDA(RMSNorm(x))``: x [B, S, d] ->
+    ([B, S, d] (``kda_wo``'s sum over the held heads, unreduced), the scan's
+    most negative chunk log-decay). ``lp['kda_a_log']`` [H] says how many
+    heads are held."""
+    b, s, _ = x.shape
+    xn = rms_norm(x, lp["norm1"], eps)
+
+    def heads(y):
+        return y.reshape(b, s, -1, head_dim)
+
+    def conv_in(name):
+        return heads(jax.nn.silu(causal_conv(
+            _dot(xn, lp["kda_w" + name], cdt), lp["kda_conv_" + name])))
+
+    def unit(y):
+        return y * jax.lax.rsqrt(
+            jnp.sum(jnp.square(y), axis=-1, keepdims=True) + L2_EPS)
+
+    q = unit(conv_in("q")) * head_dim ** -0.5
+    k, v = unit(conv_in("k")), conv_in("v")
+    g = -jnp.exp(lp["kda_a_log"])[:, None] * heads(jax.nn.softplus(
+        _dot(_dot(xn, lp["kda_w_fa"], cdt), lp["kda_w_fb"], cdt)
+        + lp["kda_dt_bias"]))
+    beta = jax.nn.sigmoid(_dot(xn, lp["kda_w_b"], cdt))
+    o, low = kda_scan(q, k, v, g, beta, cdt=cdt)
+    gate = jax.nn.sigmoid(heads(
+        _dot(_dot(xn, lp["kda_w_ga"], cdt), lp["kda_w_gb"], cdt)))
+    y = rms_norm(o, lp["kda_out_norm"], eps) * gate
+    return _dot(y.reshape(b, s, -1), lp["kda_wo"], cdt), low
+
+
+def causal(q_index: jnp.ndarray, k_index: jnp.ndarray) -> jnp.ndarray:
+    return k_index[None, :] <= q_index[:, None]
+
+
+@jax.named_scope("attn")
+def mla_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
+              rope_dim: int, eps: float, cdt: jnp.dtype) -> jnp.ndarray:
+    """The held heads' part of ``MLA(RMSNorm(x))`` without positional
+    encoding: x [B, S, d] -> [B, S, d]. A head's key is its own
+    ``head_dim`` columns of the expanded latent beside ``rope_dim`` columns
+    every head shares (not rotated); its value ``head_dim`` columns of the
+    expanded latent. The scores are ``models.sdar_moe``'s chunked XLA path
+    under the causal mask."""
+    b, s, _ = x.shape
+    xn = rms_norm(x, lp["norm1"], eps)
+    q = _dot(xn, lp["mla_wq"], cdt).reshape(b, s, -1, head_dim + rope_dim)
+    h = q.shape[2]
+    kva = _dot(xn, lp["mla_w_kva"], cdt)
+    latent = rms_norm(kva[..., :-rope_dim], lp["mla_kv_norm"], eps)
+    kv = _dot(latent, lp["mla_w_kvb"], cdt).reshape(b, s, h, 2 * head_dim)
+    shared = jnp.broadcast_to(kva[..., None, -rope_dim:],
+                              (b, s, h, rope_dim))
+    k = jnp.concatenate([kv[..., :head_dim], shared], axis=-1)
+    out = _scores_xla(_operand(q, cdt)[:, :, :, None], _operand(k, cdt),
+                      _operand(kv[..., head_dim:], cdt), cdt=cdt,
+                      mask=causal)
+    return _dot(out, lp["mla_wo"], cdt)
+
+
+@jax.named_scope("mlp")
+def swiglu(lp: Dict[str, jnp.ndarray], prefix: str, x: jnp.ndarray, *,
+           eps: float, cdt: jnp.dtype) -> jnp.ndarray:
+    """``E(RMSNorm(x; norm2))``, ``E(x) = (SiLU(x W_g) * x W_u) W_d``, whole
+    on every chip: the dense MLP (``mlp_``) or the shared expert
+    (``shared_``)."""
+    xn = rms_norm(x, lp["norm2"], eps)
+    mid = jax.nn.silu(_dot(xn, lp[prefix + "w_gate"], cdt)) \
+        * _dot(xn, lp[prefix + "w_up"], cdt)
+    return _dot(mid, lp[prefix + "w_down"], cdt)
+
+
+class KimiLinear(GraphModel):
+    """Hybrid KDA / MLA mixture-of-experts decoder over ``hist_ids``; see the
+    module's docstring."""
+
+    name = "kimi_linear"
+    uses_history = True
+    owns_loss = True
+    #: (``models.sdar_moe.SdarMoE.kernel_scopes``)
+    kernel_scopes = (("ragged-dot", "moe"),)
+
+    def __init__(self, cfg: Any):
+        super().__init__(cfg)
+        self.cdt = jnp.dtype(cfg.compute_dtype)
+        self.kinds = layer_kinds(cfg)
+        #: What the traced step is made of, said beside its counts on
+        #: ``train.log_sync`` while tracing is on.
+        self.step_notes: Dict[str, str] = {
+            "kda_scan": f"chunk{KDA_CHUNK}/sub{KDA_SUB}", "mla_scores": "xla"}
+        self.route_by = functools.partial(
+            route, score=jax.nn.sigmoid, scale=cfg.moe_route_scale)
+
+    def embedding_param_names(self) -> Tuple[str, ...]:
+        return ("tok_emb",)
+
+    def init_counts(self) -> common.State:
+        return {**{n: jnp.zeros((), jnp.int32) for n in COUNT_NAMES},
+                DECAY_MIN: jnp.zeros((), jnp.float32)}
+
+    def step_counts(self, model_state: common.State
+                    ) -> Dict[str, jnp.ndarray]:
+        """The counts a step's metrics carry beside its loss."""
+        return dict(model_state)
+
+    def _init_layer(self, rng: jax.Array, mixer: str, ffn: str
+                    ) -> Dict[str, jnp.ndarray]:
+        cfg = self.cfg
+        d, hd = cfg.embedding_size, cfg.attn_head_dim
+        keys = iter(jax.random.split(rng, 24))
+
+        def glorot(*shape):
+            return common.glorot_uniform(next(keys), shape)
+
+        def ones(*shape):
+            return jnp.ones(shape, jnp.float32)
+
+        lp = {"norm1": ones(d), "norm2": ones(d)}
+        if mixer == "kda":
+            h, kd = cfg.kda_heads, cfg.kda_head_dim
+            step = jnp.exp(jax.random.uniform(
+                next(keys), (h * kd,), jnp.float32,
+                jnp.log(1e-3), jnp.log(1e-1)))
+            lp.update({
+                "kda_wq": glorot(d, h * kd), "kda_wk": glorot(d, h * kd),
+                "kda_wv": glorot(d, h * kd),
+                "kda_conv_q": glorot(cfg.kda_conv, h * kd),
+                "kda_conv_k": glorot(cfg.kda_conv, h * kd),
+                "kda_conv_v": glorot(cfg.kda_conv, h * kd),
+                "kda_w_fa": glorot(d, kd), "kda_w_fb": glorot(kd, h * kd),
+                # a step size log-uniform in [0.001, 0.1], through the
+                # inverse of softplus; a decay rate uniform in [1, 16]
+                "kda_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+                "kda_a_log": jnp.log(jax.random.uniform(
+                    next(keys), (h,), jnp.float32, 1.0, 16.0)),
+                "kda_w_b": glorot(d, h),
+                "kda_w_ga": glorot(d, kd), "kda_w_gb": glorot(kd, h * kd),
+                "kda_out_norm": ones(kd), "kda_wo": glorot(h * kd, d)})
+        else:
+            h, rope, latent = (cfg.attn_q_heads, cfg.mla_rope_dim,
+                               cfg.mla_latent_dim)
+            lp.update({
+                "mla_wq": glorot(d, h * (hd + rope)),
+                "mla_w_kva": glorot(d, latent + rope),
+                "mla_kv_norm": ones(latent),
+                "mla_w_kvb": glorot(latent, h * 2 * hd),
+                "mla_wo": glorot(h * hd, d)})
+        if ffn == "mlp":
+            f = cfg.dense_mlp_width
+            lp.update({"mlp_w_gate": glorot(d, f), "mlp_w_up": glorot(d, f),
+                       "mlp_w_down": glorot(f, d)})
+        else:
+            f, held, fs = (cfg.moe_expert_width, cfg.moe_experts_held,
+                           cfg.moe_shared_width)
+            lp.update({
+                "router": glorot(d, cfg.moe_experts),
+                "w_gate": glorot(held, d, f), "w_up": glorot(held, d, f),
+                "w_down": glorot(held, f, d),
+                "shared_w_gate": glorot(d, fs), "shared_w_up": glorot(d, fs),
+                "shared_w_down": glorot(fs, d)})
+        return lp
+
+    def init(self, rng: jax.Array) -> Tuple[common.Params, common.State]:
+        cfg = self.cfg
+        d = cfg.embedding_size
+        k_emb, k_head, *k_layers = jax.random.split(rng, 2 + len(self.kinds))
+        params = {
+            "tok_emb": self.emb.init_entry(k_emb, (d,)),
+            "layers": {str(i): self._init_layer(k, *kind) for i, (k, kind)
+                       in enumerate(zip(k_layers, self.kinds))},
+            "final_norm": jnp.ones((d,), jnp.float32),
+            "head": common.glorot_uniform(k_head, (d, cfg.feature_size)),
+        }
+        return params, self.init_counts()
+
+    def _layer(self, mixer: str, ffn: str, x: jnp.ndarray,
+               lp: Dict[str, jnp.ndarray]
+               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+        """One block: ``h = x + Mixer(RMSNorm(x))``,
+        ``h + FFN(RMSNorm(h))`` -> (the stream, the layer's counts)."""
+        cfg = self.cfg
+        # (the barrier: ``models.sdar_moe.SdarMoE.hidden``)
+        lp = jax.lax.optimization_barrier(lp)
+        eps, counts = cfg.rms_norm_eps, {}
+        if mixer == "kda":
+            y, counts[DECAY_MIN] = kda_mixer(
+                lp, x, head_dim=cfg.kda_head_dim, eps=eps, cdt=self.cdt)
+        else:
+            y = mla_mixer(lp, x, head_dim=cfg.attn_head_dim,
+                          rope_dim=cfg.mla_rope_dim, eps=eps, cdt=self.cdt)
+        h = x + y
+        if ffn == "mlp":
+            return h + swiglu(lp, "mlp_", h, eps=eps, cdt=self.cdt), counts
+        y, moe_counts = expert_layer(
+            lp, h, top_k=cfg.moe_top_k, first_expert=cfg.moe_first_expert,
+            capacity=cfg.moe_pair_capacity, eps=eps, cdt=self.cdt,
+            route_by=self.route_by)
+        return (h + y + swiglu(lp, "shared_", h, eps=eps, cdt=self.cdt),
+                {**counts, **moe_counts})
+
+    def hidden(self, params: common.Params, ids: jnp.ndarray, *,
+               shard_axis: Optional[str] = None,
+               emb_rows: Optional[Dict[str, Any]] = None,
+               emb_plan: Optional[Dict[str, Any]] = None,
+               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+        """ids [B, L] -> (the last residual stream [B, L, d], the layers'
+        counts: sums, the ``_max`` ones' largest, the decay's least)."""
+        x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
+                             emb_plan).astype(jnp.float32)
+        seen: Dict[str, list] = {}
+        for i, kind in enumerate(self.kinds):
+            x, counts = jax.checkpoint(functools.partial(self._layer, *kind))(
+                x, params["layers"][str(i)])
+            for name, value in counts.items():
+                seen.setdefault(name, []).append(value)
+        return x, {name: _merged(name, jnp.min, jnp.max, jnp.sum)(
+            jnp.stack(values)) for name, values in seen.items()}
+
+    @jax.named_scope("head")
+    def logits(self, params: common.Params, h: jnp.ndarray) -> jnp.ndarray:
+        """[..., d] of the last residual stream -> [..., V]: final norm and
+        head product."""
+        hn = rms_norm(h, params["final_norm"], self.cfg.rms_norm_eps)
+        return _dot(hn, params["head"], self.cdt)
+
+    def _run(self, params, state, tokens, shard_axis, data_axis, emb):
+        tokens = tokens.astype(jnp.int32)
+        h, counts = self.hidden(params, tokens, shard_axis=shard_axis, **emb)
+        counts = {**self.init_counts(), **counts}
+        if data_axis is not None:       # the replicas' counts, as one
+            counts = {k: _merged(k, jax.lax.pmin, jax.lax.pmax, jax.lax.psum)(
+                v, data_axis) for k, v in counts.items()}
+        counts["moe_pairs_over_buffer"] = (
+            state["moe_pairs_over_buffer"] + counts["moe_pairs_over_buffer"])
+        return h, tokens, counts
+
+    def apply(self, params: common.Params, state: common.State,
+              feat_ids: jnp.ndarray, feat_vals: jnp.ndarray, *,
+              train: bool, rng: Optional[jax.Array] = None,
+              shard_axis: Optional[str] = None,
+              data_axis: Optional[str] = None,
+              emb_rows: Optional[Dict[str, Any]] = None,
+              emb_plan: Optional[Dict[str, Any]] = None,
+              hist_ids: Optional[jnp.ndarray] = None,
+              hist_mask: Optional[jnp.ndarray] = None,
+              ) -> Tuple[jnp.ndarray, common.State]:
+        """Logits [B, L, V]: position i's are of token i + 1."""
+        h, _, counts = self._run(
+            params, state, hist_ids, shard_axis, data_axis,
+            {"emb_rows": emb_rows, "emb_plan": emb_plan})
+        return self.logits(params, h), counts
+
+    def per_example_loss(self, params: common.Params, state: common.State,
+                         batch: Dict[str, jnp.ndarray], *, train: bool,
+                         rng: Optional[jax.Array],
+                         shard_axis: Optional[str] = None,
+                         data_axis: Optional[str] = None, **emb
+                         ) -> Tuple[jnp.ndarray, common.State]:
+        """(loss a sequence [B], new state): the mean over positions
+        0 .. L-2 of the next token's cross-entropy. The head runs over all L
+        positions in whole chunks; the last one's weight is zero."""
+        h, tokens, counts = self._run(
+            params, state, batch["hist_ids"], shard_axis, data_axis, emb)
+        length = tokens.shape[1]
+        labels = jnp.roll(tokens, -1, axis=1)
+        weight = jnp.broadcast_to(
+            (jnp.arange(length) < length - 1).astype(jnp.float32),
+            tokens.shape)
+        per_seq = weighted_nll(functools.partial(self.logits, params), h,
+                               labels, weight) / (length - 1)
+        return per_seq, counts

@@ -71,7 +71,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -173,21 +173,27 @@ def _dot(x: jnp.ndarray, w: jnp.ndarray, cdt: jnp.dtype) -> jnp.ndarray:
 
 
 def _scores_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                length: int, block: int, cdt: jnp.dtype) -> jnp.ndarray:
+                cdt: jnp.dtype, length: int = 0, block: int = 0,
+                mask: Optional[Callable] = None) -> jnp.ndarray:
     """softmax(mask(q k^T / sqrt(D))) v as XLA ops, a chunk of queries at a
     time, each chunk made again in the backward pass: q [B, S, n_kv, G, D],
-    k and v [B, S, n_kv, D] (operands) -> [B, S, n_kv * G * D] float32."""
+    k [B, S, n_kv, D] and v [B, S, n_kv, Dv] (operands) ->
+    [B, S, n_kv * G * Dv] float32. ``mask(q_index [Q], k_index [K])`` says
+    which keys a query reads: the block-diffusion mask of ``length`` and
+    ``block`` unless another is handed in (``models.kimi_linear``: causal)."""
     b, s, n_kv, group, head_dim = q.shape
     chunk = QUERY_CHUNK if s % QUERY_CHUNK == 0 else s
     scale = 1.0 / math.sqrt(head_dim)
     k_index = jnp.arange(s)
+    if mask is None:
+        mask = functools.partial(allowed, length=length, block=block)
 
     @jax.checkpoint
     def one_chunk(args):
         q_c, start = args                       # [B, Qc, n_kv, G, D]
         scores = jnp.einsum("bqngd,bknd->bngqk", q_c, k,
                             preferred_element_type=jnp.float32) * scale
-        ok = allowed(start + jnp.arange(chunk), k_index, length, block)
+        ok = mask(start + jnp.arange(chunk), k_index)
         scores = jnp.where(ok[None, None, None], scores, -jnp.inf)
         p = jax.nn.softmax(scores, axis=-1)
         return jnp.einsum("bngqk,bknd->bqngd", _operand(p, cdt), v,
@@ -278,22 +284,34 @@ def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
     return _dot(out, lp["wo"], cdt)
 
 
-def route(xn: jnp.ndarray, router: jnp.ndarray, top_k: int
+def route(xn: jnp.ndarray, router: jnp.ndarray, top_k: int, *,
+          score: Callable = functools.partial(jax.nn.softmax, axis=-1),
+          bias: Optional[jnp.ndarray] = None, scale: float = 1.0
           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """xn [T, d] -> (experts [T, k], weights [T, k]): the k largest of a
-    float32 softmax over every expert, their probabilities renormalised. The
-    product is float32 at full precision: it is 1/40 of a layer's work, and
-    a rounded logit moves which expert is the k-th."""
+    float32 ``score`` of every expert's logit (a softmax over the experts
+    unless the model hands in another: ``jax.nn.sigmoid``), their scores
+    renormalised and times ``scale``. ``bias`` [E] is added to the scores
+    for the selection only: it moves which experts are the k, never their
+    weights. Equal scores go to the lower index. The product is float32 at
+    full precision: it is 1/40 of a layer's work, and a rounded logit moves
+    which expert is the k-th."""
     logits = jnp.matmul(xn.astype(jnp.float32), router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    top_p, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    return top_e, top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    scores = score(logits)
+    if bias is None:
+        top_p, top_e = jax.lax.top_k(scores, top_k)
+    else:
+        _, top_e = jax.lax.top_k(scores + bias, top_k)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_e, weights if scale == 1.0 else weights * scale
 
 
 @jax.named_scope("moe")
 def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
                  top_k: int, first_expert: int, capacity: int,
-                 eps: float, cdt: jnp.dtype
+                 eps: float, cdt: jnp.dtype, route_by: Callable = route
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The held experts' part of ``MoE(RMSNorm(x))``: x [B, S, d] ->
     ([B, S, d], counts). ``lp['w_gate']`` [held, d, f] says how many experts
@@ -301,12 +319,13 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
     ``capacity`` of the sorted pairs are computed, in equal passes of at most
     ``PASS_ROWS`` rows, each made again in the backward pass, so that the
     layer's memory is one pass's; pairs beyond them are counted and add
-    nothing."""
+    nothing. ``route_by(xn, router, top_k)`` is the model's router
+    (``route``, with what the model binds of its keywords)."""
     shape = x.shape
     xn = rms_norm(x, lp["norm2"], eps).reshape(-1, shape[-1])
     n_tok = xn.shape[0]
     n_held = lp["w_gate"].shape[0]
-    top_e, top_w = route(xn, lp["router"], top_k)
+    top_e, top_w = route_by(xn, lp["router"], top_k)
     # Sort the (position, expert) pairs by held expert; absent ones last.
     local = top_e.reshape(-1) - first_expert
     key = jnp.where((local >= 0) & (local < n_held), local, n_held)
@@ -362,6 +381,33 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
               "moe_expert_load_max": jnp.max(load),
               "moe_layer_pairs_max": held}
     return out.reshape(shape), counts
+
+
+@jax.named_scope("head")
+def weighted_nll(logits_of: Callable, h: jnp.ndarray, labels: jnp.ndarray,
+                 weight: jnp.ndarray) -> jnp.ndarray:
+    """sum over a sequence's positions of ``weight`` times the negative
+    log-likelihood of ``labels`` [B, L] under ``logits_of(h)`` (h [B, L, d]
+    -> [B, L, V]) -> [B], a chunk of ``HEAD_CHUNK`` positions at a time
+    (each chunk's logits are made again in the backward pass and never all
+    held)."""
+    b, length, _ = h.shape
+    chunk = HEAD_CHUNK if length % HEAD_CHUNK == 0 else length
+
+    @jax.checkpoint
+    def one_chunk(args):
+        h_c, tok_c, w_c = args
+        logp = jax.nn.log_softmax(logits_of(h_c), axis=-1)
+        nll = -jnp.take_along_axis(logp, tok_c[..., None], axis=-1)
+        return jnp.sum(nll[..., 0] * w_c, axis=1)
+
+    def chunks(x):
+        return jnp.moveaxis(
+            x.reshape(b, length // chunk, chunk, *x.shape[2:]), 1, 0)
+
+    sums = jax.lax.map(one_chunk, (chunks(h), chunks(labels),
+                                   chunks(weight)))
+    return jnp.sum(sums, axis=0)
 
 
 class SdarMoE(GraphModel):
@@ -497,27 +543,11 @@ class SdarMoE(GraphModel):
                    t: jnp.ndarray) -> jnp.ndarray:
         """Loss a sequence [B] from the noisy half's residual stream h
         [B, L, d]: the 1/t-weighted cross-entropy at the masked positions
-        over L, a chunk of positions at a time (each chunk's logits are made
-        again in the backward pass and never all held)."""
-        b, length, d = h.shape
-        chunk = HEAD_CHUNK if length % HEAD_CHUNK == 0 else length
+        over L (``weighted_nll``)."""
         weight = jnp.where(masked, 1.0 / jnp.repeat(
             t, self.cfg.diffusion_block, axis=1), 0.0)
-
-        @jax.checkpoint
-        def one_chunk(args):
-            h_c, tok_c, w_c = args
-            logp = jax.nn.log_softmax(self.logits(params, h_c), axis=-1)
-            nll = -jnp.take_along_axis(logp, tok_c[..., None], axis=-1)
-            return jnp.sum(nll[..., 0] * w_c, axis=1)
-
-        def chunks(x):
-            return jnp.moveaxis(
-                x.reshape(b, length // chunk, chunk, *x.shape[2:]), 1, 0)
-
-        sums = jax.lax.map(one_chunk, (chunks(h), chunks(tokens),
-                                       chunks(weight)))
-        return jnp.sum(sums, axis=0) / length
+        return weighted_nll(functools.partial(self.logits, params), h,
+                            tokens, weight) / h.shape[1]
 
     def _run(self, params, state, tokens, rng, shard_axis, data_axis, emb):
         cfg = self.cfg

@@ -2018,7 +2018,11 @@ class Trainer:
                         if trace_lib.enabled():
                             # the step's counts (the last scanned step's,
                             # like the loss): ready with it
-                            counts = {key: int(v) for key, v in m.items()
+                            # (whole numbers, but for a model's count
+                            # that is a float: kda_chunk_log_decay_min)
+                            counts = {key: (int(v) if jnp.issubdtype(
+                                jnp.result_type(v), jnp.integer)
+                                else float(v)) for key, v in m.items()
                                       if key not in ("loss", "xent",
                                                      "steps_done")}
                             if ROW_COUNTS[0] in counts:

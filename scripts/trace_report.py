@@ -43,6 +43,13 @@ every query chunk) and, of the kernel, ``attn_score_blocks`` (visited /
 all, a head); the report prints them on its "block-masked attention" line
 (TUNING §5).
 
+Where the model scans a delta-rule recurrence (``--model kimi_linear``), each
+``train.log_sync`` carries ``kda_scan`` (the algorithm and chunk length of
+the compiled step: ``chunk64/sub16``), ``mla_scores`` (``xla`` / ``kernel``)
+and the step's ``kda_chunk_log_decay_min``, the most negative cumulative
+log-decay a chunk held; the report prints one "delta-rule scan" line with the
+count's minimum over the trace (TUNING §17).
+
 Usage:
     python scripts/trace_report.py TRACE.json [--top 20] [--json]
                                               [--stalls MS]
@@ -232,6 +239,23 @@ def attention_scores(events):
     return out
 
 
+def delta_rule_scan(events):
+    """The delta-rule scan's notes and count off the ``train.log_sync`` spans
+    that carry them: ``steps`` read, ``scan`` (``kda_scan``: algorithm and
+    chunk length), ``mla_scores`` and ``log_decay_min`` (the least
+    ``kda_chunk_log_decay_min``; None in a trace that predates the count);
+    None when no span says ``kda_scan`` (another model, or an older
+    trace)."""
+    seen = _log_syncs(events, "kda_scan")
+    if not seen:
+        return None
+    lows = [a["kda_chunk_log_decay_min"] for a in seen
+            if "kda_chunk_log_decay_min" in a]
+    return {"steps": len(seen), "scan": seen[-1]["kda_scan"],
+            "mla_scores": seen[-1].get("mla_scores", "?"),
+            "log_decay_min": min(lows) if lows else None}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("trace", help="trace-<pid>.json or a merged trace file")
@@ -250,6 +274,7 @@ def main(argv=None):
     slow = stalls(events, args.stalls) if args.stalls is not None else None
     touched = row_updates(events)
     attn = attention_scores(events)
+    scan = delta_rule_scan(events)
 
     if args.json:
         doc = {
@@ -262,6 +287,8 @@ def main(argv=None):
             doc["row_updates"] = touched
         if attn is not None:
             doc["attention_scores"] = attn
+        if scan is not None:
+            doc["delta_rule_scan"] = scan
         print(json.dumps(doc, indent=2))
         return 0
 
@@ -298,6 +325,12 @@ def main(argv=None):
                  "(%.1f%%)" % (attn["visited"], attn["total"],
                                100 * attn["visited"] / attn["total"])
                  if "visited" in attn else ", every score computed"))
+    if scan is not None:
+        low = scan["log_decay_min"]
+        print("delta-rule scan over %d logged steps: %s, latent attention's "
+              "scores by %s, most negative chunk log-decay %s"
+              % (scan["steps"], scan["scan"], scan["mla_scores"],
+                 "not in this trace" if low is None else "%.4g" % low))
     for st in slow or ():
         cover = ", ".join(f"{k} {v:.1f}" for k, v in st["cover_ms"].items()
                           if v > 0)

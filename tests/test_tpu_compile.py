@@ -220,3 +220,33 @@ def test_attention_kernels_compile_at_the_cells_shapes(v5e,
         assert f"%{name}" in text, name
     # one float32 [2, 4, 8192, 8192] score matrix would be 2.1 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_hybrid_decoder_step_at_the_cells_shapes_fits_and_names_its_layers(
+        v5e, no_compile_cache, monkeypatch):
+    """``kimi-linear-48b-a3b.train-sequences-8k``'s own step (every width,
+    5 layers, 2 x 8,192 tokens) compiled for a described v5e: ops charged to
+    each of the model's scopes, the delta-rule scan's own among them, and
+    arguments and temporaries together under the chip's memory (the issue's
+    fallback to one sequence a step starts at 15.5 GB)."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        flags = json.load(f)["flags"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = Config(**flags)
+    tr = Trainer(cfg, mesh_info=mesh_lib.build_mesh(cfg, devices=[v5e]))
+    compiled = tr.step_compiled(device=v5e)
+    text = profiling.scope_kernels(
+        profiling.whole_instructions(compiled.as_text()),
+        tr.model.kernel_scopes)
+    scopes = set(profiling.hlo_op_scopes(text).values())
+    assert {"embed", "kda", "kda_scan", "attn", "mlp", "moe", "head",
+            "opt"} <= scopes
+    memory = compiled.memory_analysis()
+    assert 7.8e9 < memory.argument_size_in_bytes < 8.0e9
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 15.5e9

@@ -465,3 +465,60 @@ def test_log_sync_reads_no_counter_when_tracing_is_off():
     tr = Trainer(_cfg(optimizer="Adagrad", l2_reg=0.0))
     _, out = tr.fit(tr.init_state(), _batches(K * 2))
     assert out["steps"] == K * 2 and not trace_lib._tracer.events()
+
+
+def test_log_sync_says_how_the_delta_rule_scan_is_computed(tmp_path, capsys):
+    """The hybrid linear-attention decoder says on the span that reads the
+    loss back what its compiled step's scan and latent attention are made
+    of, with the step's most negative chunk log-decay (a float among the
+    counts), and the report prints its line."""
+    length, vocab, batch = 12, 50, 2
+    cfg = Config(model="kimi_linear", feature_size=vocab, field_size=1,
+                 embedding_size=16, history_max_len=length, decoder_layers=2,
+                 attn_every=2, dense_layers=1, kda_heads=2, kda_head_dim=8,
+                 attn_q_heads=2, attn_kv_heads=2, attn_head_dim=8,
+                 mla_latent_dim=8, mla_rope_dim=4, dense_mlp_width=16,
+                 moe_experts=4, moe_top_k=2, moe_expert_width=8,
+                 moe_shared_width=8, moe_experts_held=2, moe_first_expert=0,
+                 moe_pair_capacity=batch * length * 2, moe_route_scale=2.446,
+                 batch_size=batch, l2_reg=0.0, learning_rate=1e-3,
+                 steps_per_loop=1, log_steps=2, compute_dtype="float32",
+                 mesh_data=1, mesh_model=1)
+    rng = np.random.default_rng(5)
+    batches = [{"feat_ids": np.zeros((batch, 1), np.int32),
+                "feat_vals": np.ones((batch, 1), np.float32),
+                "label": np.zeros((batch, 1), np.float32),
+                "hist_ids": rng.integers(0, vocab, (batch, length)
+                                         ).astype(np.int32),
+                "hist_mask": np.ones((batch, length), np.float32)}
+               for _ in range(4)]
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(cfg)
+    tr.fit(tr.init_state(), batches)
+    syncs = [e["args"] for e in trace_lib._tracer.events()
+             if e["name"] == "train.log_sync"]
+    assert [(a["kda_scan"], a["mla_scores"]) for a in syncs] == [
+        ("chunk64/sub16", "xla")] * 2
+    lows = [a["kda_chunk_log_decay_min"] for a in syncs]
+    assert all(isinstance(x, float) and x < 0 for x in lows)
+    assert all(isinstance(a["moe_pairs_held"], int) for a in syncs)
+    path = str(tmp_path / "trace.json")
+    trace_lib.export(path)
+    report = _report()
+    loaded, _ = report._load(path)
+    assert report.delta_rule_scan(loaded) == {
+        "steps": 2, "scan": "chunk64/sub16", "mla_scores": "xla",
+        "log_decay_min": pytest.approx(min(lows))}
+    assert report.attention_scores(loaded) is None
+    assert report.main([path]) == 0
+    assert ("delta-rule scan over 2 logged steps: chunk64/sub16, latent "
+            "attention's scores by xla, most negative chunk log-decay "
+            ) in capsys.readouterr().out
+    # a trace that predates the count, and one of another model
+    old = [{"name": "train.log_sync", "ph": "X",
+            "args": {"step": 2, "kda_scan": "chunk64/sub16"}}]
+    assert report.delta_rule_scan(old) == {
+        "steps": 1, "scan": "chunk64/sub16", "mla_scores": "?",
+        "log_decay_min": None}
+    assert report.delta_rule_scan(
+        [{"name": "train.log_sync", "ph": "X", "args": {"step": 2}}]) is None
