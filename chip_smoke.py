@@ -96,7 +96,8 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
                   ) -> dict:
     """fused_fm and take_rows_pallas, forward and backward, and put_rows
     against their XLA legs at (B, F, K) of ``shape``; the block-masked
-    attention kernel against the chunked XLA path at a shape of its own.
+    attention kernel against the chunked XLA path and the expert layer's row
+    kernels against take and scatter-add at shapes of their own.
     ``interpret=False`` is the compiled path (TPU only); the CPU rehearsal
     passes True."""
     import jax
@@ -207,6 +208,54 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
                                           out_and_grads(by_xla))]
     assert max(errs) <= 2.0 ** -5, f"block attention vs XLA: {errs}"
     out["block_attention_max_rel_err"] = max(errs)
+
+    # the expert layer's rows (ops/pallas_moe_rows): a pass of 1,024 buffer
+    # rows of 2,304 (18 lines) in 4 groups, 700 of them held, over 512
+    # positions, taken (bfloat16) and added back weighted, forward and
+    # backward, against take / scatter-add; spare rows name no position.
+    from deepfm_tpu.ops import pallas_moe_rows as pmr
+
+    positions, buf, held, width = 512, 1024, 700, 2304
+    tok = np.concatenate([np.sort(rng.choice(positions, 256, replace=False))
+                          for _ in range(4)]).astype(np.int32)
+    ends = jnp.asarray(np.minimum(256 * np.arange(1, 5), held), jnp.int32)
+    spare = jnp.asarray(np.where(np.arange(buf) < held, tok, 2 ** 30))
+    tok, valid = jnp.asarray(tok), jnp.arange(buf) < held
+    x, carry = (jnp.asarray(rng.normal(size=(positions, width)), jnp.float32)
+                for _ in range(2))
+    y = jnp.asarray(rng.normal(size=(buf, width)), jnp.float32)
+    scale = jnp.asarray(rng.uniform(0.1, 1.0, buf), jnp.float32)
+
+    def rows_by_kernel(x, y, carry, scale):
+        # (x's gradient comes through the argument that rides along)
+        xs, _ = pmr.gather(jax.lax.stop_gradient(x), x, spare, ends,
+                           jnp.bfloat16, interpret=interpret)
+        return pmr.combine(carry, xs.astype(jnp.float32) * y, scale, spare,
+                           ends, interpret=interpret)
+
+    def rows_by_xla(x, y, carry, scale):
+        xs = jnp.where(valid[:, None], jnp.take(x, tok, axis=0),
+                       0.0).astype(jnp.bfloat16)
+        return carry.at[tok].add(jnp.where(
+            valid[:, None], xs.astype(jnp.float32) * y * scale[:, None], 0.0))
+
+    def rows_and_grads(f):
+        def loss(*a):
+            o = f(*a)
+            return jnp.sum(o * jnp.cos(carry)), o
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3), has_aux=True))(x, y, carry, scale)
+        return (o, *g)
+
+    errs = [max_rel(g, w) for g, w in zip(rows_and_grads(rows_by_kernel),
+                                          rows_and_grads(rows_by_xla))]
+    # (the sum, then the gradients of x, y, carry, scale; x's comes through
+    # the bfloat16 rows' cotangent, whose rounding XLA is free to skip on
+    # its own path: xla_allow_excess_precision)
+    limits = (1e-5, 2.0 ** -7, 1e-5, 1e-5, 1e-5)
+    assert all(e <= lim for e, lim in zip(errs, limits)), (
+        f"moe rows vs XLA: {errs} > {limits}")
+    out["moe_rows_max_rel_err"] = max(errs)
     return out
 
 

@@ -63,8 +63,8 @@ import jax.numpy as jnp
 
 from . import common
 from .graph import GraphModel
-from .sdar_moe import (_dot, _operand, _scores_xla, expert_layer, rms_norm,
-                       route, weighted_nll)
+from .sdar_moe import (_dot, _operand, _scores_xla, expert_layer, moe_notes,
+                       moe_rows_by, rms_norm, route, weighted_nll)
 
 #: The step's counts, in the model state and (by ``step_counts``) the metrics.
 COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
@@ -284,7 +284,8 @@ class KimiLinear(GraphModel):
         self.cdt = jnp.dtype(cfg.compute_dtype)
         self.kinds = layer_kinds(cfg)
         #: What the traced step is made of, said beside its counts on
-        #: ``train.log_sync`` while tracing is on.
+        #: ``train.log_sync`` while tracing is on (``hidden`` adds the expert
+        #: layers' ``sdar_moe.moe_notes``).
         self.step_notes: Dict[str, str] = {
             "kda_scan": f"chunk{KDA_CHUNK}/sub{KDA_SUB}", "mla_scores": "xla"}
         self.route_by = functools.partial(
@@ -373,7 +374,7 @@ class KimiLinear(GraphModel):
         return params, self.init_counts()
 
     def _layer(self, mixer: str, ffn: str, x: jnp.ndarray,
-               lp: Dict[str, jnp.ndarray]
+               lp: Dict[str, jnp.ndarray], rows_by: str = "xla"
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """One block: ``h = x + Mixer(RMSNorm(x))``,
         ``h + FFN(RMSNorm(h))`` -> (the stream, the layer's counts)."""
@@ -393,7 +394,7 @@ class KimiLinear(GraphModel):
         y, moe_counts = expert_layer(
             lp, h, top_k=cfg.moe_top_k, first_expert=cfg.moe_first_expert,
             capacity=cfg.moe_pair_capacity, eps=eps, cdt=self.cdt,
-            route_by=self.route_by)
+            route_by=self.route_by, rows_by=rows_by)
         return (h + y + swiglu(lp, "shared_", h, eps=eps, cdt=self.cdt),
                 {**counts, **moe_counts})
 
@@ -401,15 +402,25 @@ class KimiLinear(GraphModel):
                shard_axis: Optional[str] = None,
                emb_rows: Optional[Dict[str, Any]] = None,
                emb_plan: Optional[Dict[str, Any]] = None,
+               data_axis: Optional[str] = None,
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """ids [B, L] -> (the last residual stream [B, L, d], the layers'
-        counts: sums, the ``_max`` ones' largest, the decay's least)."""
+        counts: sums, the ``_max`` ones' largest, the decay's least).
+        ``data_axis`` names the mesh axis of a step across data replicas."""
+        cfg = self.cfg
+        rows_by = moe_rows_by(cfg.embedding_size, ids.size,
+                              cfg.moe_pair_capacity,
+                              one_device=data_axis is None)
+        self.step_notes.update(moe_notes(
+            rows_by, cfg.moe_pair_capacity,
+            sum(ffn == "moe" for _, ffn in self.kinds)))
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
         seen: Dict[str, list] = {}
         for i, kind in enumerate(self.kinds):
-            x, counts = jax.checkpoint(functools.partial(self._layer, *kind))(
-                x, params["layers"][str(i)])
+            x, counts = jax.checkpoint(functools.partial(
+                self._layer, *kind, rows_by=rows_by))(
+                    x, params["layers"][str(i)])
             for name, value in counts.items():
                 seen.setdefault(name, []).append(value)
         return x, {name: _merged(name, jnp.min, jnp.max, jnp.sum)(
@@ -424,7 +435,8 @@ class KimiLinear(GraphModel):
 
     def _run(self, params, state, tokens, shard_axis, data_axis, emb):
         tokens = tokens.astype(jnp.int32)
-        h, counts = self.hidden(params, tokens, shard_axis=shard_axis, **emb)
+        h, counts = self.hidden(params, tokens, shard_axis=shard_axis,
+                                data_axis=data_axis, **emb)
         counts = {**self.init_counts(), **counts}
         if data_axis is not None:       # the replicas' counts, as one
             counts = {k: _merged(k, jax.lax.pmin, jax.lax.pmax, jax.lax.psum)(

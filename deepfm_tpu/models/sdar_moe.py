@@ -36,8 +36,23 @@ expert into a buffer of ``--moe_pair_capacity`` rows (static shapes), which
 is computed in equal passes of at most ``PASS_ROWS`` rows (one pass's
 memory): the three products run as grouped products over the held experts'
 (``jax.lax.ragged_dot``), and the rows are weighted and added back to their
-positions. The buffer's spare rows are computed as zeros, so a step costs
-the same whatever the routing. No pair is dropped silently: pairs beyond the
+positions. The sorted pairs put the held experts' first, so a pass's real
+rows are a prefix of it; the buffer's spare rows are computed as zeros (they
+ride the products' last group: their inputs have to be zeros and not
+whatever lay there, or a stray NaN times a zero cotangent would reach a
+weight's gradient), so the products cost the same whatever the routing.
+What moves a pass's rows from and to their positions is picked from what
+the code can see (``moe_rows_by``: backend, the row's width, whole tiles of
+positions and of buffer rows, one device's program; no flag): on a TPU at a
+row of whole 128-lane lines the two kernels of ``ops/pallas_moe_rows``, one
+DMA a row over the prefix only — ``gather`` (the rows of RMSNorm(x), cast to
+the products' type on their way; in the backward pass their cotangent summed
+into the positions', float32, in the pass loop's carry) and ``combine`` (a
+pass's weighted rows added into the layer's sum in place, a group's
+positions at a time; backward, the sum's cotangent taken to the rows) —
+and everywhere else (a CPU, the tests' narrow rows, a step across data
+replicas) ``jnp.take`` and ``.at[].add`` over every row of the buffer,
+spare ones masked. No pair is dropped silently: pairs beyond the
 buffer's rows are counted
 (``moe_pairs_over_buffer``, cumulative), with the pairs held, the fullest
 expert's count, the fullest layer's pairs (what the buffer has to hold) and
@@ -76,7 +91,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import block_attention
+from ..ops import block_attention, pallas_moe_rows
 from . import common
 from .graph import GraphModel
 
@@ -308,10 +323,43 @@ def route(xn: jnp.ndarray, router: jnp.ndarray, top_k: int, *,
     return top_e, weights if scale == 1.0 else weights * scale
 
 
+def pass_rows(capacity: int) -> Tuple[int, int]:
+    """(passes, rows a pass) of a pair buffer of ``capacity`` rows: equal
+    passes of at most ``PASS_ROWS``."""
+    passes = -(-capacity // PASS_ROWS)
+    return passes, -(-capacity // passes)
+
+
+def moe_rows_by(width: int, positions: int, capacity: int, *,
+                one_device: bool = True, backend: Optional[str] = None
+                ) -> str:
+    """``kernel`` where the expert layer's rows go to and from their
+    positions by the row-copy kernels (``ops/pallas_moe_rows.supported``: a
+    TPU backend, a row of whole 128-lane lines, positions and a pass in
+    whole sublane tiles; and a step that is one device's program,
+    as ``attn_scores_by`` asks), else ``xla`` (``jnp.take`` and
+    ``.at[].add`` over every row of the buffer): read from the backend, the
+    shapes and the mesh."""
+    return ("kernel" if one_device and pallas_moe_rows.supported(
+        width, positions, pass_rows(capacity)[1], backend) else "xla")
+
+
+def moe_notes(rows_by: str, capacity: int, layers: int) -> Dict[str, str]:
+    """What ``step_notes`` says of the expert layers' row movement:
+    ``moe_rows`` (``kernel`` / ``xla``) and ``moe_rows_moved``, the last
+    step's held pairs (the rows a kernel moves; XLA's ops move them all)
+    over the buffers' rows, the count filled in where the notes are
+    written."""
+    passes, rows = pass_rows(capacity)
+    return {"moe_rows": rows_by,
+            "moe_rows_moved": "{moe_pairs_held}/%d" % (layers * passes * rows)}
+
+
 @jax.named_scope("moe")
 def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
                  top_k: int, first_expert: int, capacity: int,
-                 eps: float, cdt: jnp.dtype, route_by: Callable = route
+                 eps: float, cdt: jnp.dtype, route_by: Callable = route,
+                 rows_by: str = "xla"
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The held experts' part of ``MoE(RMSNorm(x))``: x [B, S, d] ->
     ([B, S, d], counts). ``lp['w_gate']`` [held, d, f] says how many experts
@@ -320,7 +368,9 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
     ``PASS_ROWS`` rows, each made again in the backward pass, so that the
     layer's memory is one pass's; pairs beyond them are counted and add
     nothing. ``route_by(xn, router, top_k)`` is the model's router
-    (``route``, with what the model binds of its keywords)."""
+    (``route``, with what the model binds of its keywords); ``rows_by`` is
+    ``moe_rows_by``'s word for what moves a pass's rows from and to their
+    positions."""
     shape = x.shape
     xn = rms_norm(x, lp["norm2"], eps).reshape(-1, shape[-1])
     n_tok = xn.shape[0]
@@ -336,8 +386,7 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
         sorted_key, jnp.arange(n_held + 1, dtype=key.dtype))).astype(
             jnp.int32)
     held = jnp.sum(load)
-    passes = -(-capacity // PASS_ROWS)
-    buffer_rows = -(-capacity // passes)
+    passes, buffer_rows = pass_rows(capacity)
     capacity = passes * buffer_rows
     spare = max(capacity - order.shape[0], 0)      # rows past every pair
     order = jnp.concatenate([order, jnp.zeros((spare,), order.dtype)])
@@ -345,9 +394,13 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
         [sorted_key, jnp.full((spare,), n_held, key.dtype)])
     pair_weight = top_w.reshape(-1)
     weights = {n: _operand(lp[n], cdt) for n in ("w_gate", "w_up", "w_down")}
+    by_kernel = rows_by == "kernel"
+    # (a pass's rows leave the kernel in the products' type where that is
+    # one the kernel writes)
+    taken = cdt if cdt.itemsize >= 2 else jnp.dtype(jnp.float32)
 
     @jax.checkpoint
-    def one_pass(out, start):
+    def one_pass(carry, start):
         rows = jax.lax.dynamic_slice(order, (start,), (buffer_rows,))
         valid = jax.lax.dynamic_slice(sorted_key, (start,),
                                       (buffer_rows,)) < n_held
@@ -358,7 +411,13 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
         sizes = jnp.diff(ends, prepend=0)
         sizes = sizes.at[-1].add(buffer_rows - ends[-1])
         tok = rows // top_k
-        xs = jnp.where(valid[:, None], jnp.take(xn, tok, axis=0), 0.0)
+        if by_kernel:       # the valid prefix's rows; the others zeros
+            out, xn_through = carry
+            xs, xn_through = pallas_moe_rows.gather(
+                jax.lax.stop_gradient(xn), xn_through, tok, ends, taken)
+        else:
+            out = carry
+            xs = jnp.where(valid[:, None], jnp.take(xn, tok, axis=0), 0.0)
         xs = _operand(xs, cdt)
 
         def grouped(a, w):
@@ -369,13 +428,19 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
             * grouped(xs, weights["w_up"])
         y = grouped(_operand(mid, cdt), weights["w_down"])      # [C, d]
         weight = jnp.where(valid, pair_weight[rows], 0.0)
+        if by_kernel:       # (weighted inside, the prefix's rows only)
+            return (pallas_moe_rows.combine(out, y, weight, tok, ends),
+                    xn_through), None
         return out.at[tok].add(jnp.where(valid[:, None], y, 0.0)
                                * weight[:, None]), None
 
-    # (zeros made of xn: across data replicas the carry varies as xn does)
+    # (zeros made of xn: across data replicas the carry varies as xn does;
+    # by the kernels xn rides along: its cotangent is summed in the carry's)
     out, _ = jax.lax.scan(
-        one_pass, xn * 0.0,
+        one_pass, (jnp.zeros_like(xn), xn) if by_kernel else xn * 0.0,
         jnp.arange(passes, dtype=jnp.int32) * buffer_rows)
+    if by_kernel:
+        out = out[0]
     counts = {"moe_pairs_held": held,
               "moe_pairs_over_buffer": jnp.maximum(held - capacity, 0),
               "moe_expert_load_max": jnp.max(load),
@@ -432,7 +497,9 @@ class SdarMoE(GraphModel):
         #: What the traced step is made of, said beside its counts on
         #: ``train.log_sync`` while tracing is on: ``attn_scores`` (``kernel``
         #: / ``xla``) and, of the kernel, ``attn_score_blocks`` (blocks of
-        #: the score matrix the forward pass computes / all of them, a head).
+        #: the score matrix the forward pass computes / all of them, a head);
+        #: ``moe_rows`` and ``moe_rows_moved`` (``moe_notes``). A note may
+        #: name a count of the step in braces.
         self.step_notes: Dict[str, str] = {}
 
     def _attn_notes(self, scores_by: str, seq: int, length: int
@@ -504,7 +571,12 @@ class SdarMoE(GraphModel):
         positions = jnp.arange(seq) % length
         scores_by = attn_scores_by(seq, cfg.attn_head_dim,
                                    one_device=data_axis is None)
-        self.step_notes = self._attn_notes(scores_by, seq, length)
+        rows_by = moe_rows_by(cfg.embedding_size, ids.size,
+                              cfg.moe_pair_capacity,
+                              one_device=data_axis is None)
+        self.step_notes = {
+            **self._attn_notes(scores_by, seq, length),
+            **moe_notes(rows_by, cfg.moe_pair_capacity, cfg.decoder_layers)}
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
 
@@ -523,7 +595,7 @@ class SdarMoE(GraphModel):
                 lp, h, top_k=cfg.moe_top_k,
                 first_expert=cfg.moe_first_expert,
                 capacity=cfg.moe_pair_capacity,
-                eps=cfg.rms_norm_eps, cdt=self.cdt)
+                eps=cfg.rms_norm_eps, cdt=self.cdt, rows_by=rows_by)
             return h + y, counts
 
         x, counts = jax.lax.scan(layer, x, params["layers"])

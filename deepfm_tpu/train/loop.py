@@ -2027,9 +2027,12 @@ class Trainer:
                                                      "steps_done")}
                             if ROW_COUNTS[0] in counts:
                                 counts[ROW_WRITEBACK] = self.row_writeback
-                            # what the model says its traced step is made of
-                            counts.update(
-                                getattr(self.model, "step_notes", {}))
+                            # what the model says its traced step is made
+                            # of (a note may name a count in braces)
+                            counts.update({
+                                key: note.format(**counts) for key, note
+                                in getattr(self.model, "step_notes",
+                                           {}).items()})
                             sync.add(**counts)
                     last_loss = loss
                     if guard is not None and not guard_active:

@@ -43,6 +43,12 @@ every query chunk) and, of the kernel, ``attn_score_blocks`` (visited /
 all, a head); the report prints them on its "block-masked attention" line
 (TUNING §5).
 
+Both decoders say how their expert layers' rows go to and from their
+positions: ``moe_rows`` (``kernel``: one copy a row over the pairs really
+held, ``ops/pallas_moe_rows``; ``xla``: ``take`` and scatter-add over every
+row of the buffer) and ``moe_rows_moved`` (the step's held pairs over the
+buffers' rows); the report prints them on its "expert layers' rows" line.
+
 Where the model scans a delta-rule recurrence (``--model kimi_linear``), each
 ``train.log_sync`` carries ``kda_scan`` (the algorithm and chunk length of
 the compiled step: ``chunk64/sub16``), ``mla_scores`` (``xla`` / ``kernel``)
@@ -256,6 +262,21 @@ def delta_rule_scan(events):
             "log_decay_min": min(lows) if lows else None}
 
 
+def expert_rows(events):
+    """How the expert layers' rows moved, off the ``train.log_sync`` spans
+    that say so: ``steps`` read, ``rows`` (``moe_rows``: ``kernel``, one copy
+    a row over the pairs held, or ``xla``, every row of the buffer) and the
+    mean ``held`` of ``buffer`` rows a step (``moe_rows_moved``); None when
+    no span has them (another model, or a trace that predates them)."""
+    seen = _log_syncs(events, "moe_rows")
+    if not seen:
+        return None
+    moved = [a["moe_rows_moved"].split("/") for a in seen]
+    return {"steps": len(seen), "rows": seen[-1]["moe_rows"],
+            "held": sum(int(h) for h, _ in moved) / len(moved),
+            "buffer": int(moved[-1][1])}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("trace", help="trace-<pid>.json or a merged trace file")
@@ -275,6 +296,7 @@ def main(argv=None):
     touched = row_updates(events)
     attn = attention_scores(events)
     scan = delta_rule_scan(events)
+    moved = expert_rows(events)
 
     if args.json:
         doc = {
@@ -289,6 +311,8 @@ def main(argv=None):
             doc["attention_scores"] = attn
         if scan is not None:
             doc["delta_rule_scan"] = scan
+        if moved is not None:
+            doc["expert_rows"] = moved
         print(json.dumps(doc, indent=2))
         return 0
 
@@ -331,6 +355,11 @@ def main(argv=None):
               "scores by %s, most negative chunk log-decay %s"
               % (scan["steps"], scan["scan"], scan["mla_scores"],
                  "not in this trace" if low is None else "%.4g" % low))
+    if moved is not None:
+        print("expert layers' rows over %d logged steps: moved by %s, %.0f "
+              "of %d buffer rows a step held a pair (%.1f%%)"
+              % (moved["steps"], moved["rows"], moved["held"],
+                 moved["buffer"], 100 * moved["held"] / moved["buffer"]))
     for st in slow or ():
         cover = ", ".join(f"{k} {v:.1f}" for k, v in st["cover_ms"].items()
                           if v > 0)

@@ -525,8 +525,11 @@ def test_compiled_step_carries_each_blocks_scope():
     assert {"embed", "kda", "kda_scan", "attn", "mlp", "moe", "head",
             "opt"} <= scopes
     assert not {"fm", "tower", "cross", "bottom"} & scopes
-    assert trainer.model.step_notes == {"kda_scan": "chunk64/sub16",
-                                        "mla_scores": "xla"}
+    layers = sum(ffn == "moe" for _, ffn in trainer.model.kinds)
+    assert trainer.model.step_notes == {
+        "kda_scan": "chunk64/sub16", "mla_scores": "xla", "moe_rows": "xla",
+        "moe_rows_moved": "{moe_pairs_held}/%d" % (
+            layers * trainer.cfg.moe_pair_capacity)}
 
 
 def test_fit_trains_from_tfrecord_shards(tmp_path):
@@ -558,3 +561,48 @@ def test_fit_trains_from_tfrecord_shards(tmp_path):
     assert np.isfinite(float(out["loss"]))
     assert int(seen[-1]["moe_pairs_held"]) > 0
     assert float(seen[-1][kimi_linear.DECAY_MIN]) < 0.0
+
+
+@pytest.mark.parametrize("pass_most", [20480, 32], ids=["one-pass",
+                                                        "two-passes"])
+def test_model_by_the_row_kernels_takes_the_same_step(monkeypatch, pass_most):
+    """The expert layers' rows taken and added by the row kernels
+    (``ops/pallas_moe_rows``, forced on through the Pallas interpreter at
+    rows of one 128-lane line) under this model's sigmoid router and shared
+    expert: loss, counts and every leaf's gradient against the XLA rows, in
+    one pass a layer and in two; the notes say which moved them."""
+    import functools
+    from deepfm_tpu.models import sdar_moe
+    from deepfm_tpu.ops import pallas_moe_rows
+    monkeypatch.setattr(sdar_moe, "PASS_ROWS", pass_most)
+    cfg = config(embedding_size=128, decoder_layers=2, attn_every=2,
+                 moe_pair_capacity=64)
+    tokens = jnp.asarray(sequences(B, 3))
+
+    def grads():
+        model = get_model(cfg)
+        params, state = model.init(jax.random.PRNGKey(0))
+
+        def loss(p):
+            per_seq, counts = model.per_example_loss(
+                p, state, {"hist_ids": tokens}, train=True, rng=None)
+            return jnp.mean(per_seq), counts
+        return model, jax.value_and_grad(loss, has_aux=True)(params)
+
+    model, ((want, want_counts), want_g) = grads()
+    assert model.step_notes["moe_rows"] == "xla"
+    for name in ("gather", "combine"):
+        monkeypatch.setattr(pallas_moe_rows, name, functools.partial(
+            getattr(pallas_moe_rows, name), interpret=True))
+    monkeypatch.setattr(pallas_moe_rows, "supported",
+                        lambda width, positions, rows, backend=None: True)
+    model, ((got, got_counts), got_g) = grads()
+    assert model.step_notes["moe_rows"] == "kernel"
+    assert model.step_notes["moe_rows_moved"] == "{moe_pairs_held}/64"
+    assert 0 < int(got_counts["moe_pairs_held"]) == int(
+        want_counts["moe_pairs_held"]) < 64
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_g),
+                            jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(g, w, atol=2e-5,
+                                   err_msg=jax.tree_util.keystr(path))

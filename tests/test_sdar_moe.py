@@ -522,7 +522,133 @@ def test_a_cpu_step_makes_its_scores_with_xla(seeded):
     model, params, _ = seeded
     jax.eval_shape(lambda p: model.hidden(
         p, jnp.zeros((B, 2 * L), jnp.int32)), params)
-    assert model.step_notes == {"attn_scores": "xla"}
+    assert model.step_notes == {
+        "attn_scores": "xla", "moe_rows": "xla",
+        "moe_rows_moved": "{moe_pairs_held}/%d" % (
+            model.cfg.decoder_layers * model.cfg.moe_pair_capacity)}
+
+
+@pytest.mark.parametrize("backend, width, positions, capacity, one_device, "
+                         "says", [
+    ("tpu", 2048, 16384, 32768, True, "kernel"),    # the SDAR cell's layer
+    ("tpu", 2304, 16384, 16384, True, "kernel"),    # the Kimi-Linear cell's
+    ("cpu", 2048, 16384, 32768, True, "xla"),
+    ("tpu", 2048, 16384, 32768, False, "xla"),      # across data replicas
+    ("tpu", 64, 16384, 32768, True, "xla"),         # no whole line a row
+    ("tpu", 2048, 16380, 32768, True, "xla"),       # positions in no tiles
+    ("tpu", 2048, 16384, 32760, True, "xla")])      # nor a pass's rows
+def test_the_row_kernels_are_taken_where_backend_shape_and_mesh_allow(
+        backend, width, positions, capacity, one_device, says):
+    assert sdar_moe.moe_rows_by(width, positions, capacity,
+                                one_device=one_device,
+                                backend=backend) == says
+    assert sdar_moe.pass_rows(32768) == (2, 16384)
+    assert sdar_moe.pass_rows(16384) == (1, 16384)
+
+
+def test_the_step_notes_say_how_the_expert_layers_rows_move():
+    notes = sdar_moe.moe_notes("kernel", 32768, 6)
+    assert notes == {"moe_rows": "kernel",
+                     "moe_rows_moved": "{moe_pairs_held}/196608"}
+    # the trainer fills the count in where it writes the notes
+    assert notes["moe_rows_moved"].format(moe_pairs_held=101_000,
+                                          other=3) == "101000/196608"
+    assert sdar_moe.moe_notes("xla", 20, 1)["moe_rows_moved"].endswith("/20")
+
+
+def _forced_row_kernels(monkeypatch):
+    """The expert layer's row kernels through the Pallas interpreter."""
+    import functools
+    from deepfm_tpu.ops import pallas_moe_rows
+    for name in ("gather", "combine"):
+        monkeypatch.setattr(pallas_moe_rows, name, functools.partial(
+            getattr(pallas_moe_rows, name), interpret=True))
+
+
+@pytest.mark.parametrize("capacity, pass_most, first, case", [
+    (128, 20480, 0, "one pass, spare rows"),
+    (128, 64, 0, "two passes, the second part spare"),
+    (192, 64, 0, "three passes, the last with no valid row"),
+    (32, 20480, 0, "pairs over the buffer"),
+    (64, 20480, 20, "no expert held gets a pair")])
+def test_expert_layer_by_the_row_kernels_matches_the_xla_rows(
+        monkeypatch, capacity, pass_most, first, case):
+    """``expert_layer`` with the rows taken and added by the kernels (forced
+    on, through the interpreter) against ``jnp.take`` / ``.at[].add``: the
+    output, the counts, and the gradient of every leaf and of the input."""
+    _forced_row_kernels(monkeypatch)
+    monkeypatch.setattr(sdar_moe, "PASS_ROWS", pass_most)
+    d, f, experts, held, top_k = 128, 32, 16, 4, 4
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 8))
+    lp = {"norm2": 1 + 0.1 * jax.random.normal(next(keys), (d,)),
+          "router": 0.5 * jax.random.normal(next(keys), (d, experts)),
+          "w_gate": 0.2 * jax.random.normal(next(keys), (held, d, f)),
+          "w_up": 0.2 * jax.random.normal(next(keys), (held, d, f)),
+          "w_down": 0.2 * jax.random.normal(next(keys), (held, f, d))}
+    x = jax.random.normal(next(keys), (2, 32, d))
+    w = jax.random.normal(next(keys), x.shape)
+
+    def loss(lp, x, rows_by):
+        y, counts = sdar_moe.expert_layer(
+            lp, x, top_k=top_k, first_expert=first, capacity=capacity,
+            eps=1e-6, cdt=jnp.dtype("float32"), rows_by=rows_by)
+        return jnp.sum(y * w), (y, counts)
+
+    (_, (want, counts)), want_g = jax.value_and_grad(
+        loss, (0, 1), has_aux=True)(lp, x, "xla")
+    (_, (got, got_counts)), got_g = jax.value_and_grad(
+        loss, (0, 1), has_aux=True)(lp, x, "kernel")
+    assert {k: int(v) for k, v in got_counts.items()} == {
+        k: int(v) for k, v in counts.items()}
+    held_pairs = int(counts["moe_pairs_held"])
+    if "no expert" in case:
+        assert held_pairs == 0
+    elif "over" in case:
+        assert int(counts["moe_pairs_over_buffer"]) > 0
+    else:       # the buffer has spare rows, and a whole pass of them in (3)
+        assert 0 < held_pairs < capacity - ("three" in case) * pass_most
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    for name in lp:
+        np.testing.assert_allclose(got_g[0][name], want_g[0][name],
+                                   atol=1e-4, err_msg=name)
+    np.testing.assert_allclose(got_g[1], want_g[1], atol=1e-4)
+
+
+def test_model_by_the_row_kernels_takes_the_same_step(monkeypatch):
+    """The whole model with the row kernels forced on (rows of one 128-lane
+    line): loss and every parameter's gradient against the XLA rows, and the
+    notes say ``kernel``."""
+    from deepfm_tpu.ops import pallas_moe_rows
+    cfg = config(embedding_size=128, moe_pair_capacity=B * 2 * L * 2)
+    tokens = jnp.asarray(sequences(B, 3))
+
+    def grads():
+        model = get_model(cfg)
+        params, state = model.init(jax.random.PRNGKey(0))
+
+        def loss(p):
+            per_seq, counts = model.per_example_loss(
+                p, state, {"hist_ids": tokens}, train=True,
+                rng=jax.random.PRNGKey(2))
+            return jnp.mean(per_seq), counts
+        return model, jax.value_and_grad(loss, has_aux=True)(params)
+
+    model, ((want, want_counts), want_g) = grads()
+    assert model.step_notes["moe_rows"] == "xla"
+    _forced_row_kernels(monkeypatch)
+    monkeypatch.setattr(pallas_moe_rows, "supported",
+                        lambda width, positions, rows, backend=None: True)
+    model, ((got, got_counts), got_g) = grads()
+    assert model.step_notes["moe_rows"] == "kernel"
+    assert model.step_notes["moe_rows_moved"] == "{moe_pairs_held}/%d" % (
+        cfg.decoder_layers * cfg.moe_pair_capacity)
+    assert int(got_counts["moe_pairs_held"]) == int(
+        want_counts["moe_pairs_held"]) > 0
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_g),
+                            jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(g, w, atol=2e-5,
+                                   err_msg=jax.tree_util.keystr(path))
 
 
 def test_attention_by_the_kernel_matches_attention_by_xla(monkeypatch):

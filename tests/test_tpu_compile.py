@@ -163,8 +163,17 @@ def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
     cfg = Config(**SDAR_FLAGS)
     tr = Trainer(cfg, mesh_info=mesh_lib.build_mesh(cfg, devices=[v5e]))
     scopes = profiling.hlo_op_scopes(tr.step_hlo_text(device=v5e))
-    assert tr.model.step_notes == {"attn_scores": "kernel",
-                                   "attn_score_blocks": "3/4"}
+    assert tr.model.step_notes == {
+        "attn_scores": "kernel", "attn_score_blocks": "3/4",
+        "moe_rows": "kernel", "moe_rows_moved": "{moe_pairs_held}/8192"}
+    # the expert layer's rows move by the row kernels (rows of two lines,
+    # 1,024 positions, one pass of 4,096 rows), forward and backward, all
+    # charged to ``moe``
+    rows = {name: scope for name, scope in scopes.items()
+            if name.startswith(("moe_take_rows", "moe_add_rows"))}
+    assert {name.split(".")[0] for name in rows} == {
+        "moe_take_rows", "moe_add_rows"} and len(rows) == 5, rows
+    assert set(rows.values()) == {"moe"}, rows
     kernels = {name: scope for name, scope in scopes.items()
                if name.startswith("splash_mqa_")}
     assert {name.split(".")[0] for name in kernels} == {
@@ -189,7 +198,8 @@ def test_decoder_step_at_head_dim_64_keeps_the_xla_scores(
     cfg = Config(**{**SDAR_FLAGS, "attn_head_dim": 64})
     tr = Trainer(cfg, mesh_info=mesh_lib.build_mesh(cfg, devices=[v5e]))
     text = tr.step_hlo_text(device=v5e)
-    assert tr.model.step_notes == {"attn_scores": "xla"}
+    assert tr.model.step_notes["attn_scores"] == "xla"
+    assert "attn_score_blocks" not in tr.model.step_notes
     assert "splash_mqa" not in text
 
 
@@ -250,3 +260,52 @@ def test_hybrid_decoder_step_at_the_cells_shapes_fits_and_names_its_layers(
     assert 7.8e9 < memory.argument_size_in_bytes < 8.0e9
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 15.5e9
+    # its expert layers' rows (2,304 wide: 18 lines) move by the row kernels
+    assert tr.model.step_notes["moe_rows"] == "kernel"
+    by_op = profiling.hlo_op_scopes(text)
+    assert {scope for name, scope in by_op.items() if name.startswith(
+        ("moe_take_rows", "moe_add_rows"))} == {"moe"}
+
+
+@pytest.mark.parametrize("width", [2048, 2304])
+def test_row_kernels_compile_at_the_cells_shapes(v5e, no_compile_cache,
+                                                 width):
+    """``ops/pallas_moe_rows`` at a pass of 16,384 rows over 16,384 positions
+    of the two decoder cells' widths, forward and backward: Mosaic takes the
+    one-row strided copies (a ``[T/8, W/128, 8, 1, 128]`` view) and XLA makes
+    that view and its way back without a copy of the array: nothing
+    ``[16384, W]`` is made beside the results."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from deepfm_tpu.ops import pallas_moe_rows as pmr
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=SingleDeviceSharding(v5e))
+
+    rows = 16384
+
+    def loss(x, y, carry, tok, ends):
+        xs, through = pmr.gather(jax.lax.stop_gradient(x), x, tok, ends,
+                                 jnp.bfloat16)
+        out = pmr.combine(carry, xs.astype(jnp.float32) * y, y[:, 0], tok,
+                          ends)
+        return jnp.sum(out) + jnp.sum(through)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec((rows, width), jnp.float32), spec((rows, width), jnp.float32),
+        spec((rows, width), jnp.float32), spec((rows,), jnp.int32),
+        spec((16,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    for name in ("moe_take_rows", "moe_add_rows"):
+        assert name in text, name
+    # (one copy of an array: this function's own carry, an argument it may
+    # not spoil)
+    copies = [line for line in text.splitlines() if " copy(" in line
+              and f"[{rows},{width}]" in line.split(" copy(")[0]]
+    assert len(copies) == 1 and " copy(%carry" in copies[0], copies
+    assert " transpose(" not in text
+    # xs and its product, the rows' cotangents: a few arrays, no more
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * rows * width * 4

@@ -377,19 +377,32 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
     scores are made of on the span that reads the loss back: on this backend
     the chunked XLA path, and the report's line with it."""
     tr, events = _sdar_fit()
-    assert tr.model.step_notes == {"attn_scores": "xla"}
+    assert tr.model.step_notes == {
+        "attn_scores": "xla", "moe_rows": "xla",
+        "moe_rows_moved": "{moe_pairs_held}/64"}
     syncs = [e["args"] for e in events if e["name"] == "train.log_sync"]
     assert [a["attn_scores"] for a in syncs] == ["xla"] * 2
     assert all("attn_score_blocks" not in a for a in syncs)
+    # how the expert layer's rows moved, the step's held pairs filled in
+    assert [a["moe_rows"] for a in syncs] == ["xla"] * 2
+    assert [a["moe_rows_moved"] for a in syncs] == [
+        "%d/64" % a["moe_pairs_held"] for a in syncs]
+    assert all(0 < a["moe_pairs_held"] <= 64 for a in syncs)
     path = str(tmp_path / "trace.json")
     trace_lib.export(path)
     report = _report()
     loaded, _ = report._load(path)
     assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla"}
     assert report.row_updates(loaded) is None
+    moved = report.expert_rows(loaded)
+    assert moved == {"steps": 2, "rows": "xla", "buffer": 64,
+                     "held": sum(a["moe_pairs_held"] for a in syncs) / 2}
     assert report.main([path]) == 0
+    out = capsys.readouterr().out
     assert ("block-masked attention over 2 logged steps: scores by xla, "
-            "every score computed") in capsys.readouterr().out
+            "every score computed") in out
+    assert ("expert layers' rows over 2 logged steps: moved by xla, %.0f of "
+            "64 buffer rows a step held a pair" % moved["held"]) in out
 
 
 def test_report_prints_the_kernels_block_count(tmp_path, capsys):
@@ -405,6 +418,13 @@ def test_report_prints_the_kernels_block_count(tmp_path, capsys):
     # a ranker's trace, or one that predates the attribute, has no line
     assert report.attention_scores(
         [{"name": "train.log_sync", "ph": "X", "args": {"step": 2}}]) is None
+    assert report.expert_rows(events) is None
+    # what a TPU's trace says of the expert layers' rows
+    for e, held in zip(events, (97_000, 101_000, 102_000)):
+        e["args"].update(moe_rows="kernel",
+                         moe_rows_moved="%d/196608" % held)
+    assert report.expert_rows(events) == {
+        "steps": 3, "rows": "kernel", "held": 100_000.0, "buffer": 196608}
     path = tmp_path / "trace.json"
     path.write_text(__import__("json").dumps({"traceEvents": events}))
     assert report.main([str(path)]) == 0
