@@ -12,14 +12,22 @@ from __future__ import annotations
 import json
 import sys
 
-import jax
+from .obs import startup
 
-from .config import parse_args
-from .parallel import bootstrap
-from .train import tasks
-from .utils import compile_cache
-from .utils import logging as ulog
-from .utils import preempt as preempt_lib
+# Most of a short run's set-up is the imports below: stamped here, where the
+# launcher pays them, so that the start-up line covers its real path.
+with startup.importing("jax"):
+    import jax
+
+from .config import parse_args  # noqa: E402
+from .parallel import bootstrap  # noqa: E402
+
+with startup.importing("deepfm_tpu.train"):   # the loop, the models; tasks
+    from .train import tasks
+
+from .utils import compile_cache  # noqa: E402
+from .utils import logging as ulog  # noqa: E402
+from .utils import preempt as preempt_lib  # noqa: E402
 
 
 def device_report() -> dict:
@@ -40,6 +48,7 @@ def main(argv=None) -> int:
     # jax.process_index(), which would initialize the XLA backend and break
     # a later jax.distributed.initialize() (it must run first).
     bootstrap.initialize(cfg)
+    bootstrap.start_backend()
     ulog.info("config: " + json.dumps(cfg.to_dict(), sort_keys=True))
     try:
         result = tasks.run(cfg)

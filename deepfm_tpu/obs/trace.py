@@ -32,6 +32,12 @@ Correlation ids: :func:`new_trace_id` mints process-unique int ids
 (``pid << 20 | counter``) that ride request paths as plain ints — they
 work even when tracing is off, so flag-off call sites need no branches.
 
+What the process did before ``configure()`` — imports, the backend's
+start, building the trainer, the first dispatch's compilation — is kept by
+``obs.startup`` whether tracing is on or off; :meth:`Tracer.events` merges
+that record in, so an export holds it on the same timeline whichever tracer
+was installed when the phases ran.
+
 Child processes inherit the configuration through ``DEEPFM_TPU_TRACE*``
 env vars (set by :func:`configure`, read by :func:`configure_from_env`);
 each process exports its own ``trace-<pid>.json`` and :func:`merge`
@@ -48,6 +54,8 @@ import os
 import threading
 import time
 from typing import Deque, Dict, Iterable, List, Optional, Tuple, Union
+
+from . import startup
 
 MODES = ("off", "ring", "full")
 DEFAULT_CAPACITY = 65536
@@ -180,6 +188,18 @@ class Tracer:
             ev["args"] = attrs
         self._emit(ev)
 
+    def complete(self, name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+        """A complete ("X") event whose times someone else took (JAX times
+        its own compilations), on the calling thread."""
+        if self.mode == "off":
+            return
+        ev = {"name": name, "ph": "X", "ts": t0_ns / 1e3,
+              "dur": (t1_ns - t0_ns) / 1e3, "pid": os.getpid(),
+              "tid": threading.get_ident()}
+        if attrs:
+            ev["args"] = attrs
+        self._emit(ev)
+
     def instant(self, name: str, **attrs) -> None:
         if self.mode == "off":
             return
@@ -192,11 +212,15 @@ class Tracer:
 
     def events(self) -> List[Dict]:
         """Chronological snapshot: what the buffer holds (after a ring's
-        wraparound, the newest ``capacity`` events) and the collector's
-        ``host.gc`` events, by start time."""
+        wraparound, the newest ``capacity`` events), the collector's
+        ``host.gc`` events and, while tracing is on, the start-up record
+        (``obs.startup``: stored there once, whichever tracer was installed
+        when its phases ran), by start time."""
         with self._lock:
             out = list(self._buf)
         out.extend(self._gc_buf)
+        if self.mode != "off":
+            out.extend(startup.events())
         out.sort(key=lambda e: e["ts"])
         return out
 
@@ -272,11 +296,12 @@ def configure_from_env() -> None:
 
 
 def reset() -> None:
-    """Back to off + empty buffers (tests)."""
+    """Back to off + empty buffers, the start-up record's too (tests)."""
     global _tracer, _trace_dir
     _tracer = Tracer()
     _trace_dir = ""
     _hook_gc(False)
+    startup.reset()
     for k in (ENV_MODE, ENV_DIR, ENV_BUFFER):
         os.environ.pop(k, None)
 
@@ -297,12 +322,18 @@ def end(handle: Optional[Tuple[str, int]], **attrs) -> None:
     _tracer.end(handle, **attrs)
 
 
+def complete(name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+    _tracer.complete(name, t0_ns, t1_ns, **attrs)
+
+
 def instant(name: str, **attrs) -> None:
     _tracer.instant(name, **attrs)
 
 
 def dropped() -> int:
-    return _tracer.dropped
+    """Events lost to the ring's wraparound and phases the start-up record
+    had no room for."""
+    return _tracer.dropped + startup.dropped()
 
 
 def new_trace_id() -> int:
@@ -326,7 +357,7 @@ def export(path: Optional[str] = None) -> Optional[str]:
     events.extend(_tracer.events())
     doc = {"traceEvents": events,
            "otherData": {"pid": pid, "mode": _tracer.mode,
-                         "dropped_spans": _tracer.dropped}}
+                         "dropped_spans": dropped()}}
     tmp = f"{path}.tmp-{pid}"
     with open(tmp, "w") as f:
         json.dump(doc, f)

@@ -22,8 +22,10 @@ from __future__ import annotations
 import jax
 
 from ..config import Config
+from ..obs import startup
 
 _INITIALIZED = False
+_BACKEND_ASKED = False
 
 
 def initialize(cfg: Config) -> None:
@@ -31,18 +33,19 @@ def initialize(cfg: Config) -> None:
     global _INITIALIZED
     if _INITIALIZED or cfg.dist_mode == 0:
         return
-    if cfg.dist_mode == 1:
-        if not cfg.coordinator_address:
-            raise ValueError("dist_mode=1 requires coordinator_address")
-        jax.distributed.initialize(
-            coordinator_address=cfg.coordinator_address,
-            num_processes=cfg.num_processes,
-            process_id=cfg.process_id,
-        )
-    elif cfg.dist_mode == 2:
-        jax.distributed.initialize()
-    else:
-        raise ValueError(f"unknown dist_mode {cfg.dist_mode}")
+    with startup.phase("setup.distributed", dist_mode=cfg.dist_mode):
+        if cfg.dist_mode == 1:
+            if not cfg.coordinator_address:
+                raise ValueError("dist_mode=1 requires coordinator_address")
+            jax.distributed.initialize(
+                coordinator_address=cfg.coordinator_address,
+                num_processes=cfg.num_processes,
+                process_id=cfg.process_id,
+            )
+        elif cfg.dist_mode == 2:
+            jax.distributed.initialize()
+        else:
+            raise ValueError(f"unknown dist_mode {cfg.dist_mode}")
     _INITIALIZED = True
     if cfg.dist_mode == 1 and jax.process_count() != cfg.num_processes:
         # The coordinator only rendezvouses processes; which devices form
@@ -56,6 +59,20 @@ def initialize(cfg: Config) -> None:
             "one device topology. On one multi-chip host run ONE process "
             "over all its chips (--mesh_data/--mesh_model); pinning one chip "
             "per worker (deepfm_tpu.fanout) does not join them into a mesh.")
+
+
+def start_backend() -> None:
+    """Ask JAX for its devices, as ``setup.backend``: the XLA backend starts
+    at the first such question (after ``initialize``, which must come
+    before), so the phase holds the start where the program is the first to
+    ask — the launcher, before its first log line — and reads 0.0 where a
+    caller asked already. Once a process."""
+    global _BACKEND_ASKED
+    if _BACKEND_ASKED:
+        return
+    _BACKEND_ASKED = True
+    with startup.phase("setup.backend") as ph:
+        ph.add(devices=jax.device_count(), platform=jax.default_backend())
 
 
 def process_index() -> int:

@@ -29,12 +29,16 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from ..obs import startup
+
+# Over a second on the chip's host (PERF.md §5): stamped by name.
+with startup.importing("optax"):
+    import optax
+
 from ..config import Config
-from ..models import get_model
 from ..obs import trace as trace_lib
 from ..ops import embedding as emb_ops
 from ..ops import pallas_embedding as pemb
@@ -46,6 +50,9 @@ from . import guard as guard_lib
 from . import metrics as metrics_lib
 from . import optimizers as opt_lib
 from .state import TrainState
+
+with startup.importing("deepfm_tpu.models"):   # the zoo: every model's module
+    from ..models import get_model
 
 
 # Stand-in device memory for the CPU backend (which reports none): a test
@@ -192,6 +199,15 @@ class Trainer:
     """Builds and runs the compiled train/eval/predict step functions."""
 
     def __init__(self, cfg: Config, mesh_info: Optional[mesh_lib.MeshInfo] = None):
+        # JAX's compile timings become ``compile.*`` spans from the first
+        # program this trainer traces (the entry points' compile-cache
+        # set-up registered them already; a caller that built none has not).
+        startup.listen_to_jax()
+        with startup.phase("setup.trainer", model=cfg.model):
+            self._build(cfg, mesh_info)
+
+    def _build(self, cfg: Config,
+               mesh_info: Optional[mesh_lib.MeshInfo]) -> None:
         self.cfg = cfg
         self.model = get_model(cfg)
         # Multi-task contract: the model emits [B, T] logits and owns the
@@ -1928,6 +1944,10 @@ class Trainer:
         rollback raises :class:`guard_lib.RollbackSignal` for the task
         driver to restore the last checkpoint.
         """
+        # Start-up's last two phases, in the fit that makes the process's
+        # first dispatch (None in every later one): the first superbatch in
+        # hand, then that dispatch enqueued.
+        boot = startup.first_fit()
         cfg = self.cfg
         k = max(cfg.steps_per_loop, 1)
         world = jax.process_count() if self.mesh_info.mesh is not None else 1
@@ -1967,6 +1987,8 @@ class Trainer:
         comm_applies = 0
         try:
             for dev_batch, steps_done, local_ex in staged_iter:
+                if boot is not None:
+                    boot.batch_in_hand()
                 if self._tier is not None:
                     # Install this dispatch's fetched cold rows BEFORE the
                     # guard's prev_state snapshot: a skipped dispatch then
@@ -1984,6 +2006,10 @@ class Trainer:
                         state, m = self.train_step(state, dev_batch)
                     else:
                         state, m = self.multi_step(state, dev_batch)
+                if boot is not None:
+                    # where set-up went, tracing on or off (TUNING §17)
+                    ulog.info(boot.dispatched(steps_done))
+                    boot = None
                 # Slot fence + comms accounting BEFORE the guard verdict: a
                 # skipped dispatch still occupied its staging slot and its
                 # collectives still crossed the fabric.

@@ -4,9 +4,13 @@ from __future__ import annotations
 
 from typing import Any
 
-import flax.struct
 import jax
 import jax.numpy as jnp
+
+from ..obs import startup
+
+with startup.importing("flax"):   # half a second on the chip's host
+    import flax.struct
 
 
 @flax.struct.dataclass

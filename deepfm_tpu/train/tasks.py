@@ -25,6 +25,8 @@ import time
 import warnings
 from typing import Dict, List, Optional, Tuple
 
+_IMPORT_T0_NS = time.time_ns()   # for startup.imported(), below the imports
+
 import jax
 import numpy as np
 
@@ -35,6 +37,7 @@ from ..data import pipeline as pipe_lib
 from ..data import sharding as shard_lib
 from ..data import stream as stream_lib
 from ..obs import metrics as obs_metrics
+from ..obs import startup
 from ..obs import trace as obs_trace
 from ..obs.tensorboard import TensorBoardWriter as _TensorBoardWriter
 from ..parallel import bootstrap
@@ -50,6 +53,11 @@ from . import metrics as metrics_lib
 from . import publish as publish_lib
 from .loop import Trainer, pad_batch
 from .state import TrainState
+
+# This module's own imports (Orbax above all), for whoever imports it by
+# name: the launcher stamps ``deepfm_tpu.train`` around this, the benchmark's
+# driver imports it on a thread of its own and stamps nothing.
+startup.imported(__name__, _IMPORT_T0_NS)
 
 
 def resolve_files(directory: str, prefix: str) -> List[str]:
@@ -187,33 +195,34 @@ def make_pipeline(cfg: Config, files: List[str], *, epochs: int = 1,
                   drop_remainder: Optional[bool] = None,
                   epoch_offset: int = 0,
                   skip_batches: int = 0) -> pipe_lib.CtrPipeline:
-    return pipe_lib.CtrPipeline(
-        files,
-        decoded_cache=cfg.decoded_cache,
-        decoded_cache_dir=_decoded_cache_dir(cfg),
-        epoch_offset=epoch_offset,
-        skip_batches=skip_batches,
-        field_size=cfg.field_size,
-        batch_size=_local_batch_size(cfg),
-        num_epochs=epochs,
-        shuffle=shuffle,
-        shuffle_files=shuffle and cfg.shuffle_files,
-        shuffle_buffer=cfg.shuffle_buffer,
-        drop_remainder=cfg.drop_remainder if drop_remainder is None else drop_remainder,
-        seed=cfg.seed,
-        shard=_shard_spec(cfg, files) if sharded else None,
-        prefetch_batches=cfg.prefetch_batches,
-        use_native_decoder=cfg.use_native_decoder,
-        native_assembly=cfg.native_assembly,
-        reader_threads=cfg.reader_threads,
-        input_workers=cfg.input_workers,
-        stall_timeout_s=cfg.dispatch_timeout_s,
-        verify_crc=cfg.verify_crc,
-        num_labels=cfg.num_tasks,
-        history=cfg.history_max_len > 0,
-        history_max_len=max(1, cfg.history_max_len),
-        **_fault_tolerance_kwargs(cfg),
-    )
+    with startup.phase("setup.pipeline", files=len(files)):
+        return pipe_lib.CtrPipeline(
+            files,
+            decoded_cache=cfg.decoded_cache,
+            decoded_cache_dir=_decoded_cache_dir(cfg),
+            epoch_offset=epoch_offset,
+            skip_batches=skip_batches,
+            field_size=cfg.field_size,
+            batch_size=_local_batch_size(cfg),
+            num_epochs=epochs,
+            shuffle=shuffle,
+            shuffle_files=shuffle and cfg.shuffle_files,
+            shuffle_buffer=cfg.shuffle_buffer,
+            drop_remainder=cfg.drop_remainder if drop_remainder is None else drop_remainder,
+            seed=cfg.seed,
+            shard=_shard_spec(cfg, files) if sharded else None,
+            prefetch_batches=cfg.prefetch_batches,
+            use_native_decoder=cfg.use_native_decoder,
+            native_assembly=cfg.native_assembly,
+            reader_threads=cfg.reader_threads,
+            input_workers=cfg.input_workers,
+            stall_timeout_s=cfg.dispatch_timeout_s,
+            verify_crc=cfg.verify_crc,
+            num_labels=cfg.num_tasks,
+            history=cfg.history_max_len > 0,
+            history_max_len=max(1, cfg.history_max_len),
+            **_fault_tolerance_kwargs(cfg),
+        )
 
 
 def _eval_pipeline(cfg: Config, va_files: List[str]) -> pipe_lib.CtrPipeline:
@@ -349,38 +358,41 @@ def _restore_or_init(trainer: Trainer, cfg: Config, require: bool,
     hot cache happens after the restore — the restored Adam moments seed
     the cold tiers, making the round-trip bit-exact in both directions.
     """
-    tier = getattr(trainer, "_tier", None)
-    state = (trainer.init_state(tiered=False) if tier is not None
-             else trainer.init_state())
+    with startup.phase("setup.state", source="init") as ph:
+        tier = getattr(trainer, "_tier", None)
+        state = (trainer.init_state(tiered=False) if tier is not None
+                 else trainer.init_state())
 
-    def _adopted(s: TrainState) -> TrainState:
-        return tier.adopt(s) if tier is not None else s
+        def _adopted(s: TrainState) -> TrainState:
+            return tier.adopt(s) if tier is not None else s
 
-    if not cfg.model_dir:
-        if require:
-            raise FileNotFoundError(
-                f"task '{cfg.task_type}' requires model_dir")
-        return _adopted(state)
-    if require and not fileio.isdir(cfg.model_dir):
-        raise FileNotFoundError(
-            f"task '{cfg.task_type}' needs a checkpoint in model_dir="
-            f"{cfg.model_dir!r}")
-    own = mgr is None
-    if own:
-        mgr = ckpt_lib.CheckpointManager(
-            cfg.model_dir, max_to_keep=cfg.keep_checkpoint_max,
-            retry_policy=retry_lib.policy_from_config(cfg))
-    try:
-        if mgr.latest_step() is not None:
-            state = mgr.restore(state)
-        elif require:
+        if not cfg.model_dir:
+            if require:
+                raise FileNotFoundError(
+                    f"task '{cfg.task_type}' requires model_dir")
+            return _adopted(state)
+        if require and not fileio.isdir(cfg.model_dir):
             raise FileNotFoundError(
                 f"task '{cfg.task_type}' needs a checkpoint in model_dir="
                 f"{cfg.model_dir!r}")
-    finally:
+        own = mgr is None
         if own:
-            mgr.close()
-    return _adopted(state)
+            mgr = ckpt_lib.CheckpointManager(
+                cfg.model_dir, max_to_keep=cfg.keep_checkpoint_max,
+                retry_policy=retry_lib.policy_from_config(cfg))
+        try:
+            latest = mgr.latest_step()
+            if latest is not None:
+                state = mgr.restore(state)
+                ph.add(source="checkpoint", restored_step=int(latest))
+            elif require:
+                raise FileNotFoundError(
+                    f"task '{cfg.task_type}' needs a checkpoint in model_dir="
+                    f"{cfg.model_dir!r}")
+        finally:
+            if own:
+                mgr.close()
+        return _adopted(state)
 
 
 def _ckpt_state(trainer: Trainer, state: TrainState) -> TrainState:
@@ -402,6 +414,7 @@ def _servable_state(trainer: Trainer, state: TrainState) -> TrainState:
 def run(cfg: Config) -> Dict[str, float]:
     """Entry point: bootstrap, dispatch on task_type, return result metrics."""
     bootstrap.initialize(cfg)
+    bootstrap.start_backend()
     # Config-driven retry for every fileio op (glob/stat/open + the resume
     # sidecar reads) — not just the pipelines' own streams.
     fileio.set_retry_policy(retry_lib.policy_from_config(cfg))

@@ -17,11 +17,15 @@ import threading
 from typing import Any, Callable, Optional
 
 import jax
-import orbax.checkpoint as ocp
 
-from ..data import fileio
-from . import logging as ulog
-from . import retry as retry_lib
+from ..obs import startup
+
+with startup.importing("orbax.checkpoint"):   # Orbax and its cloud clients
+    import orbax.checkpoint as ocp
+
+from ..data import fileio  # noqa: E402
+from . import logging as ulog  # noqa: E402
+from . import retry as retry_lib  # noqa: E402
 
 
 # Orbax (0.11) numbers each save from a process-wide counter and reads the

@@ -21,6 +21,8 @@ from __future__ import annotations
 
 import os
 
+from ..obs import startup
+
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -36,9 +38,13 @@ def configure() -> str:
     """Point JAX at the persistent cache; returns the directory in effect.
 
     Must run before the process's first compile: JAX decides once, at the
-    first compilation, whether a cache is in use."""
-    import jax  # noqa: PLC0415 (entry points call this before touching jax)
+    first compilation, whether a cache is in use. From here on JAX's own
+    compile timings are ``compile.*`` spans (``obs.startup``): whether a
+    program was compiled or fetched is read there."""
+    with startup.importing("jax"):
+        import jax  # noqa: PLC0415 (entry points call this before touching jax)
 
+    startup.listen_to_jax()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     from_env = os.environ.get(ENV_VAR, "")
     if from_env:

@@ -26,13 +26,17 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-import orbax.checkpoint as ocp
 from jax import export as jax_export
 
 from ..config import Config
 from ..data import fileio
-from . import checkpoint as ckpt_lib
+from ..obs import startup
 from . import logging as ulog
+
+with startup.importing("orbax.checkpoint"):   # whichever of the two is first
+    import orbax.checkpoint as ocp
+
+from . import checkpoint as ckpt_lib  # noqa: E402
 
 _SERVING_FILE = "serving_fn.stablehlo"
 _PARAMS_DIR = "params.ckpt"

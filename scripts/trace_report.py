@@ -23,8 +23,19 @@ input thread; ``input.pool_fill`` / ``input.pool_drain`` / ``input.emit`` —
 the input thread reading+framing, permuting+decoding, slicing; ``host.gc`` —
 a collection, which stops every thread; ``train.log_sync`` — the loss read
 back at the log cadence; ``stage.transfer`` — the host->device copy; and
-``stage.wait`` itself, the part the device was simply busy). TUNING §17
-lists every span.
+``stage.wait`` itself, the part the device was simply busy; and
+``compile.backend`` — a program compiled or fetched in the middle of the run,
+named by its ``fun_name`` under the stall). TUNING §17 lists every span.
+
+Where the trace holds the process's start-up record (``obs.startup``: the
+``setup.*`` phases and JAX's ``compile.*`` timings up to the first dispatch,
+kept whether or not tracing was on when they ran), the report has a
+"start-up" section: the launcher's start-up line, each phase with its
+inclusive and self time (imports nest by containment), and every compiled
+function with its tracing, lowering and backend seconds and whether the
+persistent cache had it. Cold against warm is ``cache=miss`` against ``hit``
+and the ``backend`` column; a kernel's cost in every warm start is its
+function's ``trace`` + ``lower``, which no cache saves.
 
 Where the train step updates its tables on the rows the batch touched
 (Adagrad without L2: ``Trainer._row_local_eligible``), each
@@ -64,7 +75,12 @@ Usage:
 import argparse
 import collections
 import json
+import os
 import sys
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from deepfm_tpu.obs import startup as startup_lib  # noqa: E402  (stdlib-only)
 
 
 def _pct(sorted_vals, q):
@@ -83,34 +99,40 @@ def _load(path):
     return doc.get("traceEvents", []), doc.get("otherData", {})
 
 
-def _self_times(events):
-    """-> {name: [(dur, self)]} for X events, nesting per (pid, tid).
+def _self_times_by_event(events):
+    """-> {id(event): self microseconds} for the X events among ``events``.
 
     Within one thread, spans nest by interval containment (a span's
     children start after it and end before it). Sorting by (ts, -dur)
     visits parents before their children; a stack of open spans then
     attributes each child's duration against its direct parent's self
     time."""
+    out = {}
     per_thread = collections.defaultdict(list)
     for ev in events:
         if ev.get("ph") == "X":
             per_thread[(ev.get("pid"), ev.get("tid"))].append(ev)
-    out = collections.defaultdict(list)
     for evs in per_thread.values():
         evs.sort(key=lambda e: (float(e["ts"]), -float(e.get("dur", 0.0))))
-        stack = []  # [name, end_ts, self_us]
-        def close_until(ts):
-            while stack and stack[-1][1] <= ts:
-                name, _, self_us = stack.pop()
-                out[name].append(self_us)
+        stack = []  # [event, end_ts]
         for ev in evs:
-            ts = float(ev["ts"])
-            dur = float(ev.get("dur", 0.0))
-            close_until(ts)
+            ts, dur = float(ev["ts"]), float(ev.get("dur", 0.0))
+            while stack and stack[-1][1] <= ts:
+                stack.pop()
             if stack:
-                stack[-1][2] -= dur  # child time is not parent self time
-            stack.append([ev["name"], ts + dur, dur])
-        close_until(float("inf"))
+                out[id(stack[-1][0])] -= dur  # child time is not parent self
+            out[id(ev)] = dur
+            stack.append([ev, ts + dur])
+    return out
+
+
+def _self_times(events):
+    """-> {name: [self microseconds]} for X events, nesting per (pid, tid)."""
+    by_event = _self_times_by_event(events)
+    out = collections.defaultdict(list)
+    for ev in events:
+        if id(ev) in by_event:
+            out[ev["name"]].append(by_event[id(ev)])
     return out
 
 
@@ -166,7 +188,7 @@ def summarize(events):
 #: Spans that can hold a dispatch back, in the order they are reported.
 STALL_SPANS = ("stage.input_wait", "input.pool_fill", "input.pool_drain",
                "input.emit", "host.gc", "train.log_sync", "stage.transfer",
-               "stage.wait")
+               "stage.wait", "compile.backend")
 
 
 def stalls(events, threshold_ms):
@@ -187,15 +209,92 @@ def stalls(events, threshold_ms):
             if b - a <= threshold_ms * 1e3:
                 continue
             cover = dict.fromkeys(STALL_SPANS, 0.0)
+            compiled = []
             for e in spans:
                 if e["name"] in cover and e.get("pid") == pid:
                     t0 = float(e["ts"])
                     ov = min(t0 + float(e.get("dur", 0.0)), b) - max(t0, a)
                     if ov > 0:
                         cover[e["name"]] += ov / 1e3
+                        if e["name"] == "compile.backend":
+                            compiled.append(
+                                e.get("args", {}).get("fun_name", "?"))
             out.append({"seq": wait.get("args", {}).get("seq"),
                         "at_ms": (a - waits[0][0]) / 1e3,
-                        "interval_ms": (b - a) / 1e3, "cover_ms": cover})
+                        "interval_ms": (b - a) / 1e3, "cover_ms": cover,
+                        "compiled": compiled})
+    return out
+
+
+#: ``compile.*`` span -> its column in the start-up section's table.
+_COMPILE_COLUMN = {"compile.trace": "trace_ms", "compile.lower": "lower_ms",
+                   "compile.backend": "backend_ms",
+                   "compile.cache_fetch": "fetch_ms"}
+
+
+def start_up(events):
+    """The start-up record of each process that has one: ``pid``, the
+    launcher's ``line`` (``obs.startup.log_line`` over the same phases),
+    ``phases`` (``setup.*``: name, ``module`` of an import, inclusive and
+    self milliseconds, by start) and ``compiles`` (one row a ``fun_name``:
+    how many times, ``trace_ms`` / ``lower_ms`` / ``backend_ms`` /
+    ``fetch_ms`` and the cache's ``hit`` / ``miss`` counts, largest first),
+    of the ``compile.*`` spans up to the end of ``setup.first_dispatch``.
+    A trace without ``setup.process_start`` (an older one) has none."""
+    out = []
+    origins = {e.get("pid"): float(e["ts"]) for e in events
+               if e.get("name") == "setup.process_start"}
+    for pid, origin_us in origins.items():
+        mine = [e for e in events if e.get("pid") == pid
+                and e.get("ph") == "X"
+                and e["name"].startswith(("setup.", "compile."))]
+        ends = [float(e["ts"]) + float(e["dur"]) for e in mine
+                if e["name"] == "setup.first_dispatch"]
+        end_us = ends[-1] if ends else float("inf")
+        mine = [e for e in mine if float(e["ts"]) < end_us]
+        held = [(e["name"], int(float(e["ts"]) * 1e3),
+                 int((float(e["ts"]) + float(e["dur"])) * 1e3),
+                 e.get("tid"), e.get("args", {})) for e in mine]
+        self_us = _self_times_by_event(mine)
+        phases = [{"name": e["name"],
+                   "module": e.get("args", {}).get("module"),
+                   "at_ms": (float(e["ts"]) - origin_us) / 1e3,
+                   "inclusive_ms": float(e["dur"]) / 1e3,
+                   "self_ms": self_us[id(e)] / 1e3}
+                  for e in sorted(mine, key=lambda e: float(e["ts"]))
+                  if e["name"].startswith("setup.")]
+        rows = {}
+        for e in sorted(mine, key=lambda e: float(e["ts"])):
+            if not e["name"].startswith("compile."):
+                continue
+            args = e.get("args", {})
+            if e["name"] == "compile.cache_fetch":
+                # nested in the ``compile.backend`` that covers it
+                fun = next((b.get("args", {}).get("fun_name", "?")
+                            for b in mine if b["name"] == "compile.backend"
+                            and b.get("tid") == e.get("tid")
+                            and float(b["ts"]) <= float(e["ts"])
+                            and float(e["ts"]) + float(e["dur"])
+                            <= float(b["ts"]) + float(b["dur"]) + 1.0), "?")
+            else:
+                fun = args.get("fun_name", "?")
+            # JAX names the traced function ``f`` and its program ``jit(f)``
+            if fun.startswith("jit(") and fun.endswith(")"):
+                fun = fun[4:-1]
+            row = rows.setdefault(fun, {
+                "fun_name": fun, "count": 0, "trace_ms": 0.0,
+                "lower_ms": 0.0, "backend_ms": 0.0, "fetch_ms": 0.0,
+                "hit": 0, "miss": 0})
+            row[_COMPILE_COLUMN[e["name"]]] += float(e["dur"]) / 1e3
+            if e["name"] == "compile.backend":
+                row["count"] += 1
+                if args.get("cache") in ("hit", "miss"):
+                    row[args["cache"]] += 1
+        compiles = sorted(rows.values(), key=lambda r: -(
+            r["trace_ms"] + r["lower_ms"] + r["backend_ms"]))
+        out.append({"pid": pid,
+                    "line": startup_lib.log_line(held, int(origin_us * 1e3)),
+                    "phases": phases, "compiles": compiles})
     return out
 
 
@@ -297,6 +396,7 @@ def main(argv=None):
     attn = attention_scores(events)
     scan = delta_rule_scan(events)
     moved = expert_rows(events)
+    boots = start_up(events)
 
     if args.json:
         doc = {
@@ -313,6 +413,8 @@ def main(argv=None):
             doc["delta_rule_scan"] = scan
         if moved is not None:
             doc["expert_rows"] = moved
+        if boots:
+            doc["start_up"] = boots
         print(json.dumps(doc, indent=2))
         return 0
 
@@ -360,12 +462,31 @@ def main(argv=None):
               "of %d buffer rows a step held a pair (%.1f%%)"
               % (moved["steps"], moved["rows"], moved["held"],
                  moved["buffer"], 100 * moved["held"] / moved["buffer"]))
+    for boot in boots:
+        print(f"start-up of pid {boot['pid']}: {boot['line']}")
+        print(f"  {'phase':<46}{'at_s':>9}{'incl_s':>9}{'self_s':>9}")
+        for ph in boot["phases"]:
+            label = ph["name"] + (f" {ph['module']}" if ph["module"] else "")
+            print(f"  {label:<46}{ph['at_ms'] / 1e3:>9.2f}"
+                  f"{ph['inclusive_ms'] / 1e3:>9.2f}"
+                  f"{ph['self_ms'] / 1e3:>9.2f}")
+        print(f"  {'compiled before the first dispatch ended':<46}"
+              f"{'n':>4}{'trace_s':>9}{'lower_s':>9}{'backend_s':>10}"
+              f"{'fetch_s':>9}  cache")
+        for r in boot["compiles"][:args.top]:
+            cache = "/".join(f"{r[k]} {k}" for k in ("hit", "miss") if r[k])
+            print(f"  {r['fun_name'][:46]:<46}{r['count']:>4}"
+                  f"{r['trace_ms'] / 1e3:>9.2f}{r['lower_ms'] / 1e3:>9.2f}"
+                  f"{r['backend_ms'] / 1e3:>10.2f}"
+                  f"{r['fetch_ms'] / 1e3:>9.2f}  {cache or '-'}")
     for st in slow or ():
         cover = ", ".join(f"{k} {v:.1f}" for k, v in st["cover_ms"].items()
                           if v > 0)
         print(f"stall before transfer seq={st['seq']} at "
               f"{st['at_ms'] / 1e3:.2f} s: {st['interval_ms']:.1f} ms; "
-              f"covered (ms): {cover or 'by no known span'}")
+              f"covered (ms): {cover or 'by no known span'}"
+              + (f"; compiled: {', '.join(st['compiled'])}"
+                 if st["compiled"] else ""))
     if slow is not None:
         print(f"{len(slow)} dispatch intervals over {args.stalls:g} ms")
     return 0
