@@ -74,10 +74,16 @@ _CPU_TEST_MEMORY_BYTES = 16 << 30
 # — so both share the one constant.
 ROW_UPDATE_CAPACITY = 2048
 #: What that update reports beside the step's loss: distinct rows, trips.
+#: A dense-gradient step that builds its table gradient from rows
+#: (``Trainer._grad_by_rows``) reports the same two.
 ROW_COUNTS = ("embed_distinct_rows", "embed_row_trips")
 #: And how its rows went back: "dma", "scatter", or "dma+scatter" where a
 #: model's tables differ in row shape (``Trainer.row_writeback``).
 ROW_WRITEBACK = "embed_row_writeback"
+#: How a dense-gradient step made its table-shaped gradient: "rows" (the
+#: batch's distinct rows, each the sum of its positions' cotangents) or
+#: "positions" (AD's scatter-add of every position); ``Trainer.embed_grad``.
+EMBED_GRAD = "embed_grad"
 
 
 def pad_batch(batch: Dict[str, np.ndarray], bs: int) -> Dict[str, np.ndarray]:
@@ -293,6 +299,9 @@ class Trainer:
         # How the row-local update writes its rows back (ROW_WRITEBACK):
         # static per compiled step, known once ``_update_rows`` is traced.
         self.row_writeback: Optional[str] = None
+        # How the dense-gradient step makes its table gradient (EMBED_GRAD):
+        # known once ``_dense_value_and_grad`` is traced.
+        self.embed_grad: Optional[str] = None
 
     # ------------------------------------------------------------------
     # State creation / placement
@@ -478,9 +487,11 @@ class Trainer:
             xent, new_mstate, new_params, new_opt, counts = (
                 self._row_local_apply(data_loss, state, batch))
         else:
-            xent, l2, new_mstate, grads = self._dense_value_and_grad(
+            ids = (self.model.lookup_ids(batch["feat_ids"])
+                   if self._grad_by_rows() else None)
+            xent, l2, new_mstate, grads, counts = self._dense_value_and_grad(
                 data_loss, state.params, data_axis=data_axis,
-                shard_axis=shard_axis)
+                shard_axis=shard_axis, ids=ids)
             new_params, new_opt = self._optax_apply(
                 grads, state.opt_state, state.params)
         if self._model_loss:    # the model's counts ride beside the loss
@@ -502,11 +513,26 @@ class Trainer:
         read them by ``hist_ids``), one array each. Read from what the
         trainer was built with; anything else compiles the step with the
         table-shaped gradient."""
-        return (self.cfg.embedding_update == "dense"
+        return (self._grad_by_rows()
                 and opt_lib.zero_grad_keeps_row(self.cfg)
                 and not self.cfg.l2_reg
-                and self.mesh_info.mesh is None
+                and self.mesh_info.mesh is None)
+
+    def _grad_by_rows(self) -> bool:
+        """Whether a dense-update step may differentiate the ``[B, F, ...]``
+        views of its tables in place of the tables, and so hold the table
+        gradient as the batch's distinct rows, each the float32 sum of its
+        positions' cotangents (``_view_row_sums``): tables the model reads
+        through ``_emb_lookup`` alone (history models also read them by
+        ``hist_ids``), one whole array each (not hashed into several, no
+        row shards), one batch an apply. The row-local step goes on from
+        the rows; every other such step scatters them into the table-shaped
+        gradient (``_table_grads``), which is AD's up to the order of a
+        float32 sum. Read from what the trainer was built with; anything
+        else leaves the tables to AD, a scatter-add of every position."""
+        return (self.cfg.embedding_update == "dense"
                 and self._accum == 1
+                and self.mesh_info.model_size == 1
                 and not self.model.emb.hashed
                 and not getattr(self.model, "uses_history", False))
 
@@ -521,22 +547,10 @@ class Trainer:
         the trainer's own ``tx.update`` — elementwise, so on rows it is the
         dense formula by construction — and written back in place. The
         state's tree and shapes are the dense step's."""
-        emb, names = self.model.emb, self._embed_names
-        tabs = {n: state.params[n] for n in names}
-        rest = {k: v for k, v in state.params.items() if k not in names}
+        tabs, rest = self._tables_and_rest(state.params)
         ids = self.model.lookup_ids(batch["feat_ids"])
-        with jax.named_scope("embed"):
-            views = {n: jnp.take(tabs[n], ids, axis=0) for n in names}
-
-        def loss_fn(diff):
-            views, rest = diff
-            xent, new_mstate = data_loss(
-                {**rest, **tabs}, emb_plan=None,
-                emb_rows={n: {emb.MONO: views[n]} for n in names})
-            return xent, (xent, new_mstate)
-
-        (_, (xent, new_mstate)), (g_views, g_rest) = jax.value_and_grad(
-            loss_fn, has_aux=True)((views, rest))
+        xent, new_mstate, g_views, g_rest = self._value_and_view_grads(
+            data_loss, tabs, rest, ids)
         opt_rest = opt_lib.select_params(state.opt_state, state.params, rest)
         opt_tabs = opt_lib.select_params(state.opt_state, state.params, tabs)
         new_rest, opt_rest = self._optax_apply(g_rest, opt_rest, rest)
@@ -546,16 +560,71 @@ class Trainer:
         return (xent, new_mstate, {**new_rest, **tabs},
                 opt_lib.join_params(opt_rest, opt_tabs, rest), counts)
 
+    def _tables_and_rest(self, params):
+        """``params`` split into the embedding tables and the other leaves."""
+        names = self._embed_names
+        return ({n: params[n] for n in names},
+                {k: v for k, v in params.items() if k not in names})
+
+    def _value_and_view_grads(self, data_loss, tabs, rest, ids, *,
+                              mean_axis=None):
+        """(xent, new model state, cotangents of the tables' ``[*ids.shape,
+        *row]`` views at ``ids``, gradient of the other leaves ``rest``) of
+        ``data_loss``, the tables ``tabs`` themselves not differentiated:
+        the model reads the views (``emb_rows``) where it would have looked
+        ``ids`` up. With ``mean_axis`` the loss is its mean over that mesh
+        axis (``_dense_value_and_grad``'s sync point): the other leaves'
+        gradient comes back reduced over it, the views' cotangents — the
+        views vary over the axis — this shard's own, scaled by the mean."""
+        emb, names = self.model.emb, self._embed_names
+        with jax.named_scope("embed"):
+            views = {n: jnp.take(tabs[n], ids, axis=0) for n in names}
+
+        def loss_fn(diff):
+            views, rest = diff
+            xent, new_mstate = data_loss(
+                {**rest, **tabs}, emb_plan=None,
+                emb_rows={n: {emb.MONO: views[n]} for n in names})
+            if mean_axis is not None:
+                xent = jax.lax.pmean(xent, mean_axis)
+            return xent, (xent, new_mstate)
+
+        (_, (xent, new_mstate)), (g_views, g_rest) = jax.value_and_grad(
+            loss_fn, has_aux=True)((views, rest))
+        return xent, new_mstate, g_views, g_rest
+
+    def _view_row_sums(self, tabs, ids, g_views):
+        """(``emb_ops.RowSums`` of the distinct rows of ``ids``: per row the
+        float32 sum of its positions' cotangents ``g_views``, every table's
+        columns side by side, slots a whole number of trips; the trips of
+        ``ROW_UPDATE_CAPACITY`` rows that hold them; ``rows_of(i)`` = the
+        ids of trip ``i`` and each table's rows of sums there). Sorted, so
+        the last trip's spare slots lie past the table: read as fill,
+        dropped or skipped by a write."""
+        names, cap = self._embed_names, ROW_UPDATE_CAPACITY
+        widths = [math.prod(tabs[n].shape[1:]) for n in names]
+        cuts = np.cumsum([0] + widths)
+        rows = emb_ops.sum_rows(
+            ids, jnp.concatenate([g_views[n].reshape(ids.size, w)
+                                  for n, w in zip(names, widths)], axis=1),
+            self.model.padded_vocab, self.cfg.feature_size, multiple=cap)
+
+        def rows_of(i):
+            uids = jax.lax.dynamic_slice_in_dim(rows.uids, i * cap, cap)
+            g = jax.lax.dynamic_slice_in_dim(rows.sums, i * cap, cap)
+            return uids, {n: g[:, cuts[j]:cuts[j + 1]].reshape(
+                (cap,) + tabs[n].shape[1:]) for j, n in enumerate(names)}
+
+        return rows, (rows.count + cap - 1) // cap, rows_of
+
     @jax.named_scope("embed")
     def _update_rows(self, tabs, opt_tabs, ids, g_views):
         """(tables, their optimizer state, row counts) after the optimizer's
         update of the distinct rows of ``ids``, for the cotangents
         ``g_views`` of the tables' ``[..., *row]`` views at ``ids``; rows no
         id names are not read. A trip handles ``ROW_UPDATE_CAPACITY``
-        distinct rows (sorted, so the last trip's spare slots lie past the
-        table: read as fill, dropped or skipped on the way back), and the
-        trips are as many as the batch needs: exact for any batch at static
-        shapes.
+        distinct rows (``_view_row_sums``), and the trips are as many as
+        the batch needs: exact for any batch at static shapes.
         All of it is the ``embed`` scope's but the rows' arithmetic
         (``_optax_apply``: ``opt``, the innermost scope wins)."""
         names, cap = self._embed_names, ROW_UPDATE_CAPACITY
@@ -566,13 +635,7 @@ class Trainer:
             self.row_writeback = how
             ulog.info(f"row-local table update: {cap} rows a trip, written "
                       f"back by {how}")
-        widths = [math.prod(tabs[n].shape[1:]) for n in names]
-        cuts = np.cumsum([0] + widths)
-        rows = emb_ops.sum_rows(
-            ids, jnp.concatenate([g_views[n].reshape(ids.size, w)
-                                  for n, w in zip(names, widths)], axis=1),
-            self.model.padded_vocab, self.cfg.feature_size, multiple=cap)
-        trips = (rows.count + cap - 1) // cap
+        rows, trips, rows_of = self._view_row_sums(tabs, ids, g_views)
 
         def take(table, uids):
             return jnp.take(table, uids, axis=0, mode="fill", fill_value=0)
@@ -602,10 +665,7 @@ class Trainer:
 
         def trip(carry):
             i, tabs, opt_tabs = carry
-            uids = jax.lax.dynamic_slice_in_dim(rows.uids, i * cap, cap)
-            g = jax.lax.dynamic_slice_in_dim(rows.sums, i * cap, cap)
-            g = {n: g[:, cuts[j]:cuts[j + 1]].reshape(
-                (cap,) + tabs[n].shape[1:]) for j, n in enumerate(names)}
+            uids, g = rows_of(i)
             new, new_opt = self._optax_apply(
                 g, jax.tree.map(lambda t: take(t, uids), opt_tabs),
                 {n: take(tabs[n], uids) for n in names})
@@ -616,29 +676,90 @@ class Trainer:
             (jnp.zeros((), jnp.int32), tabs, opt_tabs))
         return tabs, opt_tabs, dict(zip(ROW_COUNTS, (rows.count, trips)))
 
+    @jax.named_scope("embed")
+    def _table_grads(self, tabs, ids, g_views, *, sum_axis=None):
+        """(the table-shaped gradient of every table, row counts) from the
+        cotangents ``g_views`` of the tables' views at ``ids``: zeros, and
+        the batch's distinct rows added, each the float32 sum of its
+        positions' cotangents (``_view_row_sums``) — AD's scatter-add of
+        every position up to the order of those sums, at a sixth of the
+        table row updates where a row is looked up six times a batch (a
+        row update costs the table's height, 122 ns at 16.9M rows, a
+        position summed in a batch-tall array a tenth of it: PERF.md §6,
+        PR 36). Only the rows really held are scattered, a trip of
+        ``ROW_UPDATE_CAPACITY`` at a time (a dropped spare slot costs what
+        a written one does), the gradient the loop's carry: exact for any
+        batch at static shapes. The scatter is told nothing of its ids
+        (``indices_are_sorted`` makes the TPU compiler sweep the table).
+        With ``sum_axis`` (data replicas: the cotangents are this shard's)
+        the tables are summed over that mesh axis, the all-reduce AD would
+        have put after its own scatter, and the counts are the fullest
+        shard's."""
+        rows, trips, rows_of = self._view_row_sums(tabs, ids, g_views)
+
+        def trip(carry):
+            i, grads = carry
+            uids, g = rows_of(i)
+            return i + 1, {n: grads[n].at[uids].add(
+                g[n].astype(grads[n].dtype), mode="drop") for n in grads}
+
+        zeros = jax.tree.map(jnp.zeros_like, tabs)
+        if sum_axis is not None:    # the carry varies over the axis from
+            # the start, as this shard's rows do (shard_map's typing)
+            zeros = jax.lax.pcast(zeros, (sum_axis,), to="varying")
+        _, grads = jax.lax.while_loop(
+            lambda carry: carry[0] < trips, trip,
+            (jnp.zeros((), jnp.int32), zeros))
+        counts = (rows.count, trips)
+        if sum_axis is not None:
+            grads = jax.lax.psum(grads, sum_axis)
+            counts = jax.lax.pmax(counts, sum_axis)
+        return grads, dict(zip(ROW_COUNTS, counts))
+
     def _dense_value_and_grad(self, data_loss, params, *, data_axis,
-                              shard_axis):
-        """(xent, l2, new_model_state, grads) of a dense-update step, for
-        ``data_loss(params) -> (mean data loss of this shard, new model
-        state)``. The plain and the accumulating step share it: the
-        gradient sync over the data axis, the L2 term and the pad-row mask
-        are defined here once."""
+                              shard_axis, ids=None):
+        """(xent, l2, new_model_state, grads, row counts) of a dense-update
+        step, for ``data_loss(params, **emb) -> (mean data loss of this
+        shard, new model state)``. The plain and the accumulating step
+        share it: the gradient sync over the data axis, the L2 term and the
+        pad-row mask are defined here once. With ``ids`` (the ids the model
+        looks up in its tables, from a step for which ``_grad_by_rows``
+        holds) the tables' gradient is built from the batch's distinct rows
+        (``_table_grads``; the counts are its); without, by AD, from every
+        position (no counts)."""
         flat_sync = data_axis is not None and self._hier_groups is None
+        how = "positions" if ids is None else "rows"
+        if how != self.embed_grad:      # said once a trainer, at trace time
+            self.embed_grad = how
+            ulog.info(f"dense-gradient step: table gradient from {how}")
 
-        def loss_fn(params):
-            xent, new_mstate = data_loss(params)
-            if flat_sync:
-                # THE gradient sync point: the loss is made a *global*
-                # scalar (mean over the data axis); differentiating it
-                # under shard_map's replication-aware AD yields gradients
-                # with the cross-replica psum already inserted by XLA —
-                # this replaces hvd.DistributedOptimizer's NCCL allreduce
-                # (2-hvd-gpu/...py:262) and the PS push/pull (X1).
-                xent = jax.lax.pmean(xent, data_axis)
-            return xent, (xent, new_mstate)
+        counts: Dict[str, jnp.ndarray] = {}
+        # THE gradient sync point: the loss is made a *global* scalar (mean
+        # over the data axis); differentiating it under shard_map's
+        # replication-aware AD yields gradients with the cross-replica psum
+        # already inserted by XLA — this replaces
+        # hvd.DistributedOptimizer's NCCL allreduce (2-hvd-gpu/...py:262)
+        # and the PS push/pull (X1).
+        sync = data_axis if flat_sync else None
+        if ids is None:
+            def loss_fn(params):
+                xent, new_mstate = data_loss(params)
+                if sync is not None:
+                    xent = jax.lax.pmean(xent, sync)
+                return xent, (xent, new_mstate)
 
-        (_, (xent, new_mstate)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
+            (_, (xent, new_mstate)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
+        else:
+            # The same sync point: the dense leaves' gradient comes reduced
+            # out of AD; the views' cotangents are local, so the tables
+            # made of them are summed over the axis explicitly.
+            tabs, rest = self._tables_and_rest(params)
+            xent, new_mstate, g_views, g_rest = self._value_and_view_grads(
+                data_loss, tabs, rest, ids, mean_axis=sync)
+            g_tabs, counts = self._table_grads(tabs, ids, g_views,
+                                               sum_axis=sync)
+            grads = {**g_rest, **g_tabs}
         if data_axis is not None and not flat_sync:
             # Hierarchical sync point (TUNING §2.13): the loss stayed
             # per-shard above, so the raw grads carry no psum; average
@@ -648,6 +769,7 @@ class Trainer:
                 grads, data_axis, self._hier_groups,
                 self.mesh_info.data_size)
             xent = jax.lax.pmean(xent, data_axis)  # metrics only
+            counts = jax.lax.pmax(counts, data_axis)
         l2, grads = self._add_dense_l2(params, grads, shard_axis=shard_axis)
         # Structural guarantee: padded_vocab pad rows never receive a
         # gradient (they are zero already — unreachable ids, masked l2 —
@@ -657,7 +779,7 @@ class Trainer:
                 n: self.model.emb.mask_pad_grads(grads[n],
                                                  axis_name=shard_axis)
                 for n in self._embed_names}}
-        return xent, l2, new_mstate, grads
+        return xent, l2, new_mstate, grads, counts
 
     def _add_dense_l2(self, params, grads, *, shard_axis):
         """(l2 value, grads + the L2 term's gradient on the embedding
@@ -1092,7 +1214,7 @@ class Trainer:
 
         # L2 charged once per APPLY, not per microbatch — matching the
         # equivalent big-batch step, where it also appears once.
-        xent, l2, new_mstate, grads = self._dense_value_and_grad(
+        xent, l2, new_mstate, grads, _ = self._dense_value_and_grad(
             data_loss, state.params, data_axis=data_axis,
             shard_axis=shard_axis)
         new_params, new_opt = self._optax_apply(
@@ -2051,8 +2173,13 @@ class Trainer:
                                 else float(v)) for key, v in m.items()
                                       if key not in ("loss", "xent",
                                                      "steps_done")}
-                            if ROW_COUNTS[0] in counts:
-                                counts[ROW_WRITEBACK] = self.row_writeback
+                            # how the compiled step made its table
+                            # gradient, or wrote its rows back
+                            for key, how in ((EMBED_GRAD, self.embed_grad),
+                                             (ROW_WRITEBACK,
+                                              self.row_writeback)):
+                                if how is not None:
+                                    counts[key] = how
                             # what the model says its traced step is made
                             # of (a note may name a count in braces)
                             counts.update({

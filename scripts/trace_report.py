@@ -44,7 +44,13 @@ and ``embed_row_trips``; the report prints their mean and maximum and the
 share of those steps that took one trip (a capacity that most steps
 overflow by a little pays a second trip for it), and how the compiled step
 writes its rows back (``embed_row_writeback``: ``dma``, one asynchronous
-copy a row, or ``scatter``, XLA's; TUNING §5).
+copy a row, or ``scatter``, XLA's; TUNING §5). A dense-gradient step (Adam,
+L2, data replicas: every row swept every step) says on the same span how it
+made its table-shaped gradient (``embed_grad``): ``rows``, from the batch's
+distinct rows, each the sum of its positions' cotangents
+(``Trainer._grad_by_rows``), with the same two counts and the same line in
+the report; or ``positions``, AD's scatter-add of every position (history
+models, hashed tables, row shards, accumulation), with a line that says so.
 
 Where the model says what makes its attention's masked scores
 (``--model sdar_moe``), each ``train.log_sync`` carries ``attn_scores``
@@ -307,12 +313,14 @@ def _log_syncs(events, attribute):
 
 
 def row_updates(events):
-    """The row-local table update's counters off the ``train.log_sync``
-    spans that carry them: ``steps`` read, mean and max of
-    ``embed_distinct_rows`` and ``embed_row_trips``, ``one_trip_share`` of
-    those steps and the step's ``writeback`` (``embed_row_writeback``; "?"
-    in a trace that predates it); None when no span has them (the step
-    sweeps the table, or the trace predates the counters)."""
+    """The distinct-row counters off the ``train.log_sync`` spans that carry
+    them (a row-local step's, or a dense-gradient step's that builds its
+    table gradient from those rows: ``table_gradient``): ``steps`` read,
+    mean and max of ``embed_distinct_rows`` and ``embed_row_trips``,
+    ``one_trip_share`` of those steps and the row-local step's
+    ``writeback`` (``embed_row_writeback``; "?" where the trace predates it
+    or the step writes no rows back); None when no span has them (the step
+    scatters every position, or the trace predates the counters)."""
     seen = _log_syncs(events, "embed_distinct_rows")
     if not seen:
         return None
@@ -325,6 +333,15 @@ def row_updates(events):
             "row_trips_max": max(trips),
             "one_trip_share": sum(t == 1 for t in trips) / len(trips),
             "writeback": seen[-1].get("embed_row_writeback", "?")}
+
+
+def table_gradient(events):
+    """How the dense-gradient step made its table-shaped gradient
+    (``embed_grad`` of the last ``train.log_sync`` that says: "rows" /
+    "positions"); None in a row-local step's trace, a sparse-update one's,
+    or one that predates the note."""
+    seen = _log_syncs(events, "embed_grad")
+    return seen[-1]["embed_grad"] if seen else None
 
 
 def attention_scores(events):
@@ -435,15 +452,23 @@ def main(argv=None):
               f"{r['p50_ms']:>9.3f}{r['p99_ms']:>9.3f}")
     for name, n in sorted(instants.items()):
         print(f"instant {name}: {n}")
+    grad = table_gradient(events)
     if touched is not None:
-        print("row-local table update over %d logged steps: "
+        by_rows = grad == "rows"
+        print("%s over %d logged steps: "
               "embed_distinct_rows mean %.0f max %d, embed_row_trips mean "
-              "%.2f max %d, one trip in %.0f%% of them, rows written back "
-              "by %s" % (
+              "%.2f max %d, one trip in %.0f%% of them, %s" % (
+                  "dense-gradient step, table gradient from rows" if by_rows
+                  else "row-local table update",
                   touched["steps"], touched["distinct_rows_mean"],
                   touched["distinct_rows_max"], touched["row_trips_mean"],
                   touched["row_trips_max"],
-                  100 * touched["one_trip_share"], touched["writeback"]))
+                  100 * touched["one_trip_share"],
+                  "every row swept after it" if by_rows
+                  else "rows written back by " + touched["writeback"]))
+    elif grad == "positions":
+        print("dense-gradient step: table gradient from positions (AD's "
+              "scatter-add of every position of the batch)")
     if attn is not None:
         print("block-masked attention over %d logged steps: scores by %s"
               % (attn["steps"], attn["scores"])

@@ -17,6 +17,7 @@ data-parallel virtual devices} x {l2_reg 0, 1e-4}:
 
 import functools
 import itertools
+import re
 
 import numpy as np
 import pytest
@@ -165,22 +166,62 @@ def test_matches_plain_dense_adam_with_l2(model, devices, l2_reg):
             assert not adam.nu[name][idle].any()
 
 
+def _carried_in(hlo_text, name):
+    """What a loop was handed for the element of its carry that ``name``
+    reads in the loop's body (``get-tuple-element(body parameter),
+    index=k`` -> operand k of the tuple the ``while`` starts from)."""
+    computation = None
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ENTRY\s+)?%([\w.\-]+) \(.*\{\s*$", line)
+        if m:
+            computation = m.group(1)
+        m = re.match(r"\s*%" + re.escape(name) + r" = .*get-tuple-element\("
+                     r"%[\w.\-]+\), index=(\d+)", line)
+        if m:
+            index, body = int(m.group(1)), computation
+            break
+    else:
+        raise AssertionError(f"{name} reads no tuple")
+    start = re.search(r" while\(%([\w.\-]+)\), condition=%[\w.\-]+, "
+                      r"body=%" + re.escape(body) + r"[,\s]", hlo_text)
+    assert start, (name, body)
+    made = re.search(r"%" + re.escape(start.group(1)) + r" = \(.*?\) "
+                     r"tuple\((.*?)\)[,\s]", hlo_text)
+    return re.findall(r"%([\w.\-]+)", made.group(1))[index]
+
+
 @pytest.mark.parametrize("model,devices,l2_reg", CASES)
 def test_l2_term_makes_no_table_of_its_own(model, devices, l2_reg):
     tr, _, _ = _plain(model, devices, l2_reg)
-    ops = profiling.hlo_table_ops(tr.step_hlo_text(), tr.model.padded_vocab)
+    text = tr.step_hlo_text()
+    ops = profiling.hlo_table_ops(text, tr.model.padded_vocab)
     by_name = {o["name"]: o for o in ops}
     scatters = [o for o in ops if "scatter" in o["name"]]
     assert scatters, ops
     for op in scatters:
         assert op["tables"], op
         for name in op["tables"]:
-            # The scatter-add's own table is a fill: made from no table.
+            # The scatter-add's own table is a fill: made from no table. The
+            # table gradient is built from the batch's distinct rows a trip
+            # at a time (``Trainer._table_grads``), so the scatter reads the
+            # loop's carry, and the fill is what the loop starts from.
+            if name not in by_name:
+                name = _carried_in(text, name)
+            assert by_name[name]["primitive"] == "broadcast_in_dim", (
+                op, by_name[name])
             assert not by_name[name]["tables"], (op, by_name[name])
 
 
 @pytest.mark.parametrize("model,devices,l2_reg", CASES)
 def test_accumulating_step_is_the_plain_step(model, devices, l2_reg):
+    """Bit for bit, though the plain step builds its table gradient from the
+    batch's distinct rows (``Trainer._table_grads``) and the accumulating
+    one leaves it to AD's scatter-add of every position: ``sum_rows``'
+    stable sort keeps a row's positions in batch order and XLA:CPU's
+    scatter-adds take their updates in order, so both add a row's
+    cotangents in the same order here. (A backend that reorders a
+    scatter-add's updates would need a summation-order tolerance; the
+    gradient's own parity is held in ``tests/test_dense_rows_grad.py``.)"""
     _, _, plain = _plain(model, devices, l2_reg)
     _, _, accumulated = _run(model, devices, l2_reg, accumulating=True)
     for tree in ("params", "opt_state"):

@@ -1,0 +1,269 @@
+"""The dense-gradient step's table gradient, built from the batch's distinct
+rows (``Trainer._grad_by_rows`` / ``_table_grads``).
+
+AD hands a dense-update step its table gradient as a scatter-add of every
+position's cotangent into a table of zeros: one latency-bound row update a
+position, 122 ns each at 16.9M rows (PERF.md §6, PR 36). Where the trainer
+can see that the model reads its tables through ``_emb_lookup`` alone, the
+step differentiates the tables' ``[B, F, ...]`` views, sums the cotangents
+per distinct row in a batch-tall array (``ops.embedding.sum_rows``) and
+scatters only the distinct rows, a trip of ``ROW_UPDATE_CAPACITY`` at a
+time: the same gradient up to the order of a float32 sum, dense Adam with
+L2 unchanged after it. Held here, at small size in float32 on the CPU:
+
+* the trained state against the same trainer with the tables left to AD,
+  over {deepfm, dcnv2, multitask} x {1, 2 data replicas} x {l2_reg 0, 1e-4};
+* the shapes that stress the rows: one row taking every id of a field, more
+  distinct rows than a (patched-small) trip holds;
+* ``_table_grads`` against NumPy: pad rows, negative ids and ids past the
+  table receive nothing;
+* the counts against NumPy's ``unique``;
+* the compiled step: no table-tall scatter of a batch's positions where the
+  step is eligible, and the scatter of every position wherever it is not.
+"""
+
+import functools
+import itertools
+import math
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepfm_tpu.config import Config
+from deepfm_tpu.train import Trainer, loop
+from deepfm_tpu.utils import profiling
+
+V, F, B, STEPS = 300, 6, 32, 3
+ID_RANGE = 200          # rows >= ID_RANGE are real and never touched
+
+MODELS = {"deepfm": {}, "dcnv2": {"model": "dcnv2"},
+          "multitask": {"tasks": "ctr,cvr", "multitask": "mmoe",
+                        "mmoe_experts": 2}}
+CASES = [pytest.param(m, d, l2, id=f"{m}-{d}dev-l2_{l2:g}")
+         for m, d, l2 in itertools.product(MODELS, (1, 2), (0.0, 1e-4))]
+
+
+def _cfg(model="deepfm", devices=1, l2_reg=1e-4, **over):
+    flags = dict(
+        feature_size=V, field_size=F, embedding_size=4, deep_layers="8,4",
+        dropout="1.0,1.0", batch_size=B, compute_dtype="float32",
+        l2_reg=l2_reg, learning_rate=0.01, log_steps=0, seed=11,
+        scale_lr_by_world=False, mesh_data=devices, mesh_model=1,
+        steps_per_loop=STEPS, transfer_ahead=0)
+    flags.update(MODELS.get(model, {"model": model}))
+    flags.update(over)
+    return Config(**flags)
+
+
+def _batches(two_label=False, one_row_field=None, steps=STEPS, seed=5):
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(steps):
+        label = rng.integers(0, 2, size=(B, 1)).astype(np.float32)
+        batch = {
+            "feat_ids": rng.integers(0, ID_RANGE, (B, F)).astype(np.int32),
+            "feat_vals": rng.normal(size=(B, F)).astype(np.float32),
+            "label": label}
+        if one_row_field is not None:
+            batch["feat_ids"][:, one_row_field] = 77
+        if two_label:
+            batch["label2"] = (label * rng.integers(0, 2, (B, 1))
+                               ).astype(np.float32)
+        out.append(batch)
+    return out
+
+
+def _host(tree):
+    return jax.tree.map(np.asarray, tree)
+
+
+def _run(cfg, by_rows=True, batches=None):
+    """(trainer, state after, metrics of each step), by single
+    ``train_step`` calls so that every step's counts come back; with
+    ``by_rows`` off the tables are left to AD (the reference)."""
+    tr = Trainer(cfg)
+    assert tr._grad_by_rows() and not tr._row_local_eligible()
+    if not by_rows:
+        tr._grad_by_rows = lambda: False
+    state = tr.init_state()
+    metrics = []
+    for batch in batches or _batches(cfg.num_tasks > 1):
+        state, m = tr.train_step(state, tr.put_batch(batch))
+        metrics.append({k: float(v) for k, v in m.items()})
+    assert tr.embed_grad == ("rows" if by_rows else "positions")
+    return tr, _host(state), metrics
+
+
+@functools.lru_cache(maxsize=None)
+def _by_ad(model, devices, l2_reg):
+    return _run(_cfg(model, devices, l2_reg), by_rows=False)
+
+
+def _assert_close(got, want, what, tol=1e-6):
+    """Leaf by leaf in norm: the two steps sum a row's cotangents in two
+    orders (and on two data replicas reduce them in two), nothing else."""
+    flat_got, _ = jax.tree_util.tree_flatten_with_path(got)
+    for (path, a), b in zip(flat_got, jax.tree.leaves(want)):
+        if not np.ndim(a):
+            assert a == b, (what, jax.tree_util.keystr(path))
+            continue
+        gap = np.linalg.norm((a - b).ravel()) / max(
+            np.linalg.norm(b.ravel()), 1e-30)
+        assert gap <= tol, (what, jax.tree_util.keystr(path), gap)
+
+
+@pytest.mark.parametrize("model,devices,l2_reg", CASES)
+def test_equals_the_gradient_ad_builds(model, devices, l2_reg):
+    _, want, plain = _by_ad(model, devices, l2_reg)
+    tr, got, rows = _run(_cfg(model, devices, l2_reg))
+    for tree in ("params", "opt_state"):
+        _assert_close(getattr(got, tree), getattr(want, tree), tree)
+    assert [m["loss"] for m in rows] == pytest.approx(
+        [m["loss"] for m in plain], rel=1e-6)
+    assert all(loop.ROW_COUNTS[0] not in m for m in plain)
+    # Pad rows stay exactly zero, parameters and moments.
+    adam = got.opt_state[0]
+    for name in tr.model.embedding_param_names():
+        for leaf in (got.params[name], adam.mu[name], adam.nu[name]):
+            assert not leaf[V:].any(), name
+
+
+@pytest.mark.parametrize("model", sorted(MODELS))
+def test_one_row_takes_every_id_of_a_field(model):
+    cfg = _cfg(model)
+    batches = _batches(cfg.num_tasks > 1, one_row_field=F - 1)
+    _, got, rows = _run(cfg, batches=batches)
+    _, want, _ = _run(cfg, by_rows=False, batches=batches)
+    for tree in ("params", "opt_state"):
+        _assert_close(getattr(got, tree), getattr(want, tree), tree)
+    assert all(m["embed_row_trips"] == 1 for m in rows)
+
+
+@pytest.mark.parametrize("capacity,trips", [(256, 1), (64, 2), (8, None),
+                                            (1, None)])
+def test_more_distinct_rows_than_a_trip_holds(monkeypatch, capacity, trips):
+    cfg = _cfg("deepfm")
+    _, want, _ = _by_ad("deepfm", 1, 1e-4)
+    monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
+    _, got, rows = _run(cfg)
+    for tree in ("params", "opt_state"):
+        _assert_close(getattr(got, tree), getattr(want, tree), tree)
+    for m, batch in zip(rows, _batches()):
+        distinct = len(np.unique(batch["feat_ids"]))
+        assert m["embed_distinct_rows"] == distinct
+        assert m["embed_row_trips"] == math.ceil(distinct / capacity)
+        if trips is not None:
+            assert m["embed_row_trips"] == trips
+        else:
+            assert m["embed_row_trips"] > 2
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+def test_counts_against_numpy_unique(devices):
+    """On data replicas each shard counts its own slice of the batch; the
+    step reports the fullest shard's."""
+    cfg = _cfg("deepfm", devices)
+    _, _, rows = _run(cfg)
+    for m, batch in zip(rows, _batches()):
+        shards = np.split(batch["feat_ids"], devices)
+        assert m["embed_distinct_rows"] == max(
+            len(np.unique(s)) for s in shards)
+        assert m["embed_row_trips"] == 1
+
+
+@pytest.mark.parametrize("capacity", [4, 16, 2048])
+def test_pad_rows_negative_ids_and_ids_past_the_table_receive_nothing(
+        monkeypatch, capacity):
+    """``_table_grads`` alone, against NumPy: ids count from the end where
+    negative (as ``jnp.take`` reads them); a pad row (``feature_size`` and
+    past it) and an id past the table receive nothing."""
+    monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
+    tr = Trainer(_cfg("deepfm"))
+    rows = tr.model.padded_vocab
+    assert rows > V
+    rng = np.random.default_rng(7)
+    ids = rng.integers(0, 40, size=(B, F)).astype(np.int32)
+    ids[0, :] = [-1, -rows, -(rows - 3), V, rows - 1, rows + 5]
+    ids[1, 0] = V - 1
+    g = {"fm_v": rng.normal(size=(B, F, 4)).astype(np.float32),
+         "fm_w": rng.normal(size=(B, F)).astype(np.float32)}
+    tabs = {n: jnp.zeros((rows,) + v.shape[2:], jnp.float32)
+            for n, v in g.items()}
+    got, counts = jax.jit(tr._table_grads)(tabs, jnp.asarray(ids), g)
+    flat = ids.reshape(-1)
+    norm = np.where(flat < 0, flat + rows, flat)
+    ok = (norm >= 0) & (norm < V)
+    for name, cot in g.items():
+        want = np.zeros(tabs[name].shape, np.float64)
+        np.add.at(want, norm[ok], cot.reshape((flat.size,) + cot.shape[2:])[ok])
+        np.testing.assert_allclose(got[name], want, atol=1e-5)
+        assert not np.asarray(got[name])[V:].any()
+    distinct = len(np.unique(norm[ok]))
+    assert int(counts["embed_distinct_rows"]) == distinct
+    assert int(counts["embed_row_trips"]) == math.ceil(distinct / capacity)
+    # rows 0 (from -rows), 3 (from -(rows - 3)) and V - 1 are real
+    assert np.asarray(got["fm_w"])[[0, 3, V - 1]].all()
+
+
+def _table_scatter_heights(hlo_text, rows):
+    """Per scatter of the compiled program into an array ``rows`` tall, how
+    many rows of updates it is handed."""
+    heights, shapes = [], {}
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[(\d*)", line)
+        if m:
+            shapes[m.group(1)] = int(m.group(2) or 0)
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[(\d+)[,\]][^ ]* "
+                     r"scatter\(%[\w.\-]+, %[\w.\-]+, %([\w.\-]+)\)", line)
+        if m and int(m.group(1)) == rows:
+            heights.append(shapes[m.group(2)])
+    return heights
+
+
+@pytest.mark.parametrize("model,devices", [
+    (m, d) for m in sorted(MODELS) for d in (1, 2)])
+def test_eligible_step_scatters_trips_of_rows_never_positions(model, devices):
+    tr = Trainer(_cfg(model, devices))
+    assert tr._grad_by_rows()
+    text = tr.step_hlo_text()
+    heights = _table_scatter_heights(text, tr.model.padded_vocab)
+    assert heights and set(heights) == {loop.ROW_UPDATE_CAPACITY}, heights
+    assert tr.embed_grad == "rows"
+    # and Adam still sweeps every row
+    ops = profiling.hlo_table_ops(text, tr.model.padded_vocab)
+    assert [o for o in ops if o["scope"] == "opt" and o["tables"]
+            and not o["primitive"].startswith("scatter")], ops
+
+
+NOT_ELIGIBLE = {
+    # why: (flags, height of the local table, positions a scatter takes)
+    "row_shards": (dict(mesh_model=2), 320 // 2,
+                   B * F),
+    "hashed": (dict(embedding_buckets="64,64"), 64, B * F),
+    "history_model": (dict(history_max_len=5), 320, None),
+    "accumulation": (dict(grad_accum_steps=2, steps_per_loop=4), 320, B * F),
+    "sparse_update": (dict(embedding_update="sparse"), 320, None),
+}
+
+
+@pytest.mark.parametrize("why", sorted(NOT_ELIGIBLE))
+def test_everything_else_compiles_the_step_it_compiled(why):
+    """Each reason alone keeps the tables with AD: the predicate says no,
+    and the compiled step scatters the batch's positions as it did, not
+    trips of rows (the sparse plane has its own row plan and another state
+    tree: only the predicate is held there)."""
+    flags, height, positions = NOT_ELIGIBLE[why]
+    tr = Trainer(_cfg("din" if why == "history_model" else "deepfm",
+                      **flags))
+    assert not tr._grad_by_rows() and not tr._row_local_eligible()
+    if why == "sparse_update":
+        return
+    heights = _table_scatter_heights(tr.step_hlo_text(), height)
+    assert tr.embed_grad == "positions"
+    assert heights and loop.ROW_UPDATE_CAPACITY not in heights, heights
+    if positions is not None:
+        assert set(heights) == {positions}, heights

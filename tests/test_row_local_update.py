@@ -8,7 +8,9 @@ sweep (PERF.md §6, PR 28). Held here, over {deepfm, dcnv2, dlrm_dcnv2} at
 small size in float32:
 
 * the mathematics, against a float32 NumPy dense Adagrad on every row;
-* the same trainer with the predicate patched off (the table-shaped form);
+* the same trainer with the predicate patched off (the table-shaped form,
+  its gradient AD's: ``tests/test_dense_rows_grad.py`` holds the
+  table-shaped gradient built from rows to the same);
 * the shapes that stress the row plan: one row taking every id of a field,
   more distinct rows than a (patched-small) capacity;
 * the counters against NumPy's ``unique``;
@@ -100,8 +102,9 @@ def _run(model, eligible=True, batches=None, **over):
     ``train_step`` calls so that every step's counters come back."""
     tr = Trainer(_cfg(model, **over))
     assert tr._row_local_eligible()
-    if not eligible:
-        tr._row_local_eligible = lambda: False
+    if not eligible:        # the tables left to AD: a scatter-add, a sweep
+        tr._grad_by_rows = lambda: False
+        assert not tr._row_local_eligible()
     state = _seeded(tr)
     before = _host(state)
     metrics = []

@@ -126,16 +126,33 @@ def test_row_local_deepfm_at_k128_writes_each_table_its_own_way(
     assert narrow == ["scatter", "scatter"], ops
 
 
+DEEPFM_K32 = dict(model="deepfm", numeric_fields=0, bottom_layers="",
+                  cross_layers=0, cross_rank=0, embedding_size=32,
+                  feature_size=1_600_000, l2_reg=1e-4)
+
+
+@pytest.mark.parametrize("over", [{}, DEEPFM_K32],
+                         ids=["dlrm_dcnv2-k128", "deepfm-k32-l2"])
 def test_the_table_shaped_step_still_sweeps(v5e, no_compile_cache,
-                                            monkeypatch):
-    """The same model under Adam: fill, scatter-add in place on the fill,
-    sweep — the three lines TUNING §5 item 6 describes."""
-    tr, compiled = _compiled(v5e, monkeypatch, optimizer="Adam")
-    assert not tr._row_local_eligible()
+                                            monkeypatch, over):
+    """The same model under Adam, and DeepFM at the DeepFM cells' row with
+    L2: fill, scatter-add, sweep — the lines TUNING §5 item 6 describes.
+    The scatter-adds take a trip of the batch's distinct rows
+    (``Trainer._table_grads``), never its positions, and update the loop's
+    carry where it lies: a copy of the 2.16 GB gradient a trip would cost
+    more than the scatter saves."""
+    tr, compiled = _compiled(v5e, monkeypatch, optimizer="Adam", **over)
+    assert not tr._row_local_eligible() and tr.embed_grad == "rows"
     ops = profiling.hlo_table_ops(compiled.as_text(), tr.model.padded_vocab)
     primitives = [o["primitive"] for o in ops if o["loop_body"]]
     assert "scatter-add" in primitives and "broadcast_in_dim" in primitives
     assert any(o["scope"] == "opt" and len(o["in_place"]) >= 3 for o in ops)
+    scatters = [o for o in ops if o["primitive"] == "scatter-add"]
+    assert len(scatters) == len(tr.model.embedding_param_names()), ops
+    for op in scatters:
+        assert op["in_place"] == [0] and op["scope"] == "embed", op
+    assert "copy" not in {o["opcode"] for o in ops
+                          if any("," in r for r in o["results"])}, ops
 
 
 # --- the block-masked attention kernel (ops/block_attention.py; PR 32) ------

@@ -65,11 +65,12 @@ def test_compiled_multi_step_names_every_phase(update, kernels):
     ops = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
     assert {profiling.innermost_scope(o) for o in ops} >= set(SCOPES)
     # The backward pass keeps the name: the gather's transpose is the
-    # scatter-add into the table-shaped (dense) or row-shaped gradient. The
-    # fused sparse step differentiates the gathered views, and scatters
-    # their cotangents itself.
+    # scatter-add into the row-shaped gradient of the sparse plan. The dense
+    # step (``Trainer._grad_by_rows``) and the fused sparse step
+    # differentiate the gathered views, and scatter their cotangents
+    # themselves, under ``embed``.
     backward = {"transpose(jvp(tower))", "transpose(jvp(fm))"}
-    if kernels != "auto" or update == "dense":
+    if kernels != "auto":
         backward.add("transpose(jvp(embed))")
     for name in backward:
         assert any(f"/{name}/" in o for o in ops), name
@@ -281,7 +282,9 @@ def _report():
 def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
     """Adagrad without L2 updates the tables on the batch's distinct rows
     and says how many and in how many trips, on the span that reads the loss
-    back (no sync of its own); any other step has nothing to say."""
+    back (no sync of its own); Adam with L2 sweeps every row and builds its
+    table gradient from the same distinct rows: the same counts, and
+    ``embed_grad`` in place of the write-back."""
     trace_lib.configure("full", export_env=False)
     over = dict(optimizer="Adagrad", l2_reg=0.0) if eligible else {}
     tr = Trainer(_cfg(**over))
@@ -296,20 +299,31 @@ def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
     trace_lib.export(path)
     report = _report()
     events, _ = report._load(path)
-    if not eligible:
-        assert all(set(a) == {"step"} for a in syncs)
-        assert report.row_updates(events) is None
-        return
     # the last scanned step's, like the loss
     want = [len(np.unique(batches[K * i - 1]["feat_ids"])) for i in (1, 2, 3)]
     assert [a["embed_distinct_rows"] for a in syncs] == want
     assert [a["embed_row_trips"] for a in syncs] == [1, 1, 1]
-    # XLA:CPU, and a [V,4] row anyway: the write-back is the scatter
-    assert [a["embed_row_writeback"] for a in syncs] == ["scatter"] * 3
-    assert report.row_updates(events) == {
+    counts = {
         "steps": 3, "distinct_rows_mean": sum(want) / 3,
         "distinct_rows_max": max(want), "row_trips_mean": 1.0,
-        "row_trips_max": 1, "one_trip_share": 1.0, "writeback": "scatter"}
+        "row_trips_max": 1, "one_trip_share": 1.0}
+    if not eligible:
+        assert all(set(a) == {"step", "embed_distinct_rows",
+                              "embed_row_trips", "embed_grad"}
+                   and a["embed_grad"] == "rows" for a in syncs)
+        assert report.row_updates(events) == {**counts, "writeback": "?"}
+        assert report.table_gradient(events) == "rows"
+        assert report.main([path]) == 0
+        assert ("dense-gradient step, table gradient from rows over 3 logged "
+                "steps: embed_distinct_rows mean %.0f max %d, embed_row_trips "
+                "mean 1.00 max 1, one trip in 100%% of them, every row swept "
+                "after it" % (sum(want) / 3, max(want))
+                ) in capsys.readouterr().out
+        return
+    # XLA:CPU, and a [V,4] row anyway: the write-back is the scatter
+    assert [a["embed_row_writeback"] for a in syncs] == ["scatter"] * 3
+    assert report.row_updates(events) == {**counts, "writeback": "scatter"}
+    assert report.table_gradient(events) is None
     assert report.main([path]) == 0
     assert ("row-local table update over 3 logged steps: embed_distinct_rows "
             "mean %.0f max %d, embed_row_trips mean 1.00 max 1, one trip in "
@@ -343,6 +357,32 @@ def test_report_reads_a_trace_that_predates_the_writeback_attribute():
     events = [{"name": "train.log_sync", "ph": "X", "args": {
         "step": 2, "embed_distinct_rows": 90, "embed_row_trips": 1}}]
     assert _report().row_updates(events)["writeback"] == "?"
+
+
+@pytest.mark.parametrize("args,says", [
+    ({"step": 2}, None),                    # predates the note: no line
+    ({"step": 2, "embed_grad": "positions"},
+     "dense-gradient step: table gradient from positions"),
+], ids=["predates-the-note", "positions"])
+def test_report_says_how_a_dense_step_made_its_table_gradient(
+        args, says, tmp_path, capsys):
+    """A trace from before ``embed_grad`` existed reads as it did; a step
+    that leaves its tables to AD (a history model, hashed tables) gets the
+    line that says so."""
+    import json
+    report = _report()
+    events = [{"name": "train.log_sync", "ph": "X", "ts": 0, "dur": 5,
+               "pid": 1, "tid": 1, "args": args}]
+    assert report.table_gradient(events) == args.get("embed_grad")
+    assert report.row_updates(events) is None
+    path = str(tmp_path / "trace.json")
+    with open(path, "w") as f:
+        json.dump({"traceEvents": events}, f)
+    assert report.main([path]) == 0
+    out = capsys.readouterr().out
+    assert "table gradient" in out if says else "table gradient" not in out
+    if says:
+        assert says in out
 
 
 def _sdar_fit(n_steps=4):
