@@ -173,39 +173,44 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
             np.asarray(g), np.asarray(t.at[uids].set(r, mode="drop")))
     out["put_rows_slots_written"] = real
 
-    # block-masked attention (the SDAR decoder's masked scores): the
-    # kernel, forward and both backward kernels, against the chunked XLA
-    # path on one key/value head's four query heads of 128, 1,024 positions
-    # under the block-diffusion mask, bfloat16 operands.
-    from deepfm_tpu.models import sdar_moe
+    # block-masked attention (the decoders' masked scores): the kernel,
+    # forward and both backward kernels, against the chunked XLA path on one
+    # key/value head's query heads of 128 over 1,024 positions, bfloat16
+    # operands: four heads under SDAR's block-diffusion mask, eight under
+    # Solar-Open2's causal one.
+    from deepfm_tpu.models import kimi_linear, sdar_moe
 
     length, cdt = 512, jnp.dtype(jnp.bfloat16)
-    q, key, val = (jnp.asarray(rng.normal(size=shape_), jnp.float32)
-                   for shape_ in ((1, 2 * length, 1, 4, 128),
-                                  (1, 2 * length, 1, 128),
-                                  (1, 2 * length, 1, 128)))
-    key, val = key.astype(cdt), val.astype(cdt)
-    weight = jnp.asarray(rng.normal(size=(1, 2 * length, 512)), jnp.float32)
+    errs = []
+    for heads, mask in ((4, sdar_moe.block_diffusion(length, 4)),
+                        (8, kimi_linear.causal)):
+        q, key, val = (jnp.asarray(rng.normal(size=shape_), jnp.float32)
+                       for shape_ in ((1, 2 * length, 1, heads, 128),
+                                      (1, 2 * length, 1, 128),
+                                      (1, 2 * length, 1, 128)))
+        key, val = key.astype(cdt), val.astype(cdt)
+        weight = jnp.asarray(rng.normal(size=(1, 2 * length, heads * 128)),
+                             jnp.float32)
 
-    def by_kernel(q, k, v):
-        return sdar_moe._scores_kernel(
-            (q / np.sqrt(128.0)).astype(cdt), k, v, length=length, block=4,
-            interpret=interpret)
+        def by_kernel(q, k, v):
+            return sdar_moe._scores_kernel(
+                (q / np.sqrt(128.0)).astype(cdt), k, v, mask=mask,
+                interpret=interpret)
 
-    def by_xla(q, k, v):
-        return sdar_moe._scores_xla(q.astype(cdt), k, v, length=length,
-                                    block=4, cdt=cdt)
+        def by_xla(q, k, v):
+            return sdar_moe._scores_xla(q.astype(cdt), k, v, mask=mask,
+                                        cdt=cdt)
 
-    def out_and_grads(f):
-        def loss(q, k, v):
-            o = f(q, k, v).astype(jnp.float32)
-            return jnp.sum(o * weight), o
-        (_, o), g = jax.jit(jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True))(q, key, val)
-        return (o, *g)
+        def out_and_grads(f):
+            def loss(q, k, v):
+                o = f(q, k, v).astype(jnp.float32)
+                return jnp.sum(o * weight), o
+            (_, o), g = jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True))(q, key, val)
+            return (o, *g)
 
-    errs = [max_rel(g, w) for g, w in zip(out_and_grads(by_kernel),
-                                          out_and_grads(by_xla))]
+        errs += [max_rel(g, w) for g, w in zip(out_and_grads(by_kernel),
+                                               out_and_grads(by_xla))]
     assert max(errs) <= 2.0 ** -5, f"block attention vs XLA: {errs}"
     out["block_attention_max_rel_err"] = max(errs)
 

@@ -49,7 +49,7 @@ class Config:
     coordinator_address: str = ""     # jax.distributed coordinator (host:port)
 
     # ---- model hyperparameters (reference: model flags) ----
-    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear
+    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2
     feature_size: int = 117581        # vocabulary size (reference ipynb:85)
     field_size: int = 39              # number of fields (reference ipynb:90)
     embedding_size: int = 32          # latent dim (reference flag default, ...py:44)
@@ -70,8 +70,9 @@ class Config:
     # told what it holds: attn_q_heads/attn_kv_heads are the heads here,
     # moe_experts_held experts from moe_first_expert on of the moe_experts
     # the router scores (moe_top_k a token). The sorted (position, expert)
-    # pairs on held experts fill a buffer of moe_pair_capacity rows a layer;
-    # pairs beyond it are counted, never dropped in silence.
+    # pairs on held experts fill a buffer of moe_pair_capacity rows a layer
+    # (at least: sdar_moe.pass_rows makes 256 rows or more up to whole
+    # multiples of 256); pairs beyond it are counted, never dropped in silence.
     decoder_layers: int = 0
     attn_q_heads: int = 0
     attn_kv_heads: int = 0
@@ -98,6 +99,12 @@ class Config:
     # through a dense MLP of dense_mlp_width, the others through the expert
     # layer beside a shared expert of moe_shared_width; the router's
     # renormalised sigmoid scores are scaled by moe_route_scale.
+    # solar_open2 (models/solar_open2.py) is told by the same flags and no
+    # other: layer i (from 0) mixes by gated grouped-query attention where
+    # attn_every divides i (attn_q_heads query heads on attn_kv_heads
+    # key/value heads of attn_head_dim, no positions) and by KDA with a write
+    # strength to 2 elsewhere; every layer has experts and a shared expert
+    # (no dense layer, no latent: dense_* and mla_* stay 0).
     kda_heads: int = 0
     kda_head_dim: int = 128
     kda_conv: int = 4
@@ -475,25 +482,31 @@ class Config:
             raise ValueError("metrics_snapshot_secs must be >= 0")
         if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm",
                               "dlrm_dcnv2", "din", "bst", "sdar_moe",
-                              "kimi_linear"):
+                              "kimi_linear", "solar_open2"):
             raise ValueError(f"unknown model: {self.model!r}")
         if self.model == "sdar_moe":
             self._validate_sdar_moe()
         elif self.model == "kimi_linear":
             self._validate_kimi_linear()
+        elif self.model == "solar_open2":
+            self._validate_solar_open2()
         elif self.decoder_layers or self.moe_experts or self.attn_q_heads:
             raise ValueError(
-                "decoder_layers/attn_*/moe_* belong to --model sdar_moe and "
-                f"kimi_linear; {self.model!r} has no decoder block")
-        if self.model != "kimi_linear" and (
-                self.kda_heads or self.attn_every or self.mla_latent_dim
-                or self.mla_rope_dim or self.dense_layers
-                or self.dense_mlp_width or self.moe_shared_width
-                or self.moe_route_scale != 1.0):
+                "decoder_layers/attn_*/moe_* belong to --model sdar_moe, "
+                f"kimi_linear and solar_open2; {self.model!r} has no "
+                "decoder block")
+        recurrent = self.model in ("kimi_linear", "solar_open2")
+        if (not recurrent and (
+                self.kda_heads or self.attn_every or self.moe_shared_width
+                or self.moe_route_scale != 1.0)) or (
+                    self.model != "kimi_linear" and (
+                        self.mla_latent_dim or self.mla_rope_dim
+                        or self.dense_layers or self.dense_mlp_width)):
             raise ValueError(
                 "kda_heads/attn_every/mla_*/dense_layers/dense_mlp_width/"
                 "moe_shared_width/moe_route_scale belong to --model "
-                f"kimi_linear; {self.model!r} has none of these layers")
+                "kimi_linear (solar_open2 takes those that are not mla_* or "
+                f"dense_*); {self.model!r} has none of these layers")
         if self.model == "dlrm_dcnv2":
             self._validate_dlrm_dcnv2()
         elif self.numeric_fields or self.bottom_layers:
@@ -964,6 +977,11 @@ class Config:
         for what, ok in need.items():
             if not ok:
                 raise ValueError(f"model kimi_linear needs {what}")
+        self._refuse_for_a_decoder("kimi_linear")
+
+    def _refuse_for_a_decoder(self, model: str) -> None:
+        """What neither recurrent decoder (kimi_linear, solar_open2)
+        takes."""
         refused = {
             "tasks (the loss is over the positions of a sequence, one task)":
                 self.num_tasks > 1,
@@ -988,7 +1006,46 @@ class Config:
         }
         for what, set_ in refused.items():
             if set_:
-                raise ValueError(f"model kimi_linear does not take {what}")
+                raise ValueError(f"model {model} does not take {what}")
+
+    def _validate_solar_open2(self) -> None:
+        """What the gated-GQA / KDA MoE decoder takes, and plainly what it
+        does not (models.solar_open2.SolarOpen2)."""
+        need = {
+            "decoder_layers >= 1": self.decoder_layers >= 1,
+            "attn_every >= 1 (layer i, from 0, mixes by gated grouped-query "
+            "attention where it divides i, by KDA elsewhere)":
+                self.attn_every >= 1,
+            "kda_heads >= 1 of kda_head_dim >= 1 and kda_conv >= 1 where a "
+            "layer is KDA": min(self.attn_every, self.decoder_layers) == 1
+                or (self.kda_heads >= 1 and self.kda_head_dim >= 1
+                    and self.kda_conv >= 1),
+            "attn_q_heads a positive multiple of attn_kv_heads >= 1, of "
+            "attn_head_dim >= 1": self.attn_kv_heads >= 1
+                and self.attn_q_heads >= 1
+                and self.attn_q_heads % self.attn_kv_heads == 0
+                and self.attn_head_dim >= 1,
+            "1 <= moe_top_k <= moe_experts, moe_expert_width >= 1, "
+            "moe_shared_width >= 1 and moe_route_scale > 0":
+                1 <= self.moe_top_k <= self.moe_experts
+                and self.moe_expert_width >= 1 and self.moe_shared_width >= 1
+                and self.moe_route_scale > 0,
+            "moe_experts_held >= 1 experts from moe_first_expert on, all "
+            "among the moe_experts": self.moe_experts_held >= 1
+                and self.moe_first_expert >= 0
+                and self.moe_first_expert + self.moe_experts_held
+                <= self.moe_experts,
+            "moe_pair_capacity >= 1 (rows of a layer's pair buffer; every "
+            "pair of a step is batch_size * history_max_len * moe_top_k)":
+                self.moe_pair_capacity >= 1,
+            "history_max_len >= 2 (the sequence length; the loss is of the "
+            "next token)": self.history_max_len >= 2,
+            "feature_size >= 2": self.feature_size >= 2,
+        }
+        for what, ok in need.items():
+            if not ok:
+                raise ValueError(f"model solar_open2 needs {what}")
+        self._refuse_for_a_decoder("solar_open2")
 
     # ---- derived views ------------------------------------------------
     @property

@@ -4,7 +4,7 @@
 (``--tasks ctr,cvr``) builds the multi-task model (``--multitask``
 architecture over the shared graph bottom); otherwise ``cfg.model`` picks a
 single-task graph from the registry, or one of the decoders (``sdar_moe``,
-``kimi_linear``), which are no rankers.
+``kimi_linear``, ``solar_open2``), which are no rankers.
 """
 
 from typing import Union
@@ -18,6 +18,7 @@ from .kimi_linear import KimiLinear
 from .multitask import MultiTaskModel  # noqa: F401
 from .sdar_moe import SdarMoE
 from .sequence import GraphBST, GraphDIN  # noqa: F401
+from .solar_open2 import SolarOpen2
 
 _REGISTRY = {
     "deepfm": DeepFM,
@@ -29,10 +30,11 @@ _REGISTRY = {
     "bst": GraphBST,
     "sdar_moe": SdarMoE,
     "kimi_linear": KimiLinear,
+    "solar_open2": SolarOpen2,
 }
 
 CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDLRMDCNv2, GraphDIN,
-                 GraphBST, SdarMoE, KimiLinear, MultiTaskModel]
+                 GraphBST, SdarMoE, KimiLinear, SolarOpen2, MultiTaskModel]
 
 
 def registered_models():
@@ -47,7 +49,6 @@ def registered_models():
 def get_model(cfg: Config) -> CtrModel:
     if cfg.num_tasks > 1:
         return MultiTaskModel(cfg)
-    try:
-        return _REGISTRY[cfg.model](cfg)
-    except KeyError:
+    if cfg.model not in _REGISTRY:     # (not a constructor's own KeyError)
         raise ValueError(f"unknown model {cfg.model!r}; have {sorted(_REGISTRY)}")
+    return _REGISTRY[cfg.model](cfg)

@@ -12,7 +12,8 @@ renormalised and scaled) beside a shared expert every token passes.
 ``benchmark/reference_kimi_linear.py`` holds the equations; this is the
 program's form of them. The expert layer, the router, the chunked attention,
 the head's loss and the rounding of operands are ``models.sdar_moe``'s, by
-import.
+import; ``models.solar_open2`` is this stack with another full layer and a
+write strength to 2 (``kda_mixer``'s ``beta_scale``), by inheritance.
 
 What it reads of a batch: ``hist_ids`` [B, L], the sequence's tokens, which
 ride the record's history list (``--history_max_len L``); the token table is
@@ -63,13 +64,16 @@ import jax.numpy as jnp
 
 from . import common
 from .graph import GraphModel
-from .sdar_moe import (_dot, _operand, _scores_xla, expert_layer, moe_notes,
-                       moe_rows_by, rms_norm, route, weighted_nll)
+from .sdar_moe import (ScoreMask, _dot, _operand, _scores_xla, expert_layer,
+                       moe_notes, moe_rows_by, rms_norm, route, weighted_nll)
 
 #: The step's counts, in the model state and (by ``step_counts``) the metrics.
 COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
                "moe_expert_load_max", "moe_layer_pairs_max")
 DECAY_MIN = "kda_chunk_log_decay_min"
+#: Positions x heads of a step whose write strength passed 1 (the transition's
+#: eigenvalue along k negative): counted where the model's strength can.
+BETA_OVER_ONE = "kda_beta_over_one"
 #: Positions a chunk of the delta-rule scan holds, and a sub-chunk inside
 #: which decays are taken pair by pair.
 KDA_CHUNK = 64
@@ -195,12 +199,16 @@ def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
 
 @jax.named_scope("kda")
 def kda_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
-              eps: float, cdt: jnp.dtype
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              eps: float, cdt: jnp.dtype, beta_scale: float = 1.0
+              ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The held heads' part of ``KDA(RMSNorm(x))``: x [B, S, d] ->
-    ([B, S, d] (``kda_wo``'s sum over the held heads, unreduced), the scan's
-    most negative chunk log-decay). ``lp['kda_a_log']`` [H] says how many
-    heads are held."""
+    ([B, S, d] (``kda_wo``'s sum over the held heads, unreduced), the
+    mixer's counts: the scan's most negative chunk log-decay and, where the
+    write strength can pass 1, how often it did). ``lp['kda_a_log']`` [H]
+    says how many heads are held. The write strength is ``beta_scale *
+    sigmoid(xn kda_w_b)``: 1 keeps the transition ``I - beta k k^T``'s
+    eigenvalue along k in (0, 1) (Kimi-Linear), 2 lets it reach -1
+    (``models.solar_open2``: ``kda_allow_neg_eigval``)."""
     b, s, _ = x.shape
     xn = rms_norm(x, lp["norm1"], eps)
 
@@ -221,15 +229,20 @@ def kda_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
         _dot(_dot(xn, lp["kda_w_fa"], cdt), lp["kda_w_fb"], cdt)
         + lp["kda_dt_bias"]))
     beta = jax.nn.sigmoid(_dot(xn, lp["kda_w_b"], cdt))
-    o, low = kda_scan(q, k, v, g, beta, cdt=cdt)
+    counts = {}
+    if beta_scale != 1.0:
+        beta = beta_scale * beta
+        counts[BETA_OVER_ONE] = jnp.sum(
+            jax.lax.stop_gradient(beta) > 1.0, dtype=jnp.int32)
+    o, counts[DECAY_MIN] = kda_scan(q, k, v, g, beta, cdt=cdt)
     gate = jax.nn.sigmoid(heads(
         _dot(_dot(xn, lp["kda_w_ga"], cdt), lp["kda_w_gb"], cdt)))
     y = rms_norm(o, lp["kda_out_norm"], eps) * gate
-    return _dot(y.reshape(b, s, -1), lp["kda_wo"], cdt), low
+    return _dot(y.reshape(b, s, -1), lp["kda_wo"], cdt), counts
 
 
-def causal(q_index: jnp.ndarray, k_index: jnp.ndarray) -> jnp.ndarray:
-    return k_index[None, :] <= q_index[:, None]
+#: A query reads the keys at and before its own position.
+causal = ScoreMask(("causal",), lambda q, k: k <= q)
 
 
 @jax.named_scope("attn")
@@ -287,7 +300,7 @@ class KimiLinear(GraphModel):
         #: ``train.log_sync`` while tracing is on (``hidden`` adds the expert
         #: layers' ``sdar_moe.moe_notes``).
         self.step_notes: Dict[str, str] = {
-            "kda_scan": f"chunk{KDA_CHUNK}/sub{KDA_SUB}", "mla_scores": "xla"}
+            "kda_scan": f"chunk{KDA_CHUNK}/sub{KDA_SUB}"}
         self.route_by = functools.partial(
             route, score=jax.nn.sigmoid, scale=cfg.moe_route_scale)
 
@@ -303,25 +316,17 @@ class KimiLinear(GraphModel):
         """The counts a step's metrics carry beside its loss."""
         return dict(model_state)
 
-    def _init_layer(self, rng: jax.Array, mixer: str, ffn: str
-                    ) -> Dict[str, jnp.ndarray]:
+    def _init_mixer(self, mixer: str, glorot, keys) -> Dict[str, jnp.ndarray]:
+        """The leaves of a mixer of kind ``mixer``: ``glorot(*shape)`` draws
+        a matrix, ``keys`` yields further keys."""
         cfg = self.cfg
         d, hd = cfg.embedding_size, cfg.attn_head_dim
-        keys = iter(jax.random.split(rng, 24))
-
-        def glorot(*shape):
-            return common.glorot_uniform(next(keys), shape)
-
-        def ones(*shape):
-            return jnp.ones(shape, jnp.float32)
-
-        lp = {"norm1": ones(d), "norm2": ones(d)}
         if mixer == "kda":
             h, kd = cfg.kda_heads, cfg.kda_head_dim
             step = jnp.exp(jax.random.uniform(
                 next(keys), (h * kd,), jnp.float32,
                 jnp.log(1e-3), jnp.log(1e-1)))
-            lp.update({
+            return {
                 "kda_wq": glorot(d, h * kd), "kda_wk": glorot(d, h * kd),
                 "kda_wv": glorot(d, h * kd),
                 "kda_conv_q": glorot(cfg.kda_conv, h * kd),
@@ -335,16 +340,29 @@ class KimiLinear(GraphModel):
                     next(keys), (h,), jnp.float32, 1.0, 16.0)),
                 "kda_w_b": glorot(d, h),
                 "kda_w_ga": glorot(d, kd), "kda_w_gb": glorot(kd, h * kd),
-                "kda_out_norm": ones(kd), "kda_wo": glorot(h * kd, d)})
-        else:
-            h, rope, latent = (cfg.attn_q_heads, cfg.mla_rope_dim,
-                               cfg.mla_latent_dim)
-            lp.update({
-                "mla_wq": glorot(d, h * (hd + rope)),
-                "mla_w_kva": glorot(d, latent + rope),
-                "mla_kv_norm": ones(latent),
-                "mla_w_kvb": glorot(latent, h * 2 * hd),
-                "mla_wo": glorot(h * hd, d)})
+                "kda_out_norm": jnp.ones((kd,), jnp.float32),
+                "kda_wo": glorot(h * kd, d)}
+        h, rope, latent = (cfg.attn_q_heads, cfg.mla_rope_dim,
+                           cfg.mla_latent_dim)
+        return {
+            "mla_wq": glorot(d, h * (hd + rope)),
+            "mla_w_kva": glorot(d, latent + rope),
+            "mla_kv_norm": jnp.ones((latent,), jnp.float32),
+            "mla_w_kvb": glorot(latent, h * 2 * hd),
+            "mla_wo": glorot(h * hd, d)}
+
+    def _init_layer(self, rng: jax.Array, mixer: str, ffn: str
+                    ) -> Dict[str, jnp.ndarray]:
+        cfg = self.cfg
+        d = cfg.embedding_size
+        keys = iter(jax.random.split(rng, 24))
+
+        def glorot(*shape):
+            return common.glorot_uniform(next(keys), shape)
+
+        lp = {"norm1": jnp.ones((d,), jnp.float32),
+              "norm2": jnp.ones((d,), jnp.float32),
+              **self._init_mixer(mixer, glorot, keys)}
         if ffn == "mlp":
             f = cfg.dense_mlp_width
             lp.update({"mlp_w_gate": glorot(d, f), "mlp_w_up": glorot(d, f),
@@ -373,21 +391,47 @@ class KimiLinear(GraphModel):
         }
         return params, self.init_counts()
 
+    def _rows_by(self, ids: jnp.ndarray, one_device: bool) -> str:
+        """``moe_rows_by``'s word for the step of ``ids`` [B, L];
+        ``step_notes`` is told."""
+        cfg = self.cfg
+        rows_by = moe_rows_by(cfg.embedding_size, ids.size,
+                              cfg.moe_pair_capacity, one_device=one_device)
+        self.step_notes.update(moe_notes(
+            rows_by, cfg.moe_pair_capacity,
+            sum(ffn == "moe" for _, ffn in self.kinds)))
+        return rows_by
+
+    def _paths(self, ids: jnp.ndarray, one_device: bool) -> Dict[str, str]:
+        """What the step of ``ids`` [B, L] is made of where the code picks
+        from what it can see, as ``_layer``'s keywords (``rows_by``; a
+        model's further ones go to its ``_mixer``); ``step_notes`` is told."""
+        self.step_notes["mla_scores"] = "xla"
+        return {"rows_by": self._rows_by(ids, one_device)}
+
+    def _mixer(self, mixer: str, lp: Dict[str, jnp.ndarray], x: jnp.ndarray
+               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+        """``Mixer(RMSNorm(x))`` of a layer of kind ``mixer`` -> (the held
+        heads' part, the mixer's counts)."""
+        cfg = self.cfg
+        if mixer == "kda":
+            return kda_mixer(lp, x, head_dim=cfg.kda_head_dim,
+                             eps=cfg.rms_norm_eps, cdt=self.cdt)
+        return mla_mixer(lp, x, head_dim=cfg.attn_head_dim,
+                         rope_dim=cfg.mla_rope_dim, eps=cfg.rms_norm_eps,
+                         cdt=self.cdt), {}
+
     def _layer(self, mixer: str, ffn: str, x: jnp.ndarray,
-               lp: Dict[str, jnp.ndarray], rows_by: str = "xla"
+               lp: Dict[str, jnp.ndarray], rows_by: str = "xla", **paths
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """One block: ``h = x + Mixer(RMSNorm(x))``,
-        ``h + FFN(RMSNorm(h))`` -> (the stream, the layer's counts)."""
+        ``h + FFN(RMSNorm(h))`` -> (the stream, the layer's counts).
+        ``paths`` is what ``_paths`` says beyond ``rows_by``, the mixer's."""
         cfg = self.cfg
         # (the barrier: ``models.sdar_moe.SdarMoE.hidden``)
         lp = jax.lax.optimization_barrier(lp)
-        eps, counts = cfg.rms_norm_eps, {}
-        if mixer == "kda":
-            y, counts[DECAY_MIN] = kda_mixer(
-                lp, x, head_dim=cfg.kda_head_dim, eps=eps, cdt=self.cdt)
-        else:
-            y = mla_mixer(lp, x, head_dim=cfg.attn_head_dim,
-                          rope_dim=cfg.mla_rope_dim, eps=eps, cdt=self.cdt)
+        eps = cfg.rms_norm_eps
+        y, counts = self._mixer(mixer, lp, x, **paths)
         h = x + y
         if ffn == "mlp":
             return h + swiglu(lp, "mlp_", h, eps=eps, cdt=self.cdt), counts
@@ -407,19 +451,13 @@ class KimiLinear(GraphModel):
         """ids [B, L] -> (the last residual stream [B, L, d], the layers'
         counts: sums, the ``_max`` ones' largest, the decay's least).
         ``data_axis`` names the mesh axis of a step across data replicas."""
-        cfg = self.cfg
-        rows_by = moe_rows_by(cfg.embedding_size, ids.size,
-                              cfg.moe_pair_capacity,
-                              one_device=data_axis is None)
-        self.step_notes.update(moe_notes(
-            rows_by, cfg.moe_pair_capacity,
-            sum(ffn == "moe" for _, ffn in self.kinds)))
+        paths = self._paths(ids, one_device=data_axis is None)
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
         seen: Dict[str, list] = {}
         for i, kind in enumerate(self.kinds):
             x, counts = jax.checkpoint(functools.partial(
-                self._layer, *kind, rows_by=rows_by))(
+                self._layer, *kind, **paths))(
                     x, params["layers"][str(i)])
             for name, value in counts.items():
                 seen.setdefault(name, []).append(value)

@@ -31,48 +31,52 @@ nothing, and the partial sums of ``wo`` and of the experts go on to the next
 layer unreduced: on one chip the layer runs without its exchange, and no
 code stands in for the absent chips.
 
-**The expert layer.** The pairs that land on held experts are sorted by
-expert into a buffer of ``--moe_pair_capacity`` rows (static shapes), which
-is computed in equal passes of at most ``PASS_ROWS`` rows (one pass's
-memory): the three products run as grouped products over the held experts'
-(``jax.lax.ragged_dot``), and the rows are weighted and added back to their
-positions. The sorted pairs put the held experts' first, so a pass's real
-rows are a prefix of it; the buffer's spare rows are computed as zeros (they
-ride the products' last group: their inputs have to be zeros and not
-whatever lay there, or a stray NaN times a zero cotangent would reach a
-weight's gradient), so the products cost the same whatever the routing.
-What moves a pass's rows from and to their positions is picked from what
-the code can see (``moe_rows_by``: backend, the row's width, whole tiles of
-positions and of buffer rows, one device's program; no flag): on a TPU at a
-row of whole 128-lane lines the two kernels of ``ops/pallas_moe_rows``, one
-DMA a row over the prefix only — ``gather`` (the rows of RMSNorm(x), cast to
-the products' type on their way; in the backward pass their cotangent summed
-into the positions', float32, in the pass loop's carry) and ``combine`` (a
-pass's weighted rows added into the layer's sum in place, a group's
-positions at a time; backward, the sum's cotangent taken to the rows) —
-and everywhere else (a CPU, the tests' narrow rows, a step across data
-replicas) ``jnp.take`` and ``.at[].add`` over every row of the buffer,
-spare ones masked. No pair is dropped silently: pairs beyond the
-buffer's rows are counted
-(``moe_pairs_over_buffer``, cumulative), with the pairs held, the fullest
-expert's count, the fullest layer's pairs (what the buffer has to hold) and
-the masked positions of the last step; the counts ride
-the model state and the step's metrics.
+**The expert layer.** The pairs that land on held experts are sorted by expert
+into a buffer of ``--moe_pair_capacity`` rows (static shapes; made up to whole
+``PRODUCT_TILE_ROWS``, ``pass_rows``), which is computed in equal passes of at
+most ``PASS_ROWS`` rows (one pass's memory): the three products run as grouped
+products over the held experts' (``jax.lax.ragged_dot``), and the rows are
+weighted and added back to their positions. The sorted pairs put the held
+experts' first, so a pass's real rows are a prefix of it; the buffer's spare
+rows are computed as zeros (they ride the products' last group: their inputs
+have to be zeros and not whatever lay there, or a stray NaN times a zero
+cotangent would reach a weight's gradient), so the products cost the same
+whatever the routing. What moves a pass's rows from and to their positions is
+picked from what the code can see (``moe_rows_by``: backend, the row's width,
+whole tiles of positions and of buffer rows, one device's program; no flag):
+on a TPU at a row of whole 128-lane lines the two kernels of
+``ops/pallas_moe_rows``, one DMA a row over the prefix only — ``gather`` (the
+rows of RMSNorm(x), cast to the products' type on their way; in the backward
+pass their cotangent summed into the positions', float32, in the pass loop's
+carry) and ``combine`` (a pass's weighted rows added into the layer's sum in
+place, a group's positions at a time; backward, the sum's cotangent taken to
+the rows) — and everywhere else (a CPU, the tests' narrow rows, a step across
+data replicas) ``jnp.take`` and ``.at[].add`` over every row of the buffer,
+spare ones masked. No pair is dropped silently: pairs beyond the buffer's rows
+are counted (``moe_pairs_over_buffer``, cumulative), with the pairs held, the
+fullest expert's count, the fullest layer's pairs (what the buffer has to
+hold) and the masked positions of the last step; the counts ride the model
+state and the step's metrics.
 
-**The masked scores.** Between the rotated, rounded q/k/v and ``wo`` the
-block computes softmax(mask(q k^T / sqrt(D))) v in one of two ways, picked
-from what the code can see (``attn_scores_by``: backend, head_dim, the
-sequence against the kernel's block, one device's program; no flag). On a
+**The masked scores** (``masked_scores``). Between the rotated q/k/v and
+``wo`` the block computes softmax(mask(q k^T / sqrt(D))) v in one of two
+ways, picked from what the code can see (``attn_scores_by``: backend,
+head_dim, the sequence against the kernel's block, one device's program; no
+flag). The mask is a ``ScoreMask``: its pair function and the key that names
+it, this model's ``block_diffusion(length, block)``, another model's its own
+(``models.kimi_linear.causal``, which ``models.solar_open2`` runs through
+the same two paths). On a
 TPU at a head_dim of whole 128-lane lines it is one Pallas flash-attention
 call (``ops/block_attention``, the kernel JAX ships) whose grid visits only
-the blocks of the score matrix in which ``allowed_pairs`` is true anywhere
-(80 of 256 blocks of 512 at L = 4,096): a block's scores live and die in
+the blocks of the score matrix in which the mask is true anywhere
+(80 of 256 blocks of 512 at L = 4,096 under ``allowed_pairs``, 136 of 256
+at 8,192 positions under the causal mask): a block's scores live and die in
 VMEM, forward and backward, float32 scores, max, sum and accumulators on
 operands of the compute precision. Everywhere else (a CPU, the tests' small
 shapes, a step across data replicas) it is XLA ops, a chunk of
 ``QUERY_CHUNK`` queries against every key at a time, the mask applied to
-scores computed whole. ``allowed_pairs`` is the one statement of the mask
-both read.
+scores computed whole. The mask's pair function (here ``allowed_pairs``) is
+the one statement of it both read.
 
 Memory: each layer is recomputed in the backward pass (``jax.checkpoint``
 around the scanned layer). No [S, S] score matrix is ever held: the kernel
@@ -84,9 +88,10 @@ not outlive the chunk.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Hashable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -115,6 +120,13 @@ ATTN_BLOCK = 512
 #: its rows' inputs, both hidden products and the output (15 KB a row at the
 #: published widths), and is made again in the backward pass.
 PASS_ROWS = 20480
+#: Rows a pass is made whole multiples of, where it has that many: the tile
+#: of XLA:TPU's grouped product. ``jax.lax.ragged_dot`` over a buffer that is
+#: no multiple of 128 rows took 5.7 x as long (3,280 rows against 3,328:
+#: 4.4 / 12 ms forward / with both gradients against 0.76 / 2.55), and over a
+#: multiple of 128 that is none of 256 about 1.5 x (4,992 against 5,120); the
+#: rows added are spare rows (zeros), which cost 0.4 us each (PERF.md, PR 37).
+PRODUCT_TILE_ROWS = 256
 
 
 def rms_norm(x: jnp.ndarray, gain: jnp.ndarray, eps: float) -> jnp.ndarray:
@@ -157,6 +169,27 @@ def allowed(q_index: jnp.ndarray, k_index: jnp.ndarray, length: int,
     return allowed_pairs(q_index[:, None], k_index[None, :], length, block)
 
 
+@dataclasses.dataclass(frozen=True)
+class ScoreMask:
+    """Which keys a query may read, stated once for both score paths:
+    ``pairs(q, k) -> bool`` on integer index arrays that broadcast against
+    each other (NumPy's or ``jnp``'s), and ``key``, which says which mask it
+    is: two masks of one key are the same mask (the kernel's tables are
+    built and kept by it, ``attn_kernel``). Called with ``q_index`` [Q] and
+    ``k_index`` [K] it is the bool [Q, K] the XLA path applies."""
+    key: Hashable
+    pairs: Callable = dataclasses.field(compare=False)
+
+    def __call__(self, q_index: jnp.ndarray, k_index: jnp.ndarray):
+        return self.pairs(q_index[:, None], k_index[None, :])
+
+
+def block_diffusion(length: int, block: int) -> ScoreMask:
+    """``allowed_pairs`` at ``length`` and ``block`` as a ``ScoreMask``."""
+    return ScoreMask(("block_diffusion", length, block), functools.partial(
+        allowed_pairs, length=length, block=block))
+
+
 def draw_noise(key: jax.Array, tokens: jnp.ndarray, *, block: int,
                t_min: float, mask_id: int
                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -188,20 +221,17 @@ def _dot(x: jnp.ndarray, w: jnp.ndarray, cdt: jnp.dtype) -> jnp.ndarray:
 
 
 def _scores_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                cdt: jnp.dtype, length: int = 0, block: int = 0,
-                mask: Optional[Callable] = None) -> jnp.ndarray:
+                cdt: jnp.dtype, mask: ScoreMask) -> jnp.ndarray:
     """softmax(mask(q k^T / sqrt(D))) v as XLA ops, a chunk of queries at a
     time, each chunk made again in the backward pass: q [B, S, n_kv, G, D],
     k [B, S, n_kv, D] and v [B, S, n_kv, Dv] (operands) ->
     [B, S, n_kv * G * Dv] float32. ``mask(q_index [Q], k_index [K])`` says
-    which keys a query reads: the block-diffusion mask of ``length`` and
-    ``block`` unless another is handed in (``models.kimi_linear``: causal)."""
+    which keys a query reads (a ``ScoreMask``: this model's
+    ``block_diffusion``, ``models.kimi_linear.causal``)."""
     b, s, n_kv, group, head_dim = q.shape
     chunk = QUERY_CHUNK if s % QUERY_CHUNK == 0 else s
     scale = 1.0 / math.sqrt(head_dim)
     k_index = jnp.arange(s)
-    if mask is None:
-        mask = functools.partial(allowed, length=length, block=block)
 
     @jax.checkpoint
     def one_chunk(args):
@@ -225,37 +255,50 @@ def _scores_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 
 @functools.lru_cache(maxsize=None)
-def attn_kernel(seq: int, length: int, block: int, heads: int,
+def attn_kernel(seq: int, mask: ScoreMask, heads: int,
                 interpret: bool = False, kernel_block: int = ATTN_BLOCK):
-    """The block-masked attention kernel of one key/value head's ``heads``
-    query heads under ``allowed_pairs`` (``ops/block_attention``), built
-    once a shape: finding the non-empty blocks takes half a second at
-    S = 8,192 and the step is traced more than once a run."""
+    """The masked attention kernel of one key/value head's ``heads`` query
+    heads over ``seq`` positions under ``mask`` (``ops/block_attention``),
+    built once a (mask key, shape) and kept: finding the non-empty blocks
+    takes half a second at S = 8,192 and the step is traced more than once a
+    run."""
     with jax.ensure_compile_time_eval():
         return block_attention.make_kernel(
-            functools.partial(allowed_pairs, length=length, block=block),
-            ("block_diffusion", length, block), seq=seq, heads=heads,
-            block=kernel_block, interpret=interpret)
+            mask.pairs, mask.key, seq=seq, heads=heads, block=kernel_block,
+            interpret=interpret)
 
 
 def _scores_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                   length: int, block: int, interpret: bool = False,
+                   mask: ScoreMask, interpret: bool = False,
                    kernel_block: int = ATTN_BLOCK) -> jnp.ndarray:
-    """``_scores_xla``'s result from the kernel, which visits only the blocks
-    of the score matrix the mask leaves something in and keeps a block's
-    scores in VMEM, forward and backward. q arrives scaled by
-    ``1/sqrt(D)``; the result is in the operands' type."""
+    """``_scores_xla``'s result under ``mask`` from the kernel, which visits
+    only the blocks of the score matrix the mask leaves something in and
+    keeps a block's scores in VMEM, forward and backward. q arrives scaled
+    by ``1/sqrt(D)``; the result is in the operands' type."""
     b, s, n_kv, group, head_dim = q.shape
-    kernel = attn_kernel(s, length, block, group, interpret, kernel_block)
+    kernel = attn_kernel(s, mask, group, interpret, kernel_block)
     heads_first = jax.vmap(jax.vmap(kernel))(      # over B and n_kv
         jnp.transpose(q, (0, 2, 3, 1, 4)),          # [B, n_kv, G, S, D]
         jnp.transpose(k, (0, 2, 1, 3)), jnp.transpose(v, (0, 2, 1, 3)))
     return jnp.transpose(heads_first, (0, 3, 1, 2, 4)).reshape(b, s, -1)
 
 
+def attn_notes(scores_by: str, mask: ScoreMask, seq: int, group: int
+               ) -> Dict[str, str]:
+    """What ``step_notes`` says of the masked scores: ``attn_scores``
+    (``kernel`` / ``xla``) and, of the kernel, ``attn_score_blocks`` (blocks
+    of the score matrix the forward pass computes / all of them, a head)."""
+    if scores_by != "kernel":           # every score of every chunk
+        return {"attn_scores": scores_by}
+    visited, total = block_attention.visited_blocks(
+        attn_kernel(seq, mask, group), seq, ATTN_BLOCK)
+    return {"attn_scores": scores_by,
+            "attn_score_blocks": f"{visited}/{total}"}
+
+
 def attn_scores_by(seq: int, head_dim: int, *, one_device: bool = True,
                    backend: Optional[str] = None) -> str:
-    """``kernel`` where the block-masked attention kernel applies (a TPU
+    """``kernel`` where the masked attention kernel applies (a TPU
     backend, ``head_dim`` whole 128-lane lines, a sequence its block
     divides: ``ops/block_attention.supported``; and a step that is one
     device's program: the shipped kernel does not say how its results vary
@@ -265,6 +308,25 @@ def attn_scores_by(seq: int, head_dim: int, *, one_device: bool = True,
     backend = jax.default_backend() if backend is None else backend
     return ("kernel" if one_device and block_attention.supported(
         backend, seq, head_dim, ATTN_BLOCK) else "xla")
+
+
+def masked_scores(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
+                  mask: ScoreMask, cdt: jnp.dtype, scores_by: str = "xla"
+                  ) -> jnp.ndarray:
+    """softmax(mask(q k^T / sqrt(D))) v by the path ``scores_by`` names
+    (``attn_scores_by``'s word): q [B, S, Hq, D] float32, rounded here once;
+    k, v [B, S, n_kv, D] operands; query head j reads key/value head
+    j // (Hq / n_kv) -> [B, S, Hq * D]."""
+    b, s, n_q, head_dim = q.shape
+    n_kv = k.shape[2]
+    grouped = (b, s, n_kv, n_q // n_kv, head_dim)
+    if scores_by == "kernel":
+        # the kernel applies no scale: it goes into q ahead of q's one
+        # rounding to the compute precision
+        q = _operand(q * (1.0 / math.sqrt(head_dim)), cdt)
+        return _scores_kernel(q.reshape(grouped), k, v, mask=mask)
+    return _scores_xla(_operand(q, cdt).reshape(grouped), k, v, cdt=cdt,
+                       mask=mask)
 
 
 @jax.named_scope("attn")
@@ -280,22 +342,11 @@ def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
     q = _dot(xn, lp["wq"], cdt).reshape(b, s, -1, head_dim)
     k = _dot(xn, lp["wk"], cdt).reshape(b, s, -1, head_dim)
     v = _dot(xn, lp["wv"], cdt).reshape(b, s, -1, head_dim)
-    n_kv = k.shape[2]
-    group = q.shape[2] // n_kv
     q = rotary(rms_norm(q, lp["q_norm"], eps), positions, theta)
     k = rotary(rms_norm(k, lp["k_norm"], eps), positions, theta)
-    k, v = _operand(k, cdt), _operand(v, cdt)
-    # query head j reads key/value head j // group
-    if scores_by == "kernel":
-        # the kernel applies no scale: it goes into q ahead of q's one
-        # rounding to the compute precision
-        q = _operand(q * (1.0 / math.sqrt(head_dim)), cdt)
-        out = _scores_kernel(q.reshape(b, s, n_kv, group, head_dim), k, v,
-                             length=length, block=block)
-    else:
-        out = _scores_xla(
-            _operand(q, cdt).reshape(b, s, n_kv, group, head_dim), k, v,
-            length=length, block=block, cdt=cdt)
+    out = masked_scores(q, _operand(k, cdt), _operand(v, cdt),
+                        mask=block_diffusion(length, block), cdt=cdt,
+                        scores_by=scores_by)
     return _dot(out, lp["wo"], cdt)
 
 
@@ -324,10 +375,14 @@ def route(xn: jnp.ndarray, router: jnp.ndarray, top_k: int, *,
 
 
 def pass_rows(capacity: int) -> Tuple[int, int]:
-    """(passes, rows a pass) of a pair buffer of ``capacity`` rows: equal
-    passes of at most ``PASS_ROWS``."""
+    """(passes, rows a pass) of a pair buffer of at least ``capacity`` rows:
+    equal passes of at most ``PASS_ROWS``, a pass of ``PRODUCT_TILE_ROWS`` or
+    more made up to whole tiles (a smaller buffer is held row for row)."""
     passes = -(-capacity // PASS_ROWS)
-    return passes, -(-capacity // passes)
+    rows = -(-capacity // passes)
+    if rows >= PRODUCT_TILE_ROWS:
+        rows = -(-rows // PRODUCT_TILE_ROWS) * PRODUCT_TILE_ROWS
+    return passes, rows
 
 
 def moe_rows_by(width: int, positions: int, capacity: int, *,
@@ -364,13 +419,13 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
     """The held experts' part of ``MoE(RMSNorm(x))``: x [B, S, d] ->
     ([B, S, d], counts). ``lp['w_gate']`` [held, d, f] says how many experts
     are held; they are experts ``first_expert`` onwards. The first
-    ``capacity`` of the sorted pairs are computed, in equal passes of at most
-    ``PASS_ROWS`` rows, each made again in the backward pass, so that the
-    layer's memory is one pass's; pairs beyond them are counted and add
-    nothing. ``route_by(xn, router, top_k)`` is the model's router
-    (``route``, with what the model binds of its keywords); ``rows_by`` is
-    ``moe_rows_by``'s word for what moves a pass's rows from and to their
-    positions."""
+    ``capacity`` of the sorted pairs (``pass_rows``: at least that many) are
+    computed, in equal passes of at most ``PASS_ROWS`` rows, each made again
+    in the backward pass, so that the layer's memory is one pass's; pairs
+    beyond them are counted and add nothing. ``route_by(xn, router, top_k)``
+    is the model's router (``route``, with what the model binds of its
+    keywords); ``rows_by`` is ``moe_rows_by``'s word for what moves a pass's
+    rows from and to their positions."""
     shape = x.shape
     xn = rms_norm(x, lp["norm2"], eps).reshape(-1, shape[-1])
     n_tok = xn.shape[0]
@@ -504,14 +559,9 @@ class SdarMoE(GraphModel):
 
     def _attn_notes(self, scores_by: str, seq: int, length: int
                     ) -> Dict[str, str]:
-        if scores_by != "kernel":       # every score of every chunk
-            return {"attn_scores": scores_by}
-        group = self.cfg.attn_q_heads // self.cfg.attn_kv_heads
-        visited, total = block_attention.visited_blocks(
-            attn_kernel(seq, length, self.cfg.diffusion_block, group),
-            seq, ATTN_BLOCK)
-        return {"attn_scores": scores_by,
-                "attn_score_blocks": f"{visited}/{total}"}
+        return attn_notes(
+            scores_by, block_diffusion(length, self.cfg.diffusion_block),
+            seq, self.cfg.attn_q_heads // self.cfg.attn_kv_heads)
 
     def embedding_param_names(self) -> Tuple[str, ...]:
         return ("tok_emb",)

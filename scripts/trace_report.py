@@ -53,11 +53,12 @@ the report; or ``positions``, AD's scatter-add of every position (history
 models, hashed tables, row shards, accumulation), with a line that says so.
 
 Where the model says what makes its attention's masked scores
-(``--model sdar_moe``), each ``train.log_sync`` carries ``attn_scores``
-(``kernel``: one Pallas call that visits only the blocks of the score
-matrix the block-diffusion mask leaves something in; ``xla``: every score of
-every query chunk) and, of the kernel, ``attn_score_blocks`` (visited /
-all, a head); the report prints them on its "block-masked attention" line
+(``--model sdar_moe``, ``--model solar_open2``), each ``train.log_sync``
+carries ``attn_scores`` (``kernel``: one Pallas call that visits only the
+blocks of the score matrix the model's mask, block-diffusion or causal,
+leaves something in; ``xla``: every score of every query chunk) and, of the
+kernel, ``attn_score_blocks`` (visited / all, a head); the report prints
+them on its "block-masked attention" line
 (TUNING §5).
 
 Both decoders say how their expert layers' rows go to and from their
@@ -66,12 +67,15 @@ held, ``ops/pallas_moe_rows``; ``xla``: ``take`` and scatter-add over every
 row of the buffer) and ``moe_rows_moved`` (the step's held pairs over the
 buffers' rows); the report prints them on its "expert layers' rows" line.
 
-Where the model scans a delta-rule recurrence (``--model kimi_linear``), each
-``train.log_sync`` carries ``kda_scan`` (the algorithm and chunk length of
-the compiled step: ``chunk64/sub16``), ``mla_scores`` (``xla`` / ``kernel``)
-and the step's ``kda_chunk_log_decay_min``, the most negative cumulative
-log-decay a chunk held; the report prints one "delta-rule scan" line with the
-count's minimum over the trace (TUNING §17).
+Where the model scans a delta-rule recurrence (``--model kimi_linear``,
+``--model solar_open2``), each ``train.log_sync`` carries ``kda_scan`` (the
+algorithm and chunk length of the compiled step: ``chunk64/sub16``),
+Kimi-Linear's ``mla_scores`` (``xla`` / ``kernel``), the step's
+``kda_chunk_log_decay_min``, the most negative cumulative log-decay a chunk
+held, and, where the write strength reaches 2 (``solar_open2``),
+``kda_beta_over_one``, the positions x heads of the step whose strength
+passed 1; the report prints one "delta-rule scan" line with the first
+count's minimum and the second's mean over the trace (TUNING §17).
 
 Usage:
     python scripts/trace_report.py TRACE.json [--top 20] [--json]
@@ -364,18 +368,24 @@ def attention_scores(events):
 def delta_rule_scan(events):
     """The delta-rule scan's notes and count off the ``train.log_sync`` spans
     that carry them: ``steps`` read, ``scan`` (``kda_scan``: algorithm and
-    chunk length), ``mla_scores`` and ``log_decay_min`` (the least
-    ``kda_chunk_log_decay_min``; None in a trace that predates the count);
-    None when no span says ``kda_scan`` (another model, or an older
-    trace)."""
+    chunk length), ``mla_scores`` (``?`` where no span says: another model's
+    full layer, or an older trace), ``log_decay_min`` (the least
+    ``kda_chunk_log_decay_min``; None in a trace that predates the count)
+    and, where the spans carry ``kda_beta_over_one``, ``beta_over_one`` (its
+    mean a step); None when no span says ``kda_scan`` (another model, or an
+    older trace)."""
     seen = _log_syncs(events, "kda_scan")
     if not seen:
         return None
     lows = [a["kda_chunk_log_decay_min"] for a in seen
             if "kda_chunk_log_decay_min" in a]
-    return {"steps": len(seen), "scan": seen[-1]["kda_scan"],
-            "mla_scores": seen[-1].get("mla_scores", "?"),
-            "log_decay_min": min(lows) if lows else None}
+    out = {"steps": len(seen), "scan": seen[-1]["kda_scan"],
+           "mla_scores": seen[-1].get("mla_scores", "?"),
+           "log_decay_min": min(lows) if lows else None}
+    over = [a["kda_beta_over_one"] for a in seen if "kda_beta_over_one" in a]
+    if over:
+        out["beta_over_one"] = sum(over) / len(over)
+    return out
 
 
 def expert_rows(events):
@@ -478,10 +488,14 @@ def main(argv=None):
                  if "visited" in attn else ", every score computed"))
     if scan is not None:
         low = scan["log_decay_min"]
-        print("delta-rule scan over %d logged steps: %s, latent attention's "
-              "scores by %s, most negative chunk log-decay %s"
-              % (scan["steps"], scan["scan"], scan["mla_scores"],
-                 "not in this trace" if low is None else "%.4g" % low))
+        print("delta-rule scan over %d logged steps: %s" % (
+            scan["steps"], scan["scan"])
+              + (", latent attention's scores by %s" % scan["mla_scores"]
+                 if scan["mla_scores"] != "?" else "")
+              + ", most negative chunk log-decay %s" % (
+                  "not in this trace" if low is None else "%.4g" % low)
+              + (", write strength over 1 at %.0f positions x heads a step"
+                 % scan["beta_over_one"] if "beta_over_one" in scan else ""))
     if moved is not None:
         print("expert layers' rows over %d logged steps: moved by %s, %.0f "
               "of %d buffer rows a step held a pair (%.1f%%)"

@@ -436,13 +436,15 @@ def test_kernel_scores_match_the_chunked_xla_path(dtype, tol, kernel_block):
     scale = 1.0 / np.sqrt(q.shape[-1])
 
     def by_xla(q, k, v):
-        return sdar_moe._scores_xla(sdar_moe._operand(q, cdt), k, v,
-                                    length=length, block=block, cdt=cdt)
+        return sdar_moe._scores_xla(
+            sdar_moe._operand(q, cdt), k, v,
+            mask=sdar_moe.block_diffusion(length, block), cdt=cdt)
 
     def by_kernel(q, k, v):
         return sdar_moe._scores_kernel(
-            sdar_moe._operand(q * scale, cdt), k, v, length=length,
-            block=block, interpret=True, kernel_block=kernel_block)
+            sdar_moe._operand(q * scale, cdt), k, v,
+            mask=sdar_moe.block_diffusion(length, block), interpret=True,
+            kernel_block=kernel_block)
 
     def value_and_grads(f):
         def loss(q, k, v):
@@ -486,7 +488,8 @@ def test_forward_grid_visits_the_blocks_allowed_leaves_something_in(
     with NumPy, a stripe of query blocks at a time (computed, not traced)."""
     from deepfm_tpu.ops import block_attention
     s, block = 2 * length, 4
-    kernel = sdar_moe.attn_kernel(s, length, block, 4, True, kernel_block)
+    mask = sdar_moe.block_diffusion(length, block)
+    kernel = sdar_moe.attn_kernel(s, mask, 4, True, kernel_block)
     index = np.arange(s)
     count = 0
     for start in range(0, s, kernel_block):
@@ -500,8 +503,8 @@ def test_forward_grid_visits_the_blocks_allowed_leaves_something_in(
     if visited is not None:
         assert count == visited
     # one kernel a shape: the step is traced more than once a run
-    assert sdar_moe.attn_kernel(s, length, block, 4, True,
-                                kernel_block) is kernel
+    assert sdar_moe.attn_kernel(s, sdar_moe.block_diffusion(length, block),
+                                4, True, kernel_block) is kernel
 
 
 @pytest.mark.parametrize("backend, seq, head_dim, one_device, says", [
@@ -536,7 +539,8 @@ def test_a_cpu_step_makes_its_scores_with_xla(seeded):
     ("tpu", 2048, 16384, 32768, False, "xla"),      # across data replicas
     ("tpu", 64, 16384, 32768, True, "xla"),         # no whole line a row
     ("tpu", 2048, 16380, 32768, True, "xla"),       # positions in no tiles
-    ("tpu", 2048, 16384, 32760, True, "xla")])      # nor a pass's rows
+    ("tpu", 2048, 16384, 100, True, "xla"),         # nor a pass's rows
+    ("tpu", 2048, 16384, 32760, True, "kernel")])   # (made up to 2 x 16,384)
 def test_the_row_kernels_are_taken_where_backend_shape_and_mesh_allow(
         backend, width, positions, capacity, one_device, says):
     assert sdar_moe.moe_rows_by(width, positions, capacity,
@@ -544,6 +548,26 @@ def test_the_row_kernels_are_taken_where_backend_shape_and_mesh_allow(
                                 backend=backend) == says
     assert sdar_moe.pass_rows(32768) == (2, 16384)
     assert sdar_moe.pass_rows(16384) == (1, 16384)
+
+
+@pytest.mark.parametrize("capacity,passes,rows", [
+    (8, 1, 8), (255, 1, 255),           # under one tile: held row for row
+    (256, 1, 256), (257, 1, 512),
+    (3280, 1, 3328), (3328, 1, 3328),   # ISSUE 37's buffer and its tile's
+    (4992, 1, 5120), (20480, 1, 20480),
+    (20481, 2, 10496), (40000, 2, 20224)])
+def test_a_pass_of_a_tile_or_more_is_whole_tiles_of_the_grouped_product(
+        capacity, passes, rows):
+    """``jax.lax.ragged_dot`` on a TPU costs 5.7 x over a buffer that is no
+    multiple of 128 rows and 1.5 x over one that is none of 256 (PERF.md,
+    PR 37): the buffer holds at least what was asked, in whole tiles."""
+    assert sdar_moe.pass_rows(capacity) == (passes, rows)
+    assert passes * rows >= capacity and rows <= sdar_moe.PASS_ROWS
+    assert rows < sdar_moe.PRODUCT_TILE_ROWS \
+        or rows % sdar_moe.PRODUCT_TILE_ROWS == 0
+    notes = sdar_moe.moe_notes("xla", capacity, 3)
+    assert notes["moe_rows_moved"] == "{moe_pairs_held}/%d" % (
+        3 * passes * rows)
 
 
 def test_the_step_notes_say_how_the_expert_layers_rows_move():

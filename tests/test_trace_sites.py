@@ -582,3 +582,67 @@ def test_log_sync_says_how_the_delta_rule_scan_is_computed(tmp_path, capsys):
         "log_decay_min": None}
     assert report.delta_rule_scan(
         [{"name": "train.log_sync", "ph": "X", "args": {"step": 2}}]) is None
+
+
+def test_log_sync_says_the_causal_score_path_and_the_write_strength(
+        tmp_path, capsys):
+    """``--model solar_open2`` says on the span that reads the loss back
+    what makes its full layer's causal scores (``attn_scores``: the site
+    ``sdar_moe`` and this model share, ``sdar_moe.attn_notes``) and how
+    often the delta rule's write strength passed 1
+    (``kda_beta_over_one``, a whole number among the counts), and the report
+    prints both on their lines."""
+    length, vocab, batch = 12, 50, 2
+    cfg = Config(model="solar_open2", feature_size=vocab, field_size=1,
+                 embedding_size=16, history_max_len=length, decoder_layers=2,
+                 attn_every=2, kda_heads=2, kda_head_dim=8, attn_q_heads=4,
+                 attn_kv_heads=2, attn_head_dim=8, moe_experts=4,
+                 moe_top_k=2, moe_expert_width=8, moe_shared_width=8,
+                 moe_experts_held=2, moe_first_expert=0,
+                 moe_pair_capacity=batch * length * 2, batch_size=batch,
+                 l2_reg=0.0, learning_rate=1e-3, steps_per_loop=1,
+                 log_steps=2, compute_dtype="float32", mesh_data=1,
+                 mesh_model=1)
+    rng = np.random.default_rng(5)
+    batches = [{"feat_ids": np.zeros((batch, 1), np.int32),
+                "feat_vals": np.ones((batch, 1), np.float32),
+                "label": np.zeros((batch, 1), np.float32),
+                "hist_ids": rng.integers(0, vocab, (batch, length)
+                                         ).astype(np.int32),
+                "hist_mask": np.ones((batch, length), np.float32)}
+               for _ in range(4)]
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(cfg)
+    tr.fit(tr.init_state(), batches)
+    syncs = [e["args"] for e in trace_lib._tracer.events()
+             if e["name"] == "train.log_sync"]
+    assert [(a["kda_scan"], a["attn_scores"]) for a in syncs] == [
+        ("chunk64/sub16", "xla")] * 2
+    assert all("mla_scores" not in a and "attn_score_blocks" not in a
+               for a in syncs)
+    over = [a["kda_beta_over_one"] for a in syncs]
+    # one KDA layer of 2 heads over 2 x 12 positions: some pass 1, not all
+    assert all(isinstance(x, int) and 0 < x < batch * length * 2
+               for x in over)
+    assert all(a["kda_chunk_log_decay_min"] < 0 for a in syncs)
+    path = str(tmp_path / "trace.json")
+    trace_lib.export(path)
+    report = _report()
+    loaded, _ = report._load(path)
+    assert report.delta_rule_scan(loaded) == {
+        "steps": 2, "scan": "chunk64/sub16", "mla_scores": "?",
+        "log_decay_min": pytest.approx(min(
+            a["kda_chunk_log_decay_min"] for a in syncs)),
+        "beta_over_one": pytest.approx(sum(over) / 2)}
+    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla"}
+    assert report.main([path]) == 0
+    out = capsys.readouterr().out
+    assert ("delta-rule scan over 2 logged steps: chunk64/sub16, most "
+            "negative chunk log-decay ") in out
+    assert ("write strength over 1 at %.0f positions x heads a step"
+            % (sum(over) / 2)) in out
+    assert ("block-masked attention over 2 logged steps: scores by xla, "
+            "every score computed") in out
+    # on the kernel the note names the causal half's blocks
+    notes = tr.model.step_notes
+    assert notes["attn_scores"] == "xla" and "kda_scan" in notes
