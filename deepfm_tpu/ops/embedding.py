@@ -76,25 +76,32 @@ def sharded_lookup_allgather(local_table: jax.Array, ids: jax.Array,
     """Row-sharded gather via table reassembly: rebuild the full [V, ...]
     table on every shard, then a plain local gather.
 
-    Implemented as scatter-into-zeros + psum rather than ``lax.all_gather``:
-    the result is identical, XLA recognizes the pattern, and psum's output
-    is *provably replicated* over the axis, which ``shard_map(check_vma)``
-    requires downstream (all_gather output is conservatively marked
-    axis-varying). Communication is O(V·K) per step independent of batch
-    (vs masked+psum's O(B·F·K)); the table cotangent reduces back with the
+    Reassembled by ``all_gather_invariant``, not ``lax.all_gather``: the
+    result is identical and typed replicated over the axis. Communication
+    is O(V·K) per step independent of batch (vs masked+psum's O(B·F·K));
+    the table cotangent reduces back with the
     transposed collective. Only competitive when ids volume exceeds table
     volume — selected via cfg.embedding_lookup for large-batch/small-table
     regimes (``test_trainer.py::test_allgather_lookup_matches_masked_psum``
     holds both strategies to the same weights)."""
-    m = jax.lax.axis_size(axis_name)
-    idx = jax.lax.axis_index(axis_name)
-    rows_local = local_table.shape[0]
-    full = jnp.zeros((rows_local * m, *local_table.shape[1:]),
-                     local_table.dtype)
-    full = jax.lax.dynamic_update_slice_in_dim(
-        full, local_table, idx * rows_local, axis=0)
-    full = jax.lax.psum(full, axis_name)
+    full = all_gather_invariant(local_table, axis_name)
     return jnp.take(full, ids.astype(jnp.int32), axis=0)
+
+
+def all_gather_invariant(x: jax.Array, axis_name: str) -> jax.Array:
+    """The shards' ``x`` ``[n, ...]`` one after another in the axis's order,
+    ``[shards * n, ...]``, the same array on every shard and typed so:
+    each shard's ``x`` in its own slice of zeros, summed over the axis.
+    Every element has one contributor that is not zero, so the sum is
+    exact. ``lax.all_gather`` moves half the bytes but its result is typed
+    varying over the axis, which ``shard_map(check_vma)`` does not let
+    through a replicated out spec."""
+    n = x.shape[0]
+    full = jnp.zeros((n * jax.lax.axis_size(axis_name), *x.shape[1:]),
+                     x.dtype)
+    full = jax.lax.dynamic_update_slice_in_dim(
+        full, x, jax.lax.axis_index(axis_name) * n, axis=0)
+    return jax.lax.psum(full, axis_name)
 
 
 # Vocab rows are padded to a multiple of this REGARDLESS of the current
@@ -492,10 +499,7 @@ def exchange_rows(local_table: jax.Array, ex: ExchangePlan,
                              concat_axis=0, tiled=True)    # [D, C, ...]
     flat = got.reshape((d * cap,) + got.shape[2:])
     mine = jnp.take(flat, ex.flat_idx, axis=0, mode="fill", fill_value=0)
-    full = jnp.zeros((d * cap,) + mine.shape[1:], mine.dtype)
-    full = jax.lax.dynamic_update_slice_in_dim(full, mine, r * cap, axis=0)
-    full = jax.lax.psum(full, axis_name)
-    return full[:ex.n_ids]
+    return all_gather_invariant(mine, axis_name)[:ex.n_ids]
 
 
 @jax.named_scope("embed")

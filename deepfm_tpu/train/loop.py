@@ -77,12 +77,17 @@ ROW_UPDATE_CAPACITY = 2048
 #: A dense-gradient step that builds its table gradient from rows
 #: (``Trainer._grad_by_rows``) reports the same two.
 ROW_COUNTS = ("embed_distinct_rows", "embed_row_trips")
+#: Under data replicas that step exchanges its rows (``_table_grads``) and
+#: reports a third: the rows every chip scatter-added, all replicas' together.
+EXCHANGED_ROWS = "embed_exchanged_rows"
 #: And how its rows went back: "dma", "scatter", or "dma+scatter" where a
 #: model's tables differ in row shape (``Trainer.row_writeback``).
 ROW_WRITEBACK = "embed_row_writeback"
 #: How a dense-gradient step made its table-shaped gradient: "rows" (the
-#: batch's distinct rows, each the sum of its positions' cotangents) or
-#: "positions" (AD's scatter-add of every position); ``Trainer.embed_grad``.
+#: batch's distinct rows, each the sum of its positions' cotangents), "rows,
+#: exchanged over data" (the same under data replicas: every chip scatters
+#: all replicas' rows, no table crosses the interconnect) or "positions"
+#: (AD's scatter-add of every position); ``Trainer.embed_grad``.
 EMBED_GRAD = "embed_grad"
 
 
@@ -593,14 +598,19 @@ class Trainer:
             loss_fn, has_aux=True)((views, rest))
         return xent, new_mstate, g_views, g_rest
 
-    def _view_row_sums(self, tabs, ids, g_views):
+    def _view_row_sums(self, tabs, ids, g_views, *, gather_axis=None):
         """(``emb_ops.RowSums`` of the distinct rows of ``ids``: per row the
         float32 sum of its positions' cotangents ``g_views``, every table's
         columns side by side, slots a whole number of trips; the trips of
         ``ROW_UPDATE_CAPACITY`` rows that hold them; ``rows_of(i)`` = the
         ids of trip ``i`` and each table's rows of sums there). Sorted, so
         the last trip's spare slots lie past the table: read as fill,
-        dropped or skipped by a write."""
+        dropped or skipped by a write. With ``gather_axis`` (data replicas:
+        the rows are this shard's) the trips are the fullest shard's and
+        ``rows_of(i)`` is every shard's trip ``i``, one after another in
+        the axis's order, the same on every shard
+        (``emb_ops.all_gather_invariant``); a shard with fewer trips hands
+        in spare slots."""
         names, cap = self._embed_names, ROW_UPDATE_CAPACITY
         widths = [math.prod(tabs[n].shape[1:]) for n in names]
         cuts = np.cumsum([0] + widths)
@@ -608,14 +618,22 @@ class Trainer:
             ids, jnp.concatenate([g_views[n].reshape(ids.size, w)
                                   for n, w in zip(names, widths)], axis=1),
             self.model.padded_vocab, self.cfg.feature_size, multiple=cap)
+        trips = (rows.count + cap - 1) // cap
+        if gather_axis is not None:     # before the loop: a collective in
+            # its body needs every shard to make the same trips
+            trips = jax.lax.pmax(trips, gather_axis)
 
         def rows_of(i):
             uids = jax.lax.dynamic_slice_in_dim(rows.uids, i * cap, cap)
             g = jax.lax.dynamic_slice_in_dim(rows.sums, i * cap, cap)
+            if gather_axis is not None:
+                uids, g = (emb_ops.all_gather_invariant(x, gather_axis)
+                           for x in (uids, g))
             return uids, {n: g[:, cuts[j]:cuts[j + 1]].reshape(
-                (cap,) + tabs[n].shape[1:]) for j, n in enumerate(names)}
+                (len(uids),) + tabs[n].shape[1:])
+                for j, n in enumerate(names)}
 
-        return rows, (rows.count + cap - 1) // cap, rows_of
+        return rows, trips, rows_of
 
     @jax.named_scope("embed")
     def _update_rows(self, tabs, opt_tabs, ids, g_views):
@@ -692,10 +710,17 @@ class Trainer:
         batch at static shapes. The scatter is told nothing of its ids
         (``indices_are_sorted`` makes the TPU compiler sweep the table).
         With ``sum_axis`` (data replicas: the cotangents are this shard's)
-        the tables are summed over that mesh axis, the all-reduce AD would
-        have put after its own scatter, and the counts are the fullest
-        shard's."""
-        rows, trips, rows_of = self._view_row_sums(tabs, ids, g_views)
+        the gradient is the sum over that mesh axis, and what crosses the
+        interconnect is rows, not tables: a trip gathers every shard's
+        ``ROW_UPDATE_CAPACITY`` (id, sum) pairs and every chip scatter-adds
+        them all, the same operands in the same order, so the replicas stay
+        bit-identical as under the all-reduce AD would have put after its
+        own scatter (a row two shards hold is added twice, which is the
+        sum). The trips and the distinct rows counted are the fullest
+        shard's; ``EXCHANGED_ROWS`` is all shards' rows together, what
+        each chip scattered (PERF.md §6, PR 38)."""
+        rows, trips, rows_of = self._view_row_sums(
+            tabs, ids, g_views, gather_axis=sum_axis)
 
         def trip(carry):
             i, grads = carry
@@ -703,18 +728,15 @@ class Trainer:
             return i + 1, {n: grads[n].at[uids].add(
                 g[n].astype(grads[n].dtype), mode="drop") for n in grads}
 
-        zeros = jax.tree.map(jnp.zeros_like, tabs)
-        if sum_axis is not None:    # the carry varies over the axis from
-            # the start, as this shard's rows do (shard_map's typing)
-            zeros = jax.lax.pcast(zeros, (sum_axis,), to="varying")
         _, grads = jax.lax.while_loop(
             lambda carry: carry[0] < trips, trip,
-            (jnp.zeros((), jnp.int32), zeros))
-        counts = (rows.count, trips)
+            (jnp.zeros((), jnp.int32), jax.tree.map(jnp.zeros_like, tabs)))
+        distinct, exchanged = rows.count, {}
         if sum_axis is not None:
-            grads = jax.lax.psum(grads, sum_axis)
-            counts = jax.lax.pmax(counts, sum_axis)
-        return grads, dict(zip(ROW_COUNTS, counts))
+            exchanged = {EXCHANGED_ROWS: jax.lax.psum(distinct, sum_axis)}
+            distinct = jax.lax.pmax(distinct, sum_axis)
+        return grads, {**dict(zip(ROW_COUNTS, (distinct, trips))),
+                       **exchanged}
 
     def _dense_value_and_grad(self, data_loss, params, *, data_axis,
                               shard_axis, ids=None):
@@ -728,7 +750,8 @@ class Trainer:
         (``_table_grads``; the counts are its); without, by AD, from every
         position (no counts)."""
         flat_sync = data_axis is not None and self._hier_groups is None
-        how = "positions" if ids is None else "rows"
+        how = ("positions" if ids is None else
+               "rows, exchanged over data" if flat_sync else "rows")
         if how != self.embed_grad:      # said once a trainer, at trace time
             self.embed_grad = how
             ulog.info(f"dense-gradient step: table gradient from {how}")
@@ -753,7 +776,8 @@ class Trainer:
         else:
             # The same sync point: the dense leaves' gradient comes reduced
             # out of AD; the views' cotangents are local, so the tables
-            # made of them are summed over the axis explicitly.
+            # made of them are summed over the axis explicitly, by an
+            # exchange of the shards' rows.
             tabs, rest = self._tables_and_rest(params)
             xent, new_mstate, g_views, g_rest = self._value_and_view_grads(
                 data_loss, tabs, rest, ids, mean_axis=sync)

@@ -49,8 +49,15 @@ L2, data replicas: every row swept every step) says on the same span how it
 made its table-shaped gradient (``embed_grad``): ``rows``, from the batch's
 distinct rows, each the sum of its positions' cotangents
 (``Trainer._grad_by_rows``), with the same two counts and the same line in
-the report; or ``positions``, AD's scatter-add of every position (history
-models, hashed tables, row shards, accumulation), with a line that says so.
+the report; ``rows, exchanged over data``, the same under data replicas,
+where a trip gathers every replica's (row id, sum) pairs and every chip
+scatter-adds them all in place of an all-reduce of the tables — the two
+counts are then the fullest replica's, and ``embed_exchanged_rows`` (all
+replicas' rows together: what each chip scattered, whose mean the line
+adds) says which side of the break-even a workload is on (TUNING §4);
+or ``positions``, AD's scatter-add of every position (history models,
+hashed tables, row shards, accumulation), all-reduced as tables under data
+replicas, with a line that says so.
 
 Where the model says what makes its attention's masked scores
 (``--model sdar_moe``, ``--model solar_open2``), each ``train.log_sync``
@@ -323,27 +330,34 @@ def row_updates(events):
     mean and max of ``embed_distinct_rows`` and ``embed_row_trips``,
     ``one_trip_share`` of those steps and the row-local step's
     ``writeback`` (``embed_row_writeback``; "?" where the trace predates it
-    or the step writes no rows back); None when no span has them (the step
-    scatters every position, or the trace predates the counters)."""
+    or the step writes no rows back) and, where data replicas exchanged
+    their rows, ``exchanged_rows_mean`` (``embed_exchanged_rows``: what
+    every chip scattered); None when no span has them (the step scatters
+    every position, or the trace predates the counters)."""
     seen = _log_syncs(events, "embed_distinct_rows")
     if not seen:
         return None
     rows = [a["embed_distinct_rows"] for a in seen]
     trips = [a["embed_row_trips"] for a in seen]
-    return {"steps": len(seen),
-            "distinct_rows_mean": sum(rows) / len(rows),
-            "distinct_rows_max": max(rows),
-            "row_trips_mean": sum(trips) / len(trips),
-            "row_trips_max": max(trips),
-            "one_trip_share": sum(t == 1 for t in trips) / len(trips),
-            "writeback": seen[-1].get("embed_row_writeback", "?")}
+    out = {"steps": len(seen),
+           "distinct_rows_mean": sum(rows) / len(rows),
+           "distinct_rows_max": max(rows),
+           "row_trips_mean": sum(trips) / len(trips),
+           "row_trips_max": max(trips),
+           "one_trip_share": sum(t == 1 for t in trips) / len(trips),
+           "writeback": seen[-1].get("embed_row_writeback", "?")}
+    exchanged = [a["embed_exchanged_rows"] for a in seen
+                 if "embed_exchanged_rows" in a]
+    if exchanged:
+        out["exchanged_rows_mean"] = sum(exchanged) / len(exchanged)
+    return out
 
 
 def table_gradient(events):
     """How the dense-gradient step made its table-shaped gradient
     (``embed_grad`` of the last ``train.log_sync`` that says: "rows" /
-    "positions"); None in a row-local step's trace, a sparse-update one's,
-    or one that predates the note."""
+    "rows, exchanged over data" / "positions"); None in a row-local step's
+    trace, a sparse-update one's, or one that predates the note."""
     seen = _log_syncs(events, "embed_grad")
     return seen[-1]["embed_grad"] if seen else None
 
@@ -464,18 +478,23 @@ def main(argv=None):
         print(f"instant {name}: {n}")
     grad = table_gradient(events)
     if touched is not None:
-        by_rows = grad == "rows"
+        by_rows = grad is not None      # "rows", exchanged or not
         print("%s over %d logged steps: "
               "embed_distinct_rows mean %.0f max %d, embed_row_trips mean "
               "%.2f max %d, one trip in %.0f%% of them, %s" % (
-                  "dense-gradient step, table gradient from rows" if by_rows
-                  else "row-local table update",
+                  f"dense-gradient step, table gradient from {grad}"
+                  if by_rows else "row-local table update",
                   touched["steps"], touched["distinct_rows_mean"],
                   touched["distinct_rows_max"], touched["row_trips_mean"],
                   touched["row_trips_max"],
                   100 * touched["one_trip_share"],
                   "every row swept after it" if by_rows
-                  else "rows written back by " + touched["writeback"]))
+                  else "rows written back by " + touched["writeback"])
+              + (" (the fullest replica's; every chip scattered all "
+                 "replicas' rows, embed_exchanged_rows mean %.0f a step, and "
+                 "no table crossed the interconnect)"
+                 % touched["exchanged_rows_mean"]
+                 if "exchanged_rows_mean" in touched else ""))
     elif grad == "positions":
         print("dense-gradient step: table gradient from positions (AD's "
               "scatter-add of every position of the batch)")

@@ -20,6 +20,18 @@ L2 unchanged after it. Held here, at small size in float32 on the CPU:
 * the counts against NumPy's ``unique``;
 * the compiled step: no table-tall scatter of a batch's positions where the
   step is eligible, and the scatter of every position wherever it is not.
+
+Under data replicas the step sums that gradient over the ``data`` axis by
+exchanging rows, not tables (PERF.md §6, PR 38): a trip gathers every
+replica's ``ROW_UPDATE_CAPACITY`` (row id, summed cotangent) pairs and every
+chip scatter-adds them all. Held on 2 and 4 virtual devices:
+
+* the same trained state as AD's scatter followed by its ``psum``;
+* the replicas' tables and both moments bit for bit the same after 3 steps;
+* replicas that need different numbers of trips, a row every replica holds,
+  and the ids that receive nothing, on every replica;
+* ``embed_exchanged_rows`` (all replicas' rows together) against NumPy;
+* the compiled step: no collective with a table-shaped result.
 """
 
 import functools
@@ -33,6 +45,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
+
 from deepfm_tpu.config import Config
 from deepfm_tpu.train import Trainer, loop
 from deepfm_tpu.utils import profiling
@@ -43,8 +58,10 @@ ID_RANGE = 200          # rows >= ID_RANGE are real and never touched
 MODELS = {"deepfm": {}, "dcnv2": {"model": "dcnv2"},
           "multitask": {"tasks": "ctr,cvr", "multitask": "mmoe",
                         "mmoe_experts": 2}}
+REPLICAS = (2, 4)
 CASES = [pytest.param(m, d, l2, id=f"{m}-{d}dev-l2_{l2:g}")
-         for m, d, l2 in itertools.product(MODELS, (1, 2), (0.0, 1e-4))]
+         for m, d, l2 in itertools.product(MODELS, (1,) + REPLICAS,
+                                           (0.0, 1e-4))]
 
 
 def _cfg(model="deepfm", devices=1, l2_reg=1e-4, **over):
@@ -94,8 +111,15 @@ def _run(cfg, by_rows=True, batches=None):
     for batch in batches or _batches(cfg.num_tasks > 1):
         state, m = tr.train_step(state, tr.put_batch(batch))
         metrics.append({k: float(v) for k, v in m.items()})
-    assert tr.embed_grad == ("rows" if by_rows else "positions")
+    assert tr.embed_grad == _how(cfg.mesh_data if by_rows else 0)
     return tr, _host(state), metrics
+
+
+def _how(devices):
+    """``Trainer.embed_grad`` of an eligible step on that many data replicas
+    (0: the tables left to AD)."""
+    return {0: "positions", 1: "rows"}.get(devices,
+                                           "rows, exchanged over data")
 
 
 @functools.lru_cache(maxsize=None)
@@ -125,6 +149,7 @@ def test_equals_the_gradient_ad_builds(model, devices, l2_reg):
     assert [m["loss"] for m in rows] == pytest.approx(
         [m["loss"] for m in plain], rel=1e-6)
     assert all(loop.ROW_COUNTS[0] not in m for m in plain)
+    assert all((loop.EXCHANGED_ROWS in m) == (devices > 1) for m in rows)
     # Pad rows stay exactly zero, parameters and moments.
     adam = got.opt_state[0]
     for name in tr.model.embedding_param_names():
@@ -162,17 +187,22 @@ def test_more_distinct_rows_than_a_trip_holds(monkeypatch, capacity, trips):
             assert m["embed_row_trips"] > 2
 
 
-@pytest.mark.parametrize("devices", [1, 2])
+@pytest.mark.parametrize("devices", (1,) + REPLICAS)
 def test_counts_against_numpy_unique(devices):
     """On data replicas each shard counts its own slice of the batch; the
-    step reports the fullest shard's."""
+    step reports the fullest shard's, and all shards' rows together: what
+    every chip scatter-added."""
     cfg = _cfg("deepfm", devices)
     _, _, rows = _run(cfg)
     for m, batch in zip(rows, _batches()):
-        shards = np.split(batch["feat_ids"], devices)
-        assert m["embed_distinct_rows"] == max(
-            len(np.unique(s)) for s in shards)
+        distinct = [len(np.unique(s))
+                    for s in np.split(batch["feat_ids"], devices)]
+        assert m["embed_distinct_rows"] == max(distinct)
         assert m["embed_row_trips"] == 1
+        if devices > 1:
+            assert m["embed_exchanged_rows"] == sum(distinct)
+        else:
+            assert "embed_exchanged_rows" not in m
 
 
 @pytest.mark.parametrize("capacity", [4, 16, 2048])
@@ -209,6 +239,154 @@ def test_pad_rows_negative_ids_and_ids_past_the_table_receive_nothing(
     assert np.asarray(got["fm_w"])[[0, 3, V - 1]].all()
 
 
+def _replica_copies(state, name_of):
+    """Per leaf of the trained ``state``'s params and optimizer state that
+    ``name_of`` keeps, every device's copy."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(
+        (state.params, state.opt_state))
+    return {jax.tree_util.keystr(path): [np.asarray(s.data)
+                                         for s in leaf.addressable_shards]
+            for path, leaf in flat if name_of(jax.tree_util.keystr(path))}
+
+
+@pytest.mark.parametrize("devices", REPLICAS)
+def test_replicas_stay_bit_identical(devices):
+    """Every chip scatter-adds the same gathered pairs in the same order,
+    so the replicated tables and both of Adam's moments cannot drift apart:
+    after 3 steps each device's copy is the first's bit for bit."""
+    tr = Trainer(_cfg("deepfm", devices))
+    state = tr.init_state()
+    for batch in _batches():
+        state, _ = tr.train_step(state, tr.put_batch(batch))
+    names = tr.model.embedding_param_names()
+    copies = _replica_copies(state, lambda k: any(n in k for n in names))
+    # the tables, and mu and nu of each
+    assert len(copies) == 3 * len(names), sorted(copies)
+    for key, per_device in copies.items():
+        assert len(per_device) == devices, key
+        assert per_device[0].any(), key
+        for other in per_device[1:]:
+            assert other.tobytes() == per_device[0].tobytes(), key
+
+
+def _exchanged_table_grads(tr, ids, g):
+    """``_table_grads`` of a trainer on data replicas, the batch split over
+    them as a step splits it: (gradients, counts), replicated."""
+    rows = tr.model.padded_vocab
+    tabs = {n: jnp.zeros((rows,) + v.shape[2:], jnp.float32)
+            for n, v in g.items()}
+    axis = tr.mesh_info.data_axis
+    fn = shard_map(
+        functools.partial(tr._table_grads, sum_axis=axis),
+        mesh=tr.mesh_info.mesh, in_specs=(P(), P(axis), P(axis)),
+        out_specs=P(), check_vma=True)
+    return jax.jit(fn)(tabs, jnp.asarray(ids), g)
+
+
+def _numpy_table_grads(ids, g, rows):
+    flat = ids.reshape(-1)
+    norm = np.where(flat < 0, flat + rows, flat)
+    ok = (norm >= 0) & (norm < V)
+    want = {}
+    for name, cot in g.items():
+        want[name] = np.zeros((rows,) + cot.shape[2:], np.float64)
+        np.add.at(want[name], norm[ok],
+                  cot.reshape((flat.size,) + cot.shape[2:])[ok])
+    return want, norm, ok
+
+
+def _cotangents(rng):
+    return {"fm_v": rng.normal(size=(B, F, 4)).astype(np.float32),
+            "fm_w": rng.normal(size=(B, F)).astype(np.float32)}
+
+
+@pytest.mark.parametrize("devices,capacity", [
+    (d, c) for d in REPLICAS for c in (4, 16, 2048)])
+def test_replicas_that_need_different_trips_lose_nothing(
+        monkeypatch, devices, capacity):
+    """The first replica holds as many distinct rows as it has positions
+    (many trips at a small capacity), the last one row; every replica
+    makes the fullest one's trips and the others hand in spare slots."""
+    monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
+    tr = Trainer(_cfg("deepfm", devices))
+    rng = np.random.default_rng(3)
+    per = B // devices
+    ids = rng.integers(0, 40, size=(B, F)).astype(np.int32)
+    ids[:per] = np.arange(per * F).reshape(per, F)      # all distinct
+    ids[-per:] = 123                                    # one row
+    g = _cotangents(rng)
+    got, counts = _exchanged_table_grads(tr, ids, g)
+    want, _, _ = _numpy_table_grads(ids, g, tr.model.padded_vocab)
+    for name in g:
+        np.testing.assert_allclose(got[name], want[name], atol=1e-5)
+    distinct = [len(np.unique(s)) for s in np.split(ids, devices)]
+    assert distinct[0] == per * F and distinct[-1] == 1
+    assert int(counts["embed_distinct_rows"]) == per * F
+    assert int(counts["embed_row_trips"]) == math.ceil(per * F / capacity)
+    assert int(counts["embed_exchanged_rows"]) == sum(distinct)
+
+
+@pytest.mark.parametrize("devices", REPLICAS)
+def test_a_row_every_replica_holds_receives_the_sum_of_all(devices):
+    """Two replicas' pairs for one row are two slots of one scatter-add."""
+    tr = Trainer(_cfg("deepfm", devices))
+    rng = np.random.default_rng(4)
+    ids = rng.integers(0, ID_RANGE, size=(B, F)).astype(np.int32)
+    ids[:, 0] = 5           # every example of every replica looks row 5 up
+    g = _cotangents(rng)
+    got, _ = _exchanged_table_grads(tr, ids, g)
+    where = ids == 5
+    np.testing.assert_allclose(
+        got["fm_w"][5], g["fm_w"][where].astype(np.float64).sum(), rtol=1e-5)
+    np.testing.assert_allclose(
+        got["fm_v"][5], g["fm_v"][where].astype(np.float64).sum(0),
+        rtol=1e-5, atol=1e-6)
+    want, _, _ = _numpy_table_grads(ids, g, tr.model.padded_vocab)
+    for name in g:
+        np.testing.assert_allclose(got[name], want[name], atol=1e-5)
+
+
+@pytest.mark.parametrize("devices,capacity", [
+    (d, c) for d in REPLICAS for c in (4, 2048)])
+def test_ids_that_receive_nothing_receive_nothing_on_any_replica(
+        monkeypatch, devices, capacity):
+    """The one-device case's ids, a few on every replica: pad rows,
+    negative ids and ids past the table."""
+    monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
+    tr = Trainer(_cfg("deepfm", devices))
+    rows = tr.model.padded_vocab
+    rng = np.random.default_rng(7)
+    ids = rng.integers(0, 40, size=(B, F)).astype(np.int32)
+    ids[::B // devices, :] = [-1, -rows, -(rows - 3), V, rows - 1, rows + 5]
+    g = _cotangents(rng)
+    got, counts = _exchanged_table_grads(tr, ids, g)
+    want, norm, ok = _numpy_table_grads(ids, g, rows)
+    for name in g:
+        np.testing.assert_allclose(got[name], want[name], atol=1e-5)
+        assert not np.asarray(got[name])[V:].any()
+    shards = np.split(np.where(ok, norm, -1).reshape(B, F), devices)
+    distinct = [len(np.setdiff1d(s, [-1])) for s in shards]
+    assert int(counts["embed_distinct_rows"]) == max(distinct)
+    assert int(counts["embed_exchanged_rows"]) == sum(distinct)
+    assert np.asarray(got["fm_w"])[[0, 3]].all()
+
+
+@pytest.mark.parametrize("devices", REPLICAS)
+def test_more_distinct_rows_than_a_trip_holds_on_replicas(monkeypatch,
+                                                          devices):
+    """The whole step over several exchanged trips against AD's."""
+    _, want, _ = _by_ad("deepfm", devices, 1e-4)
+    monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", 8)
+    _, got, rows = _run(_cfg("deepfm", devices))
+    for tree in ("params", "opt_state"):
+        _assert_close(getattr(got, tree), getattr(want, tree), tree)
+    for m, batch in zip(rows, _batches()):
+        distinct = [len(np.unique(s))
+                    for s in np.split(batch["feat_ids"], devices)]
+        assert m["embed_row_trips"] == math.ceil(max(distinct) / 8) > 2
+        assert m["embed_exchanged_rows"] == sum(distinct)
+
+
 def _table_scatter_heights(hlo_text, rows):
     """Per scatter of the compiled program into an array ``rows`` tall, how
     many rows of updates it is handed."""
@@ -225,18 +403,55 @@ def _table_scatter_heights(hlo_text, rows):
 
 
 @pytest.mark.parametrize("model,devices", [
-    (m, d) for m in sorted(MODELS) for d in (1, 2)])
+    (m, d) for m in sorted(MODELS) for d in (1,) + REPLICAS])
 def test_eligible_step_scatters_trips_of_rows_never_positions(model, devices):
+    """A trip scatters ``ROW_UPDATE_CAPACITY`` rows of every data replica."""
     tr = Trainer(_cfg(model, devices))
     assert tr._grad_by_rows()
     text = tr.step_hlo_text()
     heights = _table_scatter_heights(text, tr.model.padded_vocab)
-    assert heights and set(heights) == {loop.ROW_UPDATE_CAPACITY}, heights
-    assert tr.embed_grad == "rows"
+    assert heights and set(heights) == {
+        devices * loop.ROW_UPDATE_CAPACITY}, heights
+    assert tr.embed_grad == _how(devices)
     # and Adam still sweeps every row
     ops = profiling.hlo_table_ops(text, tr.model.padded_vocab)
     assert [o for o in ops if o["scope"] == "opt" and o["tables"]
             and not o["primitive"].startswith("scatter")], ops
+
+
+def _collective_heights(hlo_text):
+    """The leading dimension of every array a collective of the compiled
+    program results in (a tuple's every member)."""
+    heights = []
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) (?:all-reduce|"
+                     r"all-gather|reduce-scatter|all-to-all|"
+                     r"collective-permute)(?:-start)?\(", line)
+        if m:
+            heights += [int(h or 0) for h in
+                        re.findall(r"\w+\[(\d*)[,\]]", m.group(1))]
+    return heights
+
+
+@pytest.mark.parametrize("model,devices", [
+    (m, d) for m in sorted(MODELS) for d in REPLICAS])
+def test_no_table_shaped_collective_on_data_replicas(model, devices):
+    """What crosses the interconnect is a trip's pairs, the dense leaves'
+    gradient and scalars; with the tables left to AD it is the tables."""
+    tr = Trainer(_cfg(model, devices))
+    rows = tr.model.padded_vocab
+    heights = _collective_heights(tr.step_hlo_text())
+    assert devices * loop.ROW_UPDATE_CAPACITY in heights, heights
+    assert rows not in heights, heights
+    by_ad = Trainer(_cfg(model, devices))
+    by_ad._grad_by_rows = lambda: False
+    assert rows in _collective_heights(by_ad.step_hlo_text())
+
+
+def test_one_device_compiles_no_collective():
+    tr = Trainer(_cfg("deepfm", 1))
+    assert not _collective_heights(tr.step_hlo_text())
+    assert tr.embed_grad == "rows"
 
 
 NOT_ELIGIBLE = {

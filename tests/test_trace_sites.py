@@ -331,6 +331,46 @@ def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
             % (sum(want) / 3, max(want))) in capsys.readouterr().out
 
 
+@pytest.mark.parametrize("replicas", [2, 4])
+def test_log_sync_says_when_data_replicas_exchange_their_rows(
+        replicas, tmp_path, capsys):
+    """Under data replicas the dense-gradient step gathers the replicas'
+    rows in place of all-reducing the tables, says so in ``embed_grad`` and
+    adds ``embed_exchanged_rows`` (all replicas' rows, what each chip
+    scattered) to the fullest replica's two counts; the report's line
+    carries its mean."""
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(_cfg(mesh_data=replicas))
+    batches = _batches(K * 3)
+    tr.fit(tr.init_state(), batches)
+    assert tr.embed_grad == "rows, exchanged over data"
+    syncs = [e["args"] for e in trace_lib._tracer.events()
+             if e["name"] == "train.log_sync"]
+    per_replica = [[len(np.unique(shard)) for shard in np.split(
+        batches[K * i - 1]["feat_ids"], replicas)] for i in (1, 2, 3)]
+    assert all(set(a) == {"step", "embed_distinct_rows", "embed_row_trips",
+                          "embed_exchanged_rows", "embed_grad"}
+               and a["embed_grad"] == tr.embed_grad for a in syncs)
+    assert [a["embed_distinct_rows"] for a in syncs] == [
+        max(d) for d in per_replica]
+    assert [a["embed_exchanged_rows"] for a in syncs] == [
+        sum(d) for d in per_replica]
+    path = str(tmp_path / "trace.json")
+    trace_lib.export(path)
+    report = _report()
+    events, _ = report._load(path)
+    mean = sum(sum(d) for d in per_replica) / 3
+    assert report.row_updates(events)["exchanged_rows_mean"] == mean
+    assert report.table_gradient(events) == tr.embed_grad
+    assert report.main([path]) == 0
+    out = capsys.readouterr().out
+    assert ("dense-gradient step, table gradient from rows, exchanged over "
+            "data over 3 logged steps: embed_distinct_rows mean") in out
+    assert ("every row swept after it (the fullest replica's; every chip "
+            "scattered all replicas' rows, embed_exchanged_rows mean %.0f a "
+            "step, and no table crossed the interconnect)" % mean) in out
+
+
 def test_log_sync_says_dma_where_the_kernel_writes_the_rows(monkeypatch):
     """``embed_row_writeback`` is the compiled step's choice: with the
     ``embed_put_rows`` kernel taken (forced on here through the interpreter;
