@@ -177,24 +177,26 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
     # forward and both backward kernels, against the chunked XLA path on one
     # key/value head's query heads of 128 over 1,024 positions, bfloat16
     # operands: four heads under SDAR's block-diffusion mask, eight under
-    # Solar-Open2's causal one.
+    # Solar-Open2's causal one, and LFM2's four heads of 64 (half a lane
+    # line, as they are) under the causal mask.
     from deepfm_tpu.models import kimi_linear, sdar_moe
 
     length, cdt = 512, jnp.dtype(jnp.bfloat16)
     errs = []
-    for heads, mask in ((4, sdar_moe.block_diffusion(length, 4)),
-                        (8, kimi_linear.causal)):
+    for heads, head_dim, mask in (
+            (4, 128, sdar_moe.block_diffusion(length, 4)),
+            (8, 128, kimi_linear.causal), (4, 64, kimi_linear.causal)):
         q, key, val = (jnp.asarray(rng.normal(size=shape_), jnp.float32)
-                       for shape_ in ((1, 2 * length, 1, heads, 128),
-                                      (1, 2 * length, 1, 128),
-                                      (1, 2 * length, 1, 128)))
+                       for shape_ in ((1, 2 * length, 1, heads, head_dim),
+                                      (1, 2 * length, 1, head_dim),
+                                      (1, 2 * length, 1, head_dim)))
         key, val = key.astype(cdt), val.astype(cdt)
-        weight = jnp.asarray(rng.normal(size=(1, 2 * length, heads * 128)),
-                             jnp.float32)
+        weight = jnp.asarray(
+            rng.normal(size=(1, 2 * length, heads * head_dim)), jnp.float32)
 
         def by_kernel(q, k, v):
             return sdar_moe._scores_kernel(
-                (q / np.sqrt(128.0)).astype(cdt), k, v, mask=mask,
+                (q / np.sqrt(float(head_dim))).astype(cdt), k, v, mask=mask,
                 interpret=interpret)
 
         def by_xla(q, k, v):

@@ -49,7 +49,7 @@ class Config:
     coordinator_address: str = ""     # jax.distributed coordinator (host:port)
 
     # ---- model hyperparameters (reference: model flags) ----
-    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2
+    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2 | lfm2_moe
     feature_size: int = 117581        # vocabulary size (reference ipynb:85)
     field_size: int = 39              # number of fields (reference ipynb:90)
     embedding_size: int = 32          # latent dim (reference flag default, ...py:44)
@@ -115,6 +115,16 @@ class Config:
     dense_mlp_width: int = 0
     moe_shared_width: int = 0
     moe_route_scale: float = 1.0
+    # lfm2_moe only (short-convolution / GQA MoE decoder,
+    # models/lfm2_moe.py), beside decoder_layers / attn_* / rope_theta /
+    # moe_* / dense_layers / dense_mlp_width / moe_route_scale, which mean
+    # here what they mean above: layer_types names each held layer's mixer,
+    # comma-separated (conv: a gated depthwise causal convolution of
+    # conv_taps taps; full_attention: causal GQA with QK-norm and rotary);
+    # no shared expert (moe_shared_width stays 0); the head is the token
+    # table.
+    layer_types: str = ""
+    conv_taps: int = 3
     l2_reg: float = 1e-4
     loss_type: str = "log_loss"       # log_loss | square_loss
 
@@ -482,7 +492,7 @@ class Config:
             raise ValueError("metrics_snapshot_secs must be >= 0")
         if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm",
                               "dlrm_dcnv2", "din", "bst", "sdar_moe",
-                              "kimi_linear", "solar_open2"):
+                              "kimi_linear", "solar_open2", "lfm2_moe"):
             raise ValueError(f"unknown model: {self.model!r}")
         if self.model == "sdar_moe":
             self._validate_sdar_moe()
@@ -490,23 +500,34 @@ class Config:
             self._validate_kimi_linear()
         elif self.model == "solar_open2":
             self._validate_solar_open2()
+        elif self.model == "lfm2_moe":
+            self._validate_lfm2_moe()
         elif self.decoder_layers or self.moe_experts or self.attn_q_heads:
             raise ValueError(
                 "decoder_layers/attn_*/moe_* belong to --model sdar_moe, "
-                f"kimi_linear and solar_open2; {self.model!r} has no "
-                "decoder block")
-        recurrent = self.model in ("kimi_linear", "solar_open2")
-        if (not recurrent and (
-                self.kda_heads or self.attn_every or self.moe_shared_width
-                or self.moe_route_scale != 1.0)) or (
-                    self.model != "kimi_linear" and (
-                        self.mla_latent_dim or self.mla_rope_dim
-                        or self.dense_layers or self.dense_mlp_width)):
-            raise ValueError(
-                "kda_heads/attn_every/mla_*/dense_layers/dense_mlp_width/"
-                "moe_shared_width/moe_route_scale belong to --model "
-                "kimi_linear (solar_open2 takes those that are not mla_* or "
-                f"dense_*); {self.model!r} has none of these layers")
+                f"kimi_linear, solar_open2 and lfm2_moe; {self.model!r} has "
+                "no decoder block")
+        # the decoders' further flags, and the models that take each
+        takers = {
+            "kda_heads/attn_every/moe_shared_width": (
+                ("kimi_linear", "solar_open2"), self.kda_heads
+                or self.attn_every or self.moe_shared_width),
+            "moe_route_scale": (
+                ("kimi_linear", "solar_open2", "lfm2_moe"),
+                self.moe_route_scale != 1.0),
+            "mla_latent_dim/mla_rope_dim": (
+                ("kimi_linear",), self.mla_latent_dim or self.mla_rope_dim),
+            "dense_layers/dense_mlp_width": (
+                ("kimi_linear", "lfm2_moe"),
+                self.dense_layers or self.dense_mlp_width),
+            "layer_types/conv_taps": (
+                ("lfm2_moe",), self.layer_types or self.conv_taps != 3),
+        }
+        for what, (models, set_) in takers.items():
+            if set_ and self.model not in models:
+                raise ValueError(
+                    f"{what} belong to --model {', '.join(models)}; "
+                    f"{self.model!r} has none of these layers")
         if self.model == "dlrm_dcnv2":
             self._validate_dlrm_dcnv2()
         elif self.numeric_fields or self.bottom_layers:
@@ -980,8 +1001,8 @@ class Config:
         self._refuse_for_a_decoder("kimi_linear")
 
     def _refuse_for_a_decoder(self, model: str) -> None:
-        """What neither recurrent decoder (kimi_linear, solar_open2)
-        takes."""
+        """What none of the next-token decoders (kimi_linear, solar_open2,
+        lfm2_moe) takes."""
         refused = {
             "tasks (the loss is over the positions of a sequence, one task)":
                 self.num_tasks > 1,
@@ -997,8 +1018,9 @@ class Config:
                 bool(self.embedding_bucket_sizes),
             "mesh_model > 1 (experts and heads over a mesh need their "
             "exchange, which this model does not have)": self.mesh_model > 1,
-            "task_type infer/export (decoding from a recurrent state is a "
-            "serving feature; train and eval report the loss)":
+            "task_type infer/export (decoding from a recurrent or "
+            "convolution state is a serving feature; train and eval report "
+            "the loss)":
                 self.task_type in ("infer", "export"),
             "servable_model_dir (no serving export: the exported function "
             "would be the decoder)": bool(self.servable_model_dir),
@@ -1007,6 +1029,51 @@ class Config:
         for what, set_ in refused.items():
             if set_:
                 raise ValueError(f"model {model} does not take {what}")
+
+    def _validate_lfm2_moe(self) -> None:
+        """What the short-convolution / GQA MoE decoder takes, and plainly
+        what it does not (models.lfm2_moe.Lfm2Moe)."""
+        kinds = self.layer_type_list
+        moe_layers = self.decoder_layers - self.dense_layers
+        need = {
+            "decoder_layers >= 1": self.decoder_layers >= 1,
+            "layer_types: decoder_layers words, each conv or full_attention":
+                len(kinds) == self.decoder_layers and all(
+                    kind in ("conv", "full_attention") for kind in kinds),
+            "conv_taps >= 1 where a layer is conv":
+                "conv" not in kinds or self.conv_taps >= 1,
+            "attn_q_heads a positive multiple of attn_kv_heads >= 1 and an "
+            "even attn_head_dim (rotate-half rotary) where a layer is "
+            "full_attention": "full_attention" not in kinds or (
+                self.attn_kv_heads >= 1 and self.attn_q_heads >= 1
+                and self.attn_q_heads % self.attn_kv_heads == 0
+                and self.attn_head_dim >= 2 and self.attn_head_dim % 2 == 0),
+            "0 <= dense_layers <= decoder_layers": 0 <= self.dense_layers
+                <= self.decoder_layers,
+            "dense_mlp_width >= 1 where a layer is dense":
+                self.dense_layers == 0 or self.dense_mlp_width >= 1,
+            "1 <= moe_top_k <= moe_experts, moe_expert_width >= 1 and "
+            "moe_route_scale > 0 where a layer has experts":
+                moe_layers == 0 or (
+                    1 <= self.moe_top_k <= self.moe_experts
+                    and self.moe_expert_width >= 1
+                    and self.moe_route_scale > 0),
+            "moe_experts_held >= 1 experts from moe_first_expert on, all "
+            "among the moe_experts": moe_layers == 0 or (
+                self.moe_experts_held >= 1 and self.moe_first_expert >= 0
+                and self.moe_first_expert + self.moe_experts_held
+                <= self.moe_experts),
+            "moe_pair_capacity >= 1 (rows of a layer's pair buffer; every "
+            "pair of a step is batch_size * history_max_len * moe_top_k)":
+                moe_layers == 0 or self.moe_pair_capacity >= 1,
+            "history_max_len >= 2 (the sequence length; the loss is of the "
+            "next token)": self.history_max_len >= 2,
+            "feature_size >= 2": self.feature_size >= 2,
+        }
+        for what, ok in need.items():
+            if not ok:
+                raise ValueError(f"model lfm2_moe needs {what}")
+        self._refuse_for_a_decoder("lfm2_moe")
 
     def _validate_solar_open2(self) -> None:
         """What the gated-GQA / KDA MoE decoder takes, and plainly what it
@@ -1048,6 +1115,10 @@ class Config:
         self._refuse_for_a_decoder("solar_open2")
 
     # ---- derived views ------------------------------------------------
+    @property
+    def layer_type_list(self) -> List[str]:
+        return [x.strip() for x in self.layer_types.split(",") if x.strip()]
+
     @property
     def deep_layer_sizes(self) -> List[int]:
         return [int(x) for x in self.deep_layers.split(",") if x.strip()]

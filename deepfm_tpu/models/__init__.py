@@ -4,7 +4,7 @@
 (``--tasks ctr,cvr``) builds the multi-task model (``--multitask``
 architecture over the shared graph bottom); otherwise ``cfg.model`` picks a
 single-task graph from the registry, or one of the decoders (``sdar_moe``,
-``kimi_linear``, ``solar_open2``), which are no rankers.
+``kimi_linear``, ``solar_open2``, ``lfm2_moe``), which are no rankers.
 """
 
 from typing import Union
@@ -15,6 +15,7 @@ from .graph import GraphDCNv2 as DCNv2
 from .graph import GraphDeepFM as DeepFM
 from .graph import GraphWideDeep as WideDeep
 from .kimi_linear import KimiLinear
+from .lfm2_moe import Lfm2Moe
 from .multitask import MultiTaskModel  # noqa: F401
 from .sdar_moe import SdarMoE
 from .sequence import GraphBST, GraphDIN  # noqa: F401
@@ -31,10 +32,12 @@ _REGISTRY = {
     "sdar_moe": SdarMoE,
     "kimi_linear": KimiLinear,
     "solar_open2": SolarOpen2,
+    "lfm2_moe": Lfm2Moe,
 }
 
 CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDLRMDCNv2, GraphDIN,
-                 GraphBST, SdarMoE, KimiLinear, SolarOpen2, MultiTaskModel]
+                 GraphBST, SdarMoE, KimiLinear, SolarOpen2, Lfm2Moe,
+                 MultiTaskModel]
 
 
 def registered_models():

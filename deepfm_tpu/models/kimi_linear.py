@@ -291,11 +291,16 @@ class KimiLinear(GraphModel):
     owns_loss = True
     #: (``models.sdar_moe.SdarMoE.kernel_scopes``)
     kernel_scopes = (("ragged-dot", "moe"),)
+    #: Whether the head's matrix is the token table (no ``head`` leaf).
+    tied_head = False
+    #: cfg -> ((mixer, feed-forward) of each layer); a model with another
+    #: pattern names its own
+    _kinds = staticmethod(layer_kinds)
 
     def __init__(self, cfg: Any):
         super().__init__(cfg)
         self.cdt = jnp.dtype(cfg.compute_dtype)
-        self.kinds = layer_kinds(cfg)
+        self.kinds = self._kinds(cfg)
         #: What the traced step is made of, said beside its counts on
         #: ``train.log_sync`` while tracing is on (``hidden`` adds the expert
         #: layers' ``sdar_moe.moe_notes``).
@@ -373,9 +378,11 @@ class KimiLinear(GraphModel):
             lp.update({
                 "router": glorot(d, cfg.moe_experts),
                 "w_gate": glorot(held, d, f), "w_up": glorot(held, d, f),
-                "w_down": glorot(held, f, d),
-                "shared_w_gate": glorot(d, fs), "shared_w_up": glorot(d, fs),
-                "shared_w_down": glorot(fs, d)})
+                "w_down": glorot(held, f, d)})
+            if fs:      # (a model without a shared expert: lfm2_moe)
+                lp.update({"shared_w_gate": glorot(d, fs),
+                           "shared_w_up": glorot(d, fs),
+                           "shared_w_down": glorot(fs, d)})
         return lp
 
     def init(self, rng: jax.Array) -> Tuple[common.Params, common.State]:
@@ -387,8 +394,10 @@ class KimiLinear(GraphModel):
             "layers": {str(i): self._init_layer(k, *kind) for i, (k, kind)
                        in enumerate(zip(k_layers, self.kinds))},
             "final_norm": jnp.ones((d,), jnp.float32),
-            "head": common.glorot_uniform(k_head, (d, cfg.feature_size)),
         }
+        if not self.tied_head:
+            params["head"] = common.glorot_uniform(
+                k_head, (d, cfg.feature_size))
         return params, self.init_counts()
 
     def _rows_by(self, ids: jnp.ndarray, one_device: bool) -> str:
@@ -439,8 +448,10 @@ class KimiLinear(GraphModel):
             lp, h, top_k=cfg.moe_top_k, first_expert=cfg.moe_first_expert,
             capacity=cfg.moe_pair_capacity, eps=eps, cdt=self.cdt,
             route_by=self.route_by, rows_by=rows_by)
-        return (h + y + swiglu(lp, "shared_", h, eps=eps, cdt=self.cdt),
-                {**counts, **moe_counts})
+        out = h + y
+        if "shared_w_gate" in lp:
+            out = out + swiglu(lp, "shared_", h, eps=eps, cdt=self.cdt)
+        return out, {**counts, **moe_counts}
 
     def hidden(self, params: common.Params, ids: jnp.ndarray, *,
                shard_axis: Optional[str] = None,
@@ -467,9 +478,12 @@ class KimiLinear(GraphModel):
     @jax.named_scope("head")
     def logits(self, params: common.Params, h: jnp.ndarray) -> jnp.ndarray:
         """[..., d] of the last residual stream -> [..., V]: final norm and
-        head product."""
+        head product; a tied head's matrix is the token table's real rows,
+        transposed (the leaf's two uses sum in its gradient)."""
         hn = rms_norm(h, params["final_norm"], self.cfg.rms_norm_eps)
-        return _dot(hn, params["head"], self.cdt)
+        head = (params["tok_emb"][: self.cfg.feature_size].T
+                if self.tied_head else params["head"])
+        return _dot(hn, head, self.cdt)
 
     def _run(self, params, state, tokens, shard_axis, data_axis, emb):
         tokens = tokens.astype(jnp.int32)

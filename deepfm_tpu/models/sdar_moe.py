@@ -88,6 +88,7 @@ not outlive the chunk.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -104,6 +105,10 @@ from .graph import GraphModel
 COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
                "moe_expert_load_max", "moe_layer_pairs_max",
                "masked_positions")
+#: (position, layer) selections of a step whose ``top_k`` experts are not the
+#: ``top_k`` largest unbiased scores: counted where a layer has a selection
+#: bias, so that a run says whether the bias decided anything.
+BIAS_MOVED = "moe_bias_moved_picks"
 #: Queries a chunk of attention scores holds, and positions a chunk of the
 #: head's logits (all of them when the sequence does not divide).
 QUERY_CHUNK = 1024
@@ -299,9 +304,9 @@ def attn_notes(scores_by: str, mask: ScoreMask, seq: int, group: int
 def attn_scores_by(seq: int, head_dim: int, *, one_device: bool = True,
                    backend: Optional[str] = None) -> str:
     """``kernel`` where the masked attention kernel applies (a TPU
-    backend, ``head_dim`` whole 128-lane lines, a sequence its block
-    divides: ``ops/block_attention.supported``; and a step that is one
-    device's program: the shipped kernel does not say how its results vary
+    backend, ``head_dim`` whole 128-lane lines or half lines, a sequence
+    its block divides: ``ops/block_attention.supported``; and a step that is
+    one device's program: the shipped kernel does not say how its results vary
     over a mesh's axes, which a step across data replicas is checked for),
     else ``xla``: what the compiled step's attention is made of, read from
     the backend, the shapes and the mesh."""
@@ -331,12 +336,18 @@ def masked_scores(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 @jax.named_scope("attn")
 def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
-              positions: jnp.ndarray, *, length: int, block: int,
+              positions: jnp.ndarray, *, mask: ScoreMask,
               head_dim: int, eps: float, theta: float,
-              cdt: jnp.dtype, scores_by: str = "xla") -> jnp.ndarray:
+              cdt: jnp.dtype, scores_by: str = "xla",
+              scores_scope: Optional[str] = None) -> jnp.ndarray:
     """The held heads' part of ``Attn(RMSNorm(x))``: x [B, S, d] -> [B, S, d]
-    (``wo``'s sum over the held heads, unreduced). ``scores_by`` is
-    ``attn_scores_by``'s word for what makes the masked scores."""
+    (``wo``'s sum over the held heads, unreduced): grouped-query attention
+    with a per-head RMS norm of q and k and rotary positions, under
+    ``mask`` (this model's ``block_diffusion``, ``models.lfm2_moe``'s
+    ``kimi_linear.causal``). ``scores_by`` is ``attn_scores_by``'s word for
+    what makes the masked scores; ``scores_scope`` names a scope of their
+    own for them inside ``attn`` (a model whose metrics read the scores
+    apart)."""
     b, s, _ = x.shape
     xn = rms_norm(x, lp["norm1"], eps)
     q = _dot(xn, lp["wq"], cdt).reshape(b, s, -1, head_dim)
@@ -344,34 +355,44 @@ def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
     v = _dot(xn, lp["wv"], cdt).reshape(b, s, -1, head_dim)
     q = rotary(rms_norm(q, lp["q_norm"], eps), positions, theta)
     k = rotary(rms_norm(k, lp["k_norm"], eps), positions, theta)
-    out = masked_scores(q, _operand(k, cdt), _operand(v, cdt),
-                        mask=block_diffusion(length, block), cdt=cdt,
-                        scores_by=scores_by)
+    with (jax.named_scope(scores_scope) if scores_scope
+          else contextlib.nullcontext()):
+        out = masked_scores(q, _operand(k, cdt), _operand(v, cdt),
+                            mask=mask, cdt=cdt, scores_by=scores_by)
     return _dot(out, lp["wo"], cdt)
 
 
 def route(xn: jnp.ndarray, router: jnp.ndarray, top_k: int, *,
           score: Callable = functools.partial(jax.nn.softmax, axis=-1),
-          bias: Optional[jnp.ndarray] = None, scale: float = 1.0
-          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """xn [T, d] -> (experts [T, k], weights [T, k]): the k largest of a
-    float32 ``score`` of every expert's logit (a softmax over the experts
-    unless the model hands in another: ``jax.nn.sigmoid``), their scores
-    renormalised and times ``scale``. ``bias`` [E] is added to the scores
-    for the selection only: it moves which experts are the k, never their
-    weights. Equal scores go to the lower index. The product is float32 at
-    full precision: it is 1/40 of a layer's work, and a rounded logit moves
-    which expert is the k-th."""
+          bias: Optional[jnp.ndarray] = None, scale: float = 1.0,
+          renorm_eps: float = 0.0
+          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """xn [T, d] -> (experts [T, k], weights [T, k], moved []): the k largest
+    of a float32 ``score`` of every expert's logit (a softmax over the
+    experts unless the model hands in another: ``jax.nn.sigmoid``), their
+    scores renormalised (over their sum + ``renorm_eps``, where a model's
+    published form has one) and times ``scale``. ``bias`` [E] is added to the
+    scores for the selection only: it moves which experts are the k, never
+    their weights; ``moved`` counts the positions where it did (an expert
+    left out scores above one taken; none without a bias). Equal scores go
+    to the lower index. The product is float32 at full precision: it is 1/40
+    of a layer's work, and a rounded logit moves which expert is the k-th."""
     logits = jnp.matmul(xn.astype(jnp.float32), router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     scores = score(logits)
     if bias is None:
         top_p, top_e = jax.lax.top_k(scores, top_k)
+        moved = jnp.zeros((), jnp.int32)
     else:
         _, top_e = jax.lax.top_k(scores + bias, top_k)
         top_p = jnp.take_along_axis(scores, top_e, axis=-1)
-    weights = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    return top_e, weights if scale == 1.0 else weights * scale
+        # scores above the lowest taken: more among all than among the taken
+        lowest = jnp.min(top_p, axis=-1, keepdims=True)
+        moved = jnp.sum(jnp.sum(scores > lowest, axis=-1)
+                        > jnp.sum(top_p > lowest, axis=-1), dtype=jnp.int32)
+    total = jnp.sum(top_p, axis=-1, keepdims=True)
+    weights = top_p / (total + renorm_eps if renorm_eps else total)
+    return top_e, (weights if scale == 1.0 else weights * scale), moved
 
 
 def pass_rows(capacity: int) -> Tuple[int, int]:
@@ -422,15 +443,19 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
     ``capacity`` of the sorted pairs (``pass_rows``: at least that many) are
     computed, in equal passes of at most ``PASS_ROWS`` rows, each made again
     in the backward pass, so that the layer's memory is one pass's; pairs
-    beyond them are counted and add nothing. ``route_by(xn, router, top_k)``
-    is the model's router (``route``, with what the model binds of its
+    beyond them are counted and add nothing. ``route_by(xn, router, top_k,
+    bias=)`` is the model's router (``route``, with what the model binds of its
     keywords); ``rows_by`` is ``moe_rows_by``'s word for what moves a pass's
-    rows from and to their positions."""
+    rows from and to their positions. Where the layer has a selection bias
+    (``lp['select_bias']`` [experts]: ``route``'s ``bias``) the router picks
+    by score + bias, and the layer's counts say at how many positions the bias
+    moved the picks (``BIAS_MOVED``)."""
     shape = x.shape
     xn = rms_norm(x, lp["norm2"], eps).reshape(-1, shape[-1])
     n_tok = xn.shape[0]
     n_held = lp["w_gate"].shape[0]
-    top_e, top_w = route_by(xn, lp["router"], top_k)
+    bias = lp.get("select_bias")
+    top_e, top_w, moved = route_by(xn, lp["router"], top_k, bias=bias)
     # Sort the (position, expert) pairs by held expert; absent ones last.
     local = top_e.reshape(-1) - first_expert
     key = jnp.where((local >= 0) & (local < n_held), local, n_held)
@@ -499,7 +524,8 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
     counts = {"moe_pairs_held": held,
               "moe_pairs_over_buffer": jnp.maximum(held - capacity, 0),
               "moe_expert_load_max": jnp.max(load),
-              "moe_layer_pairs_max": held}
+              "moe_layer_pairs_max": held,
+              **({} if bias is None else {BIAS_MOVED: moved})}
     return out.reshape(shape), counts
 
 
@@ -624,6 +650,7 @@ class SdarMoE(GraphModel):
         rows_by = moe_rows_by(cfg.embedding_size, ids.size,
                               cfg.moe_pair_capacity,
                               one_device=data_axis is None)
+        mask = block_diffusion(length, cfg.diffusion_block)
         self.step_notes = {
             **self._attn_notes(scores_by, seq, length),
             **moe_notes(rows_by, cfg.moe_pair_capacity, cfg.decoder_layers)}
@@ -638,7 +665,7 @@ class SdarMoE(GraphModel):
             # does not have to spare).
             lp = jax.lax.optimization_barrier(lp)
             h = x + attention(
-                lp, x, positions, length=length, block=cfg.diffusion_block,
+                lp, x, positions, mask=mask,
                 head_dim=cfg.attn_head_dim, eps=cfg.rms_norm_eps,
                 theta=cfg.rope_theta, cdt=self.cdt, scores_by=scores_by)
             y, counts = expert_layer(

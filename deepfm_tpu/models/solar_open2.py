@@ -94,10 +94,7 @@ class SolarOpen2(KimiLinear):
     module's docstring."""
 
     name = "solar_open2"
-
-    def __init__(self, cfg: Any):
-        super().__init__(cfg)
-        self.kinds = layer_kinds(cfg)
+    _kinds = staticmethod(layer_kinds)
 
     def init_counts(self) -> common.State:
         return {**{n: jnp.zeros((), jnp.int32)

@@ -39,15 +39,20 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_mask as _mask,
 )
 
-#: Lanes of one vector register line: head_dim must be whole lines.
+#: Lanes of one vector register line: a head's rows are whole lines or half
+#: lines.
 LANES = 128
 
 
 def supported(backend: str, seq: int, head_dim: int, block: int) -> bool:
     """True where the compiled kernel applies: a TPU backend, a head whose
-    rows are whole 128-lane lines, and a sequence the kernel's block
-    divides."""
-    return backend == "tpu" and head_dim % LANES == 0 and seq % block == 0
+    rows are whole 128-lane lines or half lines, and a sequence the kernel's
+    block divides. A head of 64 goes to the kernel as it is: on a v5e it
+    takes the time of a head of 128 (q [2, 8192, 8, 4, 64] under the causal
+    mask, forward / forward and backward 12.1 / 46.7 ms; real heads of 128
+    12.4 / 46.0 ms: the MXU's line is the unit; PERF.md section 6, PR 40)."""
+    return backend == "tpu" and head_dim % (LANES // 2) == 0 \
+        and seq % block == 0
 
 
 class PairMask(_mask.Mask):

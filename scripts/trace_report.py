@@ -60,19 +60,27 @@ hashed tables, row shards, accumulation), all-reduced as tables under data
 replicas, with a line that says so.
 
 Where the model says what makes its attention's masked scores
-(``--model sdar_moe``, ``--model solar_open2``), each ``train.log_sync``
-carries ``attn_scores`` (``kernel``: one Pallas call that visits only the
-blocks of the score matrix the model's mask, block-diffusion or causal,
-leaves something in; ``xla``: every score of every query chunk) and, of the
-kernel, ``attn_score_blocks`` (visited / all, a head); the report prints
-them on its "block-masked attention" line
+(``--model sdar_moe``, ``--model solar_open2``, ``--model lfm2_moe``), each
+``train.log_sync`` carries ``attn_scores`` (``kernel``: one Pallas call that
+visits only the blocks of the score matrix the model's mask,
+block-diffusion or causal, leaves something in; ``xla``: every score of
+every query chunk) and, of the kernel, ``attn_score_blocks`` (visited / all,
+a head); the report prints them on its "block-masked attention" line
 (TUNING §5).
 
-Both decoders say how their expert layers' rows go to and from their
+The decoders say how their expert layers' rows go to and from their
 positions: ``moe_rows`` (``kernel``: one copy a row over the pairs really
 held, ``ops/pallas_moe_rows``; ``xla``: ``take`` and scatter-add over every
 row of the buffer) and ``moe_rows_moved`` (the step's held pairs over the
-buffers' rows); the report prints them on its "expert layers' rows" line.
+buffers' rows); the report prints them on its "expert layers' rows" line,
+and where the router has a selection bias (``lfm2_moe``) the mean of
+``moe_bias_moved_picks``, the (position, layer) selections of a step the
+bias changed.
+
+Where the model mixes by a gated short convolution (``--model lfm2_moe``),
+each ``train.log_sync`` carries ``conv_taps_by`` (what computes the mixer's
+elementwise passes: ``xla``); the report prints its "gated short
+convolution" line.
 
 Where the model scans a delta-rule recurrence (``--model kimi_linear``,
 ``--model solar_open2``), each ``train.log_sync`` carries ``kda_scan`` (the
@@ -379,6 +387,16 @@ def attention_scores(events):
     return out
 
 
+def short_convolution(events):
+    """What computes the gated short convolution's elementwise passes, off
+    the ``train.log_sync`` spans that say so (``conv_taps_by``): ``steps``
+    read and ``taps_by``; None when no span says (another model)."""
+    seen = _log_syncs(events, "conv_taps_by")
+    if not seen:
+        return None
+    return {"steps": len(seen), "taps_by": seen[-1]["conv_taps_by"]}
+
+
 def delta_rule_scan(events):
     """The delta-rule scan's notes and count off the ``train.log_sync`` spans
     that carry them: ``steps`` read, ``scan`` (``kda_scan``: algorithm and
@@ -406,15 +424,22 @@ def expert_rows(events):
     """How the expert layers' rows moved, off the ``train.log_sync`` spans
     that say so: ``steps`` read, ``rows`` (``moe_rows``: ``kernel``, one copy
     a row over the pairs held, or ``xla``, every row of the buffer) and the
-    mean ``held`` of ``buffer`` rows a step (``moe_rows_moved``); None when
-    no span has them (another model, or a trace that predates them)."""
+    mean ``held`` of ``buffer`` rows a step (``moe_rows_moved``) and, where
+    the spans carry ``moe_bias_moved_picks`` (a router with a selection
+    bias), ``bias_moved_picks``, its mean a step; None when no span has them
+    (another model, or a trace that predates them)."""
     seen = _log_syncs(events, "moe_rows")
     if not seen:
         return None
     moved = [a["moe_rows_moved"].split("/") for a in seen]
-    return {"steps": len(seen), "rows": seen[-1]["moe_rows"],
-            "held": sum(int(h) for h, _ in moved) / len(moved),
-            "buffer": int(moved[-1][1])}
+    out = {"steps": len(seen), "rows": seen[-1]["moe_rows"],
+           "held": sum(int(h) for h, _ in moved) / len(moved),
+           "buffer": int(moved[-1][1])}
+    picks = [a["moe_bias_moved_picks"] for a in seen
+             if "moe_bias_moved_picks" in a]
+    if picks:
+        out["bias_moved_picks"] = sum(picks) / len(picks)
+    return out
 
 
 def main(argv=None):
@@ -436,6 +461,7 @@ def main(argv=None):
     touched = row_updates(events)
     attn = attention_scores(events)
     scan = delta_rule_scan(events)
+    conv = short_convolution(events)
     moved = expert_rows(events)
     boots = start_up(events)
 
@@ -452,6 +478,8 @@ def main(argv=None):
             doc["attention_scores"] = attn
         if scan is not None:
             doc["delta_rule_scan"] = scan
+        if conv is not None:
+            doc["short_convolution"] = conv
         if moved is not None:
             doc["expert_rows"] = moved
         if boots:
@@ -505,6 +533,9 @@ def main(argv=None):
                  "(%.1f%%)" % (attn["visited"], attn["total"],
                                100 * attn["visited"] / attn["total"])
                  if "visited" in attn else ", every score computed"))
+    if conv is not None:
+        print("gated short convolution over %d logged steps: taps and gates "
+              "by %s" % (conv["steps"], conv["taps_by"]))
     if scan is not None:
         low = scan["log_decay_min"]
         print("delta-rule scan over %d logged steps: %s" % (
@@ -519,7 +550,10 @@ def main(argv=None):
         print("expert layers' rows over %d logged steps: moved by %s, %.0f "
               "of %d buffer rows a step held a pair (%.1f%%)"
               % (moved["steps"], moved["rows"], moved["held"],
-                 moved["buffer"], 100 * moved["held"] / moved["buffer"]))
+                 moved["buffer"], 100 * moved["held"] / moved["buffer"])
+              + (", the selection bias moved %.0f picks a step"
+                 % moved["bias_moved_picks"]
+                 if "bias_moved_picks" in moved else ""))
     for boot in boots:
         print(f"start-up of pid {boot['pid']}: {boot['line']}")
         print(f"  {'phase':<46}{'at_s':>9}{'incl_s':>9}{'self_s':>9}")

@@ -246,7 +246,7 @@ def test_sigmoid_router_by_hand():
                         [0.0, 0.0, 0.0, 0.0], [0.0, 0.0, 0.0, 0.0]])
     by = functools.partial(sdar_moe.route, score=jax.nn.sigmoid, scale=2.446)
     sig = lambda z: 1.0 / (1.0 + np.exp(-z))      # noqa: E731
-    experts, weights = by(x, router, 2)
+    experts, weights, _ = by(x, router, 2)
     # token 1's experts 0 and 3 tie at sigmoid(3): the lower index first
     np.testing.assert_array_equal(experts, [[2, 1], [0, 3]])
     np.testing.assert_allclose(
@@ -256,8 +256,9 @@ def test_sigmoid_router_by_hand():
     np.testing.assert_allclose(jnp.sum(weights, axis=-1), 2.446, rtol=1e-6)
     # a bias moves the selection and not the weights' values
     bias = jnp.array([0.0, 0.0, -1.0, 0.5])
-    experts_b, weights_b = by(x, router, 2, bias=bias)
+    experts_b, weights_b, moved = by(x, router, 2, bias=bias)
     np.testing.assert_array_equal(experts_b, [[3, 1], [3, 0]])
+    assert int(moved) == 1      # token 0's; token 1 keeps its two, reordered
     np.testing.assert_allclose(
         weights_b[0], [2.446 * sig(-1) / (sig(1) + sig(-1)),
                        2.446 * sig(1) / (sig(1) + sig(-1))], rtol=1e-6)

@@ -1,0 +1,669 @@
+"""``--model lfm2_moe`` (gated short-convolution mixers 3:1 with rotary
+QK-norm grouped-query attention, sigmoid router with a selection bias and no
+shared expert, tied token table) at small widths on the CPU, from seeded
+weights, against the plain reference (``benchmark/reference_lfm2_moe.py``):
+each layer kind's forward; loss, every leaf's gradient and three Adam steps
+of the stack, float32 and bfloat16; the convolution against a loop over
+positions; selection by score + bias with weights by score; the bias bit for
+bit after three steps; the parameter counts at the published widths from the
+model's own leaves; the share test (the four expert shares of a 4-way layer
+add up to the uncut reference's layer); ``sdar_moe.attention`` under the
+block-diffusion mask unchanged by the mask becoming an argument; the kernel
+path at heads half a lane line wide through the Pallas interpreter; what
+``Config`` refuses; the scopes and notes of the compiled step; and a fit from
+TFRecord shards."""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark import reference_lfm2_moe as ref  # noqa: E402
+from benchmark import reference_sdar_moe  # noqa: E402
+from benchmark.drivers import _program  # noqa: E402
+from benchmark.reference_sdar_moe import leaf_gap, worst_leaf_gap  # noqa: E402
+from deepfm_tpu.config import Config  # noqa: E402
+from deepfm_tpu.data import example_codec, tfrecord  # noqa: E402
+from deepfm_tpu.models import (get_model, kimi_linear, lfm2_moe,  # noqa: E402
+                               registered_models, sdar_moe)
+from deepfm_tpu.ops import block_attention  # noqa: E402
+from deepfm_tpu.parallel import mesh as mesh_lib  # noqa: E402
+from deepfm_tpu.train import Trainer  # noqa: E402
+from deepfm_tpu.utils import profiling  # noqa: E402
+
+V, L, B = 60, 24, 2
+#: The cut's own order at small widths: the dense layer, the full layer,
+#: then convolution layers with experts.
+SMALL = dict(model="lfm2_moe", feature_size=V, field_size=1,
+             embedding_size=32, history_max_len=L, decoder_layers=4,
+             layer_types="conv,full_attention,conv,conv", conv_taps=3,
+             dense_layers=1, dense_mlp_width=48,
+             attn_q_heads=4, attn_kv_heads=2, attn_head_dim=8,
+             rope_theta=1e6, rms_norm_eps=1e-5,
+             moe_experts=8, moe_top_k=2, moe_expert_width=16,
+             moe_experts_held=4, moe_first_expert=2,
+             moe_pair_capacity=B * L * 2, batch_size=B, l2_reg=0.0,
+             learning_rate=1e-3, steps_per_loop=1)
+SIZES = dict(head_dim=8, eps=1e-5, theta=1e6, top_k=2, route_scale=1.0,
+             first_expert=2)
+F32 = jnp.dtype("float32")
+#: float32 program against float32 reference; bfloat16 compute has to miss it.
+TOL = 2e-4
+KINDS = {"conv+mlp": ("conv", "mlp"), "conv+moe": ("conv", "moe"),
+         "full_attention+moe": ("full_attention", "moe")}
+#: The shortest stack with every kind of layer: what the tests of a whole
+#: trainer step compile.
+TRIO = dict(decoder_layers=3, layer_types="conv,full_attention,conv")
+
+
+def config(**kw):
+    return Config(**{**SMALL, "compute_dtype": "float32", **kw})
+
+
+def flat(params):
+    """The program's parameter tree under the reference's names, the token
+    table cut to the vocabulary's rows."""
+    leaves, _ = jax.tree_util.tree_flatten_with_path(params)
+    out = {_program.leaf_name(p): np.asarray(x) for p, x in leaves}
+    out["tok_emb"] = out["tok_emb"][:V]
+    return out
+
+
+def sequences(n, seed):
+    return np.random.default_rng(seed).integers(0, V, (n, L)).astype(np.int32)
+
+
+def batch_of(tokens):
+    n = tokens.shape[0]
+    return {"feat_ids": np.zeros((n, 1), np.int32),
+            "feat_vals": np.ones((n, 1), np.float32),
+            "label": np.zeros((n, 1), np.float32), "hist_ids": tokens,
+            "hist_mask": np.ones(tokens.shape, np.float32)}
+
+
+def off_one(key, tree):
+    """``tree`` with every gain (a leaf of ones) moved off one."""
+    leaves, treedef = jax.tree.flatten(tree)
+    keys = jax.random.split(key, len(leaves))
+    return jax.tree.unflatten(treedef, [
+        x + 0.1 * jax.random.normal(k, x.shape)
+        if x.ndim == 1 and bool(jnp.all(x == 1.0)) else x
+        for k, x in zip(keys, leaves)])
+
+
+def a_bias(model, seed=7, scale=0.05):
+    """A selection bias large enough to move picks at these widths."""
+    return scale * jax.random.normal(
+        jax.random.PRNGKey(seed), model.init_bias().shape, jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    """(model, params with gains moved off one, state with a bias)."""
+    model = get_model(config())
+    params, state = model.init(jax.random.PRNGKey(0))
+    state = {**state, lfm2_moe.SELECT_BIAS: a_bias(model)}
+    return model, off_one(jax.random.PRNGKey(5), params), state
+
+
+def a_layer(kind, experts=8, held=8, d=32, **kw):
+    """One layer's leaves, gains off one, and its selection bias."""
+    cfg = config(moe_experts=experts, moe_experts_held=held,
+                 moe_first_expert=0, embedding_size=d, **kw)
+    lp = off_one(jax.random.PRNGKey(4),
+                 get_model(cfg)._init_layer(jax.random.PRNGKey(3), *kind))
+    if kind[1] == "moe":
+        lp["select_bias"] = 0.05 * jax.random.normal(
+            jax.random.PRNGKey(9), (experts,), jnp.float32)
+    return lp
+
+
+# ------------------------------------------------ each layer kind's forward
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_a_layer_matches_the_reference(kind):
+    model = get_model(config(moe_experts_held=8, moe_first_expert=0))
+    lp = a_layer(KINDS[kind])
+    x = 2.0 * jax.random.normal(jax.random.PRNGKey(1), (B, L, 32))
+    got, counts = model._layer(*KINDS[kind], x, lp)
+    with jax.default_matmul_precision("highest"):
+        want = ref.layer(x, lp, {**SIZES, "first_expert": 0})
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert ("moe_pairs_held" in counts) == (KINDS[kind][1] == "moe")
+    assert (sdar_moe.BIAS_MOVED in counts) == (KINDS[kind][1] == "moe")
+
+
+def test_logits_and_loss_match_the_reference(seeded):
+    model, params, state = seeded
+    tokens = jnp.asarray(sequences(B, 0))
+    logits, counts = jax.jit(lambda p, s: model.apply(
+        p, s, None, None, train=True, hist_ids=tokens))(params, state)
+    per_seq, _ = jax.jit(lambda p, s: model.per_example_loss(
+        p, s, {"hist_ids": tokens}, train=True, rng=None))(params, state)
+    with jax.default_matmul_precision("highest"):
+        want_loss, want_logits = jax.jit(
+            lambda p, b: ref.forward_loss(p, tokens, b, SIZES))(
+            {k: jnp.asarray(v) for k, v in flat(params).items()},
+            state[lfm2_moe.SELECT_BIAS])
+    assert logits.shape == (B, L, V)
+    np.testing.assert_allclose(logits, want_logits, atol=2e-5)
+    np.testing.assert_allclose(jnp.mean(per_seq), want_loss, rtol=1e-6)
+    assert int(counts["moe_pairs_over_buffer"]) == 0
+    assert int(counts["moe_pairs_held"]) > 0
+    # three expert layers of B x L positions: the bias moved some picks
+    assert 0 < int(counts[sdar_moe.BIAS_MOVED]) < 3 * B * L
+    # the state hands the bias on as it came; the metrics leave it out
+    np.testing.assert_array_equal(counts[lfm2_moe.SELECT_BIAS],
+                                  state[lfm2_moe.SELECT_BIAS])
+    assert lfm2_moe.SELECT_BIAS not in model.step_counts(counts)
+    assert sdar_moe.BIAS_MOVED in model.step_counts(counts)
+    assert "head" not in params     # the head is the table
+
+
+def _mixers(lp, x, sizes, **broken):
+    xn = ref.rms_norm(x, lp["norm1"], 1e-5)
+    conv_kw = {k: v for k, v in broken.items() if k in ("taps_ahead", "gate")}
+    attn_kw = {k: v for k, v in broken.items() if k == "rotate_k"}
+    return ref.conv(xn, lp, **conv_kw) + ref.attention(xn, lp, sizes,
+                                                       **attn_kw)
+
+
+@pytest.mark.parametrize("broken, moved", [
+    ({"taps_ahead": 1}, True), ({"gate": False}, True),
+    ({"rotate_k": False}, True), ({}, False)],
+    ids=["conv-a-tap-ahead", "output-gate-left-out", "k-not-rotated",
+         "sound"])
+def test_the_references_broken_mixers_differ_from_the_sound_ones(broken,
+                                                                 moved):
+    """What the reference's own switches leave out moves its result: each
+    mechanism of the two mixers is in the mathematics."""
+    lp = {**a_layer(KINDS["conv+moe"]), **a_layer(KINDS["full_attention+moe"])}
+    x = 2.0 * jax.random.normal(jax.random.PRNGKey(1), (B, L, 32))
+    with jax.default_matmul_precision("highest"):
+        got = _mixers(lp, x, SIZES, **broken)
+        want = _mixers(lp, x, SIZES)
+    assert (leaf_gap(got, want) > 0.05) == moved
+
+
+# ------------------------------------------------- the gated convolution
+
+def test_the_convolution_is_a_loop_over_positions():
+    """``conv_mixer`` against the equations written a position at a time:
+    ``c_t = sum_j w_j z_{t-2+j}`` with ``z = B * u``, nothing read before the
+    first position, the output gated by C."""
+    lp = a_layer(KINDS["conv+mlp"])
+    x = 2.0 * jax.random.normal(jax.random.PRNGKey(2), (B, L, 32))
+    got = lfm2_moe.conv_mixer(lp, x, eps=1e-5, cdt=F32)
+    xn = np.asarray(ref.rms_norm(x, lp["norm1"], 1e-5), np.float64)
+    bcu = xn @ np.asarray(lp["conv_w_in"], np.float64)
+    b_, c_, u = bcu[..., :32], bcu[..., 32:64], bcu[..., 64:]
+    z, w = b_ * u, np.asarray(lp["conv_w"], np.float64)
+    y = np.zeros_like(z)
+    for t in range(L):
+        for j in range(3):
+            if t - 2 + j >= 0:
+                y[:, t] += w[j] * z[:, t - 2 + j]
+    want = (c_ * y) @ np.asarray(lp["conv_w_out"], np.float64)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # causal: a later position moves no earlier output
+    x2 = x.at[:, L // 2:].add(1.0)
+    got2 = lfm2_moe.conv_mixer(lp, x2, eps=1e-5, cdt=F32)
+    np.testing.assert_array_equal(got[:, :L // 2], got2[:, :L // 2])
+    assert leaf_gap(got2[:, L // 2:], got[:, L // 2:]) > 0.01
+
+
+# ------------------------------------------------------ the biased router
+
+def test_the_bias_moves_the_pick_and_never_the_weights():
+    """Scores 0.9 / 0.8 / 0.7 / 0.1 with a bias of +0.25 on the third: the
+    two picked are experts 0 and 2 (0.9, 0.95), not 0 and 1, and the weights
+    are 0.9 and 0.7 over their sum + 1e-6: the bias is in no weight."""
+    scores = np.asarray([0.9, 0.8, 0.7, 0.1])
+    logits = jnp.asarray(np.log(scores / (1 - scores)), jnp.float32)[None]
+    xn, router = jnp.ones((1, 1), jnp.float32), logits
+    bias = jnp.asarray([0.0, 0.0, 0.25, 0.0])
+    route_by = get_model(config()).route_by
+    plain_e, plain_w, plain_moved = route_by(xn, router, 2)
+    top_e, top_w, moved = route_by(xn, router, 2, bias=bias)
+    assert sorted(np.asarray(plain_e[0])) == [0, 1]
+    assert sorted(np.asarray(top_e[0])) == [0, 2]
+    assert (int(plain_moved), int(moved)) == (0, 1)
+    # a bias that leaves the two largest the two largest moves nothing
+    assert int(route_by(xn, router, 2, bias=bias / 5)[2]) == 0
+    want = {0: 0.9 / (1.6 + 1e-6), 2: 0.7 / (1.6 + 1e-6)}
+    for e, w in zip(np.asarray(top_e[0]), np.asarray(top_w[0])):
+        assert w == pytest.approx(want[int(e)], rel=1e-6)
+    # the reference says the same, and its broken form weighs by the pick
+    sizes = {"top_k": 2, "route_scale": 1.0}
+    with jax.default_matmul_precision("highest"):
+        w_ref = np.asarray(ref.router_weights(xn, router, bias, sizes))[0]
+        w_bad = np.asarray(ref.router_weights(xn, router, bias, sizes,
+                                              weigh_by_pick=True))[0]
+    np.testing.assert_allclose(w_ref, [want[0], 0.0, want[2], 0.0],
+                               rtol=1e-6)
+    assert abs(w_bad[2] - want[2]) > 0.05
+
+
+def test_the_layer_counts_the_picks_the_bias_moved():
+    lp = a_layer(KINDS["conv+moe"])
+    x = 2.0 * jax.random.normal(jax.random.PRNGKey(1), (B, L, 32))
+    model = get_model(config(moe_experts_held=8, moe_first_expert=0))
+    kw = dict(top_k=2, first_expert=0, capacity=2 * B * L, eps=1e-5, cdt=F32,
+              route_by=model.route_by)
+    _, counts = sdar_moe.expert_layer(lp, x, **kw)
+    xn = ref.rms_norm(x, lp["norm2"], 1e-5).reshape(-1, 32)
+    s = np.asarray(jax.nn.sigmoid(xn @ lp["router"]))
+    biased = np.sort(np.argsort(-(s + np.asarray(lp["select_bias"])),
+                                axis=-1, kind="stable")[:, :2], axis=-1)
+    plain = np.sort(np.argsort(-s, axis=-1, kind="stable")[:, :2], axis=-1)
+    moved = int(np.any(biased != plain, axis=-1).sum())
+    assert int(counts[sdar_moe.BIAS_MOVED]) == moved > 0
+    # no bias, no count; a zero bias, a count of zero
+    _, none = sdar_moe.expert_layer(
+        {k: v for k, v in lp.items() if k != "select_bias"}, x, **kw)
+    assert sdar_moe.BIAS_MOVED not in none
+    _, zero = sdar_moe.expert_layer(
+        {**lp, "select_bias": jnp.zeros((8,))}, x, **kw)
+    assert int(zero[sdar_moe.BIAS_MOVED]) == 0
+
+
+# --------------------------------------------------------- the share test
+
+def test_the_four_expert_shares_add_up_to_the_uncut_layer():
+    """The configuration's layout at small widths: 4 expert shares of an
+    expert layer (8 of 32 experts each: ``--moe_first_expert`` 0, 8, 16,
+    24; top-4 with the selection bias), the router and the norms whole on
+    each: the four routed partial sums added, the residual stream counted
+    once, are the uncut 32-expert reference's layer."""
+    experts, held = 32, 8
+    lp = a_layer(KINDS["conv+moe"], experts=experts, held=experts,
+                 moe_top_k=4)
+    x = 2.0 * jax.random.normal(jax.random.PRNGKey(2), (B, L, 32))
+    sizes = {**SIZES, "top_k": 4, "first_expert": 0}
+    with jax.default_matmul_precision("highest"):
+        want = ref.layer(x, lp, sizes)
+    model = get_model(config(moe_top_k=4, moe_experts=experts,
+                             moe_experts_held=held, moe_first_expert=0,
+                             moe_pair_capacity=4 * B * L))
+    h = x + model._mixer("conv", lp, x)[0]
+    routed, pairs = 0.0, 0
+    for first in (0, 8, 16, 24):
+        share = {**lp, **{n: lp[n][first:first + held]
+                          for n in ("w_gate", "w_up", "w_down")}}
+        part, counts = sdar_moe.expert_layer(
+            share, h, top_k=4, first_expert=first, capacity=4 * B * L,
+            eps=1e-5, cdt=F32, route_by=model.route_by)
+        routed = routed + part
+        pairs += int(counts["moe_pairs_held"])
+    np.testing.assert_allclose(h + routed, want, atol=3e-5)
+    assert pairs == B * L * 4           # every pair, once
+
+
+# ---------------------------------------------- gradients and Adam's steps
+
+def test_gradients_of_every_leaf_match_the_reference():
+    model = get_model(config(**TRIO))
+    params, state = model.init(jax.random.PRNGKey(0))
+    params = off_one(jax.random.PRNGKey(5), params)
+    state = {**state, lfm2_moe.SELECT_BIAS: a_bias(model)}
+    tokens = jnp.asarray(sequences(B, 1))
+
+    def loss(p):
+        per_seq, _ = model.per_example_loss(p, state, {"hist_ids": tokens},
+                                            train=True, rng=None)
+        return jnp.mean(per_seq)
+
+    # (jitted: op by op the reference's scans and maps take two minutes)
+    got = flat(jax.jit(jax.grad(loss))(params))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.grad(lambda p: ref.forward_loss(
+            p, tokens, state[lfm2_moe.SELECT_BIAS], SIZES)[0]))(
+            {k: jnp.asarray(v) for k, v in flat(params).items()})
+    assert set(got) == set(want)
+    for name in want:
+        assert leaf_gap(got[name], want[name]) < 1e-4, name
+        assert np.linalg.norm(want[name]) > 0, name
+    # tied: every row of the table has a gradient, a token's or the head's
+    assert np.all(np.abs(got["tok_emb"]).sum(axis=1) > 0)
+
+
+def follow(compute_dtype, n_dev=1, steps=3):
+    """(worst first-moment gap, worst parameter-change gap, losses, the
+    bias before and after) of ``steps`` trainer steps, on ``n_dev`` data
+    replicas, against the reference's follower."""
+    cfg = config(compute_dtype=compute_dtype, mesh_data=n_dev, **TRIO)
+    trainer = Trainer(cfg, mesh_info=mesh_lib.build_mesh(
+        cfg, devices=jax.devices()[:n_dev]))
+    state = trainer.init_state(seed=3)
+    bias = np.asarray(a_bias(trainer.model))    # (the state is donated)
+    state = state.replace(model_state={
+        **state.model_state, lfm2_moe.SELECT_BIAS: jax.device_put(
+            bias, jax.tree.leaves(state.model_state)[0].sharding)})
+    start = flat(jax.tree.map(np.asarray, state.params))
+    follower = ref.Follower(start, bias, SIZES, cfg.learning_rate * n_dev)
+    losses = []
+    for step in range(steps):
+        tokens = sequences(B, 10 + step)
+        state, m = trainer.train_step(state,
+                                      trainer.put_batch(batch_of(tokens)))
+        losses.append((float(m["xent"]), follower.step(tokens)))
+        assert lfm2_moe.SELECT_BIAS not in m and int(
+            m[sdar_moe.BIAS_MOVED]) > 0
+    got = flat(jax.tree.map(np.asarray, state.params))
+    mu = flat(jax.tree.map(np.asarray, optax.tree_utils.tree_get(
+        state.opt_state, "mu")))
+    return (worst_leaf_gap(mu, follower.mu)[0],
+            worst_leaf_gap({k: got[k] - start[k] for k in got},
+                           {k: follower.params[k] - start[k]
+                            for k in got})[0], losses,
+            (np.asarray(bias),
+             np.asarray(state.model_state[lfm2_moe.SELECT_BIAS])))
+
+
+@pytest.mark.parametrize("n_dev", [1, 2])
+def test_three_adam_steps_match_the_reference(n_dev):
+    """float32 against float32: the losses to 1e-5, Adam's first moment to
+    2e-4 (sums in another order), the parameters' change to 2% (Adam's
+    division by a small second moment amplifies a rounding); and the bias is
+    after three steps what it was, bit for bit."""
+    mu_gap, change_gap, losses, (before, after) = follow("float32", n_dev)
+    for got, want in losses:
+        assert abs(got - want) < 1e-5 * max(1.0, abs(want))
+    assert mu_gap < TOL
+    assert change_gap < 0.02
+    assert before.tobytes() == after.tobytes() and np.any(before != 0)
+
+
+def test_bfloat16_compute_misses_the_tolerance():
+    """bfloat16 products round an operand to 2^-8: ten times float32's
+    band and more, so a step one precision lower is told apart."""
+    mu_gap, change_gap, _, _ = follow("bfloat16")
+    assert mu_gap > 10 * TOL and change_gap > 0.02
+
+
+# ------------------------------------- the parameters at the published widths
+
+def test_parameter_counts_at_the_published_widths():
+    """ISSUE 40's table from the model's own leaves (``jax.eval_shape``:
+    nothing is allocated): the cut's five layers, 8 of 32 experts, a quarter
+    of the vocabulary, every width as published."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "lfm2-8b-a1b.json")) as f:
+        import json
+        flags = json.load(f)["flags"]
+    model = get_model(Config(**flags))
+    shapes, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+
+    def count(tree, *names):
+        return sum(int(np.prod(x.shape)) for n, x in tree.items()
+                   if not names or n in names)
+
+    layers = shapes["layers"]
+    conv = count(layers["0"], "conv_w_in", "conv_w", "conv_w_out")
+    attn = count(layers["1"], "wq", "wk", "wv", "wo", "q_norm", "k_norm")
+    assert conv == 12_582_912 + 6_144 + 4_194_304 == 16_783_360
+    assert attn == 10_485_888
+    assert count(layers["0"], "norm1", "norm2") == 4_096
+    assert count(layers["0"], "mlp_w_gate", "mlp_w_up",
+                 "mlp_w_down") == 44_040_192
+    assert count(layers["2"], "w_gate", "w_up", "w_down") == 88_080_384
+    assert count(layers["2"], "router") == 65_536
+    assert [count(layers[str(i)]) for i in range(5)] == [
+        60_827_648, 98_635_904, 104_933_376, 104_933_376, 104_933_376]
+    assert count(shapes, "tok_emb") == 33_554_432 and "head" not in shapes
+    assert count(shapes, "final_norm") == 2_048
+    total = sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+    assert total == 507_820_160
+    assert round(16 * total / 1e9, 2) == 8.13
+    assert round(12 * total / 1e9, 2) == 6.09
+    # the bias is no parameter: 32 a layer in the model state
+    assert state[lfm2_moe.SELECT_BIAS].shape == (4, 32)
+
+
+# --------------------- the shared attention block, its mask now an argument
+
+def test_sdar_attention_under_its_own_mask_is_what_it_was():
+    """``sdar_moe.attention`` took ``length`` and ``block`` and built
+    ``block_diffusion`` itself; it now takes the mask. Under that mask it is
+    the equations the SDAR reference has always held it to, and handing the
+    same mask twice gives the same bits."""
+    length, block, d, hd = 16, 4, 32, 8
+    keys = jax.random.split(jax.random.PRNGKey(0), 8)
+    lp = {"norm1": 1.0 + 0.1 * jax.random.normal(keys[0], (d,)),
+          "wq": jax.random.normal(keys[1], (d, 4 * hd)) * 0.2,
+          "wk": jax.random.normal(keys[2], (d, 2 * hd)) * 0.2,
+          "wv": jax.random.normal(keys[3], (d, 2 * hd)) * 0.2,
+          "q_norm": 1.0 + 0.1 * jax.random.normal(keys[4], (hd,)),
+          "k_norm": 1.0 + 0.1 * jax.random.normal(keys[5], (hd,)),
+          "wo": jax.random.normal(keys[6], (4 * hd, d)) * 0.2}
+    x = jax.random.normal(keys[7], (B, 2 * length, d))
+    positions = jnp.arange(2 * length) % length
+    kw = dict(head_dim=hd, eps=1e-6, theta=1e6, cdt=F32)
+    got = sdar_moe.attention(lp, x, positions,
+                             mask=sdar_moe.block_diffusion(length, block),
+                             **kw)
+    again = sdar_moe.attention(lp, x, positions,
+                               mask=sdar_moe.block_diffusion(length, block),
+                               scores_scope="attn_scores", **kw)
+    np.testing.assert_array_equal(got, again)       # a scope is metadata
+    with jax.default_matmul_precision("highest"):
+        want = reference_sdar_moe.attention(
+            reference_sdar_moe.rms_norm(x, lp["norm1"], 1e-6), lp,
+            {"head_dim": hd, "eps": 1e-6, "theta": 1e6},
+            jnp.asarray(reference_sdar_moe.block_diffusion_mask(
+                length, block)), positions)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # and under another mask it is another function
+    causal = sdar_moe.attention(lp, x, positions, mask=kimi_linear.causal,
+                                **kw)
+    assert leaf_gap(causal, got) > 0.05
+
+
+# ------------------------------- heads half a lane line wide on the kernel
+
+@pytest.mark.parametrize("head_dim, takes", [(128, True), (256, True),
+                                             (64, True), (192, True),
+                                             (32, False), (96, False)])
+def test_the_kernel_takes_whole_and_half_lane_lines(head_dim, takes):
+    assert block_attention.supported("tpu", 1024, head_dim, 512) == takes
+    assert not block_attention.supported("cpu", 1024, head_dim, 512)
+
+
+@pytest.mark.parametrize("backend, seq, head_dim, one_device, want", [
+    ("tpu", 8192, 64, True, "kernel"), ("tpu", 8192, 128, True, "kernel"),
+    ("tpu", 8192, 64, False, "xla"), ("cpu", 8192, 64, True, "xla"),
+    ("tpu", 8192 + 64, 64, True, "xla"), ("tpu", 8192, 32, True, "xla")])
+def test_attn_scores_by_takes_heads_of_64_on_a_tpu(backend, seq, head_dim,
+                                                   one_device, want):
+    assert sdar_moe.attn_scores_by(seq, head_dim, one_device=one_device,
+                                   backend=backend) == want
+
+
+@pytest.mark.parametrize("dtype, tol", [("float32", 1e-4),
+                                        ("bfloat16", 2e-2)])
+def test_the_kernel_at_64_lanes_matches_the_chunked_xla_path(monkeypatch,
+                                                             dtype, tol):
+    """``masked_scores`` under ``kimi_linear.causal`` at head_dim 64 by the
+    kernel (the half line as it is; forward, dq, dk/dv through the Pallas
+    interpreter, blocks of 128) against ``_scores_xla`` on the
+    same q/k/v: 512 positions, 4 query heads on each of 2 key/value heads;
+    output and the gradients of q, k, v, within 1e-4 in float32 and within
+    bfloat16's rounding of an operand under bfloat16; the scale is the real
+    width's, 1/8."""
+    monkeypatch.setattr(sdar_moe, "_scores_kernel", functools.partial(
+        sdar_moe._scores_kernel, interpret=True, kernel_block=128))
+    cdt = jnp.dtype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(keys[0], (1, 512, 8, 64), jnp.float32)
+    k = jax.random.normal(keys[1], (1, 512, 2, 64)).astype(cdt)
+    v = jax.random.normal(keys[2], (1, 512, 2, 64)).astype(cdt)
+    w = jax.random.normal(keys[3], (1, 512, 8 * 64))
+
+    def value_and_grads(scores_by):
+        def loss(q, k, v):
+            out = sdar_moe.masked_scores(
+                q, k, v, mask=kimi_linear.causal, cdt=cdt,
+                scores_by=scores_by).astype(jnp.float32)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out, *(g.astype(jnp.float32) for g in grads))
+
+    got, want = value_and_grads("kernel"), value_and_grads("xla")
+    assert got[0].shape == (1, 512, 8 * 64)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and leaf_gap(a, b) < tol
+
+
+def test_the_notes_of_a_narrow_head_are_a_whole_lines():
+    assert sdar_moe.attn_notes("kernel", kimi_linear.causal, 1024, 4) == {
+        "attn_scores": "kernel", "attn_score_blocks": "3/4"}
+    assert sdar_moe.attn_notes("xla", kimi_linear.causal, 1024, 4) == {
+        "attn_scores": "xla"}
+
+
+# ------------------------------------------------------------ configuration
+
+@pytest.mark.parametrize("change, says", [
+    ({"layer_types": "conv,full_attention,conv"}, "layer_types"),
+    ({"layer_types": "conv,kda,conv,conv"}, "layer_types"),
+    ({"conv_taps": 0}, "conv_taps"),
+    ({"attn_q_heads": 3}, "attn_q_heads"),
+    ({"attn_head_dim": 7}, "attn_head_dim"),
+    ({"dense_layers": 5}, "dense_layers"),
+    ({"dense_mlp_width": 0}, "dense_mlp_width"),
+    ({"moe_top_k": 9}, "moe_top_k"),
+    ({"moe_first_expert": 6}, "moe_experts_held"),
+    ({"moe_pair_capacity": 0}, "moe_pair_capacity"),
+    ({"history_max_len": 1}, "history_max_len"),
+    ({"moe_shared_width": 16}, "moe_shared_width"),
+    ({"kda_heads": 2}, "kda_heads"),
+    ({"attn_every": 2}, "attn_every"),
+    ({"mla_latent_dim": 8}, "mla_"),
+    ({"task_type": "infer"}, "infer/export"),
+    ({"task_type": "export"}, "infer/export"),
+    ({"online_mode": True}, "online_mode"),
+    ({"mesh_model": 2}, "mesh_model"),
+    ({"loss_type": "square_loss"}, "loss_type"),
+])
+def test_config_says_plainly_what_the_model_does_not_take(change, says):
+    with pytest.raises(ValueError, match=says):
+        config(**change)
+
+
+@pytest.mark.parametrize("model", ["deepfm", "sdar_moe", "kimi_linear",
+                                   "solar_open2"])
+def test_the_convolution_flags_belong_to_this_model(model):
+    with pytest.raises(ValueError):
+        Config(model=model, layer_types="conv")
+    with pytest.raises(ValueError):
+        Config(model=model, conv_taps=4)
+
+
+def test_the_model_is_a_stack_by_its_list():
+    assert "lfm2_moe" not in registered_models()
+    model = get_model(config())
+    assert isinstance(model, kimi_linear.KimiLinear) and model.owns_loss
+    assert model.kinds == (("conv", "mlp"), ("full_attention", "moe"),
+                           ("conv", "moe"), ("conv", "moe"))
+    assert model.moe_layers == (1, 2, 3)
+    params, state = model.init(jax.random.PRNGKey(0))
+    assert set(params["layers"]["1"]) == {
+        "norm1", "norm2", "wq", "wk", "wv", "q_norm", "k_norm", "wo",
+        "router", "w_gate", "w_up", "w_down"}       # no shared expert
+    assert set(params["layers"]["0"]) == {
+        "norm1", "norm2", "conv_w_in", "conv_w", "conv_w_out", "mlp_w_gate",
+        "mlp_w_up", "mlp_w_down"}
+    assert params["layers"]["0"]["conv_w"].shape == (3, 32)
+    assert not np.any(np.asarray(state[lfm2_moe.SELECT_BIAS]))
+
+
+def test_compiled_step_carries_each_blocks_scope():
+    cfg = config(**TRIO)
+    tr = Trainer(cfg, mesh_info=mesh_lib.build_mesh(
+        cfg, devices=jax.devices()[:1]))
+    scopes = set(profiling.hlo_op_scopes(tr.step_hlo_text()).values())
+    assert {"embed", "conv", "conv_taps", "attn", "attn_scores", "mlp",
+            "moe", "head", "opt"} <= scopes
+    assert not {"kda", "kda_scan"} & scopes
+    assert tr.model.step_notes == {
+        "conv_taps_by": "xla", "attn_scores": "xla", "moe_rows": "xla",
+        "moe_rows_moved": "{moe_pairs_held}/%d" % (2 * 2 * B * L)}
+
+
+def test_model_by_the_kernel_at_64_lanes_takes_the_same_step(monkeypatch):
+    """The whole model with its causal scores by the kernel (interpreted)
+    at heads of 64 against the XLA path: the same loss and gradients."""
+    cfg = config(history_max_len=512, attn_head_dim=64, attn_q_heads=2,
+                 attn_kv_heads=1, decoder_layers=2,
+                 layer_types="conv,full_attention", batch_size=1,
+                 moe_pair_capacity=1024)
+    model = get_model(cfg)
+    params, state = model.init(jax.random.PRNGKey(0))
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, V, (1, 512)).astype(np.int32))
+
+    def value_and_grad():
+        def loss(p):
+            per_seq, _ = model.per_example_loss(
+                p, state, {"hist_ids": tokens}, train=True, rng=None)
+            return jnp.mean(per_seq)
+        return jax.jit(jax.value_and_grad(loss))(params)
+
+    want, want_g = value_and_grad()
+    assert model.step_notes["attn_scores"] == "xla"
+    monkeypatch.setattr(sdar_moe, "_scores_kernel", functools.partial(
+        sdar_moe._scores_kernel, interpret=True, kernel_block=128))
+    monkeypatch.setattr(lfm2_moe, "attn_scores_by",
+                        lambda *a, **k: "kernel")
+    got, got_g = value_and_grad()
+    assert model.step_notes["attn_scores"] == "kernel"
+    assert model.step_notes["attn_score_blocks"] == "1/1"  # blocks of 512
+    assert "attn_head_lanes" not in model.step_notes
+    assert abs(float(got) - float(want)) < 1e-5
+    for name, g in flat(got_g).items():
+        assert leaf_gap(g, flat(want_g)[name]) < 1e-4, name
+
+
+# ----------------------------------------------------- the trainer's path
+
+def test_fit_trains_from_tfrecord_shards(tmp_path):
+    """``Trainer.fit`` over the normal file pipeline (the tokens ride the
+    record's history list), one step a dispatch, as the other decoders: the
+    loss falls and the counts ride the metrics."""
+    from deepfm_tpu.train import tasks
+
+    rng = np.random.default_rng(0)
+    path = str(tmp_path / "tr-0.tfrecord")
+    with tfrecord.TFRecordWriter(path) as w:
+        for _ in range(16):
+            # a sequence a model can learn: a walk of +1 from a random start
+            row = (rng.integers(0, V) + np.arange(L)) % V
+            w.write(example_codec.encode_ctr_example(
+                0.0, np.zeros(1), np.ones(1), hist_ids=row))
+    cfg = config(learning_rate=1e-2, log_steps=1000, **TRIO)
+    trainer = Trainer(cfg, mesh_info=mesh_lib.build_mesh(
+        cfg, devices=jax.devices()[:1]))
+    pipeline = tasks.make_pipeline(cfg, [path], epochs=6)
+    seen = []
+    try:
+        state, out = trainer.fit(trainer.init_state(seed=0), pipeline,
+                                 hooks=[lambda s, m: seen.append(m)])
+    finally:
+        pipeline.close()
+    losses = [float(m["xent"]) for m in seen]
+    assert len(losses) == 6 * 16 // B
+    assert losses[-1] < 0.6 * losses[0]
+    assert np.isfinite(float(out["loss"]))
+    assert int(seen[-1]["moe_pairs_held"]) > 0
+    # the model's own start: a zero bias moves no pick and stays zero
+    assert int(seen[-1][sdar_moe.BIAS_MOVED]) == 0
+    assert not np.any(np.asarray(state.model_state[lfm2_moe.SELECT_BIAS]))

@@ -238,7 +238,8 @@ def test_router_matches_a_hand_computed_top_k():
     router = jnp.asarray([[0.0, 1.0, 2.0, -1.0], [1.0, 0.0, -1.0, 0.5]])
     # token 0: logits (0, 1, 2, -1): experts 2 and 1, e^2 and e^1 renormalised
     # token 1: logits (2, 0, -2, 1): experts 0 and 3
-    experts, weights = sdar_moe.route(x, router, 2)
+    experts, weights, moved = sdar_moe.route(x, router, 2)
+    assert int(moved) == 0       # (no bias)
     np.testing.assert_array_equal(experts, [[2, 1], [0, 3]])
     e = np.exp
     np.testing.assert_allclose(
@@ -288,8 +289,8 @@ def test_eight_shares_add_up_to_the_uncut_layer():
                  **{n: lp[n][2 * r:2 * r + 2]
                     for n in ("w_gate", "w_up", "w_down")}}
         got_attn += sdar_moe.attention(
-            share, x, pos, length=length, block=block, head_dim=hd, eps=1e-6,
-            theta=1e6, cdt=jnp.dtype("float32"))
+            share, x, pos, mask=sdar_moe.block_diffusion(length, block),
+            head_dim=hd, eps=1e-6, theta=1e6, cdt=jnp.dtype("float32"))
         part, counts = sdar_moe.expert_layer(
             share, x, top_k=2, first_expert=2 * r, capacity=64, eps=1e-6,
             cdt=jnp.dtype("float32"))
@@ -510,7 +511,8 @@ def test_forward_grid_visits_the_blocks_allowed_leaves_something_in(
 @pytest.mark.parametrize("backend, seq, head_dim, one_device, says", [
     ("tpu", 8192, 128, True, "kernel"), ("tpu", 1024, 256, True, "kernel"),
     ("cpu", 8192, 128, True, "xla"), ("gpu", 8192, 128, True, "xla"),
-    ("tpu", 8192, 64, True, "xla"), ("tpu", 8192 + 256, 128, True, "xla"),
+    ("tpu", 8192, 64, True, "kernel"),      # (half a line, as it is: PR 40)
+    ("tpu", 8192, 32, True, "xla"), ("tpu", 8192 + 256, 128, True, "xla"),
     ("tpu", 16, 8, True, "xla"), ("tpu", 8192, 128, False, "xla")])
 def test_the_kernel_is_taken_where_backend_shape_and_mesh_allow(
         backend, seq, head_dim, one_device, says):
@@ -696,8 +698,9 @@ def test_attention_by_the_kernel_matches_attention_by_xla(monkeypatch):
 
     def loss(lp, scores_by):
         out = sdar_moe.attention(
-            lp, x, pos, length=length, block=block, head_dim=hd, eps=1e-6,
-            theta=1e6, cdt=jnp.dtype("float32"), scores_by=scores_by)
+            lp, x, pos, mask=sdar_moe.block_diffusion(length, block),
+            head_dim=hd, eps=1e-6, theta=1e6, cdt=jnp.dtype("float32"),
+            scores_by=scores_by)
         return jnp.sum(out * w), out
 
     (_, want), want_g = jax.value_and_grad(loss, has_aux=True)(lp, "xla")

@@ -686,3 +686,77 @@ def test_log_sync_says_the_causal_score_path_and_the_write_strength(
     # on the kernel the note names the causal half's blocks
     notes = tr.model.step_notes
     assert notes["attn_scores"] == "xla" and "kda_scan" in notes
+
+
+def test_log_sync_says_the_convolution_the_scores_and_the_bias(tmp_path,
+                                                               capsys):
+    """``--model lfm2_moe`` says on the span that reads the loss back what
+    computes its short convolution's passes (``conv_taps_by``), what makes
+    its full layer's causal scores (``attn_scores``, the site it shares with
+    ``sdar_moe`` and ``solar_open2``) and how many selections its routers'
+    bias changed (``moe_bias_moved_picks``, a whole number among the
+    counts; never the bias itself), and the report prints each on its
+    line."""
+    from deepfm_tpu.models import lfm2_moe
+    length, vocab, batch = 12, 50, 2
+    cfg = Config(model="lfm2_moe", feature_size=vocab, field_size=1,
+                 embedding_size=16, history_max_len=length, decoder_layers=3,
+                 layer_types="conv,full_attention,conv", dense_layers=1,
+                 dense_mlp_width=16, attn_q_heads=4, attn_kv_heads=2,
+                 attn_head_dim=8, moe_experts=4, moe_top_k=2,
+                 moe_expert_width=8, moe_experts_held=2, moe_first_expert=0,
+                 moe_pair_capacity=batch * length * 2, batch_size=batch,
+                 l2_reg=0.0, learning_rate=1e-3, steps_per_loop=1,
+                 log_steps=2, compute_dtype="float32", mesh_data=1,
+                 mesh_model=1)
+    rng = np.random.default_rng(5)
+    batches = [{"feat_ids": np.zeros((batch, 1), np.int32),
+                "feat_vals": np.ones((batch, 1), np.float32),
+                "label": np.zeros((batch, 1), np.float32),
+                "hist_ids": rng.integers(0, vocab, (batch, length)
+                                         ).astype(np.int32),
+                "hist_mask": np.ones((batch, length), np.float32)}
+               for _ in range(4)]
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(cfg)
+    state = tr.init_state()
+    state = state.replace(model_state={
+        **state.model_state, lfm2_moe.SELECT_BIAS: 0.2 * np.asarray(
+            rng.standard_normal((2, 4)), np.float32)})
+    tr.fit(state, batches)
+    syncs = [e["args"] for e in trace_lib._tracer.events()
+             if e["name"] == "train.log_sync"]
+    assert [(a["conv_taps_by"], a["attn_scores"]) for a in syncs] == [
+        ("xla", "xla")] * 2
+    assert all(k not in a for a in syncs for k in (
+        "kda_scan", "mla_scores", lfm2_moe.SELECT_BIAS))
+    picks = [a["moe_bias_moved_picks"] for a in syncs]
+    assert all(isinstance(x, int) and 0 < x <= 2 * batch * length
+               for x in picks)
+    path = str(tmp_path / "trace.json")
+    trace_lib.export(path)
+    report = _report()
+    loaded, _ = report._load(path)
+    assert report.short_convolution(loaded) == {"steps": 2, "taps_by": "xla"}
+    assert report.delta_rule_scan(loaded) is None
+    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla"}
+    assert report.expert_rows(loaded)["bias_moved_picks"] == sum(picks) / 2
+    assert report.main([path]) == 0
+    out = capsys.readouterr().out
+    assert ("gated short convolution over 2 logged steps: taps and gates by "
+            "xla") in out
+    assert ("the selection bias moved %.0f picks a step"
+            % (sum(picks) / 2)) in out
+    # what a TPU's trace says of heads of 64 on the kernel
+    events = [{"name": "train.log_sync", "ph": "X", "ts": 1.0, "dur": 1.0,
+               "pid": 1, "tid": 1,
+               "args": {"step": 1, "attn_scores": "kernel",
+                        "attn_score_blocks": "136/256"}}]
+    assert report.attention_scores(events) == {
+        "steps": 1, "scores": "kernel", "visited": 136, "total": 256}
+    assert report.short_convolution(events) is None
+    trace = tmp_path / "tpu.json"
+    trace.write_text(__import__("json").dumps({"traceEvents": events}))
+    assert report.main([str(trace)]) == 0
+    assert ("136 of 256 blocks of the score matrix visited a head "
+            "(53.1%)") in capsys.readouterr().out
