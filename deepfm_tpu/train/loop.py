@@ -86,9 +86,15 @@ ROW_WRITEBACK = "embed_row_writeback"
 #: How a dense-gradient step made its table-shaped gradient: "rows" (the
 #: batch's distinct rows, each the sum of its positions' cotangents), "rows,
 #: exchanged over data" (the same under data replicas: every chip scatters
-#: all replicas' rows, no table crosses the interconnect) or "positions"
+#: all replicas' rows, no wide table crosses the interconnect) or "positions"
 #: (AD's scatter-add of every position); ``Trainer.embed_grad``.
 EMBED_GRAD = "embed_grad"
+#: And which tables' gradient that step sums over the data replicas as a
+#: table, names joined by "," ("" where none does: one device, or every
+#: table's rows exchanged): under the exchange the tables whose row is one
+#: word, whose all-reduce costs less than their slots in the trips; with the
+#: tables left to AD every one; ``Trainer.embed_grad_by_table``.
+EMBED_GRAD_BY_TABLE = "embed_grad_by_table"
 
 
 def pad_batch(batch: Dict[str, np.ndarray], bs: int) -> Dict[str, np.ndarray]:
@@ -307,6 +313,7 @@ class Trainer:
         # How the dense-gradient step makes its table gradient (EMBED_GRAD):
         # known once ``_dense_value_and_grad`` is traced.
         self.embed_grad: Optional[str] = None
+        self.embed_grad_by_table: Optional[str] = None
 
     # ------------------------------------------------------------------
     # State creation / placement
@@ -598,19 +605,21 @@ class Trainer:
             loss_fn, has_aux=True)((views, rest))
         return xent, new_mstate, g_views, g_rest
 
-    def _view_row_sums(self, tabs, ids, g_views, *, gather_axis=None):
+    def _view_row_sums(self, tabs, ids, g_views, *, gather_axis=None,
+                       apart=()):
         """(``emb_ops.RowSums`` of the distinct rows of ``ids``: per row the
         float32 sum of its positions' cotangents ``g_views``, every table's
         columns side by side, slots a whole number of trips; the trips of
         ``ROW_UPDATE_CAPACITY`` rows that hold them; ``rows_of(i)`` = the
-        ids of trip ``i`` and each table's rows of sums there). Sorted, so
-        the last trip's spare slots lie past the table: read as fill,
-        dropped or skipped by a write. With ``gather_axis`` (data replicas:
-        the rows are this shard's) the trips are the fullest shard's and
-        ``rows_of(i)`` is every shard's trip ``i``, one after another in
-        the axis's order, the same on every shard
-        (``emb_ops.all_gather_invariant``); a shard with fewer trips hands
-        in spare slots."""
+        ids of trip ``i`` and each table's rows of sums there; the tables
+        ``apart``, which the trips leave out: each one's rows of sums at
+        every slot of ``rows.uids``). Sorted, so the last trip's spare
+        slots lie past the table: read as fill, dropped or skipped by a
+        write. With ``gather_axis`` (data replicas: the rows are this
+        shard's) the trips are the fullest shard's and ``rows_of(i)`` is
+        every shard's trip ``i``, one after another in the axis's order,
+        the same on every shard (``emb_ops.all_gather_invariant``); a shard
+        with fewer trips hands in spare slots."""
         names, cap = self._embed_names, ROW_UPDATE_CAPACITY
         widths = [math.prod(tabs[n].shape[1:]) for n in names]
         cuts = np.cumsum([0] + widths)
@@ -622,18 +631,26 @@ class Trainer:
         if gather_axis is not None:     # before the loop: a collective in
             # its body needs every shard to make the same trips
             trips = jax.lax.pmax(trips, gather_axis)
+        columns = {n: slice(cuts[j], cuts[j + 1])
+                   for j, n in enumerate(names)}
+
+        def split(g, of):
+            """Per table of ``of``, its columns of ``g`` as rows of it."""
+            return {n: g[:, columns[n]].reshape(
+                (len(g),) + tabs[n].shape[1:]) for n in of}
 
         def rows_of(i):
             uids = jax.lax.dynamic_slice_in_dim(rows.uids, i * cap, cap)
-            g = jax.lax.dynamic_slice_in_dim(rows.sums, i * cap, cap)
-            if gather_axis is not None:
-                uids, g = (emb_ops.all_gather_invariant(x, gather_axis)
-                           for x in (uids, g))
-            return uids, {n: g[:, cuts[j]:cuts[j + 1]].reshape(
-                (len(uids),) + tabs[n].shape[1:])
-                for j, n in enumerate(names)}
+            g = split(jax.lax.dynamic_slice_in_dim(rows.sums, i * cap, cap),
+                      [n for n in names if n not in apart])
+            if gather_axis is not None:     # the trips' tables alone cross
+                uids, *gathered = (
+                    emb_ops.all_gather_invariant(x, gather_axis)
+                    for x in (uids, *g.values()))
+                g = dict(zip(g, gathered))
+            return uids, g
 
-        return rows, trips, rows_of
+        return rows, trips, rows_of, split(rows.sums, apart)
 
     @jax.named_scope("embed")
     def _update_rows(self, tabs, opt_tabs, ids, g_views):
@@ -653,7 +670,7 @@ class Trainer:
             self.row_writeback = how
             ulog.info(f"row-local table update: {cap} rows a trip, written "
                       f"back by {how}")
-        rows, trips, rows_of = self._view_row_sums(tabs, ids, g_views)
+        rows, trips, rows_of, _ = self._view_row_sums(tabs, ids, g_views)
 
         def take(table, uids):
             return jnp.take(table, uids, axis=0, mode="fill", fill_value=0)
@@ -718,9 +735,18 @@ class Trainer:
         own scatter (a row two shards hold is added twice, which is the
         sum). The trips and the distinct rows counted are the fullest
         shard's; ``EXCHANGED_ROWS`` is all shards' rows together, what
-        each chip scattered (PERF.md §6, PR 38)."""
-        rows, trips, rows_of = self._view_row_sums(
-            tabs, ids, g_views, gather_axis=sum_axis)
+        each chip scattered (PERF.md §6, PR 38). But a table whose row is
+        one word (``_summed_as_tables``) stays out of the trips: its
+        gradient is this shard's own rows in one scatter-add of every slot
+        beside the loop, all-reduced — the same sum, the shards' added by
+        the collective, whose one result every replica receives. An (id,
+        sum) pair is 8 bytes a shard and a slot of every chip's scatter, 91
+        ns at 16.9M rows inside the loop; the table's all-reduce is 4 bytes
+        a row, 67.5 MB where the exchange saved 2.16 GB for the 32-wide
+        table (PERF.md §6, PR 41)."""
+        apart = self._summed_as_tables(tabs, sum_axis)
+        rows, trips, rows_of, own = self._view_row_sums(
+            tabs, ids, g_views, gather_axis=sum_axis, apart=apart)
 
         def trip(carry):
             i, grads = carry
@@ -730,13 +756,28 @@ class Trainer:
 
         _, grads = jax.lax.while_loop(
             lambda carry: carry[0] < trips, trip,
-            (jnp.zeros((), jnp.int32), jax.tree.map(jnp.zeros_like, tabs)))
+            (jnp.zeros((), jnp.int32),
+             {n: jnp.zeros_like(t) for n, t in tabs.items()
+              if n not in apart}))
+        for n in apart:
+            grads[n] = jax.lax.psum(jnp.zeros_like(tabs[n]).at[rows.uids].add(
+                own[n].astype(tabs[n].dtype), mode="drop"), sum_axis)
         distinct, exchanged = rows.count, {}
         if sum_axis is not None:
             exchanged = {EXCHANGED_ROWS: jax.lax.psum(distinct, sum_axis)}
             distinct = jax.lax.pmax(distinct, sum_axis)
         return grads, {**dict(zip(ROW_COUNTS, (distinct, trips))),
                        **exchanged}
+
+    def _summed_as_tables(self, tabs, sum_axis) -> Tuple[str, ...]:
+        """The tables of ``tabs`` whose gradient ``_table_grads`` sums over
+        ``sum_axis`` by an all-reduce of the table and not by the exchange
+        of rows: under data replicas those whose row is one word. Read from
+        the table's shape: it is one sum either way, and which carrier is
+        cheaper is a matter of the row's width alone."""
+        if sum_axis is None:
+            return ()
+        return tuple(n for n in self._embed_names if tabs[n].ndim == 1)
 
     def _dense_value_and_grad(self, data_loss, params, *, data_axis,
                               shard_axis, ids=None):
@@ -752,9 +793,19 @@ class Trainer:
         flat_sync = data_axis is not None and self._hier_groups is None
         how = ("positions" if ids is None else
                "rows, exchanged over data" if flat_sync else "rows")
-        if how != self.embed_grad:      # said once a trainer, at trace time
-            self.embed_grad = how
-            ulog.info(f"dense-gradient step: table gradient from {how}")
+        tabs, rest = self._tables_and_rest(params)
+        if data_axis is None:
+            by_table = ""
+        elif ids is not None and flat_sync:
+            by_table = ",".join(self._summed_as_tables(tabs, data_axis))
+        else:   # AD's psum, or the hierarchical mean, of every table
+            by_table = ",".join(tabs)
+        if (how, by_table) != (self.embed_grad, self.embed_grad_by_table):
+            # said once a trainer, at trace time
+            self.embed_grad, self.embed_grad_by_table = how, by_table
+            ulog.info(f"dense-gradient step: table gradient from {how}"
+                      + (f"; summed over data as tables: {by_table}"
+                         if by_table else ""))
 
         counts: Dict[str, jnp.ndarray] = {}
         # THE gradient sync point: the loss is made a *global* scalar (mean
@@ -778,7 +829,6 @@ class Trainer:
             # out of AD; the views' cotangents are local, so the tables
             # made of them are summed over the axis explicitly, by an
             # exchange of the shards' rows.
-            tabs, rest = self._tables_and_rest(params)
             xent, new_mstate, g_views, g_rest = self._value_and_view_grads(
                 data_loss, tabs, rest, ids, mean_axis=sync)
             g_tabs, counts = self._table_grads(tabs, ids, g_views,
@@ -2200,6 +2250,8 @@ class Trainer:
                             # how the compiled step made its table
                             # gradient, or wrote its rows back
                             for key, how in ((EMBED_GRAD, self.embed_grad),
+                                             (EMBED_GRAD_BY_TABLE,
+                                              self.embed_grad_by_table),
                                              (ROW_WRITEBACK,
                                               self.row_writeback)):
                                 if how is not None:

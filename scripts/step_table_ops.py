@@ -14,7 +14,14 @@ came from. Each line is at least one pass over a table in HBM, except an
 ``ops/pallas_put_rows.py``), that writes rows into a table in place costs
 its rows. A step whose only lines are ``R`` updates the tables on the rows the
 batch touched (``Trainer._row_local_eligible``); ``docs/TUNING.md`` §5 says
-how to count the others. Last, the step's ``memory_analysis()``. It takes
+how to count the others. The four-chip DeepFM step lists, beside the wide
+table's ``R`` line (the trips' scatter of the replicas' exchanged rows), the
+one table that crosses the interconnect as a table: a ``fusion f32[16881344]``
+scatter-add of the replica's own rows into a fill fused with it, outside the
+trips' ``while``, and an ``all-reduce`` whose tuple holds that
+``f32[16881344]`` (one read and one write of a 67.5 MB vector, 1/32 of a
+pass: not a pass over the 2.16 GB table); no ``[16881344,32]`` collective
+(``PERF.md`` §6, PR 41). Last, the step's ``memory_analysis()``. It takes
 ~20 s and says nothing about time: times are the chip's
 (``benchmark/run.py --trace 1``; its ``breakdown`` names the same ops).
 

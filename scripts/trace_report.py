@@ -57,7 +57,12 @@ replicas' rows together: what each chip scattered, whose mean the line
 adds) says which side of the break-even a workload is on (TUNING §4);
 or ``positions``, AD's scatter-add of every position (history models,
 hashed tables, row shards, accumulation), all-reduced as tables under data
-replicas, with a line that says so.
+replicas, with a line that says so. ``embed_grad_by_table`` names the
+tables whose gradient crossed the interconnect as a table ("" where none
+did): under the exchange those whose row is one word (``fm_w``: one scatter
+of the replica's own rows beside the trips, then the table's all-reduce,
+cheaper than its slots in every chip's trips), with the tables left to AD
+every one; the line names them.
 
 Where the model says what makes its attention's masked scores
 (``--model sdar_moe``, ``--model solar_open2``, ``--model lfm2_moe``), each
@@ -370,6 +375,16 @@ def table_gradient(events):
     return seen[-1]["embed_grad"] if seen else None
 
 
+def tables_summed_as_tables(events):
+    """Which tables' gradient the dense-gradient step summed over its data
+    replicas as a table (``embed_grad_by_table`` of the last
+    ``train.log_sync`` that says: names joined by ","; "" where none was —
+    one device, or every table's rows exchanged); None where
+    ``table_gradient`` is, or in a trace that predates the note."""
+    seen = _log_syncs(events, "embed_grad_by_table")
+    return seen[-1]["embed_grad_by_table"] if seen else None
+
+
 def attention_scores(events):
     """What makes the attention's masked scores, off the ``train.log_sync``
     spans that say so: ``steps`` read, ``scores`` (``attn_scores``:
@@ -504,7 +519,7 @@ def main(argv=None):
               f"{r['p50_ms']:>9.3f}{r['p99_ms']:>9.3f}")
     for name, n in sorted(instants.items()):
         print(f"instant {name}: {n}")
-    grad = table_gradient(events)
+    grad, whole = table_gradient(events), tables_summed_as_tables(events)
     if touched is not None:
         by_rows = grad is not None      # "rows", exchanged or not
         print("%s over %d logged steps: "
@@ -520,12 +535,15 @@ def main(argv=None):
                   else "rows written back by " + touched["writeback"])
               + (" (the fullest replica's; every chip scattered all "
                  "replicas' rows, embed_exchanged_rows mean %.0f a step, and "
-                 "no table crossed the interconnect)"
-                 % touched["exchanged_rows_mean"]
+                 "%s)" % (touched["exchanged_rows_mean"],
+                          f"of the tables only {whole} crossed the "
+                          "interconnect, all-reduced" if whole
+                          else "no table crossed the interconnect")
                  if "exchanged_rows_mean" in touched else ""))
     elif grad == "positions":
         print("dense-gradient step: table gradient from positions (AD's "
-              "scatter-add of every position of the batch)")
+              "scatter-add of every position of the batch)"
+              + (f", summed over data as tables: {whole}" if whole else ""))
     if attn is not None:
         print("block-masked attention over %d logged steps: scores by %s"
               % (attn["steps"], attn["scores"])

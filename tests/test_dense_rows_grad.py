@@ -31,7 +31,15 @@ chip scatter-adds them all. Held on 2 and 4 virtual devices:
 * replicas that need different numbers of trips, a row every replica holds,
   and the ids that receive nothing, on every replica;
 * ``embed_exchanged_rows`` (all replicas' rows together) against NumPy;
-* the compiled step: no collective with a table-shaped result.
+* the compiled step: no collective with a ``[V, k > 1]`` result.
+
+A table whose row is one word (``fm_w``) stays out of that exchange (PERF.md
+§6, PR 41): its gradient is the replica's own rows in one scatter-add beside
+the trips' loop, all-reduced as a table. Held on the compiled step: the
+trips' body scatters into no ``[V]`` operand and their pairs are as wide as
+the wide tables alone; the one table-tall collective is that table's; a
+model without such a table compiles none; one device compiles the loop it
+compiled, both tables in its trips.
 """
 
 import functools
@@ -387,31 +395,51 @@ def test_more_distinct_rows_than_a_trip_holds_on_replicas(monkeypatch,
         assert m["embed_exchanged_rows"] == sum(distinct)
 
 
-def _table_scatter_heights(hlo_text, rows):
-    """Per scatter of the compiled program into an array ``rows`` tall, how
-    many rows of updates it is handed."""
-    heights, shapes = [], {}
+def _table_scatters(hlo_text, rows):
+    """Per scatter of the compiled program into an array ``rows`` tall:
+    (that array's row shape, how many rows of updates it is handed, whether
+    it sits in the body of the trips' ``while``)."""
+    found, shapes = [], {}
     for line in hlo_text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[(\d*)", line)
         if m:
             shapes[m.group(1)] = int(m.group(2) or 0)
-        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[(\d+)[,\]][^ ]* "
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]+)\][^ ]* "
                      r"scatter\(%[\w.\-]+, %[\w.\-]+, %([\w.\-]+)\)", line)
-        if m and int(m.group(1)) == rows:
-            heights.append(shapes[m.group(2)])
-    return heights
+        if m:
+            dims = tuple(int(d) for d in m.group(1).split(","))
+            if dims[0] == rows:
+                found.append((dims[1:], shapes[m.group(2)],
+                              "embed/while/body" in line))
+    return found
+
+
+def _table_scatter_heights(hlo_text, rows):
+    return [height for _, height, _ in _table_scatters(hlo_text, rows)]
+
+
+def _own_slots(devices):
+    """The slots of one replica's ``RowSums``: its positions, rounded up to
+    whole trips."""
+    cap = loop.ROW_UPDATE_CAPACITY
+    return -(-(B // devices * F) // cap) * cap
 
 
 @pytest.mark.parametrize("model,devices", [
     (m, d) for m in sorted(MODELS) for d in (1,) + REPLICAS])
 def test_eligible_step_scatters_trips_of_rows_never_positions(model, devices):
-    """A trip scatters ``ROW_UPDATE_CAPACITY`` rows of every data replica."""
+    """A trip scatters ``ROW_UPDATE_CAPACITY`` rows of every data replica;
+    on replicas the one-word-row table takes the replica's own rows, every
+    slot in one scatter."""
     tr = Trainer(_cfg(model, devices))
     assert tr._grad_by_rows()
     text = tr.step_hlo_text()
-    heights = _table_scatter_heights(text, tr.model.padded_vocab)
-    assert heights and set(heights) == {
-        devices * loop.ROW_UPDATE_CAPACITY}, heights
+    scatters = _table_scatters(text, tr.model.padded_vocab)
+    in_trips = {h for row, h, _ in scatters if row or devices == 1}
+    assert in_trips == {devices * loop.ROW_UPDATE_CAPACITY}, scatters
+    if devices > 1:
+        assert [h for row, h, _ in scatters if not row] == [
+            _own_slots(devices)], scatters
     assert tr.embed_grad == _how(devices)
     # and Adam still sweeps every row
     ops = profiling.hlo_table_ops(text, tr.model.padded_vocab)
@@ -419,39 +447,79 @@ def test_eligible_step_scatters_trips_of_rows_never_positions(model, devices):
             and not o["primitive"].startswith("scatter")], ops
 
 
-def _collective_heights(hlo_text):
-    """The leading dimension of every array a collective of the compiled
-    program results in (a tuple's every member)."""
-    heights = []
+@pytest.mark.parametrize("devices", (1,) + REPLICAS)
+def test_the_trips_scatter_into_no_one_word_row_table_on_replicas(devices):
+    """On replicas the ``[V]`` table's one scatter lies beside the trips'
+    ``while``, whose body holds the wide table's alone; on one device the
+    loop is as it was, both tables' scatters in its body."""
+    tr = Trainer(_cfg("deepfm", devices))
+    scatters = _table_scatters(tr.step_hlo_text(), tr.model.padded_vocab)
+    assert sorted((row, inside) for row, _, inside in scatters) == [
+        ((), devices == 1), ((4,), True)], scatters
+    assert tr.embed_grad_by_table == ("fm_w" if devices > 1 else "")
+
+
+def _collective_shapes(hlo_text):
+    """The dimensions of every array a collective of the compiled program
+    results in (a tuple's every member)."""
+    shapes = []
     for line in hlo_text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*?) (?:all-reduce|"
                      r"all-gather|reduce-scatter|all-to-all|"
                      r"collective-permute)(?:-start)?\(", line)
         if m:
-            heights += [int(h or 0) for h in
-                        re.findall(r"\w+\[(\d*)[,\]]", m.group(1))]
-    return heights
+            shapes += [tuple(int(d) for d in dims.split(",") if d) for dims
+                       in re.findall(r"\w+\[([\d,]*)\]", m.group(1))]
+    return shapes
+
+
+def _as_tall_as(shapes, height):
+    return sorted(s for s in shapes if s[:1] == (height,))
 
 
 @pytest.mark.parametrize("model,devices", [
     (m, d) for m in sorted(MODELS) for d in REPLICAS])
-def test_no_table_shaped_collective_on_data_replicas(model, devices):
-    """What crosses the interconnect is a trip's pairs, the dense leaves'
-    gradient and scalars; with the tables left to AD it is the tables."""
+def test_only_one_word_row_tables_cross_as_tables_on_data_replicas(model,
+                                                                   devices):
+    """What crosses the interconnect is a trip's pairs, as wide as the wide
+    table alone, the ``[V]`` table's gradient, the dense leaves' and
+    scalars; with the tables left to AD it is every table."""
     tr = Trainer(_cfg(model, devices))
     rows = tr.model.padded_vocab
-    heights = _collective_heights(tr.step_hlo_text())
-    assert devices * loop.ROW_UPDATE_CAPACITY in heights, heights
-    assert rows not in heights, heights
+    shapes = _collective_shapes(tr.step_hlo_text())
+    pairs = devices * loop.ROW_UPDATE_CAPACITY
+    assert _as_tall_as(shapes, pairs) == [(pairs,), (pairs, 4)], shapes
+    assert _as_tall_as(shapes, rows) == [(rows,)], shapes
+    assert tr.embed_grad_by_table == "fm_w"
     by_ad = Trainer(_cfg(model, devices))
     by_ad._grad_by_rows = lambda: False
-    assert rows in _collective_heights(by_ad.step_hlo_text())
+    assert (rows, 4) in _collective_shapes(by_ad.step_hlo_text())
+    assert by_ad.embed_grad_by_table == "fm_w,fm_v"
+
+
+@pytest.mark.parametrize("devices", REPLICAS)
+def test_a_model_without_a_one_word_row_table_compiles_no_table_collective(
+        devices):
+    """``dlrm_dcnv2`` holds ``fm_v`` alone: every table's rows ride the
+    trips, as before."""
+    tr = Trainer(_cfg(
+        "dlrm_dcnv2", devices, numeric_fields=2, bottom_layers="8,4",
+        cross_layers=2, cross_rank=2, deep_layers="8,4", dropout="1,1"))
+    assert tr._grad_by_rows() and not tr._row_local_eligible()
+    rows = tr.model.padded_vocab
+    shapes = _collective_shapes(tr.step_hlo_text())
+    pairs = devices * loop.ROW_UPDATE_CAPACITY
+    assert _as_tall_as(shapes, pairs) == [(pairs,), (pairs, 4)], shapes
+    assert not _as_tall_as(shapes, rows), shapes
+    assert (tr.embed_grad, tr.embed_grad_by_table) == (_how(devices), "")
+    assert {(row, inside) for row, _, inside in _table_scatters(
+        tr.step_hlo_text(), rows)} == {((4,), True)}
 
 
 def test_one_device_compiles_no_collective():
     tr = Trainer(_cfg("deepfm", 1))
-    assert not _collective_heights(tr.step_hlo_text())
-    assert tr.embed_grad == "rows"
+    assert not _collective_shapes(tr.step_hlo_text())
+    assert (tr.embed_grad, tr.embed_grad_by_table) == ("rows", "")
 
 
 NOT_ELIGIBLE = {

@@ -309,10 +309,13 @@ def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
         "row_trips_max": 1, "one_trip_share": 1.0}
     if not eligible:
         assert all(set(a) == {"step", "embed_distinct_rows",
-                              "embed_row_trips", "embed_grad"}
-                   and a["embed_grad"] == "rows" for a in syncs)
+                              "embed_row_trips", "embed_grad",
+                              "embed_grad_by_table"}
+                   and a["embed_grad"] == "rows"
+                   and a["embed_grad_by_table"] == "" for a in syncs)
         assert report.row_updates(events) == {**counts, "writeback": "?"}
         assert report.table_gradient(events) == "rows"
+        assert report.tables_summed_as_tables(events) == ""
         assert report.main([path]) == 0
         assert ("dense-gradient step, table gradient from rows over 3 logged "
                 "steps: embed_distinct_rows mean %.0f max %d, embed_row_trips "
@@ -331,26 +334,41 @@ def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
             % (sum(want) / 3, max(want))) in capsys.readouterr().out
 
 
-@pytest.mark.parametrize("replicas", [2, 4])
+# a model whose tables are all wide: MLPerf's DLRM-DCNv2 holds ``fm_v`` alone
+_NO_ONE_WORD_ROW_TABLE = dict(
+    model="dlrm_dcnv2", numeric_fields=1, bottom_layers="8,4",
+    cross_layers=1, cross_rank=2)
+
+
+@pytest.mark.parametrize("replicas,over,by_table", [
+    (2, {}, "fm_w"), (4, {}, "fm_w"), (2, _NO_ONE_WORD_ROW_TABLE, "")],
+    ids=["2", "4", "2-no-one-word-row-table"])
 def test_log_sync_says_when_data_replicas_exchange_their_rows(
-        replicas, tmp_path, capsys):
+        replicas, over, by_table, tmp_path, capsys):
     """Under data replicas the dense-gradient step gathers the replicas'
     rows in place of all-reducing the tables, says so in ``embed_grad`` and
     adds ``embed_exchanged_rows`` (all replicas' rows, what each chip
     scattered) to the fullest replica's two counts; the report's line
-    carries its mean."""
+    carries its mean. ``embed_grad_by_table`` names the table whose row is
+    one word, which crosses as a table all the same, and is empty where the
+    model has none; the line says which."""
     trace_lib.configure("full", export_env=False)
-    tr = Trainer(_cfg(mesh_data=replicas))
+    tr = Trainer(_cfg(mesh_data=replicas, **over))
     batches = _batches(K * 3)
     tr.fit(tr.init_state(), batches)
     assert tr.embed_grad == "rows, exchanged over data"
+    assert tr.embed_grad_by_table == by_table
     syncs = [e["args"] for e in trace_lib._tracer.events()
              if e["name"] == "train.log_sync"]
-    per_replica = [[len(np.unique(shard)) for shard in np.split(
-        batches[K * i - 1]["feat_ids"], replicas)] for i in (1, 2, 3)]
+    per_replica = [[len(np.unique(tr.model.lookup_ids(shard)))
+                    for shard in np.split(
+                        batches[K * i - 1]["feat_ids"], replicas)]
+                   for i in (1, 2, 3)]
     assert all(set(a) == {"step", "embed_distinct_rows", "embed_row_trips",
-                          "embed_exchanged_rows", "embed_grad"}
-               and a["embed_grad"] == tr.embed_grad for a in syncs)
+                          "embed_exchanged_rows", "embed_grad",
+                          "embed_grad_by_table"}
+               and a["embed_grad"] == tr.embed_grad
+               and a["embed_grad_by_table"] == by_table for a in syncs)
     assert [a["embed_distinct_rows"] for a in syncs] == [
         max(d) for d in per_replica]
     assert [a["embed_exchanged_rows"] for a in syncs] == [
@@ -362,13 +380,17 @@ def test_log_sync_says_when_data_replicas_exchange_their_rows(
     mean = sum(sum(d) for d in per_replica) / 3
     assert report.row_updates(events)["exchanged_rows_mean"] == mean
     assert report.table_gradient(events) == tr.embed_grad
+    assert report.tables_summed_as_tables(events) == by_table
     assert report.main([path]) == 0
     out = capsys.readouterr().out
     assert ("dense-gradient step, table gradient from rows, exchanged over "
             "data over 3 logged steps: embed_distinct_rows mean") in out
     assert ("every row swept after it (the fullest replica's; every chip "
             "scattered all replicas' rows, embed_exchanged_rows mean %.0f a "
-            "step, and no table crossed the interconnect)" % mean) in out
+            "step, and %s)" % (mean, (
+                "of the tables only fm_w crossed the interconnect, "
+                "all-reduced" if by_table
+                else "no table crossed the interconnect"))) in out
 
 
 def test_log_sync_says_dma_where_the_kernel_writes_the_rows(monkeypatch):
@@ -403,7 +425,11 @@ def test_report_reads_a_trace_that_predates_the_writeback_attribute():
     ({"step": 2}, None),                    # predates the note: no line
     ({"step": 2, "embed_grad": "positions"},
      "dense-gradient step: table gradient from positions"),
-], ids=["predates-the-note", "positions"])
+    ({"step": 2, "embed_grad": "positions",
+      "embed_grad_by_table": "fm_w,fm_v"},
+     "of every position of the batch), summed over data as tables: "
+     "fm_w,fm_v"),
+], ids=["predates-the-note", "positions", "positions-on-replicas"])
 def test_report_says_how_a_dense_step_made_its_table_gradient(
         args, says, tmp_path, capsys):
     """A trace from before ``embed_grad`` existed reads as it did; a step
