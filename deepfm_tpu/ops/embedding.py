@@ -14,7 +14,7 @@ the local shard and ``ids`` are the (replicated-over-model) global indices.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional
+from typing import List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -371,34 +371,164 @@ class RowSums(NamedTuple):
     count: jax.Array
 
 
+class RowPlan(NamedTuple):
+    """One sort of a batch's (id, position) pairs: which distinct rows its
+    N positions name, and where each position's row lies among them. What
+    ``sum_rows`` sums by, and what a forward that reads each distinct row
+    once expands by (``Trainer._value_and_view_grads``).
+
+    The ids are sorted ascending as keys: negative ones counted from the
+    end, every id outside ``[0, kept rows)`` the one key ``int32`` max.
+
+    perm:  int32 [N]  the position of the i-th id in that order.
+    slot:  int32 [N]  the row slot of the i-th id in that order: distinct
+                      keys numbered ascending from 0.
+    uids:  int32 [M]  the distinct keys ascending, then ``int32`` max. M is
+                      static: the positions rounded up to ``multiple``.
+    held:  int32 []   distinct kept rows: the first ``held`` of ``uids``.
+    count: int32 []   those of them in ``[0, valid_rows)``, which lie
+                      first: the rows that receive.
+    """
+    perm: jax.Array
+    slot: jax.Array
+    uids: jax.Array
+    held: jax.Array
+    count: jax.Array
+
+    def ids_of(self, upto: jax.Array, num_rows: int) -> jax.Array:
+        """``uids`` with every slot from ``upto`` on an id past the table
+        (``num_rows + slot``: distinct, ascending, out of bounds), so a
+        gather reads fill there and a scatter drops it."""
+        at = jnp.arange(self.uids.shape[0], dtype=jnp.int32)
+        return jnp.where(at < upto, self.uids, num_rows + at)
+
+    def slot_of_position(self) -> jax.Array:
+        """int32 [N]: the row slot of every position, in the batch's order
+        (``slot`` through the inverse of ``perm``)."""
+        return jax.lax.sort((self.perm, self.slot), num_keys=1)[1]
+
+
 @jax.named_scope("embed")
+def plan_rows(ids: jax.Array, num_rows: int, valid_rows: int,
+              multiple: int = 1, *, keep_pad_rows: bool = False) -> RowPlan:
+    """The ``RowPlan`` of ``ids`` (any shape, N positions; negative ids
+    count from the end, as ``jnp.take`` reads them). Ids outside
+    ``[0, valid_rows)`` share the one key that receives nothing (pad rows,
+    ids past the table); with ``keep_pad_rows`` a pad row (``valid_rows <=
+    id < num_rows``) keeps a key of its own, after every row that receives
+    — a forward reads it like any other — and only the ids past the table,
+    which all read fill, share one."""
+    flat = ids.reshape(-1).astype(jnp.int32)
+    n = flat.shape[0]
+    m = -(-n // multiple) * multiple
+    flat = jnp.where(flat < 0, flat + num_rows, flat)
+    last = jnp.iinfo(jnp.int32).max
+    kept = num_rows if keep_pad_rows else valid_rows
+    key = jnp.where((flat >= 0) & (flat < kept), flat, last)
+    key, perm = jax.lax.sort((key, jnp.arange(n, dtype=jnp.int32)),
+                             num_keys=1)
+    first = jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), key[1:] != key[:-1]])
+    slot = jnp.cumsum(first.astype(jnp.int32)) - 1      # row slot a position
+    held = jnp.sum(first & (key != last), dtype=jnp.int32)
+    count = (jnp.sum(first & (key < valid_rows), dtype=jnp.int32)
+             if keep_pad_rows else held)
+    # each distinct id once, ascending: a sort is the cheapest compaction
+    uids = jnp.sort(jnp.where(first, key, last))
+    uids = jnp.concatenate([uids, jnp.full((m - n,), last, jnp.int32)])
+    return RowPlan(perm=perm, slot=slot, uids=uids, held=held, count=count)
+
+
+@jax.named_scope("embed")
+def sum_planned(plan: RowPlan, cots: jax.Array, num_rows: int) -> RowSums:
+    """Per distinct row of ``plan`` the float32 sum of the rows of ``cots``
+    ``[N, D]`` at its positions; rows outside ``[0, valid_rows)`` (slots
+    from ``plan.count`` on) hold nothing a scatter keeps."""
+    sums = jnp.zeros((plan.uids.shape[0], cots.shape[1]),
+                     jnp.float32).at[plan.slot].add(
+        jnp.take(cots.astype(jnp.float32), plan.perm, axis=0),
+        indices_are_sorted=True)
+    return RowSums(uids=plan.ids_of(plan.count, num_rows), sums=sums,
+                   count=plan.count)
+
+
+#: Words of one 128-lane line. A table row narrower than it is strided over
+#: tiles (ids run along the lanes) and dear to gather: ``take_planned`` pays.
+LANES = 128
+
+
+def narrow_rows(table: jax.Array) -> bool:
+    """Whether ``take_planned`` reads ``table``'s views cheaper than
+    ``jnp.take`` does a position: a row narrower than one 128-lane line. It
+    is one copy either way, and which is cheaper is a matter of what a table
+    row costs. In a ``[V, 32]`` table a row costs 39.9 ns to gather, in a
+    ``[V]`` one 7.6, a position copied from a batch-tall ``[N, 33]`` array
+    4.2, so at 6.8 positions a distinct row the two position gathers read
+    7.65 ms and the rows form 2.44 (PERF.md §6, PR 42: Step 0); a row of
+    whole lines gathers at 8.2 ns straight from the table (K = 128), which
+    is what the copy from the batch-tall array would cost again."""
+    return math.prod(table.shape[1:]) < LANES
+
+
+@jax.named_scope("embed")
+def take_planned(tables: Sequence[jax.Array], plan: RowPlan, shape,
+                 trip: int) -> List[jax.Array]:
+    """``jnp.take(table, ids, axis=0)`` of every table of ``tables`` (one
+    height, the ``num_rows`` of the plan) for the ``ids`` (of ``shape``)
+    that ``plan`` was made of with ``keep_pad_rows``, bit for bit, but each
+    distinct row read from its table once: the rows are gathered, ``trip``
+    slots a trip and as many trips as hold them (a spare slot of a gather
+    costs a filled one), into one array as tall as the plan, the tables'
+    columns side by side, and every position copies its slot of that array
+    (one copy for all the tables: a position of a ``[N]`` array costs what
+    one of a ``[V]`` table does, 7.6 ns, a row of ``[N, 33]`` 4.2; PERF.md
+    §6, PR 42). An id past the table names a slot past the table too, and
+    reads ``jnp.take``'s fill. Exact for any batch at static shapes."""
+    num_rows = tables[0].shape[0]
+    widths = [math.prod(t.shape[1:]) for t in tables]
+    cuts = np.cumsum([0] + widths)
+    uids = plan.ids_of(plan.held, num_rows)
+    trips = (plan.slot[-1] + trip) // trip      # slots read: slot[-1] + 1
+
+    # The rows travel as raw words, the widest table's type bit-cast (values
+    # of a narrower type pass through it and back: exact). Left as floats,
+    # XLA's bfloat16 propagation sees that the views' one reader casts them
+    # (bfloat16 compute), makes the loop's copy of the table bfloat16 and
+    # converts the whole table on the way into the loop, a pass over it a
+    # step (`convert bf16[V, 32]` in a step with one narrow table, as
+    # compiled for a v5e; PERF.md §6, PR 42).
+    wide = jnp.result_type(*tables)
+    words = jnp.dtype(f"uint{8 * wide.itemsize}")
+
+    def one(carry):
+        i, rows = carry
+        at = jax.lax.dynamic_slice_in_dim(uids, i * trip, trip)
+        got = jnp.concatenate([
+            jnp.take(t, at, axis=0).reshape(trip, w).astype(wide)
+            for t, w in zip(tables, widths)], axis=1)
+        return i + 1, jax.lax.dynamic_update_slice_in_dim(
+            rows, jax.lax.bitcast_convert_type(got, words), i * trip, axis=0)
+
+    rows = jnp.zeros((uids.shape[0], cuts[-1]), words)
+    varying = tuple(jax.typeof(uids).vma)   # inside shard_map: the ids' axes
+    if varying:     # the loop's carry is typed as what the trips write
+        rows = jax.lax.pcast(rows, varying, to="varying")
+    _, rows = jax.lax.while_loop(lambda carry: carry[0] < trips, one,
+                                 (jnp.zeros((), jnp.int32), rows))
+    views = jax.lax.bitcast_convert_type(
+        jnp.take(rows, plan.slot_of_position(), axis=0), wide)
+    return [views[:, cuts[j]:cuts[j + 1]].astype(t.dtype).reshape(
+        tuple(shape) + t.shape[1:]) for j, t in enumerate(tables)]
+
+
 def sum_rows(ids: jax.Array, cots: jax.Array, num_rows: int,
              valid_rows: int, multiple: int = 1) -> RowSums:
     """Per distinct id of ``ids`` (any shape, N positions; negative ids
     count from the end, as ``jnp.take`` reads them) the float32 sum of the
     rows of ``cots`` ``[N, D]`` at its positions. Ids outside
     ``[0, valid_rows)`` receive nothing (pad rows, ids past the table)."""
-    flat = ids.reshape(-1).astype(jnp.int32)
-    n = flat.shape[0]
-    m = -(-n // multiple) * multiple
-    flat = jnp.where(flat < 0, flat + num_rows, flat)
-    last = jnp.iinfo(jnp.int32).max
-    key = jnp.where((flat >= 0) & (flat < valid_rows), flat, last)
-    key, perm = jax.lax.sort((key, jnp.arange(n, dtype=jnp.int32)),
-                             num_keys=1)
-    first = jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_), key[1:] != key[:-1]])
-    slot = jnp.cumsum(first.astype(jnp.int32)) - 1      # row slot a position
-    count = jnp.sum(first & (key != last), dtype=jnp.int32)
-    sums = jnp.zeros((m, cots.shape[1]), jnp.float32).at[slot].add(
-        jnp.take(cots.astype(jnp.float32), perm, axis=0),
-        indices_are_sorted=True)
-    # each distinct id once, ascending: a sort is the cheapest compaction
-    uids = jnp.sort(jnp.where(first, key, last))
-    uids = jnp.concatenate([uids, jnp.full((m - n,), last, jnp.int32)])
-    at = jnp.arange(m, dtype=jnp.int32)
-    return RowSums(uids=jnp.where(at < count, uids, num_rows + at),
-                   sums=sums, count=count)
+    return sum_planned(plan_rows(ids, num_rows, valid_rows, multiple), cots,
+                       num_rows)
 
 
 # ---------------------------------------------------------------------------

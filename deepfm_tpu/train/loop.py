@@ -95,6 +95,13 @@ EMBED_GRAD = "embed_grad"
 #: word, whose all-reduce costs less than their slots in the trips; with the
 #: tables left to AD every one; ``Trainer.embed_grad_by_table``.
 EMBED_GRAD_BY_TABLE = "embed_grad_by_table"
+#: How a step that differentiates its tables' ``[B, F, ...]`` views (that
+#: one, and the row-local update) read them: "rows" (each distinct row of the
+#: batch gathered from its table once, the positions copied from those),
+#: "positions" (one table row gathered a position), or per table
+#: ("fm_w:rows,fm_v:positions") where a model's tables differ in row shape;
+#: ``Trainer.embed_lookup``.
+EMBED_LOOKUP = "embed_lookup"
 
 
 def pad_batch(batch: Dict[str, np.ndarray], bs: int) -> Dict[str, np.ndarray]:
@@ -314,6 +321,9 @@ class Trainer:
         # known once ``_dense_value_and_grad`` is traced.
         self.embed_grad: Optional[str] = None
         self.embed_grad_by_table: Optional[str] = None
+        # How a step that differentiates the tables' views read them:
+        # known once ``_value_and_view_grads`` is traced.
+        self.embed_lookup: Optional[str] = None
 
     # ------------------------------------------------------------------
     # State creation / placement
@@ -561,14 +571,14 @@ class Trainer:
         state's tree and shapes are the dense step's."""
         tabs, rest = self._tables_and_rest(state.params)
         ids = self.model.lookup_ids(batch["feat_ids"])
-        xent, new_mstate, g_views, g_rest = self._value_and_view_grads(
-            data_loss, tabs, rest, ids)
+        xent, new_mstate, g_views, g_rest, plan = (
+            self._value_and_view_grads(data_loss, tabs, rest, ids))
         opt_rest = opt_lib.select_params(state.opt_state, state.params, rest)
         opt_tabs = opt_lib.select_params(state.opt_state, state.params, tabs)
         new_rest, opt_rest = self._optax_apply(g_rest, opt_rest, rest)
 
         tabs, opt_tabs, counts = self._update_rows(tabs, opt_tabs, ids,
-                                                   g_views)
+                                                   g_views, plan)
         return (xent, new_mstate, {**new_rest, **tabs},
                 opt_lib.join_params(opt_rest, opt_tabs, rest), counts)
 
@@ -581,16 +591,41 @@ class Trainer:
     def _value_and_view_grads(self, data_loss, tabs, rest, ids, *,
                               mean_axis=None):
         """(xent, new model state, cotangents of the tables' ``[*ids.shape,
-        *row]`` views at ``ids``, gradient of the other leaves ``rest``) of
+        *row]`` views at ``ids``, gradient of the other leaves ``rest``, the
+        ``emb_ops.RowPlan`` of ``ids`` if the views were read by it) of
         ``data_loss``, the tables ``tabs`` themselves not differentiated:
         the model reads the views (``emb_rows``) where it would have looked
         ``ids`` up. With ``mean_axis`` the loss is its mean over that mesh
         axis (``_dense_value_and_grad``'s sync point): the other leaves'
         gradient comes back reduced over it, the views' cotangents — the
-        views vary over the axis — this shard's own, scaled by the mean."""
+        views vary over the axis — this shard's own, scaled by the mean.
+
+        The views are ``jnp.take(table, ids, axis=0)`` bit for bit. A table
+        whose row is narrower than one lane line (``_looked_up_by_rows``)
+        is read once a distinct row of the batch, not once a position
+        (``emb_ops.take_planned``), by the one sort of (id, position) that
+        the cotangents are then summed by (``_view_row_sums``)."""
         emb, names = self.model.emb, self._embed_names
+        by_rows = self._looked_up_by_rows(tabs)
+        lookup = ("rows" if len(by_rows) == len(names) else
+                  "positions" if not by_rows else
+                  ",".join(f"{n}:{'rows' if n in by_rows else 'positions'}"
+                           for n in names))
+        if lookup != self.embed_lookup:     # said once a trainer, at trace
+            self.embed_lookup = lookup      # time
+            ulog.info(f"table views of a step differentiated by views: "
+                      f"looked up by {lookup}")
+        plan = None
         with jax.named_scope("embed"):
-            views = {n: jnp.take(tabs[n], ids, axis=0) for n in names}
+            views = {n: jnp.take(tabs[n], ids, axis=0) for n in names
+                     if n not in by_rows}
+            if by_rows:
+                plan = emb_ops.plan_rows(
+                    ids, self.model.padded_vocab, self.cfg.feature_size,
+                    multiple=ROW_UPDATE_CAPACITY, keep_pad_rows=True)
+                views.update(zip(by_rows, emb_ops.take_planned(
+                    [tabs[n] for n in by_rows], plan, ids.shape,
+                    ROW_UPDATE_CAPACITY)))
 
         def loss_fn(diff):
             views, rest = diff
@@ -603,17 +638,27 @@ class Trainer:
 
         (_, (xent, new_mstate)), (g_views, g_rest) = jax.value_and_grad(
             loss_fn, has_aux=True)((views, rest))
-        return xent, new_mstate, g_views, g_rest
+        return xent, new_mstate, g_views, g_rest, plan
 
-    def _view_row_sums(self, tabs, ids, g_views, *, gather_axis=None,
-                       apart=()):
+    def _looked_up_by_rows(self, tabs) -> Tuple[str, ...]:
+        """The tables of ``tabs`` whose views ``_value_and_view_grads``
+        reads once a distinct row: those whose row is narrower than one
+        128-lane line (``emb_ops.narrow_rows``: read from the table's shape,
+        as ``_summed_as_tables`` reads it)."""
+        return tuple(n for n in self._embed_names
+                     if emb_ops.narrow_rows(tabs[n]))
+
+    def _view_row_sums(self, tabs, ids, g_views, plan=None, *,
+                       gather_axis=None, apart=()):
         """(``emb_ops.RowSums`` of the distinct rows of ``ids``: per row the
         float32 sum of its positions' cotangents ``g_views``, every table's
         columns side by side, slots a whole number of trips; the trips of
         ``ROW_UPDATE_CAPACITY`` rows that hold them; ``rows_of(i)`` = the
         ids of trip ``i`` and each table's rows of sums there; the tables
         ``apart``, which the trips leave out: each one's rows of sums at
-        every slot of ``rows.uids``). Sorted, so the last trip's spare
+        every slot of ``rows.uids``). Summed by ``plan`` where the forward
+        made one of ``ids`` (``_value_and_view_grads``), else by a sort of
+        its own. Sorted, so the last trip's spare
         slots lie past the table: read as fill, dropped or skipped by a
         write. With ``gather_axis`` (data replicas: the rows are this
         shard's) the trips are the fullest shard's and ``rows_of(i)`` is
@@ -623,10 +668,13 @@ class Trainer:
         names, cap = self._embed_names, ROW_UPDATE_CAPACITY
         widths = [math.prod(tabs[n].shape[1:]) for n in names]
         cuts = np.cumsum([0] + widths)
-        rows = emb_ops.sum_rows(
-            ids, jnp.concatenate([g_views[n].reshape(ids.size, w)
-                                  for n, w in zip(names, widths)], axis=1),
-            self.model.padded_vocab, self.cfg.feature_size, multiple=cap)
+        cots = jnp.concatenate([g_views[n].reshape(ids.size, w)
+                                for n, w in zip(names, widths)], axis=1)
+        num_rows = self.model.padded_vocab
+        rows = (emb_ops.sum_planned(plan, cots, num_rows)
+                if plan is not None else    # every view was read a position:
+                emb_ops.sum_rows(           # the ids are sorted here
+                    ids, cots, num_rows, self.cfg.feature_size, multiple=cap))
         trips = (rows.count + cap - 1) // cap
         if gather_axis is not None:     # before the loop: a collective in
             # its body needs every shard to make the same trips
@@ -653,7 +701,7 @@ class Trainer:
         return rows, trips, rows_of, split(rows.sums, apart)
 
     @jax.named_scope("embed")
-    def _update_rows(self, tabs, opt_tabs, ids, g_views):
+    def _update_rows(self, tabs, opt_tabs, ids, g_views, plan=None):
         """(tables, their optimizer state, row counts) after the optimizer's
         update of the distinct rows of ``ids``, for the cotangents
         ``g_views`` of the tables' ``[..., *row]`` views at ``ids``; rows no
@@ -670,7 +718,8 @@ class Trainer:
             self.row_writeback = how
             ulog.info(f"row-local table update: {cap} rows a trip, written "
                       f"back by {how}")
-        rows, trips, rows_of, _ = self._view_row_sums(tabs, ids, g_views)
+        rows, trips, rows_of, _ = self._view_row_sums(tabs, ids, g_views,
+                                                      plan)
 
         def take(table, uids):
             return jnp.take(table, uids, axis=0, mode="fill", fill_value=0)
@@ -712,7 +761,7 @@ class Trainer:
         return tabs, opt_tabs, dict(zip(ROW_COUNTS, (rows.count, trips)))
 
     @jax.named_scope("embed")
-    def _table_grads(self, tabs, ids, g_views, *, sum_axis=None):
+    def _table_grads(self, tabs, ids, g_views, plan=None, *, sum_axis=None):
         """(the table-shaped gradient of every table, row counts) from the
         cotangents ``g_views`` of the tables' views at ``ids``: zeros, and
         the batch's distinct rows added, each the float32 sum of its
@@ -746,7 +795,7 @@ class Trainer:
         table (PERF.md §6, PR 41)."""
         apart = self._summed_as_tables(tabs, sum_axis)
         rows, trips, rows_of, own = self._view_row_sums(
-            tabs, ids, g_views, gather_axis=sum_axis, apart=apart)
+            tabs, ids, g_views, plan, gather_axis=sum_axis, apart=apart)
 
         def trip(carry):
             i, grads = carry
@@ -829,9 +878,10 @@ class Trainer:
             # out of AD; the views' cotangents are local, so the tables
             # made of them are summed over the axis explicitly, by an
             # exchange of the shards' rows.
-            xent, new_mstate, g_views, g_rest = self._value_and_view_grads(
-                data_loss, tabs, rest, ids, mean_axis=sync)
-            g_tabs, counts = self._table_grads(tabs, ids, g_views,
+            xent, new_mstate, g_views, g_rest, plan = (
+                self._value_and_view_grads(data_loss, tabs, rest, ids,
+                                           mean_axis=sync))
+            g_tabs, counts = self._table_grads(tabs, ids, g_views, plan,
                                                sum_axis=sync)
             grads = {**g_rest, **g_tabs}
         if data_axis is not None and not flat_sync:
@@ -2252,6 +2302,8 @@ class Trainer:
                             for key, how in ((EMBED_GRAD, self.embed_grad),
                                              (EMBED_GRAD_BY_TABLE,
                                               self.embed_grad_by_table),
+                                             (EMBED_LOOKUP,
+                                              self.embed_lookup),
                                              (ROW_WRITEBACK,
                                               self.row_writeback)):
                                 if how is not None:

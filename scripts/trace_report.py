@@ -62,7 +62,13 @@ tables whose gradient crossed the interconnect as a table ("" where none
 did): under the exchange those whose row is one word (``fm_w``: one scatter
 of the replica's own rows beside the trips, then the table's all-reduce,
 cheaper than its slots in every chip's trips), with the tables left to AD
-every one; the line names them.
+every one; the line names them. A step that differentiates its tables'
+views (that one, and the row-local update) also says how it read them
+(``embed_lookup``): ``rows``, each distinct row of the batch gathered from
+its table once and the positions copied from those (a table whose row is
+narrower than a lane line: ``Trainer._looked_up_by_rows``), ``positions``,
+one table row gathered a position, or per table where a model's tables
+differ (``fm_w:rows,fm_v:positions``); the line names it.
 
 Where the model says what makes its attention's masked scores
 (``--model sdar_moe``, ``--model solar_open2``, ``--model lfm2_moe``), each
@@ -366,23 +372,34 @@ def row_updates(events):
     return out
 
 
+def _last_said(events, attribute):
+    """``attribute`` of the last ``train.log_sync`` that carries it; None
+    where none does."""
+    seen = _log_syncs(events, attribute)
+    return seen[-1][attribute] if seen else None
+
+
 def table_gradient(events):
     """How the dense-gradient step made its table-shaped gradient
-    (``embed_grad`` of the last ``train.log_sync`` that says: "rows" /
-    "rows, exchanged over data" / "positions"); None in a row-local step's
-    trace, a sparse-update one's, or one that predates the note."""
-    seen = _log_syncs(events, "embed_grad")
-    return seen[-1]["embed_grad"] if seen else None
+    (``embed_grad``: "rows" / "rows, exchanged over data" / "positions");
+    None in a row-local step's trace, a sparse-update one's, or one that
+    predates the note."""
+    return _last_said(events, "embed_grad")
+
+
+def table_lookup(events):
+    """How a step that differentiates its tables' views read them
+    (``embed_lookup``: "rows" / "positions" / per table); None in a trace
+    of any other step, or one that predates the note."""
+    return _last_said(events, "embed_lookup")
 
 
 def tables_summed_as_tables(events):
     """Which tables' gradient the dense-gradient step summed over its data
-    replicas as a table (``embed_grad_by_table`` of the last
-    ``train.log_sync`` that says: names joined by ","; "" where none was —
-    one device, or every table's rows exchanged); None where
-    ``table_gradient`` is, or in a trace that predates the note."""
-    seen = _log_syncs(events, "embed_grad_by_table")
-    return seen[-1]["embed_grad_by_table"] if seen else None
+    replicas as a table (``embed_grad_by_table``: names joined by ","; ""
+    where none was — one device, or every table's rows exchanged); None
+    where ``table_gradient`` is, or in a trace that predates the note."""
+    return _last_said(events, "embed_grad_by_table")
 
 
 def attention_scores(events):
@@ -522,11 +539,13 @@ def main(argv=None):
     grad, whole = table_gradient(events), tables_summed_as_tables(events)
     if touched is not None:
         by_rows = grad is not None      # "rows", exchanged or not
-        print("%s over %d logged steps: "
+        lookup = table_lookup(events)
+        print("%s%s over %d logged steps: "
               "embed_distinct_rows mean %.0f max %d, embed_row_trips mean "
               "%.2f max %d, one trip in %.0f%% of them, %s" % (
                   f"dense-gradient step, table gradient from {grad}"
                   if by_rows else "row-local table update",
+                  f", views looked up by {lookup}" if lookup else "",
                   touched["steps"], touched["distinct_rows_mean"],
                   touched["distinct_rows_max"], touched["row_trips_mean"],
                   touched["row_trips_max"],

@@ -40,6 +40,20 @@ trips' body scatters into no ``[V]`` operand and their pairs are as wide as
 the wide tables alone; the one table-tall collective is that table's; a
 model without such a table compiles none; one device compiles the loop it
 compiled, both tables in its trips.
+
+The views themselves are read by the same sort of (id, position), made
+ahead of the forward (PERF.md §6, PR 42): a table whose row is narrower than
+a lane line is gathered once a distinct row of the batch
+(``ops.embedding.take_planned``) and the positions copy their slot. Held:
+
+* the views against ``jnp.take(table, ids, axis=0)`` bit for bit: repeated
+  ids, an all-distinct batch, more distinct rows than one trip and than
+  two, negative ids, pad rows, ids past the table (which read the fill);
+* the step against the same step with every table gathered a position (the
+  parent's forward), bit for bit: loss, counts, tables and both moments,
+  on 1, 2 and 4 data replicas and in the row-local update;
+* the compiled step: no gather of a batch's positions from a table whose
+  row is narrow, and the parent's gather from one whose row is a lane line.
 """
 
 import functools
@@ -57,6 +71,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepfm_tpu.config import Config
+from deepfm_tpu.ops import embedding as emb_ops
 from deepfm_tpu.train import Trainer, loop
 from deepfm_tpu.utils import profiling
 
@@ -550,3 +565,213 @@ def test_everything_else_compiles_the_step_it_compiled(why):
     assert heights and loop.ROW_UPDATE_CAPACITY not in heights, heights
     if positions is not None:
         assert set(heights) == {positions}, heights
+
+
+# ---------------------------------------------------------------------------
+# The forward reads each distinct row once (PR 42)
+# ---------------------------------------------------------------------------
+
+ROWS = 320              # the tables' height: V = 300 real rows, 20 pad rows
+
+
+def _id_cases():
+    rng = np.random.default_rng(9)
+    some = rng.integers(0, 40, size=(B, F)).astype(np.int32)
+    odd = some.copy()
+    odd[0, :] = [-1, -ROWS, -(ROWS - 3), -ROWS - 5, -7, -(ROWS - V)]
+    pad = some.copy()
+    pad[0, :] = [V, V + 1, ROWS - 1, V, 5, V - 1]
+    past = some.copy()
+    past[0, :] = [ROWS, ROWS + 5, 2 ** 31 - 1, -ROWS - 1, -2 ** 31, ROWS]
+    return {
+        "repeated": some,
+        "one-row": np.full((B, F), 77, np.int32),
+        "all-distinct": rng.permutation(V)[:B * F].reshape(B, F).astype(
+            np.int32),
+        "negative": odd, "pad-rows": pad, "past-the-table": past,
+        "all-past-the-table": np.full((B, F), ROWS + 3, np.int32),
+    }
+
+
+ID_CASES = _id_cases()
+
+
+@pytest.mark.parametrize("trip", [4, 64, 100, 2048])
+@pytest.mark.parametrize("case", sorted(ID_CASES))
+def test_views_equal_take_bit_for_bit(case, trip):
+    """A ``[V]`` and a ``[V, K]`` table together, at trips that one, two
+    and many of hold the distinct rows (an all-distinct batch has 192: two
+    trips of 100, three of 64, 48 of 4): every view is ``jnp.take``'s, the
+    NaN an id past the table reads included."""
+    ids = ID_CASES[case]
+    rng = np.random.default_rng(2)
+    tables = [jnp.asarray(rng.normal(size=shape).astype(np.float32))
+              for shape in ((ROWS,), (ROWS, 4), (ROWS, 2, 3))]
+
+    def views(tables, ids):
+        plan = emb_ops.plan_rows(ids, ROWS, V, multiple=trip,
+                                 keep_pad_rows=True)
+        return emb_ops.take_planned(tables, plan, ids.shape, trip), plan
+
+    got, plan = jax.jit(views)(tables, jnp.asarray(ids))
+    for table, view in zip(tables, got):
+        want = jnp.take(table, jnp.asarray(ids), axis=0)
+        assert view.shape == want.shape and view.dtype == want.dtype
+        assert np.asarray(view).tobytes() == np.asarray(want).tobytes()
+    flat = ids.reshape(-1).astype(np.int64)
+    norm = np.where(flat < 0, flat + ROWS, flat)
+    in_table = (norm >= 0) & (norm < ROWS)
+    assert int(plan.held) == len(np.unique(norm[in_table]))
+    assert int(plan.count) == len(np.unique(norm[(norm >= 0) & (norm < V)]))
+    if case == "past-the-table":
+        assert np.isnan(np.asarray(got[0])[0]).all()
+
+
+def test_tables_of_two_types_are_read_as_their_own():
+    """The rows of one trip lie side by side in one array of the wider
+    type; each view comes back in its table's."""
+    ids = jnp.asarray(ID_CASES["negative"])
+    rng = np.random.default_rng(3)
+    tables = [jnp.asarray(rng.normal(size=(ROWS, 4)), jnp.bfloat16),
+              jnp.asarray(rng.normal(size=(ROWS,)), jnp.float32)]
+    plan = emb_ops.plan_rows(ids, ROWS, V, multiple=64, keep_pad_rows=True)
+    for table, view in zip(tables, emb_ops.take_planned(tables, plan,
+                                                        ids.shape, 64)):
+        want = jnp.take(table, ids, axis=0)
+        assert view.dtype == table.dtype
+        assert np.asarray(view).tobytes() == np.asarray(want).tobytes()
+
+
+def _by_positions(tr):
+    """The same trainer with every table gathered a position: the parent's
+    forward, the plan made where the cotangents are summed."""
+    tr._looked_up_by_rows = lambda tabs: ()
+    return tr
+
+
+def _fit_steps(tr, batches):
+    state, metrics = tr.init_state(), []
+    for batch in batches:
+        state, m = tr.train_step(state, tr.put_batch(batch))
+        metrics.append({k: float(v) for k, v in m.items()})
+    return _host(state), metrics
+
+
+def _assert_same_bits(got, want):
+    flat_got, _ = jax.tree_util.tree_flatten_with_path(got)
+    for (path, a), b in zip(flat_got, jax.tree.leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), (
+            jax.tree_util.keystr(path))
+
+
+STEP_CASES = [pytest.param(m, d, c, id=f"{m}-{d}dev-trip{c}")
+              for m in sorted(MODELS) for d in (1,) + REPLICAS
+              for c in (8, 2048)]
+
+
+@pytest.mark.parametrize("model,devices,capacity", STEP_CASES)
+def test_step_equals_the_step_that_gathers_a_position(monkeypatch, model,
+                                                      devices, capacity):
+    """A gather is a copy and the cotangents are summed in the same order,
+    so nothing after the views may differ by a bit: the loss and the counts
+    of every step, the tables, both moments, the dense leaves."""
+    monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
+    cfg = _cfg(model, devices)
+    batches = _batches(cfg.num_tasks > 1)
+    # negative ids and pad rows (read, as zeros; an id past the table would
+    # read NaN into both losses)
+    batches[1]["feat_ids"][0, :] = [-1, -ROWS, V, ROWS - 1, -(ROWS - V), 7]
+    tr = Trainer(cfg)
+    got, got_m = _fit_steps(tr, batches)
+    want, want_m = _fit_steps(_by_positions(Trainer(cfg)), batches)
+    assert tr.embed_lookup == "rows"
+    assert got_m == want_m
+    for tree in ("params", "opt_state"):
+        _assert_same_bits(getattr(got, tree), getattr(want, tree))
+
+
+@pytest.mark.parametrize("capacity", [8, 2048])
+def test_row_local_step_equals_the_step_that_gathers_a_position(monkeypatch,
+                                                                capacity):
+    monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
+    cfg = _cfg("deepfm", optimizer="Adagrad", l2_reg=0.0)
+    tr = Trainer(cfg)
+    assert tr._row_local_eligible()
+    got, got_m = _fit_steps(tr, _batches())
+    want, want_m = _fit_steps(_by_positions(Trainer(cfg)), _batches())
+    assert tr.embed_lookup == "rows" and got_m == want_m
+    for tree in ("params", "opt_state"):
+        _assert_same_bits(getattr(got, tree), getattr(want, tree))
+
+
+@pytest.mark.parametrize("devices", REPLICAS)
+def test_replicas_stay_bit_identical_with_the_views_read_by_rows(devices):
+    """Each replica plans and reads its own slice of the batch; what they
+    exchange and add is what it was, so their copies cannot drift."""
+    tr = Trainer(_cfg("deepfm", devices))
+    state = tr.init_state()
+    for batch in _batches(steps=2):
+        state, _ = tr.train_step(state, tr.put_batch(batch))
+    assert tr.embed_lookup == "rows"
+    names = tr.model.embedding_param_names()
+    copies = _replica_copies(state, lambda k: any(n in k for n in names))
+    for key, per_device in copies.items():
+        assert len(per_device) == devices and per_device[0].any(), key
+        assert all(other.tobytes() == per_device[0].tobytes()
+                   for other in per_device[1:]), key
+
+
+def _table_gathers(hlo_text, rows):
+    """Per gather of the compiled program from an array ``rows`` tall:
+    (that array's row shape, how many rows it gathers)."""
+    found, shapes = [], {}
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]", line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        shapes[m.group(1)] = dims
+        g = re.search(r" gather\(%([\w.\-]+), %([\w.\-]+)\)", line)
+        if g and shapes.get(g.group(1), ())[:1] == (rows,):
+            taken = shapes[g.group(2)]
+            found.append((shapes[g.group(1)][1:],
+                          math.prod(taken[:-1] if len(taken) > 1 else taken)))
+    return found
+
+
+LOOKUP_CASES = {
+    # flags: (embed_lookup, {row shape: rows its gathers take})
+    "deepfm-k4": (dict(), "rows", {(): "trip", (4,): "trip"}),
+    "deepfm-k128": (dict(embedding_size=128), "fm_w:rows,fm_v:positions",
+                    {(): "trip", (128,): "positions"}),
+    "deepfm-k127": (dict(embedding_size=127), "rows",
+                    {(): "trip", (127,): "trip"}),
+    "dlrm_dcnv2-k128": (dict(
+        model="dlrm_dcnv2", numeric_fields=2, bottom_layers="8,128",
+        cross_layers=2, cross_rank=2, deep_layers="8,4", dropout="1,1",
+        embedding_size=128), "positions", {(128,): "positions"}),
+    "row-local-k4": (dict(optimizer="Adagrad", l2_reg=0.0), "rows",
+                     {(): "trip", (4,): "trip"}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LOOKUP_CASES))
+def test_which_tables_are_read_by_rows_is_the_rows_shape(case):
+    """A row narrower than one 128-lane line is gathered a trip of distinct
+    rows at a time and never a position; a row of whole lines compiles to
+    the gather it compiled, every position straight from the table, and a
+    step with no narrow table to the parent's step."""
+    flags, lookup, want = LOOKUP_CASES[case]
+    # (one line compiles both: the text carries its callers' line numbers)
+    (tr, text), (_, parent) = [(t, t.step_hlo_text()) for t in (
+        Trainer(_cfg(**flags)), _by_positions(Trainer(_cfg(**flags))))]
+    assert tr.embed_lookup == lookup
+    positions = tr.model.lookup_ids(np.zeros((B, F), np.int32)).size
+    cap = loop.ROW_UPDATE_CAPACITY
+    forward = {}
+    for row, taken in _table_gathers(text, tr.model.padded_vocab):
+        forward.setdefault(row, set()).add(taken)
+    # (a row-local step also gathers its trips of rows to update them)
+    assert {row: ({positions} if how == "positions" else {cap})
+            for row, how in want.items()} == forward, forward
+    assert (text == parent) == (lookup == "positions")

@@ -131,8 +131,13 @@ DEEPFM_K32 = dict(model="deepfm", numeric_fields=0, bottom_layers="",
                   feature_size=1_600_000, l2_reg=1e-4)
 
 
-@pytest.mark.parametrize("over", [{}, DEEPFM_K32],
-                         ids=["dlrm_dcnv2-k128", "deepfm-k32-l2"])
+DLRM_K32 = dict(embedding_size=32, bottom_layers="64,32",
+                feature_size=1_600_000)
+
+
+@pytest.mark.parametrize("over", [{}, DEEPFM_K32, DLRM_K32],
+                         ids=["dlrm_dcnv2-k128", "deepfm-k32-l2",
+                              "dlrm_dcnv2-k32"])
 def test_the_table_shaped_step_still_sweeps(v5e, no_compile_cache,
                                             monkeypatch, over):
     """The same model under Adam, and DeepFM at the DeepFM cells' row with
@@ -140,7 +145,11 @@ def test_the_table_shaped_step_still_sweeps(v5e, no_compile_cache,
     The scatter-adds take a trip of the batch's distinct rows
     (``Trainer._table_grads``), never its positions, and update the loop's
     carry where it lies: a copy of the 2.16 GB gradient a trip would cost
-    more than the scatter saves."""
+    more than the scatter saves. A narrow table's views are read by the
+    batch's rows (``ops.embedding.take_planned``), and no cast of the whole
+    table rides into that loop: with one narrow table and bfloat16 compute
+    XLA's bfloat16 propagation put a ``convert bf16[V, 32]`` there until
+    the rows travelled as raw words (PERF.md §6, PR 42)."""
     tr, compiled = _compiled(v5e, monkeypatch, optimizer="Adam", **over)
     assert not tr._row_local_eligible() and tr.embed_grad == "rows"
     ops = profiling.hlo_table_ops(compiled.as_text(), tr.model.padded_vocab)
@@ -153,6 +162,8 @@ def test_the_table_shaped_step_still_sweeps(v5e, no_compile_cache,
         assert op["in_place"] == [0] and op["scope"] == "embed", op
     assert "copy" not in {o["opcode"] for o in ops
                           if any("," in r for r in o["results"])}, ops
+    assert "convert" not in {o["opcode"] for o in ops}, ops
+    assert tr.embed_lookup == ("positions" if not over else "rows")
 
 
 # --- the block-masked attention kernel (ops/block_attention.py; PR 32) ------

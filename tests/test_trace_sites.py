@@ -307,17 +307,21 @@ def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
         "steps": 3, "distinct_rows_mean": sum(want) / 3,
         "distinct_rows_max": max(want), "row_trips_mean": 1.0,
         "row_trips_max": 1, "one_trip_share": 1.0}
+    # either step read its [V] and [V,4] tables' views by the batch's rows
+    assert [a["embed_lookup"] for a in syncs] == ["rows"] * 3
+    assert report.table_lookup(events) == tr.embed_lookup == "rows"
     if not eligible:
         assert all(set(a) == {"step", "embed_distinct_rows",
                               "embed_row_trips", "embed_grad",
-                              "embed_grad_by_table"}
+                              "embed_grad_by_table", "embed_lookup"}
                    and a["embed_grad"] == "rows"
                    and a["embed_grad_by_table"] == "" for a in syncs)
         assert report.row_updates(events) == {**counts, "writeback": "?"}
         assert report.table_gradient(events) == "rows"
         assert report.tables_summed_as_tables(events) == ""
         assert report.main([path]) == 0
-        assert ("dense-gradient step, table gradient from rows over 3 logged "
+        assert ("dense-gradient step, table gradient from rows, views looked "
+                "up by rows over 3 logged "
                 "steps: embed_distinct_rows mean %.0f max %d, embed_row_trips "
                 "mean 1.00 max 1, one trip in 100%% of them, every row swept "
                 "after it" % (sum(want) / 3, max(want))
@@ -328,7 +332,8 @@ def test_log_sync_carries_the_row_counters(eligible, tmp_path, capsys):
     assert report.row_updates(events) == {**counts, "writeback": "scatter"}
     assert report.table_gradient(events) is None
     assert report.main([path]) == 0
-    assert ("row-local table update over 3 logged steps: embed_distinct_rows "
+    assert ("row-local table update, views looked up by rows over 3 logged "
+            "steps: embed_distinct_rows "
             "mean %.0f max %d, embed_row_trips mean 1.00 max 1, one trip in "
             "100%% of them, rows written back by scatter"
             % (sum(want) / 3, max(want))) in capsys.readouterr().out
@@ -366,8 +371,9 @@ def test_log_sync_says_when_data_replicas_exchange_their_rows(
                    for i in (1, 2, 3)]
     assert all(set(a) == {"step", "embed_distinct_rows", "embed_row_trips",
                           "embed_exchanged_rows", "embed_grad",
-                          "embed_grad_by_table"}
+                          "embed_grad_by_table", "embed_lookup"}
                and a["embed_grad"] == tr.embed_grad
+               and a["embed_lookup"] == "rows"
                and a["embed_grad_by_table"] == by_table for a in syncs)
     assert [a["embed_distinct_rows"] for a in syncs] == [
         max(d) for d in per_replica]
@@ -384,7 +390,8 @@ def test_log_sync_says_when_data_replicas_exchange_their_rows(
     assert report.main([path]) == 0
     out = capsys.readouterr().out
     assert ("dense-gradient step, table gradient from rows, exchanged over "
-            "data over 3 logged steps: embed_distinct_rows mean") in out
+            "data, views looked up by rows over 3 logged steps: "
+            "embed_distinct_rows mean") in out
     assert ("every row swept after it (the fullest replica's; every chip "
             "scattered all replicas' rows, embed_exchanged_rows mean %.0f a "
             "step, and %s)" % (mean, (
@@ -408,9 +415,12 @@ def test_log_sync_says_dma_where_the_kernel_writes_the_rows(monkeypatch):
     tr.fit(tr.init_state(), _batches(K * 2))
     # deepfm: fm_v [V,128] by the kernel, the first-order fm_w [V] not
     assert tr.row_writeback == "dma+scatter"
+    # and a row of whole lines is gathered a position, the [V] one by rows
+    assert tr.embed_lookup == "fm_w:rows,fm_v:positions"
     events = trace_lib._tracer.events()
     syncs = [e["args"] for e in events if e["name"] == "train.log_sync"]
     assert [a["embed_row_writeback"] for a in syncs] == ["dma+scatter"] * 2
+    assert [a["embed_lookup"] for a in syncs] == [tr.embed_lookup] * 2
     assert _report().row_updates(
         [dict(e, ph="X") for e in events])["writeback"] == "dma+scatter"
 
@@ -449,6 +459,47 @@ def test_report_says_how_a_dense_step_made_its_table_gradient(
     assert "table gradient" in out if says else "table gradient" not in out
     if says:
         assert says in out
+
+
+_COUNTS = {"step": 2, "embed_distinct_rows": 90, "embed_row_trips": 1}
+
+
+@pytest.mark.parametrize("args,says,lookup", [
+    ({**_COUNTS, "embed_grad": "rows", "embed_grad_by_table": ""},
+     "dense-gradient step, table gradient from rows over 1 logged steps",
+     None),                                 # predates the note: as it read
+    ({**_COUNTS, "embed_grad": "rows", "embed_grad_by_table": "",
+      "embed_lookup": "rows"},
+     "table gradient from rows, views looked up by rows over 1 logged",
+     "rows"),
+    ({**_COUNTS, "embed_grad": "rows, exchanged over data",
+      "embed_grad_by_table": "fm_w", "embed_exchanged_rows": 300,
+      "embed_lookup": "fm_w:rows,fm_v:positions"},
+     "table gradient from rows, exchanged over data, views looked up by "
+     "fm_w:rows,fm_v:positions over 1 logged", "fm_w:rows,fm_v:positions"),
+    ({**_COUNTS, "embed_row_writeback": "dma",
+      "embed_lookup": "positions"},
+     "row-local table update, views looked up by positions over 1 logged",
+     "positions"),
+], ids=["predates-the-note", "rows", "per-table-on-replicas",
+        "row-local-positions"])
+def test_report_says_how_a_step_read_its_tables_views(args, says, lookup,
+                                                      tmp_path, capsys):
+    """``embed_lookup`` rides on the span beside ``embed_grad`` (and beside
+    the write-back in a row-local step); the rows' line names it, and a
+    trace from before it existed reads as it did."""
+    import json
+    report = _report()
+    events = [{"name": "train.log_sync", "ph": "X", "ts": 0, "dur": 5,
+               "pid": 1, "tid": 1, "args": args}]
+    assert report.table_lookup(events) == lookup
+    path = str(tmp_path / "trace.json")
+    with open(path, "w") as f:
+        json.dump({"traceEvents": events}, f)
+    assert report.main([path]) == 0
+    out = capsys.readouterr().out
+    assert says in out
+    assert ("views looked up by" in out) == (lookup is not None)
 
 
 def _sdar_fit(n_steps=4):
