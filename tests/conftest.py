@@ -344,3 +344,71 @@ def _table_shaped_step_for_table_faults(request, monkeypatch):
         import deepfm_tpu.train.loop as loop
         monkeypatch.setattr(loop.Trainer, "_row_local_eligible",
                             lambda self: False)
+
+
+# ---------------------------------------------------------------------------
+# A step compiled once, and a described v5e to compile it for.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="session")
+def compiled_once():
+    """``(trainer, device=None) -> trainer.step_compiled(device)``, after
+    which the trainer answers ``step_compiled`` with that executable: a test
+    that runs the step, reads ``step_hlo_text()`` and asks
+    ``step_op_scopes()`` goes through the program's own methods and pays one
+    compilation (``tests/test_trace_sites.py`` holds the text to an
+    untouched trainer's)."""
+    def hold(trainer, device=None):
+        compiled = trainer.step_compiled(device)
+        trainer.step_compiled = lambda device=None: compiled
+        return compiled
+    return hold
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A described v5e (``jax.experimental.topologies``: no chip attached,
+    nothing runs; the ``on-chip-measurement`` guide, section 2), for what
+    only the TPU's compiler can say of a step: ``tests/test_tpu_compile_*``,
+    a file a family. Described inside a fixture because every xdist worker
+    imports every test file, and a process that may not load libtpu beside
+    another's skips these tests and no others; the tier-1 command sets
+    ``ALLOW_MULTIPLE_LIBTPU_LOAD=1``, under which none skips."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A described device cannot read an executable back from the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def step_for_v5e(v5e, no_compile_cache, compiled_once, monkeypatch):
+    """``flags -> (trainer, its step compiled for the described chip,
+    trainer.step_hlo_text())``: one compilation a call, whatever the test
+    reads of it."""
+    from deepfm_tpu.config import Config
+    from deepfm_tpu.parallel import mesh as mesh_lib
+    from deepfm_tpu.train import Trainer
+
+    # the trainer picks its kernels by backend: trace what a TPU host would
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def build(flags):
+        cfg = Config(**flags)
+        tr = Trainer(cfg, mesh_info=mesh_lib.build_mesh(cfg, devices=[v5e]))
+        return tr, compiled_once(tr, v5e), tr.step_hlo_text()
+    return build

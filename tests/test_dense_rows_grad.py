@@ -121,21 +121,50 @@ def _host(tree):
     return jax.tree.map(np.asarray, tree)
 
 
-def _run(cfg, by_rows=True, batches=None):
+def _trainer(model="deepfm", devices=1, l2_reg=1e-4, by="rows", **flags):
+    """The trainer of these flags, built once a module: a test that runs its
+    step shares the compiled step with every other that does, and a test
+    that reads its text (``_text``) the compiled text. ``by``: the tables'
+    gradient and views by ``rows`` (the program's own), the gradient left to
+    ``ad``, or the views gathered by ``positions``. The trips' capacity is
+    read when the step is traced, so it is part of the key."""
+    return _built(model, devices, l2_reg, by, loop.ROW_UPDATE_CAPACITY,
+                  tuple(sorted(flags.items())))
+
+
+@functools.lru_cache(maxsize=None)
+def _built(model, devices, l2_reg, by, capacity, flags):
+    tr = Trainer(_cfg(model, devices, l2_reg, **dict(flags)))
+    if by == "ad":
+        tr._grad_by_rows = lambda: False
+    elif by == "positions":     # the parent's forward: the plan is made
+        tr._looked_up_by_rows = lambda tabs: ()     # where the sums are
+    return tr
+
+
+@functools.lru_cache(maxsize=None)
+def _text(tr):
+    return tr.step_hlo_text()
+
+
+def _run(model="deepfm", devices=1, l2_reg=1e-4, by_rows=True, batches=None):
     """(trainer, state after, metrics of each step), by single
     ``train_step`` calls so that every step's counts come back; with
     ``by_rows`` off the tables are left to AD (the reference)."""
-    tr = Trainer(cfg)
-    assert tr._grad_by_rows() and not tr._row_local_eligible()
-    if not by_rows:
-        tr._grad_by_rows = lambda: False
-    state = tr.init_state()
-    metrics = []
-    for batch in batches or _batches(cfg.num_tasks > 1):
+    tr = _trainer(model, devices, l2_reg, by="rows" if by_rows else "ad")
+    assert by_rows == tr._grad_by_rows() and not tr._row_local_eligible()
+    state, metrics = _fit_steps(
+        tr, batches or _batches(tr.cfg.num_tasks > 1))
+    assert tr.embed_grad == _how(devices if by_rows else 0)
+    return tr, state, metrics
+
+
+def _fit_steps(tr, batches):
+    state, metrics = tr.init_state(), []
+    for batch in batches:
         state, m = tr.train_step(state, tr.put_batch(batch))
         metrics.append({k: float(v) for k, v in m.items()})
-    assert tr.embed_grad == _how(cfg.mesh_data if by_rows else 0)
-    return tr, _host(state), metrics
+    return _host(state), metrics
 
 
 def _how(devices):
@@ -147,7 +176,7 @@ def _how(devices):
 
 @functools.lru_cache(maxsize=None)
 def _by_ad(model, devices, l2_reg):
-    return _run(_cfg(model, devices, l2_reg), by_rows=False)
+    return _run(model, devices, l2_reg, by_rows=False)
 
 
 def _assert_close(got, want, what, tol=1e-6):
@@ -166,7 +195,7 @@ def _assert_close(got, want, what, tol=1e-6):
 @pytest.mark.parametrize("model,devices,l2_reg", CASES)
 def test_equals_the_gradient_ad_builds(model, devices, l2_reg):
     _, want, plain = _by_ad(model, devices, l2_reg)
-    tr, got, rows = _run(_cfg(model, devices, l2_reg))
+    tr, got, rows = _run(model, devices, l2_reg)
     for tree in ("params", "opt_state"):
         _assert_close(getattr(got, tree), getattr(want, tree), tree)
     assert [m["loss"] for m in rows] == pytest.approx(
@@ -182,10 +211,9 @@ def test_equals_the_gradient_ad_builds(model, devices, l2_reg):
 
 @pytest.mark.parametrize("model", sorted(MODELS))
 def test_one_row_takes_every_id_of_a_field(model):
-    cfg = _cfg(model)
-    batches = _batches(cfg.num_tasks > 1, one_row_field=F - 1)
-    _, got, rows = _run(cfg, batches=batches)
-    _, want, _ = _run(cfg, by_rows=False, batches=batches)
+    batches = _batches(model == "multitask", one_row_field=F - 1)
+    _, got, rows = _run(model, batches=batches)
+    _, want, _ = _run(model, by_rows=False, batches=batches)
     for tree in ("params", "opt_state"):
         _assert_close(getattr(got, tree), getattr(want, tree), tree)
     assert all(m["embed_row_trips"] == 1 for m in rows)
@@ -194,10 +222,9 @@ def test_one_row_takes_every_id_of_a_field(model):
 @pytest.mark.parametrize("capacity,trips", [(256, 1), (64, 2), (8, None),
                                             (1, None)])
 def test_more_distinct_rows_than_a_trip_holds(monkeypatch, capacity, trips):
-    cfg = _cfg("deepfm")
     _, want, _ = _by_ad("deepfm", 1, 1e-4)
     monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
-    _, got, rows = _run(cfg)
+    _, got, rows = _run("deepfm")
     for tree in ("params", "opt_state"):
         _assert_close(getattr(got, tree), getattr(want, tree), tree)
     for m, batch in zip(rows, _batches()):
@@ -215,8 +242,7 @@ def test_counts_against_numpy_unique(devices):
     """On data replicas each shard counts its own slice of the batch; the
     step reports the fullest shard's, and all shards' rows together: what
     every chip scatter-added."""
-    cfg = _cfg("deepfm", devices)
-    _, _, rows = _run(cfg)
+    _, _, rows = _run("deepfm", devices)
     for m, batch in zip(rows, _batches()):
         distinct = [len(np.unique(s))
                     for s in np.split(batch["feat_ids"], devices)]
@@ -235,7 +261,7 @@ def test_pad_rows_negative_ids_and_ids_past_the_table_receive_nothing(
     negative (as ``jnp.take`` reads them); a pad row (``feature_size`` and
     past it) and an id past the table receive nothing."""
     monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
-    tr = Trainer(_cfg("deepfm"))
+    tr = _trainer("deepfm")
     rows = tr.model.padded_vocab
     assert rows > V
     rng = np.random.default_rng(7)
@@ -277,7 +303,7 @@ def test_replicas_stay_bit_identical(devices):
     """Every chip scatter-adds the same gathered pairs in the same order,
     so the replicated tables and both of Adam's moments cannot drift apart:
     after 3 steps each device's copy is the first's bit for bit."""
-    tr = Trainer(_cfg("deepfm", devices))
+    tr = _trainer("deepfm", devices)
     state = tr.init_state()
     for batch in _batches():
         state, _ = tr.train_step(state, tr.put_batch(batch))
@@ -331,7 +357,7 @@ def test_replicas_that_need_different_trips_lose_nothing(
     (many trips at a small capacity), the last one row; every replica
     makes the fullest one's trips and the others hand in spare slots."""
     monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
-    tr = Trainer(_cfg("deepfm", devices))
+    tr = _trainer("deepfm", devices)
     rng = np.random.default_rng(3)
     per = B // devices
     ids = rng.integers(0, 40, size=(B, F)).astype(np.int32)
@@ -352,7 +378,7 @@ def test_replicas_that_need_different_trips_lose_nothing(
 @pytest.mark.parametrize("devices", REPLICAS)
 def test_a_row_every_replica_holds_receives_the_sum_of_all(devices):
     """Two replicas' pairs for one row are two slots of one scatter-add."""
-    tr = Trainer(_cfg("deepfm", devices))
+    tr = _trainer("deepfm", devices)
     rng = np.random.default_rng(4)
     ids = rng.integers(0, ID_RANGE, size=(B, F)).astype(np.int32)
     ids[:, 0] = 5           # every example of every replica looks row 5 up
@@ -376,7 +402,7 @@ def test_ids_that_receive_nothing_receive_nothing_on_any_replica(
     """The one-device case's ids, a few on every replica: pad rows,
     negative ids and ids past the table."""
     monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
-    tr = Trainer(_cfg("deepfm", devices))
+    tr = _trainer("deepfm", devices)
     rows = tr.model.padded_vocab
     rng = np.random.default_rng(7)
     ids = rng.integers(0, 40, size=(B, F)).astype(np.int32)
@@ -400,7 +426,7 @@ def test_more_distinct_rows_than_a_trip_holds_on_replicas(monkeypatch,
     """The whole step over several exchanged trips against AD's."""
     _, want, _ = _by_ad("deepfm", devices, 1e-4)
     monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", 8)
-    _, got, rows = _run(_cfg("deepfm", devices))
+    _, got, rows = _run("deepfm", devices)
     for tree in ("params", "opt_state"):
         _assert_close(getattr(got, tree), getattr(want, tree), tree)
     for m, batch in zip(rows, _batches()):
@@ -446,9 +472,9 @@ def test_eligible_step_scatters_trips_of_rows_never_positions(model, devices):
     """A trip scatters ``ROW_UPDATE_CAPACITY`` rows of every data replica;
     on replicas the one-word-row table takes the replica's own rows, every
     slot in one scatter."""
-    tr = Trainer(_cfg(model, devices))
+    tr = _trainer(model, devices)
     assert tr._grad_by_rows()
-    text = tr.step_hlo_text()
+    text = _text(tr)
     scatters = _table_scatters(text, tr.model.padded_vocab)
     in_trips = {h for row, h, _ in scatters if row or devices == 1}
     assert in_trips == {devices * loop.ROW_UPDATE_CAPACITY}, scatters
@@ -467,8 +493,8 @@ def test_the_trips_scatter_into_no_one_word_row_table_on_replicas(devices):
     """On replicas the ``[V]`` table's one scatter lies beside the trips'
     ``while``, whose body holds the wide table's alone; on one device the
     loop is as it was, both tables' scatters in its body."""
-    tr = Trainer(_cfg("deepfm", devices))
-    scatters = _table_scatters(tr.step_hlo_text(), tr.model.padded_vocab)
+    tr = _trainer("deepfm", devices)
+    scatters = _table_scatters(_text(tr), tr.model.padded_vocab)
     assert sorted((row, inside) for row, _, inside in scatters) == [
         ((), devices == 1), ((4,), True)], scatters
     assert tr.embed_grad_by_table == ("fm_w" if devices > 1 else "")
@@ -499,16 +525,15 @@ def test_only_one_word_row_tables_cross_as_tables_on_data_replicas(model,
     """What crosses the interconnect is a trip's pairs, as wide as the wide
     table alone, the ``[V]`` table's gradient, the dense leaves' and
     scalars; with the tables left to AD it is every table."""
-    tr = Trainer(_cfg(model, devices))
+    tr = _trainer(model, devices)
     rows = tr.model.padded_vocab
-    shapes = _collective_shapes(tr.step_hlo_text())
+    shapes = _collective_shapes(_text(tr))
     pairs = devices * loop.ROW_UPDATE_CAPACITY
     assert _as_tall_as(shapes, pairs) == [(pairs,), (pairs, 4)], shapes
     assert _as_tall_as(shapes, rows) == [(rows,)], shapes
     assert tr.embed_grad_by_table == "fm_w"
-    by_ad = Trainer(_cfg(model, devices))
-    by_ad._grad_by_rows = lambda: False
-    assert (rows, 4) in _collective_shapes(by_ad.step_hlo_text())
+    by_ad = _trainer(model, devices, by="ad")
+    assert (rows, 4) in _collective_shapes(_text(by_ad))
     assert by_ad.embed_grad_by_table == "fm_w,fm_v"
 
 
@@ -517,23 +542,23 @@ def test_a_model_without_a_one_word_row_table_compiles_no_table_collective(
         devices):
     """``dlrm_dcnv2`` holds ``fm_v`` alone: every table's rows ride the
     trips, as before."""
-    tr = Trainer(_cfg(
+    tr = _trainer(
         "dlrm_dcnv2", devices, numeric_fields=2, bottom_layers="8,4",
-        cross_layers=2, cross_rank=2, deep_layers="8,4", dropout="1,1"))
+        cross_layers=2, cross_rank=2, deep_layers="8,4", dropout="1,1")
     assert tr._grad_by_rows() and not tr._row_local_eligible()
     rows = tr.model.padded_vocab
-    shapes = _collective_shapes(tr.step_hlo_text())
+    shapes = _collective_shapes(_text(tr))
     pairs = devices * loop.ROW_UPDATE_CAPACITY
     assert _as_tall_as(shapes, pairs) == [(pairs,), (pairs, 4)], shapes
     assert not _as_tall_as(shapes, rows), shapes
     assert (tr.embed_grad, tr.embed_grad_by_table) == (_how(devices), "")
     assert {(row, inside) for row, _, inside in _table_scatters(
-        tr.step_hlo_text(), rows)} == {((4,), True)}
+        _text(tr), rows)} == {((4,), True)}
 
 
 def test_one_device_compiles_no_collective():
-    tr = Trainer(_cfg("deepfm", 1))
-    assert not _collective_shapes(tr.step_hlo_text())
+    tr = _trainer("deepfm", 1)
+    assert not _collective_shapes(_text(tr))
     assert (tr.embed_grad, tr.embed_grad_by_table) == ("rows", "")
 
 
@@ -555,12 +580,11 @@ def test_everything_else_compiles_the_step_it_compiled(why):
     trips of rows (the sparse plane has its own row plan and another state
     tree: only the predicate is held there)."""
     flags, height, positions = NOT_ELIGIBLE[why]
-    tr = Trainer(_cfg("din" if why == "history_model" else "deepfm",
-                      **flags))
+    tr = _trainer("din" if why == "history_model" else "deepfm", **flags)
     assert not tr._grad_by_rows() and not tr._row_local_eligible()
     if why == "sparse_update":
         return
-    heights = _table_scatter_heights(tr.step_hlo_text(), height)
+    heights = _table_scatter_heights(_text(tr), height)
     assert tr.embed_grad == "positions"
     assert heights and loop.ROW_UPDATE_CAPACITY not in heights, heights
     if positions is not None:
@@ -642,21 +666,6 @@ def test_tables_of_two_types_are_read_as_their_own():
         assert np.asarray(view).tobytes() == np.asarray(want).tobytes()
 
 
-def _by_positions(tr):
-    """The same trainer with every table gathered a position: the parent's
-    forward, the plan made where the cotangents are summed."""
-    tr._looked_up_by_rows = lambda tabs: ()
-    return tr
-
-
-def _fit_steps(tr, batches):
-    state, metrics = tr.init_state(), []
-    for batch in batches:
-        state, m = tr.train_step(state, tr.put_batch(batch))
-        metrics.append({k: float(v) for k, v in m.items()})
-    return _host(state), metrics
-
-
 def _assert_same_bits(got, want):
     flat_got, _ = jax.tree_util.tree_flatten_with_path(got)
     for (path, a), b in zip(flat_got, jax.tree.leaves(want)):
@@ -676,14 +685,14 @@ def test_step_equals_the_step_that_gathers_a_position(monkeypatch, model,
     so nothing after the views may differ by a bit: the loss and the counts
     of every step, the tables, both moments, the dense leaves."""
     monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
-    cfg = _cfg(model, devices)
-    batches = _batches(cfg.num_tasks > 1)
+    batches = _batches(model == "multitask")
     # negative ids and pad rows (read, as zeros; an id past the table would
     # read NaN into both losses)
     batches[1]["feat_ids"][0, :] = [-1, -ROWS, V, ROWS - 1, -(ROWS - V), 7]
-    tr = Trainer(cfg)
+    tr = _trainer(model, devices)
     got, got_m = _fit_steps(tr, batches)
-    want, want_m = _fit_steps(_by_positions(Trainer(cfg)), batches)
+    want, want_m = _fit_steps(_trainer(model, devices, by="positions"),
+                              batches)
     assert tr.embed_lookup == "rows"
     assert got_m == want_m
     for tree in ("params", "opt_state"):
@@ -694,11 +703,12 @@ def test_step_equals_the_step_that_gathers_a_position(monkeypatch, model,
 def test_row_local_step_equals_the_step_that_gathers_a_position(monkeypatch,
                                                                 capacity):
     monkeypatch.setattr(loop, "ROW_UPDATE_CAPACITY", capacity)
-    cfg = _cfg("deepfm", optimizer="Adagrad", l2_reg=0.0)
-    tr = Trainer(cfg)
+    row_local = dict(l2_reg=0.0, optimizer="Adagrad")
+    tr = _trainer(**row_local)
     assert tr._row_local_eligible()
     got, got_m = _fit_steps(tr, _batches())
-    want, want_m = _fit_steps(_by_positions(Trainer(cfg)), _batches())
+    want, want_m = _fit_steps(_trainer(by="positions", **row_local),
+                              _batches())
     assert tr.embed_lookup == "rows" and got_m == want_m
     for tree in ("params", "opt_state"):
         _assert_same_bits(getattr(got, tree), getattr(want, tree))
@@ -708,7 +718,7 @@ def test_row_local_step_equals_the_step_that_gathers_a_position(monkeypatch,
 def test_replicas_stay_bit_identical_with_the_views_read_by_rows(devices):
     """Each replica plans and reads its own slice of the batch; what they
     exchange and add is what it was, so their copies cannot drift."""
-    tr = Trainer(_cfg("deepfm", devices))
+    tr = _trainer("deepfm", devices)
     state = tr.init_state()
     for batch in _batches(steps=2):
         state, _ = tr.train_step(state, tr.put_batch(batch))
@@ -762,9 +772,10 @@ def test_which_tables_are_read_by_rows_is_the_rows_shape(case):
     the gather it compiled, every position straight from the table, and a
     step with no narrow table to the parent's step."""
     flags, lookup, want = LOOKUP_CASES[case]
-    # (one line compiles both: the text carries its callers' line numbers)
-    (tr, text), (_, parent) = [(t, t.step_hlo_text()) for t in (
-        Trainer(_cfg(**flags)), _by_positions(Trainer(_cfg(**flags))))]
+    # (one call compiles both: the text carries its callers' lines and
+    # columns)
+    (tr, text), (_, parent) = [(t, _text(t)) for t in (
+        _trainer(**flags), _trainer(by="positions", **flags))]
     assert tr.embed_lookup == lookup
     positions = tr.model.lookup_ids(np.zeros((B, F), np.int32)).size
     cap = loop.ROW_UPDATE_CAPACITY

@@ -274,9 +274,14 @@ def test_compiled_steps_carry_each_blocks_scope(model, has, lacks):
     assert has <= scopes and not (lacks & scopes), scopes
 
 
-def test_launcher_trains_evaluates_exports_and_serves(tmp_path, capsys):
+def test_launcher_trains_evaluates_exports_and_serves(tmp_path, capsys,
+                                                      monkeypatch):
     from deepfm_tpu import launch
 
+    # What serves below is the StableHLO artifact. The TensorFlow sidecar
+    # beside it (a worker's first costs the TensorFlow import and autograph
+    # over jax2tf, 57 of this test's 74 s) is ``test_savedmodel_export``'s.
+    monkeypatch.setenv("DEEPFM_TPU_SKIP_TF_EXPORT", "1")
     data = tmp_path / "data"
     for prefix, n, seed in (("tr", 512, 1), ("va", 256, 2)):
         libsvm.generate_synthetic_ctr(
@@ -293,7 +298,8 @@ def test_launcher_trains_evaluates_exports_and_serves(tmp_path, capsys):
                                str(tmp_path / "servable")]) == 0
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["task"] == "train" and line["steps"] == 2 * (2 * 512 // 64)
-    assert 0.0 <= line["auc"] <= 1.0 and line["saved_model"]
+    assert 0.0 <= line["auc"] <= 1.0
+    assert line["saved_model"].startswith("skipped: DEEPFM_TPU_SKIP_TF_EXPORT")
     assert launch.main(argv + ["--task_type", "eval"]) == 0
     line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert line["task"] == "eval" and np.isfinite(line["loss"])

@@ -305,11 +305,28 @@ class TestShardedParity:
             np.asarray(s2.params["fm_w"])[:500], rtol=1e-3, atol=1e-5)
 
     def test_dp4_mp2_matches_single(self):
+        """Element for element to ``rtol`` 1e-3, ``atol`` 1e-5, but for the
+        few elements where Adam's division amplifies a rounding. Four data
+        replicas add a row's cotangents in another order than one device: a
+        gap of one ULP (4.7e-9 at row 10, step 2). At a later touch of such
+        a row the first moment nearly cancels (an update of 1.6e-4 or 1.9e-4
+        where a step is lr = 1e-2: row 128, step 2) and ``m / sqrt(v)``
+        turns that ULP into a fraction of a step, which stays. Read here
+        (XLA:CPU, 8 steps): 3 of 4,000 elements, rows 10, 128 and 458, all
+        in the last column, 2.9e-5, 5.1e-5 and 1.4e-5 apart: under 1% of one
+        step each. So: nearly every element to the tight tolerance, and none
+        further off than 1% of a step (``tests/test_trainer.py``'s
+        ``test_dp8_matches_single`` holds ``fm_b`` to 2e-4 for the same
+        cause; ``conftest.py``'s note on the ``mesh_bitexact`` probe)."""
         _, s1, ev1 = self._single()
-        _, s8, ev8 = _fit(_cfg(mesh_data=4, mesh_model=2))
-        np.testing.assert_allclose(
-            np.asarray(s1.params["fm_v"])[:500],
-            np.asarray(s8.params["fm_v"])[:500], rtol=1e-3, atol=1e-5)
+        cfg = _cfg(mesh_data=4, mesh_model=2)
+        _, s8, ev8 = _fit(cfg)
+        one = np.asarray(s1.params["fm_v"])[:500]
+        mesh = np.asarray(s8.params["fm_v"])[:500]
+        gap = np.abs(one - mesh)
+        off = gap > 1e-5 + 1e-3 * np.abs(mesh)
+        assert off.mean() < 0.005, np.argwhere(off)
+        assert gap.max() < 0.01 * cfg.learning_rate, gap.max()
         assert abs(ev1["loss"] - ev8["loss"]) < 1e-3
 
     def test_hashed_mp2_matches_single(self):

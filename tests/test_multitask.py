@@ -160,7 +160,12 @@ def _mt_cfg(mt_dir, **kw):
 def mt_trained(mt_dir):
     """One MMoE CTR+CVR train → publish run shared by the e2e tests."""
     cfg = _mt_cfg(mt_dir, servable_model_dir=str(mt_dir / "servable"))
-    result = tasks.run(cfg)
+    # (the e2e tests read the StableHLO artifact; the TensorFlow sidecar, 40
+    # of this fixture's 48 s in a worker's first export, is
+    # ``test_savedmodel_export``'s)
+    with pytest.MonkeyPatch.context() as patched:
+        patched.setenv("DEEPFM_TPU_SKIP_TF_EXPORT", "1")
+        result = tasks.run(cfg)
     [sub] = os.listdir(str(mt_dir / "servable"))
     return result, str(mt_dir / "servable" / sub)
 

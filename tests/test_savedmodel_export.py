@@ -46,6 +46,52 @@ def test_savedmodel_exists_and_matches_jax(artifact):
     np.testing.assert_allclose(tf_probs, jax_probs, rtol=1e-5, atol=1e-6)
 
 
+# The other signatures the sidecar is written with, held here, where the
+# TensorFlow import is already paid: the launcher and task tests of these
+# models export with ``DEEPFM_TPU_SKIP_TF_EXPORT`` set and serve the StableHLO
+# artifact (PR 43).
+SIGNATURES = {
+    # {task: probs}: one named head a task
+    "multitask": dict(
+        feature_size=120, field_size=5, embedding_size=4, deep_layers="8",
+        dropout="1.0", tasks="ctr,cvr", multitask="mmoe", mmoe_experts=2),
+    # numeric columns through the bottom MLP beside the categorical ones
+    "dlrm_dcnv2": dict(
+        model="dlrm_dcnv2", feature_size=120, field_size=7, numeric_fields=2,
+        embedding_size=4, bottom_layers="4,4", cross_layers=1, cross_rank=2,
+        deep_layers="8,4", dropout="1,1", optimizer="Adagrad"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SIGNATURES))
+def test_savedmodel_of_each_signature_matches_jax(name, tmp_path):
+    import tensorflow as tf
+    cfg = Config(**SIGNATURES[name], batch_size=32, compute_dtype="float32",
+                 mesh_data=1, log_steps=0, seed=7)
+    trainer = Trainer(cfg)
+    out = str(tmp_path / "1")
+    export_lib.export_serving(trainer.model, trainer.init_state(), cfg, out)
+    assert export_lib.saved_model_status(out) == "written"
+    sig = tf.saved_model.load(f"{out}/saved_model").signatures[
+        "serving_default"]
+
+    rng = np.random.default_rng(1)
+    ids = rng.integers(0, 120, (16, cfg.field_size))
+    vals = rng.normal(size=(16, cfg.field_size)).astype(np.float32)
+    got = {k: v.numpy() for k, v in sig(
+        feat_ids=tf.constant(ids, tf.int64),
+        feat_vals=tf.constant(vals)).items()}
+    want = export_lib.load_serving(out)(ids.astype(np.int32), vals)
+    if not isinstance(want, dict):
+        want = {"prob": want}
+    assert set(got) == set(want) == (
+        {"ctr", "cvr"} if name == "multitask" else {"prob"})
+    for task in want:
+        assert got[task].shape == (16,)
+        np.testing.assert_allclose(got[task], want[task], rtol=1e-5,
+                                   atol=1e-6)
+
+
 def test_artifact_without_program_refuses_to_load(artifact, tmp_path):
     """export_serving writes serving_fn.stablehlo or raises, so an artifact
     without it is damaged: load_serving must not rebuild a predict function

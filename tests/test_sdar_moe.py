@@ -1,13 +1,17 @@
 """``--model sdar_moe`` (block-diffusion MoE decoder) at small widths on the
 CPU, from seeded weights, against the plain reference
-(``benchmark/reference_sdar_moe.py``): logits, loss, every leaf's gradient
-and three Adam steps in float32, on one device and on two data replicas, with
-bfloat16 compute required to miss the same tolerance; the mask against a
-hand-written example; the router against a hand-computed top-k; the share
-test (the 8 shares' partial results of an attention block and of an expert
-layer add up to the uncut reference's); pairs over a small buffer are
-counted, not lost; what ``Config`` refuses; the scopes in the compiled step;
-and the launcher's train -> eval on a tiny file."""
+(``benchmark/reference_sdar_moe.py``). The decoders' shared tests are
+``tests/decoder_contract.py``'s, read through ``SPEC`` (logits, loss, every
+leaf's gradient and three Adam steps in float32, on one device and on two
+data replicas, with bfloat16 compute required to miss the same tolerance;
+pairs over a small buffer; what ``Config`` refuses; the scopes in the
+compiled step; the row kernels), this model's loss being over the positions
+its own noise masked. This model's own are here: the noise against the
+objective's statement of it; the mask against a hand-written example; the
+router against a hand-computed top-k; the share test (the 8 shares' partial
+results of an attention block and of an expert layer add up to the uncut
+reference's); the block-masked attention kernel; where the kernels are
+taken; and the launcher's train -> eval on a tiny file."""
 
 import json
 import os
@@ -16,18 +20,18 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark import reference_sdar_moe as ref  # noqa: E402
-from deepfm_tpu.config import Config  # noqa: E402
+from decoder_contract import (DecoderContract, RowKernels,  # noqa: E402
+                              SmallBuffer, Spec, force_row_kernels,
+                              trainer_on)
 from deepfm_tpu.data import example_codec, tfrecord  # noqa: E402
 from deepfm_tpu.models import get_model, registered_models, sdar_moe  # noqa: E402
-from deepfm_tpu.parallel import mesh as mesh_lib  # noqa: E402
-from deepfm_tpu.train import Trainer  # noqa: E402
 
 V, L, B = 50, 8, 4
 SMALL = dict(model="sdar_moe", feature_size=V, field_size=1,
@@ -39,80 +43,113 @@ SMALL = dict(model="sdar_moe", feature_size=V, field_size=1,
              batch_size=B, l2_reg=0.0, learning_rate=1e-3, steps_per_loop=1)
 SIZES = dict(head_dim=8, top_k=2, first_expert=2, eps=1e-6, theta=1e6,
              block=4)
-#: float32 program against float32 reference; bfloat16 compute has to miss it.
-TOL = 2e-4
+
+SPEC = Spec(
+    ref=ref, small=SMALL, sizes=SIZES,
+    scopes=frozenset({"embed", "attn", "moe", "head", "opt"}),
+    reserved_rows=1, draws_noise=True, grad_tol=1e-5, logits_atol=1e-5,
+    small_buffer=8,
+    row_kernels=dict(flags=dict(embedding_size=128),
+                     passes={"one-pass": sdar_moe.PASS_ROWS},
+                     moved=2 * B * 2 * L * 2),
+    refusals=(
+        ({"tasks": "ctr,cvr"}, "tasks"),
+        ({"embedding_update": "sparse"}, "embedding_update=sparse"),
+        ({"task_type": "infer"}, "infer/export"),
+        ({"task_type": "export"}, "infer/export"),
+        ({"servable_model_dir": "/tmp/x"}, "servable_model_dir"),
+        ({"batch_norm": True}, "batch_norm"),
+        ({"loss_type": "square_loss"}, "loss_type"),
+        ({"embedding_buckets": "64,64"}, "embedding_buckets"),
+        ({"history_max_len": 6}, "multiple of diffusion_block"),
+        ({"history_max_len": 0}, "multiple of diffusion_block"),
+        ({"decoder_layers": 0}, "decoder_layers"),
+        ({"attn_q_heads": 3}, "attn_q_heads"),
+        ({"moe_top_k": 9}, "moe_top_k"),
+        ({"moe_first_expert": 6}, "moe_experts_held"),
+        ({"moe_pair_capacity": 0}, "moe_pair_capacity"),
+        ({"diffusion_t_min": 0.0}, "diffusion_t_min"),
+        ({"model": "deepfm", "history_max_len": 0},
+         "belong to --model sdar_moe"),
+    ))
+config, sequences = SPEC.config, SPEC.sequences
 
 
-def config(**kw):
-    return Config(**{**SMALL, "compute_dtype": "float32", **kw})
+def draw(key, tokens):
+    """(noisy tokens, t a block) as the program's step of ``key`` draws."""
+    return sdar_moe.draw_noise(key, jnp.asarray(tokens), block=4, t_min=1e-3,
+                               mask_id=V - 1)
 
 
-def flat(params):
-    """The program's parameter tree under the reference's names, the token
-    table cut to the vocabulary's rows."""
-    out = {}
-    for key, value in params.items():
-        if key == "layers":
-            out.update({"layers." + n: np.asarray(a)
-                        for n, a in value.items()})
-        else:
-            out[key] = np.asarray(value)
-    out["tok_emb"] = out["tok_emb"][:V]
-    return out
+class TestSdarMoE(DecoderContract, SmallBuffer, RowKernels):
+    spec = SPEC
 
+    def reference_loss(self, params, tokens, state, rng):
+        """Over the positions the program's own draw of ``rng`` masked."""
+        noisy, t = draw(rng, tokens)
+        return ref.forward_loss(params, noisy, tokens, t, SIZES)
 
-def sequences(n, seed):
-    return np.random.default_rng(seed).integers(0, V - 1, (n, L)).astype(
-        np.int32)
+    def reference_inputs(self, tokens, base, step, n_dev):
+        """A replica draws from the step's key folded with its index."""
+        key = jax.random.fold_in(base, step)
+        per = B // n_dev
+        noise = [draw(jax.random.fold_in(key, s) if n_dev > 1 else key,
+                      tokens[s * per:(s + 1) * per]) for s in range(n_dev)]
+        return (np.concatenate([n for n, _ in noise]), tokens,
+                np.concatenate([t for _, t in noise]))
 
+    def test_logits_and_loss_match_the_reference(self, seeded):
+        counts = self.logits_and_loss(seeded)
+        tokens = sequences(B, 0)
+        noisy, _ = draw(self.key(7), tokens)
+        assert int(counts["masked_positions"]) == int(
+            jnp.sum(noisy != tokens))
 
-def trainer_on(n_dev, cfg):
-    return Trainer(cfg, mesh_info=mesh_lib.build_mesh(
-        cfg, devices=jax.devices()[:n_dev]))
+    def test_pairs_over_a_small_buffer_are_counted_not_lost(self,
+                                                            monkeypatch):
+        lp = uncut_layer(experts=4)
+        x = jax.random.normal(jax.random.PRNGKey(2), (1, 16, 32))
+        kw = dict(top_k=2, first_expert=0, eps=1e-6,
+                  cdt=jnp.dtype("float32"))
+        whole, all_counts = sdar_moe.expert_layer(lp, x, capacity=32, **kw)
+        assert int(all_counts["moe_pairs_held"]) == 32      # all 4 are held
+        assert int(all_counts["moe_pairs_over_buffer"]) == 0
+        cut, counts = sdar_moe.expert_layer(lp, x, capacity=20, **kw)
+        with monkeypatch.context() as patched:
+            patched.setattr(sdar_moe, "PASS_ROWS", 16)
+            for capacity in (32, 20):   # two passes of 16 and of 10: the same
+                twice, two = sdar_moe.expert_layer(lp, x, capacity=capacity,
+                                                   **kw)
+                assert int(two["moe_pairs_over_buffer"]) == 32 - capacity
+                np.testing.assert_allclose(
+                    twice, whole if capacity == 32 else cut, atol=1e-5)
+        assert int(counts["moe_pairs_held"]) == 32
+        assert int(counts["moe_layer_pairs_max"]) == 32     # one layer's
+        assert int(counts["moe_pairs_over_buffer"]) == 12
+        assert int(counts["moe_expert_load_max"]) == int(
+            all_counts["moe_expert_load_max"]) >= 8
+        assert np.isfinite(np.asarray(cut)).all()
+        assert not np.allclose(cut, whole)
+        # and a trainer's state keeps the run's total
+        super().test_pairs_over_a_small_buffer_are_counted_not_lost()
 
+    def test_compiled_step_carries_each_blocks_scope(self):
+        """Of two steps a dispatch (``multi_step``: the other decoders'
+        shared program is ``train_step``'s), so a program of its own."""
+        scopes = set(trainer_on(1, config(steps_per_loop=2))
+                     .step_op_scopes().values())
+        assert SPEC.scopes <= scopes and not SPEC.no_scopes & scopes
 
-def batch_of(tokens):
-    n = tokens.shape[0]
-    return {"feat_ids": np.zeros((n, 1), np.int32),
-            "feat_vals": np.ones((n, 1), np.float32),
-            "label": np.zeros((n, 1), np.float32), "hist_ids": tokens,
-            "hist_mask": np.ones(tokens.shape, np.float32)}
-
-
-@pytest.fixture(scope="module")
-def seeded():
-    """(model, params with gains moved off one, state)."""
-    model = get_model(config())
-    params, state = model.init(jax.random.PRNGKey(0))
-    keys = iter(jax.random.split(jax.random.PRNGKey(5), 8))
-    for name in ("norm1", "norm2", "q_norm", "k_norm"):
-        g = params["layers"][name]
-        params["layers"][name] = g + 0.1 * jax.random.normal(next(keys),
-                                                             g.shape)
-    params["final_norm"] = params["final_norm"] + 0.1 * jax.random.normal(
-        next(keys), params["final_norm"].shape)
-    return model, params, state
-
-
-def test_logits_and_loss_match_the_reference(seeded):
-    model, params, state = seeded
-    tokens = jnp.asarray(sequences(B, 0))
-    key = jax.random.PRNGKey(7)
-    logits, counts = model.apply(params, state, None, None, train=True,
-                                 rng=key, hist_ids=tokens)
-    per_seq, _ = model.per_example_loss(params, state, {"hist_ids": tokens},
-                                        train=True, rng=key)
-    noisy, t = sdar_moe.draw_noise(key, tokens, block=4, t_min=1e-3,
-                                   mask_id=V - 1)
-    with jax.default_matmul_precision("highest"):
-        want_loss, want_logits = ref.forward_loss(
-            {k: jnp.asarray(v) for k, v in flat(params).items()}, noisy,
-            tokens, t, SIZES)
-    assert logits.shape == (B, L, V)
-    np.testing.assert_allclose(logits, want_logits, atol=1e-5)
-    np.testing.assert_allclose(jnp.mean(per_seq), want_loss, rtol=1e-6)
-    assert int(counts["masked_positions"]) == int(jnp.sum(noisy != tokens))
-    assert int(counts["moe_pairs_over_buffer"]) == 0
+    def test_a_cpu_step_makes_its_scores_with_xla(self, seeded):
+        """On this backend the model's step takes the chunked XLA path and
+        says so (what ``train.log_sync`` carries while tracing is on)."""
+        model, params, _ = seeded
+        jax.eval_shape(lambda p: model.hidden(
+            p, jnp.zeros((B, 2 * L), jnp.int32)), params)
+        assert model.step_notes == {
+            "attn_scores": "xla", "moe_rows": "xla",
+            "moe_rows_moved": "{moe_pairs_held}/%d" % (
+                model.cfg.decoder_layers * model.cfg.moe_pair_capacity)}
 
 
 def _drawn(seed, kind):
@@ -146,75 +183,28 @@ def test_the_reference_tells_a_sound_draw_of_the_noise_from_a_wrong_one(
     assert max(z) < 4.0 if kind == "sound" else min(z) > 5.0, z
 
 
-def test_gradients_of_every_leaf_match_the_reference(seeded):
-    model, params, state = seeded
-    tokens = jnp.asarray(sequences(B, 1))
-    key = jax.random.PRNGKey(8)
-    noisy, t = sdar_moe.draw_noise(key, tokens, block=4, t_min=1e-3,
-                                   mask_id=V - 1)
+def uncut_layer(seed=0, d=32, hd=8, n_q=16, n_kv=4, experts=16, f=16):
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
 
-    def loss(p):
-        per_seq, _ = model.per_example_loss(p, state, {"hist_ids": tokens},
-                                            train=True, rng=key)
-        return jnp.mean(per_seq)
-
-    got = flat(jax.grad(loss)(params))
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(lambda p: ref.forward_loss(p, noisy, tokens, t,
-                                                   SIZES)[0])(
-            {k: jnp.asarray(v) for k, v in flat(params).items()})
-    assert set(got) == set(want)
-    for name in want:
-        assert ref.leaf_gap(got[name], want[name]) < 1e-5, name
-        assert np.linalg.norm(want[name]) > 0, name
+    def w(*shape):
+        return 0.3 * jax.random.normal(next(keys), shape)
+    return {"norm1": 1 + w(d), "norm2": 1 + w(d), "q_norm": 1 + w(hd),
+            "k_norm": 1 + w(hd), "wq": w(d, n_q * hd), "wk": w(d, n_kv * hd),
+            "wv": w(d, n_kv * hd), "wo": w(n_q * hd, d),
+            "router": w(d, experts), "w_gate": w(experts, d, f),
+            "w_up": w(experts, d, f), "w_down": w(experts, f, d)}
 
 
-def follow(n_dev, compute_dtype, steps=3):
-    """(worst first-moment gap, worst parameter-change gap, losses) of
-    ``steps`` trainer steps against the reference's follower."""
-    cfg = config(compute_dtype=compute_dtype, mesh_data=n_dev)
-    trainer = trainer_on(n_dev, cfg)
-    state = trainer.init_state(seed=3)
-    start = flat(jax.tree.map(np.asarray, state.params))
-    base = jnp.asarray(np.asarray(state.rng))
-    follower = ref.Follower(start, SIZES, cfg.learning_rate * n_dev)
-    losses = []
-    for step in range(steps):
-        tokens = sequences(B, 10 + step)
-        key = jax.random.fold_in(base, step)
-        per = B // n_dev
-        noise = [sdar_moe.draw_noise(
-            jax.random.fold_in(key, s) if n_dev > 1 else key,
-            jnp.asarray(tokens[s * per:(s + 1) * per]), block=4, t_min=1e-3,
-            mask_id=V - 1) for s in range(n_dev)]
-        state, m = trainer.train_step(state,
-                                      trainer.put_batch(batch_of(tokens)))
-        want = follower.step(np.concatenate([n for n, _ in noise]), tokens,
-                             np.concatenate([t for _, t in noise]))
-        losses.append((float(m["xent"]), want))
-    got = flat(jax.tree.map(np.asarray, state.params))
-    mu = flat(jax.tree.map(np.asarray, optax.tree_utils.tree_get(
-        state.opt_state, "mu")))
-    return (ref.worst_leaf_gap(mu, follower.mu)[0],
-            ref.worst_leaf_gap({k: got[k] - start[k] for k in got},
-                               {k: follower.params[k] - start[k]
-                                for k in got})[0], losses)
-
-
-@pytest.mark.parametrize("n_dev", [1, 2])
-def test_three_adam_steps_match_the_reference(n_dev):
-    mu_gap, change_gap, losses = follow(n_dev, "float32")
-    for got, want in losses:
-        assert abs(got - want) < 1e-5 * max(1.0, abs(want))
-    assert mu_gap < TOL
-    # Adam divides by the gradient's own size: where a gradient is tiny its
-    # rounding decides the step's sign, and the change reads it.
-    assert change_gap < 0.02
-
-
-def test_bfloat16_compute_misses_the_tolerance():
-    mu_gap, change_gap, _ = follow(1, "bfloat16")
-    assert mu_gap > 10 * TOL and change_gap > 0.02
+def test_the_model_is_built_with_one_table_leaf():
+    assert "sdar_moe" not in registered_models()    # the rankers' zoo
+    model = get_model(config())
+    assert model.embedding_param_names() == ("tok_emb",)
+    assert model.uses_history and model.owns_loss
+    params, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    assert params["tok_emb"].shape == (model.padded_vocab, 32)
+    assert params["head"].shape == (32, V)
+    assert params["layers"]["w_gate"].shape == (2, 4, 32, 16)
+    assert set(state) == set(sdar_moe.COUNT_NAMES)
 
 
 def test_mask_matches_a_hand_written_example():
@@ -252,18 +242,6 @@ def test_router_matches_a_hand_computed_top_k():
                                rtol=1e-6)
 
 
-def uncut_layer(seed=0, d=32, hd=8, n_q=16, n_kv=4, experts=16, f=16):
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 16))
-
-    def w(*shape):
-        return 0.3 * jax.random.normal(next(keys), shape)
-    return {"norm1": 1 + w(d), "norm2": 1 + w(d), "q_norm": 1 + w(hd),
-            "k_norm": 1 + w(hd), "wq": w(d, n_q * hd), "wk": w(d, n_kv * hd),
-            "wv": w(d, n_kv * hd), "wo": w(n_q * hd, d),
-            "router": w(d, experts), "w_gate": w(experts, d, f),
-            "w_up": w(experts, d, f), "w_down": w(experts, f, d)}
-
-
 def test_eight_shares_add_up_to_the_uncut_layer():
     """8 chips share the layer as the benchmark's deployment does: each holds
     2 of 16 query heads, the key/value head they read (a key/value head lives
@@ -280,6 +258,13 @@ def test_eight_shares_add_up_to_the_uncut_layer():
         want_attn = ref.attention(ref.rms_norm(x, lp["norm1"], 1e-6), lp,
                                   sizes, mask, pos)
         want_moe = ref.moe(ref.rms_norm(x, lp["norm2"], 1e-6), lp, sizes)
+    # (a share's program is every share's: compiled once, run on each)
+    attn = jax.jit(lambda share: sdar_moe.attention(
+        share, x, pos, mask=sdar_moe.block_diffusion(length, block),
+        head_dim=hd, eps=1e-6, theta=1e6, cdt=jnp.dtype("float32")))
+    moe = jax.jit(lambda share, first: sdar_moe.expert_layer(
+        share, x, top_k=2, first_expert=first, capacity=64, eps=1e-6,
+        cdt=jnp.dtype("float32")))
     got_attn, got_moe, held = 0.0, 0.0, 0
     for r in range(8):
         q = slice(2 * r * hd, (2 * r + 2) * hd)
@@ -288,94 +273,14 @@ def test_eight_shares_add_up_to_the_uncut_layer():
                  "wv": lp["wv"][:, kv], "wo": lp["wo"][q],
                  **{n: lp[n][2 * r:2 * r + 2]
                     for n in ("w_gate", "w_up", "w_down")}}
-        got_attn += sdar_moe.attention(
-            share, x, pos, mask=sdar_moe.block_diffusion(length, block),
-            head_dim=hd, eps=1e-6, theta=1e6, cdt=jnp.dtype("float32"))
-        part, counts = sdar_moe.expert_layer(
-            share, x, top_k=2, first_expert=2 * r, capacity=64, eps=1e-6,
-            cdt=jnp.dtype("float32"))
+        got_attn += attn(share)
+        part, counts = moe(share, 2 * r)
         got_moe += part
         held += int(counts["moe_pairs_held"])
         assert int(counts["moe_pairs_over_buffer"]) == 0
     assert held == x.shape[0] * x.shape[1] * 2     # every pair, once
     np.testing.assert_allclose(got_attn, want_attn, atol=2e-5)
     np.testing.assert_allclose(got_moe, want_moe, atol=2e-5)
-
-
-def test_pairs_over_a_small_buffer_are_counted_not_lost(monkeypatch):
-    lp = uncut_layer(experts=4)
-    x = jax.random.normal(jax.random.PRNGKey(2), (1, 16, 32))
-    kw = dict(top_k=2, first_expert=0, eps=1e-6, cdt=jnp.dtype("float32"))
-    whole, all_counts = sdar_moe.expert_layer(lp, x, capacity=32, **kw)
-    assert int(all_counts["moe_pairs_held"]) == 32       # all 4 are held
-    assert int(all_counts["moe_pairs_over_buffer"]) == 0
-    cut, counts = sdar_moe.expert_layer(lp, x, capacity=20, **kw)
-    monkeypatch.setattr(sdar_moe, "PASS_ROWS", 16)
-    for capacity in (32, 20):   # two passes of 16 and of 10: the same
-        twice, two = sdar_moe.expert_layer(lp, x, capacity=capacity, **kw)
-        assert int(two["moe_pairs_over_buffer"]) == 32 - capacity
-        np.testing.assert_allclose(twice, whole if capacity == 32 else cut,
-                                   atol=1e-5)
-    assert int(counts["moe_pairs_held"]) == 32
-    assert int(counts["moe_layer_pairs_max"]) == 32      # one layer's
-    assert int(counts["moe_pairs_over_buffer"]) == 12
-    assert int(counts["moe_expert_load_max"]) == int(
-        all_counts["moe_expert_load_max"]) >= 8
-    assert np.isfinite(np.asarray(cut)).all()
-    assert not np.allclose(cut, whole)
-    # and a trainer's state keeps the run's total
-    trainer = trainer_on(1, config(moe_pair_capacity=8))
-    state = trainer.init_state(seed=1)
-    seen = []
-    for step in range(2):
-        state, m = trainer.train_step(
-            state, trainer.put_batch(batch_of(sequences(B, step))))
-        seen.append(int(m["moe_pairs_over_buffer"]))
-    assert 0 < seen[0] < seen[1]
-    assert int(state.model_state["moe_pairs_over_buffer"]) == seen[1]
-
-
-@pytest.mark.parametrize("change, says", [
-    ({"tasks": "ctr,cvr"}, "tasks"),
-    ({"embedding_update": "sparse"}, "embedding_update=sparse"),
-    ({"task_type": "infer"}, "infer/export"),
-    ({"task_type": "export"}, "infer/export"),
-    ({"servable_model_dir": "/tmp/x"}, "servable_model_dir"),
-    ({"batch_norm": True}, "batch_norm"),
-    ({"loss_type": "square_loss"}, "loss_type"),
-    ({"embedding_buckets": "64,64"}, "embedding_buckets"),
-    ({"history_max_len": 6}, "multiple of diffusion_block"),
-    ({"history_max_len": 0}, "multiple of diffusion_block"),
-    ({"decoder_layers": 0}, "decoder_layers"),
-    ({"attn_q_heads": 3}, "attn_q_heads"),
-    ({"moe_top_k": 9}, "moe_top_k"),
-    ({"moe_first_expert": 6}, "moe_experts_held"),
-    ({"moe_pair_capacity": 0}, "moe_pair_capacity"),
-    ({"diffusion_t_min": 0.0}, "diffusion_t_min"),
-    ({"model": "deepfm", "history_max_len": 0}, "belong to --model sdar_moe"),
-])
-def test_config_says_plainly_what_the_model_does_not_take(change, says):
-    with pytest.raises(ValueError, match=says):
-        config(**change)
-
-
-def test_the_model_is_built_with_one_table_leaf():
-    assert "sdar_moe" not in registered_models()    # the rankers' zoo
-    model = get_model(config())
-    assert model.embedding_param_names() == ("tok_emb",)
-    assert model.uses_history and model.owns_loss
-    params, state = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    assert params["tok_emb"].shape == (model.padded_vocab, 32)
-    assert params["head"].shape == (32, V)
-    assert params["layers"]["w_gate"].shape == (2, 4, 32, 16)
-    assert set(state) == set(sdar_moe.COUNT_NAMES)
-
-
-def test_compiled_step_carries_each_blocks_scope():
-    scopes = set(trainer_on(1, config(steps_per_loop=2))
-                 .step_op_scopes().values())
-    assert {"embed", "attn", "moe", "head", "opt"} <= scopes
-    assert not {"fm", "tower", "cross", "bottom"} & scopes
 
 
 def test_grouped_product_kernels_are_charged_to_the_expert_layer():
@@ -407,8 +312,6 @@ def test_grouped_product_kernels_are_charged_to_the_expert_layer():
     assert [a == b for a, b in zip(text.splitlines(), named.splitlines())
             ] == [False, False, True, True, True]
 
-
-# --- the block-masked attention kernel (ops/block_attention.py) -------------
 
 def _qkv(dtype, length=256, group=4, head_dim=128, batch=1):
     """Rotated, normalised q/k/v as ``attention`` hands them on: unit-RMS
@@ -521,18 +424,6 @@ def test_the_kernel_is_taken_where_backend_shape_and_mesh_allow(
     assert sdar_moe.ATTN_BLOCK == 512
 
 
-def test_a_cpu_step_makes_its_scores_with_xla(seeded):
-    """On this backend the model's step takes the chunked XLA path and says
-    so (what ``train.log_sync`` carries while tracing is on)."""
-    model, params, _ = seeded
-    jax.eval_shape(lambda p: model.hidden(
-        p, jnp.zeros((B, 2 * L), jnp.int32)), params)
-    assert model.step_notes == {
-        "attn_scores": "xla", "moe_rows": "xla",
-        "moe_rows_moved": "{moe_pairs_held}/%d" % (
-            model.cfg.decoder_layers * model.cfg.moe_pair_capacity)}
-
-
 @pytest.mark.parametrize("backend, width, positions, capacity, one_device, "
                          "says", [
     ("tpu", 2048, 16384, 32768, True, "kernel"),    # the SDAR cell's layer
@@ -582,15 +473,6 @@ def test_the_step_notes_say_how_the_expert_layers_rows_move():
     assert sdar_moe.moe_notes("xla", 20, 1)["moe_rows_moved"].endswith("/20")
 
 
-def _forced_row_kernels(monkeypatch):
-    """The expert layer's row kernels through the Pallas interpreter."""
-    import functools
-    from deepfm_tpu.ops import pallas_moe_rows
-    for name in ("gather", "combine"):
-        monkeypatch.setattr(pallas_moe_rows, name, functools.partial(
-            getattr(pallas_moe_rows, name), interpret=True))
-
-
 @pytest.mark.parametrize("capacity, pass_most, first, case", [
     (128, 20480, 0, "one pass, spare rows"),
     (128, 64, 0, "two passes, the second part spare"),
@@ -602,7 +484,7 @@ def test_expert_layer_by_the_row_kernels_matches_the_xla_rows(
     """``expert_layer`` with the rows taken and added by the kernels (forced
     on, through the interpreter) against ``jnp.take`` / ``.at[].add``: the
     output, the counts, and the gradient of every leaf and of the input."""
-    _forced_row_kernels(monkeypatch)
+    force_row_kernels(monkeypatch)
     monkeypatch.setattr(sdar_moe, "PASS_ROWS", pass_most)
     d, f, experts, held, top_k = 128, 32, 16, 4, 4
     keys = iter(jax.random.split(jax.random.PRNGKey(0), 8))
@@ -640,43 +522,6 @@ def test_expert_layer_by_the_row_kernels_matches_the_xla_rows(
     np.testing.assert_allclose(got_g[1], want_g[1], atol=1e-4)
 
 
-def test_model_by_the_row_kernels_takes_the_same_step(monkeypatch):
-    """The whole model with the row kernels forced on (rows of one 128-lane
-    line): loss and every parameter's gradient against the XLA rows, and the
-    notes say ``kernel``."""
-    from deepfm_tpu.ops import pallas_moe_rows
-    cfg = config(embedding_size=128, moe_pair_capacity=B * 2 * L * 2)
-    tokens = jnp.asarray(sequences(B, 3))
-
-    def grads():
-        model = get_model(cfg)
-        params, state = model.init(jax.random.PRNGKey(0))
-
-        def loss(p):
-            per_seq, counts = model.per_example_loss(
-                p, state, {"hist_ids": tokens}, train=True,
-                rng=jax.random.PRNGKey(2))
-            return jnp.mean(per_seq), counts
-        return model, jax.value_and_grad(loss, has_aux=True)(params)
-
-    model, ((want, want_counts), want_g) = grads()
-    assert model.step_notes["moe_rows"] == "xla"
-    _forced_row_kernels(monkeypatch)
-    monkeypatch.setattr(pallas_moe_rows, "supported",
-                        lambda width, positions, rows, backend=None: True)
-    model, ((got, got_counts), got_g) = grads()
-    assert model.step_notes["moe_rows"] == "kernel"
-    assert model.step_notes["moe_rows_moved"] == "{moe_pairs_held}/%d" % (
-        cfg.decoder_layers * cfg.moe_pair_capacity)
-    assert int(got_counts["moe_pairs_held"]) == int(
-        want_counts["moe_pairs_held"]) > 0
-    np.testing.assert_allclose(got, want, rtol=1e-6)
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_g),
-                            jax.tree.leaves(want_g)):
-        np.testing.assert_allclose(g, w, atol=2e-5,
-                                   err_msg=jax.tree_util.keystr(path))
-
-
 def test_attention_by_the_kernel_matches_attention_by_xla(monkeypatch):
     """The whole block (projections, QK-norm, rotary, scale folded into q,
     ``wo``) with the kernel forced on through the interpreter against the
@@ -703,8 +548,9 @@ def test_attention_by_the_kernel_matches_attention_by_xla(monkeypatch):
             scores_by=scores_by)
         return jnp.sum(out * w), out
 
-    (_, want), want_g = jax.value_and_grad(loss, has_aux=True)(lp, "xla")
-    (_, got), got_g = jax.value_and_grad(loss, has_aux=True)(lp, "kernel")
+    by = jax.jit(jax.value_and_grad(loss, has_aux=True), static_argnums=1)
+    (_, want), want_g = by(lp, "xla")
+    (_, got), got_g = by(lp, "kernel")
     np.testing.assert_allclose(got, want, atol=1e-4)
     for name in lp:
         np.testing.assert_allclose(got_g[name], want_g[name], atol=2e-4,

@@ -1,43 +1,37 @@
 """``--model solar_open2`` (gated NoPE grouped-query attention 1:3 with a
 delta-rule scan whose write strength reaches 2, sigmoid router beside a
 shared expert) at small widths on the CPU, from seeded weights, against the
-plain reference (``benchmark/reference_solar_open2.py``): each layer kind's
-forward; loss, every leaf's gradient and three Adam steps of the four-layer
-stack, float32 and bfloat16; the chunked scan at write strengths near 2 with
-keys repeated (the eigenvalue -1 case) against the position-at-a-time
-recurrence; the causal block kernel through the Pallas interpreter against
-the chunked XLA path at a group of 8, and its visited blocks; the share test
-(40 expert shares and 8 head shares add up to the uncut reference's layer);
-the router plan's general rule at P = 2 and at P = 5; what ``Config``
-refuses; the scopes and notes of the compiled step; and a fit from TFRecord
-shards."""
+plain reference (``benchmark/reference_solar_open2.py``). The decoders'
+shared tests are ``tests/decoder_contract.py``'s, read through ``SPEC`` (each
+layer kind's forward; loss, every leaf's gradient and three Adam steps,
+float32 and bfloat16; the share test: 40 expert shares and 8 head shares add
+up to the uncut reference's layer; pairs over a small buffer; what ``Config``
+refuses; the scopes and notes of the compiled step; a fit from TFRecord
+shards). This model's own are the router plan's general rule at P = 2 and
+at P = 5, the cell's flags and the stack's shapes, here, and its blocks (the
+scan at write strengths to 2, the causal block kernel) in
+``tests/test_solar_open2_blocks.py``."""
 
-import functools
 import os
 import sys
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-import optax
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from benchmark import reference_solar_open2 as ref  # noqa: E402
 from benchmark.drivers import _program  # noqa: E402
 from benchmark.drivers import _program_kimi_linear  # noqa: E402
 from benchmark.drivers import _program_solar_open2 as seeding  # noqa: E402
-from benchmark.reference_kimi_linear import kda_recurrence  # noqa: E402
-from benchmark.reference_sdar_moe import leaf_gap, worst_leaf_gap  # noqa: E402
-from deepfm_tpu.config import Config  # noqa: E402
-from deepfm_tpu.data import example_codec, tfrecord  # noqa: E402
+from decoder_contract import (DecoderContract, HybridStack,  # noqa: E402
+                              SmallBuffer, Spec, cut_columns, cut_rows,
+                              kda_head_share, off_one)
 from deepfm_tpu.models import (get_model, kimi_linear,  # noqa: E402
-                               registered_models, sdar_moe, solar_open2)
-from deepfm_tpu.ops import block_attention  # noqa: E402
-from deepfm_tpu.parallel import mesh as mesh_lib  # noqa: E402
-from deepfm_tpu.train import Trainer  # noqa: E402
+                               registered_models, solar_open2)
 
 V, L, B = 60, 24, 2
 SMALL = dict(model="solar_open2", feature_size=V, field_size=1,
@@ -51,9 +45,6 @@ SMALL = dict(model="solar_open2", feature_size=V, field_size=1,
              learning_rate=1e-3, steps_per_loop=1)
 SIZES = dict(kda_head_dim=8, head_dim=8, eps=1e-5, top_k=2, route_scale=1.0,
              first_expert=2)
-F32 = jnp.dtype("float32")
-#: float32 program against float32 reference; bfloat16 compute has to miss it.
-TOL = 2e-4
 KINDS = {"gqa+moe": ("gqa", "moe"), "kda+moe": ("kda", "moe")}
 #: The shortest stack with both kinds of layer, the full one leading: what
 #: the tests of a whole trainer step compile (half of a period's time; the
@@ -61,409 +52,97 @@ KINDS = {"gqa+moe": ("gqa", "moe"), "kda+moe": ("kda", "moe")}
 PAIR = dict(decoder_layers=2, attn_every=2)
 
 
-def config(**kw):
-    return Config(**{**SMALL, "compute_dtype": "float32", **kw})
-
-
-def flat(params):
-    """The program's parameter tree under the reference's names, the token
-    table cut to the vocabulary's rows."""
-    leaves, _ = jax.tree_util.tree_flatten_with_path(params)
-    out = {_program.leaf_name(p): np.asarray(x) for p, x in leaves}
-    out["tok_emb"] = out["tok_emb"][:V]
-    return out
-
-
-def sequences(n, seed):
-    return np.random.default_rng(seed).integers(0, V, (n, L)).astype(np.int32)
-
-
-def trainer_on(n_dev, cfg):
-    return Trainer(cfg, mesh_info=mesh_lib.build_mesh(
-        cfg, devices=jax.devices()[:n_dev]))
-
-
-def batch_of(tokens):
-    n = tokens.shape[0]
-    return {"feat_ids": np.zeros((n, 1), np.int32),
-            "feat_vals": np.ones((n, 1), np.float32),
-            "label": np.zeros((n, 1), np.float32), "hist_ids": tokens,
-            "hist_mask": np.ones(tokens.shape, np.float32)}
-
-
-def off_one(key, tree):
-    """``tree`` with every gain (a leaf of ones) moved off one."""
-    leaves, treedef = jax.tree.flatten(tree)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree.unflatten(treedef, [
-        x + 0.1 * jax.random.normal(k, x.shape)
-        if x.ndim == 1 and bool(jnp.all(x == 1.0)) else x
-        for k, x in zip(keys, leaves)])
-
-
-@pytest.fixture(scope="module")
-def seeded():
-    """(model, params with gains moved off one, state)."""
-    model = get_model(config())
-    params, state = model.init(jax.random.PRNGKey(0))
-    return model, off_one(jax.random.PRNGKey(5), params), state
-
-
 def uncut(kind, heads=8, kv_heads=2, experts=40, d=32):
     """One layer's leaves for ``heads`` heads of both mixers (``kv_heads``
     key/value heads) and ``experts`` experts, the published ratios kept at
     small widths (head 8, group 4, top-4), gains off one."""
-    cfg = config(kda_heads=heads, attn_q_heads=heads, attn_kv_heads=kv_heads,
-                 moe_experts=experts, moe_experts_held=experts,
-                 moe_first_expert=0, moe_top_k=4, embedding_size=d)
+    cfg = SPEC.config(kda_heads=heads, attn_q_heads=heads,
+                      attn_kv_heads=kv_heads, moe_experts=experts,
+                      moe_experts_held=experts, moe_first_expert=0,
+                      moe_top_k=4, embedding_size=d)
     lp = get_model(cfg)._init_layer(jax.random.PRNGKey(3), *kind)
     return off_one(jax.random.PRNGKey(4), lp)
 
 
-# ------------------------------------------------ each layer kind's forward
-
-@pytest.mark.parametrize("kind", sorted(KINDS))
-def test_a_layer_matches_the_reference(kind):
-    cfg = config(moe_top_k=4, moe_experts=40, moe_experts_held=40,
-                 moe_first_expert=0, kda_heads=8, attn_q_heads=8,
-                 attn_kv_heads=2, moe_pair_capacity=4 * B * L)
-    model = get_model(cfg)
-    lp = uncut(KINDS[kind])
-    x = 2.0 * jax.random.normal(jax.random.PRNGKey(1), (B, L, 32))
-    got, counts = model._layer(*KINDS[kind], x, lp)
-    with jax.default_matmul_precision("highest"):
-        want = ref.layer(x, lp, {**SIZES, "top_k": 4, "first_expert": 0})
-    np.testing.assert_allclose(got, want, atol=2e-5)
-    assert "moe_pairs_held" in counts
-    assert (kimi_linear.DECAY_MIN in counts) == (kind == "kda+moe")
-    assert (kimi_linear.BETA_OVER_ONE in counts) == (kind == "kda+moe")
+def head_share(lp, mixer, r, heads=8, kv_heads=2):
+    """Share ``r`` of 8: one of a mixer's 8 heads; the full layer's 8 query
+    heads are on 2 key/value heads, so 4 shares read one key/value head."""
+    if mixer == "kda":
+        return kda_head_share(lp, r, 1)
+    kv = r // (heads // kv_heads)       # the head's key/value head
+    return {**lp,
+            **{n: cut_columns(lp[n], r, 1, heads, 8)
+               for n in ("gqa_wq", "gqa_w_gate")},
+            **{n: cut_columns(lp[n], kv, 1, kv_heads, 8)
+               for n in ("gqa_wk", "gqa_wv")},
+            "gqa_wo": cut_rows(lp["gqa_wo"], r, 1, heads, 8)}
 
 
-def test_logits_and_loss_match_the_reference(seeded):
-    model, params, state = seeded
-    tokens = jnp.asarray(sequences(B, 0))
-    logits, counts = model.apply(params, state, None, None, train=True,
-                                 hist_ids=tokens)
-    per_seq, _ = model.per_example_loss(params, state, {"hist_ids": tokens},
-                                        train=True, rng=None)
-    with jax.default_matmul_precision("highest"):
-        want_loss, want_logits = ref.forward_loss(
-            {k: jnp.asarray(v) for k, v in flat(params).items()}, tokens,
-            SIZES)
-    assert logits.shape == (B, L, V)
-    np.testing.assert_allclose(logits, want_logits, atol=2e-5)
-    np.testing.assert_allclose(jnp.mean(per_seq), want_loss, rtol=1e-6)
-    assert int(counts["moe_pairs_over_buffer"]) == 0
-    assert int(counts["moe_pairs_held"]) > 0
-    assert float(counts[kimi_linear.DECAY_MIN]) < 0.0
-    # three KDA layers of 2 heads: some strengths pass 1, not all
-    assert 0 < int(counts[kimi_linear.BETA_OVER_ONE]) < 3 * B * L * 2
+def notes(trainer):
+    return {"kda_scan": "chunk64/sub16", "attn_scores": "xla",
+            "moe_rows": "xla", "moe_rows_moved": "{moe_pairs_held}/%d" % (
+                2 * trainer.cfg.moe_pair_capacity)}
 
 
-@pytest.mark.parametrize("broken, moved", [
-    ({"beta_scale": 1.0}, True), ({"gate": False}, True),
-    ({"causal": False}, True), ({}, False)],
-    ids=["beta-without-its-2", "gate-left-out", "mask-not-causal", "sound"])
-def test_the_references_broken_models_differ_from_the_sound_one(broken, moved):
-    """What the reference's own switches leave out moves its result: the
-    three mechanisms this model adds are each in the mathematics."""
-    lp = {**uncut(KINDS["gqa+moe"]), **uncut(KINDS["kda+moe"])}
-    x = 2.0 * jax.random.normal(jax.random.PRNGKey(1), (B, L, 32))
-    with jax.default_matmul_precision("highest"):
-        xn = ref.rms_norm(x, lp["norm1"], 1e-5)
-        kda_kw = {k: v for k, v in broken.items() if k == "beta_scale"}
-        gqa_kw = {k: v for k, v in broken.items() if k != "beta_scale"}
-        got = ref.kda(xn, lp, SIZES, **kda_kw) + ref.gqa(xn, lp, SIZES,
-                                                         **gqa_kw)
-        want = ref.kda(xn, lp, SIZES) + ref.gqa(xn, lp, SIZES)
-    assert (leaf_gap(got, want) > 0.05) == moved
+SPEC = Spec(
+    ref=ref, small=SMALL, sizes=SIZES, stack=PAIR,
+    scopes=frozenset({"embed", "attn", "attn_scores", "kda", "kda_scan",
+                      "mlp", "moe", "head", "opt"}),
+    notes=notes, kinds=KINDS,
+    layer_counts={"moe_pairs_held": "moe", kimi_linear.DECAY_MIN: "kda",
+                  kimi_linear.BETA_OVER_ONE: "kda"},
+    layer_flags=dict(moe_top_k=4, moe_experts=40, moe_experts_held=40,
+                     moe_first_expert=0, kda_heads=8, attn_q_heads=8,
+                     attn_kv_heads=2, moe_pair_capacity=4 * B * L),
+    layer_sizes={"top_k": 4, "first_expert": 0}, layer_leaves=uncut,
+    # 8 head shares of a mixer (one of 8 heads each) and 40 expert shares
+    # (one of 40 experts each, top-4)
+    share_kinds=tuple(sorted(KINDS)), share_leaves=uncut,
+    head_share=head_share, head_shares=8, expert_shares=40, share_experts=40,
+    small_buffer=4,
+    refusals=(
+        ({"tasks": "ctr,cvr"}, "tasks"),
+        ({"embedding_update": "sparse"}, "embedding_update=sparse"),
+        ({"task_type": "infer"}, "infer/export"),
+        ({"task_type": "export"}, "infer/export"),
+        ({"servable_model_dir": "/tmp/x"}, "servable_model_dir"),
+        ({"online_mode": True}, "online_mode"),
+        ({"mesh_model": 2}, "mesh_model"),
+        ({"batch_norm": True}, "batch_norm"),
+        ({"history_max_len": 1}, "history_max_len >= 2"),
+        ({"decoder_layers": 0}, "decoder_layers"),
+        ({"attn_every": 0}, "attn_every"),
+        ({"kda_heads": 0}, "kda_heads"),
+        ({"attn_kv_heads": 3}, "multiple of attn_kv_heads"),
+        ({"attn_q_heads": 0}, "attn_q_heads"),
+        ({"moe_shared_width": 0}, "moe_shared_width"),
+        ({"moe_top_k": 9}, "moe_top_k"),
+        ({"moe_first_expert": 6}, "moe_experts_held"),
+        ({"moe_pair_capacity": 0}, "moe_pair_capacity"),
+        ({"mla_latent_dim": 16}, "belong to --model kimi_linear"),
+        ({"dense_layers": 1}, "belong to --model kimi_linear"),
+        ({"model": "sdar_moe"}, "belong to --model kimi_linear"),
+    ))
+config = SPEC.config
 
 
-# ------------------------------- the delta-rule scan at write strength to 2
+class TestSolarOpen2(DecoderContract, HybridStack, SmallBuffer):
+    spec = SPEC
 
-def scan_inputs(length, near_two, heads=2, dk=16, seed=0):
-    """q, k (unit), v, g, beta as the mixer hands them on. ``near_two``:
-    write strengths 2 sigmoid(z) with z about 5 (1.98 to 2) and every key
-    repeated once (positions 2i and 2i + 1 share it: the second write meets
-    the eigenvalue 1 - beta = -1 along the key the first just wrote)."""
-    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    def step_metrics_hold(self, metrics):
+        assert int(metrics[kimi_linear.BETA_OVER_ONE]) > 0
 
-    def unit(y):
-        return y / jnp.linalg.norm(y, axis=-1, keepdims=True)
+    def test_logits_and_loss_match_the_reference(self, seeded):
+        counts = self.logits_and_loss(seeded)
+        assert int(counts["moe_pairs_held"]) > 0
+        assert float(counts[kimi_linear.DECAY_MIN]) < 0.0
+        # three KDA layers of 2 heads: some strengths pass 1, not all
+        assert 0 < int(counts[kimi_linear.BETA_OVER_ONE]) < 3 * B * L * 2
 
-    q = unit(jax.random.normal(keys[0], (B, length, heads, dk))) * dk ** -0.5
-    k = unit(jax.random.normal(keys[1], (B, length, heads, dk)))
-    v = jax.random.normal(keys[2], (B, length, heads, dk))
-    g = -0.04 * jax.random.uniform(keys[3], (B, length, heads, dk),
-                                   minval=0.8, maxval=1.2)
-    z = jax.random.normal(keys[4], (B, length, heads))
-    if near_two:
-        k = jnp.repeat(k[:, ::2], 2, axis=1)[:, :length]
-        z = 5.0 + 0.5 * z
-    return q, k, v, g, solar_open2.BETA_SCALE * jax.nn.sigmoid(z)
-
-
-def by_position(q, k, v, g, beta):
-    per_head = jax.vmap(kda_recurrence, in_axes=1, out_axes=1)
-    with jax.default_matmul_precision("highest"):
-        return jax.vmap(per_head)(q, k, v, g, beta)
-
-
-@pytest.mark.parametrize("near_two", [True, False],
-                         ids=["near-2-keys-repeated", "over-0-to-2"])
-@pytest.mark.parametrize("length, chunk, sub", [
-    (256, 64, 16), (200, 64, 16), (64, 64, 64), (96, 32, 8)])
-def test_chunked_scan_at_strengths_to_2_matches_the_recurrence(
-        length, chunk, sub, near_two):
-    """Output and every input's gradient over several chunks, float32: the
-    chunk's unit lower-triangular system ``(I + Diag(beta) A) U = ...`` has
-    twice Kimi-Linear's off-diagonal at beta -> 2 and its solved rows grow
-    faster with the chunk; the 64-position chunk still agrees with the
-    token-by-token recurrence to 2e-5 of the output's size (1e-5 at
-    beta <= 1, ``tests/test_kimi_linear.py``)."""
-    args = scan_inputs(length, near_two)
-    assert float(args[4].max()) > (1.98 if near_two else 1.5)
-    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-
-    def chunked(*a):
-        o, _ = kimi_linear.kda_scan(*a, cdt=F32, chunk=chunk, sub=sub)
-        return jnp.sum(o * w), o
-
-    (_, got), got_grads = jax.value_and_grad(
-        chunked, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
-    want, vjp = jax.vjp(by_position, *args)
-    scale = max(1.0, float(jnp.abs(want).max()))
-    np.testing.assert_allclose(got, want, atol=2e-5 * scale)
-    for a, b in zip(got_grads, vjp(w)):
-        assert np.isfinite(np.asarray(a)).all()
-        np.testing.assert_allclose(a, b, atol=2e-5 * max(
-            1.0, float(jnp.abs(b).max())))
-
-
-def test_the_mixer_doubles_the_write_strength_and_counts_it():
-    lp = uncut(KINDS["kda+moe"], heads=2)
-    x = 2.0 * jax.random.normal(jax.random.PRNGKey(2), (B, L, 32))
-    kw = dict(head_dim=8, eps=1e-5, cdt=F32)
-    one, counts_one = kimi_linear.kda_mixer(lp, x, **kw)
-    two, counts_two = kimi_linear.kda_mixer(lp, x, beta_scale=2.0, **kw)
-    assert set(counts_one) == {kimi_linear.DECAY_MIN}   # Kimi-Linear's step
-    assert set(counts_two) == {kimi_linear.DECAY_MIN,
-                               kimi_linear.BETA_OVER_ONE}
-    beta = 2.0 * jax.nn.sigmoid(sdar_moe.rms_norm(
-        x, lp["norm1"], 1e-5) @ lp["kda_w_b"])
-    assert int(counts_two[kimi_linear.BETA_OVER_ONE]) == int(
-        jnp.sum(beta > 1.0)) > 0
-    assert leaf_gap(two, one) > 0.05
-    with jax.default_matmul_precision("highest"):
-        want = ref.kda(ref.rms_norm(x, lp["norm1"], 1e-5), lp, SIZES)
-    np.testing.assert_allclose(two, want, atol=2e-5)
-
-
-# ----------------------------------------------- the causal block kernel
-
-def _qkv(cdt, length, group=8, head_dim=128):
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(keys[0], (1, length, group, head_dim), jnp.float32)
-    k = jax.random.normal(keys[1], (1, length, 1, head_dim)).astype(cdt)
-    v = jax.random.normal(keys[2], (1, length, 1, head_dim)).astype(cdt)
-    w = jax.random.normal(keys[3], (1, length, group * head_dim))
-    return q, k, v, w
-
-
-@pytest.mark.parametrize("dtype, tol", [("float32", 1e-4),
-                                        ("bfloat16", 2e-2)])
-def test_causal_kernel_matches_the_chunked_xla_path(monkeypatch, dtype, tol):
-    """``masked_scores`` under ``kimi_linear.causal`` by the kernel
-    (forward, dq, dk/dv through the Pallas interpreter, blocks of 128)
-    against the XLA path on the same q/k/v: 512 positions, 8 query heads on
-    1 key/value head, head_dim 128; output and the gradients of q, k, v,
-    within 1e-4 in float32 and within bfloat16's rounding of an operand
-    (2^-8, through three products) under bfloat16."""
-    monkeypatch.setattr(sdar_moe, "_scores_kernel", functools.partial(
-        sdar_moe._scores_kernel, interpret=True, kernel_block=128))
-    cdt = jnp.dtype(dtype)
-    q, k, v, w = _qkv(cdt, 512)
-
-    def value_and_grads(scores_by):
-        def loss(q, k, v):
-            out = sdar_moe.masked_scores(
-                q, k, v, mask=kimi_linear.causal, cdt=cdt,
-                scores_by=scores_by).astype(jnp.float32)
-            return jnp.sum(out * w), out
-        (_, out), grads = jax.value_and_grad(
-            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-        return (out, *(g.astype(jnp.float32) for g in grads))
-
-    for got, want in zip(value_and_grads("kernel"), value_and_grads("xla")):
-        assert leaf_gap(got, want) < tol
-
-
-@pytest.mark.parametrize("seq, kernel_block", [(8192, 512), (1024, 128),
-                                               (512, 512)])
-def test_causal_forward_grid_visits_the_lower_triangle(seq, kernel_block):
-    """n (n + 1) / 2 of the n^2 blocks: 136 of 256 blocks of 512 at 8,192
-    positions, read from the kernel's own block table; one kernel a (mask
-    key, shape)."""
-    kernel = sdar_moe.attn_kernel(seq, kimi_linear.causal, 8, True,
-                                  kernel_block)
-    n = seq // kernel_block
-    assert block_attention.visited_blocks(kernel, seq, kernel_block) == (
-        n * (n + 1) // 2, n * n)
-    if seq == 8192:
-        assert n * (n + 1) // 2 == 136
-    again = sdar_moe.ScoreMask(("causal",), lambda q, k: k <= q)
-    assert again == kimi_linear.causal
-    assert sdar_moe.attn_kernel(seq, again, 8, True, kernel_block) is kernel
-    assert sdar_moe.attn_kernel(
-        seq, sdar_moe.block_diffusion(seq // 2, 4), 8, True,
-        kernel_block) is not kernel
-
-
-def test_attn_notes_are_one_function_for_both_models():
-    assert sdar_moe.attn_notes("xla", kimi_linear.causal, 1024, 8) == {
-        "attn_scores": "xla"}
-    assert sdar_moe.attn_notes("kernel", kimi_linear.causal, 1024, 8) == {
-        "attn_scores": "kernel", "attn_score_blocks": "3/4"}
-    sdar = get_model(Config(
-        model="sdar_moe", feature_size=V, field_size=1, embedding_size=32,
-        history_max_len=512, decoder_layers=1, attn_q_heads=4,
-        attn_kv_heads=1, attn_head_dim=128, moe_experts=4, moe_top_k=1,
-        moe_expert_width=8, moe_experts_held=4, moe_pair_capacity=8,
-        batch_size=1, l2_reg=0.0))
-    assert sdar._attn_notes("kernel", 1024, 512) == sdar_moe.attn_notes(
-        "kernel", sdar_moe.block_diffusion(512, 4), 1024, 4)
-
-
-# ------------------------------------------------------------- the shares
-
-def _cols(a, r, n, heads, per):     # share r's n heads' columns
-    return a.reshape(*a.shape[:-1], heads, per)[
-        ..., r * n:(r + 1) * n, :].reshape(*a.shape[:-1], n * per)
-
-
-def _rows(a, r, n, heads, per):
-    return a.reshape(heads, per, -1)[r * n:(r + 1) * n].reshape(n * per, -1)
-
-
-@pytest.mark.parametrize("kind", sorted(KINDS))
-def test_the_shares_add_up_to_the_uncut_layer(kind):
-    """The configuration's layout at small widths: 8 head shares of a mixer
-    (one of 8 heads each; the full layer's 8 query heads on 2 key/value
-    heads, so 4 shares read one key/value head) and 40 expert shares (one of
-    40 experts each, top-4): the 8 ``wo`` partial sums and the 40 routed
-    partial sums added, the shared expert and the residual stream counted
-    once, are the uncut reference's layer."""
-    mixer, _ = KINDS[kind]
-    heads, kv_heads, experts = 8, 2, 40
-    lp = uncut(KINDS[kind], heads, kv_heads, experts)
-    x = 2.0 * jax.random.normal(jax.random.PRNGKey(2), (B, L, 32))
-    sizes = {**SIZES, "top_k": 4, "first_expert": 0}
-    with jax.default_matmul_precision("highest"):
-        want = ref.layer(x, lp, sizes)
-
-    def head_share(r):
-        out = dict(lp)
-        if mixer == "kda":
-            for n in ("kda_wq", "kda_wk", "kda_wv", "kda_conv_q",
-                      "kda_conv_k", "kda_conv_v", "kda_w_fb", "kda_w_gb",
-                      "kda_dt_bias"):
-                out[n] = _cols(lp[n], r, 1, heads, 8)
-            out["kda_a_log"] = lp["kda_a_log"][r:r + 1]
-            out["kda_w_b"] = lp["kda_w_b"][:, r:r + 1]
-            out["kda_wo"] = _rows(lp["kda_wo"], r, 1, heads, 8)
-        else:
-            kv = r // (heads // kv_heads)       # the head's key/value head
-            for n in ("gqa_wq", "gqa_w_gate"):
-                out[n] = _cols(lp[n], r, 1, heads, 8)
-            for n in ("gqa_wk", "gqa_wv"):
-                out[n] = _cols(lp[n], kv, 1, kv_heads, 8)
-            out["gqa_wo"] = _rows(lp["gqa_wo"], r, 1, heads, 8)
-        return out
-
-    model = get_model(config(moe_top_k=4, moe_experts=experts,
-                             moe_experts_held=1, moe_first_expert=0,
-                             moe_pair_capacity=4 * B * L))
-    mixed = sum(model._mixer(mixer, head_share(r), x)[0]
-                for r in range(heads))
-    h = x + mixed
-    routed, held = 0.0, 0
-    for r in range(experts):
-        share = {**lp, **{n: lp[n][r:r + 1]
-                          for n in ("w_gate", "w_up", "w_down")}}
-        part, counts = sdar_moe.expert_layer(
-            share, h, top_k=4, first_expert=r, capacity=4 * B * L, eps=1e-5,
-            cdt=F32, route_by=model.route_by)
-        routed = routed + part
-        held += int(counts["moe_pairs_held"])
-    whole = kimi_linear.swiglu(lp, "shared_", h, eps=1e-5, cdt=F32)
-    np.testing.assert_allclose(h + routed + whole, want, atol=3e-5)
-    assert held == B * L * 4            # every pair, once
-
-
-# ---------------------------------------------- gradients and Adam's steps
-
-def test_gradients_of_every_leaf_match_the_reference():
-    model = get_model(config(**PAIR))
-    params, state = model.init(jax.random.PRNGKey(0))
-    params = off_one(jax.random.PRNGKey(5), params)
-    tokens = jnp.asarray(sequences(B, 1))
-
-    def loss(p):
-        per_seq, _ = model.per_example_loss(p, state, {"hist_ids": tokens},
-                                            train=True, rng=None)
-        return jnp.mean(per_seq)
-
-    got = flat(jax.grad(loss)(params))
-    with jax.default_matmul_precision("highest"):
-        want = jax.grad(lambda p: ref.forward_loss(p, tokens, SIZES)[0])(
-            {k: jnp.asarray(v) for k, v in flat(params).items()})
-    assert set(got) == set(want)
-    for name in want:
-        assert leaf_gap(got[name], want[name]) < 1e-4, name
-        assert np.linalg.norm(want[name]) > 0, name
-
-
-def follow(compute_dtype, n_dev=1, steps=3):
-    """(worst first-moment gap, worst parameter-change gap, losses) of
-    ``steps`` trainer steps, on ``n_dev`` data replicas, against the
-    reference's follower."""
-    cfg = config(compute_dtype=compute_dtype, mesh_data=n_dev, **PAIR)
-    trainer = trainer_on(n_dev, cfg)
-    state = trainer.init_state(seed=3)
-    start = flat(jax.tree.map(np.asarray, state.params))
-    follower = ref.Follower(start, SIZES, cfg.learning_rate * n_dev)
-    losses = []
-    for step in range(steps):
-        tokens = sequences(B, 10 + step)
-        state, m = trainer.train_step(state,
-                                      trainer.put_batch(batch_of(tokens)))
-        losses.append((float(m["xent"]), follower.step(tokens)))
-    got = flat(jax.tree.map(np.asarray, state.params))
-    mu = flat(jax.tree.map(np.asarray, optax.tree_utils.tree_get(
-        state.opt_state, "mu")))
-    return (worst_leaf_gap(mu, follower.mu)[0],
-            worst_leaf_gap({k: got[k] - start[k] for k in got},
-                           {k: follower.params[k] - start[k]
-                            for k in got})[0], losses)
-
-
-@pytest.mark.parametrize("n_dev", [1, 2])
-def test_three_adam_steps_match_the_reference(n_dev):
-    """float32 against float32: the losses to 1e-5, Adam's first moment to
-    2e-4 (sums in another order), the parameters' change to 2% (Adam's
-    division by a small second moment amplifies a rounding)."""
-    mu_gap, change_gap, losses = follow("float32", n_dev)
-    for got, want in losses:
-        assert abs(got - want) < 1e-5 * max(1.0, abs(want))
-    assert mu_gap < TOL
-    assert change_gap < 0.02
-
-
-def test_bfloat16_compute_misses_the_tolerance():
-    """bfloat16 products round an operand to 2^-8: ten times float32's
-    band and more, so a step one precision lower is told apart."""
-    mu_gap, change_gap, _ = follow("bfloat16")
-    assert mu_gap > 10 * TOL and change_gap > 0.02
+    def test_fit_trains_from_tfrecord_shards(self, tmp_path):
+        seen, _ = self.fit_from_shards(tmp_path)
+        assert int(seen[-1][kimi_linear.BETA_OVER_ONE]) > 0
+        assert float(seen[-1][kimi_linear.DECAY_MIN]) < 0.0
 
 
 # ------------------------------------------------- the benchmark's seeding
@@ -542,48 +221,7 @@ def test_the_placed_router_holds_a_fifth_of_a_layers_positions():
                 np.nonzero(placed[layer, c])[0])
 
 
-def test_pairs_over_a_small_buffer_are_counted_not_lost():
-    trainer = trainer_on(1, config(moe_pair_capacity=4, **PAIR))
-    state = trainer.init_state(seed=1)
-    seen = []
-    for step in range(2):
-        state, m = trainer.train_step(
-            state, trainer.put_batch(batch_of(sequences(B, step))))
-        seen.append(int(m["moe_pairs_over_buffer"]))
-        assert int(m[kimi_linear.BETA_OVER_ONE]) > 0
-    assert 0 < seen[0] < seen[1]
-    assert int(state.model_state["moe_pairs_over_buffer"]) == seen[1]
-
-
 # ---------------------------------------------------------------- the rest
-
-@pytest.mark.parametrize("change, says", [
-    ({"tasks": "ctr,cvr"}, "tasks"),
-    ({"embedding_update": "sparse"}, "embedding_update=sparse"),
-    ({"task_type": "infer"}, "infer/export"),
-    ({"task_type": "export"}, "infer/export"),
-    ({"servable_model_dir": "/tmp/x"}, "servable_model_dir"),
-    ({"online_mode": True}, "online_mode"),
-    ({"mesh_model": 2}, "mesh_model"),
-    ({"batch_norm": True}, "batch_norm"),
-    ({"history_max_len": 1}, "history_max_len >= 2"),
-    ({"decoder_layers": 0}, "decoder_layers"),
-    ({"attn_every": 0}, "attn_every"),
-    ({"kda_heads": 0}, "kda_heads"),
-    ({"attn_kv_heads": 3}, "multiple of attn_kv_heads"),
-    ({"attn_q_heads": 0}, "attn_q_heads"),
-    ({"moe_shared_width": 0}, "moe_shared_width"),
-    ({"moe_top_k": 9}, "moe_top_k"),
-    ({"moe_first_expert": 6}, "moe_experts_held"),
-    ({"moe_pair_capacity": 0}, "moe_pair_capacity"),
-    ({"mla_latent_dim": 16}, "belong to --model kimi_linear"),
-    ({"dense_layers": 1}, "belong to --model kimi_linear"),
-    ({"model": "sdar_moe"}, "belong to --model kimi_linear"),
-])
-def test_config_says_plainly_what_the_model_does_not_take(change, says):
-    with pytest.raises(ValueError, match=says):
-        config(**change)
-
 
 def test_config_accepts_the_cells_flags():
     from benchmark import harness
@@ -632,84 +270,3 @@ def test_the_model_is_a_stack_led_by_its_full_layer():
         assert lp["shared_w_gate"].shape == (32, 16)
     assert set(state) == {*kimi_linear.COUNT_NAMES, kimi_linear.DECAY_MIN,
                           kimi_linear.BETA_OVER_ONE}
-
-
-def test_compiled_step_carries_each_blocks_scope():
-    trainer = trainer_on(1, config(**PAIR))
-    scopes = set(trainer.step_op_scopes().values())
-    assert {"embed", "attn", "attn_scores", "kda", "kda_scan", "mlp", "moe",
-            "head", "opt"} <= scopes
-    assert not {"fm", "tower", "cross", "bottom"} & scopes
-    assert trainer.model.step_notes == {
-        "kda_scan": "chunk64/sub16", "attn_scores": "xla", "moe_rows": "xla",
-        "moe_rows_moved": "{moe_pairs_held}/%d" % (
-            2 * trainer.cfg.moe_pair_capacity)}
-
-
-def test_model_by_the_causal_kernel_takes_the_same_step(monkeypatch):
-    """The full layer's scores by the block kernel (forced on through the
-    Pallas interpreter at head_dim 128, run in blocks of 128) in the whole
-    model:
-    loss and every leaf's gradient against the XLA path's; the notes say the
-    path and the visited blocks."""
-    cfg = config(history_max_len=512, attn_head_dim=128, attn_q_heads=8,
-                 attn_kv_heads=1, decoder_layers=2, attn_every=2,
-                 moe_pair_capacity=2 * 512 * 2)
-    tokens = jnp.asarray(np.random.default_rng(3).integers(
-        0, V, (B, 512)).astype(np.int32))
-
-    def grads():
-        model = get_model(cfg)
-        params, state = model.init(jax.random.PRNGKey(0))
-
-        def loss(p):
-            per_seq, _ = model.per_example_loss(
-                p, state, {"hist_ids": tokens}, train=True, rng=None)
-            return jnp.mean(per_seq)
-        return model, jax.value_and_grad(loss)(params)
-
-    model, (want, want_g) = grads()
-    assert model.step_notes["attn_scores"] == "xla"
-    monkeypatch.setattr(sdar_moe, "_scores_kernel", functools.partial(
-        sdar_moe._scores_kernel, interpret=True, kernel_block=128))
-    monkeypatch.setattr(solar_open2, "attn_scores_by",
-                        lambda seq, head_dim, one_device=True: "kernel")
-    model, (got, got_g) = grads()
-    assert model.step_notes["attn_scores"] == "kernel"
-    assert model.step_notes["attn_score_blocks"] == "1/1"  # blocks of 512
-    np.testing.assert_allclose(got, want, rtol=1e-5)
-    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_g),
-                            jax.tree.leaves(want_g)):
-        assert leaf_gap(g, w) < 1e-4, jax.tree_util.keystr(path)
-
-
-def test_fit_trains_from_tfrecord_shards(tmp_path):
-    """``Trainer.fit`` over the normal file pipeline (the tokens ride the
-    record's history list), one step a dispatch: the loss falls and the
-    counts ride the metrics."""
-    from deepfm_tpu.train import tasks
-
-    rng = np.random.default_rng(0)
-    path = str(tmp_path / "tr-0.tfrecord")
-    with tfrecord.TFRecordWriter(path) as w:
-        for _ in range(16):
-            # a sequence a model can learn: a walk of +1 from a random start
-            row = (rng.integers(0, V) + np.arange(L)) % V
-            w.write(example_codec.encode_ctr_example(
-                0.0, np.zeros(1), np.ones(1), hist_ids=row))
-    cfg = config(learning_rate=1e-2, log_steps=1000, **PAIR)
-    trainer = trainer_on(1, cfg)
-    pipeline = tasks.make_pipeline(cfg, [path], epochs=6)
-    seen = []
-    try:
-        state, out = trainer.fit(trainer.init_state(seed=0), pipeline,
-                                 hooks=[lambda s, m: seen.append(m)])
-    finally:
-        pipeline.close()
-    losses = [float(m["xent"]) for m in seen]
-    assert len(losses) == 6 * 16 // B
-    assert losses[-1] < 0.6 * losses[0]
-    assert np.isfinite(float(out["loss"]))
-    assert int(seen[-1]["moe_pairs_held"]) > 0
-    assert int(seen[-1][kimi_linear.BETA_OVER_ONE]) > 0
-    assert float(seen[-1][kimi_linear.DECAY_MIN]) < 0.0

@@ -50,7 +50,10 @@ class TestTrainTask:
     # numerics; on drifting XLA CPU builds the 4x2-mesh trajectory lands
     # elsewhere (see conftest capability probes).
     @pytest.mark.mesh_bitexact
-    def test_train_eval_export_and_resume(self, workdir):
+    def test_train_eval_export_and_resume(self, workdir, monkeypatch):
+        # (the artifact read back below is the StableHLO one; the TensorFlow
+        # sidecar, 50 of this test's 67 s, is ``test_savedmodel_export``'s)
+        monkeypatch.setenv("DEEPFM_TPU_SKIP_TF_EXPORT", "1")
         cfg = _cfg(workdir, servable_model_dir=str(workdir / "servable"))
         result = tasks.run(cfg)
         assert result["auc"] > 0.6, result

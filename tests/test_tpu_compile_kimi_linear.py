@@ -1,0 +1,80 @@
+"""``kimi-linear-48b-a3b.train-sequences-8k``'s own step, every width,
+compiled for a *described* v5e (no chip attached, nothing runs; the fixtures
+are ``conftest.py``'s): that it fits the chip and names its layers, as a
+standing test; and the expert layers' row kernels alone at the two widths
+the decoder cells move (2,304 is this cell's).
+"""
+
+import pytest
+
+import jax
+
+from benchmark import harness
+from deepfm_tpu.utils import profiling
+
+
+def test_hybrid_decoder_step_at_the_cells_shapes_fits_and_names_its_layers(
+        step_for_v5e):
+    """``kimi-linear-48b-a3b.train-sequences-8k``'s own step (every width,
+    5 layers, 2 x 8,192 tokens) compiled for a described v5e: ops charged to
+    each of the model's scopes, the delta-rule scan's own among them, and
+    arguments and temporaries together under the chip's memory (the issue's
+    fallback to one sequence a step starts at 15.5 GB)."""
+    tr, compiled, text = step_for_v5e(
+        harness.load_json("configs", "kimi-linear-48b-a3b.json")["flags"])
+    scopes = set(profiling.hlo_op_scopes(text).values())
+    assert {"embed", "kda", "kda_scan", "attn", "mlp", "moe", "head",
+            "opt"} <= scopes
+    memory = compiled.memory_analysis()
+    assert 7.8e9 < memory.argument_size_in_bytes < 8.0e9
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 15.5e9
+    # its expert layers' rows (2,304 wide: 18 lines) move by the row kernels
+    assert tr.model.step_notes["moe_rows"] == "kernel"
+    by_op = profiling.hlo_op_scopes(text)
+    assert {scope for name, scope in by_op.items() if name.startswith(
+        ("moe_take_rows", "moe_add_rows"))} == {"moe"}
+
+
+@pytest.mark.parametrize("width", [2048, 2304])
+def test_row_kernels_compile_at_the_cells_shapes(v5e, no_compile_cache,
+                                                 width):
+    """``ops/pallas_moe_rows`` at a pass of 16,384 rows over 16,384 positions
+    of the two decoder cells' widths, forward and backward: Mosaic takes the
+    one-row strided copies (a ``[T/8, W/128, 8, 1, 128]`` view) and XLA makes
+    that view and its way back without a copy of the array: nothing
+    ``[16384, W]`` is made beside the results."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from deepfm_tpu.ops import pallas_moe_rows as pmr
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=SingleDeviceSharding(v5e))
+
+    rows = 16384
+
+    def loss(x, y, carry, tok, ends):
+        xs, through = pmr.gather(jax.lax.stop_gradient(x), x, tok, ends,
+                                 jnp.bfloat16)
+        out = pmr.combine(carry, xs.astype(jnp.float32) * y, y[:, 0], tok,
+                          ends)
+        return jnp.sum(out) + jnp.sum(through)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        spec((rows, width), jnp.float32), spec((rows, width), jnp.float32),
+        spec((rows, width), jnp.float32), spec((rows,), jnp.int32),
+        spec((16,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    for name in ("moe_take_rows", "moe_add_rows"):
+        assert name in text, name
+    # (one copy of an array: this function's own carry, an argument it may
+    # not spoil)
+    copies = [line for line in text.splitlines() if " copy(" in line
+              and f"[{rows},{width}]" in line.split(" copy(")[0]]
+    assert len(copies) == 1 and " copy(%carry" in copies[0], copies
+    assert " transpose(" not in text
+    # xs and its product, the rows' cotangents: a few arrays, no more
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * rows * width * 4

@@ -1,0 +1,116 @@
+"""What only the TPU's compiler can say of the decoders' kernels in an
+SDAR-shaped step, asked of a *described* v5e (no chip attached, nothing
+runs; the fixtures are ``conftest.py``'s): the masked scores are the
+block-masked attention kernels' where a head is a whole or half a lane line
+wide (``ops/block_attention.py``; PR 32, PR 40), the expert layers' rows
+move by the row kernels (``ops/pallas_moe_rows``; PR 34), each charged to
+its scope by the step's own text, in a step cut to compile in seconds; and
+the attention kernels alone at ``sdar-30b-a3b.train-sequences``'s shapes.
+(The other families' are ``tests/test_tpu_compile_rankers.py``,
+``_kimi_linear.py``, ``_solar_open2.py`` and ``_lfm2.py``.)
+"""
+
+import jax
+
+from deepfm_tpu.utils import profiling
+
+# The SDAR cell's attention widths (4 query heads of 128 on one key/value
+# head) with the depth, the width, the experts, the vocabulary and the
+# length cut so that the step compiles in seconds; 2L = 1,024 positions are
+# two of the kernel's blocks.
+SDAR_FLAGS = dict(
+    model="sdar_moe", feature_size=512, field_size=1, embedding_size=256,
+    history_max_len=512, decoder_layers=2, attn_q_heads=4, attn_kv_heads=1,
+    attn_head_dim=128, moe_experts=8, moe_top_k=2, moe_expert_width=128,
+    moe_experts_held=4, moe_first_expert=0, moe_pair_capacity=4096,
+    diffusion_block=4, batch_size=1, l2_reg=0.0, learning_rate=1e-5,
+    compute_dtype="bfloat16", steps_per_loop=1)
+
+
+def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
+        step_for_v5e):
+    """On a TPU at head_dim 128 the step's masked scores are the three
+    kernels JAX's flash attention is made of (forward; dq; dk and dv), each
+    charged to ``attn`` by the step's own text, though each prints over three
+    lines (``profiling.whole_instructions``), and the model says so."""
+    tr, compiled, text = step_for_v5e(SDAR_FLAGS)
+    scopes = profiling.hlo_op_scopes(text)
+    assert tr.model.step_notes == {
+        "attn_scores": "kernel", "attn_score_blocks": "3/4",
+        "moe_rows": "kernel", "moe_rows_moved": "{moe_pairs_held}/8192"}
+    # the expert layer's rows move by the row kernels (rows of two lines,
+    # 1,024 positions, one pass of 4,096 rows), forward and backward, all
+    # charged to ``moe``
+    rows = {name: scope for name, scope in scopes.items()
+            if name.startswith(("moe_take_rows", "moe_add_rows"))}
+    assert {name.split(".")[0] for name in rows} == {
+        "moe_take_rows", "moe_add_rows"} and len(rows) == 5, rows
+    assert set(rows.values()) == {"moe"}, rows
+    kernels = {name: scope for name, scope in scopes.items()
+               if name.startswith("splash_mqa_")}
+    assert {name.split(".")[0] for name in kernels} == {
+        "splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+        "splash_mqa_dkv_no_residuals"}, kernels
+    assert set(kernels.values()) == {"attn"}, kernels
+    # the raw text loses them: their op_name is on a continuation line
+    raw = profiling.hlo_op_scopes(compiled.as_text())
+    assert {raw[name] for name in kernels} == {""}
+    # and every other instruction is charged as it was (the grouped
+    # products' kernels get their scope from the model, as before)
+    def others(by_op):
+        return {n: s for n, s in by_op.items()
+                if n not in kernels and not n.startswith(
+                    ("ragged-dot", "pallas_call"))}    # (the kernels' parts)
+    assert others(scopes) == others(raw)
+
+
+def test_decoder_step_at_head_dim_32_keeps_the_xla_scores(step_for_v5e):
+    """A head a quarter of a lane line wide keeps XLA's chunks
+    (``block_attention.supported``: whole and half lines)."""
+    tr, _, text = step_for_v5e({**SDAR_FLAGS, "attn_head_dim": 32})
+    assert tr.model.step_notes["attn_scores"] == "xla"
+    assert "attn_score_blocks" not in tr.model.step_notes
+    assert "splash_mqa" not in text
+
+
+def test_decoder_step_at_head_dim_64_takes_the_kernels(step_for_v5e):
+    """Since PR 40 a head half a lane line wide takes the kernels, as it is
+    (Mosaic compiles them at 64 lanes)."""
+    tr, _, text = step_for_v5e({**SDAR_FLAGS, "attn_head_dim": 64})
+    notes = tr.model.step_notes
+    assert (notes["attn_scores"], notes["attn_score_blocks"]) == (
+        "kernel", "3/4")
+    assert "bf16[4,1024,64]" in text and "bf16[4,1024,128]" not in text
+    for name in ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                 "splash_mqa_dkv_no_residuals"):
+        assert f"%{name}" in text, name
+
+
+def test_attention_kernels_compile_at_the_cells_shapes(v5e,
+                                                       no_compile_cache):
+    """Forward and backward at q [2, 8192, 1, 4, 128] bfloat16 under the
+    block-diffusion mask of 4,096 tokens: Mosaic takes the three kernels at
+    blocks of 512 (VMEM, tiling), and nothing [S, S] is made outside them."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from deepfm_tpu.models import sdar_moe
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(v5e))
+
+    def loss(q, k, v):
+        return jnp.sum(sdar_moe._scores_kernel(
+            q, k, v, mask=sdar_moe.block_diffusion(4096, 4)).astype(
+                jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec(2, 8192, 1, 4, 128), spec(2, 8192, 1, 128),
+        spec(2, 8192, 1, 128)).compile()
+    text = compiled.as_text()
+    for name in ("splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
+                 "splash_mqa_dkv_no_residuals"):
+        assert f"%{name}" in text, name
+    # one float32 [2, 4, 8192, 8192] score matrix would be 2.1 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20

@@ -80,6 +80,22 @@ def test_compiled_multi_step_names_every_phase(update, kernels):
     assert all(re.fullmatch(r"[\w.\-]+", name) for name in by_op)
 
 
+def test_a_step_compiled_once_reads_as_an_untouched_trainers(compiled_once):
+    """``conftest.py``'s ``compiled_once``: the trainer answers with the one
+    executable, and its text and scopes, by the trainer's own methods, are
+    those of a trainer that compiles for every question."""
+    held, alone = Trainer(_cfg()), Trainer(_cfg())
+    compiled = compiled_once(held)
+    assert held.step_compiled() is compiled
+    # (the text's tables of source frames name the caller: not instructions)
+    def instructions(text):
+        return [re.sub(r" stack_frame_id=\d+", "", line)
+                for line in text.splitlines() if " = " in line]
+    assert instructions(held.step_hlo_text()) == instructions(
+        alone.step_hlo_text())
+    assert held.step_op_scopes() == alone.step_op_scopes()
+
+
 def test_innermost_scope():
     f = profiling.innermost_scope
     assert f("jit(multi)/jit(main)/while/body/transpose(jvp(embed))/"
