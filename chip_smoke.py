@@ -177,22 +177,26 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
     # forward and both backward kernels, against the chunked XLA path on one
     # key/value head's query heads of 128 over 1,024 positions, bfloat16
     # operands: four heads under SDAR's block-diffusion mask, eight under
-    # Solar-Open2's causal one, and LFM2's four heads of 64 (half a lane
-    # line, as they are) under the causal mask.
-    from deepfm_tpu.models import kimi_linear, sdar_moe
+    # Solar-Open2's causal one, LFM2's four heads of 64 (half a lane
+    # line, as they are) under the causal mask, and Phi-4-flash's two heads
+    # of 64 on a pair's values of 128 under a window's band (300 positions:
+    # the diagonal blocks and part of the one below).
+    from deepfm_tpu.models import kimi_linear, phi4_flash, sdar_moe
 
     length, cdt = 512, jnp.dtype(jnp.bfloat16)
     errs = []
-    for heads, head_dim, mask in (
-            (4, 128, sdar_moe.block_diffusion(length, 4)),
-            (8, 128, kimi_linear.causal), (4, 64, kimi_linear.causal)):
+    for heads, head_dim, value_dim, mask in (
+            (4, 128, 128, sdar_moe.block_diffusion(length, 4)),
+            (8, 128, 128, kimi_linear.causal),
+            (4, 64, 64, kimi_linear.causal),
+            (2, 64, 128, phi4_flash.window(300))):
         q, key, val = (jnp.asarray(rng.normal(size=shape_), jnp.float32)
                        for shape_ in ((1, 2 * length, 1, heads, head_dim),
                                       (1, 2 * length, 1, head_dim),
-                                      (1, 2 * length, 1, head_dim)))
+                                      (1, 2 * length, 1, value_dim)))
         key, val = key.astype(cdt), val.astype(cdt)
         weight = jnp.asarray(
-            rng.normal(size=(1, 2 * length, heads * head_dim)), jnp.float32)
+            rng.normal(size=(1, 2 * length, heads * value_dim)), jnp.float32)
 
         def by_kernel(q, k, v):
             return sdar_moe._scores_kernel(
@@ -263,6 +267,52 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
     assert all(e <= lim for e, lim in zip(errs, limits)), (
         f"moe rows vs XLA: {errs} > {limits}")
     out["moe_rows_max_rel_err"] = max(errs)
+
+    # the selective scan as the program ships it here
+    # (models/phi4_flash.selective_scan by ``scan_by``'s word: on a TPU the
+    # two kernels of ops/pallas_selective_scan, their state in registers;
+    # the rehearsal runs them through the interpreter) against a
+    # ``lax.scan`` over positions: 2,048 positions (32 time blocks) of 1,024
+    # channels x 16 states, float32, the output and every input's gradient.
+    t_len, width, states = 2048, 1024, 16
+    by = phi4_flash.scan_by(width, t_len,
+                            backend="tpu" if interpret else None)
+    assert by == "kernel", by
+    xs = jnp.asarray(rng.normal(size=(1, t_len, width)), jnp.float32)
+    step = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1),
+                                          (1, t_len, width))), jnp.float32)
+    rate = -jnp.broadcast_to(jnp.arange(1, states + 1, dtype=jnp.float32),
+                             (width, states))
+    b_in, c_out = (jnp.asarray(rng.normal(size=(1, t_len, states)),
+                               jnp.float32) for _ in range(2))
+    skip = jnp.asarray(rng.normal(size=(width,)), jnp.float32)
+    w_out = jnp.asarray(rng.normal(size=(1, t_len, width)), jnp.float32)
+
+    def by_positions(x, d, a, b_, c_, skip_):
+        def one(state, at):             # state [N, C]
+            x_t, d_t, b_t, c_t = at
+            state = jnp.exp(d_t[None, :] * a.T) * state \
+                + (d_t * x_t)[None, :] * b_t[:, None]
+            return state, jnp.sum(c_t[:, None] * state, axis=0)
+        _, y = jax.lax.scan(one, jnp.zeros((states, width), jnp.float32),
+                            (x[0], d[0], b_[0], c_[0]))
+        return y[None] + skip_ * x
+
+    def scan_and_grads(f):
+        def loss(*a):
+            y = f(*a)
+            return jnp.sum(y * w_out), y
+        (_, y), g = jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(6)), has_aux=True))(
+                xs, step, rate, b_in, c_out, skip)
+        return (y, *g)
+
+    errs = [max_rel(g, w) for g, w in zip(
+        scan_and_grads(lambda *a: phi4_flash.selective_scan(
+            *a, by=by, interpret=interpret)[0]),
+        scan_and_grads(by_positions))]
+    assert max(errs) <= 1e-4, f"selective scan vs positions: {errs}"
+    out["selective_scan_max_rel_err"] = max(errs)
     return out
 
 

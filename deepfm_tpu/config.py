@@ -49,7 +49,7 @@ class Config:
     coordinator_address: str = ""     # jax.distributed coordinator (host:port)
 
     # ---- model hyperparameters (reference: model flags) ----
-    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2 | lfm2_moe
+    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2 | lfm2_moe | phi4_flash
     feature_size: int = 117581        # vocabulary size (reference ipynb:85)
     field_size: int = 39              # number of fields (reference ipynb:90)
     embedding_size: int = 32          # latent dim (reference flag default, ...py:44)
@@ -125,6 +125,22 @@ class Config:
     # table.
     layer_types: str = ""
     conv_taps: int = 3
+    # phi4_flash only (selective-scan / differential-attention
+    # decoder-decoder, models/phi4_flash.py), beside decoder_layers / attn_* /
+    # dense_mlp_width (every layer's MLP) / rms_norm_eps (the LayerNorms'
+    # epsilon) / layer_types, whose words are here mamba, window_attention,
+    # full_attention, gmu and cross_attention: first_layer is the published
+    # index of the first held layer (lambda_init follows it); attn_window the
+    # positions a windowed query reads, its own among them; a scan has
+    # mamba_expand * embedding_size channels of mamba_state states, a
+    # convolution of mamba_conv taps and a step size of rank mamba_dt_rank.
+    # No experts, no positions; the head is the token table.
+    first_layer: int = 0
+    attn_window: int = 0
+    mamba_state: int = 0
+    mamba_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
     l2_reg: float = 1e-4
     loss_type: str = "log_loss"       # log_loss | square_loss
 
@@ -492,7 +508,8 @@ class Config:
             raise ValueError("metrics_snapshot_secs must be >= 0")
         if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm",
                               "dlrm_dcnv2", "din", "bst", "sdar_moe",
-                              "kimi_linear", "solar_open2", "lfm2_moe"):
+                              "kimi_linear", "solar_open2", "lfm2_moe",
+                              "phi4_flash"):
             raise ValueError(f"unknown model: {self.model!r}")
         if self.model == "sdar_moe":
             self._validate_sdar_moe()
@@ -502,11 +519,13 @@ class Config:
             self._validate_solar_open2()
         elif self.model == "lfm2_moe":
             self._validate_lfm2_moe()
+        elif self.model == "phi4_flash":
+            self._validate_phi4_flash()
         elif self.decoder_layers or self.moe_experts or self.attn_q_heads:
             raise ValueError(
                 "decoder_layers/attn_*/moe_* belong to --model sdar_moe, "
-                f"kimi_linear, solar_open2 and lfm2_moe; {self.model!r} has "
-                "no decoder block")
+                f"kimi_linear, solar_open2, lfm2_moe and phi4_flash; "
+                f"{self.model!r} has no decoder block")
         # the decoders' further flags, and the models that take each
         takers = {
             "kda_heads/attn_every/moe_shared_width": (
@@ -517,11 +536,16 @@ class Config:
                 self.moe_route_scale != 1.0),
             "mla_latent_dim/mla_rope_dim": (
                 ("kimi_linear",), self.mla_latent_dim or self.mla_rope_dim),
-            "dense_layers/dense_mlp_width": (
-                ("kimi_linear", "lfm2_moe"),
-                self.dense_layers or self.dense_mlp_width),
-            "layer_types/conv_taps": (
-                ("lfm2_moe",), self.layer_types or self.conv_taps != 3),
+            "dense_layers": (("kimi_linear", "lfm2_moe"), self.dense_layers),
+            "dense_mlp_width": (
+                ("kimi_linear", "lfm2_moe", "phi4_flash"),
+                self.dense_mlp_width),
+            "layer_types": (("lfm2_moe", "phi4_flash"), self.layer_types),
+            "conv_taps": (("lfm2_moe",), self.conv_taps != 3),
+            "first_layer/attn_window/mamba_*": (
+                ("phi4_flash",), self.first_layer or self.attn_window
+                or self.mamba_state or self.mamba_dt_rank
+                or self.mamba_conv != 4 or self.mamba_expand != 2),
         }
         for what, (models, set_) in takers.items():
             if set_ and self.model not in models:
@@ -1002,7 +1026,7 @@ class Config:
 
     def _refuse_for_a_decoder(self, model: str) -> None:
         """What none of the next-token decoders (kimi_linear, solar_open2,
-        lfm2_moe) takes."""
+        lfm2_moe, phi4_flash) takes."""
         refused = {
             "tasks (the loss is over the positions of a sequence, one task)":
                 self.num_tasks > 1,
@@ -1074,6 +1098,58 @@ class Config:
             if not ok:
                 raise ValueError(f"model lfm2_moe needs {what}")
         self._refuse_for_a_decoder("lfm2_moe")
+
+    def _validate_phi4_flash(self) -> None:
+        """What the selective-scan / differential-attention decoder-decoder
+        takes, and plainly what it does not (models.phi4_flash.Phi4Flash)."""
+        kinds = self.layer_type_list
+        words = ("mamba", "window_attention", "full_attention", "gmu",
+                 "cross_attention")
+        attends = any(kind.endswith("attention") for kind in kinds)
+
+        def follows(reader: str, writer: str) -> bool:
+            """Every ``reader`` layer has a ``writer`` layer before it."""
+            return all(writer in kinds[:i] for i, kind in enumerate(kinds)
+                       if kind == reader)
+        need = {
+            "decoder_layers >= 1": self.decoder_layers >= 1,
+            "layer_types: decoder_layers words, each one of "
+            + ", ".join(words): len(kinds) == self.decoder_layers
+                and all(kind in words for kind in kinds),
+            "layer_types: a mamba layer ahead of every gmu (whose scan it "
+            "reads) and a full_attention layer ahead of every "
+            "cross_attention (whose keys and values it reads)":
+                follows("gmu", "mamba")
+                and follows("cross_attention", "full_attention"),
+            "first_layer >= 0 (the first held layer's published index)":
+                self.first_layer >= 0,
+            "dense_mlp_width >= 1": self.dense_mlp_width >= 1,
+            "mamba_state, mamba_dt_rank, mamba_conv and mamba_expand >= 1 "
+            "where a layer is mamba or gmu":
+                not {"mamba", "gmu"} & set(kinds) or min(
+                    self.mamba_state, self.mamba_dt_rank, self.mamba_conv,
+                    self.mamba_expand) >= 1,
+            "even attn_q_heads and attn_kv_heads >= 2 (adjacent heads "
+            "pair), attn_q_heads a multiple of attn_kv_heads, of "
+            "attn_head_dim >= 1, where a layer attends": not attends or (
+                self.attn_kv_heads >= 2 and self.attn_kv_heads % 2 == 0
+                and self.attn_q_heads >= 2
+                and self.attn_q_heads % self.attn_kv_heads == 0
+                and self.attn_head_dim >= 1),
+            "attn_window >= 1 where a layer is window_attention":
+                "window_attention" not in kinds or self.attn_window >= 1,
+            "history_max_len >= 2 (the sequence length; the loss is of the "
+            "next token)": self.history_max_len >= 2,
+            "feature_size >= 2": self.feature_size >= 2,
+        }
+        for what, ok in need.items():
+            if not ok:
+                raise ValueError(f"model phi4_flash needs {what}")
+        if self.moe_experts or self.moe_top_k or self.moe_pair_capacity \
+                or self.moe_experts_held or self.moe_expert_width:
+            raise ValueError("model phi4_flash does not take moe_* (it has "
+                             "no experts)")
+        self._refuse_for_a_decoder("phi4_flash")
 
     def _validate_solar_open2(self) -> None:
         """What the gated-GQA / KDA MoE decoder takes, and plainly what it

@@ -4,7 +4,8 @@
 (``--tasks ctr,cvr``) builds the multi-task model (``--multitask``
 architecture over the shared graph bottom); otherwise ``cfg.model`` picks a
 single-task graph from the registry, or one of the decoders (``sdar_moe``,
-``kimi_linear``, ``solar_open2``, ``lfm2_moe``), which are no rankers.
+``kimi_linear``, ``solar_open2``, ``lfm2_moe``, ``phi4_flash``), which are no
+rankers.
 """
 
 from typing import Union
@@ -17,6 +18,7 @@ from .graph import GraphWideDeep as WideDeep
 from .kimi_linear import KimiLinear
 from .lfm2_moe import Lfm2Moe
 from .multitask import MultiTaskModel  # noqa: F401
+from .phi4_flash import Phi4Flash
 from .sdar_moe import SdarMoE
 from .sequence import GraphBST, GraphDIN  # noqa: F401
 from .solar_open2 import SolarOpen2
@@ -33,11 +35,12 @@ _REGISTRY = {
     "kimi_linear": KimiLinear,
     "solar_open2": SolarOpen2,
     "lfm2_moe": Lfm2Moe,
+    "phi4_flash": Phi4Flash,
 }
 
 CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDLRMDCNv2, GraphDIN,
                  GraphBST, SdarMoE, KimiLinear, SolarOpen2, Lfm2Moe,
-                 MultiTaskModel]
+                 Phi4Flash, MultiTaskModel]
 
 
 def registered_models():

@@ -84,9 +84,10 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _merged(name: str, least, largest, total):
-    """How a count is merged over layers or replicas: the decay's least,
-    a ``_max`` count's largest, any other's sum."""
-    return least if name == DECAY_MIN else (
+    """How a count is merged over layers or replicas: a ``_min`` count's
+    least (a scan's most negative chunk log-decay), a ``_max`` count's
+    largest, any other's sum."""
+    return least if name.endswith("_min") else (
         largest if name.endswith("_max") else total)
 
 
@@ -453,6 +454,19 @@ class KimiLinear(GraphModel):
             out = out + swiglu(lp, "shared_", h, eps=eps, cdt=self.cdt)
         return out, {**counts, **moe_counts}
 
+    def _run_layer(self, i: int, kind: Tuple[str, str], x: jnp.ndarray,
+                   lp: Dict[str, jnp.ndarray], left: Dict[str, jnp.ndarray],
+                   paths: Dict[str, str]):
+        """Layer ``i`` (of kind ``kind``, leaves ``lp``), made again in the
+        backward pass -> (the stream, the layer's counts, what the layers so
+        far leave for later ones to read, by name). ``left`` is what the
+        earlier layers left; here no layer reads or leaves anything (a model
+        whose layers read other layers' tensors hands them through its own:
+        ``models.phi4_flash``)."""
+        x, counts = jax.checkpoint(functools.partial(
+            self._layer, *kind, **paths))(x, lp)
+        return x, counts, left
+
     def hidden(self, params: common.Params, ids: jnp.ndarray, *,
                shard_axis: Optional[str] = None,
                emb_rows: Optional[Dict[str, Any]] = None,
@@ -466,10 +480,10 @@ class KimiLinear(GraphModel):
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
         seen: Dict[str, list] = {}
+        left: Dict[str, jnp.ndarray] = {}
         for i, kind in enumerate(self.kinds):
-            x, counts = jax.checkpoint(functools.partial(
-                self._layer, *kind, **paths))(
-                    x, params["layers"][str(i)])
+            x, counts, left = self._run_layer(
+                i, kind, x, params["layers"][str(i)], left, paths)
             for name, value in counts.items():
                 seen.setdefault(name, []).append(value)
         return x, {name: _merged(name, jnp.min, jnp.max, jnp.sum)(
@@ -493,8 +507,10 @@ class KimiLinear(GraphModel):
         if data_axis is not None:       # the replicas' counts, as one
             counts = {k: _merged(k, jax.lax.pmin, jax.lax.pmax, jax.lax.psum)(
                 v, data_axis) for k, v in counts.items()}
-        counts["moe_pairs_over_buffer"] = (
-            state["moe_pairs_over_buffer"] + counts["moe_pairs_over_buffer"])
+        if "moe_pairs_over_buffer" in counts:   # (a model with experts)
+            counts["moe_pairs_over_buffer"] = (
+                state["moe_pairs_over_buffer"]
+                + counts["moe_pairs_over_buffer"])
         return h, tokens, counts
 
     def apply(self, params: common.Params, state: common.State,

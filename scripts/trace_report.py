@@ -103,6 +103,14 @@ held, and, where the write strength reaches 2 (``solar_open2``),
 passed 1; the report prints one "delta-rule scan" line with the first
 count's minimum and the second's mean over the trace (TUNING §17).
 
+Where the model scans a selective recurrence (``--model phi4_flash``), each
+``train.log_sync`` carries ``mamba_scan`` (the form of the compiled step:
+``kernel steps64`` on a TPU, ``lockstep chunk32/segment512`` elsewhere), the step's
+``mamba_chunk_log_decay_min``, the most negative whole-chunk log-decay, and
+beside ``attn_scores`` / ``attn_score_blocks`` (the causal layers')
+``attn_window_blocks``, the blocks the kernel visits under the window; the
+report prints one "selective scan" line.
+
 Usage:
     python scripts/trace_report.py TRACE.json [--top 20] [--json]
                                               [--stalls MS]
@@ -452,6 +460,26 @@ def delta_rule_scan(events):
     return out
 
 
+def selective_scan(events):
+    """The selective scan's note and count off the ``train.log_sync`` spans
+    that carry them: ``steps`` read, ``scan`` (``mamba_scan``: form, chunk
+    and segment), ``log_decay_min`` (the least
+    ``mamba_chunk_log_decay_min``) and, of the attention kernel under the
+    window, ``window_blocks`` (``attn_window_blocks``; absent where XLA
+    makes the scores); None when no span says ``mamba_scan`` (another
+    model)."""
+    seen = _log_syncs(events, "mamba_scan")
+    if not seen:
+        return None
+    lows = [a["mamba_chunk_log_decay_min"] for a in seen
+            if "mamba_chunk_log_decay_min" in a]
+    out = {"steps": len(seen), "scan": seen[-1]["mamba_scan"],
+           "log_decay_min": min(lows) if lows else None}
+    if "attn_window_blocks" in seen[-1]:
+        out["window_blocks"] = seen[-1]["attn_window_blocks"]
+    return out
+
+
 def expert_rows(events):
     """How the expert layers' rows moved, off the ``train.log_sync`` spans
     that say so: ``steps`` read, ``rows`` (``moe_rows``: ``kernel``, one copy
@@ -494,6 +522,7 @@ def main(argv=None):
     attn = attention_scores(events)
     scan = delta_rule_scan(events)
     conv = short_convolution(events)
+    selective = selective_scan(events)
     moved = expert_rows(events)
     boots = start_up(events)
 
@@ -512,6 +541,8 @@ def main(argv=None):
             doc["delta_rule_scan"] = scan
         if conv is not None:
             doc["short_convolution"] = conv
+        if selective is not None:
+            doc["selective_scan"] = selective
         if moved is not None:
             doc["expert_rows"] = moved
         if boots:
@@ -583,6 +614,15 @@ def main(argv=None):
                   "not in this trace" if low is None else "%.4g" % low)
               + (", write strength over 1 at %.0f positions x heads a step"
                  % scan["beta_over_one"] if "beta_over_one" in scan else ""))
+    if selective is not None:
+        low = selective["log_decay_min"]
+        print("selective scan over %d logged steps: %s" % (
+            selective["steps"], selective["scan"])
+              + ", most negative chunk log-decay %s" % (
+                  "not in this trace" if low is None else "%.4g" % low)
+              + (", %s blocks of the score matrix visited a head under the "
+                 "window" % selective["window_blocks"]
+                 if "window_blocks" in selective else ""))
     if moved is not None:
         print("expert layers' rows over %d logged steps: moved by %s, %.0f "
               "of %d buffer rows a step held a pair (%.1f%%)"

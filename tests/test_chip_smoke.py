@@ -66,7 +66,8 @@ def test_kernel_phase_rehearsal():
                         "take_rows_bwd_max_rel_err",
                         "put_rows_slots_written",
                         "block_attention_max_rel_err",
-                        "moe_rows_max_rel_err"}
+                        "moe_rows_max_rel_err",
+                        "selective_scan_max_rel_err"}
 
 
 @pytest.mark.slow

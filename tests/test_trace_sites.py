@@ -853,3 +853,72 @@ def test_log_sync_says_the_convolution_the_scores_and_the_bias(tmp_path,
     assert report.main([str(trace)]) == 0
     assert ("136 of 256 blocks of the score matrix visited a head "
             "(53.1%)") in capsys.readouterr().out
+
+
+def test_log_sync_says_the_selective_scan_and_both_masks_blocks(tmp_path,
+                                                                capsys):
+    """``--model phi4_flash`` says on the span that reads the loss back the
+    form of its selective scan (``mamba_scan``), the step's most negative
+    whole-chunk log-decay (``mamba_chunk_log_decay_min``, a float among the
+    counts) and what makes its masked scores (``attn_scores``, the site it
+    shares with the other decoders; on a TPU the visited blocks of both
+    masks), no ``moe_*`` count, and the report prints its line."""
+    length, vocab, batch = 12, 50, 2
+    cfg = Config(model="phi4_flash", feature_size=vocab, field_size=1,
+                 embedding_size=16, history_max_len=length, decoder_layers=6,
+                 layer_types="mamba,window_attention,mamba,full_attention,"
+                             "gmu,cross_attention", first_layer=14,
+                 attn_window=4, mamba_state=4, mamba_dt_rank=2,
+                 dense_mlp_width=16, attn_q_heads=4, attn_kv_heads=2,
+                 attn_head_dim=8, rms_norm_eps=1e-5, batch_size=batch,
+                 l2_reg=0.0, learning_rate=1e-3, steps_per_loop=1,
+                 log_steps=2, compute_dtype="float32", mesh_data=1,
+                 mesh_model=1)
+    rng = np.random.default_rng(5)
+    batches = [{"feat_ids": np.zeros((batch, 1), np.int32),
+                "feat_vals": np.ones((batch, 1), np.float32),
+                "label": np.zeros((batch, 1), np.float32),
+                "hist_ids": rng.integers(0, vocab, (batch, length)
+                                         ).astype(np.int32),
+                "hist_mask": np.ones((batch, length), np.float32)}
+               for _ in range(4)]
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(cfg)
+    tr.fit(tr.init_state(), batches)
+    syncs = [e["args"] for e in trace_lib._tracer.events()
+             if e["name"] == "train.log_sync"]
+    assert [(a["mamba_scan"], a["attn_scores"]) for a in syncs] == [
+        ("lockstep chunk32/segment512", "xla")] * 2
+    lows = [a["mamba_chunk_log_decay_min"] for a in syncs]
+    assert all(isinstance(x, float) and x < 0 for x in lows)
+    assert not any(k.startswith(("moe_", "kda_", "conv_"))
+                   for a in syncs for k in a)
+    path = str(tmp_path / "trace.json")
+    trace_lib.export(path)
+    report = _report()
+    loaded, _ = report._load(path)
+    assert report.selective_scan(loaded) == {
+        "steps": 2, "scan": "lockstep chunk32/segment512",
+        "log_decay_min": min(lows)}
+    assert report.delta_rule_scan(loaded) is None
+    assert report.expert_rows(loaded) is None
+    assert report.main([path]) == 0
+    assert ("selective scan over 2 logged steps: lockstep "
+            "chunk32/segment512, most negative chunk log-decay") \
+        in capsys.readouterr().out
+    # what a TPU's trace says of both masks on the kernel
+    events = [{"name": "train.log_sync", "ph": "X", "ts": 1.0, "dur": 1.0,
+               "pid": 1, "tid": 1,
+               "args": {"step": 1, "attn_scores": "kernel",
+                        "attn_score_blocks": "136/256",
+                        "attn_window_blocks": "31/256",
+                        "mamba_scan": "kernel steps64",
+                        "mamba_chunk_log_decay_min": -38.5}}]
+    assert report.selective_scan(events) == {
+        "steps": 1, "scan": "kernel steps64",
+        "log_decay_min": -38.5, "window_blocks": "31/256"}
+    trace = tmp_path / "tpu.json"
+    trace.write_text(__import__("json").dumps({"traceEvents": events}))
+    assert report.main([str(trace)]) == 0
+    assert "31/256 blocks of the score matrix visited a head under the " \
+        "window" in capsys.readouterr().out
