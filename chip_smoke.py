@@ -313,6 +313,47 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
         scan_and_grads(by_positions))]
     assert max(errs) <= 1e-4, f"selective scan vs positions: {errs}"
     out["selective_scan_max_rel_err"] = max(errs)
+
+    # the delta-rule scan as the program ships it here
+    # (models/kimi_linear.kda_scan by ``kda_scan_by``'s word: on a TPU the two
+    # kernels of ops/pallas_kda_scan; the rehearsal runs them through the
+    # interpreter) against XLA's chunked form: 512 positions (8 chunks) of
+    # 2 heads x 128, the write strength to 2, a log-decay to -8 a position
+    # (-64 a sub-chunk of 16 in the mean and past it on many channels: the
+    # kernels take every pair's exponent as a sum of log-decays, as XLA's
+    # form does pair by pair); the output and every input's gradient.
+    # Float32 throughout:
+    # the products with the state too, which at the default precision are
+    # one bfloat16 pass on a TPU in either form (the two then differ by
+    # 7e-4 of the output's size; chip, PR 45).
+    length, heads, dim = 512, 2, 128
+    by = kimi_linear.kda_scan_by(length, dim,
+                                 backend="tpu" if interpret else None)
+    assert by == "kernel", by
+
+    def unit(y):
+        return y / np.linalg.norm(y, axis=-1, keepdims=True)
+
+    shape = (1, length, heads, dim)
+    scan_in = tuple(jnp.asarray(x, jnp.float32) for x in (
+        unit(rng.normal(size=shape)) * dim ** -0.5,
+        unit(rng.normal(size=shape)), rng.normal(size=shape),
+        -rng.uniform(0.001, 8.0, shape), rng.uniform(0.0, 2.0, shape[:3])))
+    w_o = jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    def kda_and_grads(**how):
+        def loss(*a):
+            o, _ = kimi_linear.kda_scan(*a, cdt=jnp.dtype("float32"), **how)
+            return jnp.sum(o * w_o), o
+        with jax.default_matmul_precision("highest"):
+            (_, o), g = jax.jit(jax.value_and_grad(
+                loss, argnums=tuple(range(5)), has_aux=True))(*scan_in)
+        return (o, *g)
+
+    errs = [max_rel(g, w) for g, w in zip(
+        kda_and_grads(by=by, interpret=interpret), kda_and_grads())]
+    assert max(errs) <= 1e-4, f"delta-rule scan vs XLA: {errs}"
+    out["kda_scan_max_rel_err"] = max(errs)
     return out
 
 

@@ -50,7 +50,22 @@ log-decay of -1.4 a position on). The backward pass is the forward's,
 differentiated. ``kda_chunk_log_decay_min`` in the model state and the
 step's metrics is the most negative ``G_end`` of the step.
 
-Memory: every layer is recomputed in the backward pass (``jax.checkpoint``).
+**Which form runs where** (``kda_scan_by``: read from the backend, the
+shapes and the mesh; no flag). On a TPU, with the sequence in whole chunks
+of 64, heads of whole 128-lane lines and a step that is one device's
+program, the same chunked mathematics at the same precisions is the two
+Pallas kernels of ``ops/pallas_kda_scan`` (the state, the score matrices
+and the inverse never leave VMEM; the backward kernel is ``jax.vjp`` of the
+chunk, the chunks last to first; ``step_notes``: ``kernel chunk64``). Off a
+TPU, across data replicas, at ragged lengths and narrower heads it is the
+XLA form above (``chunk64/sub16``), which is also what the tests hold the
+kernels to.
+
+Memory: every layer is recomputed in the backward pass (``jax.checkpoint``);
+a KDA layer whose scan is the kernels' keeps what the forward kernel hands
+the backward one (the scan's output, the chunks' entering states and
+inverses: 117 MB a layer at 8 heads x 8,192 positions) and is made again
+around the scan, not through it (``_run_layer``).
 The stack is a Python loop over layers of three shapes, not a scan.
 """
 
@@ -62,6 +77,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import pallas_kda_scan
 from . import common
 from .graph import GraphModel
 from .sdar_moe import (ScoreMask, _dot, _operand, _scores_xla, expert_layer,
@@ -75,9 +91,8 @@ DECAY_MIN = "kda_chunk_log_decay_min"
 #: eigenvalue along k negative): counted where the model's strength can.
 BETA_OVER_ONE = "kda_beta_over_one"
 #: Positions a chunk of the delta-rule scan holds, and a sub-chunk inside
-#: which decays are taken pair by pair.
-KDA_CHUNK = 64
-KDA_SUB = 16
+#: which decays are taken pair by pair (the kernels': their one statement).
+KDA_CHUNK, KDA_SUB = pallas_kda_scan.CHUNK, pallas_kda_scan.SUB
 #: epsilon of the L2 normalisation of q and k
 L2_EPS = 1e-6
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -139,17 +154,42 @@ def _decayed_scores(x: jnp.ndarray, k: jnp.ndarray, gc: jnp.ndarray,
     return jnp.where(sub_of[:, None] == sub_of[None, :], diag, off)
 
 
+def kda_scan_by(length: int, head_dim: int, *, one_device: bool = True,
+                backend: Optional[str] = None) -> str:
+    """``kernel`` where the scan's kernels apply
+    (``ops/pallas_kda_scan.supported``: a TPU backend, positions in whole
+    chunks of 64, heads of whole 128-lane lines; and a step that is one
+    device's program, as ``sdar_moe.attn_scores_by`` asks), else ``xla``:
+    read from the backend, the shapes and the mesh."""
+    return "kernel" if one_device and pallas_kda_scan.supported(
+        length, head_dim, backend) else "xla"
+
+
+def kda_scan_note(by: str) -> str:
+    """What ``step_notes`` says of the scan's form."""
+    return (f"kernel chunk{KDA_CHUNK}" if by == "kernel"
+            else f"chunk{KDA_CHUNK}/sub{KDA_SUB}")
+
+
 @jax.named_scope("kda_scan")
 def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
              beta: jnp.ndarray, *, cdt: jnp.dtype, chunk: int = KDA_CHUNK,
-             sub: int = KDA_SUB) -> Tuple[jnp.ndarray, jnp.ndarray]:
+             sub: int = KDA_SUB, by: str = "xla", interpret: bool = False
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The gated delta-rule recurrence from a zero state, chunk by chunk (the
     module's docstring): q, k, g [B, L, H, Dk], v [B, L, H, Dv], beta
     [B, L, H], float32 -> (o [B, L, H, Dv] float32, the most negative
     cumulative log-decay a chunk held). Decays, the pairwise scores and the
     triangular solve are float32; the products with the state take operands
-    of the compute precision."""
+    of the compute precision. ``by`` is ``kda_scan_by``'s word for what
+    computes it; the kernels take no other ``chunk`` and ``sub`` than these."""
     b, length, h, _ = q.shape
+    if by == "kernel":
+        assert (chunk, sub) == (KDA_CHUNK, KDA_SUB), (chunk, sub)
+        whole = jnp.sum(g.reshape(b, -1, chunk, *g.shape[2:]), axis=2)
+        o = pallas_kda_scan.kda_scan(q, k, v, g, beta, cdt=cdt,
+                                     interpret=interpret)
+        return o, jnp.min(jax.lax.stop_gradient(whole))
     pad = -length % chunk
     n = (length + pad) // chunk
 
@@ -200,7 +240,8 @@ def kda_scan(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, g: jnp.ndarray,
 
 @jax.named_scope("kda")
 def kda_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
-              eps: float, cdt: jnp.dtype, beta_scale: float = 1.0
+              eps: float, cdt: jnp.dtype, beta_scale: float = 1.0,
+              scan_by: str = "xla"
               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The held heads' part of ``KDA(RMSNorm(x))``: x [B, S, d] ->
     ([B, S, d] (``kda_wo``'s sum over the held heads, unreduced), the
@@ -209,7 +250,8 @@ def kda_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
     says how many heads are held. The write strength is ``beta_scale *
     sigmoid(xn kda_w_b)``: 1 keeps the transition ``I - beta k k^T``'s
     eigenvalue along k in (0, 1) (Kimi-Linear), 2 lets it reach -1
-    (``models.solar_open2``: ``kda_allow_neg_eigval``)."""
+    (``models.solar_open2``: ``kda_allow_neg_eigval``). ``scan_by`` is
+    ``kda_scan_by``'s word."""
     b, s, _ = x.shape
     xn = rms_norm(x, lp["norm1"], eps)
 
@@ -235,7 +277,7 @@ def kda_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
         beta = beta_scale * beta
         counts[BETA_OVER_ONE] = jnp.sum(
             jax.lax.stop_gradient(beta) > 1.0, dtype=jnp.int32)
-    o, counts[DECAY_MIN] = kda_scan(q, k, v, g, beta, cdt=cdt)
+    o, counts[DECAY_MIN] = kda_scan(q, k, v, g, beta, cdt=cdt, by=scan_by)
     gate = jax.nn.sigmoid(heads(
         _dot(_dot(xn, lp["kda_w_ga"], cdt), lp["kda_w_gb"], cdt)))
     y = rms_norm(o, lp["kda_out_norm"], eps) * gate
@@ -305,8 +347,7 @@ class KimiLinear(GraphModel):
         #: What the traced step is made of, said beside its counts on
         #: ``train.log_sync`` while tracing is on (``hidden`` adds the expert
         #: layers' ``sdar_moe.moe_notes``).
-        self.step_notes: Dict[str, str] = {
-            "kda_scan": f"chunk{KDA_CHUNK}/sub{KDA_SUB}"}
+        self.step_notes: Dict[str, str] = {}
         self.route_by = functools.partial(
             route, score=jax.nn.sigmoid, scale=cfg.moe_route_scale)
 
@@ -417,16 +458,27 @@ class KimiLinear(GraphModel):
         from what it can see, as ``_layer``'s keywords (``rows_by``; a
         model's further ones go to its ``_mixer``); ``step_notes`` is told."""
         self.step_notes["mla_scores"] = "xla"
-        return {"rows_by": self._rows_by(ids, one_device)}
+        return {"rows_by": self._rows_by(ids, one_device),
+                "scan_by": self._scan_by(ids, one_device)}
 
-    def _mixer(self, mixer: str, lp: Dict[str, jnp.ndarray], x: jnp.ndarray
+    def _scan_by(self, ids: jnp.ndarray, one_device: bool) -> str:
+        """``kda_scan_by``'s word for the step of ``ids`` [B, L];
+        ``step_notes`` is told."""
+        by = kda_scan_by(ids.shape[1], self.cfg.kda_head_dim,
+                         one_device=one_device)
+        self.step_notes["kda_scan"] = kda_scan_note(by)
+        return by
+
+    def _mixer(self, mixer: str, lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
+               scan_by: str = "xla"
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """``Mixer(RMSNorm(x))`` of a layer of kind ``mixer`` -> (the held
-        heads' part, the mixer's counts)."""
+        heads' part, the mixer's counts). ``scan_by``: ``_paths``' word."""
         cfg = self.cfg
         if mixer == "kda":
             return kda_mixer(lp, x, head_dim=cfg.kda_head_dim,
-                             eps=cfg.rms_norm_eps, cdt=self.cdt)
+                             eps=cfg.rms_norm_eps, cdt=self.cdt,
+                             scan_by=scan_by)
         return mla_mixer(lp, x, head_dim=cfg.attn_head_dim,
                          rope_dim=cfg.mla_rope_dim, eps=cfg.rms_norm_eps,
                          cdt=self.cdt), {}
@@ -463,8 +515,14 @@ class KimiLinear(GraphModel):
         earlier layers left; here no layer reads or leaves anything (a model
         whose layers read other layers' tensors hands them through its own:
         ``models.phi4_flash``)."""
+        # (a KDA layer by the kernels keeps its scan's output, entering
+        # states and inverses: the layer is made again around them, the scan
+        # is not)
+        policy = (jax.checkpoint_policies.save_only_these_names(
+            pallas_kda_scan.KEPT) if paths.get("scan_by") == "kernel"
+            else None)
         x, counts = jax.checkpoint(functools.partial(
-            self._layer, *kind, **paths))(x, lp)
+            self._layer, *kind, **paths), policy=policy)(x, lp)
         return x, counts, left
 
     def hidden(self, params: common.Params, ids: jnp.ndarray, *,

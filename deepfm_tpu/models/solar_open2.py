@@ -120,16 +120,17 @@ class SolarOpen2(KimiLinear):
         self.step_notes.update(attn_notes(
             scores_by, causal, seq, cfg.attn_q_heads // cfg.attn_kv_heads))
         return {"rows_by": self._rows_by(ids, one_device),
-                "scores_by": scores_by}
+                "scores_by": scores_by,
+                "scan_by": self._scan_by(ids, one_device)}
 
     def _mixer(self, mixer: str, lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
-               scores_by: str = "xla"
+               scores_by: str = "xla", scan_by: str = "xla"
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         cfg = self.cfg
         if mixer == "kda":
             return kda_mixer(lp, x, head_dim=cfg.kda_head_dim,
                              eps=cfg.rms_norm_eps, cdt=self.cdt,
-                             beta_scale=BETA_SCALE)
+                             beta_scale=BETA_SCALE, scan_by=scan_by)
         return gqa_mixer(lp, x, head_dim=cfg.attn_head_dim,
                          eps=cfg.rms_norm_eps, cdt=self.cdt,
                          scores_by=scores_by), {}

@@ -94,8 +94,11 @@ elementwise passes: ``xla``); the report prints its "gated short
 convolution" line.
 
 Where the model scans a delta-rule recurrence (``--model kimi_linear``,
-``--model solar_open2``), each ``train.log_sync`` carries ``kda_scan`` (the
-algorithm and chunk length of the compiled step: ``chunk64/sub16``),
+``--model solar_open2``), each ``train.log_sync`` carries ``kda_scan`` (what
+computes the compiled step's scan, and its chunk length: ``kernel chunk64``,
+the Pallas kernels of ``ops/pallas_kda_scan.py``, on one TPU at whole
+chunks and 128-lane heads; ``chunk64/sub16``, XLA's chunked form, anywhere
+else: ``kimi_linear.kda_scan_by``),
 Kimi-Linear's ``mla_scores`` (``xla`` / ``kernel``), the step's
 ``kda_chunk_log_decay_min``, the most negative cumulative log-decay a chunk
 held, and, where the write strength reaches 2 (``solar_open2``),

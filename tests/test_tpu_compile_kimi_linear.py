@@ -5,6 +5,8 @@ standing test; and the expert layers' row kernels alone at the two widths
 the decoder cells move (2,304 is this cell's).
 """
 
+import re
+
 import pytest
 
 import jax
@@ -34,6 +36,22 @@ def test_hybrid_decoder_step_at_the_cells_shapes_fits_and_names_its_layers(
     by_op = profiling.hlo_op_scopes(text)
     assert {scope for name, scope in by_op.items() if name.startswith(
         ("moe_take_rows", "moe_add_rows"))} == {"moe"}
+    assert_scan_by_the_kernels(tr, text, by_op, kda_layers=4)
+
+
+def assert_scan_by_the_kernels(tr, text, by_op, kda_layers):
+    """The compiled step's delta-rule scan is two kernel calls a KDA layer
+    under the scope ``kda_scan`` (the forward that keeps the entering states
+    and the inverses, once: the layer's recomputation reads what it kept;
+    and the backward), and nothing of the XLA form's pairwise-decay tensors
+    (``[..., 4, 16, 16, 128]``) is left."""
+    assert tr.model.step_notes["kda_scan"] == "kernel chunk64"
+    calls = {name: scope for name, scope in by_op.items()
+             if name.startswith("kda_scan_")}
+    assert set(calls.values()) == {"kda_scan"}
+    assert sorted(name.split(".")[0] for name in calls) == sorted(
+        ["kda_scan_fwd_keep", "kda_scan_bwd"] * kda_layers)
+    assert not re.search(r"\[[\d,]*4,16,16,128\]", text)
 
 
 @pytest.mark.parametrize("width", [2048, 2304])

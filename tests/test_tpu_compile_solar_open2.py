@@ -11,6 +11,7 @@ import jax
 
 from benchmark import harness
 from deepfm_tpu.utils import profiling
+from test_tpu_compile_kimi_linear import assert_scan_by_the_kernels
 
 
 def test_solar_open2_step_at_the_cells_shapes_fits_beside_its_state(
@@ -35,6 +36,7 @@ def test_solar_open2_step_at_the_cells_shapes_fits_beside_its_state(
             if name.startswith("splash_mqa")} == {"attn_scores"}
     assert {scope for name, scope in by_op.items() if name.startswith(
         ("moe_take_rows", "moe_add_rows"))} == {"moe"}
+    assert_scan_by_the_kernels(tr, text, by_op, kda_layers=3)
     # a float32 array of a large parameter's shape is never copied
     shapes, _ = jax.eval_shape(tr.model.init, jax.random.PRNGKey(0))
     large = {",".join(map(str, x.shape)) for x in jax.tree.leaves(shapes)

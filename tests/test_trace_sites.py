@@ -717,6 +717,42 @@ def test_log_sync_says_how_the_delta_rule_scan_is_computed(tmp_path, capsys):
         [{"name": "train.log_sync", "ph": "X", "args": {"step": 2}}]) is None
 
 
+@pytest.mark.parametrize("model, seq, backend, note", [
+    ("kimi_linear", 128, "tpu", "kernel chunk64"),
+    ("solar_open2", 128, "tpu", "kernel chunk64"),
+    ("kimi_linear", 100, "tpu", "chunk64/sub16"),
+    ("solar_open2", 128, "cpu", "chunk64/sub16")])
+def test_the_scans_note_follows_the_backend_and_the_length(
+        monkeypatch, model, seq, backend, note):
+    """``kda_scan`` among ``step_notes`` is ``kimi_linear.kda_scan_by``'s
+    word: the kernels on a TPU at whole chunks and heads of 128-lane lines,
+    one device; the XLA form off a TPU, at a ragged length and across data
+    replicas."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepfm_tpu.models import get_model
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    own = {"kimi_linear": dict(dense_layers=1, mla_latent_dim=8,
+                               mla_rope_dim=4, dense_mlp_width=16),
+           "solar_open2": {}}[model]
+    cfg = Config(model=model, feature_size=50, field_size=1,
+                 embedding_size=256, history_max_len=seq, decoder_layers=2,
+                 attn_every=2, kda_heads=2, kda_head_dim=128,
+                 attn_q_heads=2, attn_kv_heads=2, attn_head_dim=8,
+                 moe_experts=4, moe_top_k=2, moe_expert_width=8,
+                 moe_shared_width=8, moe_experts_held=2, moe_first_expert=0,
+                 moe_pair_capacity=2 * seq * 2, batch_size=2,
+                 compute_dtype="float32", mesh_data=1, mesh_model=1, **own)
+    built = get_model(cfg)
+    ids = jnp.zeros((2, seq), jnp.int32)
+    assert built._paths(ids, one_device=True)["scan_by"] == (
+        "kernel" if note.startswith("kernel") else "xla")
+    assert built.step_notes["kda_scan"] == note
+    assert built._paths(ids, one_device=False)["scan_by"] == "xla"
+    assert built.step_notes["kda_scan"] == "chunk64/sub16"
+
+
 def test_log_sync_says_the_causal_score_path_and_the_write_strength(
         tmp_path, capsys):
     """``--model solar_open2`` says on the span that reads the loss back
