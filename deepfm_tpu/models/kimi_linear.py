@@ -65,17 +65,27 @@ Memory: every layer is recomputed in the backward pass (``jax.checkpoint``);
 a KDA layer whose scan is the kernels' keeps what the forward kernel hands
 the backward one (the scan's output, the chunks' entering states and
 inverses: 117 MB a layer at 8 heads x 8,192 positions) and is made again
-around the scan, not through it (``_run_layer``).
+around the scan, not through it (``_run_layer``). A layer also keeps the
+float32 first products of its dense SwiGLU (``swiglu``'s gate and up
+products, named ``MLP_KEPT``: 8 bytes a position and unit of width) where
+the device's memory holds them: the backward pass reads them where it would
+make them again, and makes SiLU, the gate's product with ``up`` and the down
+product's operand again from them. How many layers keep is
+``mlp_kept_by``'s, from the last layer back, read from the device's memory
+limit, the parameters' bytes and the step's positions (no flag;
+``step_notes``: ``mlp_kept``, ``6/6 layers 4.03 GB``); off a TPU, or where
+the device says nothing of its memory, no layer keeps anything.
 The stack is a Python loop over layers of three shapes, not a scan.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import pallas_kda_scan
 from . import common
@@ -95,6 +105,29 @@ BETA_OVER_ONE = "kda_beta_over_one"
 KDA_CHUNK, KDA_SUB = pallas_kda_scan.CHUNK, pallas_kda_scan.SUB
 #: epsilon of the L2 normalisation of q and k
 L2_EPS = 1e-6
+#: ``checkpoint_name`` of a dense SwiGLU's first products (``swiglu``'s gate
+#: and up products, ``phi4_flash.mlp``'s one ``[gate | up]`` product; the
+#: float32 results as SiLU reads them): a layer whose ``jax.checkpoint``
+#: saves the name reads them in the backward pass and does not make them
+#: again.
+MLP_KEPT = "mlp_first_products"
+#: Parameter-shaped arrays that ``train.optimizers.build_optimizer``'s state
+#: holds beside each parameter (a test holds this to the states themselves).
+OPTIMIZER_COPIES = {"adam": 2, "ftrl": 2, "adagrad": 1, "momentum": 1,
+                    "sgd": 0}
+#: Bytes a position of the step that ``mlp_kept_by`` leaves free of kept
+#: products: the room of a layer's working set, which follows the step's
+#: positions. Set from four steps compiled for a v5e (``memory_analysis()``,
+#: PERF.md section 6, PR 47; 16,909,336,064 bytes of memory): what a step
+#: that keeps nothing holds beyond 16 bytes a parameter is 1.26 GB (Phi-4-
+#: flash) and 1.12 GB (Solar-Open2) at 8,192 positions, 2.06 GB
+#: (Kimi-Linear) and 2.22 GB (LFM2) at 16,384: 126 to 153 KB a position.
+#: 200 KiB is a third above the largest, and inside the one window the
+#: Phi-4-flash stack leaves: its six layers keep all six products (4.03 GB;
+#: the step compiles to 15.11 GB) at anything up to 206 KiB, and the same
+#: stack of eight layers (14.65 GB of parameters, moments and gradients),
+#: which compiles today and must go on compiling, keeps none from 190 KiB.
+MLP_KEEP_RESERVE = 200 * 1024
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -169,6 +202,55 @@ def kda_scan_note(by: str) -> str:
     """What ``step_notes`` says of the scan's form."""
     return (f"kernel chunk{KDA_CHUNK}" if by == "kernel"
             else f"chunk{KDA_CHUNK}/sub{KDA_SUB}")
+
+
+def device_memory_bytes() -> int:
+    """The memory limit of the device a step is traced for, as the backend
+    says it (``memory_stats()["bytes_limit"]``); 0 off a TPU and where the
+    device says nothing of its memory."""
+    if jax.default_backend() != "tpu":
+        return 0
+    return int((jax.local_devices()[0].memory_stats() or {}).get(
+        "bytes_limit", 0))
+
+
+def mlp_kept_by(layer_bytes: Sequence[int], *, positions: int, limit: int,
+                held: int) -> int:
+    """How many of the stack's SwiGLUs keep their first products for the
+    backward pass, counted from the last layer back (the last layer's are
+    freed first, while the layers' gradients come to life): ``layer_bytes``
+    what each would keep, first layer first; ``limit`` the device's memory
+    (``device_memory_bytes``; 0: nothing is known, nothing is kept);
+    ``held`` what the step holds without them (``KimiLinear._held_bytes``);
+    ``positions`` the step's. A layer keeps while what is left of the
+    memory stays above ``MLP_KEEP_RESERVE`` bytes a position, the room of a
+    layer's working set."""
+    if limit <= 0:
+        return 0
+    room = limit - held - MLP_KEEP_RESERVE * positions
+    kept = 0
+    for size in reversed(layer_bytes):
+        if not 0 < size <= room:
+            break
+        room -= size
+        kept += 1
+    return kept
+
+
+def mlp_kept_note(kept: int, layers: int, kept_bytes: int) -> str:
+    """What ``step_notes`` says of the SwiGLUs' first products."""
+    return f"{kept}/{layers}" + (
+        f" layers {kept_bytes / 1e9:.2f} GB" if kept else "")
+
+
+def layer_policy(keeps: Dict[str, bool]):
+    """The ``jax.checkpoint`` policy of a layer that keeps what carries a
+    name (``checkpoint_name``) that ``keeps`` says yes to and makes
+    everything else again; None, a layer that keeps nothing, where it says
+    yes to none."""
+    names = [name for name, keep in keeps.items() if keep]
+    return (jax.checkpoint_policies.save_only_these_names(*names)
+            if names else None)
 
 
 @jax.named_scope("kda_scan")
@@ -318,11 +400,11 @@ def swiglu(lp: Dict[str, jnp.ndarray], prefix: str, x: jnp.ndarray, *,
            eps: float, cdt: jnp.dtype) -> jnp.ndarray:
     """``E(RMSNorm(x; norm2))``, ``E(x) = (SiLU(x W_g) * x W_u) W_d``, whole
     on every chip: the dense MLP (``mlp_``) or the shared expert
-    (``shared_``)."""
+    (``shared_``). The two first products carry ``MLP_KEPT``."""
     xn = rms_norm(x, lp["norm2"], eps)
-    mid = jax.nn.silu(_dot(xn, lp[prefix + "w_gate"], cdt)) \
-        * _dot(xn, lp[prefix + "w_up"], cdt)
-    return _dot(mid, lp[prefix + "w_down"], cdt)
+    gate, up = (checkpoint_name(_dot(xn, lp[prefix + w], cdt), MLP_KEPT)
+                for w in ("w_gate", "w_up"))
+    return _dot(jax.nn.silu(gate) * up, lp[prefix + "w_down"], cdt)
 
 
 class KimiLinear(GraphModel):
@@ -469,6 +551,37 @@ class KimiLinear(GraphModel):
         self.step_notes["kda_scan"] = kda_scan_note(by)
         return by
 
+    def _held_bytes(self, params: common.Params) -> int:
+        """What a train step holds on a device whatever its layers keep,
+        from what ``hidden`` can see: the parameters as it is handed them,
+        as many more copies as the optimizer's state holds
+        (``OPTIMIZER_COPIES``) and one of gradients. (Not the bytes resident
+        when the step is traced: ``Trainer.step_compiled`` traces from
+        shapes, and a step traced twice has to be one program.)"""
+        leaves = sum(x.size * x.dtype.itemsize
+                     for x in jax.tree.leaves(params))
+        return leaves * (2 + OPTIMIZER_COPIES[self.cfg.optimizer.lower()])
+
+    def _mlp_keeps(self, params: common.Params, ids: jnp.ndarray
+                   ) -> Tuple[bool, ...]:
+        """Whether each layer of the step of ``ids`` [B, L] keeps its
+        SwiGLU's first products for the backward pass (``mlp_kept_by``; a
+        layer without a dense SwiGLU has none to keep); ``step_notes`` is
+        told."""
+        cfg = self.cfg
+        # float32 gate and up products a position
+        sizes = [8 * ids.size * (cfg.dense_mlp_width if ffn == "mlp"
+                                 else cfg.moe_shared_width)
+                 for _, ffn in self.kinds]
+        have = [i for i, size in enumerate(sizes) if size]
+        kept = mlp_kept_by([sizes[i] for i in have], positions=ids.size,
+                           limit=device_memory_bytes(),
+                           held=self._held_bytes(params))
+        keeping = have[len(have) - kept:]
+        self.step_notes["mlp_kept"] = mlp_kept_note(
+            kept, len(have), sum(sizes[i] for i in keeping))
+        return tuple(i in keeping for i in range(len(sizes)))
+
     def _mixer(self, mixer: str, lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
                scan_by: str = "xla"
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
@@ -508,19 +621,21 @@ class KimiLinear(GraphModel):
 
     def _run_layer(self, i: int, kind: Tuple[str, str], x: jnp.ndarray,
                    lp: Dict[str, jnp.ndarray], left: Dict[str, jnp.ndarray],
-                   paths: Dict[str, str]):
+                   paths: Dict[str, str], keep_mlp: bool = False):
         """Layer ``i`` (of kind ``kind``, leaves ``lp``), made again in the
         backward pass -> (the stream, the layer's counts, what the layers so
         far leave for later ones to read, by name). ``left`` is what the
         earlier layers left; here no layer reads or leaves anything (a model
         whose layers read other layers' tensors hands them through its own:
-        ``models.phi4_flash``)."""
+        ``models.phi4_flash``). ``keep_mlp``: ``_mlp_keeps``' word for this
+        layer."""
         # (a KDA layer by the kernels keeps its scan's output, entering
         # states and inverses: the layer is made again around them, the scan
-        # is not)
-        policy = (jax.checkpoint_policies.save_only_these_names(
-            pallas_kda_scan.KEPT) if paths.get("scan_by") == "kernel"
-            else None)
+        # is not; a layer that keeps its SwiGLU's first products is made
+        # again around those too)
+        policy = layer_policy({
+            pallas_kda_scan.KEPT: paths.get("scan_by") == "kernel",
+            MLP_KEPT: keep_mlp})
         x, counts = jax.checkpoint(functools.partial(
             self._layer, *kind, **paths), policy=policy)(x, lp)
         return x, counts, left
@@ -535,13 +650,14 @@ class KimiLinear(GraphModel):
         counts: sums, the ``_max`` ones' largest, the decay's least).
         ``data_axis`` names the mesh axis of a step across data replicas."""
         paths = self._paths(ids, one_device=data_axis is None)
+        keeps = self._mlp_keeps(params, ids)
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
         seen: Dict[str, list] = {}
         left: Dict[str, jnp.ndarray] = {}
         for i, kind in enumerate(self.kinds):
             x, counts, left = self._run_layer(
-                i, kind, x, params["layers"][str(i)], left, paths)
+                i, kind, x, params["layers"][str(i)], left, paths, keeps[i])
             for name, value in counts.items():
                 seen.setdefault(name, []).append(value)
         return x, {name: _merged(name, jnp.min, jnp.max, jnp.sum)(

@@ -78,10 +78,12 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import pallas_selective_scan
 from . import common
-from .kimi_linear import KimiLinear, causal, causal_conv
+from .kimi_linear import (MLP_KEPT, KimiLinear, causal, causal_conv,
+                          layer_policy)
 from .sdar_moe import (ScoreMask, _dot, _operand, attn_notes, attn_scores_by,
                        masked_scores, rms_norm)
 
@@ -293,9 +295,11 @@ def diff_attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
 def mlp(lp: Dict[str, jnp.ndarray], h: jnp.ndarray, *, eps: float,
         cdt: jnp.dtype) -> jnp.ndarray:
     """``(SiLU(u W_g) * u W_u) W_2`` of ``u = LN2(h)``, ``[W_g | W_u]`` one
-    matrix, the gate half first."""
+    matrix, the gate half first. The first product carries
+    ``kimi_linear.MLP_KEPT``."""
     hn = layer_norm(h, lp["norm2"], lp["norm2_b"], eps)
-    gate, up = jnp.split(_dot(hn, lp["mlp_w_gate_up"], cdt), 2, axis=-1)
+    gate, up = jnp.split(checkpoint_name(
+        _dot(hn, lp["mlp_w_gate_up"], cdt), MLP_KEPT), 2, axis=-1)
     return _dot(jax.nn.silu(gate) * up, lp["mlp_w_down"], cdt)
 
 
@@ -430,11 +434,12 @@ class Phi4Flash(KimiLinear):
 
     def _run_layer(self, i: int, kind: Tuple[str, str], x: jnp.ndarray,
                    lp: Dict[str, jnp.ndarray], left: Dict[str, jnp.ndarray],
-                   paths: Dict[str, str]):
+                   paths: Dict[str, str], keep_mlp: bool = False):
         read = {name: left[name] for name in READS.get(kind[0], ())}
-        x, counts, leaves = jax.checkpoint(functools.partial(
-            self._layer, *kind, layer=self.cfg.first_layer + i, **paths))(
-                x, lp, read)
+        x, counts, leaves = jax.checkpoint(
+            functools.partial(self._layer, *kind,
+                              layer=self.cfg.first_layer + i, **paths),
+            policy=layer_policy({MLP_KEPT: keep_mlp}))(x, lp, read)
         return x, counts, {**left, **leaves}
 
     @jax.named_scope("head")

@@ -21,8 +21,13 @@ scatter-add of the replica's own rows into a fill fused with it, outside the
 trips' ``while``, and an ``all-reduce`` whose tuple holds that
 ``f32[16881344]`` (one read and one write of a 67.5 MB vector, 1/32 of a
 pass: not a pass over the 2.16 GB table); no ``[16881344,32]`` collective
-(``PERF.md`` §6, PR 41). Last, the step's ``memory_analysis()``. It takes
-~20 s and says nothing about time: times are the chip's
+(``PERF.md`` §6, PR 41). Last, what the model says its traced step is made
+of (``step_notes``) and the step's ``memory_analysis()``. A described device
+says nothing of its memory, so what the program reads from the device
+(``kimi_linear.device_memory_bytes``: how many layers keep their SwiGLU's
+first products) is described here too, ``BYTES_LIMIT`` by device kind. It
+takes ~20 s (a decoder cell's step one to two minutes) and says nothing
+about time: times are the chip's
 (``benchmark/run.py --trace 1``; its ``breakdown`` names the same ops).
 
 Usage (from the repo root; keep ``JAX_PLATFORMS=cpu``):
@@ -40,13 +45,18 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 ".."))
 
 
+#: ``memory_stats()["bytes_limit"]`` by ``device_kind`` (my chip run, PR 47).
+BYTES_LIMIT = {"TPU v5 lite": 16_909_336_064}
+
+
 def compile_step(workload: str, topology: str, chips: int) -> tuple:
-    """(the cell's compiled dispatch, table height)."""
+    """(the cell's compiled dispatch, table height, the model's notes)."""
     import jax
     from jax.experimental import topologies
 
     from benchmark import harness
     from benchmark.drivers import _program
+    from deepfm_tpu.models import kimi_linear
 
     # A described device cannot read an executable back from the cache.
     jax.config.update("jax_enable_compilation_cache", False)
@@ -56,10 +66,13 @@ def compile_step(workload: str, topology: str, chips: int) -> tuple:
     jax.default_backend = lambda: "tpu"
     cell = harness.load_cell(workload)
     devices = list(topo.devices)[:chips or cell.chips]
+    limit = BYTES_LIMIT[devices[0].device_kind]
+    kimi_linear.device_memory_bytes = lambda: limit
     trainer = _program.build_trainer(
         _program.make_config(dict(cell.config["flags"])), devices)
     return (trainer.step_compiled(device=devices[0]),
-            int(trainer.model.padded_vocab))
+            int(trainer.model.padded_vocab),
+            dict(getattr(trainer.model, "step_notes", {})))
 
 
 def main(argv=None) -> int:
@@ -74,7 +87,8 @@ def main(argv=None) -> int:
 
     from deepfm_tpu.utils import profiling
 
-    compiled, rows = compile_step(args.workload, args.topology, args.chips)
+    compiled, rows, notes = compile_step(args.workload, args.topology,
+                                         args.chips)
     text, memory = compiled.as_text(), compiled.memory_analysis()
     if args.hlo_out:
         with open(args.hlo_out, "w") as f:
@@ -96,6 +110,9 @@ def main(argv=None) -> int:
             "*" if o["loop_body"] else " ", "R" if o in row_writes else " ",
             o["name"], o["opcode"], " ".join(o["results"]), operands,
             in_place, o["scope"] or "-", o["primitive"]))
+    if notes:
+        print("step_notes: " + ", ".join(
+            f"{k} {v}" for k, v in sorted(notes.items())))
     print("memory_analysis: arguments %.3f GB, outputs %.3f GB (aliased "
           "%.3f), temporaries %.3f GB" % tuple(
               getattr(memory, f"{k}_size_in_bytes") / 1e9

@@ -114,6 +114,15 @@ beside ``attn_scores`` / ``attn_score_blocks`` (the causal layers')
 ``attn_window_blocks``, the blocks the kernel visits under the window; the
 report prints one "selective scan" line.
 
+Where the model has dense SwiGLUs (a dense MLP or a shared expert:
+``--model kimi_linear``, ``solar_open2``, ``lfm2_moe``, ``phi4_flash``), each
+``train.log_sync`` carries ``mlp_kept``: how many of those layers keep their
+float32 first products for the backward pass and the bytes kept
+(``6/6 layers 4.03 GB``; ``0/5`` where the device's memory holds none, off a
+TPU or where the device says nothing of its memory:
+``kimi_linear.mlp_kept_by``); the report prints one "dense SwiGLUs" line
+(TUNING §17).
+
 Usage:
     python scripts/trace_report.py TRACE.json [--top 20] [--json]
                                               [--stalls MS]
@@ -483,6 +492,17 @@ def selective_scan(events):
     return out
 
 
+def kept_products(events):
+    """What the dense SwiGLUs keep for the backward pass, off the
+    ``train.log_sync`` spans that say so: ``steps`` read and ``kept``
+    (``mlp_kept``: layers keeping of layers that could, and the bytes);
+    None when no span says (another model, or an older trace)."""
+    seen = _log_syncs(events, "mlp_kept")
+    if not seen:
+        return None
+    return {"steps": len(seen), "kept": seen[-1]["mlp_kept"]}
+
+
 def expert_rows(events):
     """How the expert layers' rows moved, off the ``train.log_sync`` spans
     that say so: ``steps`` read, ``rows`` (``moe_rows``: ``kernel``, one copy
@@ -526,6 +546,7 @@ def main(argv=None):
     scan = delta_rule_scan(events)
     conv = short_convolution(events)
     selective = selective_scan(events)
+    kept = kept_products(events)
     moved = expert_rows(events)
     boots = start_up(events)
 
@@ -546,6 +567,8 @@ def main(argv=None):
             doc["short_convolution"] = conv
         if selective is not None:
             doc["selective_scan"] = selective
+        if kept is not None:
+            doc["kept_products"] = kept
         if moved is not None:
             doc["expert_rows"] = moved
         if boots:
@@ -626,6 +649,9 @@ def main(argv=None):
               + (", %s blocks of the score matrix visited a head under the "
                  "window" % selective["window_blocks"]
                  if "window_blocks" in selective else ""))
+    if kept is not None:
+        print("dense SwiGLUs over %d logged steps: first products kept for "
+              "the backward pass in %s" % (kept["steps"], kept["kept"]))
     if moved is not None:
         print("expert layers' rows over %d logged steps: moved by %s, %.0f "
               "of %d buffer rows a step held a pair (%.1f%%)"

@@ -395,17 +395,26 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
+#: ``memory_stats()["bytes_limit"]`` of a v5e (my chip run, PR 47): a
+#: described device says nothing of its memory.
+V5E_BYTES_LIMIT = 16_909_336_064
+
+
 @pytest.fixture()
 def step_for_v5e(v5e, no_compile_cache, compiled_once, monkeypatch):
     """``flags -> (trainer, its step compiled for the described chip,
     trainer.step_hlo_text())``: one compilation a call, whatever the test
     reads of it."""
     from deepfm_tpu.config import Config
+    from deepfm_tpu.models import kimi_linear
     from deepfm_tpu.parallel import mesh as mesh_lib
     from deepfm_tpu.train import Trainer
 
-    # the trainer picks its kernels by backend: trace what a TPU host would
+    # the trainer picks its kernels by backend: trace what a TPU host would,
+    # with the chip's memory described to what asks for it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kimi_linear, "device_memory_bytes",
+                        lambda: V5E_BYTES_LIMIT)
 
     def build(flags):
         cfg = Config(**flags)

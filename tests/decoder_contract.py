@@ -29,6 +29,7 @@ exactly that one. (Not collected by name: no ``test_`` in the file's.)"""
 import dataclasses
 import functools
 import os
+import re
 import sys
 from types import SimpleNamespace
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -81,6 +82,16 @@ def off_one(key, tree):
     return jax.tree.unflatten(treedef, [
         x + 0.1 * jax.random.normal(k, x.shape)
         if bool(jnp.all(x == 1.0)) else x for k, x in zip(keys, leaves)])
+
+
+def products_in_scope(text: str, scope: str):
+    """(matrix products, those of them made again in the backward pass) of a
+    compiled TPU step's text under the named scope ``scope``: the
+    ``convolution`` instructions whose ``op_name`` has the scope, and among
+    them ``jax.checkpoint``'s ``rematted_computation``."""
+    lines = [line for line in text.splitlines() if " convolution(" in line
+             and re.search(r'op_name="[^"]*[/(]%s[/)]' % scope, line)]
+    return len(lines), sum("rematted_computation" in line for line in lines)
 
 
 def cut_columns(a, first, n, heads, per):

@@ -88,6 +88,9 @@ SPEC = Spec(
     no_scopes=frozenset({"kda", "kda_scan"}),
     notes=lambda trainer: {
         "conv_taps_by": "xla", "attn_scores": "xla", "moe_rows": "xla",
+        # the dense layers' SwiGLUs (no shared expert): none kept off a TPU
+        "mlp_kept": "0/%d" % sum(
+            ffn == "mlp" for _, ffn in trainer.model.kinds),
         "moe_rows_moved": "{moe_pairs_held}/%d" % (2 * 2 * B * L)},
     kinds=KINDS,
     layer_counts={"moe_pairs_held": "moe", sdar_moe.BIAS_MOVED: "moe"},

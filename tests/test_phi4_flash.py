@@ -62,7 +62,8 @@ SPEC = Spec(
     no_scopes=frozenset({"fm", "tower", "cross", "bottom", "moe", "kda",
                          "conv"}),
     notes=lambda trainer: {"mamba_scan": "lockstep chunk4/segment8",
-                           "attn_scores": "xla"},
+                           "attn_scores": "xla",
+                           "mlp_kept": "0/%d" % len(trainer.model.kinds)},
     refusals=(
         ({"layer_types": "mamba,gmu"}, "layer_types"),
         ({"layer_types": CUT.replace("gmu", "conv")}, "layer_types"),
@@ -419,10 +420,10 @@ def drop_cotangent(monkeypatch, names):
     """What layers leave under ``names`` handed on without its cotangent."""
     whole = phi4_flash.Phi4Flash._run_layer
 
-    def broken(self, i, kind, x, lp, left, paths):
+    def broken(self, i, kind, x, lp, left, *rest):
         return whole(self, i, kind, x, lp, {
             k: jax.lax.stop_gradient(v) if k in names else v
-            for k, v in left.items()}, paths)
+            for k, v in left.items()}, *rest)
     monkeypatch.setattr(phi4_flash.Phi4Flash, "_run_layer", broken)
 
 

@@ -9,6 +9,7 @@ import re
 import jax
 
 from benchmark import harness
+from decoder_contract import products_in_scope
 from deepfm_tpu.utils import profiling
 
 
@@ -22,13 +23,18 @@ def test_lfm2_step_at_the_cells_shapes_takes_the_kernels_at_64_lanes(
     kernels, ops charged to each of the model's scopes (``conv``,
     ``conv_taps`` and ``attn_scores`` among them), and arguments and
     temporaries together under the issue's 15.5 GB (measured here: 6.094 +
-    4.254 GB; with the kernel refused 6.094 + 8.941)."""
+    4.254 GB; with the kernel refused 6.094 + 8.941) **with the one dense
+    MLP keeping its first products** (the chip's memory described to
+    ``kimi_linear.mlp_kept_by``: 0.94 GB; no layer has a shared expert):
+    nine products under ``mlp``, none made again."""
     tr, compiled, text = step_for_v5e(
         harness.load_json("configs", "lfm2-8b-a1b.json")["flags"])
     notes = tr.model.step_notes
     assert (notes["attn_scores"], notes["attn_score_blocks"],
             notes["moe_rows"], notes["conv_taps_by"]) == (
                 "kernel", "136/256", "kernel", "xla")
+    assert notes["mlp_kept"] == "1/1 layers 0.94 GB"
+    assert products_in_scope(text, "mlp") == (9, 0)
     by_op = profiling.hlo_op_scopes(text)
     assert {"embed", "conv", "conv_taps", "attn", "attn_scores", "mlp",
             "moe", "head", "opt"} <= set(by_op.values())

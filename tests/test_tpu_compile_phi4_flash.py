@@ -11,6 +11,7 @@ import jax
 import pytest
 
 from benchmark import harness
+from decoder_contract import products_in_scope
 from deepfm_tpu.utils import profiling
 
 
@@ -24,7 +25,13 @@ def test_phi4_flash_step_at_the_cells_shapes_fits_and_takes_the_kernels(
     model's scopes (``mamba``, ``mamba_scan`` and ``gmu`` among them), no
     whole-sequence scan state
     (``[8192, 16, 5120]`` float32 would be 2.7 GB), and arguments and
-    temporaries together under the issue's 15.5 GB."""
+    temporaries together under the issue's 15.5 GB **with every layer
+    keeping its MLP's first product** (the chip's memory described to
+    ``kimi_linear.mlp_kept_by``: six of 671 MB; 8.366 + 6.739 GB, and 4.043
+    GB of temporaries where none keeps): the scope ``mlp`` holds six
+    products a layer (the first and the down product forward, four
+    backward) and none made again; a layer that keeps nothing holds the
+    first product a third time."""
     tr, compiled, text = step_for_v5e(
         harness.load_json("configs",
                           "phi-4-mini-flash-reasoning.json")["flags"])
@@ -32,6 +39,8 @@ def test_phi4_flash_step_at_the_cells_shapes_fits_and_takes_the_kernels(
     assert (notes["attn_scores"], notes["attn_window_blocks"],
             notes["attn_score_blocks"]) == ("kernel", "31/256", "136/256")
     assert notes["mamba_scan"] == "kernel steps64"
+    assert notes["mlp_kept"] == "6/6 layers 4.03 GB"
+    assert products_in_scope(text, "mlp") == (6 * 6, 0)
     by_op = profiling.hlo_op_scopes(text)
     assert {"embed", "mamba", "mamba_scan", "gmu", "attn", "attn_scores",
             "mlp", "head", "opt"} <= set(by_op.values())

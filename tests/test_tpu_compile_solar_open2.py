@@ -10,6 +10,7 @@ import numpy as np
 import jax
 
 from benchmark import harness
+from decoder_contract import products_in_scope
 from deepfm_tpu.utils import profiling
 from test_tpu_compile_kimi_linear import assert_scan_by_the_kernels
 
@@ -23,12 +24,17 @@ def test_solar_open2_step_at_the_cells_shapes_fits_beside_its_state(
     the model's scopes (``attn_scores`` among them), no float32 copy of an
     array as large as a parameter, and the 10.09 GB of weights and moments
     with the step's temporaries under the chip's 16 GB (measured here:
-    10.090 + 5.191 GB)."""
+    10.090 + 5.191 GB) **with the four shared experts keeping their first
+    products** (the chip's memory described to ``kimi_linear.mlp_kept_by``:
+    4 x 84 MB): nine products a shared expert under ``mlp``, none made
+    again."""
     tr, compiled, text = step_for_v5e(
         harness.load_json("configs", "solar-open2-250b.json")["flags"])
     notes = tr.model.step_notes
     assert (notes["attn_scores"], notes["attn_score_blocks"],
             notes["moe_rows"]) == ("kernel", "136/256", "kernel")
+    assert notes["mlp_kept"] == "4/4 layers 0.34 GB"
+    assert products_in_scope(text, "mlp") == (4 * 9, 0)
     by_op = profiling.hlo_op_scopes(text)
     assert {"embed", "attn", "attn_scores", "kda", "kda_scan", "mlp", "moe",
             "head", "opt"} <= set(by_op.values())

@@ -692,6 +692,9 @@ def test_log_sync_says_how_the_delta_rule_scan_is_computed(tmp_path, capsys):
              if e["name"] == "train.log_sync"]
     assert [(a["kda_scan"], a["mla_scores"]) for a in syncs] == [
         ("chunk64/sub16", "xla")] * 2
+    # the dense MLP's and the shared expert's first products: off a TPU
+    # no layer keeps them
+    assert [a["mlp_kept"] for a in syncs] == ["0/2"] * 2
     lows = [a["kda_chunk_log_decay_min"] for a in syncs]
     assert all(isinstance(x, float) and x < 0 for x in lows)
     assert all(isinstance(a["moe_pairs_held"], int) for a in syncs)
@@ -703,10 +706,14 @@ def test_log_sync_says_how_the_delta_rule_scan_is_computed(tmp_path, capsys):
         "steps": 2, "scan": "chunk64/sub16", "mla_scores": "xla",
         "log_decay_min": pytest.approx(min(lows))}
     assert report.attention_scores(loaded) is None
+    assert report.kept_products(loaded) == {"steps": 2, "kept": "0/2"}
     assert report.main([path]) == 0
+    out = capsys.readouterr().out
     assert ("delta-rule scan over 2 logged steps: chunk64/sub16, latent "
             "attention's scores by xla, most negative chunk log-decay "
-            ) in capsys.readouterr().out
+            ) in out
+    assert ("dense SwiGLUs over 2 logged steps: first products kept for the "
+            "backward pass in 0/2") in out
     # a trace that predates the count, and one of another model
     old = [{"name": "train.log_sync", "ph": "X",
             "args": {"step": 2, "kda_scan": "chunk64/sub16"}}]
@@ -715,6 +722,7 @@ def test_log_sync_says_how_the_delta_rule_scan_is_computed(tmp_path, capsys):
         "log_decay_min": None}
     assert report.delta_rule_scan(
         [{"name": "train.log_sync", "ph": "X", "args": {"step": 2}}]) is None
+    assert report.kept_products(old) is None
 
 
 @pytest.mark.parametrize("model, seq, backend, note", [
@@ -787,6 +795,7 @@ def test_log_sync_says_the_causal_score_path_and_the_write_strength(
              if e["name"] == "train.log_sync"]
     assert [(a["kda_scan"], a["attn_scores"]) for a in syncs] == [
         ("chunk64/sub16", "xla")] * 2
+    assert [a["mlp_kept"] for a in syncs] == ["0/2"] * 2   # shared experts
     assert all("mla_scores" not in a and "attn_score_blocks" not in a
                for a in syncs)
     over = [a["kda_beta_over_one"] for a in syncs]
@@ -857,6 +866,8 @@ def test_log_sync_says_the_convolution_the_scores_and_the_bias(tmp_path,
              if e["name"] == "train.log_sync"]
     assert [(a["conv_taps_by"], a["attn_scores"]) for a in syncs] == [
         ("xla", "xla")] * 2
+    # one dense layer, no shared expert beside the two expert layers
+    assert [a["mlp_kept"] for a in syncs] == ["0/1"] * 2
     assert all(k not in a for a in syncs for k in (
         "kda_scan", "mla_scores", lfm2_moe.SELECT_BIAS))
     picks = [a["moe_bias_moved_picks"] for a in syncs]
@@ -925,6 +936,7 @@ def test_log_sync_says_the_selective_scan_and_both_masks_blocks(tmp_path,
              if e["name"] == "train.log_sync"]
     assert [(a["mamba_scan"], a["attn_scores"]) for a in syncs] == [
         ("lockstep chunk32/segment512", "xla")] * 2
+    assert [a["mlp_kept"] for a in syncs] == ["0/6"] * 2
     lows = [a["mamba_chunk_log_decay_min"] for a in syncs]
     assert all(isinstance(x, float) and x < 0 for x in lows)
     assert not any(k.startswith(("moe_", "kda_", "conv_"))
@@ -949,12 +961,18 @@ def test_log_sync_says_the_selective_scan_and_both_masks_blocks(tmp_path,
                         "attn_score_blocks": "136/256",
                         "attn_window_blocks": "31/256",
                         "mamba_scan": "kernel steps64",
-                        "mamba_chunk_log_decay_min": -38.5}}]
+                        "mamba_chunk_log_decay_min": -38.5,
+                        "mlp_kept": "6/6 layers 4.03 GB"}}]
     assert report.selective_scan(events) == {
         "steps": 1, "scan": "kernel steps64",
         "log_decay_min": -38.5, "window_blocks": "31/256"}
+    assert report.kept_products(events) == {
+        "steps": 1, "kept": "6/6 layers 4.03 GB"}
     trace = tmp_path / "tpu.json"
     trace.write_text(__import__("json").dumps({"traceEvents": events}))
     assert report.main([str(trace)]) == 0
+    out = capsys.readouterr().out
     assert "31/256 blocks of the score matrix visited a head under the " \
-        "window" in capsys.readouterr().out
+        "window" in out
+    assert ("dense SwiGLUs over 1 logged steps: first products kept for the "
+            "backward pass in 6/6 layers 4.03 GB") in out
