@@ -178,9 +178,11 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
     # key/value head's query heads of 128 over 1,024 positions, bfloat16
     # operands: four heads under SDAR's block-diffusion mask, eight under
     # Solar-Open2's causal one, LFM2's four heads of 64 (half a lane
-    # line, as they are) under the causal mask, and Phi-4-flash's two heads
+    # line, as they are) under the causal mask, Phi-4-flash's two heads
     # of 64 on a pair's values of 128 under a window's band (300 positions:
-    # the diagonal blocks and part of the one below).
+    # the diagonal blocks and part of the one below), and GLM-4.7-Flash's
+    # latent attention: one query head a key/value head at keys and values
+    # of 256 (two lane lines) under the causal mask.
     from deepfm_tpu.models import kimi_linear, phi4_flash, sdar_moe
 
     length, cdt = 512, jnp.dtype(jnp.bfloat16)
@@ -189,7 +191,8 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
             (4, 128, 128, sdar_moe.block_diffusion(length, 4)),
             (8, 128, 128, kimi_linear.causal),
             (4, 64, 64, kimi_linear.causal),
-            (2, 64, 128, phi4_flash.window(300))):
+            (2, 64, 128, phi4_flash.window(300)),
+            (1, 256, 256, kimi_linear.causal)):
         q, key, val = (jnp.asarray(rng.normal(size=shape_), jnp.float32)
                        for shape_ in ((1, 2 * length, 1, heads, head_dim),
                                       (1, 2 * length, 1, head_dim),

@@ -49,7 +49,7 @@ class Config:
     coordinator_address: str = ""     # jax.distributed coordinator (host:port)
 
     # ---- model hyperparameters (reference: model flags) ----
-    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2 | lfm2_moe | phi4_flash
+    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2 | lfm2_moe | phi4_flash | glm4_moe_lite
     feature_size: int = 117581        # vocabulary size (reference ipynb:85)
     field_size: int = 39              # number of fields (reference ipynb:90)
     embedding_size: int = 32          # latent dim (reference flag default, ...py:44)
@@ -141,6 +141,21 @@ class Config:
     mamba_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: int = 0
+    # glm4_moe_lite only (latent-attention MoE decoder with a
+    # multi-token-prediction module, models/glm4_moe_lite.py), beside
+    # decoder_layers / attn_q_heads = attn_kv_heads (the heads held) /
+    # rope_theta / rms_norm_eps / mla_latent_dim / mla_rope_dim (rotated
+    # here) / dense_* / moe_*, which mean what they mean above: every layer
+    # mixes by latent attention whose queries pass a normed bottleneck of
+    # mla_q_rank; a head's query and key are mla_nope_dim unrotated columns
+    # beside the mla_rope_dim rotated ones, its value mla_value_dim wide
+    # (attn_head_dim is not read); mtp_depth (0 or 1) multi-token-prediction
+    # modules follow the last layer, whose loss weighs mtp_loss_weight.
+    mla_q_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_value_dim: int = 0
+    mtp_depth: int = 0
+    mtp_loss_weight: float = 0.1
     l2_reg: float = 1e-4
     loss_type: str = "log_loss"       # log_loss | square_loss
 
@@ -509,7 +524,7 @@ class Config:
         if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm",
                               "dlrm_dcnv2", "din", "bst", "sdar_moe",
                               "kimi_linear", "solar_open2", "lfm2_moe",
-                              "phi4_flash"):
+                              "phi4_flash", "glm4_moe_lite"):
             raise ValueError(f"unknown model: {self.model!r}")
         if self.model == "sdar_moe":
             self._validate_sdar_moe()
@@ -521,24 +536,36 @@ class Config:
             self._validate_lfm2_moe()
         elif self.model == "phi4_flash":
             self._validate_phi4_flash()
+        elif self.model == "glm4_moe_lite":
+            self._validate_glm4_moe_lite()
         elif self.decoder_layers or self.moe_experts or self.attn_q_heads:
             raise ValueError(
                 "decoder_layers/attn_*/moe_* belong to --model sdar_moe, "
-                f"kimi_linear, solar_open2, lfm2_moe and phi4_flash; "
-                f"{self.model!r} has no decoder block")
+                f"kimi_linear, solar_open2, lfm2_moe, phi4_flash and "
+                f"glm4_moe_lite; {self.model!r} has no decoder block")
         # the decoders' further flags, and the models that take each
         takers = {
-            "kda_heads/attn_every/moe_shared_width": (
-                ("kimi_linear", "solar_open2"), self.kda_heads
-                or self.attn_every or self.moe_shared_width),
+            "kda_heads/attn_every": (
+                ("kimi_linear", "solar_open2"),
+                self.kda_heads or self.attn_every),
+            "moe_shared_width": (
+                ("kimi_linear", "solar_open2", "glm4_moe_lite"),
+                self.moe_shared_width),
             "moe_route_scale": (
-                ("kimi_linear", "solar_open2", "lfm2_moe"),
+                ("kimi_linear", "solar_open2", "lfm2_moe", "glm4_moe_lite"),
                 self.moe_route_scale != 1.0),
             "mla_latent_dim/mla_rope_dim": (
-                ("kimi_linear",), self.mla_latent_dim or self.mla_rope_dim),
-            "dense_layers": (("kimi_linear", "lfm2_moe"), self.dense_layers),
+                ("kimi_linear", "glm4_moe_lite"),
+                self.mla_latent_dim or self.mla_rope_dim),
+            "mla_q_rank/mla_nope_dim/mla_value_dim/mtp_depth/"
+            "mtp_loss_weight": (
+                ("glm4_moe_lite",), self.mla_q_rank or self.mla_nope_dim
+                or self.mla_value_dim or self.mtp_depth
+                or self.mtp_loss_weight != 0.1),
+            "dense_layers": (("kimi_linear", "lfm2_moe", "glm4_moe_lite"),
+                             self.dense_layers),
             "dense_mlp_width": (
-                ("kimi_linear", "lfm2_moe", "phi4_flash"),
+                ("kimi_linear", "lfm2_moe", "phi4_flash", "glm4_moe_lite"),
                 self.dense_mlp_width),
             "layer_types": (("lfm2_moe", "phi4_flash"), self.layer_types),
             "conv_taps": (("lfm2_moe",), self.conv_taps != 3),
@@ -1026,7 +1053,7 @@ class Config:
 
     def _refuse_for_a_decoder(self, model: str) -> None:
         """What none of the next-token decoders (kimi_linear, solar_open2,
-        lfm2_moe, phi4_flash) takes."""
+        lfm2_moe, phi4_flash, glm4_moe_lite) takes."""
         refused = {
             "tasks (the loss is over the positions of a sequence, one task)":
                 self.num_tasks > 1,
@@ -1150,6 +1177,55 @@ class Config:
             raise ValueError("model phi4_flash does not take moe_* (it has "
                              "no experts)")
         self._refuse_for_a_decoder("phi4_flash")
+
+    def _validate_glm4_moe_lite(self) -> None:
+        """What the latent-attention MoE decoder with a multi-token-
+        prediction module takes, and plainly what it does not
+        (models.glm4_moe_lite.Glm4MoeLite)."""
+        # the module's block is an expert layer
+        moe_blocks = self.decoder_layers - self.dense_layers + self.mtp_depth
+        need = {
+            "decoder_layers >= 1": self.decoder_layers >= 1,
+            "attn_q_heads = attn_kv_heads >= 1 (the latent-attention heads "
+            "held, a key and a value each)":
+                self.attn_q_heads == self.attn_kv_heads >= 1,
+            "mla_q_rank >= 1, mla_latent_dim >= 1, mla_nope_dim >= 0 and "
+            "mla_value_dim >= 1": self.mla_q_rank >= 1
+                and self.mla_latent_dim >= 1 and self.mla_nope_dim >= 0
+                and self.mla_value_dim >= 1,
+            "an even mla_rope_dim >= 2 (rotate-half rotary)":
+                self.mla_rope_dim >= 2 and self.mla_rope_dim % 2 == 0,
+            "0 <= dense_layers <= decoder_layers": 0 <= self.dense_layers
+                <= self.decoder_layers,
+            "dense_mlp_width >= 1 where a layer is dense":
+                self.dense_layers == 0 or self.dense_mlp_width >= 1,
+            "mtp_depth 0 or 1 (one multi-token-prediction module; a chain "
+            "of modules is not written)": self.mtp_depth in (0, 1),
+            "mtp_loss_weight > 0 where mtp_depth is 1":
+                self.mtp_depth == 0 or self.mtp_loss_weight > 0,
+            "1 <= moe_top_k <= moe_experts, moe_expert_width >= 1, "
+            "moe_shared_width >= 1 and moe_route_scale > 0 where a block "
+            "has experts": moe_blocks == 0 or (
+                1 <= self.moe_top_k <= self.moe_experts
+                and self.moe_expert_width >= 1 and self.moe_shared_width >= 1
+                and self.moe_route_scale > 0),
+            "moe_experts_held >= 1 experts from moe_first_expert on, all "
+            "among the moe_experts": moe_blocks == 0 or (
+                self.moe_experts_held >= 1 and self.moe_first_expert >= 0
+                and self.moe_first_expert + self.moe_experts_held
+                <= self.moe_experts),
+            "moe_pair_capacity >= 1 (rows of a block's pair buffer; every "
+            "pair of a step is batch_size * history_max_len * moe_top_k)":
+                moe_blocks == 0 or self.moe_pair_capacity >= 1,
+            "history_max_len >= 2 + mtp_depth (the sequence length; the "
+            "module's loss is of the token after the next)":
+                self.history_max_len >= 2 + self.mtp_depth,
+            "feature_size >= 2": self.feature_size >= 2,
+        }
+        for what, ok in need.items():
+            if not ok:
+                raise ValueError(f"model glm4_moe_lite needs {what}")
+        self._refuse_for_a_decoder("glm4_moe_lite")
 
     def _validate_solar_open2(self) -> None:
         """What the gated-GQA / KDA MoE decoder takes, and plainly what it

@@ -4,8 +4,8 @@
 (``--tasks ctr,cvr``) builds the multi-task model (``--multitask``
 architecture over the shared graph bottom); otherwise ``cfg.model`` picks a
 single-task graph from the registry, or one of the decoders (``sdar_moe``,
-``kimi_linear``, ``solar_open2``, ``lfm2_moe``, ``phi4_flash``), which are no
-rankers.
+``kimi_linear``, ``solar_open2``, ``lfm2_moe``, ``phi4_flash``, ``glm4_moe_lite``), which are
+no rankers.
 """
 
 from typing import Union
@@ -15,6 +15,7 @@ from .graph import DLRM, GraphDLRMDCNv2
 from .graph import GraphDCNv2 as DCNv2
 from .graph import GraphDeepFM as DeepFM
 from .graph import GraphWideDeep as WideDeep
+from .glm4_moe_lite import Glm4MoeLite
 from .kimi_linear import KimiLinear
 from .lfm2_moe import Lfm2Moe
 from .multitask import MultiTaskModel  # noqa: F401
@@ -36,11 +37,12 @@ _REGISTRY = {
     "solar_open2": SolarOpen2,
     "lfm2_moe": Lfm2Moe,
     "phi4_flash": Phi4Flash,
+    "glm4_moe_lite": Glm4MoeLite,
 }
 
 CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDLRMDCNv2, GraphDIN,
                  GraphBST, SdarMoE, KimiLinear, SolarOpen2, Lfm2Moe,
-                 Phi4Flash, MultiTaskModel]
+                 Phi4Flash, Glm4MoeLite, MultiTaskModel]
 
 
 def registered_models():

@@ -426,6 +426,10 @@ class KimiLinear(GraphModel):
         super().__init__(cfg)
         self.cdt = jnp.dtype(cfg.compute_dtype)
         self.kinds = self._kinds(cfg)
+        #: the kinds of every block a step runs: the stack's layers and, in
+        #: a model that has one, what follows them (``models.glm4_moe_lite``:
+        #: the multi-token-prediction module's block)
+        self.block_kinds = self.kinds
         #: What the traced step is made of, said beside its counts on
         #: ``train.log_sync`` while tracing is on (``hidden`` adds the expert
         #: layers' ``sdar_moe.moe_notes``).
@@ -532,7 +536,7 @@ class KimiLinear(GraphModel):
                               cfg.moe_pair_capacity, one_device=one_device)
         self.step_notes.update(moe_notes(
             rows_by, cfg.moe_pair_capacity,
-            sum(ffn == "moe" for _, ffn in self.kinds)))
+            sum(ffn == "moe" for _, ffn in self.block_kinds)))
         return rows_by
 
     def _paths(self, ids: jnp.ndarray, one_device: bool) -> Dict[str, str]:
@@ -564,15 +568,15 @@ class KimiLinear(GraphModel):
 
     def _mlp_keeps(self, params: common.Params, ids: jnp.ndarray
                    ) -> Tuple[bool, ...]:
-        """Whether each layer of the step of ``ids`` [B, L] keeps its
-        SwiGLU's first products for the backward pass (``mlp_kept_by``; a
-        layer without a dense SwiGLU has none to keep); ``step_notes`` is
-        told."""
+        """Whether each block (``block_kinds``) of the step of ``ids``
+        [B, L] keeps its SwiGLU's first products for the backward pass
+        (``mlp_kept_by``; a layer without a dense SwiGLU has none to keep);
+        ``step_notes`` is told."""
         cfg = self.cfg
         # float32 gate and up products a position
         sizes = [8 * ids.size * (cfg.dense_mlp_width if ffn == "mlp"
                                  else cfg.moe_shared_width)
-                 for _, ffn in self.kinds]
+                 for _, ffn in self.block_kinds]
         have = [i for i, size in enumerate(sizes) if size]
         kept = mlp_kept_by([sizes[i] for i in have], positions=ids.size,
                            limit=device_memory_bytes(),
@@ -653,6 +657,15 @@ class KimiLinear(GraphModel):
         keeps = self._mlp_keeps(params, ids)
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
+        x, seen = self._run_layers(params, x, paths, keeps)
+        return x, self._merged_counts(seen)
+
+    def _run_layers(self, params: common.Params, x: jnp.ndarray,
+                    paths: Dict[str, str], keeps: Sequence[bool]
+                    ) -> Tuple[jnp.ndarray, Dict[str, list]]:
+        """The stack's layers over the looked-up rows x [B, L, d] -> (the
+        last residual stream, the layers' counts: {count: [a value a
+        layer]})."""
         seen: Dict[str, list] = {}
         left: Dict[str, jnp.ndarray] = {}
         for i, kind in enumerate(self.kinds):
@@ -660,7 +673,13 @@ class KimiLinear(GraphModel):
                 i, kind, x, params["layers"][str(i)], left, paths, keeps[i])
             for name, value in counts.items():
                 seen.setdefault(name, []).append(value)
-        return x, {name: _merged(name, jnp.min, jnp.max, jnp.sum)(
+        return x, seen
+
+    @staticmethod
+    def _merged_counts(seen: Dict[str, list]) -> Dict[str, jnp.ndarray]:
+        """The layers' counts as the step's: sums, the ``_max`` ones'
+        largest, the ``_min`` ones' least."""
+        return {name: _merged(name, jnp.min, jnp.max, jnp.sum)(
             jnp.stack(values)) for name, values in seen.items()}
 
     @jax.named_scope("head")

@@ -88,7 +88,55 @@ def conv_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, eps: float,
     return _dot(y, lp["conv_w_out"], cdt)
 
 
-class Lfm2Moe(KimiLinear):
+class SelectionBias:
+    """For a ``KimiLinear`` stack whose routers pick by score + bias (mixed
+    in ahead of it): the bias ``SELECT_BIAS`` [expert blocks, experts] in
+    the model state, a row an expert layer in the layers' order, read by
+    each beside its own leaves and handed on as it came; ``BIAS_MOVED``
+    among the counts."""
+
+    @property
+    def moe_layers(self) -> Tuple[int, ...]:
+        """The expert layers' numbers among the layers, in order."""
+        return tuple(i for i, (_, ffn) in enumerate(self.kinds)
+                     if ffn == "moe")
+
+    def init_counts(self) -> common.State:
+        return {n: jnp.zeros((), jnp.int32)
+                for n in (*COUNT_NAMES, BIAS_MOVED)}
+
+    def init_bias(self) -> jnp.ndarray:
+        return jnp.zeros((sum(ffn == "moe" for _, ffn in self.block_kinds),
+                          self.cfg.moe_experts), jnp.float32)
+
+    def init(self, rng: jax.Array) -> Tuple[common.Params, common.State]:
+        params, counts = super().init(rng)
+        return params, {**counts, SELECT_BIAS: self.init_bias()}
+
+    def step_counts(self, model_state: common.State
+                    ) -> Dict[str, jnp.ndarray]:
+        """The counts a step's metrics carry beside its loss (the state
+        without the bias, which is no count)."""
+        return {k: v for k, v in model_state.items() if k != SELECT_BIAS}
+
+    def _with_bias(self, params: common.Params, bias: jnp.ndarray
+                   ) -> common.Params:
+        """``params`` with each expert layer reading its row of ``bias``
+        beside its own leaves, as ``select_bias``."""
+        layers = dict(params["layers"])
+        for row, i in enumerate(self.moe_layers):
+            layers[str(i)] = {**layers[str(i)], "select_bias": bias[row]}
+        return {**params, "layers": layers}
+
+    def _run(self, params, state, tokens, shard_axis, data_axis, emb):
+        bias = state[SELECT_BIAS]
+        h, tokens, counts = super()._run(
+            self._with_bias(params, bias), state, tokens, shard_axis,
+            data_axis, emb)
+        return h, tokens, {**counts, SELECT_BIAS: bias}
+
+
+class Lfm2Moe(SelectionBias, KimiLinear):
     """Short-convolution / GQA mixture-of-experts decoder over ``hist_ids``;
     see the module's docstring."""
 
@@ -102,27 +150,6 @@ class Lfm2Moe(KimiLinear):
         self.route_by = functools.partial(
             route, score=jax.nn.sigmoid, scale=cfg.moe_route_scale,
             renorm_eps=RENORM_EPS)
-        #: the expert layers' numbers among the layers, in order
-        self.moe_layers = tuple(i for i, (_, ffn) in enumerate(self.kinds)
-                                if ffn == "moe")
-
-    def init_counts(self) -> common.State:
-        return {n: jnp.zeros((), jnp.int32)
-                for n in (*COUNT_NAMES, BIAS_MOVED)}
-
-    def init_bias(self) -> jnp.ndarray:
-        return jnp.zeros((len(self.moe_layers), self.cfg.moe_experts),
-                         jnp.float32)
-
-    def init(self, rng: jax.Array) -> Tuple[common.Params, common.State]:
-        params, counts = super().init(rng)
-        return params, {**counts, SELECT_BIAS: self.init_bias()}
-
-    def step_counts(self, model_state: common.State
-                    ) -> Dict[str, jnp.ndarray]:
-        """The counts a step's metrics carry beside its loss (the state
-        without the bias, which is no count)."""
-        return {k: v for k, v in model_state.items() if k != SELECT_BIAS}
 
     def _init_mixer(self, mixer: str, glorot, keys) -> Dict[str, jnp.ndarray]:
         cfg = self.cfg
@@ -158,14 +185,3 @@ class Lfm2Moe(KimiLinear):
                          head_dim=cfg.attn_head_dim, eps=cfg.rms_norm_eps,
                          theta=cfg.rope_theta, cdt=self.cdt,
                          scores_by=scores_by, scores_scope="attn_scores"), {}
-
-    def _run(self, params, state, tokens, shard_axis, data_axis, emb):
-        # each expert layer reads its row of the bias beside its own leaves
-        bias = state[SELECT_BIAS]
-        layers = dict(params["layers"])
-        for row, i in enumerate(self.moe_layers):
-            layers[str(i)] = {**layers[str(i)], "select_bias": bias[row]}
-        h, tokens, counts = super()._run(
-            {**params, "layers": layers}, state, tokens, shard_axis,
-            data_axis, emb)
-        return h, tokens, {**counts, SELECT_BIAS: bias}

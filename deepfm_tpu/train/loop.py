@@ -516,7 +516,10 @@ class Trainer:
                 shard_axis=shard_axis, ids=ids)
             new_params, new_opt = self._optax_apply(
                 grads, state.opt_state, state.params)
-        if self._model_loss:    # the model's counts ride beside the loss
+        if self._model_loss:
+            # the model's counts ride beside the loss; a model whose loss
+            # has parts (``loss_parts``) says them among its counts, the
+            # main one as ``xent`` in place of the mean differentiated here
             counts = self.model.step_counts(new_mstate)
         new_state = state.replace(
             step=state.step + 1, params=new_params, opt_state=new_opt,
@@ -2297,6 +2300,11 @@ class Trainer:
                                 else float(v)) for key, v in m.items()
                                       if key not in ("loss", "xent",
                                                      "steps_done")}
+                            # a model whose loss has parts says each, the
+                            # main one (``xent``) too
+                            counts.update({
+                                key: float(m[key]) for key in getattr(
+                                    self.model, "loss_parts", ())})
                             # how the compiled step made its table
                             # gradient, or wrote its rows back
                             for key, how in ((EMBED_GRAD, self.embed_grad),

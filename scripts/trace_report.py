@@ -114,8 +114,16 @@ beside ``attn_scores`` / ``attn_score_blocks`` (the causal layers')
 ``attn_window_blocks``, the blocks the kernel visits under the window; the
 report prints one "selective scan" line.
 
+Where the model's loss has parts (``--model glm4_moe_lite``: the main
+model's next-token loss and its multi-token-prediction module's), each
+``train.log_sync`` carries each part (``xent``, ``mtp_xent``) beside the
+decoders' other keys (``attn_scores`` / ``attn_score_blocks``: its latent
+attention at heads of 256; ``moe_rows``, ``moe_bias_moved_picks``,
+``mlp_kept``); the report prints one "multi-token prediction" line.
+
 Where the model has dense SwiGLUs (a dense MLP or a shared expert:
-``--model kimi_linear``, ``solar_open2``, ``lfm2_moe``, ``phi4_flash``), each
+``--model kimi_linear``, ``solar_open2``, ``lfm2_moe``, ``phi4_flash``,
+``glm4_moe_lite``), each
 ``train.log_sync`` carries ``mlp_kept``: how many of those layers keep their
 float32 first products for the backward pass and the bytes kept
 (``6/6 layers 4.03 GB``; ``0/5`` where the device's memory holds none, off a
@@ -503,6 +511,18 @@ def kept_products(events):
     return {"steps": len(seen), "kept": seen[-1]["mlp_kept"]}
 
 
+def multi_token_prediction(events):
+    """The two losses of a model with a multi-token-prediction module, off
+    the ``train.log_sync`` spans that carry ``mtp_xent``: ``steps`` read and
+    the last span's ``xent`` (the main model's) and ``mtp_xent`` (the
+    module's); None when no span has it (another model)."""
+    seen = _log_syncs(events, "mtp_xent")
+    if not seen:
+        return None
+    return {"steps": len(seen), "xent": seen[-1].get("xent"),
+            "mtp_xent": seen[-1]["mtp_xent"]}
+
+
 def expert_rows(events):
     """How the expert layers' rows moved, off the ``train.log_sync`` spans
     that say so: ``steps`` read, ``rows`` (``moe_rows``: ``kernel``, one copy
@@ -546,6 +566,7 @@ def main(argv=None):
     scan = delta_rule_scan(events)
     conv = short_convolution(events)
     selective = selective_scan(events)
+    mtp = multi_token_prediction(events)
     kept = kept_products(events)
     moved = expert_rows(events)
     boots = start_up(events)
@@ -567,6 +588,8 @@ def main(argv=None):
             doc["short_convolution"] = conv
         if selective is not None:
             doc["selective_scan"] = selective
+        if mtp is not None:
+            doc["multi_token_prediction"] = mtp
         if kept is not None:
             doc["kept_products"] = kept
         if moved is not None:
@@ -652,6 +675,11 @@ def main(argv=None):
     if kept is not None:
         print("dense SwiGLUs over %d logged steps: first products kept for "
               "the backward pass in %s" % (kept["steps"], kept["kept"]))
+    if mtp is not None:
+        print("multi-token prediction over %d logged steps: the main loss "
+              "%s, the module's %.5g at the last" % (
+                  mtp["steps"], "not in this trace" if mtp["xent"] is None
+                  else "%.5g" % mtp["xent"], mtp["mtp_xent"]))
     if moved is not None:
         print("expert layers' rows over %d logged steps: moved by %s, %.0f "
               "of %d buffer rows a step held a pair (%.1f%%)"

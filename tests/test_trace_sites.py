@@ -976,3 +976,64 @@ def test_log_sync_says_the_selective_scan_and_both_masks_blocks(tmp_path,
         "window" in out
     assert ("dense SwiGLUs over 1 logged steps: first products kept for the "
             "backward pass in 6/6 layers 4.03 GB") in out
+
+
+def test_log_sync_says_both_losses_and_the_latent_attentions_path(tmp_path,
+                                                                  capsys):
+    """``--model glm4_moe_lite`` says on the span that reads the loss back
+    both parts of its loss (``xent``, the main model's, and ``mtp_xent``,
+    its multi-token-prediction module's: floats among the counts), what
+    makes its latent attention's masked scores (``attn_scores``, the site it
+    shares with the other decoders; on a TPU the visited blocks at heads of
+    256), the picks its selection bias moved and how its expert blocks' rows
+    moved (the module's block among them); the report prints its line."""
+    length, vocab, batch = 12, 50, 2
+    cfg = Config(model="glm4_moe_lite", feature_size=vocab, field_size=1,
+                 embedding_size=16, history_max_len=length, decoder_layers=2,
+                 dense_layers=1, dense_mlp_width=16, attn_q_heads=2,
+                 attn_kv_heads=2, mla_q_rank=6, mla_latent_dim=8,
+                 mla_nope_dim=4, mla_rope_dim=4, mla_value_dim=8,
+                 rms_norm_eps=1e-5, moe_experts=8, moe_top_k=2,
+                 moe_expert_width=8, moe_shared_width=8, moe_route_scale=1.8,
+                 moe_experts_held=4, moe_first_expert=0,
+                 moe_pair_capacity=2 * batch * length, mtp_depth=1,
+                 batch_size=batch, l2_reg=0.0, learning_rate=1e-3,
+                 steps_per_loop=1, log_steps=2, compute_dtype="float32",
+                 mesh_data=1, mesh_model=1)
+    rng = np.random.default_rng(5)
+    batches = [{"feat_ids": np.zeros((batch, 1), np.int32),
+                "feat_vals": np.ones((batch, 1), np.float32),
+                "label": np.zeros((batch, 1), np.float32),
+                "hist_ids": rng.integers(0, vocab, (batch, length)
+                                         ).astype(np.int32),
+                "hist_mask": np.ones((batch, length), np.float32)}
+               for _ in range(4)]
+    trace_lib.configure("full", export_env=False)
+    tr = Trainer(cfg)
+    tr.fit(tr.init_state(), batches)
+    syncs = [e["args"] for e in trace_lib._tracer.events()
+             if e["name"] == "train.log_sync"]
+    assert len(syncs) == 2
+    for a in syncs:
+        assert isinstance(a["xent"], float) and isinstance(a["mtp_xent"],
+                                                           float)
+        assert 0 < a["xent"] != a["mtp_xent"] > 0
+        assert (a["attn_scores"], a["moe_rows"]) == ("xla", "xla")
+        # the expert layer and the module's block, one pass each
+        assert a["moe_rows_moved"].endswith("/%d" % (2 * 2 * batch * length))
+        assert a["mlp_kept"] == "0/3" and a["moe_bias_moved_picks"] == 0
+        assert "moe_select_bias" not in a
+    path = str(tmp_path / "trace.json")
+    trace_lib.export(path)
+    report = _report()
+    loaded, _ = report._load(path)
+    assert report.multi_token_prediction(loaded) == {
+        "steps": 2, "xent": syncs[-1]["xent"],
+        "mtp_xent": syncs[-1]["mtp_xent"]}
+    assert report.selective_scan(loaded) is None
+    assert report.main([path]) == 0
+    assert "multi-token prediction over 2 logged steps: the main loss" \
+        in capsys.readouterr().out
+    # another model's trace has no such line
+    assert report.multi_token_prediction(
+        [{"name": "train.log_sync", "ph": "X", "args": {"step": 1}}]) is None
