@@ -61,7 +61,7 @@ the experts' partial sums unreduced.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -231,6 +231,13 @@ class Glm4MoeLite(SelectionBias, KimiLinear):
             seen.setdefault(name, []).append(value)
         return (h, m), seen
 
+    def _head_params(self, params: common.Params) -> List[Any]:
+        """... and the module's pass: its own norm, the same matrix."""
+        reads = super()._head_params(params)
+        if "mtp" not in params:
+            return reads
+        return reads + [(params["mtp"]["final_norm"], params["head"])]
+
     @jax.named_scope("mtp_head")
     def mtp_logits(self, params: common.Params, m: jnp.ndarray
                    ) -> jnp.ndarray:
@@ -280,6 +287,7 @@ class Glm4MoeLite(SelectionBias, KimiLinear):
             return nll(logits_of, stream, jnp.roll(tokens, -ahead, axis=1),
                        weight) / (length - ahead)
 
+        self.step_notes.update(self._head_notes(params, tokens))
         xent = mean_nll(weighted_nll, functools.partial(self.logits, params),
                         h, 1)
         parts = {XENT: jnp.mean(xent), MTP_XENT: jnp.zeros((), jnp.float32)}

@@ -81,17 +81,18 @@ The stack is a Python loop over layers of three shapes, not a scan.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import pallas_kda_scan
-from . import common
+from . import common, sdar_moe
 from .graph import GraphModel
-from .sdar_moe import (ScoreMask, _dot, _operand, _scores_xla, expert_layer,
-                       moe_notes, moe_rows_by, rms_norm, route, weighted_nll)
+from .sdar_moe import (ScoreMask, _dot, _float32_bytes, _operand, _scores_xla,
+                       expert_layer, head_grad_by, head_grad_note, moe_notes,
+                       moe_rows_by, rms_norm, route, weighted_nll)
 
 #: The step's counts, in the model state and (by ``step_counts``) the metrics.
 COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
@@ -204,23 +205,14 @@ def kda_scan_note(by: str) -> str:
             else f"chunk{KDA_CHUNK}/sub{KDA_SUB}")
 
 
-def device_memory_bytes() -> int:
-    """The memory limit of the device a step is traced for, as the backend
-    says it (``memory_stats()["bytes_limit"]``); 0 off a TPU and where the
-    device says nothing of its memory."""
-    if jax.default_backend() != "tpu":
-        return 0
-    return int((jax.local_devices()[0].memory_stats() or {}).get(
-        "bytes_limit", 0))
-
-
 def mlp_kept_by(layer_bytes: Sequence[int], *, positions: int, limit: int,
                 held: int) -> int:
     """How many of the stack's SwiGLUs keep their first products for the
     backward pass, counted from the last layer back (the last layer's are
     freed first, while the layers' gradients come to life): ``layer_bytes``
     what each would keep, first layer first; ``limit`` the device's memory
-    (``device_memory_bytes``; 0: nothing is known, nothing is kept);
+    (``sdar_moe.device_memory_bytes``; 0: nothing is known, nothing is
+    kept);
     ``held`` what the step holds without them (``KimiLinear._held_bytes``);
     ``positions`` the step's. A layer keeps while what is left of the
     memory stays above ``MLP_KEEP_RESERVE`` bytes a position, the room of a
@@ -555,16 +547,48 @@ class KimiLinear(GraphModel):
         self.step_notes["kda_scan"] = kda_scan_note(by)
         return by
 
-    def _held_bytes(self, params: common.Params) -> int:
-        """What a train step holds on a device whatever its layers keep,
-        from what ``hidden`` can see: the parameters as it is handed them,
-        as many more copies as the optimizer's state holds
-        (``OPTIMIZER_COPIES``) and one of gradients. (Not the bytes resident
-        when the step is traced: ``Trainer.step_compiled`` traces from
-        shapes, and a step traced twice has to be one program.)"""
+    def _head_params(self, params: common.Params) -> List[Any]:
+        """What each head pass of a step reads (``weighted_nll``'s
+        ``logits_of``: final norm and head matrix), a pass an entry."""
+        return [(params["final_norm"],
+                 params["tok_emb" if self.tied_head else "head"])]
+
+    def _head_notes(self, params: common.Params, ids: jnp.ndarray
+                    ) -> Dict[str, str]:
+        """What ``step_notes`` says of the head passes of the step of
+        ``ids`` [B, L] (``head_grad``; a second pass's under its scope's
+        name)."""
+        return {name: head_grad_note(by, ids.shape[0], size)
+                for name, (by, size) in zip(
+                    ("head_grad", "mtp_head_grad"),
+                    self._head_grads(params, ids))}
+
+    def _head_grads(self, params: common.Params, ids: jnp.ndarray
+                    ) -> List[Tuple[str, int]]:
+        """(``sdar_moe.head_grad_by``'s word, the float32 bytes of what the
+        pass reads) of each head pass of the step of ``ids`` [B, L]."""
+        sizes = [_float32_bytes(read) for read in self._head_params(params)]
+        return [(head_grad_by(ids.shape[0], size,
+                              sdar_moe.device_memory_bytes(),
+                              one_device=not jax.typeof(ids).vma), size)
+                for size in sizes]
+
+    def _held_bytes(self, params: common.Params, ids: jnp.ndarray) -> int:
+        """What a train step of ``ids`` [B, L] holds on a device whatever
+        its layers keep, from what ``hidden`` can see: the parameters as it
+        is handed them, as many more copies as the optimizer's state holds
+        (``OPTIMIZER_COPIES``), one of gradients, and what the head passes
+        keep a sequence beyond that one (``sdar_moe.head_grad_by``). (Not
+        the bytes resident when the step is traced: ``Trainer.step_compiled``
+        traces from shapes, and a step traced twice has to be one
+        program.)"""
         leaves = sum(x.size * x.dtype.itemsize
                      for x in jax.tree.leaves(params))
-        return leaves * (2 + OPTIMIZER_COPIES[self.cfg.optimizer.lower()])
+        heads = sum((ids.shape[0] - 1) * size
+                    for by, size in self._head_grads(params, ids)
+                    if by == "forward")
+        return heads + leaves * (
+            2 + OPTIMIZER_COPIES[self.cfg.optimizer.lower()])
 
     def _mlp_keeps(self, params: common.Params, ids: jnp.ndarray
                    ) -> Tuple[bool, ...]:
@@ -579,8 +603,8 @@ class KimiLinear(GraphModel):
                  for _, ffn in self.block_kinds]
         have = [i for i, size in enumerate(sizes) if size]
         kept = mlp_kept_by([sizes[i] for i in have], positions=ids.size,
-                           limit=device_memory_bytes(),
-                           held=self._held_bytes(params))
+                           limit=sdar_moe.device_memory_bytes(),
+                           held=self._held_bytes(params, ids))
         keeping = have[len(have) - kept:]
         self.step_notes["mlp_kept"] = mlp_kept_note(
             kept, len(have), sum(sizes[i] for i in keeping))
@@ -738,6 +762,7 @@ class KimiLinear(GraphModel):
         weight = jnp.broadcast_to(
             (jnp.arange(length) < length - 1).astype(jnp.float32),
             tokens.shape)
+        self.step_notes.update(self._head_notes(params, tokens))
         per_seq = weighted_nll(functools.partial(self.logits, params), h,
                                labels, weight) / (length - 1)
         return per_seq, counts

@@ -84,6 +84,18 @@ keeps a block of scores in VMEM and saves a log-sum-exp a query for its
 backward kernels, which make the scores again; the XLA path runs a chunk of
 queries at a time, each chunk recomputed too, so its [chunk, S] scores do
 not outlive the chunk.
+
+**The head's loss** (``weighted_nll``, which the other decoders import): the
+final norm, the head product and the cross-entropy, ``HEAD_CHUNK`` positions
+at a time, so that no [L, V] of logits is held. Where the loss is
+differentiated the forward pass makes a chunk's gradient while its logits
+are at hand (a ``jax.custom_vjp``: three products a chunk, the logits made
+once, the head's and the norm's gradients summed a sequence in float32 and
+scaled by the cotangent in the backward pass, which makes no product);
+evaluation makes one product a chunk. Where the further copies a sequence do
+not fit the device's memory, or the step runs across data replicas
+(``head_grad_by``; no flag), each chunk is made again in the backward pass
+under ``jax.checkpoint`` (``step_notes``: ``head_grad``).
 """
 
 from __future__ import annotations
@@ -113,6 +125,11 @@ BIAS_MOVED = "moe_bias_moved_picks"
 #: head's logits (all of them when the sequence does not divide).
 QUERY_CHUNK = 1024
 HEAD_CHUNK = 1024
+#: The share of a device's memory (1 / this) that a head pass's gradients a
+#: sequence may take beyond their sum (``head_grad_by``): 1.06 GB of a v5e's
+#: 16.91. The fullest of the cells' steps, GLM-4.7-Flash's, holds 0.32 GB
+#: more a pass at B = 2 (PERF.md section 6, PR 49).
+HEAD_KEPT_DIVISOR = 16
 #: Queries by keys a block of the attention kernel holds, forward and
 #: backward. On a v5e at the cell's shapes (q [2, 4, 8192, 128] on one
 #: key/value head, L = 4,096; PERF.md section 6, PR 32), forward / forward +
@@ -529,31 +546,172 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
     return out.reshape(shape), counts
 
 
+def device_memory_bytes() -> int:
+    """The memory limit of the device a step is traced for, as the backend
+    says it (``memory_stats()["bytes_limit"]``); 0 off a TPU and where the
+    device says nothing of its memory."""
+    if jax.default_backend() != "tpu":
+        return 0
+    return int((jax.local_devices()[0].memory_stats() or {}).get(
+        "bytes_limit", 0))
+
+
+def head_grad_by(batch: int, param_bytes: int, limit: int, *,
+                 one_device: bool = True) -> str:
+    """``forward`` where a differentiated ``weighted_nll`` makes a chunk's
+    gradient beside its loss, ``recomputed`` where it makes the chunk again
+    in the backward pass: read from the shapes, the device's memory
+    (``device_memory_bytes``; 0, nothing known of it: ``forward``) and the
+    mesh. The forward form holds the gradient of what ``logits_of`` reads
+    (``param_bytes``: final norm and head matrix, as float32) a sequence,
+    ``batch - 1`` copies more than the sum the other form ends in; it runs
+    where those are no more than 1 / ``HEAD_KEPT_DIVISOR`` of the memory
+    and the step is one device's program (across data replicas what
+    ``logits_of`` closed over is traced as every replica's: its gradient a
+    chunk would be summed over them a chunk)."""
+    extra = (batch - 1) * param_bytes
+    return ("forward" if one_device and (
+        limit <= 0 or extra * HEAD_KEPT_DIVISOR <= limit) else "recomputed")
+
+
+def head_grad_note(by: str, batch: int, param_bytes: int) -> str:
+    """What ``step_notes`` says of a head pass (``head_grad``): its form
+    (``head_grad_by``'s word) and what the forward form keeps."""
+    if by != "forward":
+        return by
+    return "forward 3 products/chunk, %.2f GB kept" % (
+        batch * param_bytes / 1e9)
+
+
+def _float32_bytes(tree: Any) -> int:
+    return sum(4 * x.size for x in jax.tree.leaves(tree))
+
+
+def _chunked(x: jnp.ndarray, chunk: int) -> jnp.ndarray:
+    """[B, L, ...] -> [L / chunk, B, chunk, ...]"""
+    b, length = x.shape[:2]
+    return jnp.moveaxis(
+        x.reshape(b, length // chunk, chunk, *x.shape[2:]), 1, 0)
+
+
+def _nll(logits: jnp.ndarray, tokens: jnp.ndarray
+         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(the negative log-likelihood of ``tokens`` [...] under ``logits``
+    [..., V], the log of the sum of the logits' exponentials [..., 1]):
+    ``jax.nn.log_softmax``'s operations to the bit, the label's column
+    taken before the subtractions, so that nothing [..., V] but the logits
+    is written out."""
+    top = jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True))
+    lse = jnp.log(jnp.sum(jnp.exp(logits - top), axis=-1, keepdims=True))
+    at_label = jnp.take_along_axis(logits, tokens[..., None], axis=-1) - top
+    return -(at_label - lse)[..., 0], top + lse
+
+
+def _sums_by_chunk(logits_of: Callable, chunk: int, h: jnp.ndarray,
+                   labels: jnp.ndarray, weight: jnp.ndarray, *,
+                   remade: bool = False) -> jnp.ndarray:
+    """``weighted_nll`` as one product a chunk; ``remade``: each chunk made
+    again in the backward pass (``jax.checkpoint``)."""
+    def one_chunk(args):
+        h_c, tok_c, w_c = args
+        return jnp.sum(_nll(logits_of(h_c), tok_c)[0] * w_c, axis=1)
+
+    return jnp.sum(jax.lax.map(
+        jax.checkpoint(one_chunk) if remade else one_chunk,
+        tuple(_chunked(x, chunk) for x in (h, labels, weight))), axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _nll_sums(logits_fn: Callable, chunk: int, h: jnp.ndarray, consts: Any,
+              labels: jnp.ndarray, weight: jnp.ndarray) -> jnp.ndarray:
+    """``weighted_nll`` with what ``logits_of`` closed over as an argument
+    (``logits_fn(h, *consts)``, traced for one sequence's chunk
+    [1, chunk, d]). Not differentiated: one product a chunk, nothing
+    kept."""
+    def logits_of(h_c):                         # any number of sequences
+        return jax.vmap(lambda h_1: logits_fn(h_1[None], *consts)[0])(h_c)
+
+    return _sums_by_chunk(logits_of, chunk, h, labels, weight)
+
+
+def _nll_sums_fwd(logits_fn, chunk, h, consts, labels, weight):
+    """The sums, and each sequence's sum's gradient while a chunk's logits
+    are at hand: softmax less the label's one, times the weight, back
+    through ``logits_fn`` (``jax.vjp``: whatever it does to what it reads)
+    into the chunk's rows of the stream's gradient and into a float32 sum
+    of every constant's, carried over the sequence's chunks. Three products
+    a chunk, the logits made once; a sequence at a time, so that each
+    product is a plain one (XLA:TPU computes a product batched over two
+    sequences as a dilated convolution, at twice the work: PERF.md section
+    6, PR 49)."""
+    def one_chunk(kept, args):
+        h_c, tok_c, w_c = args                  # [chunk, d], [chunk] x 2
+        logits, pull = jax.vjp(
+            lambda h_c, consts: logits_fn(h_c[None], *consts)[0], h_c, consts)
+        nll, log_sum = _nll(logits, tok_c)
+        at_label = jax.nn.one_hot(tok_c, logits.shape[-1],
+                                  dtype=logits.dtype)
+        dh, dconsts = pull(
+            w_c[:, None] * (jnp.exp(logits - log_sum) - at_label))
+        return ([k + g.astype(k.dtype) for k, g in zip(kept, dconsts)],
+                (jnp.sum(nll * w_c), nll, dh))
+
+    def one_sequence(kept, args):
+        b, *sequence = args
+        summed, (sums, nll, dh) = jax.lax.scan(
+            one_chunk, [jnp.zeros(c.shape, jnp.float32) for c in consts],
+            tuple(x.reshape(-1, chunk, *x.shape[1:]) for x in sequence))
+        # (the sequences' sums are the carry, not the loop's stacked
+        # results, and start as zeros made of h: XLA fills a loop's results,
+        # and any constant, under no scope)
+        kept = [jax.lax.dynamic_update_index_in_dim(k, g, b, 0)
+                for k, g in zip(kept, summed)]
+        return kept, (jnp.sum(sums), nll.reshape(-1),
+                      dh.reshape(sequence[0].shape))
+
+    batch = h.shape[0]
+    zero = h[:, 0, 0].astype(jnp.float32) * 0.0
+    kept, (sums, nll, dh) = jax.lax.scan(
+        one_sequence,
+        [jnp.broadcast_to(zero.reshape(batch, *[1] * c.ndim),
+                          (batch, *c.shape)) for c in consts],
+        (jnp.arange(batch), h, labels, weight))
+    return sums, (nll, dh, kept, consts)
+
+
+def _nll_sums_bwd(logits_fn, chunk, kept, g):
+    """The kept gradients times the sums' cotangent [B]: no product. (Its
+    ops carry the scope ``weighted_nll`` was called under.)"""
+    nll, dh, dconsts, consts = kept
+    return (g[:, None, None] * dh,
+            [jnp.sum(g.reshape(-1, *[1] * c.ndim) * k, axis=0).astype(c.dtype)
+             for k, c in zip(dconsts, consts)],
+            None, g[:, None] * nll)
+
+
+_nll_sums.defvjp(_nll_sums_fwd, _nll_sums_bwd)
+
+
 @jax.named_scope("head")
 def weighted_nll(logits_of: Callable, h: jnp.ndarray, labels: jnp.ndarray,
                  weight: jnp.ndarray) -> jnp.ndarray:
     """sum over a sequence's positions of ``weight`` times the negative
     log-likelihood of ``labels`` [B, L] under ``logits_of(h)`` (h [B, L, d]
-    -> [B, L, V]) -> [B], a chunk of ``HEAD_CHUNK`` positions at a time
-    (each chunk's logits are made again in the backward pass and never all
-    held)."""
+    -> [B, L, V]; it may close over traced parameters) -> [B], a chunk of
+    ``HEAD_CHUNK`` positions at a time; a chunk's logits are never all held.
+    Where the sums are differentiated the forward pass makes a chunk's
+    gradient while its logits are at hand (``_nll_sums_fwd``: three products
+    a chunk) and the backward pass makes no product; where a sequence's
+    copy of the head's gradients does not fit (``head_grad_by``) each chunk
+    is made again in the backward pass (``jax.checkpoint``: four products
+    and more). Not differentiated it is one product a chunk."""
     b, length, _ = h.shape
     chunk = HEAD_CHUNK if length % HEAD_CHUNK == 0 else length
-
-    @jax.checkpoint
-    def one_chunk(args):
-        h_c, tok_c, w_c = args
-        logp = jax.nn.log_softmax(logits_of(h_c), axis=-1)
-        nll = -jnp.take_along_axis(logp, tok_c[..., None], axis=-1)
-        return jnp.sum(nll[..., 0] * w_c, axis=1)
-
-    def chunks(x):
-        return jnp.moveaxis(
-            x.reshape(b, length // chunk, chunk, *x.shape[2:]), 1, 0)
-
-    sums = jax.lax.map(one_chunk, (chunks(h), chunks(labels),
-                                   chunks(weight)))
-    return jnp.sum(sums, axis=0)
+    logits_fn, consts = jax.closure_convert(logits_of, h[:1, :chunk])
+    if head_grad_by(b, _float32_bytes(consts), device_memory_bytes(),
+                    one_device=not jax.typeof(h).vma) == "forward":
+        return _nll_sums(logits_fn, chunk, h, consts, labels, weight)
+    return _sums_by_chunk(logits_of, chunk, h, labels, weight, remade=True)
 
 
 class SdarMoE(GraphModel):
@@ -579,8 +737,9 @@ class SdarMoE(GraphModel):
         #: ``train.log_sync`` while tracing is on: ``attn_scores`` (``kernel``
         #: / ``xla``) and, of the kernel, ``attn_score_blocks`` (blocks of
         #: the score matrix the forward pass computes / all of them, a head);
-        #: ``moe_rows`` and ``moe_rows_moved`` (``moe_notes``). A note may
-        #: name a count of the step in braces.
+        #: ``moe_rows`` and ``moe_rows_moved`` (``moe_notes``); ``head_grad``
+        #: (``head_grad_note``). A note may name a count of the step in
+        #: braces.
         self.step_notes: Dict[str, str] = {}
 
     def _attn_notes(self, scores_by: str, seq: int, length: int
@@ -695,6 +854,10 @@ class SdarMoE(GraphModel):
         over L (``weighted_nll``)."""
         weight = jnp.where(masked, 1.0 / jnp.repeat(
             t, self.cfg.diffusion_block, axis=1), 0.0)
+        read = _float32_bytes((params["final_norm"], params["head"]))
+        self.step_notes["head_grad"] = head_grad_note(
+            head_grad_by(h.shape[0], read, device_memory_bytes(),
+                         one_device=not jax.typeof(h).vma), h.shape[0], read)
         return weighted_nll(functools.partial(self.logits, params), h,
                             tokens, weight) / h.shape[1]
 

@@ -24,7 +24,7 @@ pass: not a pass over the 2.16 GB table); no ``[16881344,32]`` collective
 (``PERF.md`` §6, PR 41). Last, what the model says its traced step is made
 of (``step_notes``) and the step's ``memory_analysis()``. A described device
 says nothing of its memory, so what the program reads from the device
-(``kimi_linear.device_memory_bytes``: how many layers keep their SwiGLU's
+(``sdar_moe.device_memory_bytes``: how many layers keep their SwiGLU's
 first products) is described here too, ``BYTES_LIMIT`` by device kind. It
 takes ~20 s (a decoder cell's step one to two minutes) and says nothing
 about time: times are the chip's
@@ -56,7 +56,7 @@ def compile_step(workload: str, topology: str, chips: int) -> tuple:
 
     from benchmark import harness
     from benchmark.drivers import _program
-    from deepfm_tpu.models import kimi_linear
+    from deepfm_tpu.models import sdar_moe
 
     # A described device cannot read an executable back from the cache.
     jax.config.update("jax_enable_compilation_cache", False)
@@ -67,7 +67,7 @@ def compile_step(workload: str, topology: str, chips: int) -> tuple:
     cell = harness.load_cell(workload)
     devices = list(topo.devices)[:chips or cell.chips]
     limit = BYTES_LIMIT[devices[0].device_kind]
-    kimi_linear.device_memory_bytes = lambda: limit
+    sdar_moe.device_memory_bytes = lambda: limit
     trainer = _program.build_trainer(
         _program.make_config(dict(cell.config["flags"])), devices)
     return (trainer.step_compiled(device=devices[0]),

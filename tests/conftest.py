@@ -406,14 +406,14 @@ def step_for_v5e(v5e, no_compile_cache, compiled_once, monkeypatch):
     trainer.step_hlo_text())``: one compilation a call, whatever the test
     reads of it."""
     from deepfm_tpu.config import Config
-    from deepfm_tpu.models import kimi_linear
+    from deepfm_tpu.models import sdar_moe
     from deepfm_tpu.parallel import mesh as mesh_lib
     from deepfm_tpu.train import Trainer
 
     # the trainer picks its kernels by backend: trace what a TPU host would,
     # with the chip's memory described to what asks for it
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(kimi_linear, "device_memory_bytes",
+    monkeypatch.setattr(sdar_moe, "device_memory_bytes",
                         lambda: V5E_BYTES_LIMIT)
 
     def build(flags):

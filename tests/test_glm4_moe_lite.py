@@ -106,6 +106,9 @@ SPEC = Spec(
     no_scopes=frozenset({"kda", "kda_scan", "conv"}),
     notes=lambda trainer: {
         "attn_scores": "xla", "moe_rows": "xla",
+        # the two head passes, each its gradient beside its loss
+        "head_grad": "forward 3 products/chunk, 0.00 GB kept",
+        "mtp_head_grad": "forward 3 products/chunk, 0.00 GB kept",
         # the dense layer's SwiGLU and each expert block's shared expert:
         # none kept off a TPU
         "mlp_kept": "0/%d" % len(trainer.model.block_kinds),

@@ -70,6 +70,7 @@ def notes(trainer):
     layers = sum(ffn == "moe" for _, ffn in trainer.model.kinds)
     # (off a TPU no layer keeps its SwiGLU's first products)
     return {"kda_scan": "chunk64/sub16", "mla_scores": "xla",
+            "head_grad": "forward 3 products/chunk, 0.00 GB kept",
             "mlp_kept": "0/%d" % len(trainer.model.kinds),
             "moe_rows": "xla", "moe_rows_moved": "{moe_pairs_held}/%d" % (
                 layers * trainer.cfg.moe_pair_capacity)}

@@ -88,6 +88,7 @@ SPEC = Spec(
     no_scopes=frozenset({"kda", "kda_scan"}),
     notes=lambda trainer: {
         "conv_taps_by": "xla", "attn_scores": "xla", "moe_rows": "xla",
+        "head_grad": "forward 3 products/chunk, 0.00 GB kept",
         # the dense layers' SwiGLUs (no shared expert): none kept off a TPU
         "mlp_kept": "0/%d" % sum(
             ffn == "mlp" for _, ffn in trainer.model.kinds),

@@ -25,7 +25,7 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from decoder_contract import off_one  # noqa: E402
-from deepfm_tpu.models import get_model, kimi_linear  # noqa: E402
+from deepfm_tpu.models import get_model, kimi_linear, sdar_moe  # noqa: E402
 from deepfm_tpu.train.optimizers import build_optimizer  # noqa: E402
 import test_kimi_linear  # noqa: E402
 import test_phi4_flash  # noqa: E402
@@ -176,7 +176,8 @@ def test_the_optimizers_copies_are_their_states(optimizer):
     state = jax.eval_shape(build_optimizer(cfg).init, params)
     copies = sum(x.size for x in jax.tree.leaves(state) if x.ndim) / own
     assert copies == kimi_linear.OPTIMIZER_COPIES[optimizer]
-    assert model._held_bytes(params) == 4 * own * (2 + copies)
+    assert model._held_bytes(params, jnp.zeros((1, L), jnp.int32)) \
+        == 4 * own * (2 + copies)
 
 
 #: the whole models at two layers: Kimi-Linear's a dense layer and an expert
@@ -190,7 +191,7 @@ WHOLE = {"kimi": (test_kimi_linear.SPEC, dict(decoder_layers=2,
 
 
 def described(monkeypatch, limit):
-    monkeypatch.setattr(kimi_linear, "device_memory_bytes", lambda: limit)
+    monkeypatch.setattr(sdar_moe, "device_memory_bytes", lambda: limit)
 
 
 @pytest.mark.parametrize("which, spare, keeps, note", [
@@ -212,7 +213,7 @@ def test_which_layers_keep_where_the_memory_is_described(
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))[0]
     ids = jnp.zeros((B, L), jnp.int32)
     if spare is not None:
-        described(monkeypatch, int(model._held_bytes(params)
+        described(monkeypatch, int(model._held_bytes(params, ids)
                                    + RESERVE * B * L + spare))
     got = model._mlp_keeps(params, ids)
     assert "".join("-+"[k] for k in got) == keeps
@@ -220,10 +221,10 @@ def test_which_layers_keep_where_the_memory_is_described(
 
 
 def test_a_backend_that_is_no_tpu_or_says_nothing_keeps_nothing(monkeypatch):
-    assert kimi_linear.device_memory_bytes() == 0       # the CPU
+    assert sdar_moe.device_memory_bytes() == 0          # the CPU
     # a TPU backend whose device reports no limit (here: the CPU's None)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert kimi_linear.device_memory_bytes() == 0
+    assert sdar_moe.device_memory_bytes() == 0
     assert kimi_linear.mlp_kept_note(0, 4, 0) == "0/4"
     assert kimi_linear.mlp_kept_note(6, 6, 4_026_531_840) \
         == "6/6 layers 4.03 GB"

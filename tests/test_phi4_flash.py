@@ -63,6 +63,8 @@ SPEC = Spec(
                          "conv"}),
     notes=lambda trainer: {"mamba_scan": "lockstep chunk4/segment8",
                            "attn_scores": "xla",
+                           "head_grad":
+                           "forward 3 products/chunk, 0.00 GB kept",
                            "mlp_kept": "0/%d" % len(trainer.model.kinds)},
     refusals=(
         ({"layer_types": "mamba,gmu"}, "layer_types"),

@@ -37,6 +37,7 @@ def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
     scopes = profiling.hlo_op_scopes(text)
     assert tr.model.step_notes == {
         "attn_scores": "kernel", "attn_score_blocks": "3/4",
+        "head_grad": "forward 3 products/chunk, 0.00 GB kept",
         "moe_rows": "kernel", "moe_rows_moved": "{moe_pairs_held}/8192"}
     # the expert layer's rows move by the row kernels (rows of two lines,
     # 1,024 positions, one pass of 4,096 rows), forward and backward, all

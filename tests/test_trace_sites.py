@@ -552,6 +552,7 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
     tr, events = _sdar_fit()
     assert tr.model.step_notes == {
         "attn_scores": "xla", "moe_rows": "xla",
+        "head_grad": "forward 3 products/chunk, 0.00 GB kept",
         "moe_rows_moved": "{moe_pairs_held}/64"}
     syncs = [e["args"] for e in events if e["name"] == "train.log_sync"]
     assert [a["attn_scores"] for a in syncs] == ["xla"] * 2
