@@ -1,4 +1,4 @@
-"""Three shares of the chip's peaks for a ``--model glm4_moe_lite`` train
+"""Two shares of the chip's peaks for a ``--model glm4_moe_lite`` train
 step, in %, from ``benchmark/roofline_glm4_moe_lite.py``'s counts and
 ``peaks.json``:
 
@@ -8,25 +8,18 @@ step, in %, from ``benchmark/roofline_glm4_moe_lite.py``'s counts and
   backward, over the bf16 peak, or their operands' bytes over the peak
   bandwidth, the larger) over the own device time of the ops under the scope
   ``attn_scores``;
-* ``share="moe_matmul"``: the least time of the expert blocks' grouped
-  products (their FLOPs on the pairs the run really routed to the experts
-  held here, forward and backward, over the bf16 peak) over the device time
-  of the grouped-product kernels themselves (``roofline_sdar_moe.GROUPED``:
-  ``train_moe_matmul_roofline``'s own definition; the buffer's spare rows and
-  the forward's recomputation are in the time and in no count);
 * ``share="step"``: the least time of the whole step (the larger of its
   matrix products' FLOPs over the peak rate and its parameters' bytes over
   the peak bandwidth) over its device time.
 
 The forward's recomputation is in every time and in no count: a share reads
 low, never high. None where there is nothing to read: no trace, a driver
-that counted no pairs, a step's text with no ``attn_scores`` scope in it, or
-a trace with no grouped-product op in it.
+that counted no pairs, or a step's text with no ``attn_scores`` scope in it.
+(The expert blocks' share is ``readers/roofline_moe.py``'s.)
 """
 
 from benchmark import harness, roofline_glm4_moe_lite
 from benchmark.readers import scope_device_ms
-from benchmark.readers.roofline_sdar_moe import GROUPED
 
 
 def read(ctx, share):
@@ -40,15 +33,6 @@ def read(ctx, share):
         least = roofline_glm4_moe_lite.train_step_least_seconds(
             flags, pairs, peaks)["seconds"]
         return 100.0 * least / (ctx.trace["busy_s"] / steps)
-    if share == "moe_matmul":
-        path = scope_device_ms.newest_trace(ctx.cell.name)
-        ops = scope_device_ms.own_seconds(path, ctx.window)[0] if path else {}
-        seconds = sum(t for key, t in ops.items() if GROUPED.match(key))
-        if not seconds:
-            return None
-        least = roofline_glm4_moe_lite.moe_matmul_flops(flags, pairs) \
-            / peaks["bf16_flops_per_s"]
-        return 100.0 * least / (seconds / steps)
     if share != "attn_scores":
         raise ValueError(f"unknown share {share!r}")
     scope_ms = scope_device_ms.read(ctx, ["attn_scores"])

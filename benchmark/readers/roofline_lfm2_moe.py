@@ -1,4 +1,4 @@
-"""Four shares of the chip's peaks for a ``--model lfm2_moe`` train step,
+"""Three shares of the chip's peaks for a ``--model lfm2_moe`` train step,
 in %, from ``benchmark/roofline_lfm2_moe.py``'s counts and ``peaks.json``:
 
 * ``share="conv"``: the least time of the convolution mixers as the step
@@ -11,12 +11,6 @@ in %, from ``benchmark/roofline_lfm2_moe.py``'s counts and ``peaks.json``:
   backward, over the bf16 peak, or their operands' bytes over the peak
   bandwidth, the larger) over the own device time of the ops under the scope
   ``attn_scores``: a lane the kernel pads is in the time and in no count;
-* ``share="moe_matmul"``: the least time of the expert layers' grouped
-  products (their FLOPs on the pairs the run really routed to the experts
-  held here, forward and backward, over the bf16 peak) over the device time
-  of the grouped-product kernels themselves (``roofline_sdar_moe.GROUPED``:
-  ``train_moe_matmul_roofline``'s own definition; the buffer's spare rows and
-  the forward's recomputation are in the time and in no count);
 * ``share="step"``: the least time of the whole step (the larger of its
   matrix products' FLOPs over the peak rate and its parameters' bytes over
   the peak bandwidth) over its device time.
@@ -25,12 +19,11 @@ The forward's recomputation is in every time and, but for the convolution
 mixer's, in no count: a share reads low, never high. None where there is
 nothing to read: no trace, a driver that counted no pairs, or, for a scope's
 share, a step's text with no such scope in it (a program from before the
-scope), or a trace with no grouped-product op in it.
+scope). (The expert layers' share is ``readers/roofline_moe.py``'s.)
 """
 
 from benchmark import harness, roofline_lfm2_moe
 from benchmark.readers import scope_device_ms
-from benchmark.readers.roofline_sdar_moe import GROUPED
 
 #: share -> (the scopes whose own time it is over, its count)
 SCOPED = {"conv": (["conv", "conv_taps"],
@@ -50,15 +43,6 @@ def read(ctx, share):
         least = roofline_lfm2_moe.train_step_least_seconds(
             flags, pairs, peaks)["seconds"]
         return 100.0 * least / (ctx.trace["busy_s"] / steps)
-    if share == "moe_matmul":
-        path = scope_device_ms.newest_trace(ctx.cell.name)
-        ops = scope_device_ms.own_seconds(path, ctx.window)[0] if path else {}
-        seconds = sum(t for key, t in ops.items() if GROUPED.match(key))
-        if not seconds:
-            return None
-        least = roofline_lfm2_moe.moe_matmul_flops(flags, pairs) \
-            / peaks["bf16_flops_per_s"]
-        return 100.0 * least / (seconds / steps)
     if share not in SCOPED:
         raise ValueError(f"unknown share {share!r}")
     scopes, least = SCOPED[share]

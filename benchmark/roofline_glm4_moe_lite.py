@@ -19,10 +19,6 @@ value product of every (query, key) pair with key <= query, a held head and
 block (the stack's layers and the module's block), forward and twice that
 backward, the same whatever implements it; against the bytes of q and k
 (256 wide) and v and o (256 wide) in the operands' two bytes, once each way.
-
-The routed experts' grouped products alone (``moe_matmul_flops``): the three
-products of every (position, expert) pair the run routed to an expert held
-here, forward and twice that backward, as ``roofline_lfm2_moe`` counts them.
 """
 
 from __future__ import annotations
@@ -110,12 +106,6 @@ def train_step_least_seconds(flags: dict, pairs: float, peaks: dict) -> dict:
     its parameters' bytes over the peak bandwidth."""
     return _least(3.0 * sum(forward_flops(flags, pairs).values()),
                   float(BYTES_PER_PARAM * param_count(flags)["all"]), peaks)
-
-
-def moe_matmul_flops(flags: dict, pairs: float) -> float:
-    """The routed experts' grouped products' FLOPs of one step, forward and
-    backward, on the routed pairs only."""
-    return 3.0 * forward_flops(flags, pairs)["experts"]
 
 
 def attn_scores_least_seconds(flags: dict, peaks: dict) -> dict:

@@ -19,11 +19,6 @@ value product of every (query, key) pair with key <= query over the head's
 the bytes of q and o (a query head each) and k and v (a key/value head each)
 in the operands' two bytes, once each way.
 
-The routed experts' grouped products alone (``moe_matmul_flops``): the
-three products of every (position, expert) pair the run routed to an expert
-held here, forward and twice that backward; the buffer's spare rows and the
-forward's recomputation are no work.
-
 The convolution mixer alone (``conv_least_seconds``), **as the step runs
 it**: its two products (``[T, d] x [d, 3d]`` and ``[T, d] x [d, d]``) four
 times over (forward, the layer's recomputation, and the backward pass's two
@@ -126,12 +121,6 @@ def train_step_least_seconds(flags: dict, pairs: float, peaks: dict) -> dict:
     its parameters' bytes over the peak bandwidth."""
     return _least(3.0 * sum(forward_flops(flags, pairs).values()),
                   float(BYTES_PER_PARAM * param_count(flags)["all"]), peaks)
-
-
-def moe_matmul_flops(flags: dict, pairs: float) -> float:
-    """The routed experts' grouped products' FLOPs of one step, forward and
-    backward, on the routed pairs only."""
-    return 3.0 * forward_flops(flags, pairs)["experts"]
 
 
 def attn_scores_least_seconds(flags: dict, peaks: dict) -> dict:

@@ -73,11 +73,6 @@ def forward_flops(flags: dict, pairs: float) -> Dict[str, float]:
     }
 
 
-def moe_matmul_flops(flags: dict, pairs: float) -> float:
-    """The grouped products' FLOPs of one step, forward and backward."""
-    return 3.0 * forward_flops(flags, pairs)["experts"]
-
-
 def train_step_least_seconds(flags: dict, pairs: float, peaks: dict) -> dict:
     """The least time of one step: the larger of its matrix products' FLOPs
     (forward and backward: three times the forward's) over the peak rate and

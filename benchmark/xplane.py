@@ -20,9 +20,12 @@ COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)")
 #: The opcode in an op's HLO text: the first ``word(`` after the `` = ``.
 OPCODE = re.compile(r"([a-z][a-z0-9\-]*)\(")
-#: Spans an idle gap may be named for, in the program's own words.
+#: Spans an idle gap may be named for, in the program's own words: what the
+#: fit thread, the staging thread, the collector or the server was doing
+#: while the device had nothing to run.
 GAP_SPANS = ("stage.wait", "stage.transfer", "train.dispatch", "serve.batch",
-             "serve.handoff_wait", "serve.flush")
+             "serve.handoff_wait", "serve.flush", "stage.input_wait",
+             "train.log_sync", "host.gc")
 
 Interval = Tuple[float, float]
 

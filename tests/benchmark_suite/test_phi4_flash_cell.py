@@ -183,12 +183,13 @@ def test_the_cell_its_traffic_and_its_who_are_the_issues():
             "train_unscoped_device_ms", "compiles_in_window.train",
             "dispatch_interval_ms_p50", "input_ns_per_record",
             "stage_transfer_ms", "input_wait_ms_max", "input_busy_share",
-            } | NEW == set(cell.per_layer)
+            } | NEW <= set(cell.per_layer)  # what a later PR adds is welcome
     # entries are found by name, never by position: a later PR appends its
     # own
     for m in BENCH["per_layer"]:
         if m["name"] in ("train_cross_device_ms",   # DLRM's cross network
                          "train_moe_device_ms", "moe_pairs_over_buffer",
+                         "train_moe_roofline",      # it has no experts
                          "train_kda_device_ms", "train_conv_device_ms",
                          "train_kda_scan_roofline"):
             assert CELL not in m["workloads"], m["name"]

@@ -31,6 +31,16 @@ NEW_METRICS = {
 }
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
+#: A listed metric has to be in every traced line, and a reader with nothing
+#: to read leaves its metric out. ``host_gc_ms_max`` is the longest collection
+#: of the window: it lists the cells in which one falls in every 12 s (the
+#: rankers': 18 to 122 dispatches of 8 steps, each with host batches of its
+#: own). The decoders run 20 to 43 dispatches of a step or two: no
+#: collection ran in any of their traced chip runs (PERF.md section 7
+#: row 20(c); the driver refused PR 31 for listing the metric there).
+WORKLOADS = {"host_gc_ms_max": ["deepfm-criteo.train-files",
+                                "deepfm-criteo-host4.train-files",
+                                "dlrm-dcnv2-criteo1tb.train-files"]}
 
 
 @pytest.fixture(scope="module")
@@ -218,7 +228,8 @@ def test_new_metric_resolves(name):
     entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
     assert entry["moves"] == "train_examples_per_s_per_chip"
     assert entry["better"] == "lower"
-    assert entry["workloads"] == [w["name"] for w in BENCH["workloads"]]
+    assert entry["workloads"] == WORKLOADS.get(
+        name, [w["name"] for w in BENCH["workloads"]])
     spec = harness.load_json("metrics", f"{name}.json")
     reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
     inspect.signature(reader.read).bind(None, **spec["args"])

@@ -225,6 +225,7 @@ def test_the_cell_its_traffic_and_its_who_are_the_issues():
            "train_step_roofline.solar_open2"}
     assert {"train_kda_device_ms", "train_mlp_device_ms",
             "train_attn_device_ms", "train_moe_device_ms",
+            "train_moe_roofline",         # every expert cell's since PR 50
             "train_head_device_ms", "moe_expert_load_max_over_mean",
             "moe_pairs_over_buffer", "device_idle_share.train",
             "peak_hbm_gb.train", "train_step_device_ms",
@@ -233,13 +234,12 @@ def test_the_cell_its_traffic_and_its_who_are_the_issues():
             "train_unscoped_device_ms", "compiles_in_window.train",
             "dispatch_interval_ms_p50", "input_ns_per_record",
             "stage_transfer_ms", "input_wait_ms_max", "input_busy_share",
-            } | new == set(cell.per_layer)
+            } | new <= set(cell.per_layer)  # what a later PR adds is welcome
     # not on `host_gc_ms_max` (PERF.md section 7 row 20(c)) nor on the other
     # models' rooflines (entries are found by name, never by position: a
     # later PR appends its own)
     for m in BENCH["per_layer"]:
-        if m["name"] in ("host_gc_ms_max", "train_moe_matmul_roofline",
-                         "train_kda_scan_roofline",
+        if m["name"] in ("host_gc_ms_max", "train_kda_scan_roofline",
                          "train_step_roofline.kimi_linear"):
             assert CELL not in m["workloads"], m["name"]
     for name in cell.per_layer:
