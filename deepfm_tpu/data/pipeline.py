@@ -330,7 +330,8 @@ class _DrainPool:
     def get(self):
         if self._ex is None:
             from concurrent.futures import ThreadPoolExecutor  # noqa: PLC0415
-            self._ex = ThreadPoolExecutor(self._n)
+            self._ex = ThreadPoolExecutor(
+                self._n, thread_name_prefix="pipeline-decode")
         return self._ex
 
     def shutdown(self) -> None:
@@ -541,7 +542,8 @@ class CtrPipeline:
         else:
             import collections  # noqa: PLC0415
             from concurrent.futures import ThreadPoolExecutor  # noqa: PLC0415
-            with ThreadPoolExecutor(n_threads) as ex:
+            with ThreadPoolExecutor(
+                    n_threads, thread_name_prefix="pipeline-read") as ex:
                 inflight: "collections.deque" = collections.deque()
                 for job in jobs:
                     inflight.append(ex.submit(decode, job))

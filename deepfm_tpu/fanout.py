@@ -101,7 +101,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         procs.append(p)
         t = threading.Thread(
             target=_pump, args=(p.stdout, sys.stdout, f"worker {pid}"),
-            daemon=True)
+            name=f"fanout-pump-{pid}", daemon=True)
         t.start()
         pumps.append(t)
 

@@ -18,14 +18,24 @@ the pace, and further apart when it was starved. (``train.dispatch`` starts
 will not do: the fit thread reads the loss back every ``log_steps`` steps
 and enqueues in bunches.) Every interval longer than MS is printed with the
 time each of the spans that can hold a dispatch back covered of it
-(``STALL_SPANS``: ``stage.input_wait`` — the staging thread waiting for the
+(``STALL_SPANS``: ``host.stall`` — the tracer's pulse thread woke that late:
+the whole process ran no Python, whatever the other spans say their threads
+were doing; ``stage.input_wait`` — the staging thread waiting for the
 input thread; ``input.pool_fill`` / ``input.pool_drain`` / ``input.emit`` —
 the input thread reading+framing, permuting+decoding, slicing; ``host.gc`` —
 a collection, which stops every thread; ``train.log_sync`` — the loss read
 back at the log cadence; ``stage.transfer`` — the host->device copy; and
 ``stage.wait`` itself, the part the device was simply busy; and
 ``compile.backend`` — a program compiled or fetched in the middle of the run,
-named by its ``fun_name`` under the stall). TUNING §17 lists every span.
+named by its ``fun_name`` under the stall). Under each interval a line a
+``host.stall`` that overlaps it: its ``cause`` (``host_cpu``, ``gil``,
+``memory``, ``io``, ``frozen``: ``obs.trace.stall_cause``), ``late_ms`` and
+what the operating system counted meanwhile (``runq_ms``, ``cpu_ms``,
+``steal_ms``, ``busiest_thread``). Where the trace holds the pulse's spans
+the report has a "host pulse" line: the stalls by cause (count and total
+ms) and the median ``late_ms_max`` of the quiet seconds (``host.pulse``
+spans in which no beat stalled): this host's baseline. A trace that
+predates the pulse reports as before. TUNING §17 lists every span.
 
 Where the trace holds the process's start-up record (``obs.startup``: the
 ``setup.*`` phases and JAX's ``compile.*`` timings up to the first dispatch,
@@ -140,11 +150,13 @@ import argparse
 import collections
 import json
 import os
+import statistics
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from deepfm_tpu.obs import startup as startup_lib  # noqa: E402  (stdlib-only)
+from deepfm_tpu.obs import trace as trace_lib  # noqa: E402  (stdlib-only)
 
 
 def _pct(sorted_vals, q):
@@ -250,9 +262,12 @@ def summarize(events):
 
 
 #: Spans that can hold a dispatch back, in the order they are reported.
-STALL_SPANS = ("stage.input_wait", "input.pool_fill", "input.pool_drain",
-               "input.emit", "host.gc", "train.log_sync", "stage.transfer",
-               "stage.wait", "compile.backend")
+STALL_SPANS = ("host.stall", "stage.input_wait", "input.pool_fill",
+               "input.pool_drain", "input.emit", "host.gc", "train.log_sync",
+               "stage.transfer", "stage.wait", "compile.backend")
+#: Of a ``host.stall``'s attributes, those printed under a stalled interval.
+HOST_STALL_KEYS = ("cause", "late_ms", "runq_ms", "cpu_ms", "steal_ms",
+                   "busiest_thread")
 
 
 def stalls(events, threshold_ms):
@@ -260,7 +275,9 @@ def stalls(events, threshold_ms):
     process) over ``threshold_ms``: one dict each with the ``seq`` of the
     transfer that waited last, the interval and, per ``STALL_SPANS`` name,
     the milliseconds of the interval that spans of that name cover (summed
-    over threads, so two busy threads can cover more than the interval)."""
+    over threads, so two busy threads can cover more than the interval),
+    and under ``host_stalls`` the ``HOST_STALL_KEYS`` of each ``host.stall``
+    that overlaps it."""
     spans = [e for e in events if e.get("ph") == "X"]
     by_pid = collections.defaultdict(list)    # pid -> [(end, stage.wait)]
     for e in spans:
@@ -273,7 +290,7 @@ def stalls(events, threshold_ms):
             if b - a <= threshold_ms * 1e3:
                 continue
             cover = dict.fromkeys(STALL_SPANS, 0.0)
-            compiled = []
+            compiled, host_stalls = [], []
             for e in spans:
                 if e["name"] in cover and e.get("pid") == pid:
                     t0 = float(e["ts"])
@@ -283,11 +300,37 @@ def stalls(events, threshold_ms):
                         if e["name"] == "compile.backend":
                             compiled.append(
                                 e.get("args", {}).get("fun_name", "?"))
+                        elif e["name"] == "host.stall":
+                            args = e.get("args", {})
+                            host_stalls.append({k: args[k] for k in
+                                                HOST_STALL_KEYS if k in args})
             out.append({"seq": wait.get("args", {}).get("seq"),
                         "at_ms": (a - waits[0][0]) / 1e3,
                         "interval_ms": (b - a) / 1e3, "cover_ms": cover,
-                        "compiled": compiled})
+                        "compiled": compiled, "host_stalls": host_stalls})
     return out
+
+
+def host_pulse(events):
+    """What the tracer's pulse recorded: ``stalls`` by cause (count and
+    total ms of the ``host.stall`` spans), the number of ``host.pulse``
+    spans and the median ``late_ms_max`` of the quiet ones (no beat of
+    theirs stalled). None for a trace without the pulse."""
+    by_cause = {}
+    for e in events:
+        if e.get("name") == "host.stall" and e.get("ph") == "X":
+            row = by_cause.setdefault(e.get("args", {}).get("cause", "?"),
+                                      {"count": 0, "total_ms": 0.0})
+            row["count"] += 1
+            row["total_ms"] += float(e["dur"]) / 1e3
+    late = [float(e["args"]["late_ms_max"]) for e in events
+            if e.get("name") == "host.pulse" and e.get("ph") == "X"]
+    if not by_cause and not late:
+        return None
+    quiet = sorted(v for v in late if v <= trace_lib.STALL_MS)
+    return {"stalls": by_cause, "pulses": len(late),
+            "quiet_late_ms_max_median":
+                statistics.median(quiet) if quiet else None}
 
 
 #: ``compile.*`` span -> its column in the start-up section's table.
@@ -570,6 +613,7 @@ def main(argv=None):
     kept = kept_products(events)
     moved = expert_rows(events)
     boots = start_up(events)
+    pulse = host_pulse(events)
 
     if args.json:
         doc = {
@@ -596,6 +640,8 @@ def main(argv=None):
             doc["expert_rows"] = moved
         if boots:
             doc["start_up"] = boots
+        if pulse is not None:
+            doc["host_pulse"] = pulse
         print(json.dumps(doc, indent=2))
         return 0
 
@@ -713,8 +759,21 @@ def main(argv=None):
               f"covered (ms): {cover or 'by no known span'}"
               + (f"; compiled: {', '.join(st['compiled'])}"
                  if st["compiled"] else ""))
+        for under in st["host_stalls"]:
+            print("  host.stall: " + ", ".join(
+                f"{k} {v:.1f}" if isinstance(v, float) else f"{k} {v}"
+                for k, v in under.items()))
     if slow is not None:
         print(f"{len(slow)} dispatch intervals over {args.stalls:g} ms")
+    if pulse is not None:
+        by_cause = "; ".join(
+            f"{cause} {row['count']}, {row['total_ms']:.1f} ms"
+            for cause, row in sorted(pulse["stalls"].items()))
+        quiet = pulse["quiet_late_ms_max_median"]
+        print(f"host pulse: {sum(r['count'] for r in pulse['stalls'].values())}"
+              f" stalls ({by_cause or 'none'}) in {pulse['pulses']} s of "
+              "pulses; a quiet second's late_ms_max, median: "
+              + ("no quiet second" if quiet is None else f"{quiet:.2f} ms"))
     return 0
 
 
