@@ -96,8 +96,9 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
                   ) -> dict:
     """fused_fm and take_rows_pallas, forward and backward, and put_rows
     against their XLA legs at (B, F, K) of ``shape``; the block-masked
-    attention kernel against the chunked XLA path and the expert layer's row
-    kernels against take and scatter-add at shapes of their own.
+    attention kernel against the chunked XLA path, the expert layer's row
+    kernels against take and scatter-add and its grouped products against
+    ``jax.lax.ragged_dot`` at shapes of their own.
     ``interpret=False`` is the compiled path (TPU only); the CPU rehearsal
     passes True."""
     import jax
@@ -270,6 +271,58 @@ def check_kernels(shape: Dict[str, object], *, interpret: bool = False
     assert all(e <= lim for e, lim in zip(errs, limits)), (
         f"moe rows vs XLA: {errs} > {limits}")
     out["moe_rows_max_rel_err"] = max(errs)
+
+    # the expert layer's grouped products (ops/pallas_grouped_dot): a pass
+    # of the SDAR cell's shape (16,384 rows of 2,048 by 16 experts of 768;
+    # the rehearsal's interpreter a small one), bfloat16 operands, half full
+    # and empty, the rows and both gradients against ``jax.lax.ragged_dot``
+    # over the whole buffer with the spare rows (zeros) in the last group.
+    # The kernels leave the rows past the prefix as they found them: they
+    # are compared on the prefix.
+    from deepfm_tpu.ops import pallas_grouped_dot as pgd
+
+    buf, width, hidden, groups = ((512, 256, 128, 4) if interpret
+                                  else (16384, 2048, 768, 16))
+    a = jnp.asarray(rng.normal(size=(buf, width)), jnp.bfloat16)
+    mats = jnp.asarray(rng.normal(size=(groups, width, hidden)) * 0.03,
+                       jnp.bfloat16)
+    # (a cotangent bfloat16 holds: the kernels round a float32 one to it,
+    # as the MXU does for XLA's)
+    dy = jnp.asarray(rng.normal(size=(buf, hidden)), jnp.bfloat16).astype(
+        jnp.float32)
+    errs = []
+    for held in (buf // 2 - 37, 0):
+        share = rng.uniform(0.7, 1.3, groups)
+        ends = np.round(np.cumsum(share / share.sum()) * held).astype(
+            np.int32)
+        ends[-1] = held
+        ends, valid = jnp.asarray(ends), (jnp.arange(buf) < held)[:, None]
+        a_held = jnp.where(valid, a, 0).astype(jnp.bfloat16)
+
+        def by_kernel(a, w):
+            return pgd.grouped_dot(a, w, ends, interpret=interpret)
+
+        def by_ragged_dot(a, w):
+            sizes = jnp.diff(ends, prepend=0)
+            return jax.lax.ragged_dot(
+                a, w, sizes.at[-1].add(buf - held),
+                preferred_element_type=jnp.float32)
+
+        def products_and_grads(f):
+            def loss(a, w):
+                o = f(a, w)
+                return jnp.sum(jnp.where(valid, o * dy, 0.0)), o
+            (_, o), (da, dw) = jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True))(a_held, mats)
+            return jnp.where(valid, o, 0.0), jnp.where(valid, da, 0), dw
+
+        got, want = (products_and_grads(f)
+                     for f in (by_kernel, by_ragged_dot))
+        assert all(np.isfinite(np.asarray(g, np.float32)).all() for g in got)
+        errs += [max_rel(g, w) for g, w in zip(got, want)]
+    # (the sums differ in their order; the gradients leave in bfloat16)
+    assert max(errs) <= 2.0 ** -7, f"grouped products vs ragged_dot: {errs}"
+    out["moe_grouped_dot_max_rel_err"] = max(errs)
 
     # the selective scan as the program ships it here
     # (models/phi4_flash.selective_scan by ``scan_by``'s word: on a TPU the

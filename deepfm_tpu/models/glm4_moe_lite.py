@@ -198,7 +198,7 @@ class Glm4MoeLite(SelectionBias, KimiLinear):
             seq, w, one_device=one_device) == "kernel" for w in widths) \
             else "xla"
         self.step_notes.update(attn_notes(scores_by, causal, seq, 1))
-        return {"rows_by": self._rows_by(ids, one_device),
+        return {**self._moe_paths(ids, one_device),
                 "scores_by": scores_by}
 
     def _mixer(self, mixer: str, lp: Dict[str, jnp.ndarray], x: jnp.ndarray,

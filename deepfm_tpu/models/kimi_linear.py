@@ -92,7 +92,8 @@ from . import common, sdar_moe
 from .graph import GraphModel
 from .sdar_moe import (ScoreMask, _dot, _float32_bytes, _operand, _scores_xla,
                        expert_layer, head_grad_by, head_grad_note, moe_notes,
-                       moe_rows_by, rms_norm, route, weighted_nll)
+                       moe_products_by, moe_rows_by, rms_norm, route,
+                       weighted_nll)
 
 #: The step's counts, in the model state and (by ``step_counts``) the metrics.
 COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
@@ -520,23 +521,29 @@ class KimiLinear(GraphModel):
                 k_head, (d, cfg.feature_size))
         return params, self.init_counts()
 
-    def _rows_by(self, ids: jnp.ndarray, one_device: bool) -> str:
-        """``moe_rows_by``'s word for the step of ``ids`` [B, L];
-        ``step_notes`` is told."""
+    def _moe_paths(self, ids: jnp.ndarray, one_device: bool
+                   ) -> Dict[str, str]:
+        """``moe_rows_by``'s and ``moe_products_by``'s words for the step of
+        ``ids`` [B, L], as ``expert_layer``'s keywords; ``step_notes`` is
+        told."""
         cfg = self.cfg
-        rows_by = moe_rows_by(cfg.embedding_size, ids.size,
-                              cfg.moe_pair_capacity, one_device=one_device)
+        width, hidden = cfg.embedding_size, cfg.moe_expert_width
+        rows_by = moe_rows_by(width, ids.size, cfg.moe_pair_capacity,
+                              one_device=one_device)
+        products_by = moe_products_by(width, hidden, cfg.moe_pair_capacity,
+                                      one_device=one_device)
         self.step_notes.update(moe_notes(
             rows_by, cfg.moe_pair_capacity,
-            sum(ffn == "moe" for _, ffn in self.block_kinds)))
-        return rows_by
+            sum(ffn == "moe" for _, ffn in self.block_kinds), products_by,
+            width, hidden))
+        return {"rows_by": rows_by, "products_by": products_by}
 
     def _paths(self, ids: jnp.ndarray, one_device: bool) -> Dict[str, str]:
         """What the step of ``ids`` [B, L] is made of where the code picks
-        from what it can see, as ``_layer``'s keywords (``rows_by``; a
+        from what it can see, as ``_layer``'s keywords (``_moe_paths``'s; a
         model's further ones go to its ``_mixer``); ``step_notes`` is told."""
         self.step_notes["mla_scores"] = "xla"
-        return {"rows_by": self._rows_by(ids, one_device),
+        return {**self._moe_paths(ids, one_device),
                 "scan_by": self._scan_by(ids, one_device)}
 
     def _scan_by(self, ids: jnp.ndarray, one_device: bool) -> str:
@@ -625,11 +632,13 @@ class KimiLinear(GraphModel):
                          cdt=self.cdt), {}
 
     def _layer(self, mixer: str, ffn: str, x: jnp.ndarray,
-               lp: Dict[str, jnp.ndarray], rows_by: str = "xla", **paths
+               lp: Dict[str, jnp.ndarray], rows_by: str = "xla",
+               products_by: str = "xla", **paths
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """One block: ``h = x + Mixer(RMSNorm(x))``,
         ``h + FFN(RMSNorm(h))`` -> (the stream, the layer's counts).
-        ``paths`` is what ``_paths`` says beyond ``rows_by``, the mixer's."""
+        ``paths`` is what ``_paths`` says beyond the expert layer's
+        ``rows_by`` and ``products_by``, the mixer's."""
         cfg = self.cfg
         # (the barrier: ``models.sdar_moe.SdarMoE.hidden``)
         lp = jax.lax.optimization_barrier(lp)
@@ -641,7 +650,8 @@ class KimiLinear(GraphModel):
         y, moe_counts = expert_layer(
             lp, h, top_k=cfg.moe_top_k, first_expert=cfg.moe_first_expert,
             capacity=cfg.moe_pair_capacity, eps=eps, cdt=self.cdt,
-            route_by=self.route_by, rows_by=rows_by)
+            route_by=self.route_by, rows_by=rows_by,
+            products_by=products_by)
         out = h + y
         if "shared_w_gate" in lp:
             out = out + swiglu(lp, "shared_", h, eps=eps, cdt=self.cdt)

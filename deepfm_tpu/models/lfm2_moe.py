@@ -165,7 +165,7 @@ class Lfm2Moe(SelectionBias, KimiLinear):
 
     def _paths(self, ids: jnp.ndarray, one_device: bool) -> Dict[str, str]:
         cfg = self.cfg
-        paths = {"rows_by": self._rows_by(ids, one_device)}
+        paths = self._moe_paths(ids, one_device)
         if any(mixer == "full_attention" for mixer, _ in self.kinds):
             seq = ids.shape[1]
             paths["scores_by"] = attn_scores_by(seq, cfg.attn_head_dim,

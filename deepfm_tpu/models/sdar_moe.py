@@ -35,28 +35,38 @@ code stands in for the absent chips.
 into a buffer of ``--moe_pair_capacity`` rows (static shapes; made up to whole
 ``PRODUCT_TILE_ROWS``, ``pass_rows``), which is computed in equal passes of at
 most ``PASS_ROWS`` rows (one pass's memory): the three products run as grouped
-products over the held experts' (``jax.lax.ragged_dot``), and the rows are
-weighted and added back to their positions. The sorted pairs put the held
-experts' first, so a pass's real rows are a prefix of it; the buffer's spare
-rows are computed as zeros (they ride the products' last group: their inputs
-have to be zeros and not whatever lay there, or a stray NaN times a zero
-cotangent would reach a weight's gradient), so the products cost the same
-whatever the routing. What moves a pass's rows from and to their positions is
-picked from what the code can see (``moe_rows_by``: backend, the row's width,
-whole tiles of positions and of buffer rows, one device's program; no flag):
-on a TPU at a row of whole 128-lane lines the two kernels of
-``ops/pallas_moe_rows``, one DMA a row over the prefix only — ``gather`` (the
-rows of RMSNorm(x), cast to the products' type on their way; in the backward
-pass their cotangent summed into the positions', float32, in the pass loop's
-carry) and ``combine`` (a pass's weighted rows added into the layer's sum in
-place, a group's positions at a time; backward, the sum's cotangent taken to
-the rows) — and everywhere else (a CPU, the tests' narrow rows, a step across
-data replicas) ``jnp.take`` and ``.at[].add`` over every row of the buffer,
-spare ones masked. No pair is dropped silently: pairs beyond the buffer's rows
-are counted (``moe_pairs_over_buffer``, cumulative), with the pairs held, the
-fullest expert's count, the fullest layer's pairs (what the buffer has to
-hold) and the masked positions of the last step; the counts ride the model
-state and the step's metrics.
+products over the held experts', and the rows are weighted and added back to
+their positions. The sorted pairs put the held experts' first, so a pass's
+real rows are a prefix of it, ``ends[-1]`` rows, and the rest of the buffer
+(twice the mean load) is spare. What multiplies a pass's rows and what moves
+them from and to their positions are each picked from what the code can see
+(``moe_products_by``, ``moe_rows_by``: backend, widths in whole 128-lane
+lines, whole tiles of positions and of buffer rows, one device's program; no
+flag). On a TPU at such widths the products are the kernels of
+``ops/pallas_grouped_dot`` — forward, in the pass's recomputation and in both
+gradients a grid over the row tiles the groups really reach, so a pass costs
+what its prefix holds and an empty pass next to nothing — and the rows move
+by the two kernels of ``ops/pallas_moe_rows``, one DMA a row over the prefix
+only: ``gather`` (the rows of RMSNorm(x), cast to the products' type on their
+way; in the backward pass their cotangent summed into the positions',
+float32, in the pass loop's carry) and ``combine`` (a pass's weighted rows
+added into the layer's sum in place, a group's positions at a time; backward,
+the sum's cotangent taken to the rows). **The spare rows of a product's
+result are then never written: they hold anything, and nothing that sums
+reads them** — the row kernels and the gradients' kernels stop at the prefix
+or select before they multiply, the XLA row path reads under
+``jnp.where(valid, ...)``, and ``silu(gate) * up`` between the products is
+elementwise. Everywhere else (a CPU, the tests' narrow rows, a step across
+data replicas) the products are ``jax.lax.ragged_dot`` over every row of the
+buffer, the spare rows in its last group (their inputs have to be zeros and
+not whatever lay there, or a stray NaN times a zero cotangent would reach a
+weight's gradient: both row paths write them so), and the rows move by
+``jnp.take`` and ``.at[].add`` over every row, spare ones masked. No pair is
+dropped silently: pairs beyond the buffer's rows are counted
+(``moe_pairs_over_buffer``, cumulative), with the pairs held, the fullest
+expert's count, the fullest layer's pairs (what the buffer has to hold) and
+the masked positions of the last step; the counts ride the model state and
+the step's metrics.
 
 **The masked scores** (``masked_scores``). Between the rotated q/k/v and
 ``wo`` the block computes softmax(mask(q k^T / sqrt(D))) v in one of two
@@ -109,7 +119,7 @@ from typing import Any, Callable, Dict, Hashable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import block_attention, pallas_moe_rows
+from ..ops import block_attention, pallas_grouped_dot, pallas_moe_rows
 from . import common
 from .graph import GraphModel
 
@@ -142,12 +152,14 @@ ATTN_BLOCK = 512
 #: its rows' inputs, both hidden products and the output (15 KB a row at the
 #: published widths), and is made again in the backward pass.
 PASS_ROWS = 20480
-#: Rows a pass is made whole multiples of, where it has that many: the tile
-#: of XLA:TPU's grouped product. ``jax.lax.ragged_dot`` over a buffer that is
-#: no multiple of 128 rows took 5.7 x as long (3,280 rows against 3,328:
-#: 4.4 / 12 ms forward / with both gradients against 0.76 / 2.55), and over a
-#: multiple of 128 that is none of 256 about 1.5 x (4,992 against 5,120); the
-#: rows added are spare rows (zeros), which cost 0.4 us each (PERF.md, PR 37).
+#: Rows a pass is made whole multiples of, where it has that many: the row
+#: tile of the grouped products, the kernels' (``pallas_grouped_dot
+#: .TILE_ROWS`` divides it) and XLA:TPU's. ``jax.lax.ragged_dot`` over a
+#: buffer that is no multiple of 128 rows took 5.7 x as long (3,280 rows
+#: against 3,328: 4.4 / 12 ms forward / with both gradients against 0.76 /
+#: 2.55), and over a multiple of 128 that is none of 256 about 1.5 x (4,992
+#: against 5,120; PERF.md, PR 37). The rows added are spare rows: the kernels
+#: never visit them, ``ragged_dot`` pays 0.4 us each.
 PRODUCT_TILE_ROWS = 256
 
 
@@ -437,14 +449,35 @@ def moe_rows_by(width: int, positions: int, capacity: int, *,
         width, positions, pass_rows(capacity)[1], backend) else "xla")
 
 
-def moe_notes(rows_by: str, capacity: int, layers: int) -> Dict[str, str]:
-    """What ``step_notes`` says of the expert layers' row movement:
-    ``moe_rows`` (``kernel`` / ``xla``) and ``moe_rows_moved``, the last
-    step's held pairs (the rows a kernel moves; XLA's ops move them all)
-    over the buffers' rows, the count filled in where the notes are
-    written."""
+def moe_products_by(width: int, hidden: int, capacity: int, *,
+                    one_device: bool = True, backend: Optional[str] = None
+                    ) -> str:
+    """``kernel`` where a pass's grouped products are the kernels of
+    ``ops/pallas_grouped_dot``, which visit the valid prefix's row tiles
+    only (``supported``: a TPU backend, the model's and the experts' widths
+    in whole 128-lane lines, a pass in whole row tiles; and a step that is
+    one device's program, as ``attn_scores_by`` asks), else ``xla``
+    (``jax.lax.ragged_dot`` over every row of the buffer, the spare rows in
+    the last group): read from the backend, the shapes and the mesh."""
+    return ("kernel" if one_device and pallas_grouped_dot.supported(
+        pass_rows(capacity)[1], width, hidden, backend) else "xla")
+
+
+def moe_notes(rows_by: str, capacity: int, layers: int,
+              products_by: str = "xla", width: int = 0, hidden: int = 0
+              ) -> Dict[str, str]:
+    """What ``step_notes`` says of the expert layers: ``moe_rows``
+    (``kernel`` / ``xla``: what moves the rows), ``moe_products`` (``kernel
+    <tiling>`` / ``xla``: what multiplies them, ``moe_products_by``'s word
+    and the kernels' tiles at the experts' ``width`` and ``hidden``) and
+    ``moe_rows_moved``, the last step's held pairs over the buffers' rows,
+    the count filled in where the notes are written: the rows a row kernel
+    moves (XLA's ops move them all) and, to a row tile a group, the share of
+    the buffer the product kernels visit (``ragged_dot`` visits it all)."""
     passes, rows = pass_rows(capacity)
     return {"moe_rows": rows_by,
+            "moe_products": products_by if products_by != "kernel" else
+            "kernel " + pallas_grouped_dot.tiling(width, hidden),
             "moe_rows_moved": "{moe_pairs_held}/%d" % (layers * passes * rows)}
 
 
@@ -452,7 +485,7 @@ def moe_notes(rows_by: str, capacity: int, layers: int) -> Dict[str, str]:
 def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
                  top_k: int, first_expert: int, capacity: int,
                  eps: float, cdt: jnp.dtype, route_by: Callable = route,
-                 rows_by: str = "xla"
+                 rows_by: str = "xla", products_by: str = "xla"
                  ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The held experts' part of ``MoE(RMSNorm(x))``: x [B, S, d] ->
     ([B, S, d], counts). ``lp['w_gate']`` [held, d, f] says how many experts
@@ -463,7 +496,8 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
     beyond them are counted and add nothing. ``route_by(xn, router, top_k,
     bias=)`` is the model's router (``route``, with what the model binds of its
     keywords); ``rows_by`` is ``moe_rows_by``'s word for what moves a pass's
-    rows from and to their positions. Where the layer has a selection bias
+    rows from and to their positions, ``products_by`` ``moe_products_by``'s
+    for what multiplies them. Where the layer has a selection bias
     (``lp['select_bias']`` [experts]: ``route``'s ``bias``) the router picks
     by score + bias, and the layer's counts say at how many positions the bias
     moved the picks (``BIAS_MOVED``)."""
@@ -501,12 +535,9 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
         rows = jax.lax.dynamic_slice(order, (start,), (buffer_rows,))
         valid = jax.lax.dynamic_slice(sorted_key, (start,),
                                       (buffer_rows,)) < n_held
-        # Group sizes of this pass's rows: the held experts' loads, cut to
-        # the pass; the rows left over (zeros) ride the last group, so a
-        # pass costs the same whatever the routing.
+        # Where each held expert's rows end in this pass: its load, cut to
+        # the pass. The rows from ends[-1] on name no pair.
         ends = jnp.clip(jnp.cumsum(load) - start, 0, buffer_rows)
-        sizes = jnp.diff(ends, prepend=0)
-        sizes = sizes.at[-1].add(buffer_rows - ends[-1])
         tok = rows // top_k
         if by_kernel:       # the valid prefix's rows; the others zeros
             out, xn_through = carry
@@ -517,9 +548,16 @@ def expert_layer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *,
             xs = jnp.where(valid[:, None], jnp.take(xn, tok, axis=0), 0.0)
         xs = _operand(xs, cdt)
 
-        def grouped(a, w):
-            return jax.lax.ragged_dot(a, w, sizes,
-                                      preferred_element_type=jnp.float32)
+        if products_by == "kernel":     # the prefix's row tiles only: the
+            def grouped(a, w):          # other rows hold anything
+                return pallas_grouped_dot.grouped_dot(a, w, ends)
+        else:       # every row: the spare ones, zeros, ride the last group
+            sizes = jnp.diff(ends, prepend=0)
+            sizes = sizes.at[-1].add(buffer_rows - ends[-1])
+
+            def grouped(a, w):
+                return jax.lax.ragged_dot(
+                    a, w, sizes, preferred_element_type=jnp.float32)
 
         mid = jax.nn.silu(grouped(xs, weights["w_gate"])) \
             * grouped(xs, weights["w_up"])
@@ -723,10 +761,12 @@ class SdarMoE(GraphModel):
     uses_history = True
     #: the model computes its own per-example loss (``per_example_loss``)
     owns_loss = True
-    #: XLA's TPU backend compiles the expert layer's ``ragged_dot`` to
-    #: kernels it names ``ragged-dot-*`` and strips of where they were
-    #: traced: the step's text puts them under ``moe``
-    #: (``Trainer.step_hlo_text``).
+    #: Where the expert layer's products are ``ragged_dot``
+    #: (``moe_products_by``: a TPU step across data replicas, or at widths
+    #: of no whole lines), XLA's TPU backend compiles it to kernels it
+    #: names ``ragged-dot-*`` and strips of where they were traced: the
+    #: step's text puts them under ``moe`` (``Trainer.step_hlo_text``). The
+    #: kernels of ``ops/pallas_grouped_dot`` keep their own ``op_name``.
     kernel_scopes = (("ragged-dot", "moe"),)
 
     def __init__(self, cfg: Any):
@@ -737,7 +777,8 @@ class SdarMoE(GraphModel):
         #: ``train.log_sync`` while tracing is on: ``attn_scores`` (``kernel``
         #: / ``xla``) and, of the kernel, ``attn_score_blocks`` (blocks of
         #: the score matrix the forward pass computes / all of them, a head);
-        #: ``moe_rows`` and ``moe_rows_moved`` (``moe_notes``); ``head_grad``
+        #: ``moe_rows``, ``moe_products`` and ``moe_rows_moved``
+        #: (``moe_notes``); ``head_grad``
         #: (``head_grad_note``). A note may name a count of the step in
         #: braces.
         self.step_notes: Dict[str, str] = {}
@@ -809,10 +850,15 @@ class SdarMoE(GraphModel):
         rows_by = moe_rows_by(cfg.embedding_size, ids.size,
                               cfg.moe_pair_capacity,
                               one_device=data_axis is None)
+        products_by = moe_products_by(
+            cfg.embedding_size, cfg.moe_expert_width, cfg.moe_pair_capacity,
+            one_device=data_axis is None)
         mask = block_diffusion(length, cfg.diffusion_block)
         self.step_notes = {
             **self._attn_notes(scores_by, seq, length),
-            **moe_notes(rows_by, cfg.moe_pair_capacity, cfg.decoder_layers)}
+            **moe_notes(rows_by, cfg.moe_pair_capacity, cfg.decoder_layers,
+                        products_by, cfg.embedding_size,
+                        cfg.moe_expert_width)}
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
 
@@ -831,7 +877,8 @@ class SdarMoE(GraphModel):
                 lp, h, top_k=cfg.moe_top_k,
                 first_expert=cfg.moe_first_expert,
                 capacity=cfg.moe_pair_capacity,
-                eps=cfg.rms_norm_eps, cdt=self.cdt, rows_by=rows_by)
+                eps=cfg.rms_norm_eps, cdt=self.cdt, rows_by=rows_by,
+                products_by=products_by)
             return h + y, counts
 
         x, counts = jax.lax.scan(layer, x, params["layers"])

@@ -119,7 +119,7 @@ class SolarOpen2(KimiLinear):
                                    one_device=one_device)
         self.step_notes.update(attn_notes(
             scores_by, causal, seq, cfg.attn_q_heads // cfg.attn_kv_heads))
-        return {"rows_by": self._rows_by(ids, one_device),
+        return {**self._moe_paths(ids, one_device),
                 "scores_by": scores_by,
                 "scan_by": self._scan_by(ids, one_device)}
 

@@ -17,9 +17,11 @@ block's rows in VMEM:
 semaphore and waited for in bulk; the block leaves as one ``[block, W]``
 tile of ``dtype`` (the products': the cast rides the kernel) while the next
 block's rows arrive. Rows ``i >= n`` are **zeros**, written so and not left
-as they were found: the spare rows ride the last group of the grouped
-products, and a stray NaN times a zero cotangent poisons a weight's
-gradient. ``tok`` of a row past ``n`` is never read.
+as they were found: where the grouped products are ``jax.lax.ragged_dot``
+the spare rows ride its last group, and a stray NaN times a zero cotangent
+poisons a weight's gradient (the kernels of ``ops/pallas_grouped_dot`` stop
+at the prefix and read none of them). ``tok`` of a row past ``n`` is never
+read.
 
 ``add_rows(out, y, tok, ends, scale)``: ``out[tok[i]] += scale[i] * y[i]``
 for ``i < n``, in float32, **in place** (``out`` is aliased to the result: a

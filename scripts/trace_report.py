@@ -92,8 +92,11 @@ a head); the report prints them on its "block-masked attention" line
 The decoders say how their expert layers' rows go to and from their
 positions: ``moe_rows`` (``kernel``: one copy a row over the pairs really
 held, ``ops/pallas_moe_rows``; ``xla``: ``take`` and scatter-add over every
-row of the buffer) and ``moe_rows_moved`` (the step's held pairs over the
-buffers' rows); the report prints them on its "expert layers' rows" line,
+row of the buffer), ``moe_products`` (``kernel <tiling>``: the grouped
+products over the held rows' tiles only, ``ops/pallas_grouped_dot``;
+``xla``: ``jax.lax.ragged_dot`` over every row) and ``moe_rows_moved`` (the
+step's held pairs over the buffers' rows: the share of the buffer both
+kernels touch); the report prints them on its "expert layers' rows" line,
 and where the router has a selection bias (``lfm2_moe``) the mean of
 ``moe_bias_moved_picks``, the (position, layer) selections of a step the
 bias changed.
@@ -570,7 +573,10 @@ def expert_rows(events):
     """How the expert layers' rows moved, off the ``train.log_sync`` spans
     that say so: ``steps`` read, ``rows`` (``moe_rows``: ``kernel``, one copy
     a row over the pairs held, or ``xla``, every row of the buffer) and the
-    mean ``held`` of ``buffer`` rows a step (``moe_rows_moved``) and, where
+    mean ``held`` of ``buffer`` rows a step (``moe_rows_moved``), where the
+    spans say it ``products`` (``moe_products``: ``kernel <tiling>``, the
+    grouped products over the held rows' tiles only, or ``xla``,
+    ``ragged_dot`` over every row of the buffer) and, where
     the spans carry ``moe_bias_moved_picks`` (a router with a selection
     bias), ``bias_moved_picks``, its mean a step; None when no span has them
     (another model, or a trace that predates them)."""
@@ -581,6 +587,8 @@ def expert_rows(events):
     out = {"steps": len(seen), "rows": seen[-1]["moe_rows"],
            "held": sum(int(h) for h, _ in moved) / len(moved),
            "buffer": int(moved[-1][1])}
+    if "moe_products" in seen[-1]:
+        out["products"] = seen[-1]["moe_products"]
     picks = [a["moe_bias_moved_picks"] for a in seen
              if "moe_bias_moved_picks" in a]
     if picks:
@@ -731,6 +739,8 @@ def main(argv=None):
               "of %d buffer rows a step held a pair (%.1f%%)"
               % (moved["steps"], moved["rows"], moved["held"],
                  moved["buffer"], 100 * moved["held"] / moved["buffer"])
+              + (", multiplied by %s" % moved["products"]
+                 if "products" in moved else "")
               + (", the selection bias moved %.0f picks a step"
                  % moved["bias_moved_picks"]
                  if "bias_moved_picks" in moved else ""))

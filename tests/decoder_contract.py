@@ -48,7 +48,7 @@ from benchmark.reference_sdar_moe import leaf_gap, worst_leaf_gap  # noqa: E402
 from deepfm_tpu.config import Config  # noqa: E402
 from deepfm_tpu.data import example_codec, tfrecord  # noqa: E402
 from deepfm_tpu.models import get_model, kimi_linear, sdar_moe  # noqa: E402
-from deepfm_tpu.ops import pallas_moe_rows  # noqa: E402
+from deepfm_tpu.ops import pallas_grouped_dot, pallas_moe_rows  # noqa: E402
 from deepfm_tpu.parallel import mesh as mesh_lib  # noqa: E402
 from deepfm_tpu.train import Trainer  # noqa: E402
 
@@ -597,7 +597,9 @@ class RowKernels(FromSpec):
         the row kernels (``ops/pallas_moe_rows``, forced on through the
         Pallas interpreter at rows of one 128-lane line): loss, counts and
         every leaf's gradient against the XLA rows, in passes of at most
-        ``pass_most`` rows; the notes say which moved them -> pairs held."""
+        ``pass_most`` rows, and the same again with the grouped products by
+        their kernels; the notes say which moved and multiplied them ->
+        pairs held."""
         spec = self.spec
         monkeypatch.setattr(sdar_moe, "PASS_ROWS", pass_most)
         cfg = spec.config(**spec.row_kernels["flags"])
@@ -624,13 +626,30 @@ class RowKernels(FromSpec):
         assert model.step_notes["moe_rows"] == "kernel"
         assert model.step_notes["moe_rows_moved"] == "{moe_pairs_held}/%d" % (
             spec.row_kernels["moved"])
-        held = int(got_counts["moe_pairs_held"])
-        assert 0 < held == int(want_counts["moe_pairs_held"])
-        np.testing.assert_allclose(got, want, rtol=1e-6)
-        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got_g),
-                                jax.tree.leaves(want_g)):
-            np.testing.assert_allclose(g, w, atol=2e-5,
-                                       err_msg=jax.tree_util.keystr(path))
+        held = int(want_counts["moe_pairs_held"])
+
+        def same_step():
+            assert 0 < held == int(got_counts["moe_pairs_held"])
+            np.testing.assert_allclose(got, want, rtol=1e-6)
+            for (path, g), w in zip(
+                    jax.tree_util.tree_leaves_with_path(got_g),
+                    jax.tree.leaves(want_g)):
+                np.testing.assert_allclose(
+                    g, w, atol=2e-5, err_msg=jax.tree_util.keystr(path))
+
+        same_step()
+        # and with the grouped products made by the kernels that stop at the
+        # valid prefix too (``ops/pallas_grouped_dot``, through the
+        # interpreter, which leaves NaNs in the rows they do not write)
+        assert model.step_notes["moe_products"] == "xla"
+        monkeypatch.setattr(pallas_grouped_dot, "grouped_dot",
+                            functools.partial(pallas_grouped_dot.grouped_dot,
+                                              tile=16, interpret=True))
+        monkeypatch.setattr(pallas_grouped_dot, "supported",
+                            lambda rows, width, hidden, backend=None: True)
+        model, ((got, got_counts), got_g) = grads()
+        assert model.step_notes["moe_products"].startswith("kernel rows")
+        same_step()
         return held
 
     def test_model_by_the_row_kernels_takes_the_same_step(self, monkeypatch,

@@ -67,6 +67,7 @@ def test_kernel_phase_rehearsal():
                         "put_rows_slots_written",
                         "block_attention_max_rel_err",
                         "moe_rows_max_rel_err",
+                        "moe_grouped_dot_max_rel_err",
                         "selective_scan_max_rel_err",
                         "kda_scan_max_rel_err"}
 

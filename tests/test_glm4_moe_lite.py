@@ -113,6 +113,7 @@ SPEC = Spec(
         # none kept off a TPU
         "mlp_kept": "0/%d" % len(trainer.model.block_kinds),
         # the expert layers and the module's block, one pass each
+        "moe_products": "xla",
         "moe_rows_moved": "{moe_pairs_held}/%d" % (
             sum(ffn == "moe" for _, ffn in trainer.model.block_kinds)
             * 2 * B * L)},

@@ -92,6 +92,7 @@ SPEC = Spec(
         # the dense layers' SwiGLUs (no shared expert): none kept off a TPU
         "mlp_kept": "0/%d" % sum(
             ffn == "mlp" for _, ffn in trainer.model.kinds),
+        "moe_products": "xla",
         "moe_rows_moved": "{moe_pairs_held}/%d" % (2 * 2 * B * L)},
     kinds=KINDS,
     layer_counts={"moe_pairs_held": "moe", sdar_moe.BIAS_MOVED: "moe"},

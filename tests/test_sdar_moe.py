@@ -13,6 +13,7 @@ results of an attention block and of an expert layer add up to the uncut
 reference's); the block-masked attention kernel; where the kernels are
 taken; and the launcher's train -> eval on a tiny file."""
 
+import functools
 import json
 import os
 import sys
@@ -32,6 +33,7 @@ from decoder_contract import (DecoderContract, RowKernels,  # noqa: E402
                               trainer_on)
 from deepfm_tpu.data import example_codec, tfrecord  # noqa: E402
 from deepfm_tpu.models import get_model, registered_models, sdar_moe  # noqa: E402
+from deepfm_tpu.ops import pallas_grouped_dot  # noqa: E402
 
 V, L, B = 50, 8, 4
 SMALL = dict(model="sdar_moe", feature_size=V, field_size=1,
@@ -147,7 +149,7 @@ class TestSdarMoE(DecoderContract, SmallBuffer, RowKernels):
         jax.eval_shape(lambda p: model.hidden(
             p, jnp.zeros((B, 2 * L), jnp.int32)), params)
         assert model.step_notes == {
-            "attn_scores": "xla", "moe_rows": "xla",
+            "attn_scores": "xla", "moe_rows": "xla", "moe_products": "xla",
             "moe_rows_moved": "{moe_pairs_held}/%d" % (
                 model.cfg.decoder_layers * model.cfg.moe_pair_capacity)}
 
@@ -370,7 +372,6 @@ def test_kernel_scores_match_the_chunked_xla_path(dtype, tol, kernel_block):
 @pytest.mark.parametrize("length", [8, 1024])
 def test_kernel_mask_is_allowed_entry_for_entry(length):
     from deepfm_tpu.ops import block_attention
-    import functools
     s, block = 2 * length, 4
     mask = block_attention.PairMask(
         s, functools.partial(sdar_moe.allowed_pairs, length=length,
@@ -443,6 +444,29 @@ def test_the_row_kernels_are_taken_where_backend_shape_and_mesh_allow(
     assert sdar_moe.pass_rows(16384) == (1, 16384)
 
 
+@pytest.mark.parametrize("backend, width, hidden, capacity, one_device, "
+                         "says", [
+    ("tpu", 2048, 768, 32768, True, "kernel"),      # the SDAR cell's layer
+    ("tpu", 2048, 1792, 32768, True, "kernel"),     # the LFM2 cell's
+    ("tpu", 2304, 1024, 16384, True, "kernel"),     # the Kimi-Linear cell's
+    ("tpu", 2048, 1536, 16384, True, "kernel"),     # the GLM-4.7-Flash cell's
+    ("tpu", 4096, 1280, 3280, True, "kernel"),      # Solar-Open2's (3,328)
+    ("cpu", 2048, 768, 32768, True, "xla"),
+    ("gpu", 2048, 768, 32768, True, "xla"),
+    ("tpu", 2048, 768, 32768, False, "xla"),        # across data replicas
+    ("tpu", 2048, 96, 32768, True, "xla"),          # experts of no whole line
+    ("tpu", 64, 768, 32768, True, "xla"),           # nor the model's width
+    ("tpu", 2048, 768, 100, True, "xla"),           # a pass under one tile
+    ("tpu", 2048, 768, 32760, True, "kernel")])     # (made up to 2 x 16,384)
+def test_the_product_kernels_are_taken_where_backend_shape_and_mesh_allow(
+        backend, width, hidden, capacity, one_device, says):
+    assert sdar_moe.moe_products_by(width, hidden, capacity,
+                                    one_device=one_device,
+                                    backend=backend) == says
+    # a pass of a tile or more is whole tiles of the kernels' too
+    assert sdar_moe.PRODUCT_TILE_ROWS % pallas_grouped_dot.TILE_ROWS == 0
+
+
 @pytest.mark.parametrize("capacity,passes,rows", [
     (8, 1, 8), (255, 1, 255),           # under one tile: held row for row
     (256, 1, 256), (257, 1, 512),
@@ -465,12 +489,27 @@ def test_a_pass_of_a_tile_or_more_is_whole_tiles_of_the_grouped_product(
 
 def test_the_step_notes_say_how_the_expert_layers_rows_move():
     notes = sdar_moe.moe_notes("kernel", 32768, 6)
-    assert notes == {"moe_rows": "kernel",
+    assert notes == {"moe_rows": "kernel", "moe_products": "xla",
                      "moe_rows_moved": "{moe_pairs_held}/196608"}
     # the trainer fills the count in where it writes the notes
     assert notes["moe_rows_moved"].format(moe_pairs_held=101_000,
                                           other=3) == "101000/196608"
     assert sdar_moe.moe_notes("xla", 20, 1)["moe_rows_moved"].endswith("/20")
+
+
+@pytest.mark.parametrize("products_by, width, hidden, says", [
+    ("xla", 2048, 768, "xla"), ("xla", 0, 0, "xla"),
+    ("kernel", 2048, 768, "kernel rows256 dw768/2048"),
+    ("kernel", 4096, 1280, "kernel rows256 dw640/2048")])
+def test_the_step_notes_say_what_multiplies_the_expert_layers_rows(
+        products_by, width, hidden, says):
+    """``moe_products`` beside ``moe_rows``: ``moe_products_by``'s word and,
+    of the kernels, their tiles; ``moe_rows_moved`` is then the share of
+    the buffer the products visit too."""
+    notes = sdar_moe.moe_notes("kernel", 32768, 6, products_by, width,
+                               hidden)
+    assert notes == {"moe_rows": "kernel", "moe_products": says,
+                     "moe_rows_moved": "{moe_pairs_held}/196608"}
 
 
 @pytest.mark.parametrize("capacity, pass_most, first, case", [
@@ -522,11 +561,63 @@ def test_expert_layer_by_the_row_kernels_matches_the_xla_rows(
     np.testing.assert_allclose(got_g[1], want_g[1], atol=1e-4)
 
 
+@pytest.mark.parametrize("rows_by", ["xla", "kernel"])
+@pytest.mark.parametrize("capacity, pass_most, first, case", [
+    (128, 20480, 0, "one pass, spare rows"),
+    (128, 64, 0, "two passes, the second part spare"),
+    (192, 64, 0, "three passes, the last with no valid row"),
+    (32, 20480, 0, "pairs over the buffer"),
+    (64, 20480, 20, "no expert held gets a pair")])
+def test_expert_layer_by_the_product_kernels_matches_ragged_dot(
+        monkeypatch, capacity, pass_most, first, case, rows_by):
+    """``expert_layer`` with the grouped products made by the kernels of
+    ``ops/pallas_grouped_dot`` (forced on, through the interpreter, which
+    leaves NaNs in every row the kernels do not write: the rows past a
+    pass's prefix) against ``jax.lax.ragged_dot`` over the whole buffer,
+    under either way of moving the rows: the output, the counts, and the
+    gradient of every leaf and of the input, all finite."""
+    force_row_kernels(monkeypatch)
+    monkeypatch.setattr(pallas_grouped_dot, "grouped_dot", functools.partial(
+        pallas_grouped_dot.grouped_dot, tile=16, interpret=True))
+    monkeypatch.setattr(sdar_moe, "PASS_ROWS", pass_most)
+    d, f, experts, held, top_k = 128, 128, 16, 4, 4
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 8))
+    lp = {"norm2": 1 + 0.1 * jax.random.normal(next(keys), (d,)),
+          "router": 0.5 * jax.random.normal(next(keys), (d, experts)),
+          "w_gate": 0.2 * jax.random.normal(next(keys), (held, d, f)),
+          "w_up": 0.2 * jax.random.normal(next(keys), (held, d, f)),
+          "w_down": 0.2 * jax.random.normal(next(keys), (held, f, d))}
+    x = jax.random.normal(next(keys), (2, 32, d))
+    w = jax.random.normal(next(keys), x.shape)
+
+    def loss(lp, x, products_by):
+        y, counts = sdar_moe.expert_layer(
+            lp, x, top_k=top_k, first_expert=first, capacity=capacity,
+            eps=1e-6, cdt=jnp.dtype("float32"), rows_by=rows_by,
+            products_by=products_by)
+        return jnp.sum(y * w), (y, counts)
+
+    (_, (want, counts)), want_g = jax.value_and_grad(
+        loss, (0, 1), has_aux=True)(lp, x, "xla")
+    (_, (got, got_counts)), got_g = jax.value_and_grad(
+        loss, (0, 1), has_aux=True)(lp, x, "kernel")
+    assert {k: int(v) for k, v in got_counts.items()} == {
+        k: int(v) for k, v in counts.items()}
+    if "no expert" in case:
+        assert int(counts["moe_pairs_held"]) == 0
+    elif "over" not in case:    # spare rows, which the interpreter poisons
+        assert 0 < int(counts["moe_pairs_held"]) < capacity
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    for name in lp:
+        np.testing.assert_allclose(got_g[0][name], want_g[0][name],
+                                   atol=2e-4, err_msg=name)
+    np.testing.assert_allclose(got_g[1], want_g[1], atol=2e-4)
+
+
 def test_attention_by_the_kernel_matches_attention_by_xla(monkeypatch):
     """The whole block (projections, QK-norm, rotary, scale folded into q,
     ``wo``) with the kernel forced on through the interpreter against the
     XLA path, output and every weight's gradient, float32."""
-    import functools
     monkeypatch.setattr(sdar_moe, "_scores_kernel", functools.partial(
         sdar_moe._scores_kernel, interpret=True, kernel_block=128))
     d, hd, length, block = 64, 128, 128, 4

@@ -29,7 +29,12 @@ def test_glm4_step_at_the_cells_shapes_takes_the_kernel_at_256(step_for_v5e):
             notes["moe_rows"]) == ("kernel", "136/256", "kernel")
     # five expert blocks of one pass of 16,384 rows
     assert notes["moe_rows_moved"] == "{moe_pairs_held}/%d" % (5 * 16384)
+    # multiplied by the kernels that stop at the valid prefix (PR 52)
+    assert notes["moe_products"] == "kernel rows256 dw1536/2048"
     by_op = profiling.hlo_op_scopes(text)
+    assert {scope for name, scope in by_op.items()
+            if name.startswith("moe_grouped_dot")} == {"moe"}
+    assert not any(name.startswith("ragged-dot") for name in by_op)
     assert {"embed", "attn", "attn_scores", "mlp", "moe", "mtp", "head",
             "mtp_head", "opt"} <= set(by_op.values())
     assert {scope for name, scope in by_op.items()

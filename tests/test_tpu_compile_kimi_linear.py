@@ -44,6 +44,12 @@ def test_hybrid_decoder_step_at_the_cells_shapes_fits_and_names_its_layers(
     by_op = profiling.hlo_op_scopes(text)
     assert {scope for name, scope in by_op.items() if name.startswith(
         ("moe_take_rows", "moe_add_rows"))} == {"moe"}
+    # and are multiplied by the kernels that stop at the valid prefix (PR 52)
+    assert tr.model.step_notes["moe_products"] \
+        == "kernel rows256 dw1024/2304"
+    assert {scope for name, scope in by_op.items()
+            if name.startswith("moe_grouped_dot")} == {"moe"}
+    assert not any(name.startswith("ragged-dot") for name in by_op)
     assert_scan_by_the_kernels(tr, text, by_op, kda_layers=4)
 
 

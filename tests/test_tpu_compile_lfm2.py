@@ -34,6 +34,7 @@ def test_lfm2_step_at_the_cells_shapes_takes_the_kernels_at_64_lanes(
             notes["moe_rows"], notes["conv_taps_by"]) == (
                 "kernel", "136/256", "kernel", "xla")
     assert notes["mlp_kept"] == "1/1 layers 0.94 GB"
+    assert notes["moe_products"] == "kernel rows256 dw1792/2048"
     assert products_in_scope(text, "mlp") == (9, 0)
     by_op = profiling.hlo_op_scopes(text)
     assert {"embed", "conv", "conv_taps", "attn", "attn_scores", "mlp",

@@ -3,12 +3,15 @@ SDAR-shaped step, asked of a *described* v5e (no chip attached, nothing
 runs; the fixtures are ``conftest.py``'s): the masked scores are the
 block-masked attention kernels' where a head is a whole or half a lane line
 wide (``ops/block_attention.py``; PR 32, PR 40), the expert layers' rows
-move by the row kernels (``ops/pallas_moe_rows``; PR 34), each charged to
-its scope by the step's own text, in a step cut to compile in seconds; and
+move by the row kernels (``ops/pallas_moe_rows``; PR 34) and are multiplied
+by the grouped-product kernels (``ops/pallas_grouped_dot``; PR 52), each
+charged to its scope by the step's own text, in a step cut to compile in seconds; and
 the attention kernels alone at ``sdar-30b-a3b.train-sequences``'s shapes.
 (The other families' are ``tests/test_tpu_compile_rankers.py``,
 ``_kimi_linear.py``, ``_solar_open2.py`` and ``_lfm2.py``.)
 """
+
+import collections
 
 import jax
 
@@ -38,7 +41,8 @@ def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
     assert tr.model.step_notes == {
         "attn_scores": "kernel", "attn_score_blocks": "3/4",
         "head_grad": "forward 3 products/chunk, 0.00 GB kept",
-        "moe_rows": "kernel", "moe_rows_moved": "{moe_pairs_held}/8192"}
+        "moe_rows": "kernel", "moe_products": "kernel rows256 dw128/256",
+        "moe_rows_moved": "{moe_pairs_held}/8192"}
     # the expert layer's rows move by the row kernels (rows of two lines,
     # 1,024 positions, one pass of 4,096 rows), forward and backward, all
     # charged to ``moe``
@@ -47,6 +51,18 @@ def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
     assert {name.split(".")[0] for name in rows} == {
         "moe_take_rows", "moe_add_rows"} and len(rows) == 5, rows
     assert set(rows.values()) == {"moe"}, rows
+    # and its grouped products are the kernels that stop at the valid prefix
+    # (``ops/pallas_grouped_dot``; PR 52): three forward, the three again in
+    # the pass's recomputation, three rows' gradients and three matrices',
+    # charged to ``moe`` by their own op_name; no ``ragged-dot`` is left
+    products = {name: scope for name, scope in scopes.items()
+                if name.startswith("moe_grouped_dot")}
+    by_kernel = collections.Counter(
+        name.split(".")[0] for name in products)
+    assert by_kernel == {"moe_grouped_dot": 6, "moe_grouped_dot_da": 3,
+                         "moe_grouped_dot_dw": 3}, products
+    assert set(products.values()) == {"moe"}, products
+    assert not any(name.startswith("ragged-dot") for name in scopes)
     kernels = {name: scope for name, scope in scopes.items()
                if name.startswith("splash_mqa_")}
     assert {name.split(".")[0] for name in kernels} == {
@@ -56,12 +72,10 @@ def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
     # the raw text loses them: their op_name is on a continuation line
     raw = profiling.hlo_op_scopes(compiled.as_text())
     assert {raw[name] for name in kernels} == {""}
-    # and every other instruction is charged as it was (the grouped
-    # products' kernels get their scope from the model, as before)
+    # and every other instruction is charged as it was
     def others(by_op):
         return {n: s for n, s in by_op.items()
-                if n not in kernels and not n.startswith(
-                    ("ragged-dot", "pallas_call"))}    # (the kernels' parts)
+                if n not in kernels and not n.startswith("pallas_call")}
     assert others(scopes) == others(raw)
 
 
@@ -115,3 +129,33 @@ def test_attention_kernels_compile_at_the_cells_shapes(v5e,
         assert f"%{name}" in text, name
     # one float32 [2, 4, 8192, 8192] score matrix would be 2.1 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+
+
+def test_grouped_product_kernels_compile_at_the_cells_pass_shape(
+        v5e, no_compile_cache):
+    """``ops/pallas_grouped_dot`` at the SDAR cell's pass (16,384 rows of
+    2,048 by 16 experts of 768, bfloat16 operands), the up product and the
+    down product, forward and both gradients: Mosaic takes a group's whole
+    matrix and a float32 block of its gradient in VMEM at tiles of 256 rows,
+    and nothing of the buffer's size is made outside the kernels."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from deepfm_tpu.ops import pallas_grouped_dot
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=SingleDeviceSharding(v5e))
+
+    def loss(a, w, ends):
+        return jnp.sum(pallas_grouped_dot.grouped_dot(a, w, ends))
+
+    for k, n in ((2048, 768), (768, 2048)):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            spec((16384, k)), spec((16, k, n)),
+            spec((16,), jnp.int32)).compile()
+        text = compiled.as_text()
+        for name in ("moe_grouped_dot_da", "moe_grouped_dot_dw"):
+            assert f"%{name}" in text, name
+        # (the cotangent of the sum, float32 [16384, n], and the gradients)
+        assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20

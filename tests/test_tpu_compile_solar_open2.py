@@ -34,6 +34,7 @@ def test_solar_open2_step_at_the_cells_shapes_fits_beside_its_state(
     assert (notes["attn_scores"], notes["attn_score_blocks"],
             notes["moe_rows"]) == ("kernel", "136/256", "kernel")
     assert notes["mlp_kept"] == "4/4 layers 0.34 GB"
+    assert notes["moe_products"] == "kernel rows256 dw640/2048"
     assert products_in_scope(text, "mlp") == (4 * 9, 0)
     by_op = profiling.hlo_op_scopes(text)
     assert {"embed", "attn", "attn_scores", "kda", "kda_scan", "mlp", "moe",

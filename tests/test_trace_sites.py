@@ -551,7 +551,7 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
     the chunked XLA path, and the report's line with it."""
     tr, events = _sdar_fit()
     assert tr.model.step_notes == {
-        "attn_scores": "xla", "moe_rows": "xla",
+        "attn_scores": "xla", "moe_rows": "xla", "moe_products": "xla",
         "head_grad": "forward 3 products/chunk, 0.00 GB kept",
         "moe_rows_moved": "{moe_pairs_held}/64"}
     syncs = [e["args"] for e in events if e["name"] == "train.log_sync"]
@@ -559,6 +559,7 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
     assert all("attn_score_blocks" not in a for a in syncs)
     # how the expert layer's rows moved, the step's held pairs filled in
     assert [a["moe_rows"] for a in syncs] == ["xla"] * 2
+    assert [a["moe_products"] for a in syncs] == ["xla"] * 2
     assert [a["moe_rows_moved"] for a in syncs] == [
         "%d/64" % a["moe_pairs_held"] for a in syncs]
     assert all(0 < a["moe_pairs_held"] <= 64 for a in syncs)
@@ -570,6 +571,7 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
     assert report.row_updates(loaded) is None
     moved = report.expert_rows(loaded)
     assert moved == {"steps": 2, "rows": "xla", "buffer": 64,
+                     "products": "xla",
                      "held": sum(a["moe_pairs_held"] for a in syncs) / 2}
     assert report.main([path]) == 0
     out = capsys.readouterr().out
@@ -577,6 +579,7 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
             "every score computed") in out
     assert ("expert layers' rows over 2 logged steps: moved by xla, %.0f of "
             "64 buffer rows a step held a pair" % moved["held"]) in out
+    assert "multiplied by xla" in out
 
 
 def test_report_prints_the_kernels_block_count(tmp_path, capsys):
@@ -599,6 +602,11 @@ def test_report_prints_the_kernels_block_count(tmp_path, capsys):
                          moe_rows_moved="%d/196608" % held)
     assert report.expert_rows(events) == {
         "steps": 3, "rows": "kernel", "held": 100_000.0, "buffer": 196608}
+    # and, since PR 52, of what multiplies them
+    for e in events:
+        e["args"]["moe_products"] = "kernel rows256 dw768/2048"
+    assert report.expert_rows(events)["products"] \
+        == "kernel rows256 dw768/2048"
     path = tmp_path / "trace.json"
     path.write_text(__import__("json").dumps({"traceEvents": events}))
     assert report.main([str(path)]) == 0
