@@ -49,7 +49,7 @@ class Config:
     coordinator_address: str = ""     # jax.distributed coordinator (host:port)
 
     # ---- model hyperparameters (reference: model flags) ----
-    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2 | lfm2_moe | phi4_flash | glm4_moe_lite
+    model: str = "deepfm"             # deepfm | widedeep | dcnv2 | dlrm | dlrm_dcnv2 | din | bst | sdar_moe | kimi_linear | solar_open2 | lfm2_moe | phi4_flash | glm4_moe_lite | afmoe
     feature_size: int = 117581        # vocabulary size (reference ipynb:85)
     field_size: int = 39              # number of fields (reference ipynb:90)
     embedding_size: int = 32          # latent dim (reference flag default, ...py:44)
@@ -156,6 +156,14 @@ class Config:
     mla_value_dim: int = 0
     mtp_depth: int = 0
     mtp_loss_weight: float = 0.1
+    # afmoe (windowed / global gated-attention MoE decoder, models/afmoe.py)
+    # has no flag of its own: decoder_layers / attn_* / rope_theta (the
+    # windowed layers') / rms_norm_eps / dense_* / moe_* with
+    # moe_shared_width and moe_route_scale mean what they mean above;
+    # layer_types' words are here window_attention and full_attention, and
+    # attn_window is the positions a windowed query reads. What the model's
+    # form fixes (the output gate, the norms on the sublayers' outputs,
+    # which kind rotates, the embedding's constant) is the class's.
     l2_reg: float = 1e-4
     loss_type: str = "log_loss"       # log_loss | square_loss
 
@@ -524,7 +532,7 @@ class Config:
         if self.model not in ("deepfm", "widedeep", "dcnv2", "dlrm",
                               "dlrm_dcnv2", "din", "bst", "sdar_moe",
                               "kimi_linear", "solar_open2", "lfm2_moe",
-                              "phi4_flash", "glm4_moe_lite"):
+                              "phi4_flash", "glm4_moe_lite", "afmoe"):
             raise ValueError(f"unknown model: {self.model!r}")
         if self.model == "sdar_moe":
             self._validate_sdar_moe()
@@ -538,22 +546,25 @@ class Config:
             self._validate_phi4_flash()
         elif self.model == "glm4_moe_lite":
             self._validate_glm4_moe_lite()
+        elif self.model == "afmoe":
+            self._validate_afmoe()
         elif self.decoder_layers or self.moe_experts or self.attn_q_heads:
             raise ValueError(
                 "decoder_layers/attn_*/moe_* belong to --model sdar_moe, "
-                f"kimi_linear, solar_open2, lfm2_moe, phi4_flash and "
-                f"glm4_moe_lite; {self.model!r} has no decoder block")
+                f"kimi_linear, solar_open2, lfm2_moe, phi4_flash, "
+                f"glm4_moe_lite and afmoe; {self.model!r} has no decoder "
+                "block")
         # the decoders' further flags, and the models that take each
         takers = {
             "kda_heads/attn_every": (
                 ("kimi_linear", "solar_open2"),
                 self.kda_heads or self.attn_every),
             "moe_shared_width": (
-                ("kimi_linear", "solar_open2", "glm4_moe_lite"),
+                ("kimi_linear", "solar_open2", "glm4_moe_lite", "afmoe"),
                 self.moe_shared_width),
             "moe_route_scale": (
-                ("kimi_linear", "solar_open2", "lfm2_moe", "glm4_moe_lite"),
-                self.moe_route_scale != 1.0),
+                ("kimi_linear", "solar_open2", "lfm2_moe", "glm4_moe_lite",
+                 "afmoe"), self.moe_route_scale != 1.0),
             "mla_latent_dim/mla_rope_dim": (
                 ("kimi_linear", "glm4_moe_lite"),
                 self.mla_latent_dim or self.mla_rope_dim),
@@ -562,15 +573,18 @@ class Config:
                 ("glm4_moe_lite",), self.mla_q_rank or self.mla_nope_dim
                 or self.mla_value_dim or self.mtp_depth
                 or self.mtp_loss_weight != 0.1),
-            "dense_layers": (("kimi_linear", "lfm2_moe", "glm4_moe_lite"),
-                             self.dense_layers),
+            "dense_layers": (
+                ("kimi_linear", "lfm2_moe", "glm4_moe_lite", "afmoe"),
+                self.dense_layers),
             "dense_mlp_width": (
-                ("kimi_linear", "lfm2_moe", "phi4_flash", "glm4_moe_lite"),
-                self.dense_mlp_width),
-            "layer_types": (("lfm2_moe", "phi4_flash"), self.layer_types),
+                ("kimi_linear", "lfm2_moe", "phi4_flash", "glm4_moe_lite",
+                 "afmoe"), self.dense_mlp_width),
+            "layer_types": (("lfm2_moe", "phi4_flash", "afmoe"),
+                            self.layer_types),
             "conv_taps": (("lfm2_moe",), self.conv_taps != 3),
-            "first_layer/attn_window/mamba_*": (
-                ("phi4_flash",), self.first_layer or self.attn_window
+            "attn_window": (("phi4_flash", "afmoe"), self.attn_window),
+            "first_layer/mamba_*": (
+                ("phi4_flash",), self.first_layer
                 or self.mamba_state or self.mamba_dt_rank
                 or self.mamba_conv != 4 or self.mamba_expand != 2),
         }
@@ -1053,7 +1067,7 @@ class Config:
 
     def _refuse_for_a_decoder(self, model: str) -> None:
         """What none of the next-token decoders (kimi_linear, solar_open2,
-        lfm2_moe, phi4_flash, glm4_moe_lite) takes."""
+        lfm2_moe, phi4_flash, glm4_moe_lite, afmoe) takes."""
         refused = {
             "tasks (the loss is over the positions of a sequence, one task)":
                 self.num_tasks > 1,
@@ -1226,6 +1240,51 @@ class Config:
             if not ok:
                 raise ValueError(f"model glm4_moe_lite needs {what}")
         self._refuse_for_a_decoder("glm4_moe_lite")
+
+    def _validate_afmoe(self) -> None:
+        """What the windowed / global gated-attention MoE decoder takes, and
+        plainly what it does not (models.afmoe.Afmoe)."""
+        kinds = self.layer_type_list
+        words = ("window_attention", "full_attention")
+        moe_layers = self.decoder_layers - self.dense_layers
+        need = {
+            "decoder_layers >= 1": self.decoder_layers >= 1,
+            "layer_types: decoder_layers words, each one of "
+            + ", ".join(words): len(kinds) == self.decoder_layers
+                and all(kind in words for kind in kinds),
+            "attn_q_heads a positive multiple of attn_kv_heads >= 1 and an "
+            "even attn_head_dim (rotate-half rotary)":
+                self.attn_kv_heads >= 1 and self.attn_q_heads >= 1
+                and self.attn_q_heads % self.attn_kv_heads == 0
+                and self.attn_head_dim >= 2 and self.attn_head_dim % 2 == 0,
+            "attn_window >= 1 where a layer is window_attention":
+                "window_attention" not in kinds or self.attn_window >= 1,
+            "0 <= dense_layers <= decoder_layers": 0 <= self.dense_layers
+                <= self.decoder_layers,
+            "dense_mlp_width >= 1 where a layer is dense":
+                self.dense_layers == 0 or self.dense_mlp_width >= 1,
+            "1 <= moe_top_k <= moe_experts, moe_expert_width >= 1, "
+            "moe_shared_width >= 1 and moe_route_scale > 0 where a layer "
+            "has experts": moe_layers == 0 or (
+                1 <= self.moe_top_k <= self.moe_experts
+                and self.moe_expert_width >= 1 and self.moe_shared_width >= 1
+                and self.moe_route_scale > 0),
+            "moe_experts_held >= 1 experts from moe_first_expert on, all "
+            "among the moe_experts": moe_layers == 0 or (
+                self.moe_experts_held >= 1 and self.moe_first_expert >= 0
+                and self.moe_first_expert + self.moe_experts_held
+                <= self.moe_experts),
+            "moe_pair_capacity >= 1 (rows of a layer's pair buffer; every "
+            "pair of a step is batch_size * history_max_len * moe_top_k)":
+                moe_layers == 0 or self.moe_pair_capacity >= 1,
+            "history_max_len >= 2 (the sequence length; the loss is of the "
+            "next token)": self.history_max_len >= 2,
+            "feature_size >= 2": self.feature_size >= 2,
+        }
+        for what, ok in need.items():
+            if not ok:
+                raise ValueError(f"model afmoe needs {what}")
+        self._refuse_for_a_decoder("afmoe")
 
     def _validate_solar_open2(self) -> None:
         """What the gated-GQA / KDA MoE decoder takes, and plainly what it

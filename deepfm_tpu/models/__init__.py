@@ -4,13 +4,14 @@
 (``--tasks ctr,cvr``) builds the multi-task model (``--multitask``
 architecture over the shared graph bottom); otherwise ``cfg.model`` picks a
 single-task graph from the registry, or one of the decoders (``sdar_moe``,
-``kimi_linear``, ``solar_open2``, ``lfm2_moe``, ``phi4_flash``, ``glm4_moe_lite``), which are
-no rankers.
+``kimi_linear``, ``solar_open2``, ``lfm2_moe``, ``phi4_flash``,
+``glm4_moe_lite``, ``afmoe``), which are no rankers.
 """
 
 from typing import Union
 
 from ..config import Config
+from .afmoe import Afmoe
 from .graph import DLRM, GraphDLRMDCNv2
 from .graph import GraphDCNv2 as DCNv2
 from .graph import GraphDeepFM as DeepFM
@@ -38,11 +39,12 @@ _REGISTRY = {
     "lfm2_moe": Lfm2Moe,
     "phi4_flash": Phi4Flash,
     "glm4_moe_lite": Glm4MoeLite,
+    "afmoe": Afmoe,
 }
 
 CtrModel = Union[DeepFM, WideDeep, DCNv2, DLRM, GraphDLRMDCNv2, GraphDIN,
                  GraphBST, SdarMoE, KimiLinear, SolarOpen2, Lfm2Moe,
-                 Phi4Flash, Glm4MoeLite, MultiTaskModel]
+                 Phi4Flash, Glm4MoeLite, Afmoe, MultiTaskModel]
 
 
 def registered_models():

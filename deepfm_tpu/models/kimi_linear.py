@@ -363,6 +363,12 @@ def kda_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
 causal = ScoreMask(("causal",), lambda q, k: k <= q)
 
 
+def window(width: int) -> ScoreMask:
+    """A query reads its own position and the ``width - 1`` before it."""
+    return ScoreMask(("window", width),
+                     lambda q, k: (k <= q) & (q - k < width))
+
+
 @jax.named_scope("attn")
 def mla_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
               rope_dim: int, eps: float, cdt: jnp.dtype) -> jnp.ndarray:
@@ -411,6 +417,9 @@ class KimiLinear(GraphModel):
     kernel_scopes = (("ragged-dot", "moe"),)
     #: Whether the head's matrix is the token table (no ``head`` leaf).
     tied_head = False
+    #: What the looked-up rows are multiplied by on their way into the
+    #: stream (``models.afmoe``: sqrt(d)); 1, nothing.
+    embed_scale = 1.0
     #: cfg -> ((mixer, feed-forward) of each layer); a model with another
     #: pattern names its own
     _kinds = staticmethod(layer_kinds)
@@ -636,26 +645,42 @@ class KimiLinear(GraphModel):
                products_by: str = "xla", **paths
                ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
         """One block: ``h = x + Mixer(RMSNorm(x))``,
-        ``h + FFN(RMSNorm(h))`` -> (the stream, the layer's counts).
-        ``paths`` is what ``_paths`` says beyond the expert layer's
-        ``rows_by`` and ``products_by``, the mixer's."""
+        ``h + FFN(RMSNorm(h))`` -> (the stream, the layer's counts). Where
+        the layer has the leaves, a sublayer's output passes a norm of its
+        own before it is added (``norm1_post`` the mixer's, ``norm2_post``
+        the feed-forward's whole sum: ``models.afmoe``). ``paths`` is what
+        ``_paths`` says beyond the expert layer's ``rows_by`` and
+        ``products_by``, the mixer's."""
         cfg = self.cfg
         # (the barrier: ``models.sdar_moe.SdarMoE.hidden``)
         lp = jax.lax.optimization_barrier(lp)
         eps = cfg.rms_norm_eps
         y, counts = self._mixer(mixer, lp, x, **paths)
+        if "norm1_post" in lp:
+            with jax.named_scope("attn"):
+                y = rms_norm(y, lp["norm1_post"], eps)
         h = x + y
         if ffn == "mlp":
-            return h + swiglu(lp, "mlp_", h, eps=eps, cdt=self.cdt), counts
+            y = swiglu(lp, "mlp_", h, eps=eps, cdt=self.cdt)
+            if "norm2_post" in lp:
+                with jax.named_scope("mlp"):
+                    y = rms_norm(y, lp["norm2_post"], eps)
+            return h + y, counts
         y, moe_counts = expert_layer(
             lp, h, top_k=cfg.moe_top_k, first_expert=cfg.moe_first_expert,
             capacity=cfg.moe_pair_capacity, eps=eps, cdt=self.cdt,
             route_by=self.route_by, rows_by=rows_by,
             products_by=products_by)
+        counts = {**counts, **moe_counts}
+        if "norm2_post" in lp:      # the norm of the routed and shared sum
+            if "shared_w_gate" in lp:
+                y = y + swiglu(lp, "shared_", h, eps=eps, cdt=self.cdt)
+            with jax.named_scope("moe"):
+                return h + rms_norm(y, lp["norm2_post"], eps), counts
         out = h + y
         if "shared_w_gate" in lp:
             out = out + swiglu(lp, "shared_", h, eps=eps, cdt=self.cdt)
-        return out, {**counts, **moe_counts}
+        return out, counts
 
     def _run_layer(self, i: int, kind: Tuple[str, str], x: jnp.ndarray,
                    lp: Dict[str, jnp.ndarray], left: Dict[str, jnp.ndarray],
@@ -691,6 +716,9 @@ class KimiLinear(GraphModel):
         keeps = self._mlp_keeps(params, ids)
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
+        if self.embed_scale != 1.0:
+            with jax.named_scope("embed"):
+                x = x * jnp.float32(self.embed_scale)
         x, seen = self._run_layers(params, x, paths, keeps)
         return x, self._merged_counts(seen)
 

@@ -83,9 +83,9 @@ from jax.ad_checkpoint import checkpoint_name
 from ..ops import pallas_selective_scan
 from . import common
 from .kimi_linear import (MLP_KEPT, KimiLinear, causal, causal_conv,
-                          layer_policy)
-from .sdar_moe import (ScoreMask, _dot, _operand, attn_notes, attn_scores_by,
-                       masked_scores, rms_norm)
+                          layer_policy, window)
+from .sdar_moe import (ScoreMask, _dot, _operand, attn_scores_by,
+                       masked_scores, masks_notes, rms_norm)
 
 #: What a mixer reads of what earlier layers left (``LEAVES``), by name.
 READS = {"gmu": ("memory",), "cross_attention": ("shared_k", "shared_v")}
@@ -115,12 +115,6 @@ def layer_kinds(cfg: Any) -> Tuple[Tuple[str, str], ...]:
 def lambda_init(layer: int) -> float:
     """Differential attention's lambda_init at published layer ``layer``."""
     return LAMBDA_TOP - LAMBDA_SPAN * math.exp(-LAMBDA_RATE * layer)
-
-
-def window(width: int) -> ScoreMask:
-    """A query reads its own position and the ``width - 1`` before it."""
-    return ScoreMask(("window", width),
-                     lambda q, k: (k <= q) & (q - k < width))
 
 
 def layer_norm(x: jnp.ndarray, gain: jnp.ndarray, bias: jnp.ndarray,
@@ -388,16 +382,13 @@ class Phi4Flash(KimiLinear):
         if "mamba" in mixers:
             self.step_notes["mamba_scan"] = scan_note(by)
         # the blocks the kernel visits under each mask the stack has
-        for mask, key, of in (
-                (self.window, "attn_window_blocks", {"window_attention"}),
-                (causal, "attn_score_blocks",
-                 {"full_attention", "cross_attention"})):
-            if of & mixers:
-                notes = attn_notes(scores_by, mask, seq,
-                                   cfg.attn_q_heads // cfg.attn_kv_heads)
-                self.step_notes["attn_scores"] = notes["attn_scores"]
-                if "attn_score_blocks" in notes:
-                    self.step_notes[key] = notes["attn_score_blocks"]
+        masks = {"attn_window_blocks": (self.window, {"window_attention"}),
+                 "attn_score_blocks": (causal, {"full_attention",
+                                                "cross_attention"})}
+        self.step_notes.update(masks_notes(
+            scores_by, {key: mask for key, (mask, of) in masks.items()
+                        if of & mixers},
+            seq, cfg.attn_q_heads // cfg.attn_kv_heads))
         return {"scores_by": scores_by, "scan_by": by}
 
     def _layer(self, mixer: str, ffn: str, x: jnp.ndarray,

@@ -330,6 +330,22 @@ def attn_notes(scores_by: str, mask: ScoreMask, seq: int, group: int
             "attn_score_blocks": f"{visited}/{total}"}
 
 
+def masks_notes(scores_by: str, masks: Dict[str, ScoreMask], seq: int,
+                group: int) -> Dict[str, str]:
+    """``attn_notes`` of a stack whose layers run under more than one mask:
+    ``masks`` {the note that holds a mask's visited blocks: the mask} ->
+    ``attn_scores`` once and, of the kernel, each mask's blocks under its
+    own name (``attn_score_blocks`` the causal layers', ``attn_window_blocks``
+    the windowed ones')."""
+    out = {}
+    for key, mask in masks.items():
+        notes = attn_notes(scores_by, mask, seq, group)
+        out["attn_scores"] = notes["attn_scores"]
+        if "attn_score_blocks" in notes:
+            out[key] = notes["attn_score_blocks"]
+    return out
+
+
 def attn_scores_by(seq: int, head_dim: int, *, one_device: bool = True,
                    backend: Optional[str] = None) -> str:
     """``kernel`` where the masked attention kernel applies (a TPU
@@ -366,28 +382,41 @@ def masked_scores(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 @jax.named_scope("attn")
 def attention(lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
               positions: jnp.ndarray, *, mask: ScoreMask,
-              head_dim: int, eps: float, theta: float,
+              head_dim: int, eps: float, theta: Optional[float],
               cdt: jnp.dtype, scores_by: str = "xla",
               scores_scope: Optional[str] = None) -> jnp.ndarray:
     """The held heads' part of ``Attn(RMSNorm(x))``: x [B, S, d] -> [B, S, d]
     (``wo``'s sum over the held heads, unreduced): grouped-query attention
-    with a per-head RMS norm of q and k and rotary positions, under
-    ``mask`` (this model's ``block_diffusion``, ``models.lfm2_moe``'s
-    ``kimi_linear.causal``). ``scores_by`` is ``attn_scores_by``'s word for
-    what makes the masked scores; ``scores_scope`` names a scope of their
-    own for them inside ``attn`` (a model whose metrics read the scores
-    apart)."""
+    under ``mask`` (this model's ``block_diffusion``, ``models.lfm2_moe``'s
+    ``kimi_linear.causal``, ``models.afmoe``'s ``kimi_linear.window``), each
+    further step there where the layer has it: a per-head RMS norm of q and
+    k where the layer has the gains (``q_norm``, ``k_norm``); rotary
+    positions where the layer rotates (``theta`` None: a positionless
+    layer); the heads' outputs times ``sigmoid(xn wg)`` ahead of ``wo``
+    where the layer has the gate (``wg`` [d, Hq * D]). This model and
+    ``lfm2_moe`` norm and rotate, ``solar_open2`` gates only, ``afmoe``
+    norms and gates every layer and rotates its windowed ones. ``scores_by``
+    is ``attn_scores_by``'s word for what makes the masked scores;
+    ``scores_scope`` names a scope of their own for them inside ``attn`` (a
+    model whose metrics read the scores apart)."""
     b, s, _ = x.shape
     xn = rms_norm(x, lp["norm1"], eps)
     q = _dot(xn, lp["wq"], cdt).reshape(b, s, -1, head_dim)
     k = _dot(xn, lp["wk"], cdt).reshape(b, s, -1, head_dim)
     v = _dot(xn, lp["wv"], cdt).reshape(b, s, -1, head_dim)
-    q = rotary(rms_norm(q, lp["q_norm"], eps), positions, theta)
-    k = rotary(rms_norm(k, lp["k_norm"], eps), positions, theta)
+
+    def normed_rotated(y, gain):
+        if gain in lp:
+            y = rms_norm(y, lp[gain], eps)
+        return y if theta is None else rotary(y, positions, theta)
+
+    q, k = normed_rotated(q, "q_norm"), normed_rotated(k, "k_norm")
     with (jax.named_scope(scores_scope) if scores_scope
           else contextlib.nullcontext()):
         out = masked_scores(q, _operand(k, cdt), _operand(v, cdt),
                             mask=mask, cdt=cdt, scores_by=scores_by)
+    if "wg" in lp:
+        out = out * jax.nn.sigmoid(_dot(xn, lp["wg"], cdt))
     return _dot(out, lp["wo"], cdt)
 
 

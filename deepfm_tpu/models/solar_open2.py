@@ -16,7 +16,8 @@ shared expert, the head's loss, the layers' loop and the counts are
   period. Every layer's feed-forward is the expert layer beside the shared
   expert: there is no dense layer.
 * **The full layer is gated grouped-query attention without positions**
-  (``gqa_mixer``): ``--attn_q_heads`` query heads on ``--attn_kv_heads``
+  (``gqa_mixer``, which is ``sdar_moe.attention`` over this layer's
+  leaves): ``--attn_q_heads`` query heads on ``--attn_kv_heads``
   key/value heads of ``--attn_head_dim`` (query head j reads key/value head
   j // group), no rotary and no QK-norm, causal softmax, and the heads'
   outputs times ``sigmoid(xn gqa_w_gate)`` elementwise ahead of ``gqa_wo``
@@ -45,14 +46,12 @@ from __future__ import annotations
 
 from typing import Any, Dict, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from . import common
 from .kimi_linear import (BETA_OVER_ONE, COUNT_NAMES, DECAY_MIN, KimiLinear,
                           causal, kda_mixer)
-from .sdar_moe import (_dot, _operand, attn_notes, attn_scores_by,
-                       masked_scores, rms_norm)
+from .sdar_moe import attention, attn_notes, attn_scores_by
 
 #: The write strength's factor: ``beta = BETA_SCALE * sigmoid(...)``.
 BETA_SCALE = 2.0
@@ -66,27 +65,20 @@ def layer_kinds(cfg: Any) -> Tuple[Tuple[str, str], ...]:
                  for i in range(cfg.decoder_layers))
 
 
-@jax.named_scope("attn")
 def gqa_mixer(lp: Dict[str, jnp.ndarray], x: jnp.ndarray, *, head_dim: int,
               eps: float, cdt: jnp.dtype, scores_by: str = "xla"
               ) -> jnp.ndarray:
     """The held heads' part of ``GQA(RMSNorm(x))``, gated and without
     positional encoding: x [B, S, d] -> [B, S, d] (``gqa_wo``'s sum over the
-    held heads, unreduced). ``scores_by`` is ``sdar_moe.attn_scores_by``'s
-    word for what makes the causal scores."""
-    b, s, _ = x.shape
-    xn = rms_norm(x, lp["norm1"], eps)
-
-    def heads(name):
-        return _dot(xn, lp[name], cdt).reshape(b, s, -1, head_dim)
-
-    q = heads("gqa_wq")
-    k, v = _operand(heads("gqa_wk"), cdt), _operand(heads("gqa_wv"), cdt)
-    with jax.named_scope("attn_scores"):
-        out = masked_scores(q, k, v, mask=causal, cdt=cdt,
-                            scores_by=scores_by)
-    gate = jax.nn.sigmoid(_dot(xn, lp["gqa_w_gate"], cdt))
-    return _dot(out * gate, lp["gqa_wo"], cdt)
+    held heads, unreduced): ``sdar_moe.attention`` over this layer's leaves
+    under its names, with no gains for q and k and nothing rotated.
+    ``scores_by`` is ``sdar_moe.attn_scores_by``'s word for what makes the
+    causal scores."""
+    leaves = {"norm1": lp["norm1"], "wg": lp["gqa_w_gate"],
+              **{w: lp["gqa_" + w] for w in ("wq", "wk", "wv", "wo")}}
+    return attention(leaves, x, None, mask=causal, head_dim=head_dim,
+                     eps=eps, theta=None, cdt=cdt, scores_by=scores_by,
+                     scores_scope="attn_scores")
 
 
 class SolarOpen2(KimiLinear):

@@ -81,9 +81,9 @@ class StepWindowTracer:
 #: every HLO instruction's ``op_name`` carries the scopes it was traced under,
 #: forward and backward (``transpose(jvp(embed))``).
 STEP_SCOPES = ("embed", "fm", "cross", "bottom", "tower", "attn",
-               "attn_scores", "kda", "kda_scan", "conv", "conv_taps", "mamba",
-               "mamba_scan", "gmu", "mlp", "moe", "mtp", "head", "mtp_head",
-               "loss", "l2", "opt")
+               "attn_scores", "attn_scores_window", "kda", "kda_scan", "conv",
+               "conv_taps", "mamba", "mamba_scan", "gmu", "mlp", "moe", "mtp",
+               "head", "mtp_head", "loss", "l2", "opt")
 
 _HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
