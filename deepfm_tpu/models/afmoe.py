@@ -74,6 +74,7 @@ class Afmoe(SelectionBias, KimiLinear):
 
     name = "afmoe"
     _kinds = staticmethod(layer_kinds)
+    score_mixers = tuple(SCORES)
 
     def __init__(self, cfg: Any):
         super().__init__(cfg)

@@ -151,6 +151,9 @@ class Glm4MoeLite(SelectionBias, KimiLinear):
         return {**super().init_counts(),
                 **{n: jnp.zeros((), jnp.float32) for n in self.loss_parts}}
 
+    def _score_heads(self) -> Tuple[int, int]:
+        return self.cfg.attn_q_heads, self.cfg.mla_value_dim
+
     def _init_mixer(self, mixer: str, glorot, keys) -> Dict[str, jnp.ndarray]:
         cfg = self.cfg
         d, h, rank, latent = (cfg.embedding_size, cfg.attn_q_heads,

@@ -65,16 +65,26 @@ Memory: every layer is recomputed in the backward pass (``jax.checkpoint``);
 a KDA layer whose scan is the kernels' keeps what the forward kernel hands
 the backward one (the scan's output, the chunks' entering states and
 inverses: 117 MB a layer at 8 heads x 8,192 positions) and is made again
-around the scan, not through it (``_run_layer``). A layer also keeps the
+around the scan, not through it (``_run_layer``). Two more things a layer
+keeps where the device's memory holds them (``_keeps``). A layer whose
+masked scores are the block kernel's (``score_mixers``; a stack derived from
+this one, whose own latent attention is XLA's) keeps what that forward
+kernel hands its backward kernels, the output in the operands' type and a
+float32 log-sum-exp a query (``ops/block_attention.KEPT``: 134 + 2 MB a
+layer at 32 heads of 128 over 16,384 positions), and is made again around
+the kernel, which so runs once a layer and step. A layer also keeps the
 float32 first products of its dense SwiGLU (``swiglu``'s gate and up
-products, named ``MLP_KEPT``: 8 bytes a position and unit of width) where
-the device's memory holds them: the backward pass reads them where it would
-make them again, and makes SiLU, the gate's product with ``up`` and the down
-product's operand again from them. How many layers keep is
-``mlp_kept_by``'s, from the last layer back, read from the device's memory
-limit, the parameters' bytes and the step's positions (no flag;
-``step_notes``: ``mlp_kept``, ``6/6 layers 4.03 GB``); off a TPU, or where
-the device says nothing of its memory, no layer keeps anything.
+products, named ``MLP_KEPT``: 8 bytes a position and unit of width): the
+backward pass reads them where it would make them again, and makes SiLU,
+the gate's product with ``up`` and the down product's operand again from
+them. How many layers keep each is ``sdar_moe.kept_by``'s, from the last
+layer back, read from the device's memory limit, the parameters' bytes and
+the step's positions (no flag), **the kernel's tensors first and the
+products in what they leave**: a byte of the first buys about eight times
+the time of a byte of the second (PERF.md section 6, PR 54). ``step_notes``
+says both: ``attn_kept`` (``5/5 layers 0.68 GB``) and ``mlp_kept`` (``6/6
+layers 4.03 GB``); off a TPU, or where the device says nothing of its
+memory, no layer keeps anything.
 The stack is a Python loop over layers of three shapes, not a scan.
 """
 
@@ -87,13 +97,14 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..ops import pallas_kda_scan
+from ..ops import block_attention, pallas_kda_scan
 from . import common, sdar_moe
 from .graph import GraphModel
-from .sdar_moe import (ScoreMask, _dot, _float32_bytes, _operand, _scores_xla,
-                       expert_layer, head_grad_by, head_grad_note, moe_notes,
-                       moe_products_by, moe_rows_by, rms_norm, route,
-                       weighted_nll)
+from .sdar_moe import (ScoreMask, _dot, _float32_bytes, _operand,
+                       _operand_bytes, _scores_xla, expert_layer,
+                       head_grad_by, head_grad_note, held_bytes, kept_by,
+                       kept_note, layer_policy, moe_notes, moe_products_by,
+                       moe_rows_by, rms_norm, route, weighted_nll)
 
 #: The step's counts, in the model state and (by ``step_counts``) the metrics.
 COUNT_NAMES = ("moe_pairs_held", "moe_pairs_over_buffer",
@@ -113,23 +124,6 @@ L2_EPS = 1e-6
 #: saves the name reads them in the backward pass and does not make them
 #: again.
 MLP_KEPT = "mlp_first_products"
-#: Parameter-shaped arrays that ``train.optimizers.build_optimizer``'s state
-#: holds beside each parameter (a test holds this to the states themselves).
-OPTIMIZER_COPIES = {"adam": 2, "ftrl": 2, "adagrad": 1, "momentum": 1,
-                    "sgd": 0}
-#: Bytes a position of the step that ``mlp_kept_by`` leaves free of kept
-#: products: the room of a layer's working set, which follows the step's
-#: positions. Set from four steps compiled for a v5e (``memory_analysis()``,
-#: PERF.md section 6, PR 47; 16,909,336,064 bytes of memory): what a step
-#: that keeps nothing holds beyond 16 bytes a parameter is 1.26 GB (Phi-4-
-#: flash) and 1.12 GB (Solar-Open2) at 8,192 positions, 2.06 GB
-#: (Kimi-Linear) and 2.22 GB (LFM2) at 16,384: 126 to 153 KB a position.
-#: 200 KiB is a third above the largest, and inside the one window the
-#: Phi-4-flash stack leaves: its six layers keep all six products (4.03 GB;
-#: the step compiles to 15.11 GB) at anything up to 206 KiB, and the same
-#: stack of eight layers (14.65 GB of parameters, moments and gradients),
-#: which compiles today and must go on compiling, keeps none from 190 KiB.
-MLP_KEEP_RESERVE = 200 * 1024
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -204,46 +198,6 @@ def kda_scan_note(by: str) -> str:
     """What ``step_notes`` says of the scan's form."""
     return (f"kernel chunk{KDA_CHUNK}" if by == "kernel"
             else f"chunk{KDA_CHUNK}/sub{KDA_SUB}")
-
-
-def mlp_kept_by(layer_bytes: Sequence[int], *, positions: int, limit: int,
-                held: int) -> int:
-    """How many of the stack's SwiGLUs keep their first products for the
-    backward pass, counted from the last layer back (the last layer's are
-    freed first, while the layers' gradients come to life): ``layer_bytes``
-    what each would keep, first layer first; ``limit`` the device's memory
-    (``sdar_moe.device_memory_bytes``; 0: nothing is known, nothing is
-    kept);
-    ``held`` what the step holds without them (``KimiLinear._held_bytes``);
-    ``positions`` the step's. A layer keeps while what is left of the
-    memory stays above ``MLP_KEEP_RESERVE`` bytes a position, the room of a
-    layer's working set."""
-    if limit <= 0:
-        return 0
-    room = limit - held - MLP_KEEP_RESERVE * positions
-    kept = 0
-    for size in reversed(layer_bytes):
-        if not 0 < size <= room:
-            break
-        room -= size
-        kept += 1
-    return kept
-
-
-def mlp_kept_note(kept: int, layers: int, kept_bytes: int) -> str:
-    """What ``step_notes`` says of the SwiGLUs' first products."""
-    return f"{kept}/{layers}" + (
-        f" layers {kept_bytes / 1e9:.2f} GB" if kept else "")
-
-
-def layer_policy(keeps: Dict[str, bool]):
-    """The ``jax.checkpoint`` policy of a layer that keeps what carries a
-    name (``checkpoint_name``) that ``keeps`` says yes to and makes
-    everything else again; None, a layer that keeps nothing, where it says
-    yes to none."""
-    names = [name for name, keep in keeps.items() if keep]
-    return (jax.checkpoint_policies.save_only_these_names(*names)
-            if names else None)
 
 
 @jax.named_scope("kda_scan")
@@ -423,6 +377,11 @@ class KimiLinear(GraphModel):
     #: cfg -> ((mixer, feed-forward) of each layer); a model with another
     #: pattern names its own
     _kinds = staticmethod(layer_kinds)
+    #: The mixers that mix by attention scores (a model with others names
+    #: its own): where ``_paths`` says the scores are the block kernel's
+    #: (``scores_by``; this model's are XLA's and it says nothing), their
+    #: layers may keep the kernel's output and log-sum-exp (``_keeps``).
+    score_mixers: Tuple[str, ...] = ("mla",)
 
     def __init__(self, cfg: Any):
         super().__init__(cfg)
@@ -590,41 +549,57 @@ class KimiLinear(GraphModel):
                 for size in sizes]
 
     def _held_bytes(self, params: common.Params, ids: jnp.ndarray) -> int:
-        """What a train step of ``ids`` [B, L] holds on a device whatever
-        its layers keep, from what ``hidden`` can see: the parameters as it
-        is handed them, as many more copies as the optimizer's state holds
-        (``OPTIMIZER_COPIES``), one of gradients, and what the head passes
-        keep a sequence beyond that one (``sdar_moe.head_grad_by``). (Not
-        the bytes resident when the step is traced: ``Trainer.step_compiled``
-        traces from shapes, and a step traced twice has to be one
-        program.)"""
-        leaves = sum(x.size * x.dtype.itemsize
-                     for x in jax.tree.leaves(params))
-        heads = sum((ids.shape[0] - 1) * size
-                    for by, size in self._head_grads(params, ids)
-                    if by == "forward")
-        return heads + leaves * (
-            2 + OPTIMIZER_COPIES[self.cfg.optimizer.lower()])
+        """``sdar_moe.held_bytes`` of the step of ``ids`` [B, L]."""
+        return held_bytes(params, self.cfg.optimizer,
+                          self._head_grads(params, ids), ids.shape[0])
 
-    def _mlp_keeps(self, params: common.Params, ids: jnp.ndarray
-                   ) -> Tuple[bool, ...]:
-        """Whether each block (``block_kinds``) of the step of ``ids``
-        [B, L] keeps its SwiGLU's first products for the backward pass
-        (``mlp_kept_by``; a layer without a dense SwiGLU has none to keep);
-        ``step_notes`` is told."""
+    def _score_heads(self) -> Tuple[int, int]:
+        """(query heads, a value's width) of a layer's masked scores."""
+        return self.cfg.attn_q_heads, self.cfg.attn_head_dim
+
+    def _keeps(self, params: common.Params, ids: jnp.ndarray,
+               paths: Dict[str, str]) -> Tuple[Dict[str, bool], ...]:
+        """What each block (``block_kinds``) of the step of ``ids`` [B, L]
+        keeps for the backward pass, as ``layer_policy``'s argument:
+        ``block_attention.KEPT``, the attention kernel's output and
+        log-sum-exp, where its scores are the kernel's (``paths``'
+        ``scores_by``); ``MLP_KEPT``, its SwiGLU's first products, where it
+        has a dense SwiGLU. The kernel's tensors are placed first, the
+        products in the room they leave (``kept_by``, each from the last
+        block back); ``step_notes`` is told both (``attn_kept``,
+        ``mlp_kept``)."""
         cfg = self.cfg
+        heads, value = self._score_heads()
+        scores = block_attention.kept_bytes(ids.size * heads, value,
+                                            _operand_bytes(self.cdt))
+        limit = sdar_moe.device_memory_bytes()
+        keeps = [{} for _ in self.block_kinds]
+
+        def place(name, note, sizes, limit, held):
+            """``name`` kept in as many of the blocks that have ``sizes``
+            of it as find room beside ``held`` -> the bytes placed."""
+            have = [i for i, size in enumerate(sizes) if size]
+            kept = kept_by([sizes[i] for i in have], positions=ids.size,
+                           limit=limit, held=held)
+            keeping = have[len(have) - kept:]
+            placed = sum(sizes[i] for i in keeping)
+            self.step_notes[note] = kept_note(kept, len(have), placed)
+            for i, keep in enumerate(keeps):
+                keep[name] = i in keeping
+            return placed
+
+        held = self._held_bytes(params, ids)
+        held += place(
+            block_attention.KEPT, "attn_kept",
+            [scores * (mixer in self.score_mixers)
+             for mixer, _ in self.block_kinds],
+            limit if paths.get("scores_by") == "kernel" else 0, held)
         # float32 gate and up products a position
-        sizes = [8 * ids.size * (cfg.dense_mlp_width if ffn == "mlp"
-                                 else cfg.moe_shared_width)
-                 for _, ffn in self.block_kinds]
-        have = [i for i, size in enumerate(sizes) if size]
-        kept = mlp_kept_by([sizes[i] for i in have], positions=ids.size,
-                           limit=sdar_moe.device_memory_bytes(),
-                           held=self._held_bytes(params, ids))
-        keeping = have[len(have) - kept:]
-        self.step_notes["mlp_kept"] = mlp_kept_note(
-            kept, len(have), sum(sizes[i] for i in keeping))
-        return tuple(i in keeping for i in range(len(sizes)))
+        place(MLP_KEPT, "mlp_kept",
+              [8 * ids.size * (cfg.dense_mlp_width if ffn == "mlp"
+                               else cfg.moe_shared_width)
+               for _, ffn in self.block_kinds], limit, held)
+        return tuple(keeps)
 
     def _mixer(self, mixer: str, lp: Dict[str, jnp.ndarray], x: jnp.ndarray,
                scan_by: str = "xla"
@@ -684,21 +659,23 @@ class KimiLinear(GraphModel):
 
     def _run_layer(self, i: int, kind: Tuple[str, str], x: jnp.ndarray,
                    lp: Dict[str, jnp.ndarray], left: Dict[str, jnp.ndarray],
-                   paths: Dict[str, str], keep_mlp: bool = False):
+                   paths: Dict[str, str],
+                   keeps: Optional[Dict[str, bool]] = None):
         """Layer ``i`` (of kind ``kind``, leaves ``lp``), made again in the
         backward pass -> (the stream, the layer's counts, what the layers so
         far leave for later ones to read, by name). ``left`` is what the
         earlier layers left; here no layer reads or leaves anything (a model
         whose layers read other layers' tensors hands them through its own:
-        ``models.phi4_flash``). ``keep_mlp``: ``_mlp_keeps``' word for this
+        ``models.phi4_flash``). ``keeps``: ``_keeps``' word for this
         layer."""
         # (a KDA layer by the kernels keeps its scan's output, entering
         # states and inverses: the layer is made again around them, the scan
-        # is not; a layer that keeps its SwiGLU's first products is made
-        # again around those too)
+        # is not; a layer that keeps its attention kernel's output and
+        # log-sum-exp or its SwiGLU's first products is made again around
+        # those too)
         policy = layer_policy({
             pallas_kda_scan.KEPT: paths.get("scan_by") == "kernel",
-            MLP_KEPT: keep_mlp})
+            **(keeps or {})})
         x, counts = jax.checkpoint(functools.partial(
             self._layer, *kind, **paths), policy=policy)(x, lp)
         return x, counts, left
@@ -713,7 +690,7 @@ class KimiLinear(GraphModel):
         counts: sums, the ``_max`` ones' largest, the decay's least).
         ``data_axis`` names the mesh axis of a step across data replicas."""
         paths = self._paths(ids, one_device=data_axis is None)
-        keeps = self._mlp_keeps(params, ids)
+        keeps = self._keeps(params, ids, paths)
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
         if self.embed_scale != 1.0:
@@ -723,7 +700,7 @@ class KimiLinear(GraphModel):
         return x, self._merged_counts(seen)
 
     def _run_layers(self, params: common.Params, x: jnp.ndarray,
-                    paths: Dict[str, str], keeps: Sequence[bool]
+                    paths: Dict[str, str], keeps: Sequence[Dict[str, bool]]
                     ) -> Tuple[jnp.ndarray, Dict[str, list]]:
         """The stack's layers over the looked-up rows x [B, L, d] -> (the
         last residual stream, the layers' counts: {count: [a value a

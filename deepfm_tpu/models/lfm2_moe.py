@@ -143,6 +143,7 @@ class Lfm2Moe(SelectionBias, KimiLinear):
     name = "lfm2_moe"
     tied_head = True
     _kinds = staticmethod(layer_kinds)
+    score_mixers = ("full_attention",)
 
     def __init__(self, cfg: Any):
         super().__init__(cfg)

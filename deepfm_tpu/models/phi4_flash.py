@@ -82,10 +82,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import pallas_selective_scan
 from . import common
-from .kimi_linear import (MLP_KEPT, KimiLinear, causal, causal_conv,
-                          layer_policy, window)
+from .kimi_linear import MLP_KEPT, KimiLinear, causal, causal_conv, window
 from .sdar_moe import (ScoreMask, _dot, _operand, attn_scores_by,
-                       masked_scores, masks_notes, rms_norm)
+                       layer_policy, masked_scores, masks_notes, rms_norm)
 
 #: What a mixer reads of what earlier layers left (``LEAVES``), by name.
 READS = {"gmu": ("memory",), "cross_attention": ("shared_k", "shared_v")}
@@ -306,6 +305,7 @@ class Phi4Flash(KimiLinear):
     #: no grouped product: the model has no experts
     kernel_scopes = ()
     _kinds = staticmethod(layer_kinds)
+    score_mixers = ("window_attention", "full_attention", "cross_attention")
 
     def __init__(self, cfg: Any):
         super().__init__(cfg)
@@ -314,6 +314,10 @@ class Phi4Flash(KimiLinear):
 
     def init_counts(self) -> common.State:
         return {DECAY_MIN: jnp.zeros((), jnp.float32)}
+
+    def _score_heads(self) -> Tuple[int, int]:
+        """... a key's pair of values side by side."""
+        return self.cfg.attn_q_heads, 2 * self.cfg.attn_head_dim
 
     def _init_layer(self, rng: jax.Array, mixer: str, ffn: str
                     ) -> Dict[str, jnp.ndarray]:
@@ -425,12 +429,13 @@ class Phi4Flash(KimiLinear):
 
     def _run_layer(self, i: int, kind: Tuple[str, str], x: jnp.ndarray,
                    lp: Dict[str, jnp.ndarray], left: Dict[str, jnp.ndarray],
-                   paths: Dict[str, str], keep_mlp: bool = False):
+                   paths: Dict[str, str],
+                   keeps: Optional[Dict[str, bool]] = None):
         read = {name: left[name] for name in READS.get(kind[0], ())}
         x, counts, leaves = jax.checkpoint(
             functools.partial(self._layer, *kind,
                               layer=self.cfg.first_layer + i, **paths),
-            policy=layer_policy({MLP_KEPT: keep_mlp}))(x, lp, read)
+            policy=layer_policy(keeps or {}))(x, lp, read)
         return x, counts, {**left, **leaves}
 
     @jax.named_scope("head")

@@ -90,10 +90,18 @@ the one statement of it both read.
 
 Memory: each layer is recomputed in the backward pass (``jax.checkpoint``
 around the scanned layer). No [S, S] score matrix is ever held: the kernel
-keeps a block of scores in VMEM and saves a log-sum-exp a query for its
-backward kernels, which make the scores again; the XLA path runs a chunk of
-queries at a time, each chunk recomputed too, so its [chunk, S] scores do
-not outlive the chunk.
+keeps a block of scores in VMEM and hands its backward kernels, which make
+the scores again, its output and a log-sum-exp a query; the XLA path runs a
+chunk of queries at a time, each chunk recomputed too, so its [chunk, S]
+scores do not outlive the chunk. Where the scores are the kernel's, a layer
+keeps those two tensors (``ops/block_attention.KEPT``: 17 MB a layer at 4
+held heads of 128 over 2 x 8,192 positions, the scan's stacked outputs) and
+is made again around the forward kernel, which so runs once a layer and
+step, wherever the device's memory holds them beside the step (``kept_by``:
+the device's memory limit, 16 bytes a parameter under Adam, ``KEEP_RESERVE``
+a position; every layer or none, the layers are one scan; no flag;
+``step_notes``: ``attn_kept``, ``6/6 layers 0.10 GB``; off a TPU, on the XLA
+path or where the device says nothing of its memory, ``0/6``).
 
 **The head's loss** (``weighted_nll``, which the other decoders import): the
 final norm, the head product and the cross-entropy, ``HEAD_CHUNK`` positions
@@ -114,7 +122,7 @@ import contextlib
 import dataclasses
 import functools
 import math
-from typing import Any, Callable, Dict, Hashable, Optional, Tuple
+from typing import Any, Callable, Dict, Hashable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -246,6 +254,11 @@ def _operand(x: jnp.ndarray, compute_dtype: jnp.dtype) -> jnp.ndarray:
     narrower; a precision below that is a rounding of the operands)."""
     x = x.astype(compute_dtype)
     return x if compute_dtype.itemsize >= 2 else x.astype(jnp.bfloat16)
+
+
+def _operand_bytes(compute_dtype: jnp.dtype) -> int:
+    """Bytes a number of an ``_operand``."""
+    return max(compute_dtype.itemsize, 2)
 
 
 def _dot(x: jnp.ndarray, w: jnp.ndarray, cdt: jnp.dtype) -> jnp.ndarray:
@@ -623,6 +636,93 @@ def device_memory_bytes() -> int:
         "bytes_limit", 0))
 
 
+#: Parameter-shaped arrays that ``train.optimizers.build_optimizer``'s state
+#: holds beside each parameter (a test holds this to the states themselves).
+OPTIMIZER_COPIES = {"adam": 2, "ftrl": 2, "adagrad": 1, "momentum": 1,
+                    "sgd": 0}
+#: Bytes a position of the step that ``kept_by`` leaves free of what layers
+#: keep for the backward pass: the room of a layer's working set, which
+#: follows the step's positions. Set from the ``models.kimi_linear`` stacks'
+#: steps compiled for a v5e (``memory_analysis()``, PERF.md section 6, PR
+#: 54; 16,909,336,064 bytes of memory): what a step that keeps nothing holds
+#: beyond 16 bytes a parameter is 0.36 GB (Solar-Open2) and 1.00 GB
+#: (Phi-4-flash) at 8,192 positions, 1.41 GB (Kimi-Linear), 1.45 GB
+#: (GLM-4.7-Flash), 1.95 GB (LFM2) and 2.83 GB (Trinity-Mini) at 16,384: 44
+#: to 173 KB a position. 225 KiB is a third above the largest, and inside
+#: the window three of those steps leave.
+#: Trinity-Mini keeps its five layers' kernel tensors and its four shared
+#: experts' products (15.06 GB compiled) from 215 KiB; below, its dense
+#: layer's products too, 15.87 GB, over the 15.5 GB its compile test allows
+#: a step. GLM-4.7-Flash keeps its six blocks' kernel tensors and five
+#: shared experts' products (14.02 GB) up to 227 KiB, and Phi-4-flash's six
+#: layers their three kernel tensors and five of six products (14.56 GB) up
+#: to 255 KiB; the same stack of eight layers (14.65 GB of parameters,
+#: moments and gradients), which compiled before anything was kept and must
+#: go on compiling, keeps four layers' kernel tensors and no product: 15.13
+#: GB. (200 KiB until PR 54, from PR 47's four steps, 126 to 153 KB a
+#: position; Trinity-Mini's came with PR 53. ``SdarMoE``'s scanned stack
+#: holds 330 KB a position beyond its parameters' 16 bytes and 2.6 GB under
+#: the limit: what its layers keep, 0.10 GB, this reserve does not decide.)
+KEEP_RESERVE = 225 * 1024
+
+
+def held_bytes(params: Any, optimizer: str, head_passes: Sequence[
+        Tuple[str, int]], batch: int) -> int:
+    """What a train step holds on a device whatever its layers keep, from
+    what a model's ``hidden`` can see: the parameters as it is handed them,
+    as many more copies as the optimizer's state holds
+    (``OPTIMIZER_COPIES``), one of gradients, and what the head passes
+    (``head_grad_by``'s word, the float32 bytes the pass reads) keep a
+    sequence of ``batch`` beyond that one. (Not the bytes resident when the
+    step is traced: ``Trainer.step_compiled`` traces from shapes, and a step
+    traced twice has to be one program.)"""
+    leaves = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    heads = sum((batch - 1) * size for by, size in head_passes
+                if by == "forward")
+    return heads + leaves * (2 + OPTIMIZER_COPIES[optimizer.lower()])
+
+
+def kept_by(layer_bytes: Sequence[int], *, positions: int, limit: int,
+            held: int) -> int:
+    """How many of a stack's layers keep something for the backward pass (a
+    dense SwiGLU's first products, the attention kernel's output and
+    log-sum-exp), counted from the last layer back (the last layer's are
+    freed first, while the layers' gradients come to life): ``layer_bytes``
+    what each would keep, first layer first; ``limit`` the device's memory
+    (``device_memory_bytes``; 0: nothing is known, nothing is kept);
+    ``held`` what the step holds without them (``held_bytes``, and what was
+    placed ahead of these); ``positions`` the step's. A layer keeps while
+    what is left of the memory stays above ``KEEP_RESERVE`` bytes a
+    position, the room of a layer's working set."""
+    if limit <= 0:
+        return 0
+    room = limit - held - KEEP_RESERVE * positions
+    kept = 0
+    for size in reversed(layer_bytes):
+        if not 0 < size <= room:
+            break
+        room -= size
+        kept += 1
+    return kept
+
+
+def kept_note(kept: int, layers: int, kept_bytes: int) -> str:
+    """What ``step_notes`` says of what ``kept`` of ``layers`` layers keep
+    (``mlp_kept``, ``attn_kept``)."""
+    return f"{kept}/{layers}" + (
+        f" layers {kept_bytes / 1e9:.2f} GB" if kept else "")
+
+
+def layer_policy(keeps: Dict[str, bool]):
+    """The ``jax.checkpoint`` policy of a layer that keeps what carries a
+    name (``checkpoint_name``) that ``keeps`` says yes to and makes
+    everything else again; None, a layer that keeps nothing, where it says
+    yes to none."""
+    names = [name for name, keep in keeps.items() if keep]
+    return (jax.checkpoint_policies.save_only_these_names(*names)
+            if names else None)
+
+
 def head_grad_by(batch: int, param_bytes: int, limit: int, *,
                  one_device: bool = True) -> str:
     """``forward`` where a differentiated ``weighted_nll`` makes a chunk's
@@ -806,10 +906,11 @@ class SdarMoE(GraphModel):
         #: ``train.log_sync`` while tracing is on: ``attn_scores`` (``kernel``
         #: / ``xla``) and, of the kernel, ``attn_score_blocks`` (blocks of
         #: the score matrix the forward pass computes / all of them, a head);
-        #: ``moe_rows``, ``moe_products`` and ``moe_rows_moved``
-        #: (``moe_notes``); ``head_grad``
-        #: (``head_grad_note``). A note may name a count of the step in
-        #: braces.
+        #: ``attn_kept`` (``kept_note``: the layers that keep the kernel's
+        #: output and log-sum-exp for the backward pass); ``moe_rows``,
+        #: ``moe_products`` and ``moe_rows_moved`` (``moe_notes``);
+        #: ``head_grad`` (``head_grad_note``). A note may name a count of
+        #: the step in braces.
         self.step_notes: Dict[str, str] = {}
 
     def _attn_notes(self, scores_by: str, seq: int, length: int
@@ -817,6 +918,34 @@ class SdarMoE(GraphModel):
         return attn_notes(
             scores_by, block_diffusion(length, self.cfg.diffusion_block),
             seq, self.cfg.attn_q_heads // self.cfg.attn_kv_heads)
+
+    def _head_grad(self, params: common.Params, batch: int,
+                   one_device: bool) -> Tuple[str, int]:
+        """(``head_grad_by``'s word, the float32 bytes of what the pass
+        reads) of the head pass of a step of ``batch`` sequences."""
+        read = _float32_bytes((params["final_norm"], params["head"]))
+        return head_grad_by(batch, read, device_memory_bytes(),
+                            one_device=one_device), read
+
+    def _attn_keep(self, params: common.Params, ids: jnp.ndarray,
+                   scores_by: str) -> bool:
+        """Whether the layers of the step of ``ids`` [B, 2L] keep the
+        attention kernel's output and log-sum-exp for the backward pass
+        (``kept_by``: every layer or none, the layers are one scan; nothing
+        where the scores are not the kernel's); ``step_notes`` is told."""
+        cfg = self.cfg
+        layers = cfg.decoder_layers
+        size = block_attention.kept_bytes(
+            ids.size * cfg.attn_q_heads, cfg.attn_head_dim,
+            _operand_bytes(self.cdt))
+        keep = scores_by == "kernel" and layers == kept_by(
+            [size] * layers, positions=ids.size, limit=device_memory_bytes(),
+            held=held_bytes(params, cfg.optimizer,
+                            [self._head_grad(params, ids.shape[0], True)],
+                            ids.shape[0]))
+        self.step_notes["attn_kept"] = kept_note(
+            layers * keep, layers, layers * size * keep)
+        return keep
 
     def embedding_param_names(self) -> Tuple[str, ...]:
         return ("tok_emb",)
@@ -888,10 +1017,14 @@ class SdarMoE(GraphModel):
             **moe_notes(rows_by, cfg.moe_pair_capacity, cfg.decoder_layers,
                         products_by, cfg.embedding_size,
                         cfg.moe_expert_width)}
+        # (layers by the kernel keep its output and log-sum-exp: a layer is
+        # made again around the forward kernel, not through it)
+        policy = layer_policy(
+            {block_attention.KEPT: self._attn_keep(params, ids, scores_by)})
         x = self._emb_lookup(params, "tok_emb", ids, shard_axis, emb_rows,
                              emb_plan).astype(jnp.float32)
 
-        @jax.checkpoint
+        @functools.partial(jax.checkpoint, policy=policy)
         def layer(x, lp):
             # The barrier keeps the layer's casts to the compute precision
             # inside the loop: without it XLA casts the whole stack of every
@@ -930,10 +1063,9 @@ class SdarMoE(GraphModel):
         over L (``weighted_nll``)."""
         weight = jnp.where(masked, 1.0 / jnp.repeat(
             t, self.cfg.diffusion_block, axis=1), 0.0)
-        read = _float32_bytes((params["final_norm"], params["head"]))
-        self.step_notes["head_grad"] = head_grad_note(
-            head_grad_by(h.shape[0], read, device_memory_bytes(),
-                         one_device=not jax.typeof(h).vma), h.shape[0], read)
+        by, read = self._head_grad(params, h.shape[0],
+                                   not jax.typeof(h).vma)
+        self.step_notes["head_grad"] = head_grad_note(by, h.shape[0], read)
         return weighted_nll(functools.partial(self.logits, params), h,
                             tokens, weight) / h.shape[1]
 

@@ -87,6 +87,7 @@ class SolarOpen2(KimiLinear):
 
     name = "solar_open2"
     _kinds = staticmethod(layer_kinds)
+    score_mixers = ("gqa",)
 
     def init_counts(self) -> common.State:
         return {**{n: jnp.zeros((), jnp.int32)

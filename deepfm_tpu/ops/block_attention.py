@@ -11,6 +11,12 @@ again from the saved log-sum-exp. Several query heads on one key/value head
 are its MQA form's head axis, so no key or value is repeated. It applies no
 scale: the caller folds ``1/sqrt(head_dim)`` into q.
 
+What the forward kernel hands the backward kernels beside its operands, its
+output and the log-sum-exp, carries the name ``KEPT``: a ``jax.checkpoint``
+whose policy saves the name holds those two (``kept_bytes``) and does not run
+the forward kernel again in the backward pass; one that does not runs it
+twice, as before the name.
+
 What this module adds is the mask's form. The caller states its mask once, as
 an elementwise function of (query index, key index) — the same function its
 XLA path uses — and ``PairMask`` evaluates it block by block on the host
@@ -42,6 +48,9 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
 #: Lanes of one vector register line: a head's rows are whole lines or half
 #: lines.
 LANES = 128
+#: ``checkpoint_name`` of the forward kernel's output and log-sum-exp, which
+#: the backward kernels read.
+KEPT = "block_attention_kept"
 
 
 def supported(backend: str, seq: int, head_dim: int, block: int) -> bool:
@@ -93,7 +102,15 @@ def make_kernel(pairs: Callable, key: Hashable, *, seq: int, heads: int,
         block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
         block_q_dq=block, block_kv_dq=block)
     return _kernel.make_splash_mqa_single_device(
-        mask, block_sizes=sizes, interpret=interpret)
+        mask, block_sizes=sizes, residual_checkpoint_name=KEPT,
+        interpret=interpret)
+
+
+def kept_bytes(queries: int, value_dim: int, itemsize: int) -> int:
+    """Bytes of what carries ``KEPT`` over ``queries`` (sequences x positions
+    x query heads): an output of ``value_dim`` numbers of ``itemsize`` bytes
+    (the operands' type) and a float32 log-sum-exp a query."""
+    return queries * (value_dim * itemsize + 4)
 
 
 def visited_blocks(kernel, seq: int, block: int) -> Tuple[int, int]:

@@ -86,8 +86,12 @@ Where the model says what makes its attention's masked scores
 visits only the blocks of the score matrix the model's mask,
 block-diffusion or causal, leaves something in; ``xla``: every score of
 every query chunk) and, of the kernel, ``attn_score_blocks`` (visited / all,
-a head); the report prints them on its "block-masked attention" line
-(TUNING §5).
+a head), and ``attn_kept``: how many attention layers keep the forward
+kernel's output and log-sum-exp for the backward pass, which so runs that
+kernel once a layer and not twice, and the bytes kept (``5/5 layers 0.68
+GB``; ``0/5`` on the XLA path and where the device's memory holds none:
+``sdar_moe.kept_by``); the report prints them on its "block-masked
+attention" line (TUNING §5).
 
 The decoders say how their expert layers' rows go to and from their
 positions: ``moe_rows`` (``kernel``: one copy a row over the pairs really
@@ -141,7 +145,7 @@ Where the model has dense SwiGLUs (a dense MLP or a shared expert:
 float32 first products for the backward pass and the bytes kept
 (``6/6 layers 4.03 GB``; ``0/5`` where the device's memory holds none, off a
 TPU or where the device says nothing of its memory:
-``kimi_linear.mlp_kept_by``); the report prints one "dense SwiGLUs" line
+``sdar_moe.kept_by``); the report prints one "dense SwiGLUs" line
 (TUNING §17).
 
 Usage:
@@ -481,12 +485,16 @@ def attention_scores(events):
     spans that say so: ``steps`` read, ``scores`` (``attn_scores``:
     ``kernel`` / ``xla``, the compiled step's choice) and, of the kernel,
     ``visited`` and ``total`` blocks of the score matrix a head
-    (``attn_score_blocks``); None when no span has them (another model, or
-    a trace that predates them)."""
+    (``attn_score_blocks``), and ``kept`` where the spans say it
+    (``attn_kept``: layers keeping the forward kernel's results of layers
+    that could, and the bytes); None when no span has them (another model,
+    or a trace that predates them)."""
     seen = _log_syncs(events, "attn_scores")
     if not seen:
         return None
     out = {"steps": len(seen), "scores": seen[-1]["attn_scores"]}
+    if "attn_kept" in seen[-1]:
+        out["kept"] = seen[-1]["attn_kept"]
     if "attn_score_blocks" in seen[-1]:
         visited, total = seen[-1]["attn_score_blocks"].split("/")
         out.update(visited=int(visited), total=int(total))
@@ -703,7 +711,10 @@ def main(argv=None):
               + (", %d of %d blocks of the score matrix visited a head "
                  "(%.1f%%)" % (attn["visited"], attn["total"],
                                100 * attn["visited"] / attn["total"])
-                 if "visited" in attn else ", every score computed"))
+                 if "visited" in attn else ", every score computed")
+              + ("; the forward kernel's output and log-sum-exp kept for "
+                 "the backward pass in %s" % attn["kept"]
+                 if "kept" in attn else ""))
     if conv is not None:
         print("gated short convolution over %d logged steps: taps and gates "
               "by %s" % (conv["steps"], conv["taps_by"]))

@@ -26,6 +26,7 @@ the tests read them. A test that needs another program (two replicas,
 bfloat16, a small buffer, the row kernels through the interpreter) builds
 exactly that one. (Not collected by name: no ``test_`` in the file's.)"""
 
+import collections
 import dataclasses
 import functools
 import os
@@ -92,6 +93,27 @@ def products_in_scope(text: str, scope: str):
     lines = [line for line in text.splitlines() if " convolution(" in line
              and re.search(r'op_name="[^"]*[/(]%s[/)]' % scope, line)]
     return len(lines), sum("rematted_computation" in line for line in lines)
+
+
+def attention_kernel_calls(by_op) -> Tuple[int, int, int]:
+    """Calls of the block attention's (forward, dq, dk/dv) kernels among a
+    compiled step's instructions (``profiling.hlo_op_scopes``' names:
+    ``kernel`` or ``kernel.<n>``)."""
+    called = collections.Counter(name.split(".")[0] for name in by_op)
+    return tuple(called["splash_mqa_" + kernel] for kernel in (
+        "fwd_residuals", "dq_no_residuals", "dkv_no_residuals"))
+
+
+def all_eqns(jaxpr):
+    """The equations of ``jaxpr`` and of every jaxpr inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (tuple, list))
+                          else (value,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from all_eqns(inner)
 
 
 def cut_columns(a, first, n, heads, per):

@@ -95,6 +95,8 @@ SPEC = Spec(
         "head_grad": "forward 3 products/chunk, 0.00 GB kept",
         # the dense SwiGLU and the two shared experts: none kept off a TPU
         "mlp_kept": "0/3",
+        # the three attention layers: XLA's scores, nothing to keep
+        "attn_kept": "0/3",
         "moe_rows_moved": "{moe_pairs_held}/%d" % (2 * 2 * B * L)},
     kinds=KINDS,
     layer_counts={"moe_pairs_held": "moe", sdar_moe.BIAS_MOVED: "moe"},

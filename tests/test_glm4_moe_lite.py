@@ -112,6 +112,8 @@ SPEC = Spec(
         # the dense layer's SwiGLU and each expert block's shared expert:
         # none kept off a TPU
         "mlp_kept": "0/%d" % len(trainer.model.block_kinds),
+        # every block mixes by attention: XLA's scores, nothing to keep
+        "attn_kept": "0/%d" % len(trainer.model.block_kinds),
         # the expert layers and the module's block, one pass each
         "moe_products": "xla",
         "moe_rows_moved": "{moe_pairs_held}/%d" % (

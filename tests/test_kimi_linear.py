@@ -72,6 +72,9 @@ def notes(trainer):
     return {"kda_scan": "chunk64/sub16", "mla_scores": "xla",
             "head_grad": "forward 3 products/chunk, 0.00 GB kept",
             "mlp_kept": "0/%d" % len(trainer.model.kinds),
+            # (nor, anywhere, a latent-attention layer its XLA scores)
+            "attn_kept": "0/%d" % sum(
+                mixer == "mla" for mixer, _ in trainer.model.kinds),
             "moe_rows": "xla", "moe_products": "xla",
             "moe_rows_moved": "{moe_pairs_held}/%d" % (
                 layers * trainer.cfg.moe_pair_capacity)}

@@ -92,6 +92,9 @@ SPEC = Spec(
         # the dense layers' SwiGLUs (no shared expert): none kept off a TPU
         "mlp_kept": "0/%d" % sum(
             ffn == "mlp" for _, ffn in trainer.model.kinds),
+        # the full-attention layers: XLA's scores, nothing to keep
+        "attn_kept": "0/%d" % sum(
+            mixer == "full_attention" for mixer, _ in trainer.model.kinds),
         "moe_products": "xla",
         "moe_rows_moved": "{moe_pairs_held}/%d" % (2 * 2 * B * L)},
     kinds=KINDS,

@@ -1,5 +1,5 @@
 """The dense SwiGLU's first products kept for the backward pass
-(``kimi_linear.MLP_KEPT``, ``mlp_kept_by``, ``KimiLinear._mlp_keeps``), at
+(``kimi_linear.MLP_KEPT``, ``sdar_moe.kept_by``, ``KimiLinear._keeps``), at
 small widths on the CPU, each comparison one jitted program: a layer that
 keeps them gives the output and every leaf's gradient of the layer that
 keeps nothing (``mlp_``, ``shared_`` and ``phi4_flash.mlp``'s one-matrix
@@ -24,7 +24,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from decoder_contract import off_one  # noqa: E402
+from decoder_contract import all_eqns, off_one  # noqa: E402
 from deepfm_tpu.models import get_model, kimi_linear, sdar_moe  # noqa: E402
 from deepfm_tpu.train.optimizers import build_optimizer  # noqa: E402
 import test_kimi_linear  # noqa: E402
@@ -57,7 +57,8 @@ def value_and_grads(model, kind, paths, keep, x, lp):
     w = jax.random.normal(jax.random.PRNGKey(7), x.shape)
 
     def loss(x, lp):
-        out = model._run_layer(0, kind, x, lp, {}, paths, keep)[0]
+        out = model._run_layer(0, kind, x, lp, {}, paths,
+                               {kimi_linear.MLP_KEPT: keep})[0]
         return jnp.sum(out * w), out
     (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1),
                                          has_aux=True)(x, lp)
@@ -75,18 +76,9 @@ def assert_same(got, want):
 def products_of(jaxpr, shape) -> int:
     """``dot_general``s of ``jaxpr`` (and of every jaxpr inside it) whose
     result has ``shape``."""
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "dot_general" \
-                and tuple(eqn.outvars[0].aval.shape) == tuple(shape):
-            n += 1
-        for value in eqn.params.values():
-            for inner in (value if isinstance(value, (tuple, list))
-                          else (value,)):
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    n += products_of(inner, shape)
-    return n
+    return sum(eqn.primitive.name == "dot_general"
+               and tuple(eqn.outvars[0].aval.shape) == tuple(shape)
+               for eqn in all_eqns(jaxpr))
 
 
 @pytest.mark.parametrize("form", sorted(FORMS))
@@ -126,7 +118,7 @@ def test_beside_the_scan_kernels_kept_the_gradients_are_the_same(monkeypatch):
     assert products_of(jaxpr, (1, 64, 64)) == 3     # gate, up, d(mid)
 
 
-RESERVE = kimi_linear.MLP_KEEP_RESERVE
+RESERVE = sdar_moe.KEEP_RESERVE
 
 
 @pytest.mark.parametrize("layer_bytes, room, kept", [
@@ -147,25 +139,46 @@ def test_the_rules_table(layer_bytes, room, kept):
     reserve of its positions (None: the device said nothing)."""
     positions, held = 100, 7 * GB
     limit = 0 if room is None else held + RESERVE * positions + room
-    assert kimi_linear.mlp_kept_by(layer_bytes, positions=positions,
+    assert sdar_moe.kept_by(layer_bytes, positions=positions,
                                    limit=limit, held=held) == kept
 
 
-def test_the_reserve_holds_the_cells_steps():
-    """The constant against the readings it was set from (``PERF.md``
-    section 6, PR 47; a v5e's 16.9 GB): the Phi-4-flash cell's step
-    (697,094,272 parameters at 16 bytes, six layers of 671 MB, 8,192
-    positions) keeps six of six, and the same stack of eight layers
-    (915,352,576 parameters, published layers 12-19) keeps none."""
+#: the readings the reserve was set from (``sdar_moe.KEEP_RESERVE``'s
+#: comment; PERF.md section 6, PR 54): a stack's held bytes (16 a parameter,
+#: and GLM's two head passes' second sequence), positions, the attention
+#: layers' kept bytes, the SwiGLUs', and what the rule has to say of both
+READINGS = {
+    # Trinity-Mini: the dense layer's products give way to the kernels'
+    "trinity": (11_287_588_864, 16384, [136_314_880] * 5,
+                [805_306_368] + [134_217_728] * 4, (5, 4)),
+    # Phi-4-flash: three attention layers, five of six products
+    "phi4": (16 * 697_094_272, 8192, [85_196_800] * 3,
+             [8192 * 2 * 10240 * 4] * 6, (3, 5)),
+    # the same stack of eight layers (published 12-19): four attention
+    # layers' kernel tensors and no product
+    "phi4-eight": (16 * 915_352_576, 8192, [85_196_800] * 4,
+                   [8192 * 2 * 10240 * 4] * 8, (4, 0)),
+    # GLM-4.7-Flash: six blocks' kernel tensors, the five shared experts'
+    "glm": (11_839_091_712, 16384, [41_943_040 + 327_680] * 6,
+            [1_342_177_280] + [201_326_592] * 5, (6, 5)),
+}
+
+
+@pytest.mark.parametrize("stack", sorted(READINGS))
+def test_the_reserve_holds_the_cells_steps(stack):
+    """The constant against the compiled steps it was set from (a v5e's
+    16.9 GB): the rule, attention first and the products in what is left,
+    says of each stack what its compiled step was read with."""
+    held, positions, attn, mlp, want = READINGS[stack]
     limit = 16_909_336_064
-    layer = 8192 * 2 * 10240 * 4
-    assert kimi_linear.mlp_kept_by([layer] * 6, positions=8192, limit=limit,
-                                   held=16 * 697_094_272) == 6
-    assert kimi_linear.mlp_kept_by([layer] * 8, positions=8192, limit=limit,
-                                   held=16 * 915_352_576) == 0
+    kept = sdar_moe.kept_by(attn, positions=positions, limit=limit,
+                            held=held)
+    assert (kept, sdar_moe.kept_by(
+        mlp, positions=positions, limit=limit,
+        held=held + sum(attn[len(attn) - kept:]))) == want
 
 
-@pytest.mark.parametrize("optimizer", sorted(kimi_linear.OPTIMIZER_COPIES))
+@pytest.mark.parametrize("optimizer", sorted(sdar_moe.OPTIMIZER_COPIES))
 def test_the_optimizers_copies_are_their_states(optimizer):
     """``OPTIMIZER_COPIES`` against ``build_optimizer``'s own state, and
     ``_held_bytes``: the parameters, those copies, one of gradients."""
@@ -175,7 +188,7 @@ def test_the_optimizers_copies_are_their_states(optimizer):
     own = sum(x.size for x in jax.tree.leaves(params))
     state = jax.eval_shape(build_optimizer(cfg).init, params)
     copies = sum(x.size for x in jax.tree.leaves(state) if x.ndim) / own
-    assert copies == kimi_linear.OPTIMIZER_COPIES[optimizer]
+    assert copies == sdar_moe.OPTIMIZER_COPIES[optimizer]
     assert model._held_bytes(params, jnp.zeros((1, L), jnp.int32)) \
         == 4 * own * (2 + copies)
 
@@ -215,8 +228,8 @@ def test_which_layers_keep_where_the_memory_is_described(
     if spare is not None:
         described(monkeypatch, int(model._held_bytes(params, ids)
                                    + RESERVE * B * L + spare))
-    got = model._mlp_keeps(params, ids)
-    assert "".join("-+"[k] for k in got) == keeps
+    got = model._keeps(params, ids, {})
+    assert "".join("-+"[k[kimi_linear.MLP_KEPT]] for k in got) == keeps
     assert model.step_notes["mlp_kept"] == note
 
 
@@ -225,8 +238,8 @@ def test_a_backend_that_is_no_tpu_or_says_nothing_keeps_nothing(monkeypatch):
     # a TPU backend whose device reports no limit (here: the CPU's None)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert sdar_moe.device_memory_bytes() == 0
-    assert kimi_linear.mlp_kept_note(0, 4, 0) == "0/4"
-    assert kimi_linear.mlp_kept_note(6, 6, 4_026_531_840) \
+    assert sdar_moe.kept_note(0, 4, 0) == "0/4"
+    assert sdar_moe.kept_note(6, 6, 4_026_531_840) \
         == "6/6 layers 4.03 GB"
 
 

@@ -65,7 +65,9 @@ SPEC = Spec(
                            "attn_scores": "xla",
                            "head_grad":
                            "forward 3 products/chunk, 0.00 GB kept",
-                           "mlp_kept": "0/%d" % len(trainer.model.kinds)},
+                           "mlp_kept": "0/%d" % len(trainer.model.kinds),
+                           # the window, full and cross layers
+                           "attn_kept": "0/3"},
     refusals=(
         ({"layer_types": "mamba,gmu"}, "layer_types"),
         ({"layer_types": CUT.replace("gmu", "conv")}, "layer_types"),

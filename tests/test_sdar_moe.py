@@ -150,6 +150,7 @@ class TestSdarMoE(DecoderContract, SmallBuffer, RowKernels):
             p, jnp.zeros((B, 2 * L), jnp.int32)), params)
         assert model.step_notes == {
             "attn_scores": "xla", "moe_rows": "xla", "moe_products": "xla",
+            "attn_kept": "0/%d" % model.cfg.decoder_layers,
             "moe_rows_moved": "{moe_pairs_held}/%d" % (
                 model.cfg.decoder_layers * model.cfg.moe_pair_capacity)}
 

@@ -82,6 +82,8 @@ def notes(trainer):
     return {"kda_scan": "chunk64/sub16", "attn_scores": "xla",
             "head_grad": "forward 3 products/chunk, 0.00 GB kept",
             "mlp_kept": "0/%d" % len(trainer.model.kinds),
+            "attn_kept": "0/%d" % sum(
+                mixer == "gqa" for mixer, _ in trainer.model.kinds),
             "moe_rows": "xla", "moe_products": "xla",
             "moe_rows_moved": "{moe_pairs_held}/%d" % (
                 2 * trainer.cfg.moe_pair_capacity)}

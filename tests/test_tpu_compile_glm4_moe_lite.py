@@ -10,6 +10,7 @@ import re
 import jax
 
 from benchmark import harness
+from decoder_contract import attention_kernel_calls
 from deepfm_tpu.utils import profiling
 
 
@@ -21,7 +22,13 @@ def test_glm4_step_at_the_cells_shapes_takes_the_kernel_at_256(step_for_v5e):
     the row kernels, ops charged to each of the model's scopes (``mtp`` and
     ``mtp_head`` among them, the second head pass's products under
     ``mtp_head`` and not ``head``), and arguments and temporaries together
-    under the issue's 15.5 GB."""
+    under the issue's 15.5 GB **with every block keeping its forward
+    kernel's output and log-sum-exp** (the chip's memory described to
+    ``sdar_moe.kept_by``: six of 42 MB, the module's block's among them,
+    placed first: six calls of the forward kernel, the parent's twelve)
+    **and the five shared experts their first products** (5 x 201 MB, as on
+    the parent's tree: the dense layer's 1.34 GB have no room since PR 49's
+    head; 8.404 + 5.614 = 14.02 GB, the parent's 13.79)."""
     tr, compiled, text = step_for_v5e(
         harness.load_json("configs", "glm-4.7-flash.json")["flags"])
     notes = tr.model.step_notes
@@ -31,7 +38,10 @@ def test_glm4_step_at_the_cells_shapes_takes_the_kernel_at_256(step_for_v5e):
     assert notes["moe_rows_moved"] == "{moe_pairs_held}/%d" % (5 * 16384)
     # multiplied by the kernels that stop at the valid prefix (PR 52)
     assert notes["moe_products"] == "kernel rows256 dw1536/2048"
+    assert notes["attn_kept"] == "6/6 layers 0.25 GB"
+    assert notes["mlp_kept"] == "5/6 layers 1.01 GB"
     by_op = profiling.hlo_op_scopes(text)
+    assert attention_kernel_calls(by_op) == (6,) * 3
     assert {scope for name, scope in by_op.items()
             if name.startswith("moe_grouped_dot")} == {"moe"}
     assert not any(name.startswith("ragged-dot") for name in by_op)

@@ -14,6 +14,7 @@ import jax
 from benchmark import harness
 from decoder_contract import products_in_scope
 from deepfm_tpu.utils import profiling
+from test_tpu_compile_shared_attention import PARENT, opcode_counts
 
 
 def test_hybrid_decoder_step_at_the_cells_shapes_fits_and_names_its_layers(
@@ -24,16 +25,22 @@ def test_hybrid_decoder_step_at_the_cells_shapes_fits_and_names_its_layers(
     arguments and temporaries together under the chip's memory (the issue's
     fallback to one sequence a step starts at 15.5 GB) **with the dense
     MLP and the four shared experts keeping their first products** (the
-    chip's memory described to ``kimi_linear.mlp_kept_by``: 1.21 + 4 x 0.134
+    chip's memory described to ``sdar_moe.kept_by``: 1.21 + 4 x 0.134
     GB): the scope ``mlp`` holds nine products a SwiGLU (gate, up and down
     forward, six backward) and none made again; one that keeps nothing
-    holds gate and up a third time."""
+    holds gate and up a third time. Its latent attention's scores are
+    XLA's: no block kernel is in the step, ``attn_kept`` is 0/1, and the
+    step is the one PR 53's tree compiled, instruction for instruction by
+    opcode (``step_opcodes_shared_attention.json``, counted there)."""
     tr, compiled, text = step_for_v5e(
         harness.load_json("configs", "kimi-linear-48b-a3b.json")["flags"])
     scopes = set(profiling.hlo_op_scopes(text).values())
     assert {"embed", "kda", "kda_scan", "attn", "mlp", "moe", "head",
             "opt"} <= scopes
     assert tr.model.step_notes["mlp_kept"] == "5/5 layers 1.74 GB"
+    assert tr.model.step_notes["attn_kept"] == "0/1"
+    assert "splash_mqa" not in text
+    assert opcode_counts(text) == PARENT["kimi-linear-48b-a3b"]["opcodes"]
     assert products_in_scope(text, "mlp") == (5 * 9, 0)
     memory = compiled.memory_analysis()
     assert 7.8e9 < memory.argument_size_in_bytes < 8.0e9

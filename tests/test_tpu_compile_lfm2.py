@@ -9,7 +9,7 @@ import re
 import jax
 
 from benchmark import harness
-from decoder_contract import products_in_scope
+from decoder_contract import attention_kernel_calls, products_in_scope
 from deepfm_tpu.utils import profiling
 
 
@@ -25,14 +25,18 @@ def test_lfm2_step_at_the_cells_shapes_takes_the_kernels_at_64_lanes(
     temporaries together under the issue's 15.5 GB (measured here: 6.094 +
     4.254 GB; with the kernel refused 6.094 + 8.941) **with the one dense
     MLP keeping its first products** (the chip's memory described to
-    ``kimi_linear.mlp_kept_by``: 0.94 GB; no layer has a shared expert):
-    nine products under ``mlp``, none made again."""
+    ``sdar_moe.kept_by``: 0.94 GB; no layer has a shared expert): nine
+    products under ``mlp``, none made again; **and the full layer its
+    forward kernel's output and log-sum-exp** (67 + 2 MB, placed first): one
+    call of the forward kernel, where the parent's step holds two (6.094 +
+    5.138 GB since PR 54)."""
     tr, compiled, text = step_for_v5e(
         harness.load_json("configs", "lfm2-8b-a1b.json")["flags"])
     notes = tr.model.step_notes
     assert (notes["attn_scores"], notes["attn_score_blocks"],
             notes["moe_rows"], notes["conv_taps_by"]) == (
                 "kernel", "136/256", "kernel", "xla")
+    assert notes["attn_kept"] == "1/1 layers 0.07 GB"
     assert notes["mlp_kept"] == "1/1 layers 0.94 GB"
     assert notes["moe_products"] == "kernel rows256 dw1792/2048"
     assert products_in_scope(text, "mlp") == (9, 0)
@@ -41,6 +45,7 @@ def test_lfm2_step_at_the_cells_shapes_takes_the_kernels_at_64_lanes(
             "moe", "head", "opt"} <= set(by_op.values())
     assert {scope for name, scope in by_op.items()
             if name.startswith("splash_mqa")} == {"attn_scores"}
+    assert attention_kernel_calls(by_op) == (1,) * 3
     assert {scope for name, scope in by_op.items() if name.startswith(
         ("moe_take_rows", "moe_add_rows"))} == {"moe"}
     assert not re.search(r"f32\[[\d,]*1024,8192\]", text)

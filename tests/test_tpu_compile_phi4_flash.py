@@ -11,7 +11,7 @@ import jax
 import pytest
 
 from benchmark import harness
-from decoder_contract import products_in_scope
+from decoder_contract import attention_kernel_calls, products_in_scope
 from deepfm_tpu.utils import profiling
 
 
@@ -25,13 +25,18 @@ def test_phi4_flash_step_at_the_cells_shapes_fits_and_takes_the_kernels(
     model's scopes (``mamba``, ``mamba_scan`` and ``gmu`` among them), no
     whole-sequence scan state
     (``[8192, 16, 5120]`` float32 would be 2.7 GB), and arguments and
-    temporaries together under the issue's 15.5 GB **with every layer
-    keeping its MLP's first product** (the chip's memory described to
-    ``kimi_linear.mlp_kept_by``: six of 671 MB; 8.366 + 6.739 GB, and 4.043
-    GB of temporaries where none keeps): the scope ``mlp`` holds six
-    products a layer (the first and the down product forward, four
-    backward) and none made again; a layer that keeps nothing holds the
-    first product a third time."""
+    temporaries together under the issue's 15.5 GB **with the three
+    attention layers keeping their forward kernel's output and log-sum-exp**
+    (the chip's memory described to ``sdar_moe.kept_by``: three of 85 MB,
+    placed first: the forward kernel is called three times, the parent's
+    six) **and five of the six layers their MLP's first product** (five of
+    671 MB in what is left; 6/6 layers 4.03 GB until PR 54: the rule's room
+    is 3.87 GB at 225 KiB a position, the kernels' 0.26 GB leave 3.61, five
+    products take 3.36; 8.366 + 6.190 = 14.56 GB, the parent's 14.94, and
+    3.787 GB of temporaries where nothing is kept): the scope ``mlp`` holds
+    six products a layer (the first and the down product forward, four
+    backward) and the first layer, which keeps nothing, the first product a
+    third time, made again."""
     tr, compiled, text = step_for_v5e(
         harness.load_json("configs",
                           "phi-4-mini-flash-reasoning.json")["flags"])
@@ -39,9 +44,11 @@ def test_phi4_flash_step_at_the_cells_shapes_fits_and_takes_the_kernels(
     assert (notes["attn_scores"], notes["attn_window_blocks"],
             notes["attn_score_blocks"]) == ("kernel", "31/256", "136/256")
     assert notes["mamba_scan"] == "kernel steps64"
-    assert notes["mlp_kept"] == "6/6 layers 4.03 GB"
-    assert products_in_scope(text, "mlp") == (6 * 6, 0)
+    assert notes["attn_kept"] == "3/3 layers 0.26 GB"
+    assert notes["mlp_kept"] == "5/6 layers 3.36 GB"
+    assert products_in_scope(text, "mlp") == (6 * 6 + 1, 1)
     by_op = profiling.hlo_op_scopes(text)
+    assert attention_kernel_calls(by_op) == (3,) * 3
     assert {"embed", "mamba", "mamba_scan", "gmu", "attn", "attn_scores",
             "mlp", "head", "opt"} <= set(by_op.values())
     assert not {"moe", "kda", "conv", "cross"} & set(by_op.values())

@@ -15,6 +15,8 @@ import collections
 
 import jax
 
+from benchmark import harness
+from decoder_contract import attention_kernel_calls
 from deepfm_tpu.utils import profiling
 
 # The SDAR cell's attention widths (4 query heads of 128 on one key/value
@@ -35,11 +37,15 @@ def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
     """On a TPU at head_dim 128 the step's masked scores are the three
     kernels JAX's flash attention is made of (forward; dq; dk and dv), each
     charged to ``attn`` by the step's own text, though each prints over three
-    lines (``profiling.whole_instructions``), and the model says so."""
+    lines (``profiling.whole_instructions``), and the model says so. The
+    scanned layers keep the forward kernel's output and log-sum-exp (the
+    chip's memory described to ``sdar_moe.kept_by``), so the scan's backward
+    body holds no forward kernel: one call in the step, the parent's two."""
     tr, compiled, text = step_for_v5e(SDAR_FLAGS)
     scopes = profiling.hlo_op_scopes(text)
     assert tr.model.step_notes == {
         "attn_scores": "kernel", "attn_score_blocks": "3/4",
+        "attn_kept": "2/2 layers 0.00 GB",
         "head_grad": "forward 3 products/chunk, 0.00 GB kept",
         "moe_rows": "kernel", "moe_products": "kernel rows256 dw128/256",
         "moe_rows_moved": "{moe_pairs_held}/8192"}
@@ -69,6 +75,7 @@ def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
         "splash_mqa_fwd_residuals", "splash_mqa_dq_no_residuals",
         "splash_mqa_dkv_no_residuals"}, kernels
     assert set(kernels.values()) == {"attn"}, kernels
+    assert attention_kernel_calls(kernels) == (1,) * 3
     # the raw text loses them: their op_name is on a continuation line
     raw = profiling.hlo_op_scopes(compiled.as_text())
     assert {raw[name] for name in kernels} == {""}
@@ -77,6 +84,31 @@ def test_decoder_step_makes_its_masked_scores_in_the_attention_kernels(
         return {n: s for n, s in by_op.items()
                 if n not in kernels and not n.startswith("pallas_call")}
     assert others(scopes) == others(raw)
+
+
+def test_sdar_step_at_the_cells_shapes_keeps_the_kernels_results_and_fits(
+        step_for_v5e):
+    """``sdar-30b-a3b.train-sequences``'s own step (every width, 6 scanned
+    layers, 2 x 2 x 4,096 positions) compiled for a described v5e: every
+    layer keeps its forward kernel's output and log-sum-exp (6 x 17 MB, the
+    scan's stacked outputs), the forward kernel is called once in the step
+    (the scan's forward body; the parent's step holds it in the backward
+    body too), and arguments and temporaries together are under the 15.5 GB
+    the other cells' tests allow a step (6.578 + 7.584 = 14.16 GB; the
+    parent's 14.33: what the backward body no longer makes again outweighs
+    what is kept)."""
+    tr, compiled, text = step_for_v5e(
+        harness.load_json("configs", "sdar-30b-a3b.json")["flags"])
+    notes = tr.model.step_notes
+    assert (notes["attn_scores"], notes["attn_score_blocks"]) == (
+        "kernel", "80/256")
+    assert notes["attn_kept"] == "6/6 layers 0.10 GB"
+    by_op = profiling.hlo_op_scopes(text)
+    assert attention_kernel_calls(by_op) == (1,) * 3
+    memory = compiled.memory_analysis()
+    assert 6.5e9 < memory.argument_size_in_bytes < 6.7e9
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 15.5e9
 
 
 def test_decoder_step_at_head_dim_32_keeps_the_xla_scores(step_for_v5e):

@@ -3,10 +3,31 @@ Solar-Open2), each a short stack of its cell's own widths compiled for a
 *described* v5e (no chip attached, nothing runs; the fixtures are
 ``conftest.py``'s): with the leaves and steps absent that PR 53 made the
 layer's choice (no output gate or a gate only, QK-norm or none, a rotation
-or none), the compiled step is the one PR 52's tree compiled, held as its
-instructions counted by opcode (``step_opcodes_shared_attention.json``:
-counted on PR 52's tree by the same expression, so the test needs no parent
-checkout)."""
+or none), the compiled step is a pinned one, held as its instructions
+counted by opcode (``step_opcodes_shared_attention.json``: counted by the
+same expression on the tree that pinned it, so the test needs no parent
+checkout). PR 53 held the three to PR 52's counts. PR 54 counted them anew,
+on purpose: a layer by the kernel keeps the forward kernel's output and
+log-sum-exp (``ops/block_attention.KEPT``), so the second
+``splash_mqa_fwd_residuals`` call of each kernel layer is gone with what fed
+only it. Against PR 52's counts (8,251 / 5,650 / 9,481 instructions): LFM2
+8,177 (``custom-call`` 150 -> 145, ``copy-start`` / ``copy-done`` 273 ->
+254, ``slice-start`` / ``slice-done`` 213 -> 199, ``bitcast`` -2,
+``convert``, ``copy`` and ``get-tuple-element`` -1, ``reduce-precision``
+and ``reshape`` +1); Solar-Open2 9,417 (``custom-call`` 181 -> 176,
+``copy-start`` / ``copy-done`` 381 -> 366, ``slice-start`` /
+``slice-done`` 424 -> 408, ``bitcast`` and ``convert`` -1, ``fusion``,
+``get-tuple-element``, ``parameter``, ``reduce-precision`` and ``tuple``
++1); SDAR 5,726, more, not fewer: the call leaves the scan's backward body,
+and the two kept tensors become the scan's stacked outputs
+(``dynamic-update-slice`` 20 -> 22 written, ``dynamic-slice`` 37 -> 39
+read, ``copy-start`` / ``copy-done`` 155 -> 168, ``fusion`` 298 -> 306,
+``parameter`` 901 -> 917, ``custom-call`` 98 -> 101, ``constant`` +5,
+``get-tuple-element`` +8, ``reduce-precision`` and ``bitcast`` +2,
+``add``, ``reduce`` and ``slice`` +1, ``convert`` -1). The file's fourth
+entry is the whole Kimi-Linear cell's step, which holds no block kernel:
+counted on PR 53's tree and the same on PR 54's
+(``test_tpu_compile_kimi_linear.py`` reads it)."""
 
 import collections
 import json

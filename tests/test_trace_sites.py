@@ -552,6 +552,7 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
     tr, events = _sdar_fit()
     assert tr.model.step_notes == {
         "attn_scores": "xla", "moe_rows": "xla", "moe_products": "xla",
+        "attn_kept": "0/1",
         "head_grad": "forward 3 products/chunk, 0.00 GB kept",
         "moe_rows_moved": "{moe_pairs_held}/64"}
     syncs = [e["args"] for e in events if e["name"] == "train.log_sync"]
@@ -567,7 +568,8 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
     trace_lib.export(path)
     report = _report()
     loaded, _ = report._load(path)
-    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla"}
+    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla",
+                                               "kept": "0/1"}
     assert report.row_updates(loaded) is None
     moved = report.expert_rows(loaded)
     assert moved == {"steps": 2, "rows": "xla", "buffer": 64,
@@ -576,7 +578,8 @@ def test_log_sync_says_what_makes_the_attention_scores(tmp_path, capsys):
     assert report.main([path]) == 0
     out = capsys.readouterr().out
     assert ("block-masked attention over 2 logged steps: scores by xla, "
-            "every score computed") in out
+            "every score computed; the forward kernel's output and "
+            "log-sum-exp kept for the backward pass in 0/1\n") in out
     assert ("expert layers' rows over 2 logged steps: moved by xla, %.0f of "
             "64 buffer rows a step held a pair" % moved["held"]) in out
     assert "multiplied by xla" in out
@@ -588,10 +591,13 @@ def test_report_prints_the_kernels_block_count(tmp_path, capsys):
     events = [{"name": "train.log_sync", "ph": "X", "ts": 10.0 * i,
                "dur": 1.0, "pid": 1, "tid": 1,
                "args": {"step": i, "attn_scores": "kernel",
-                        "attn_score_blocks": "80/256"}} for i in (1, 2, 3)]
+                        "attn_score_blocks": "80/256",
+                        "attn_kept": "6/6 layers 0.10 GB"}}
+              for i in (1, 2, 3)]
     report = _report()
     assert report.attention_scores(events) == {
-        "steps": 3, "scores": "kernel", "visited": 80, "total": 256}
+        "steps": 3, "scores": "kernel", "visited": 80, "total": 256,
+        "kept": "6/6 layers 0.10 GB"}
     # a ranker's trace, or one that predates the attribute, has no line
     assert report.attention_scores(
         [{"name": "train.log_sync", "ph": "X", "args": {"step": 2}}]) is None
@@ -611,7 +617,9 @@ def test_report_prints_the_kernels_block_count(tmp_path, capsys):
     path.write_text(__import__("json").dumps({"traceEvents": events}))
     assert report.main([str(path)]) == 0
     assert ("block-masked attention over 3 logged steps: scores by kernel, "
-            "80 of 256 blocks of the score matrix visited a head (31.2%)"
+            "80 of 256 blocks of the score matrix visited a head (31.2%); "
+            "the forward kernel's output and log-sum-exp kept for the "
+            "backward pass in 6/6 layers 0.10 GB\n"
             ) in capsys.readouterr().out
     assert report.main([str(path), "--json"]) == 0
     assert '"attention_scores"' in capsys.readouterr().out
@@ -821,7 +829,8 @@ def test_log_sync_says_the_causal_score_path_and_the_write_strength(
         "log_decay_min": pytest.approx(min(
             a["kda_chunk_log_decay_min"] for a in syncs)),
         "beta_over_one": pytest.approx(sum(over) / 2)}
-    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla"}
+    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla",
+                                               "kept": "0/1"}
     assert report.main([path]) == 0
     out = capsys.readouterr().out
     assert ("delta-rule scan over 2 logged steps: chunk64/sub16, most "
@@ -829,7 +838,8 @@ def test_log_sync_says_the_causal_score_path_and_the_write_strength(
     assert ("write strength over 1 at %.0f positions x heads a step"
             % (sum(over) / 2)) in out
     assert ("block-masked attention over 2 logged steps: scores by xla, "
-            "every score computed") in out
+            "every score computed; the forward kernel's output and "
+            "log-sum-exp kept for the backward pass in 0/1\n") in out
     # on the kernel the note names the causal half's blocks
     notes = tr.model.step_notes
     assert notes["attn_scores"] == "xla" and "kda_scan" in notes
@@ -888,7 +898,8 @@ def test_log_sync_says_the_convolution_the_scores_and_the_bias(tmp_path,
     loaded, _ = report._load(path)
     assert report.short_convolution(loaded) == {"steps": 2, "taps_by": "xla"}
     assert report.delta_rule_scan(loaded) is None
-    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla"}
+    assert report.attention_scores(loaded) == {"steps": 2, "scores": "xla",
+                                               "kept": "0/1"}
     assert report.expert_rows(loaded)["bias_moved_picks"] == sum(picks) / 2
     assert report.main([path]) == 0
     out = capsys.readouterr().out
